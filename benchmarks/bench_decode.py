@@ -26,7 +26,7 @@ legs, and zero decode-step recompiles after warmup.
 CPU-friendly by design: the win is scheduling arithmetic — how many
 sequences' tokens ride one fixed-shape dispatch — the same lever on a
 TPU, where the per-dispatch cost is even more expensive relative to
-per-row compute (chip capture queued via tools/tpu_watchdog2.sh).
+per-row compute (not measured on a chip).
 
 Two further legs ride the same harness (ISSUE 15):
 

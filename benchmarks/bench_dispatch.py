@@ -192,7 +192,7 @@ def run_prefetch_regime(iters, reps, smoke):
     # pipeline, not recompiles; each leg still gets a fresh scope (fresh
     # params + fresh fast-path binding)
     exe = fluid.Executor()
-    feeder = fluid.DataFeeder(feed_list=["x", "y"], place=fluid.TPUPlace(),
+    feeder = fluid.DataFeeder(feed_list=["x", "y"], place=exe.place,
                               program=model["main"])
 
     # calibrate: steady-state step time (dispatch + compute: the loss is

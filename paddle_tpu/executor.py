@@ -30,9 +30,10 @@ a prefetched batch costs zero host copies at dispatch
 (``feed_host_copy_count`` instruments the contract).
 Invalidation: ``program.version`` bump, any public
 scope mutation, feed shape/dtype drift.  ``PADDLE_TPU_FAST_PATH=0`` /
-``PADDLE_TPU_LAZY_FETCH=0`` are killswitches, and
-``PADDLE_TPU_COMPILATION_CACHE_DIR`` opts into a persistent XLA compile
-cache so warm-up survives process restarts (enable_compilation_cache).
+``PADDLE_TPU_LAZY_FETCH=0`` are killswitches.
+A persistent XLA compile cache (``$JAX_COMPILATION_CACHE_DIR``, else
+``<checkout>/.jax_cache``) lets warm-up survive process restarts
+(enable_compilation_cache).
 """
 from __future__ import annotations
 
@@ -279,6 +280,10 @@ class LazyFetch:
     def __getattr__(self, name):
         if name in ("_np", "_device_value"):  # guard copy/pickle recursion
             raise AttributeError(name)
+        if name in ("__array_interface__", "__array_struct__"):
+            # numpy prefers these to __array__, and they cannot describe an
+            # extension dtype: a bfloat16 fetch would come back as raw '|V2'
+            raise AttributeError(name)
         # anything not handled above delegates to the materialized array
         return getattr(self.materialize(), name)
 
@@ -467,18 +472,25 @@ class JitStepCache:
         return fn
 
 
-def enable_compilation_cache(cache_dir=None):
-    """Opt-in persistent XLA compilation cache: compiled executables are
-    written to ``cache_dir`` (or ``$PADDLE_TPU_COMPILATION_CACHE_DIR``) via
-    jax's ``jax_compilation_cache_dir``, so warm-up compiles survive process
-    restarts.  Returns True if the cache was enabled.  Also called lazily by
-    the first ``Executor()`` when the environment variable is set."""
+# where the persistent compile cache lives when nobody says otherwise: one
+# fixed path inside the checkout (the path is part of the cache key, so a
+# directory that moves — a tmp name, a pid, the time — would never hit)
+_DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache")
+
+
+def enable_compilation_cache():
+    """Persistent XLA compilation cache, so warm-up compiles survive process
+    restarts.  ``$JAX_COMPILATION_CACHE_DIR`` is how a caller places it: jax
+    reads the variable itself and this function sets no directory at all;
+    where it is unset the cache goes to ``<checkout>/.jax_cache``.  Returns
+    True if a cache directory is in use.  Called by the first
+    ``Executor()``."""
     from .core import safe_import_jax
 
     jax = safe_import_jax()
-    cache_dir = cache_dir or os.environ.get("PADDLE_TPU_COMPILATION_CACHE_DIR")
-    if not cache_dir:
-        return False
+    from_env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    cache_dir = from_env or _DEFAULT_COMPILE_CACHE_DIR
     # a corrupt/unwritable cache dir (a file squatting on the path, a dead
     # mount, bad permissions) must degrade to running uncached — warm-up
     # persistence is an optimization, never a reason executor setup fails
@@ -499,23 +511,27 @@ def enable_compilation_cache(cache_dir=None):
             "persistent compilation cache dir %r is unusable (%s); "
             "continuing without a compile cache" % (cache_dir, e))
         return False
-    try:
+    if not from_env:
         jax.config.update("jax_compilation_cache_dir", cache_dir)
-    except Exception as e:  # pragma: no cover - jax without the option
-        warnings.warn("persistent compilation cache unavailable: %s" % e)
-        return False
     # default thresholds skip tiny/fast compiles; persist everything —
     # dispatch-bound training loops are exactly the small-program regime
-    for opt, val in (("jax_persistent_cache_min_entry_size_bytes", -1),
-                     ("jax_persistent_cache_min_compile_time_secs", 0.0)):
-        try:
-            jax.config.update(opt, val)
-        except Exception:
-            pass
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     return True
 
 
 _compile_cache_checked = [False]
+
+
+def _step_dtype(dtype):
+    """A feed dtype as the compiled step sees it: with x64 off jax narrows
+    an int64 host array to int32 on the way in, so a numpy int64 feed and
+    the int32 device array the prefetcher made from it are ONE signature
+    (and one executable), not two."""
+    import jax
+
+    return str(jax.dtypes.canonicalize_dtype(dtype))
+
 
 def _retry_fresh_entry(entry, state_in, feed_arrays, key):
     """First call of a freshly built entry is the compile: transient XLA
@@ -1032,13 +1048,15 @@ class Executor:
     _BOUND_CAP = 64  # fast-path bound (program, scope, fetches, shapes)
 
     def __init__(self, place=None):
-        from .core import TPUPlace, safe_import_jax
+        from .core import default_place, safe_import_jax
 
         safe_import_jax()  # first jax import eats np.random state otherwise
         if not _compile_cache_checked[0]:
             _compile_cache_checked[0] = True
-            enable_compilation_cache()  # opt-in via env var, no-op otherwise
-        self.place = place if place is not None else TPUPlace()
+            enable_compilation_cache()
+        # no place given = jax's default device, resolved once and visible
+        self.place = place if place is not None else default_place()
+        self.place.jax_device()  # an explicit place that names no device raises here
         self._cache: dict = {}
         self._bound: dict = {}
         self._cache_cap = _env_cap("PADDLE_TPU_EXECUTOR_CACHE_CAP",
@@ -1202,7 +1220,7 @@ class Executor:
 
         sig = (
             program.fingerprint(),
-            tuple(sorted((n, tuple(np.shape(v)), str(np.asarray(v).dtype) if not hasattr(v, "dtype") else str(v.dtype)) for n, v in feed_arrays.items())),
+            tuple(sorted((n, tuple(np.shape(v)), _step_dtype(v.dtype if hasattr(v, "dtype") else np.asarray(v).dtype)) for n, v in feed_arrays.items())),
             tuple(fetch_names),
             tuple(sorted(state_in)),
             _NAN_DEBUG["on"],  # probes are baked into the executable
@@ -1606,7 +1624,8 @@ class Executor:
                 if blk.has_var(name):
                     var = blk.var(name)
                     want = var.dtype
-                    if want is not None and val.dtype != core.np_dtype(want):
+                    if want is not None and str(val.dtype) != _step_dtype(
+                            core.np_dtype(want)):
                         val = val.astype(core.np_dtype(want))
                     self._check_feed_shape(name, var, val)
                 out[name] = val
@@ -1825,11 +1844,20 @@ class Executor:
                 new_mut = {n: new_state[n] for n in out_names if n in new_state}
                 return fetches, new_mut, next_key
 
-            jitted = jax.jit(split_step, donate_argnums=(0,))
+            # everything the step takes and returns lives on the place's
+            # device, said explicitly: jax keys its executables on whether
+            # each argument is committed, so leaving placement to "wherever
+            # the arrays happen to be" compiled the same step up to four
+            # times (host vs prefetched feeds x fresh vs stepped state).
+            # jit re-places a committed argument that sits whole on another
+            # device by itself; what it refuses is one SPLIT over devices
+            # (state a ParallelExecutor tp-sharded in the same scope), so
+            # the runner gathers those onto the place's device first.
             device = self.place.jax_device()
-            _filter_donation_warning_once()
-            is_default_device = device == jax.devices()[0]
             home = jax.sharding.SingleDeviceSharding(device)
+            jitted = jax.jit(split_step, donate_argnums=(0,),
+                             in_shardings=home, out_shardings=home)
+            _filter_donation_warning_once()
 
             def runner(state, feeds, key):
                 mut_set = cells["mut_set"]
@@ -1839,22 +1867,13 @@ class Executor:
                 mut = {}
                 ro = {}
                 for n, v in state.items():
+                    sh = getattr(v, "sharding", home)
+                    if sh is not home and not sh.is_fully_replicated:
+                        v = jax.device_put(v, home)
                     if n in mut_set:
                         mut[n] = v
                     else:
                         ro[n] = v
-                # a committed device feed on the WRONG device would abort
-                # the jit call; re-place it (prefetched feeds land on
-                # `device` already, so the common case is a no-op check)
-                conformed = None
-                for n, v in feeds.items():
-                    if (self._is_device_array(v)
-                            and getattr(v, "sharding", None) != home):
-                        if conformed is None:
-                            conformed = dict(feeds)
-                        conformed[n] = jax.device_put(v, device)
-                if conformed is not None:
-                    feeds = conformed
                 if _xla_stats.active() and not cap_cell["done"]:
                     # capture BEFORE the first real call so the gauges are
                     # live by the time the step's record/observe fires;
@@ -1864,10 +1883,7 @@ class Executor:
                     cap_cell["fresh"] = True
                     cap_cell["stats"] = _xla_stats.capture_jitted(
                         prog_tag, jitted, (mut, ro, feeds, key))
-                if is_default_device:
-                    return jitted(mut, ro, feeds, key)
-                with jax.default_device(device):
-                    return jitted(mut, ro, feeds, key)
+                return jitted(mut, ro, feeds, key)
 
             runner._alias_cell = alias_cell
             runner._guard_cell = guard_cell

@@ -855,12 +855,11 @@ def build_decode_model(params, meta, eos_id=None, use_flash=None,
     paged attention engine is ``attn_impl`` ("auto"/"reference"/
     "pallas", shared with the decode step's paged_decode_attention).
     """
-    import jax
-
+    from ..core import cpu_backend
     from ..serving.decode_scheduler import DecodeModel
 
     if use_flash is None:
-        use_flash = jax.default_backend() == "tpu"
+        use_flash = not cpu_backend()
     n_head = meta["n_head"]
 
     def prefill_fn(tokens, length):

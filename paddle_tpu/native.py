@@ -3,11 +3,15 @@ threaded dataloader, async sparse pserver).
 
 Reference analogs: paddle/fluid/recordio/*, operators/reader/*, go/pserver.
 The library is optional: every consumer has a pure-python fallback, so
-``lib() is None`` is a supported state (e.g. before `make -C csrc`).
+``lib() is None`` is a supported state (no compiler, failed build).  It is
+always built from the sources in the checkout: ``lib()`` runs ``make -C
+csrc`` (a no-op when up to date), so a stale binary lying in the tree is
+rebuilt, never loaded as it is.
 """
 from __future__ import annotations
 
 import ctypes
+import fcntl
 import os
 import subprocess
 
@@ -24,16 +28,17 @@ def lib():
     if _LIB is not None or _TRIED:
         return _LIB
     _TRIED = True
-    if not os.path.exists(_SO):
-        try:
+    try:
+        # one builder at a time: test workers start together and would
+        # otherwise interleave writes to the same objects
+        os.makedirs(os.path.dirname(_SO), exist_ok=True)
+        with open(_SO + ".lock", "w") as lock:
+            fcntl.flock(lock, fcntl.LOCK_EX)
             subprocess.run(
                 ["make", "-C", _CSRC], check=True, capture_output=True, timeout=120
             )
-        except Exception:
-            return None
-    try:
         L = ctypes.CDLL(_SO)
-    except OSError:
+    except (OSError, subprocess.SubprocessError):
         return None
 
     u8p = ctypes.POINTER(ctypes.c_uint8)

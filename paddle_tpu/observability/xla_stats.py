@@ -29,7 +29,7 @@ executor pays one module-flag read per step.  Enabled, capture costs one
 extra lowering+compile per (program, shapes) entry through the AOT path
 — jax exposes no public handle to the executable its C++ jit path built,
 so the introspection compile is a second one.  With the persistent
-compilation cache on (``PADDLE_TPU_COMPILATION_CACHE_DIR``) the second
+compilation cache on (``executor.enable_compilation_cache``) the second
 compile is a cache hit; either way it happens once per entry, never per
 step.  Capture never touches program state or RNG (lower+compile is
 pure), so training is bitwise-identical with the plane on or off —
@@ -50,6 +50,7 @@ what makes them usable as drift-gate invariants (tools/check_perf_drift.py).
 from __future__ import annotations
 
 import os
+import re
 import threading
 
 from . import registry as _reg
@@ -108,9 +109,9 @@ PEAK_TABLE = (
     ("TPU v5e", 197e12, 819e9),
     ("TPU v5p", 459e12, 2765e9),
     ("TPU v6", 918e12, 1640e9),
-    # host-CPU fallback: a placeholder roof so MFU/BW-util stay defined
-    # in the hermetic CPU test mesh; tests pin explicit peaks instead of
-    # asserting against these.
+    # named placeholder (not a default for unknown kinds): a roof so
+    # MFU/BW-util stay defined in the hermetic CPU test mesh; tests pin
+    # explicit peaks instead of asserting against these.
     ("cpu", 1e11, 5e10),
 )
 
@@ -119,21 +120,19 @@ def device_peaks(device_kind=None):
     """(peak_flops, peak_membw) per device for ``device_kind`` (default:
     the first jax device's kind).  Env overrides
     ``PADDLE_TPU_PEAK_FLOPS`` / ``PADDLE_TPU_PEAK_BW`` win over the
-    table; an unknown kind falls back to the cpu row."""
+    table; a kind that matches no row is an error, not a default."""
     if device_kind is None:
-        try:
-            import jax
+        import jax
 
-            device_kind = jax.devices()[0].device_kind
-        except Exception:
-            device_kind = "cpu"
-    flops = bw = None
-    for key, f, b in PEAK_TABLE:
+        device_kind = jax.devices()[0].device_kind
+    for key, flops, bw in PEAK_TABLE:
         if key.lower() in str(device_kind).lower():
-            flops, bw = f, b
             break
-    if flops is None:
-        flops, bw = PEAK_TABLE[-1][1], PEAK_TABLE[-1][2]
+    else:
+        raise ValueError(
+            "device_kind %r matches no row of PEAK_TABLE (%s); add its "
+            "documented peaks there" % (
+                device_kind, ", ".join(k for k, _, _ in PEAK_TABLE)))
     env_f = os.environ.get("PADDLE_TPU_PEAK_FLOPS")
     env_b = os.environ.get("PADDLE_TPU_PEAK_BW")
     if env_f:
@@ -150,12 +149,13 @@ class ProgramStats:
 
     __slots__ = ("tag", "flops", "bytes_accessed", "arg_bytes", "out_bytes",
                  "temp_bytes", "alias_bytes", "code_bytes", "peak_hbm_bytes",
-                 "num_devices", "device_kind", "steps", "total_time_s",
+                 "num_devices", "device_kind", "kernel_calls", "collectives",
+                 "steps", "total_time_s",
                  "last_time_s", "last_mfu", "last_bw_util")
 
     def __init__(self, tag, flops, bytes_accessed, arg_bytes, out_bytes,
                  temp_bytes, alias_bytes, code_bytes, num_devices,
-                 device_kind):
+                 device_kind, kernel_calls=0, collectives=0):
         self.tag = tag
         self.flops = flops
         self.bytes_accessed = bytes_accessed
@@ -170,6 +170,12 @@ class ProgramStats:
         self.peak_hbm_bytes = arg_bytes + out_bytes + temp_bytes
         self.num_devices = max(1, int(num_devices))
         self.device_kind = device_kind
+        # compiled Pallas kernels in the executable (``tpu_custom_call``
+        # instructions); 0 where kernels ran interpreted or not at all
+        self.kernel_calls = int(kernel_calls)
+        # cross-device instructions the partitioner put in (all-reduce,
+        # all-gather, reduce-scatter, all-to-all, collective-permute)
+        self.collectives = int(collectives)
         self.steps = 0
         self.total_time_s = 0.0
         self.last_time_s = None
@@ -196,6 +202,8 @@ class ProgramStats:
             "peak_hbm_bytes": self.peak_hbm_bytes,
             "num_devices": self.num_devices,
             "device_kind": self.device_kind,
+            "kernel_calls": self.kernel_calls,
+            "collectives": self.collectives,
             "arith_intensity": self.arith_intensity,
             "steps": self.steps,
             "total_time_s": self.total_time_s,
@@ -297,6 +305,11 @@ def _cost_dict(compiled):
     return dict(ca or {})
 
 
+_COLLECTIVE_RE = re.compile(
+    r"(?<=[\])}] )(?:all-reduce|all-gather|reduce-scatter|all-to-all|"
+    r"collective-permute)(?:-start)?\(")
+
+
 def extract_compiled(compiled, tag="<adhoc>", num_devices=None):
     """Build a :class:`ProgramStats` from a ``jax.stages.Compiled``
     WITHOUT registering it — the pure extraction, shared by the capture
@@ -320,13 +333,10 @@ def extract_compiled(compiled, tag="<adhoc>", num_devices=None):
             num_devices = len(compiled.input_shardings[0][0].device_set)  # type: ignore[index]
         except Exception:
             num_devices = 1
-    try:
-        import jax
+    import jax
 
-        kind = jax.devices()[0].device_kind
-    except Exception:
-        kind = "cpu"
     g = lambda o, a: float(getattr(o, a, 0) or 0)  # noqa: E731
+    hlo = compiled.as_text()
     return ProgramStats(
         tag,
         flops=float(cost.get("flops", 0.0) or 0.0),
@@ -337,7 +347,9 @@ def extract_compiled(compiled, tag="<adhoc>", num_devices=None):
         alias_bytes=g(mem, "alias_size_in_bytes"),
         code_bytes=g(mem, "generated_code_size_in_bytes"),
         num_devices=num_devices,
-        device_kind=kind,
+        device_kind=jax.devices()[0].device_kind,
+        kernel_calls=hlo.count('custom_call_target="tpu_custom_call"'),
+        collectives=len(_COLLECTIVE_RE.findall(hlo)),
     )
 
 

@@ -53,5 +53,36 @@ def _flash_attention(ctx, op):
             ctx.set_output(op, "Out", out)
             return
 
-    out = flash_attention(q, k, v, kv_lens, causal)
+    if ctx.mesh is not None:
+        out = _flash_on_mesh(q, k, v, kv_lens, causal, ctx.mesh)
+    else:
+        out = flash_attention(q, k, v, kv_lens, causal)
     ctx.set_output(op, "Out", out)
+
+
+def _flash_on_mesh(q, k, v, kv_lens, causal, mesh):
+    """The single-shard kernel under an executor mesh.  XLA's partitioner
+    cannot split a Mosaic kernel ("cannot be automatically partitioned"), so
+    the call runs inside ``shard_map``: attention is independent across
+    batch and heads, so the batch splits on ``dp`` and the heads on ``tp``
+    (an axis that does not divide stays replicated, as do all others)."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    from ..parallel.flash_attention import flash_attention
+
+    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+
+    def axis(name, dim):
+        n = int(sizes.get(name, 1))
+        return name if n > 1 and dim % n == 0 else None
+
+    spec = P(axis("dp", q.shape[0]), axis("tp", q.shape[1]), None, None)
+    lens = () if kv_lens is None else (kv_lens,)
+
+    def shard(q, k, v, *lens):
+        return flash_attention(q, k, v, lens[0] if lens else None, causal)
+
+    return jax.shard_map(
+        shard, mesh=mesh, in_specs=(spec,) * 3 + (P(spec[0]),) * len(lens),
+        out_specs=spec, check_vma=False)(q, k, v, *lens)

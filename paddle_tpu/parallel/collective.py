@@ -15,7 +15,6 @@ from __future__ import annotations
 import numpy as np
 
 __all__ = [
-    "shard_map_compat",
     "all_reduce",
     "psum",
     "pmean",
@@ -30,26 +29,6 @@ __all__ = [
     "init_distributed",
     "shutdown_distributed",
 ]
-
-
-def shard_map_compat(**kwargs):
-    """``jax.shard_map`` partial application across jax versions.
-
-    Newer jax exposes ``jax.shard_map`` with a ``check_vma`` flag; older
-    releases only have ``jax.experimental.shard_map.shard_map`` whose
-    equivalent flag is ``check_rep``.  Returns a decorator equivalent to
-    ``functools.partial(shard_map, **kwargs)`` with the flag translated, so
-    call sites write the new spelling once and run on both."""
-    import functools
-
-    try:
-        from jax import shard_map as sm
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-    return functools.partial(sm, **kwargs)
 
 
 # Tracks whether THIS module initialized jax.distributed, so repeat calls

@@ -36,6 +36,8 @@ import os
 import jax
 import numpy as np
 
+from ..core import cpu_backend
+
 __all__ = ["flash_attention", "mha_reference", "paged_decode_attention",
            "paged_prefill_attention", "paged_kv_finite"]
 
@@ -187,8 +189,8 @@ def _flash_fwd(q, k, v, kv_lens, causal, sm_scale, block_q, block_k, interpret):
             jax.ShapeDtypeStruct((bh, T, D), q.dtype),
             jax.ShapeDtypeStruct((bh, T, 128), jnp.float32),
         ],
-        compiler_params=_tpu_compiler_params(
-            pltpu, dimension_semantics=("parallel", "parallel", "arbitrary"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(lens_bh, qr, kr, vr)
@@ -359,13 +361,6 @@ def _bwd_dq_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
         dq_ref[0] = dq_scr[:, :].astype(dq_ref.dtype)
 
 
-def _tpu_compiler_params(pltpu, **kwargs):
-    """pltpu.CompilerParams across jax versions (older releases spell it
-    TPUCompilerParams)."""
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)
-
-
 # Backward engine switch.  Measured on v5e.  Round 3 (fwd+bwd, causal,
 # H=8 D=64, tokens held at 16k): scan 9.9/11.6/14.7/20.8 ms vs the
 # two-kernel pallas pair 11.1/13.2/18.1/27.6 ms at T=256/512/1024/2048 —
@@ -400,8 +395,8 @@ _FUSED_MIN_T = 2048
 # 16MB/core scoped limit − margin.  14MB left only ~3% headroom on the one
 # calibrated shape (T=2048 D=64 bf16 bk=128 reports 16.70M/16M at T=4096);
 # 13MB keeps ~19% margin so model error can't push a "fits" verdict into a
-# compile-time OOM — and _fused_bwd_compiles() below is the belt to this
-# suspenders: a RESOURCE_EXHAUSTED probe compile falls back to scan.
+# compile-time OOM.  This budget is the only selector: where it is wrong
+# at some shape the step's compile error says so (no probe, no fallback).
 _FUSED_VMEM_BUDGET = 13 * 1024 * 1024
 
 
@@ -424,69 +419,6 @@ def _fused_bwd_vmem_bytes(T, D, in_itemsize, block_k):
     return T * per_token + kv
 
 
-def _is_resource_exhausted(err) -> bool:
-    """True only for capacity misses (the RESOURCE_EXHAUSTED status or the
-    Mosaic scoped-VMEM OOM phrasings) — a genuine lowering/layout bug whose
-    message merely *mentions* vmem must NOT be demoted to the scan engine,
-    it has to surface."""
-    msg = str(err).lower()
-    return ("resource_exhausted" in msg or "resource exhausted" in msg
-            or "ran out of memory" in msg
-            or "scoped allocation" in msg
-            or "exceeds the vmem limit" in msg
-            or "exceeded vmem" in msg)
-
-
-# probe-compile verdicts keyed by (shapes, dtypes, flags) — one real Mosaic
-# compile per distinct shape, then cached for the process lifetime
-_FUSED_COMPILE_OK: dict = {}
-
-
-def _fused_bwd_compiles(causal, sm_scale, block_k, res, do):
-    """Whether the fused backward actually compiles for these shapes.
-
-    The analytic VMEM model (_fused_bwd_vmem_bytes) is calibrated, not
-    exact — so the fused-engine compile itself is wrapped in a try/except:
-    a RESOURCE_EXHAUSTED (scoped-VMEM OOM) verdict falls back to the scan
-    engine instead of failing the whole step compile.  Probing is a real
-    ahead-of-time compile of JUST the backward kernel (abstract args, no
-    execution), done once per shape signature; any non-OOM error is
-    re-raised — it is a genuine bug, not a capacity miss."""
-    q = res[0]
-    key = (causal, float(sm_scale) if sm_scale else None, int(block_k),
-           tuple(tuple(x.shape) + (str(x.dtype),) for x in res if x is not None),
-           tuple(do.shape), str(do.dtype))
-    cached = _FUSED_COMPILE_OK.get(key)
-    if cached is not None:
-        return cached
-    import jax
-
-    if jax.default_backend() != "tpu":
-        # nothing to probe off-TPU: pallas either interprets or the real
-        # compile error is not a capacity question
-        _FUSED_COMPILE_OK[key] = True
-        return True
-    abstract = jax.tree_util.tree_map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), (res, do))
-    try:
-        jax.jit(
-            functools.partial(_flash_bwd_fused, causal, sm_scale, block_k, False)
-        ).lower(*abstract).compile()
-        ok = True
-    except Exception as e:  # noqa: BLE001 — classified below
-        if not _is_resource_exhausted(e):
-            raise
-        import warnings
-
-        warnings.warn(
-            "fused flash backward exceeds scoped VMEM for shape %s "
-            "(block_k=%d); falling back to the scan engine"
-            % (tuple(q.shape), block_k))
-        ok = False
-    _FUSED_COMPILE_OK[key] = ok
-    return ok
-
-
 def _flash_bwd(causal, sm_scale, block_q, block_k, interpret, res, do):
     if FLASH_BWD_BLOCK_K:
         block_k = int(FLASH_BWD_BLOCK_K)
@@ -496,9 +428,6 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, interpret, res, do):
         T, D = q.shape[2], q.shape[3]
         fits = _fused_bwd_vmem_bytes(T, D, q.dtype.itemsize, min(block_k, k_len(res))) <= _FUSED_VMEM_BUDGET
         impl = "fused" if (T >= _FUSED_MIN_T and fits) else "scan"
-    if impl == "fused" and not interpret and not _fused_bwd_compiles(
-            causal, sm_scale, block_k, res, do):
-        impl = "scan"
     if impl == "fused":
         return _flash_bwd_fused(causal, sm_scale, block_k, interpret, res, do)
     if impl == "pallas":
@@ -628,8 +557,8 @@ def _flash_bwd_fused(causal, sm_scale, block_k, interpret, res, do):
             jax.ShapeDtypeStruct((bh, S, D), k.dtype),
             jax.ShapeDtypeStruct((bh, S, D), v.dtype),
         ],
-        compiler_params=_tpu_compiler_params(
-            pltpu, dimension_semantics=("parallel", "arbitrary"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(lens_bh, qr, kr, vr, dor, ld)
@@ -700,8 +629,8 @@ def _flash_bwd_pallas(causal, sm_scale, block_q, block_k, interpret, res, do):
             jax.ShapeDtypeStruct((bh, S, D), k.dtype),
             jax.ShapeDtypeStruct((bh, S, D), v.dtype),
         ],
-        compiler_params=_tpu_compiler_params(
-            pltpu, dimension_semantics=("parallel", "parallel", "arbitrary"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(lens_bh, qr, kr, vr, orr, dor, lse_rep)
@@ -729,8 +658,8 @@ def _flash_bwd_pallas(causal, sm_scale, block_q, block_k, interpret, res, do):
             scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         ),
         out_shape=[jax.ShapeDtypeStruct((bh, T, D), q.dtype)],
-        compiler_params=_tpu_compiler_params(
-            pltpu, dimension_semantics=("parallel", "parallel", "arbitrary"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(lens_bh, qr, kr, vr, orr, dor, lse_rep)
@@ -740,22 +669,6 @@ def _flash_bwd_pallas(causal, sm_scale, block_q, block_k, interpret, res, do):
         dk.reshape(B, H, S, D),
         dv.reshape(B, H, S, D),
     )
-
-
-def _infer_interpret(x):
-    """Pallas interpret mode: off only when the inputs live on a TPU.
-
-    Concrete arrays report their platform directly; tracers (inside jit)
-    don't carry devices, so fall back to the default backend — which is
-    what the surrounding jit will compile for absent explicit placement.
-    """
-    try:
-        platforms = {d.platform for d in x.devices()}
-        if platforms:
-            return "tpu" not in platforms
-    except Exception:
-        pass
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
@@ -779,7 +692,7 @@ def _flash_impl(q, k, v, kv_lens, causal, sm_scale, block_q, block_k, interpret)
     if sm_scale is None:
         sm_scale = 1.0 / float(np.sqrt(q.shape[-1]))
     if interpret is None:
-        interpret = _infer_interpret(q)
+        interpret = cpu_backend()
     return _flash_fwd(q, k, v, kv_lens, causal, sm_scale, block_q, block_k, interpret)
 
 
@@ -792,7 +705,7 @@ def _flash_vjp_bwd(causal, sm_scale, block_q, block_k, interpret, res, do):
     if sm_scale is None:
         sm_scale = 1.0 / float(np.sqrt(res[0].shape[-1]))
     if interpret is None:
-        interpret = _infer_interpret(res[0])
+        interpret = cpu_backend()
     dq, dk, dv = _flash_bwd(causal, sm_scale, block_q, block_k, interpret, res, do)
     kv_lens = res[3]
     dlens = None
@@ -821,8 +734,10 @@ flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 #   ``PrefetchScalarGridSpec`` machinery ``kv_lens`` already uses): the
 #   kernel's k/v BlockSpec index maps read the prefetched table to DMA
 #   exactly this slot's pages — no gathered [S, max_kv, H, D] intermediate
-#   ever exists in HBM.  Online softmax across the slot's page walk, fully
-#   masked pages skipped via ``pl.when``.
+#   ever exists in HBM.  A block carries ALL heads of a page (the chip
+#   refuses a one-head block in the second-minor dimension).  Online
+#   softmax across the slot's page walk, fully masked pages skipped via
+#   ``pl.when``.
 #
 # Contract (shared by both engines, tested in test_flash_decode.py):
 # ``kv_lens[s] == 0`` (inactive slot) yields EXACT ZEROS for that slot.
@@ -848,11 +763,18 @@ def _paged_reference(q, k_pool, v_pool, page_tables, kv_lens, sm_scale):
 def _paged_decode_kernel(pt_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
                          m_scr, l_scr, acc_scr, *, page_size, num_pages_per_seq,
                          sm_scale):
+    """One grid step = one slot x one page, ALL heads: the k/v block is the
+    whole page ``[ps, H, Dh]`` and the query block the slot's ``[H, Dh]``,
+    so the last two block dims equal the arrays' own (the only blocking of
+    a one-row-per-head query the TPU lowering accepts).  A single query row
+    per head is a matvec, so scores and p.v run on the VPU as broadcast
+    multiplies + reductions; scores stay ``[ps, H, 1]`` (heads on sublanes)
+    so nothing is ever relaid between lanes and sublanes."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
     s_idx = pl.program_id(0)
-    j = pl.program_id(2)  # page walk for this slot (h rides grid dim 1)
+    j = pl.program_id(1)  # page walk for this slot
 
     @pl.when(j == 0)
     def _init():
@@ -869,26 +791,23 @@ def _paged_decode_kernel(pt_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(visible)
     def _body():
-        q = q_ref[0].astype(jnp.float32)        # [1, Dh]
-        k = k_ref[0, :, 0].astype(jnp.float32)  # [ps, Dh]
-        v = v_ref[0, :, 0].astype(jnp.float32)  # [ps, Dh]
-        col = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (page_size, 1), 0)
-        k = jnp.where(col < kvl, k, 0.0)  # 0*garbage tail rows stay finite
-        v = jnp.where(col < kvl, v, 0.0)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
+        q = q_ref[0].astype(jnp.float32)  # [H, Dh]
+        k = k_ref[0].astype(jnp.float32)  # [ps, H, Dh]
+        v = v_ref[0].astype(jnp.float32)
         ok = (j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1)) < kvl
-        s = jnp.where(ok, s, NEG_INF)
+            jnp.int32, (page_size, 1, 1), 0)) < kvl
+        k = jnp.where(ok, k, 0.0)  # 0*garbage tail rows stay finite
+        v = jnp.where(ok, v, 0.0)
+        s = jnp.sum(q[None] * k, axis=-1, keepdims=True) * sm_scale
+        s = jnp.where(ok, s, NEG_INF)               # [ps, H, 1]
 
-        m_prev = m_scr[:, 0:1]                      # [1, 1]
-        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
+        m_prev = m_scr[:, 0:1]                      # [H, 1]
+        m_new = jnp.maximum(m_prev, s.max(axis=0))
+        p = jnp.exp(s - m_new[None])
         alpha = jnp.exp(m_prev - m_new)
         l_scr[:] = jnp.broadcast_to(
-            l_scr[:, 0:1] * alpha + p.sum(axis=1, keepdims=True), l_scr.shape)
-        acc_scr[:, :] = acc_scr[:, :] * alpha + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
+            l_scr[:, 0:1] * alpha + p.sum(axis=0), l_scr.shape)
+        acc_scr[:, :] = acc_scr[:, :] * alpha + jnp.sum(p * v, axis=0)
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
 
     @pl.when(j == num_pages_per_seq - 1)
@@ -912,33 +831,28 @@ def _paged_pallas(q, k_pool, v_pool, page_tables, kv_lens, sm_scale, interpret):
     kernel = functools.partial(
         _paged_decode_kernel, page_size=ps, num_pages_per_seq=mp,
         sm_scale=sm_scale)
+    # the slot's j-th PAGE, straight out of the pool: the block index comes
+    # from the prefetched page table
+    page = pl.BlockSpec((1, ps, H, Dh),
+                        lambda s, j, pt, kl: (pt[s * mp + j], 0, 0, 0))
+    row = pl.BlockSpec((1, H, Dh), lambda s, j, pt, kl: (s, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(S, H, mp),
-        in_specs=[
-            pl.BlockSpec((1, 1, Dh), lambda s, h, j, pt, kl: (s, h, 0)),
-            # the slot's j-th PAGE, straight out of the pool: the block
-            # index comes from the prefetched page table
-            pl.BlockSpec((1, ps, 1, Dh),
-                         lambda s, h, j, pt, kl: (pt[s * mp + j], 0, h, 0)),
-            pl.BlockSpec((1, ps, 1, Dh),
-                         lambda s, h, j, pt, kl: (pt[s * mp + j], 0, h, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, Dh), lambda s, h, j, pt, kl: (s, h, 0)),
-        ],
+        grid=(S, mp),
+        in_specs=[row, page, page],
+        out_specs=[row],
         scratch_shapes=[
-            pltpu.VMEM((1, 128), jnp.float32),  # running max (lane-replicated)
-            pltpu.VMEM((1, 128), jnp.float32),  # running sum
-            pltpu.VMEM((1, Dh), jnp.float32),   # output accumulator
+            pltpu.VMEM((H, 128), jnp.float32),  # running max (lane-replicated)
+            pltpu.VMEM((H, 128), jnp.float32),  # running sum
+            pltpu.VMEM((H, Dh), jnp.float32),   # output accumulator
         ],
     )
     (out,) = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((S, H, Dh), q.dtype)],
-        compiler_params=_tpu_compiler_params(
-            pltpu, dimension_semantics=("parallel", "parallel", "arbitrary"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(pt_flat, lens, q, k_pool, v_pool)
@@ -990,11 +904,18 @@ def _paged_prefill_reference(q, k_pool, v_pool, pages, start, sm_scale):
 
 def _paged_prefill_kernel(pt_ref, start_ref, q_ref, k_ref, v_ref, o_ref,
                           m_scr, l_scr, acc_scr, *, page_size,
-                          num_pages_per_seq, chunk, sm_scale):
+                          num_pages_per_seq, chunk, n_head, head_dim,
+                          sm_scale):
+    """One grid step = one page against the whole chunk, ALL heads.  Heads
+    are folded into the lane dimension (``[C, H*Dh]`` queries, ``[ps,
+    H*Dh]`` pages — free reshapes of the row-major pool), which is what
+    makes the blocks legal on the chip; the head loop runs inside the
+    kernel over static lane slices, one ``[C, Dh] x [Dh, ps]`` matmul
+    each, with per-head online-softmax state in the leading scratch dim."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    j = pl.program_id(1)  # page walk (h rides grid dim 0)
+    j = pl.program_id(0)  # page walk
     start = start_ref[0]
 
     @pl.when(j == 0)
@@ -1010,36 +931,41 @@ def _paged_prefill_kernel(pt_ref, start_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(visible)
     def _body():
-        q = q_ref[0].astype(jnp.float32)        # [C, Dh]
-        k = k_ref[0, :, 0].astype(jnp.float32)  # [ps, Dh]
-        v = v_ref[0, :, 0].astype(jnp.float32)
         kcol = j * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (page_size, 1), 0)
-        # zero key/value rows past the chunk's visibility so stale page
-        # tails can't poison the p·v accumulation (0·garbage stays 0)
-        k = jnp.where(kcol < start + chunk, k, 0.0)
-        v = jnp.where(kcol < start + chunk, v, 0.0)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
         row = jax.lax.broadcasted_iota(jnp.int32, (chunk, page_size), 0)
         col = j * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (chunk, page_size), 1)
         ok = col <= start + row  # causal by absolute position
-        s = jnp.where(ok, s, NEG_INF)
+        for h in range(n_head):
+            lanes = slice(h * head_dim, (h + 1) * head_dim)
+            q = q_ref[:, lanes].astype(jnp.float32)     # [C, Dh]
+            k = k_ref[0, :, lanes].astype(jnp.float32)  # [ps, Dh]
+            v = v_ref[0, :, lanes].astype(jnp.float32)
+            # zero key/value rows past the chunk's visibility so stale page
+            # tails can't poison the p·v accumulation (0·garbage stays 0)
+            k = jnp.where(kcol < start + chunk, k, 0.0)
+            v = jnp.where(kcol < start + chunk, v, 0.0)
+            s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
+            s = jnp.where(ok, s, NEG_INF)
 
-        m_prev = m_scr[:, 0:1]
-        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[:] = jnp.broadcast_to(
-            l_scr[:, 0:1] * alpha + p.sum(axis=1, keepdims=True), l_scr.shape)
-        acc_scr[:, :] = acc_scr[:, :] * alpha + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+            m_prev = m_scr[h, :, 0:1]
+            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_scr[h] = jnp.broadcast_to(
+                l_scr[h, :, 0:1] * alpha + p.sum(axis=1, keepdims=True),
+                l_scr.shape[1:])
+            acc_scr[h] = acc_scr[h] * alpha + jnp.dot(
+                p, v, preferred_element_type=jnp.float32)
+            m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
 
     @pl.when(j == num_pages_per_seq - 1)
     def _finish():
-        denom = jnp.maximum(l_scr[:, 0:1], 1e-30)
-        o_ref[0] = (acc_scr[:, :] / denom).astype(o_ref.dtype)
+        for h in range(n_head):
+            denom = jnp.maximum(l_scr[h, :, 0:1], 1e-30)
+            o_ref[:, h * head_dim:(h + 1) * head_dim] = (
+                acc_scr[h] / denom).astype(o_ref.dtype)
 
 
 def _paged_prefill_pallas(q, k_pool, v_pool, pages, start, sm_scale,
@@ -1049,44 +975,38 @@ def _paged_prefill_pallas(q, k_pool, v_pool, pages, start, sm_scale,
     from jax.experimental.pallas import tpu as pltpu
 
     C, H, Dh = q.shape
-    ps = k_pool.shape[1]
+    P, ps = k_pool.shape[:2]
     mp = pages.shape[0]
-    qh = q.transpose(1, 0, 2)  # [H, C, Dh]
     pt = pages.astype(jnp.int32)
     start_arr = jnp.reshape(jnp.asarray(start, jnp.int32), (1,))
 
     kernel = functools.partial(
         _paged_prefill_kernel, page_size=ps, num_pages_per_seq=mp,
-        chunk=C, sm_scale=sm_scale)
+        chunk=C, n_head=H, head_dim=Dh, sm_scale=sm_scale)
+    page = pl.BlockSpec((1, ps, H * Dh), lambda j, pt, st: (pt[j], 0, 0))
+    rows = pl.BlockSpec((C, H * Dh), lambda j, pt, st: (0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(H, mp),
-        in_specs=[
-            pl.BlockSpec((1, C, Dh), lambda h, j, pt, st: (h, 0, 0)),
-            pl.BlockSpec((1, ps, 1, Dh),
-                         lambda h, j, pt, st: (pt[j], 0, h, 0)),
-            pl.BlockSpec((1, ps, 1, Dh),
-                         lambda h, j, pt, st: (pt[j], 0, h, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, C, Dh), lambda h, j, pt, st: (h, 0, 0)),
-        ],
+        grid=(mp,),
+        in_specs=[rows, page, page],
+        out_specs=[rows],
         scratch_shapes=[
-            pltpu.VMEM((C, 128), jnp.float32),  # running max (lane-replicated)
-            pltpu.VMEM((C, 128), jnp.float32),  # running sum
-            pltpu.VMEM((C, Dh), jnp.float32),   # output accumulator
+            pltpu.VMEM((H, C, 128), jnp.float32),  # running max (lane-replicated)
+            pltpu.VMEM((H, C, 128), jnp.float32),  # running sum
+            pltpu.VMEM((H, C, Dh), jnp.float32),   # output accumulator
         ],
     )
     (out,) = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((H, C, Dh), q.dtype)],
-        compiler_params=_tpu_compiler_params(
-            pltpu, dimension_semantics=("parallel", "arbitrary"),
+        out_shape=[jax.ShapeDtypeStruct((C, H * Dh), q.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
-    )(pt, start_arr, qh, k_pool, v_pool)
-    return out.transpose(1, 0, 2)
+    )(pt, start_arr, q.reshape(C, H * Dh),
+      k_pool.reshape(P, ps, H * Dh), v_pool.reshape(P, ps, H * Dh))
+    return out.reshape(C, H, Dh)
 
 
 def paged_prefill_attention(q, k_pool, v_pool, pages, start, sm_scale=None,
@@ -1113,14 +1033,14 @@ def paged_prefill_attention(q, k_pool, v_pool, pages, start, sm_scale=None,
     if sm_scale is None:
         sm_scale = 1.0 / float(np.sqrt(q.shape[-1]))
     if impl in (None, "auto"):
-        impl = "reference" if _infer_interpret(q) else "pallas"
+        impl = "reference" if cpu_backend() else "pallas"
     if impl == "reference":
         return _paged_prefill_reference(q, k_pool, v_pool, pages, start,
                                         sm_scale)
     if impl != "pallas":
         raise ValueError("impl must be auto|reference|pallas, got %r" % impl)
     if interpret is None:
-        interpret = _infer_interpret(q)
+        interpret = cpu_backend()
     return _paged_prefill_pallas(q, k_pool, v_pool, pages, start, sm_scale,
                                  interpret)
 
@@ -1142,14 +1062,14 @@ def paged_decode_attention(q, k_pool, v_pool, page_tables, kv_lens,
     if sm_scale is None:
         sm_scale = 1.0 / float(np.sqrt(q.shape[-1]))
     if impl in (None, "auto"):
-        impl = "reference" if _infer_interpret(q) else "pallas"
+        impl = "reference" if cpu_backend() else "pallas"
     if impl == "reference":
         return _paged_reference(q, k_pool, v_pool, page_tables, kv_lens,
                                 sm_scale)
     if impl != "pallas":
         raise ValueError("impl must be auto|reference|pallas, got %r" % impl)
     if interpret is None:
-        interpret = _infer_interpret(q)
+        interpret = cpu_backend()
     return _paged_pallas(q, k_pool, v_pool, page_tables, kv_lens, sm_scale,
                          interpret)
 

@@ -62,8 +62,6 @@ def switch_moe(x, gate_w, expert_params, expert_fn, mesh, axis_name="ep",
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from .collective import shard_map_compat
-
     E = int(dict(zip(mesh.axis_names, mesh.devices.shape))[axis_name])
     B = x.shape[0]
     if B % E:
@@ -82,7 +80,8 @@ def switch_moe(x, gate_w, expert_params, expert_fn, mesh, axis_name="ep",
 
     param_specs = jax.tree_util.tree_map(lambda _: P(axis_name), expert_params)
 
-    @shard_map_compat(
+    @functools.partial(
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(axis_name), P(), param_specs),
         out_specs=P(axis_name),

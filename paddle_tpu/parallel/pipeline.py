@@ -77,8 +77,6 @@ def pipeline_apply_circular(stage_fn, stacked_params, x, mesh, n_microbatches,
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from .collective import shard_map_compat
-
     S = int(dict(zip(mesh.axis_names, mesh.devices.shape))[axis_name])
     R = int(repeats)
     L = S * R
@@ -109,7 +107,8 @@ def pipeline_apply_circular(stage_fn, stacked_params, x, mesh, n_microbatches,
     param_specs = jax.tree_util.tree_map(lambda _: P(axis_name), stacked_params)
     side_specs = jax.tree_util.tree_map(lambda _: P(), sides)
 
-    @shard_map_compat(
+    @functools.partial(
+        jax.shard_map,
         mesh=mesh,
         in_specs=(param_specs, P(), side_specs),
         out_specs=P(),
@@ -177,8 +176,6 @@ def pipeline_apply(stage_fn, stacked_params, x, mesh, n_microbatches,
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from .collective import shard_map_compat
-
     S = int(dict(zip(mesh.axis_names, mesh.devices.shape))[axis_name])
     B = x.shape[0]
     if B % n_microbatches:
@@ -197,7 +194,8 @@ def pipeline_apply(stage_fn, stacked_params, x, mesh, n_microbatches,
     param_specs = jax.tree_util.tree_map(lambda _: P(axis_name), stacked_params)
     side_specs = jax.tree_util.tree_map(lambda _: P(), sides)
 
-    @shard_map_compat(
+    @functools.partial(
+        jax.shard_map,
         mesh=mesh,
         in_specs=(param_specs, P(), side_specs),
         out_specs=P(),

@@ -61,11 +61,10 @@ def ulysses_attention_sharded(q, k, v, mesh, axis_name="sp", causal=False, sm_sc
     sharded over ``axis_name`` of ``mesh``."""
     from jax.sharding import PartitionSpec as P
 
-    from .collective import shard_map_compat
-
     spec = P(None, None, axis_name, None)
 
-    @shard_map_compat(
+    @functools.partial(
+        jax.shard_map,
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False
     )
     def _run(qs, ks, vs):

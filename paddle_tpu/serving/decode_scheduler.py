@@ -83,6 +83,7 @@ import numpy as np
 
 from .. import observability as _obs
 from .. import resilience as _resilience
+from ..core import cpu_backend
 from ..executor import JitStepCache
 from .errors import (
     KVCorruption,
@@ -512,8 +513,6 @@ class DecodeScheduler:
                  gate=None, name=None, evict_on_death=False, breaker=None,
                  sessions=None, replica_index=0, role="both",
                  on_handoff=None, claim=None):
-        import jax
-
         self.model = model
         cfg = self.config = config or DecodeConfig()
         self._use_chunks = model.prefill_chunk_fn is not None
@@ -595,7 +594,7 @@ class DecodeScheduler:
         self._telemetry = _obs.get_telemetry()
         # pool donation saves an HBM copy per step on chip; CPU jax has no
         # donation and would warn every dispatch
-        donate = (2, 3) if jax.default_backend() == "tpu" else ()
+        donate = () if cpu_backend() else (2, 3)
         self._donated = bool(donate)
         # the prefill leg is replayable (its pool inputs survive a failed
         # attempt — KV writes are functional), so transient dispatch
@@ -1450,7 +1449,7 @@ class DecodeScheduler:
             # worker killed mid-chunk.  Solo mode: fail the sequence and
             # release its reservation before the death propagates —
             # ServingDegraded (not ServingError): the engine is sick,
-            # the request was fine, same taxonomy as the batcher death.
+            # the request was fine, same error class as the batcher death.
             # Pool mode (evict_on_death): leave the slot INTACT — the
             # chunk's functional writes never landed, so the slot state
             # is consistent, and the pool harvests it via

@@ -1,4 +1,4 @@
-"""Serving error taxonomy.
+"""Serving error classes.
 
 Every failure a client of :class:`~paddle_tpu.serving.InferenceEngine`
 can see maps to one of these, so callers distinguish "shed this request"

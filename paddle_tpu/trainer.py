@@ -409,9 +409,9 @@ class Trainer:
         checkpoint at startup (torn/corrupt serials are skipped), so the
         continued run is bitwise-identical to one that never crashed.
         ``resume=False`` starts fresh even when checkpoints exist."""
-        from .core import TPUPlace
+        from .core import default_place
 
-        self.place = place if place is not None else TPUPlace()
+        self.place = place if place is not None else default_place()
         self.parallel = parallel
         self.use_program_cache = bool(use_program_cache)
         self.checkpoint_cfg = checkpoint_config
@@ -784,9 +784,9 @@ class Inferencer:
     (reference: contrib/inferencer.py)."""
 
     def __init__(self, infer_func, param_path, place=None, parallel=False):
-        from .core import TPUPlace
+        from .core import default_place
 
-        self.place = place if place is not None else TPUPlace()
+        self.place = place if place is not None else default_place()
         self.scope = Scope()
         self.startup_program = Program()
         self.inference_program = Program()

@@ -69,7 +69,7 @@ def _train(async_feed, mesh=False, steps=6):
     exe = fluid.Executor()
     if mesh:
         exe.attach_mesh(True)
-    feeder = fluid.DataFeeder(feed_list=["x", "y"], place=fluid.TPUPlace(),
+    feeder = fluid.DataFeeder(feed_list=["x", "y"], place=exe.place,
                               program=main)
     reader = sample_batches(steps)
     with fluid.scope_guard(scope):
@@ -112,7 +112,7 @@ def test_on_device_feeds_zero_host_copies():
     main, startup, loss = build_model()
     scope = fluid.Scope()
     exe = fluid.Executor()
-    feeder = fluid.DataFeeder(feed_list=["x", "y"], place=fluid.TPUPlace(),
+    feeder = fluid.DataFeeder(feed_list=["x", "y"], place=exe.place,
                               program=main)
     batch = next(iter(sample_batches(1)()))
     with fluid.scope_guard(scope):
@@ -145,7 +145,7 @@ def test_prefetcher_transfers_each_batch_once():
     main, startup, loss = build_model()
     scope = fluid.Scope()
     exe = fluid.Executor()
-    feeder = fluid.DataFeeder(feed_list=["x", "y"], place=fluid.TPUPlace(),
+    feeder = fluid.DataFeeder(feed_list=["x", "y"], place=exe.place,
                               program=main)
     with fluid.scope_guard(scope):
         exe.run(startup)
@@ -178,7 +178,7 @@ def test_prefetcher_abandoned_early_leaves_no_threads_and_closes_reader():
     main, startup, loss = build_model()
     scope = fluid.Scope()
     exe = fluid.Executor()
-    feeder = fluid.DataFeeder(feed_list=["x", "y"], place=fluid.TPUPlace(),
+    feeder = fluid.DataFeeder(feed_list=["x", "y"], place=exe.place,
                               program=main)
     closed = []
     batches = sample_batches(1000, delay=0.001)
@@ -205,7 +205,7 @@ def test_prefetcher_break_out_of_for_loop_leaves_no_threads():
     main, startup, loss = build_model()
     scope = fluid.Scope()
     exe = fluid.Executor()
-    feeder = fluid.DataFeeder(feed_list=["x", "y"], place=fluid.TPUPlace(),
+    feeder = fluid.DataFeeder(feed_list=["x", "y"], place=exe.place,
                               program=main)
     with fluid.scope_guard(scope):
         exe.run(startup)
@@ -245,7 +245,7 @@ def test_prefetcher_propagates_reader_error():
     main, startup, _loss = build_model()
     scope = fluid.Scope()
     exe = fluid.Executor()
-    feeder = fluid.DataFeeder(feed_list=["x", "y"], place=fluid.TPUPlace(),
+    feeder = fluid.DataFeeder(feed_list=["x", "y"], place=exe.place,
                               program=main)
     good = sample_batches(2)
 
@@ -269,7 +269,7 @@ def test_prefetcher_propagates_conversion_error():
     np.random.seed(5)
     main, startup, _loss = build_model()
     exe = fluid.Executor()
-    feeder = fluid.DataFeeder(feed_list=["x", "y"], place=fluid.TPUPlace(),
+    feeder = fluid.DataFeeder(feed_list=["x", "y"], place=exe.place,
                               program=main)
 
     def bad_batches():
@@ -292,7 +292,7 @@ def test_slow_reader_overlaps_compute():
     main, startup, loss = build_model()
     scope = fluid.Scope()
     exe = fluid.Executor()
-    feeder = fluid.DataFeeder(feed_list=["x", "y"], place=fluid.TPUPlace(),
+    feeder = fluid.DataFeeder(feed_list=["x", "y"], place=exe.place,
                               program=main)
     n, delay, work = 10, 0.02, 0.015
     with fluid.scope_guard(scope):
@@ -427,3 +427,54 @@ def test_prefetcher_casts_to_declared_dtype_off_critical_path():
     dev = device_prefetch.put_feed_on_device(feed, exe, main)
     assert str(dev["x"].dtype) == "float32"
     assert str(dev["y"].dtype) == "float32"
+
+
+def test_host_and_prefetched_feeds_share_one_executable():
+    """jax keys executables on whether each argument is committed: a step
+    fed from the host and then from the prefetcher (and state fresh from
+    startup vs already stepped) used to compile up to four times underneath
+    ONE executor entry.  The step's placement is explicit now, so int64
+    labels from numpy, int32 device arrays from the prefetcher and either
+    kind of state are the same executable."""
+    import jax.monitoring
+
+    backend_compiles = []
+
+    def on_duration(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            backend_compiles.append(duration)
+
+    main = fluid.Program()
+    startup = fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        x = fluid.layers.data(name="x", shape=[WIDTH], dtype="float32")
+        y = fluid.layers.data(name="y", shape=[1], dtype="int64")
+        logits = fluid.layers.fc(x, size=4)
+        loss = fluid.layers.mean(
+            fluid.layers.softmax_with_cross_entropy(logits, y))
+        fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
+    rng = np.random.RandomState(0)
+    rows = [(rng.randn(WIDTH).astype(np.float32),
+             rng.randint(0, 4, size=1).astype(np.int64)) for _ in range(BATCH)]
+    host_feed = {"x": np.stack([r[0] for r in rows]),
+                 "y": np.stack([r[1] for r in rows])}
+    exe = fluid.Executor()
+    feeder = fluid.DataFeeder(feed_list=["x", "y"], place=exe.place,
+                              program=main)
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(startup)
+        exe.run(main, feed=host_feed, fetch_list=[loss])
+        entries = executor_mod.compile_count()
+        jax.monitoring.register_event_duration_secs_listener(on_duration)
+        try:
+            exe.run(main, feed=host_feed, fetch_list=[loss])
+            for feed in device_prefetch.decorate_device_feed(
+                    lambda: iter([rows, rows, rows]), feeder, exe, main,
+                    buffer_size=2)():
+                assert str(feed["y"].dtype) == "int32"  # x64 is off
+                exe.run(main, feed=feed, fetch_list=[loss])
+            np.asarray(exe.run(main, feed=host_feed, fetch_list=[loss])[0])
+        finally:
+            jax.monitoring.unregister_event_duration_listener(on_duration)
+    assert executor_mod.compile_count() == entries
+    assert backend_compiles == []

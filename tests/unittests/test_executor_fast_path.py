@@ -403,3 +403,16 @@ def test_feed_shape_change_falls_back_and_rebinds():
         ref_small = exe2.run(test_prog, feed=small, fetch_list=[loss],
                              use_program_cache=False)
         assert np.asarray(out_small[0]).tobytes() == np.asarray(ref_small[0]).tobytes()
+
+
+def test_bfloat16_fetch_converts_as_bfloat16():
+    """numpy prefers __array_interface__ to __array__, and the interface
+    cannot name an extension dtype: a bf16 LazyFetch used to come back from
+    np.asarray as raw '|V2' bytes (found by ResNet-50 bf16 on the chip)."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.executor import LazyFetch
+
+    got = np.asarray(LazyFetch(jnp.asarray([1.5, -2.0], jnp.bfloat16)))
+    assert got.dtype == jnp.bfloat16
+    assert [float(v) for v in got] == [1.5, -2.0]

@@ -429,35 +429,77 @@ def test_nan_guard_direct_executor_api():
 
 
 # ---------------------------------------------------------------------------
-# compile-cache degradation (PADDLE_TPU_COMPILATION_CACHE_DIR)
+# compile cache: where it goes, and degradation on a bad directory
 # ---------------------------------------------------------------------------
 
 
-def test_compilation_cache_bad_dir_warns_and_continues(tmp_path):
-    from paddle_tpu.executor import enable_compilation_cache
+@pytest.fixture()
+def cache_dir_updates(monkeypatch):
+    """Record (instead of apply) every ``jax_compilation_cache_dir`` update
+    the code under test makes, so no test moves this process's cache."""
+    import jax
+
+    seen = []
+    real = jax.config.update
+
+    def update(name, value):
+        if name == "jax_compilation_cache_dir":
+            seen.append(value)
+        else:
+            real(name, value)
+
+    monkeypatch.setattr(jax.config, "update", update)
+    return seen
+
+
+@pytest.mark.parametrize("how", ["JAX_COMPILATION_CACHE_DIR", "fixed-path"])
+def test_compilation_cache_bad_dir_warns_and_continues(
+        tmp_path, monkeypatch, cache_dir_updates, how):
+    from paddle_tpu import executor as executor_mod
 
     squatter = tmp_path / "cache_squatter"
     squatter.write_text("not a directory")
+    usable = tmp_path / "cache_ok"
+
+    def place(path):
+        if how == "fixed-path":
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            monkeypatch.setattr(executor_mod, "_DEFAULT_COMPILE_CACHE_DIR",
+                                str(path))
+        else:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(path))
+
+    place(squatter)
     with pytest.warns(UserWarning, match="continuing without a compile cache"):
-        assert enable_compilation_cache(str(squatter)) is False
+        assert executor_mod.enable_compilation_cache() is False
+    assert cache_dir_updates == []
     # and a usable dir still enables it
-    assert enable_compilation_cache(str(tmp_path / "cache_ok")) is True
+    place(usable)
+    assert executor_mod.enable_compilation_cache() is True
+    assert cache_dir_updates == (
+        [str(usable)] if how == "fixed-path" else [])
 
 
-def test_executor_setup_tolerates_bad_cache_env(tmp_path, monkeypatch):
+@pytest.mark.parametrize("env_set", [True, False],
+                         ids=["JAX_COMPILATION_CACHE_DIR-set", "unset"])
+def test_compilation_cache_dir_rule(tmp_path, monkeypatch, cache_dir_updates,
+                                    env_set):
+    """Variable set -> jax reads it itself and no directory is set in code;
+    unset -> one fixed path inside the checkout.  Executor setup goes
+    through the same rule and still runs."""
     from paddle_tpu import executor as executor_mod
 
-    squatter = tmp_path / "squat"
-    squatter.write_text("x")
-    monkeypatch.setenv("PADDLE_TPU_COMPILATION_CACHE_DIR", str(squatter))
-    was_checked = executor_mod._compile_cache_checked[0]
-    executor_mod._compile_cache_checked[0] = False
-    try:
-        with pytest.warns(UserWarning,
-                          match="continuing without a compile cache"):
-            exe = fluid.Executor(fluid.CPUPlace())
-    finally:
-        executor_mod._compile_cache_checked[0] = was_checked
+    repo = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    if env_set:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "c"))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setattr(executor_mod, "_compile_cache_checked", [False])
+    exe = fluid.Executor(fluid.CPUPlace())
+    assert executor_mod._compile_cache_checked == [True]
+    assert cache_dir_updates == (
+        [] if env_set else [os.path.join(repo, ".jax_cache")])
     prog = fluid.Program()
     startup = fluid.Program()
     with fluid.unique_name.guard():

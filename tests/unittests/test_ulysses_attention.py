@@ -2,6 +2,7 @@
 forward vs full attention, gradients, and the head-divisibility guard."""
 from __future__ import annotations
 
+import functools
 import numpy as np
 import pytest
 
@@ -34,12 +35,11 @@ def test_ulysses_grads_match():
 
     from jax.sharding import PartitionSpec as P
 
-    from paddle_tpu.parallel.collective import shard_map_compat
 
     spec = P(None, None, "sp", None)
 
     @jax.jit
-    @shard_map_compat(mesh=mesh, in_specs=(spec, spec, spec), out_specs=P(), check_vma=False)
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(spec, spec, spec), out_specs=P(), check_vma=False)
     def loss_ulysses(qs, ks, vs):
         o = ulysses_attention(qs, ks, vs, "sp")
         return jax.lax.psum((o ** 2).sum(), "sp")
