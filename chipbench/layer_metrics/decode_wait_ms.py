@@ -1,0 +1,8 @@
+"""Mean of the cell ``serving.decode.step.wait``: the ``np.asarray`` of the
+sampled tokens, i.e. how long the host is blocked on the decode program.  Over
+the process."""
+from chipbench import cells
+
+
+def read(observed):
+    return cells.mean_ms("serving.decode.step.wait")

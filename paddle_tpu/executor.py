@@ -422,10 +422,19 @@ _compiles = _obs.counter("executor.compile")
 
 
 def compile_count():
-    """Fresh step-executable builds across the process — a view of the
-    ``executor.compile`` telemetry counter.  Replays of cached/bound
-    entries don't count; a nonzero delta across a steady-state serving
-    window means a shape escaped the warmed menu."""
+    """Fresh executor ENTRIES built across the process — a view of the
+    ``executor.compile`` telemetry counter: one per ``Executor`` cache
+    miss (program x feed shapes x fetch list x guard) and one per
+    ``JitStepCache`` key miss.  Replays of cached/bound entries don't
+    count; a nonzero delta across a steady-state serving window means a
+    shape escaped the warmed menu.  It is NOT a count of XLA compiles:
+    jax keys executables underneath an entry (on argument committed-ness
+    and sharding, say), so one entry can compile more than once and a
+    persistent-cache hit compiles nothing.  For those, listen to jax's
+    own events (``jax.monitoring.register_event_listener``:
+    ``/jax/compilation_cache/cache_hits`` and ``.../cache_misses`` are
+    one compile request each), as ``chipbench/run.py`` and
+    ``chip_smoke.py`` do."""
     return _compiles.value
 
 
@@ -1123,197 +1132,189 @@ class Executor:
         unguarded executables are cached separately, with the guard off
         the compiled step has zero extra outputs, and a step that writes
         no state (eval/inference) compiles identically guarded or not —
-        there is no update to skip, so last_step_ok stays None."""
-        program = program or default_main_program()
-        scope = scope or global_scope()
-        feed = feed or {}
-        nan_guard = bool(nan_guard)
+        there is no update to skip, so last_step_ok stays None.
 
-        # step-record gate: one attribute read; when no sink is attached
-        # (or PADDLE_TPU_TELEMETRY=0) the whole telemetry path below is
-        # two cheap boolean checks
-        recording = self._telemetry.recording
-        t_run0 = time.perf_counter() if recording else 0.0
+        The whole call is one span: it closes into the cell
+        ``executor.run`` when a compiled entry was replayed and into
+        ``executor.first_run`` when this call built one (the start-up
+        program and each shape's first step: trace, lower, compile or
+        cache look-up), so compile steps never sit among the replays."""
+        # the body stays in this frame (no wrapper around a ``_run``): one
+        # Python frame more between the caller and a fresh entry's trace
+        # cost the first Transformer-base step 1.5-2 s of tracing and
+        # lowering on the chip's host (PERF.md section 6, PR 24)
+        with self._telemetry.span("executor.run") as whole:
+            program = program or default_main_program()
+            scope = scope or global_scope()
+            feed = feed or {}
+            nan_guard = bool(nan_guard)
 
-        fetch_names = [f.name if isinstance(f, Variable) else str(f) for f in (fetch_list or [])]
+            # step-record gate: one attribute read; when no sink is attached
+            # (or PADDLE_TPU_TELEMETRY=0) the whole telemetry path below is
+            # two cheap boolean checks
+            recording = self._telemetry.recording
+            t_run0 = time.perf_counter() if recording else 0.0
 
-        # fast path: a prior run of this (program, scope, fetch list) bound
-        # the compiled runner to pre-resolved owner scopes and a feed plan;
-        # on a hit the whole per-step re-derivation below is skipped
-        bound_key = None
-        if use_program_cache and self.fast_path:
-            # the key carries each feed's shape so workloads that alternate
-            # among a fixed set of feed shapes — a serving batcher cycling
-            # its bucket ladder — keep one bound entry PER shape instead of
-            # thrashing rebind on every size change; the per-entry plan
-            # still validates dtype/kind before replay.  Sorted so feed
-            # dicts built in different key orders share one entry.
-            bound_key = (id(program), id(scope), tuple(fetch_names),
-                         nan_guard,
-                         tuple(sorted((n, getattr(v, "shape", None))
-                                      for n, v in feed.items())))
-            bound = self._bound.get(bound_key)
-            if type(bound) is _BoundProgram:
-                out = self._run_bound(bound, program, scope, feed,
-                                      return_numpy, recording, t_run0)
-                if out is not _BOUND_MISS:
-                    # LRU touch: keep concurrently hot bindings resident
-                    del self._bound[bound_key]
-                    self._bound[bound_key] = bound
-                    return out
-                # a missed entry is stale; drop it now so it cannot pin
-                # anything until the slow path rebinds (or never, if this
-                # scope is on its way out)
-                self._bound.pop(bound_key, None)
+            fetch_names = [f.name if isinstance(f, Variable) else str(f) for f in (fetch_list or [])]
 
-        # last_step_ok must never report a previous run's verdict: clear
-        # before any slow-path branch (distributed early returns, reader
-        # EOF, a raising entry) can skip the guarded set below
-        self._last_guard_flag = None
+            # fast path: a prior run of this (program, scope, fetch list) bound
+            # the compiled runner to pre-resolved owner scopes and a feed plan;
+            # on a hit the whole per-step re-derivation below is skipped
+            bound_key = None
+            if use_program_cache and self.fast_path:
+                # the key carries each feed's shape so workloads that alternate
+                # among a fixed set of feed shapes — a serving batcher cycling
+                # its bucket ladder — keep one bound entry PER shape instead of
+                # thrashing rebind on every size change; the per-entry plan
+                # still validates dtype/kind before replay.  Sorted so feed
+                # dicts built in different key orders share one entry.
+                bound_key = (id(program), id(scope), tuple(fetch_names),
+                             nan_guard,
+                             tuple(sorted((n, getattr(v, "shape", None))
+                                          for n, v in feed.items())))
+                bound = self._bound.get(bound_key)
+                if type(bound) is _BoundProgram:
+                    out = self._run_bound(bound, program, scope, feed,
+                                          return_numpy, recording, t_run0)
+                    if out is not _BOUND_MISS:
+                        # LRU touch: keep concurrently hot bindings resident
+                        del self._bound[bound_key]
+                        self._bound[bound_key] = bound
+                        return out
+                    # a missed entry is stale; drop it now so it cannot pin
+                    # anything until the slow path rebinds (or never, if this
+                    # scope is on its way out)
+                    self._bound.pop(bound_key, None)
 
-        # started py_reader pipelines feed the step when the caller passes
-        # no feed (the reference's in-graph reader semantics); an exhausted
-        # pipeline raises core.EOFException out of run().  Items are pulled
-        # from EVERY reader before any is consumed so one reader hitting
-        # EOF pushes the others' items back instead of desynchronizing.
-        reader_fed = False
-        if not feed:
-            from .layers.io import program_readers
+            # last_step_ok must never report a previous run's verdict: clear
+            # before any slow-path branch (distributed early returns, reader
+            # EOF, a raising entry) can skip the guarded set below
+            self._last_guard_flag = None
 
-            # every registered reader is consulted: an unstarted one raises
-            # the diagnostic EOF instead of the step failing on missing vars
-            started = program_readers(program)
-            if started:
-                pulled = []
-                try:
-                    for reader in started:
-                        pulled.append((reader, reader.feed_dict()))
-                except Exception:
-                    for reader, item_feed in reversed(pulled):
-                        reader._pushback.appendleft(
-                            tuple(item_feed[n] for n in reader.names))
-                    raise
-                feed = {}
-                for _, item_feed in pulled:
-                    feed.update(item_feed)
-                reader_fed = True
+            # started py_reader pipelines feed the step when the caller passes
+            # no feed (the reference's in-graph reader semantics); an exhausted
+            # pipeline raises core.EOFException out of run().  Items are pulled
+            # from EVERY reader before any is consumed so one reader hitting
+            # EOF pushes the others' items back instead of desynchronizing.
+            reader_fed = False
+            if not feed:
+                from .layers.io import program_readers
 
-        # distributed programs: listen_and_serv blocks serving; send/recv
-        # trainer programs run compute as one XLA step + host-side RPC round
-        op_types = {op.type for op in program.global_block().ops}
-        if "listen_and_serv" in op_types:
-            from .transpiler import pserver_runtime
+                # every registered reader is consulted: an unstarted one raises
+                # the diagnostic EOF instead of the step failing on missing vars
+                started = program_readers(program)
+                if started:
+                    pulled = []
+                    try:
+                        for reader in started:
+                            pulled.append((reader, reader.feed_dict()))
+                    except Exception:
+                        for reader, item_feed in reversed(pulled):
+                            reader._pushback.appendleft(
+                                tuple(item_feed[n] for n in reader.names))
+                        raise
+                    feed = {}
+                    for _, item_feed in pulled:
+                        feed.update(item_feed)
+                    reader_fed = True
 
-            return pserver_runtime.serve(self, program, scope)
-        if "send" in op_types or "recv" in op_types:
-            from .transpiler import pserver_runtime
+            # distributed programs: listen_and_serv blocks serving; send/recv
+            # trainer programs run compute as one XLA step + host-side RPC round
+            op_types = {op.type for op in program.global_block().ops}
+            if "listen_and_serv" in op_types:
+                from .transpiler import pserver_runtime
 
-            clients = self._pserver_clients(program)
-            return pserver_runtime.run_trainer_step(self, program, feed, fetch_list, scope, clients)
+                whole.name = None  # serves until shut down: no step's time
+                return pserver_runtime.serve(self, program, scope)
+            if "send" in op_types or "recv" in op_types:
+                from .transpiler import pserver_runtime
 
-        with self._telemetry.span("executor.prepare_feed"):
-            feed_arrays = self._prepare_feed(program, feed)
-        if resilience._feed_fault is not None:  # fault-injection harness
-            feed_arrays = resilience._feed_fault(feed_arrays)
-        state_in = self._collect_state(program, scope)
-        key = self._rng_key(program, scope)
+                clients = self._pserver_clients(program)
+                return pserver_runtime.run_trainer_step(self, program, feed, fetch_list, scope, clients)
 
-        sig = (
-            program.fingerprint(),
-            tuple(sorted((n, tuple(np.shape(v)), _step_dtype(v.dtype if hasattr(v, "dtype") else np.asarray(v).dtype)) for n, v in feed_arrays.items())),
-            tuple(fetch_names),
-            tuple(sorted(state_in)),
-            _NAN_DEBUG["on"],  # probes are baked into the executable
-            int(getattr(program, "_recompute_segments", 0) or 0),
-            nan_guard,  # guard reductions/gating are baked in too
-        )
-        entry = self._cache.get(sig) if use_program_cache else None
-        call_entry = entry
-        compiled_fresh = False
-        if entry is not None:
-            # LRU touch: re-inserting keeps hot entries at the young end
-            del self._cache[sig]
-            self._cache[sig] = entry
-        if entry is None:
-            compiled_fresh = True
-            _compiles.inc()
-            entry = self._build(program, sorted(feed_arrays), fetch_names,
-                                sorted(state_in), nan_guard=nan_guard)
-            if use_program_cache:
-                while len(self._cache) >= self._cache_cap:
-                    self._cache.pop(next(iter(self._cache)))  # oldest entry
-                    _cache_evicts.inc()
-                self._cache[sig] = entry
-            # first call compiles: retry transient XLA setup failures
-            call_entry = lambda *a: _retry_fresh_entry(entry, *a)  # noqa: E731
+            tel = self._telemetry
+            with tel.span("executor.prepare_feed"):
+                feed_arrays = self._prepare_feed(program, feed)
+            if resilience._feed_fault is not None:  # fault-injection harness
+                feed_arrays = resilience._feed_fault(feed_arrays)
+            with tel.span("executor.bind"):
+                state_in = self._collect_state(program, scope)
+                key = self._rng_key(program, scope)
 
-        execute_s = None
-        xs_active = _xla_stats.active()
-        if _prof.is_profiling():
-            import jax
+                sig = (
+                    program.fingerprint(),
+                    tuple(sorted((n, tuple(np.shape(v)), _step_dtype(v.dtype if hasattr(v, "dtype") else np.asarray(v).dtype)) for n, v in feed_arrays.items())),
+                    tuple(fetch_names),
+                    tuple(sorted(state_in)),
+                    _NAN_DEBUG["on"],  # probes are baked into the executable
+                    int(getattr(program, "_recompute_segments", 0) or 0),
+                    nan_guard,  # guard reductions/gating are baked in too
+                )
+                entry = self._cache.get(sig) if use_program_cache else None
+                call_entry = entry
+                compiled_fresh = False
+                if entry is not None:
+                    # LRU touch: re-inserting keeps hot entries at the young end
+                    del self._cache[sig]
+                    self._cache[sig] = entry
+                if entry is None:
+                    compiled_fresh = True
+                    whole.name = "executor.first_run"
+                    _compiles.inc()
+                    entry = self._build(program, sorted(feed_arrays), fetch_names,
+                                        sorted(state_in), nan_guard=nan_guard)
+                    if use_program_cache:
+                        while len(self._cache) >= self._cache_cap:
+                            self._cache.pop(next(iter(self._cache)))  # oldest entry
+                            _cache_evicts.inc()
+                        self._cache[sig] = entry
+                    # first call compiles: retry transient XLA setup failures
+                    call_entry = lambda *a: _retry_fresh_entry(entry, *a)  # noqa: E731
 
-            t0 = time.perf_counter()
-            fetches, new_state, new_key = call_entry(state_in, feed_arrays, key)
-            jax.block_until_ready(fetches)
-            execute_s = time.perf_counter() - t0
-            _prof.record("executor.run[prog@%x v%d]" % (id(program), program.version), execute_s)
-        elif recording or self._telemetry.span_active() or xs_active:
-            # span-only sinks (a trace with no record sink) must still
-            # see the dispatch/compile spans, not just the other sites';
-            # an armed compute-introspection plane needs the step time for
-            # the MFU / BW-util gauges even with no sink attached
-            t0 = time.perf_counter()
-            with self._telemetry.span(
-                    "executor.compile" if compiled_fresh
-                    else "executor.dispatch"):
+            # a fresh entry's call traces, lowers and compiles: it has a cell
+            # of its own, so compile steps never land in the dispatch cell
+            profiling = _prof.is_profiling()
+            with tel.span("executor.compile" if compiled_fresh
+                          else "executor.dispatch") as call:
                 fetches, new_state, new_key = call_entry(state_in, feed_arrays, key)
-            if xs_active and _xla_stats.sync_timing():
-                import jax
+                if profiling:
+                    # the profiler's report wants the step's device time too
+                    import jax
 
-                jax.block_until_ready(fetches)
-            execute_s = time.perf_counter() - t0
-        else:
-            fetches, new_state, new_key = call_entry(state_in, feed_arrays, key)
-        if xs_active and execute_s is not None:
-            # a step whose wall includes an XLA compile — a fresh entry,
-            # or the step that paid the capture's AOT compile (plane
-            # armed mid-run) — must not land in MFU.  The entry's own
-            # capture cell (not the program tag) supplies the stats, so
-            # shape-distinct entries of one program never cross wires.
-            cap = getattr(entry, "_xla_cap", None)
-            if cap is not None:
-                if cap["fresh"] or compiled_fresh:
-                    cap["fresh"] = False
-                elif cap["stats"] is not None:
-                    _xla_stats.observe_stats(cap["stats"], execute_s)
-        if nan_guard and getattr(entry, "_guard_cell", {}).get("emits"):
-            # the guard verdict rides as an extra trailing pseudo-fetch;
-            # peel it off before anything sees the fetch list (guard off,
-            # or a no-state step: the flag stays None from the reset above)
-            self._last_guard_flag = fetches[-1][0]
-            fetches = fetches[:-1]
-        # write each updated var back to the scope that owns it (param
-        # updates through a child scope must mutate the parent's param,
-        # as in the reference); new names land in the local scope
-        wb_owners = {}
-        for name, val in new_state.items():
-            owner = scope._owner(name) or scope
-            owner.vars[name] = val
-            wb_owners[name] = owner
-        key_owner = scope._owner("__rng_key__") or scope
-        key_owner.vars["__rng_key__"] = new_key
+                    jax.block_until_ready(fetches)
+            execute_s = call.duration
+            if profiling:
+                _prof.record("executor.run[prog@%x v%d]" % (id(program), program.version), execute_s)
+            with tel.span("executor.writeback"):
+                if nan_guard and getattr(entry, "_guard_cell", {}).get("emits"):
+                    # the guard verdict rides as an extra trailing pseudo-fetch;
+                    # peel it off before anything sees the fetch list (guard
+                    # off, or a no-state step: the flag stays None from the
+                    # reset above)
+                    self._last_guard_flag = fetches[-1][0]
+                    fetches = fetches[:-1]
+                # write each updated var back to the scope that owns it (param
+                # updates through a child scope must mutate the parent's param,
+                # as in the reference); new names land in the local scope
+                wb_owners = {}
+                for name, val in new_state.items():
+                    owner = scope._owner(name) or scope
+                    owner.vars[name] = val
+                    wb_owners[name] = owner
+                key_owner = scope._owner("__rng_key__") or scope
+                key_owner.vars["__rng_key__"] = new_key
 
-        if bound_key is not None:
-            self._bind(bound_key, program, scope, feed, feed_arrays,
-                       state_in, new_state, wb_owners, key_owner, entry,
-                       fetch_names, reader_fed, nan_guard)
-        if recording:
-            self._emit_step(program, time.perf_counter() - t_run0,
-                            execute_s, fast_path=False,
-                            compiled=compiled_fresh, nan_guard=nan_guard)
-        # slow path converts eagerly — exactly the pre-fast-path contract
-        return self._finalize_fetches(fetches, return_numpy, lazy=False,
-                                      eager_idx=())
+                if bound_key is not None:
+                    self._bind(bound_key, program, scope, feed, feed_arrays,
+                               state_in, new_state, wb_owners, key_owner, entry,
+                               fetch_names, reader_fed, nan_guard)
+            if recording:
+                self._emit_step(program, time.perf_counter() - t_run0,
+                                execute_s, fast_path=False,
+                                compiled=compiled_fresh, nan_guard=nan_guard)
+            # slow path converts eagerly — exactly the pre-fast-path contract
+            return self._finalize_fetches(fetches, return_numpy, lazy=False,
+                                          eager_idx=())
 
     def last_step_ok(self):
         """After a ``nan_guard=True`` run: the on-device finiteness verdict
@@ -1490,87 +1491,72 @@ class Executor:
                 return _BOUND_MISS
         if _prof.is_profiling():
             return _BOUND_MISS  # keep the slow path's instrumentation
-        plan = bound.feed_plan
-        if len(feed) != len(plan):
-            return _BOUND_MISS
-        feed_arrays = {}
-        for name, val in feed.items():
-            p = plan.get(name)
-            shape = getattr(val, "shape", None)
-            dtype = getattr(val, "dtype", None)
-            if (p is None or shape is None or dtype is None
-                    or tuple(shape) != p[0] or dtype != p[1]
-                    # non-plain feeds (LoDArray whose .shape/.dtype delegate
-                    # to .data, a LazyFetch fed back in, ...) go through the
-                    # slow path's full _prepare_feed, never a blind asarray
-                    or not self._is_plain_array(val)):
+        tel = self._telemetry
+        with tel.span("executor.prepare_feed"):
+            plan = bound.feed_plan
+            if len(feed) != len(plan):
                 return _BOUND_MISS
-            if p[2] is not None:
-                # ndarray: one astype, no asarray round-trip (copy=False
-                # is a no-op here since p[2] != the feed dtype by plan
-                # construction, but keeps an accidental same-dtype plan
-                # from copying); device array: cast stays on device
-                if isinstance(val, (np.ndarray, np.generic)):
-                    val = val.astype(p[2], copy=False)
-                    _feed_copies.inc()
-                else:
-                    val = val.astype(p[2])
-            feed_arrays[name] = val
-        state_in = {}
-        for name, oref in bound.state_owners:
-            owner = oref()
-            if owner is None:
+            feed_arrays = {}
+            for name, val in feed.items():
+                p = plan.get(name)
+                shape = getattr(val, "shape", None)
+                dtype = getattr(val, "dtype", None)
+                if (p is None or shape is None or dtype is None
+                        or tuple(shape) != p[0] or dtype != p[1]
+                        # non-plain feeds (LoDArray whose .shape/.dtype
+                        # delegate to .data, a LazyFetch fed back in, ...)
+                        # go through the slow path's full _prepare_feed,
+                        # never a blind asarray
+                        or not self._is_plain_array(val)):
+                    return _BOUND_MISS
+                if p[2] is not None:
+                    # ndarray: one astype, no asarray round-trip
+                    # (copy=False is a no-op here since p[2] != the feed
+                    # dtype by plan construction, but keeps an accidental
+                    # same-dtype plan from copying); device array: cast
+                    # stays on device
+                    if isinstance(val, (np.ndarray, np.generic)):
+                        val = val.astype(p[2], copy=False)
+                        _feed_copies.inc()
+                    else:
+                        val = val.astype(p[2])
+                feed_arrays[name] = val
+        with tel.span("executor.bind"):
+            state_in = {}
+            for name, oref in bound.state_owners:
+                owner = oref()
+                if owner is None:
+                    return _BOUND_MISS
+                v = owner.vars.get(name)
+                if v is None:
+                    return _BOUND_MISS
+                state_in[name] = v
+            key_owner = bound.key_owner()
+            if key_owner is None:
                 return _BOUND_MISS
-            v = owner.vars.get(name)
-            if v is None:
+            key = key_owner.vars.get("__rng_key__")
+            if key is None:
                 return _BOUND_MISS
-            state_in[name] = v
-        key_owner = bound.key_owner()
-        if key_owner is None:
-            return _BOUND_MISS
-        key = key_owner.vars.get("__rng_key__")
-        if key is None:
-            return _BOUND_MISS
 
         if resilience._feed_fault is not None:  # fault-injection harness
             feed_arrays = resilience._feed_fault(feed_arrays)
         self._last_guard_flag = None  # never report a previous run's verdict
-        execute_s = None
-        xs_active = _xla_stats.active()
-        if recording or self._telemetry.span_active() or xs_active:
-            t0 = time.perf_counter()
-            with self._telemetry.span("executor.dispatch"):
-                fetches, new_state, new_key = bound.entry(
-                    state_in, feed_arrays, key)
-            if xs_active and _xla_stats.sync_timing():
-                import jax
-
-                jax.block_until_ready(fetches)
-            execute_s = time.perf_counter() - t0
-        else:
+        with tel.span("executor.dispatch") as call:
             fetches, new_state, new_key = bound.entry(state_in, feed_arrays, key)
-        if xs_active and execute_s is not None:
-            cap = getattr(bound.entry, "_xla_cap", None)
-            if cap is not None:
-                if cap["fresh"]:
-                    # this step paid the capture's AOT compile (plane
-                    # armed mid-run): its wall is not a step time
-                    cap["fresh"] = False
-                elif cap["stats"] is not None:
-                    _xla_stats.observe_stats(cap["stats"], execute_s)
-        if bound.guard:
-            self._last_guard_flag = fetches[-1][0]
-            fetches = fetches[:-1]
+        with tel.span("executor.writeback"):
+            if bound.guard:
+                self._last_guard_flag = fetches[-1][0]
+                fetches = fetches[:-1]
 
-        wb = bound.wb_owners
-        for name, val in new_state.items():
-            oref = wb.get(name)
-            owner = oref() if oref is not None else None
-            if owner is None:  # defensive: retrace surfaced a new name
-                owner = scope._owner(name) or scope
-                wb[name] = weakref.ref(owner)
-            owner.vars[name] = val
-        key_owner.vars["__rng_key__"] = new_key
+            wb = bound.wb_owners
+            for name, val in new_state.items():
+                oref = wb.get(name)
+                owner = oref() if oref is not None else None
+                if owner is None:  # defensive: retrace surfaced a new name
+                    owner = scope._owner(name) or scope
+                    wb[name] = weakref.ref(owner)
+                owner.vars[name] = val
+            key_owner.vars["__rng_key__"] = new_key
 
         eager = bound.eager_idx
         cell = bound.alias_cell
@@ -1578,7 +1564,7 @@ class Executor:
             eager = eager | cell["idx"]
         if recording:
             self._emit_step(bound.program, time.perf_counter() - t_run0,
-                            execute_s, fast_path=True, compiled=False,
+                            call.duration, fast_path=True, compiled=False,
                             nan_guard=bound.guard)
         return self._finalize_fetches(fetches, return_numpy,
                                       lazy=self.lazy_fetches, eager_idx=eager)
@@ -1707,15 +1693,12 @@ class Executor:
                nan_guard=False):
         import jax
 
-        # compute-introspection capture: one analysis per built ENTRY
-        # (shape-distinct entries of one program each get their own cell,
-        # so MFU never divides one entry's time by another's flops),
+        # compute-introspection capture: one analysis per built ENTRY,
         # registered under the same program tag step records carry;
         # armed/disarmed per call so enabling the plane mid-run captures
-        # on the next step.  "fresh" marks the step that PAID the capture
-        # compile — run()/_run_bound skip observing that step's time.
+        # on the next step
         prog_tag = "%x:v%d" % (id(program), getattr(program, "version", 0))
-        cap_cell = {"done": False, "stats": None, "fresh": False}
+        cap_cell = {"done": False}
 
         persistable_names = program.persistable_names()
         # a fetch that aliases a state output (fetching a param directly, or
@@ -1874,20 +1857,18 @@ class Executor:
                         mut[n] = v
                     else:
                         ro[n] = v
-                if _xla_stats.active() and not cap_cell["done"]:
+                if not cap_cell["done"] and _xla_stats.active():
                     # capture BEFORE the first real call so the gauges are
-                    # live by the time the step's record/observe fires;
-                    # lower+compile is pure (no state/RNG effects), so the
-                    # step itself is bitwise-unaffected
+                    # live by the time the step returns; lower+compile is
+                    # pure (no state/RNG effects), so the step itself is
+                    # bitwise-unaffected
                     cap_cell["done"] = True
-                    cap_cell["fresh"] = True
-                    cap_cell["stats"] = _xla_stats.capture_jitted(
+                    _xla_stats.capture_jitted(
                         prog_tag, jitted, (mut, ro, feeds, key))
                 return jitted(mut, ro, feeds, key)
 
             runner._alias_cell = alias_cell
             runner._guard_cell = guard_cell
-            runner._xla_cap = cap_cell
             return runner
 
         def step(state, feeds, key):
@@ -2042,10 +2023,9 @@ class Executor:
                     conformed[n] = jax.device_put(v, want_sh)
             if conformed is not None:
                 feeds = conformed
-            if _xla_stats.active() and not cap_cell["done"]:
+            if not cap_cell["done"] and _xla_stats.active():
                 cap_cell["done"] = True
-                cap_cell["fresh"] = True
-                cap_cell["stats"] = _xla_stats.capture_jitted(
+                _xla_stats.capture_jitted(
                     prog_tag, cell["jit"], (state, feeds, key),
                     num_devices=int(np.prod(mesh.devices.shape)))
             with warnings.catch_warnings():
@@ -2081,7 +2061,6 @@ class Executor:
 
         runner._alias_cell = alias_cell
         runner._guard_cell = guard_cell
-        runner._xla_cap = cap_cell
         return runner
 
     def close(self):

@@ -102,7 +102,7 @@ def save_vars(executor, dirname, main_program=None, vars=None, predicate=None, f
         vars = list(filter(predicate, main_program.list_vars()))
     scope = global_scope()
     os.makedirs(dirname, exist_ok=True)
-    with _obs.timed("io.save_vars", vars=len(vars)):
+    with _obs.span("io.save_vars", vars=len(vars)):
         if filename is None:
             for v in vars:
                 _write_npy(os.path.join(dirname, v.name + ".npy"), _var_bytes(scope, v.name))

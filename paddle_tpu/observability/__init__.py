@@ -19,26 +19,25 @@ module-level counters:
   retry/rewind totals, checkpoint durations) tagged with program/run
   ids.  Records only flow when telemetry is enabled AND a sink is
   attached; otherwise the per-step cost is one attribute read.
-- trace spans — host-side phases (feed conversion, device_put,
-  dispatch, fetch materialization, checkpoint IO) recorded as
-  begin/duration events per thread, exportable as Chrome
-  ``trace_event`` JSON (:class:`~.sinks.ChromeTraceSink`) that loads in
-  Perfetto next to ``jax.profiler`` device traces — the overlap the
-  async feed pipeline buys is visually verifiable.
+- phases — :func:`span` is the one way to time a host-side phase (the
+  scheduler's iteration, ``Executor.run``'s feed preparation / bind /
+  dispatch / write-back, the prefetcher's conversion and wait,
+  checkpoint IO).  It is always on: every span observes into the
+  histogram cell of its name, and lies in any running ``jax.profiler``
+  trace as ``paddle_tpu.<name>`` on its thread's line, on the device
+  trace's clock.  With a span sink attached the same spans also export
+  as Chrome ``trace_event`` JSON (:class:`~.sinks.ChromeTraceSink`), for
+  hosts with no profiler session.  See docs/observability.md "Phases".
 - pluggable sinks (:mod:`~.sinks`) — JSONL file, in-memory ring buffer
   for tests, periodic stdout summary, Chrome-trace exporter.
-- compute introspection (:mod:`~.xla_stats`, :mod:`~.attribution`) —
-  per-compiled-program XLA cost/memory capture published as
-  ``compute.*`` gauges (flops, bytes accessed, peak HBM, MFU and
-  HBM-BW utilization against a per-device peak table), and
-  :class:`StepAttribution`, a sink that decomposes step wall into
-  input/compute/compile/fetch phases and classifies each window
-  input-bound vs compute-bound.  See docs/observability.md "Compute
-  introspection & MFU".
+- compute introspection (:mod:`~.xla_stats`) — per-compiled-program
+  XLA cost/memory capture published as ``compute.*`` gauges (flops,
+  bytes accessed, peak HBM, roofline verdict against a per-device peak
+  table).  See docs/observability.md "Compute introspection".
 
 ``PADDLE_TPU_TELEMETRY=0`` is the process-wide killswitch: step records,
-spans, and the profiler's implicit stdout report all go quiet; counter
-arithmetic is unaffected.
+the span sinks, and the profiler's implicit stdout report all go quiet;
+counters and phase cells still count.
 
 Usage::
 
@@ -57,7 +56,6 @@ Usage::
 from __future__ import annotations
 
 from . import xla_stats
-from .attribution import PHASE_OF_SPAN, StepAttribution
 from .export import (
     MetricsServer,
     parse_prometheus,
@@ -144,8 +142,6 @@ __all__ = [
     "SLOTarget",
     "SLOAlert",
     "xla_stats",
-    "StepAttribution",
-    "PHASE_OF_SPAN",
 ]
 
 # The step-record schema every future perf/robustness PR reports into.
@@ -176,6 +172,5 @@ STEP_SCHEMA = {
         "checkpoint_save_s",  # duration, present on checkpoint steps
         "checkpoint_load_s",  # duration, present after a rewind/resume
         "metrics",         # fetched scalar metrics when cheaply available
-        "mfu",             # model flops utilization when xla_stats is armed
     ],
 }

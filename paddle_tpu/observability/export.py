@@ -182,8 +182,7 @@ def render_prometheus(telemetry=None, prefix="paddle_tpu_"):
     timers = {key: t for key, t in tel.timers().items() if key not in hists}
     for m, group in sorted(_families(timers, prefix, "_seconds").items()):
         if m in hist_fams:
-            # serving wires a Timer AND a Histogram onto the same name
-            # (e.g. serving.queue_wait); both would render as
+            # a Timer AND a Histogram on the same name would both render as
             # <name>_seconds with conflicting TYPE lines and duplicate
             # _sum/_count samples — a Prometheus parser rejects the
             # whole scrape.  The histogram subsumes the summary (same

@@ -7,10 +7,19 @@ Design constraints (see docs/observability.md for the measured numbers):
   tested contracts — toggling telemetry must never change them.  An
   increment is one lock acquire + int add (~100ns), paid identically on
   and off.
-- **Everything else is gated.**  Spans and step records cost one
-  attribute read when disabled or sink-less: the hot paths check
-  ``telemetry.recording`` / call ``span()`` which returns a shared no-op
-  context manager.  ``PADDLE_TPU_TELEMETRY=0`` forces the quiet path.
+- **Phases always time.**  :meth:`Telemetry.span` is the ONE way to time
+  a phase and extends the counters' contract to durations: every span
+  observes into the histogram cell of its name (one ``perf_counter``
+  pair + one locked increment), sink or no sink, so ``count``/``sum``/
+  ``mean`` of a phase are exact and phase means add up.  It also enters
+  a ``jax.profiler.TraceAnnotation("paddle_tpu.<name>")``, so whenever a
+  ``jax.profiler`` session runs the phase lies on its thread's line of
+  ``/host:CPU`` in the same ``.xplane.pb`` as the device, on one clock;
+  with no session that is one static TraceMe check (~0.02us).
+- **Records and sink fan-out are gated.**  Step records and the span
+  sinks cost one attribute read when disabled or sink-less
+  (``telemetry.recording`` / ``_span_sinks``).
+  ``PADDLE_TPU_TELEMETRY=0`` forces that quiet path; cells still count.
 - **Thread-safe.**  The async device-feed pipeline publishes counters
   and spans from its transfer thread(s); every mutable structure here is
   lock-protected.  Metric objects are created once and mutated in place,
@@ -205,40 +214,72 @@ class Timer:
         return "Timer(%r, n=%d)" % (self.name, self._count)
 
 
-class _NullContext:
-    """Shared no-op context manager: the disabled span path allocates
-    nothing."""
+#: prefix of every span's name in a ``jax.profiler`` trace
+TRACE_PREFIX = "paddle_tpu."
 
-    __slots__ = ()
+_annotation = None      # jax.profiler.TraceAnnotation, resolved at first use
 
-    def __enter__(self):
-        return None
 
-    def __exit__(self, *exc):
+class _NoAnnotation:
+    """Stands in for ``TraceAnnotation`` where jax is not importable:
+    never enabled, so never constructed."""
+
+    @staticmethod
+    def is_enabled():
         return False
 
 
-_NULL_CONTEXT = _NullContext()
+def _resolve_annotation():
+    """``jax.profiler.TraceAnnotation``, lazily: the package must import
+    without jax, and cells and sinks work without it."""
+    global _annotation
+    try:
+        from jax.profiler import TraceAnnotation
+    except Exception:
+        TraceAnnotation = _NoAnnotation
+    _annotation = TraceAnnotation
+    return TraceAnnotation
 
 
 class _Span:
-    __slots__ = ("_telemetry", "_name", "_tags", "_t0", "_wall0")
+    """One timed phase (see :meth:`Telemetry.span`).  ``name`` may be
+    reassigned before the block exits: the span then closes into that
+    cell (``executor.run`` -> ``executor.first_run``), or into none when
+    set to None (the extent stays in a running profiler trace under the
+    name it was opened with).  ``duration`` is set on exit."""
+
+    __slots__ = ("name", "tags", "duration", "_telemetry", "_t0", "_ann")
 
     def __init__(self, telemetry, name, tags):
         self._telemetry = telemetry
-        self._name = name
-        self._tags = tags
+        self.name = name
+        self.tags = tags
+        self.duration = None
 
     def __enter__(self):
-        self._wall0 = time.time()
+        # TraceMe's own inactive path, hoisted: with no profiler session
+        # running no annotation is built at all (one static call)
+        cls = _annotation or _resolve_annotation()
+        if cls.is_enabled():
+            ann = self._ann = cls(TRACE_PREFIX + self.name)
+            ann.__enter__()
+        else:
+            self._ann = None
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        dur = time.perf_counter() - self._t0
-        self._telemetry._emit_span(
-            self._name, self._wall0, dur, threading.current_thread(),
-            self._tags)
+        dur = self.duration = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        name = self.name
+        if name is not None:
+            tel = self._telemetry
+            cell = tel._histograms.get(name)
+            (cell or tel.histogram(name)).observe(dur)
+            if tel._span_sinks:
+                tel._emit_span(name, time.time() - dur, dur,
+                               threading.current_thread(), self.tags)
         return False
 
 
@@ -390,45 +431,41 @@ class Telemetry:
                 pass
 
     def span(self, name, **tags):
-        """Context manager recording a (ts, duration, thread) trace span.
-        Returns a shared no-op when no span sink is attached — the
-        disabled path is one tuple truthiness check."""
-        if not self._span_sinks:
-            return _NULL_CONTEXT
+        """Context manager timing one phase — the one primitive.  It
+        ALWAYS observes the duration into the histogram cell ``name``,
+        enters ``jax.profiler.TraceAnnotation("paddle_tpu." + name)`` so
+        a running profiler session shows the phase on this thread's
+        line beside the device, and feeds the span sinks when one is
+        attached (``tags`` ride along; trace ids come from the caller's
+        :class:`~.tracing.TraceContext`).  Nested spans nest: a parent's
+        duration covers its children's."""
         return _Span(self, name, tags)
+
+    #: alias of :meth:`span`, for call sites spelled ``timed(...)``
+    timed = span
 
     def span_active(self):
         return bool(self._span_sinks)
 
     def record_span(self, name, ts, dur, tags=None, thread=None):
         """Emit an already-measured span (``ts`` = wall-clock start
-        seconds, ``dur`` seconds) — for call sites that time themselves
-        and only want the trace event, without a context manager."""
+        seconds, ``dur`` seconds) to the span sinks only — per-request
+        roots and leaves assembled after the fact, which are no phase of
+        a thread and feed no cell."""
         if not self._span_sinks:
             return
         self._emit_span(name, ts, dur,
                         thread or threading.current_thread(), tags or {})
 
-    @contextlib.contextmanager
-    def timed(self, name, **tags):
-        """Time a block onto the ``name`` timer AND (when a trace sink is
-        attached) emit the matching span — the one primitive behind the
-        instrumented IO paths, so timer and span names can't drift."""
-        wall0 = time.time()
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.observe_span(name, wall0, t0, tags)
-
     def observe_span(self, name, wall0, t0, tags=None):
-        """The tail half of :meth:`timed` for hand-timed sites whose
+        """The tail half of :meth:`span` for hand-timed sites whose
         control flow doesn't fit a with-block (multi-exit loops):
-        observe ``perf_counter() - t0`` on the ``name`` timer and emit
-        the span starting at wall-clock ``wall0``.  Returns the
+        observes ``perf_counter() - t0`` into the SAME cell ``name`` and
+        feeds the span sinks a span starting at wall-clock ``wall0``.
+        It cannot annotate a profiler trace after the fact.  Returns the
         duration."""
         dur = time.perf_counter() - t0
-        self.timer(name).observe(dur)
+        self.histogram(name).observe(dur)
         if self._span_sinks:
             self._emit_span(name, wall0, dur,
                             threading.current_thread(), tags or {})
@@ -485,8 +522,7 @@ def record_span(name, ts, dur, tags=None, thread=None):
     _global.record_span(name, ts, dur, tags, thread)
 
 
-def timed(name, **tags):
-    return _global.timed(name, **tags)
+timed = span
 
 
 def observe_span(name, wall0, t0, tags=None):

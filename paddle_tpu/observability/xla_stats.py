@@ -8,24 +8,24 @@ analysis needs — per-executable flop counts and bytes-accessed
 reserve (``memory_analysis()``: argument / output / temp / alias /
 generated-code bytes) — but jax leaves it sitting on the ``Compiled``
 object.  Here it is captured once per compiled step and published as
-``compute.*`` registry gauges, so the ResNet-50 72%-BW-util / >=20%-MFU
-chase (ROADMAP item 4) reads off the SAME export plane the serving SLO
-dashboards already scrape:
+static ``compute.*`` registry gauges on the SAME export plane the
+serving SLO dashboards already scrape: ``compute.flops_per_step``,
+``compute.bytes_per_step`` (bytes accessed), ``compute.peak_hbm_bytes``
+(argument+output+temp), ``compute.arg_bytes``, ``compute.temp_bytes``,
+``compute.output_bytes``, ``compute.arith_intensity`` (flops/byte) and
+``compute.roofline_compute_bound`` (1.0 when the program's intensity
+exceeds the device's machine balance, else 0.0 — the roofline verdict).
 
-- static, at capture: ``compute.flops_per_step``,
-  ``compute.bytes_per_step`` (bytes accessed), ``compute.peak_hbm_bytes``
-  (argument+output+temp), ``compute.arg_bytes``, ``compute.temp_bytes``,
-  ``compute.output_bytes``, ``compute.arith_intensity`` (flops/byte) and
-  ``compute.roofline_compute_bound`` (1.0 when the program's intensity
-  exceeds the device's machine balance, else 0.0 — the roofline verdict).
-- dynamic, per observed step: ``compute.step_time_s``, ``compute.mfu``
-  (flops / step_time / peak_flops) and ``compute.bw_util``
-  (bytes_accessed / step_time / peak_membw), both against the per-device
-  peak table below scaled by the executable's device count.
+**No step time here.**  A utilization needs the device's busy time,
+which a host clock around an async dispatch does not give: MFU comes
+from ``chipbench/layer_metrics/mfu_pct.py`` (model FLOPs counted from
+shapes over the traced busy time), and host phases from the always-on
+span cells (docs/observability.md "Phases").
 
 **Cost model of the capture itself.**  The plane is OFF by default
-(``PADDLE_TPU_XLA_STATS=1`` or :func:`enable` arms it); disabled, the
-executor pays one module-flag read per step.  Enabled, capture costs one
+(``PADDLE_TPU_XLA_STATS=1`` or :func:`enable` arms it); disabled, an
+entry's runner pays one flag read per step and ``Executor.run`` itself
+nothing.  Enabled, capture costs one
 extra lowering+compile per (program, shapes) entry through the AOT path
 — jax exposes no public handle to the executable its C++ jit path built,
 so the introspection compile is a second one.  With the persistent
@@ -35,15 +35,10 @@ step.  Capture never touches program state or RNG (lower+compile is
 pure), so training is bitwise-identical with the plane on or off —
 tested in test_xla_stats.py.
 
-**Honesty notes.**  Step time is host-observed wall time around the
-dispatch; under async device dispatch that under-reports device busyness,
-so :func:`enable` takes ``sync_timing=True`` (or
-``PADDLE_TPU_XLA_STATS_BLOCK=1``) to block on the fetches inside the
-timing window when accuracy matters more than overlap.  MFU is computed
-against the PEAK flops of the device kind regardless of the dtype mix
-the program actually issues — the conventional definition; pass explicit
-``peak_flops``/``peak_membw`` to measure against a different roof.
-cost/memory analysis values are exact for the executable XLA built, and
+**Honesty notes.**  The roofline verdict is computed against the PEAK
+of the device kind regardless of the dtype mix the program actually
+issues; pass explicit ``peak_flops``/``peak_membw`` to :func:`enable` to
+measure against a different roof.  Cost/memory analysis values are exact for the executable XLA built, and
 deterministic for a fixed (program, shapes, jax/XLA version) — which is
 what makes them usable as drift-gate invariants (tools/check_perf_drift.py).
 """
@@ -59,7 +54,6 @@ __all__ = [
     "enable",
     "disable",
     "active",
-    "sync_timing",
     "configure_peaks",
     "restore_defaults",
     "device_peaks",
@@ -67,11 +61,8 @@ __all__ = [
     "capture_compiled",
     "capture_jitted",
     "extract_compiled",
-    "observe_step",
-    "observe_stats",
     "program_stats",
     "all_stats",
-    "last_mfu",
     "summary",
     "reset",
     "GAUGES",
@@ -88,9 +79,6 @@ GAUGES = (
     "compute.output_bytes",
     "compute.arith_intensity",
     "compute.roofline_compute_bound",
-    "compute.step_time_s",
-    "compute.mfu",
-    "compute.bw_util",
 )
 
 # -- per-device peak table ----------------------------------------------------
@@ -109,9 +97,9 @@ PEAK_TABLE = (
     ("TPU v5e", 197e12, 819e9),
     ("TPU v5p", 459e12, 2765e9),
     ("TPU v6", 918e12, 1640e9),
-    # named placeholder (not a default for unknown kinds): a roof so
-    # MFU/BW-util stay defined in the hermetic CPU test mesh; tests pin
-    # explicit peaks instead of asserting against these.
+    # named placeholder (not a default for unknown kinds): a roof so the
+    # roofline verdict stays defined in the hermetic CPU test mesh; tests
+    # pin explicit peaks instead of asserting against these.
     ("cpu", 1e11, 5e10),
 )
 
@@ -143,15 +131,12 @@ def device_peaks(device_kind=None):
 
 
 class ProgramStats:
-    """Static cost/memory analysis + running step-time aggregates for one
-    compiled program entry (keyed by the executor's program tag,
-    ``<id-hex>:v<version>``)."""
+    """Static cost/memory analysis of one compiled program entry (keyed
+    by the executor's program tag, ``<id-hex>:v<version>``)."""
 
     __slots__ = ("tag", "flops", "bytes_accessed", "arg_bytes", "out_bytes",
                  "temp_bytes", "alias_bytes", "code_bytes", "peak_hbm_bytes",
-                 "num_devices", "device_kind", "kernel_calls", "collectives",
-                 "steps", "total_time_s",
-                 "last_time_s", "last_mfu", "last_bw_util")
+                 "num_devices", "device_kind", "kernel_calls", "collectives")
 
     def __init__(self, tag, flops, bytes_accessed, arg_bytes, out_bytes,
                  temp_bytes, alias_bytes, code_bytes, num_devices,
@@ -176,11 +161,6 @@ class ProgramStats:
         # cross-device instructions the partitioner put in (all-reduce,
         # all-gather, reduce-scatter, all-to-all, collective-permute)
         self.collectives = int(collectives)
-        self.steps = 0
-        self.total_time_s = 0.0
-        self.last_time_s = None
-        self.last_mfu = None
-        self.last_bw_util = None
 
     @property
     def arith_intensity(self):
@@ -205,27 +185,21 @@ class ProgramStats:
             "kernel_calls": self.kernel_calls,
             "collectives": self.collectives,
             "arith_intensity": self.arith_intensity,
-            "steps": self.steps,
-            "total_time_s": self.total_time_s,
-            "last_time_s": self.last_time_s,
-            "last_mfu": self.last_mfu,
-            "last_bw_util": self.last_bw_util,
         }
 
     def __repr__(self):
-        return ("ProgramStats(%r, flops=%.3g, bytes=%.3g, peak_hbm=%.3g, "
-                "steps=%d)" % (self.tag, self.flops, self.bytes_accessed,
-                               self.peak_hbm_bytes, self.steps))
+        return ("ProgramStats(%r, flops=%.3g, bytes=%.3g, peak_hbm=%.3g)"
+                % (self.tag, self.flops, self.bytes_accessed,
+                   self.peak_hbm_bytes))
 
 
 class _Plane:
-    """Module-wide capture state.  ``active`` is read on the executor's
-    per-step path, so it is a plain attribute (one read when disabled);
-    everything behind it is lock-protected."""
+    """Module-wide capture state.  ``active`` is read by a compiled
+    entry's runner until its one capture is done, so it is a plain
+    attribute; everything behind it is lock-protected."""
 
     def __init__(self):
         self.active = os.environ.get("PADDLE_TPU_XLA_STATS", "0") == "1"
-        self.sync = os.environ.get("PADDLE_TPU_XLA_STATS_BLOCK", "0") == "1"
         self.peak_flops = None     # per-device override (None = table)
         self.peak_membw = None
         self.lock = threading.Lock()
@@ -240,29 +214,19 @@ _capture_errors = _reg.counter("compute.capture_errors")
 
 
 def active():
-    """Whether the plane is armed — the executor's one-read gate."""
+    """Whether the plane is armed."""
     return _plane.active
 
 
-def sync_timing():
-    """Whether step timing should block on the fetches (accuracy over
-    overlap; see module docstring)."""
-    return _plane.sync
-
-
-def enable(peak_flops=None, peak_membw=None, sync_timing=None):
+def enable(peak_flops=None, peak_membw=None):
     """Arm the capture plane.  ``peak_flops``/``peak_membw`` override the
-    per-device peak table for MFU / BW-util (per device; totals scale by
-    the executable's device count).  ``sync_timing=True`` blocks on the
-    step's fetches inside the timing window.  None arguments leave the
-    current setting untouched, and overrides OUTLIVE :func:`disable` —
-    call :func:`restore_defaults` to return to the table/env."""
+    per-device peak table for the roofline verdict.  None arguments leave
+    the current setting untouched, and overrides OUTLIVE :func:`disable`
+    — call :func:`restore_defaults` to return to the table/env."""
     if peak_flops is not None:
         _plane.peak_flops = float(peak_flops)
     if peak_membw is not None:
         _plane.peak_membw = float(peak_membw)
-    if sync_timing is not None:
-        _plane.sync = bool(sync_timing)
     _plane.active = True
 
 
@@ -278,13 +242,11 @@ def configure_peaks(peak_flops=None, peak_membw=None):
 
 
 def restore_defaults():
-    """Clear the peak overrides and re-read the sync-timing env default —
-    ``enable()``'s overrides otherwise persist process-wide (``disable``
-    only disarms), so tools that pin a roof for one report call this on
-    the way out."""
+    """Clear the peak overrides — ``enable()``'s otherwise persist
+    process-wide (``disable`` only disarms), so tools that pin a roof
+    for one report call this on the way out."""
     _plane.peak_flops = None
     _plane.peak_membw = None
-    _plane.sync = os.environ.get("PADDLE_TPU_XLA_STATS_BLOCK", "0") == "1"
 
 
 def _peaks(device_kind):
@@ -400,40 +362,6 @@ def _publish_static(st):
                 1.0 if ai >= balance else 0.0)
 
 
-def observe_step(tag, seconds):
-    """Fold one measured step of ``tag`` into its aggregates and publish
-    the dynamic gauges (``compute.step_time_s`` / ``compute.mfu`` /
-    ``compute.bw_util``).  Unknown tags (entry compiled before the plane
-    was armed, capture failed) are a no-op.  Note the registry keeps the
-    LAST capture per tag; call sites that can hold the exact
-    :class:`ProgramStats` (the executor does, via its per-entry capture
-    cell) should use :func:`observe_stats` instead so shape-distinct
-    entries of one program never cross wires."""
-    with _plane.lock:
-        st = _plane.programs.get(tag)
-    return observe_stats(st, seconds)
-
-
-def observe_stats(st, seconds):
-    """:func:`observe_step` against an explicit :class:`ProgramStats`."""
-    if st is None or seconds <= 0:
-        return None
-    pf, pb = _peaks(st.device_kind)
-    mfu = st.flops / seconds / (pf * st.num_devices) if pf else None
-    bw = st.bytes_accessed / seconds / (pb * st.num_devices) if pb else None
-    st.steps += 1
-    st.total_time_s += seconds
-    st.last_time_s = seconds
-    st.last_mfu = mfu
-    st.last_bw_util = bw
-    _reg.gauge("compute.step_time_s").set(seconds)
-    if mfu is not None:
-        _reg.gauge("compute.mfu").set(mfu)
-    if bw is not None:
-        _reg.gauge("compute.bw_util").set(bw)
-    return mfu
-
-
 def program_stats(tag=None):
     """The :class:`ProgramStats` for ``tag`` (default: the most recently
     captured program), or None."""
@@ -448,28 +376,18 @@ def all_stats():
         return dict(_plane.programs)
 
 
-def last_mfu():
-    """Most recently published MFU (None before any observed step)."""
-    v = _reg.gauge("compute.mfu").value
-    return v if isinstance(v, (int, float)) else None
-
-
 def summary():
     """One formatted table over every captured program — the quick look
     before reaching for tools/perf_report.py."""
     rows = sorted(all_stats().values(), key=lambda s: -s.flops)
-    lines = ["%-22s %12s %12s %12s %10s %8s %8s" % (
-        "Program", "GFLOPs", "MB accessed", "peak HBM MB", "intensity",
-        "steps", "MFU")]
+    lines = ["%-22s %12s %12s %12s %10s" % (
+        "Program", "GFLOPs", "MB accessed", "peak HBM MB", "intensity")]
     for st in rows:
         ai = st.arith_intensity
-        lines.append("%-22s %12.3f %12.3f %12.3f %10s %8d %8s" % (
+        lines.append("%-22s %12.3f %12.3f %12.3f %10s" % (
             st.tag, st.flops / 1e9, st.bytes_accessed / 1e6,
             st.peak_hbm_bytes / 1e6,
-            "%.2f" % ai if ai is not None else "-",
-            st.steps,
-            "%.2f%%" % (100 * st.last_mfu) if st.last_mfu is not None
-            else "-"))
+            "%.2f" % ai if ai is not None else "-"))
     return "\n".join(lines)
 
 

@@ -48,7 +48,6 @@ from __future__ import annotations
 import os
 import queue as _queue
 import threading
-import time
 import weakref
 
 import numpy as np
@@ -72,14 +71,6 @@ __all__ = [
 # counter (its internal lock covers transfer_threads > 1), the same cell
 # executor step records report as ``prefetch_transfers``
 _transfers = _obs.counter("prefetch.transfer")
-
-# input-boundedness signals for the step-attribution plane
-# (observability.attribution): how many ready batches sat in the buffer
-# when the consumer arrived (0 = the step loop is about to starve) and
-# the buffer's capacity to normalize against.  Last-created prefetcher
-# wins the capacity gauge — one live feed pipeline per loop is the norm.
-_occupancy = _obs.gauge("prefetch.buffer_occupancy")
-_capacity = _obs.gauge("prefetch.buffer_capacity")
 
 
 def transfer_count():
@@ -258,7 +249,6 @@ class DevicePrefetcher:
                  transfer_threads=1):
         self._source = source
         self._q = _queue.Queue(maxsize=max(int(buffer_size), 1))
-        _capacity.set(self._q.maxsize)
         self._stop = threading.Event()
         self._live = max(int(transfer_threads), 1)
         self._closed = False
@@ -289,16 +279,11 @@ class DevicePrefetcher:
         if self._closed:
             raise StopIteration
         while True:
-            # consumer-side starvation probe: occupancy BEFORE the get
-            # (0 = the step loop is about to block on input) and the time
-            # actually spent blocked — the "input-bound" half of the
-            # step-attribution verdict.  observe_span feeds the
-            # ``prefetch.wait`` timer always (O(1) aggregate) and emits
-            # the trace span only when a span sink is attached.
-            _occupancy.set(self._q.qsize())
-            wall0, t0 = time.time(), time.perf_counter()
-            item = self._q.get()
-            _obs.observe_span("prefetch.wait", wall0, t0)
+            # the time the step loop actually spent blocked on input:
+            # the ``prefetch.wait`` cell, always on (a near-zero mean is
+            # a pipeline that keeps ahead of the step loop)
+            with _obs.span("prefetch.wait"):
+                item = self._q.get()
             if item is _STOP:
                 self._live -= 1
                 if self._live > 0:

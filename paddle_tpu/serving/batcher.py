@@ -65,7 +65,6 @@ from .worker import RestartableWorker
 __all__ = ["CompletionTracker", "DynamicBatcher"]
 
 _expired = _obs.counter("serving.expired")
-_queue_wait = _obs.timer("serving.queue_wait")
 _queue_wait_hist = _obs.histogram("serving.queue_wait")
 
 
@@ -266,7 +265,6 @@ class DynamicBatcher:
             for r in batch:
                 r.dispatch_ts = now
                 wait = now - r.enqueue_ts
-                _queue_wait.observe(wait)
                 _queue_wait_hist.observe(wait)
                 if spans and r.trace is not None:
                     # the queue-wait leg of the request's trace tree,

@@ -110,16 +110,17 @@ _expired_mid_decode = _obs.counter("serving.decode.expired_mid_decode")
 _queue_full = _obs.counter("serving.decode.queue_full")
 _queue_depth = _obs.gauge("serving.decode.queue_depth")
 _active_slots = _obs.gauge("serving.decode.active_slots")
-_prefill_timer = _obs.timer("serving.decode.prefill_step")
-_decode_timer = _obs.timer("serving.decode.decode_step")
-_queue_wait = _obs.timer("serving.decode.queue_wait")
 # tail-latency histograms (log-bucketed, SLO-grade quantiles): decode
-# queue wait, time-to-first-token (admission -> first sampled token, the
-# interactive-latency number), and per-iteration decode step time (the
-# inter-token-latency distribution)
+# queue wait and time-to-first-token (admission -> first sampled token,
+# the interactive-latency number).  The worker's phases (iteration,
+# admit, chunk, prefill, step, ...) are spans: each observes into the
+# cell of its own name, see docs/observability.md "Phases"
 _queue_wait_hist = _obs.histogram("serving.decode.queue_wait")
 _ttft_hist = _obs.histogram("serving.decode.ttft")
-_step_hist = _obs.histogram("serving.decode.step")
+# one observation per iteration: its duration less the ``*.wait`` spans
+# inside it — host time during which this scheduler has nothing queued
+# on the device
+_iteration_host = _obs.histogram("serving.decode.iteration.host")
 _prefill_retries = _obs.counter("serving.decode.prefill_retries")
 _prefill_tokens = _obs.counter("serving.decode.prefill_tokens")
 _expired_mid_prefill = _obs.counter("serving.decode.expired_mid_prefill")
@@ -619,6 +620,9 @@ class DecodeScheduler:
         self._tables = np.zeros(
             (cfg.num_slots, self._cache.max_pages_per_seq), np.int32)
         self._hol = None               # head-of-line request awaiting pages
+        # seconds this turn of the serve loop spent in ``*.wait`` spans
+        # (blocked on the device): what ``iteration.host`` subtracts
+        self._turn_wait_s = 0.0
         # serializes _hol handoff between the worker (_admit/_fail_all)
         # and a stop() that timed out joining a wedged-but-alive worker
         # — an unsynchronized claim could fail AND decode one request
@@ -746,7 +750,7 @@ class DecodeScheduler:
         import jax.numpy as jnp
 
         cfg = self.config
-        with _obs.timed("serving.decode.warmup", slots=cfg.num_slots):
+        with _obs.span("serving.decode.warmup", slots=cfg.num_slots):
             step = self._jit.get(("decode",))
             toks, k_pool, v_pool = step(
                 jnp.zeros((cfg.num_slots,), jnp.int32),
@@ -1105,31 +1109,86 @@ class DecodeScheduler:
         # admission): retirements per second of BUSY wall time
         self._note_ts = time.perf_counter()
         self._note_retired = self._retired_total
+        tel = self._telemetry
         while True:
-            # queued session-pin releases first: freed pages may be
-            # exactly what this iteration's admission needs
-            self._drain_pending()
-            self._admit()
-            if self._active_count():
-                if self._worker.stopping and not self._drain:
-                    # non-drain stop: fail the actives after the
-                    # in-flight iteration instead of decoding every
-                    # sequence to completion (unbounded shutdown)
-                    self._fail_all(ServingClosed("decode scheduler stopped"))
-                    return
-                self._iterate()
-                self._note_throughput()
-                continue
+            if self._active_count() or self._has_admissible():
+                # one turn: admit, then one iteration over the active
+                # slots.  Its phases are children of this span on the
+                # worker's line of a profiler trace; a turn that could
+                # seat nothing is no iteration and closes into no cell
+                self._turn_wait_s = 0.0
+                with tel.span("serving.decode.iteration") as turn:
+                    with tel.span("serving.decode.admit") as admit:
+                        # queued session-pin releases first: freed pages
+                        # may be exactly what this admission needs
+                        self._drain_pending()
+                        self._admit()
+                        iterated = self._active_count() > 0
+                        if not iterated:
+                            turn.name = admit.name = None
+                    if iterated:
+                        if self._worker.stopping and not self._drain:
+                            # non-drain stop: fail the actives after the
+                            # in-flight iteration instead of decoding
+                            # every sequence to completion (unbounded
+                            # shutdown)
+                            self._fail_all(
+                                ServingClosed("decode scheduler stopped"))
+                            return
+                        self._iterate()
+                        self._note_throughput()
+                if iterated:
+                    _iteration_host.observe(
+                        turn.duration - self._turn_wait_s)
+                    continue
+            else:
+                self._await_request()
+                if self._has_admissible():
+                    continue
             # idle: re-anchor so idle gaps don't dilute the rate
             self._note_ts = time.perf_counter()
             self._note_retired = self._retired_total
             if self._worker.stopping and (not self._drain
                                           or (self._queue.depth() == 0
-                                              and self._hol is None
-                                              and not self._pending_handoffs)):
+                                              and not self._has_admissible())):
                 if not self._drain:
                     self._fail_all(ServingClosed("decode scheduler stopped"))
                 return
+
+    def _has_admissible(self):
+        """A parked head-of-line request or a staged hand-off packet:
+        work ``_admit`` can seat without pulling from the queue."""
+        return self._hol is not None or bool(self._pending_handoffs)
+
+    def _await_request(self):
+        """Nothing to decode and nothing parked: wait up to 50 ms for a
+        request so the loop doesn't spin, and park what arrives head of
+        line for the next turn's ``_admit``.  The wait is the cell
+        ``serving.decode.idle``: it is no part of ``admit`` or of an
+        iteration."""
+        with self._telemetry.span("serving.decode.idle"):
+            self._drain_pending()
+            if self._worker.stopping and not self._drain:
+                return
+            req = self._pull(0.05)
+            if req is not None:
+                self._park_hol(req, [], None)
+
+    def _pull(self, timeout):
+        """Claim the next request off the queue, or None."""
+        # the pool's claim gate (least-loaded dispatch, breaker, replica
+        # quiesce) applies to SHARED-queue pulls only — a parked HOL
+        # request already belongs to this replica (its prefix pages are
+        # pinned here)
+        if self._gate is not None and not self._gate():
+            if timeout:
+                time.sleep(0.002)  # don't spin while gated out
+            return None
+        req = self._queue.get(timeout=timeout, accept=self._claim)
+        if (req is not None
+                and getattr(req, "affinity", None) == self._replica_index):
+            _affinity_honored.inc()
+        return req
 
     def _note_throughput(self):
         """Feed retired-sequences-per-second into the queue's EMA so
@@ -1240,8 +1299,7 @@ class DecodeScheduler:
 
     def _admit(self):
         """Fill free slots from the queue (iteration-level admission).
-        Never blocks while sequences are decoding; waits briefly when
-        idle so the loop doesn't spin."""
+        Never blocks: the idle wait is ``_await_request``'s."""
         cache, cfg = self._cache, self.config
         if not self._admit_handoffs():
             return                 # blocked on pages for a staged packet
@@ -1252,22 +1310,8 @@ class DecodeScheduler:
             if hol is not None:
                 req, cached_pages, hashes = hol
             else:
-                # the pool's claim gate (least-loaded dispatch, breaker,
-                # replica quiesce) applies to SHARED-queue pulls only —
-                # a parked HOL request already belongs to this replica
-                # (its prefix pages are pinned here)
-                if self._gate is not None and not self._gate():
-                    if not self._active_count():
-                        time.sleep(0.002)  # don't spin while gated out
-                    return
-                req = self._queue.get(
-                    timeout=0.0 if self._active_count() else 0.05,
-                    accept=self._claim)
+                req = self._pull(0.0)
                 cached_pages, hashes = [], None
-                if (req is not None
-                        and getattr(req, "affinity", None)
-                        == self._replica_index):
-                    _affinity_honored.inc()
             if req is None:
                 return
             if req.cancelled:
@@ -1330,7 +1374,6 @@ class DecodeScheduler:
         idx = next(i for i, s in enumerate(self._slots) if s is None)
         now = time.perf_counter()
         wait = now - req.enqueue_ts
-        _queue_wait.observe(wait)
         _queue_wait_hist.observe(wait)
         req.dispatch_ts = now
         tel = self._telemetry
@@ -1395,27 +1438,28 @@ class DecodeScheduler:
         import jax.numpy as jnp
 
         cfg = self.config
-        slot = self._slots[idx]
-        req = slot.req
-        start = slot.prefill_pos
-        remaining = req.prompt_len - start
-        width = self._chunk_width_for(remaining)
-        valid = min(remaining, width)
-        ps = cfg.page_size
-        tokens = np.zeros((width,), np.int32)
-        tokens[:valid] = req.prompt[start:start + valid]
-        # pages this chunk writes: the prompt's pages covering
-        # [start, start + width); window tail past the prompt's pages
-        # scatters to scratch, exactly like the monolithic pad tail
-        n_prompt_pages = self._cache.pages_for(req.prompt_len)
-        p0 = start // ps
-        chunk_vec = np.zeros((width // ps,), np.int32)
-        for i in range(width // ps):
-            if p0 + i < n_prompt_pages:
-                chunk_vec[i] = slot.pages[p0 + i]
-        fn = self._jit.get(("chunk", width))
-        temp, seed = self._sampling_params(req)
-        t0 = time.perf_counter()
+        tel = self._telemetry
+        with tel.span("serving.decode.chunk.build"):
+            slot = self._slots[idx]
+            req = slot.req
+            start = slot.prefill_pos
+            remaining = req.prompt_len - start
+            width = self._chunk_width_for(remaining)
+            valid = min(remaining, width)
+            ps = cfg.page_size
+            tokens = np.zeros((width,), np.int32)
+            tokens[:valid] = req.prompt[start:start + valid]
+            # pages this chunk writes: the prompt's pages covering
+            # [start, start + width); window tail past the prompt's pages
+            # scatters to scratch, exactly like the monolithic pad tail
+            n_prompt_pages = self._cache.pages_for(req.prompt_len)
+            p0 = start // ps
+            chunk_vec = np.zeros((width // ps,), np.int32)
+            for i in range(width // ps):
+                if p0 + i < n_prompt_pages:
+                    chunk_vec[i] = slot.pages[p0 + i]
+            fn = self._jit.get(("chunk", width))
+            temp, seed = self._sampling_params(req)
 
         def attempt():
             # the chaos choke point is consulted per ATTEMPT (a retry is
@@ -1423,22 +1467,26 @@ class DecodeScheduler:
             serve_fault = _resilience._serve_fault
             if serve_fault is not None:
                 serve_fault([req])
-            with self._telemetry.timed("serving.decode.prefill",
-                                       bucket=width, rows=valid,
-                                       start=start, seq=req.seq):
+            with tel.span("serving.decode.prefill.dispatch"):
                 tok, kp, vp = fn(
                     jnp.asarray(tokens), jnp.int32(start),
                     jnp.int32(valid),
                     self._cache.k_pool, self._cache.v_pool,
                     jnp.asarray(chunk_vec),
                     jnp.asarray(self._tables[idx]), seed, temp)
-                return int(np.asarray(tok)), kp, vp
+            with tel.span("serving.decode.prefill.wait") as wait:
+                first = int(np.asarray(tok))
+            self._turn_wait_s += wait.duration
+            return first, kp, vp
 
         try:
             chunk_wall = time.time()
-            first, k_pool, v_pool = _resilience.call_with_retry(
-                attempt, policy=self._prefill_policy,
-                on_retry=self._note_prefill_retry(req))
+            # the chunk program, dispatch to readback (retries included)
+            with tel.span("serving.decode.prefill", bucket=width,
+                          rows=valid, start=start, seq=req.seq) as prefill:
+                first, k_pool, v_pool = _resilience.call_with_retry(
+                    attempt, policy=self._prefill_policy,
+                    on_retry=self._note_prefill_retry(req))
         except Exception as exc:  # noqa: BLE001 — worker must survive
             self._retire(idx, error=exc)
             self._recover_pools(exc)
@@ -1458,44 +1506,45 @@ class DecodeScheduler:
                 self._retire(idx, error=ServingDegraded(
                     "decode worker died mid-prefill; request aborted"))
             raise
-        done = time.perf_counter()
-        _prefill_timer.observe(done - t0)
-        tel = self._telemetry
-        if tel.span_active() and req.trace is not None:
-            tel.record_span(
-                "serving.execute", chunk_wall, done - t0,
-                tags=req.trace.child().tags(phase="prefill", bucket=width,
-                                            rows=valid, start=start))
-        self._cache.k_pool, self._cache.v_pool = k_pool, v_pool
-        if self._breaker is not None:
-            self._breaker.record_success()
-        if self.config.kv_guard and self._guard_pages(
-                [idx] * len(chunk_vec), chunk_vec, phase="prefill"):
-            return
-        slot.prefill_pos = start + valid
-        slot.kv_len = slot.prefill_pos
-        _prefills.inc()
-        _prefill_tokens.inc(valid)
-        if cfg.prefix_cache and slot.hashes:
-            # publish every full REAL page this chunk completed: its
-            # content is now immutable (decode appends only past the
-            # prompt), so later identical prefixes can map it read-only
-            for pi in range(p0, (start + valid) // ps):
-                if pi < len(slot.hashes):
-                    self._cache.register_prefix(slot.hashes, pi,
-                                                slot.pages[pi])
-        if slot.prefill_pos >= req.prompt_len:
-            # final chunk: the sampled token at position prompt_len - 1
-            # is the sequence's first generated token
-            slot.generated.append(first)
-            req.journal.accepted.append(first)
-            req.token_times.append(time.perf_counter())
-            # TTFT: admission -> first sampled token, the number an
-            # interactive-decode SLO is written against
-            _ttft_hist.observe(done - req.enqueue_ts)
-            _tokens.inc()
-            if not self._finish_if_done(idx):
-                self._maybe_handoff(idx)
+        with tel.span("serving.decode.chunk.commit"):
+            done = time.perf_counter()
+            if tel.span_active() and req.trace is not None:
+                tel.record_span(
+                    "serving.execute", chunk_wall, prefill.duration,
+                    tags=req.trace.child().tags(
+                        phase="prefill", bucket=width, rows=valid,
+                        start=start))
+            self._cache.k_pool, self._cache.v_pool = k_pool, v_pool
+            if self._breaker is not None:
+                self._breaker.record_success()
+            if self.config.kv_guard and self._guard_pages(
+                    [idx] * len(chunk_vec), chunk_vec, phase="prefill"):
+                return
+            slot.prefill_pos = start + valid
+            slot.kv_len = slot.prefill_pos
+            _prefills.inc()
+            _prefill_tokens.inc(valid)
+            if cfg.prefix_cache and slot.hashes:
+                # publish every full REAL page this chunk completed: its
+                # content is now immutable (decode appends only past the
+                # prompt), so later identical prefixes can map it
+                # read-only
+                for pi in range(p0, (start + valid) // ps):
+                    if pi < len(slot.hashes):
+                        self._cache.register_prefix(slot.hashes, pi,
+                                                    slot.pages[pi])
+            if slot.prefill_pos >= req.prompt_len:
+                # final chunk: the sampled token at position
+                # prompt_len - 1 is the sequence's first generated token
+                slot.generated.append(first)
+                req.journal.accepted.append(first)
+                req.token_times.append(time.perf_counter())
+                # TTFT: admission -> first sampled token, the number an
+                # interactive-decode SLO is written against
+                _ttft_hist.observe(done - req.enqueue_ts)
+                _tokens.inc()
+                if not self._finish_if_done(idx):
+                    self._maybe_handoff(idx)
 
     def _maybe_handoff(self, idx):
         """Roles mode, prefill side: a freshly prefilled (and not yet
@@ -1568,7 +1617,6 @@ class DecodeScheduler:
         fn = self._jit.get(("prefill", bucket))
         now = time.perf_counter()
         wait = now - req.enqueue_ts
-        _queue_wait.observe(wait)
         _queue_wait_hist.observe(wait)
         req.dispatch_ts = now
         tel = self._telemetry
@@ -1585,20 +1633,25 @@ class DecodeScheduler:
             serve_fault = _resilience._serve_fault
             if serve_fault is not None:
                 serve_fault([req])
-            with self._telemetry.timed("serving.decode.prefill",
-                                       bucket=bucket, rows=req.prompt_len,
-                                       seq=req.seq):
+            with tel.span("serving.decode.prefill.dispatch"):
                 tok, kp, vp = fn(
                     jnp.asarray(tokens), jnp.int32(req.prompt_len),
                     self._cache.k_pool, self._cache.v_pool,
                     jnp.asarray(page_vec), seed, temp)
-                return int(np.asarray(tok)), kp, vp
+            with tel.span("serving.decode.prefill.wait") as wait:
+                first = int(np.asarray(tok))
+            self._turn_wait_s += wait.duration
+            return first, kp, vp
 
         try:
             prefill_wall = time.time()
-            first, k_pool, v_pool = _resilience.call_with_retry(
-                attempt, policy=self._prefill_policy,
-                on_retry=self._note_prefill_retry(req))
+            # the whole-prompt program, dispatch to readback (retries
+            # included); it runs inside ``serving.decode.admit``
+            with tel.span("serving.decode.prefill", bucket=bucket,
+                          rows=req.prompt_len, seq=req.seq) as prefill:
+                first, k_pool, v_pool = _resilience.call_with_retry(
+                    attempt, policy=self._prefill_policy,
+                    on_retry=self._note_prefill_retry(req))
         except Exception as exc:  # noqa: BLE001 — worker must survive
             self._cache.free(pages)
             self._completed += 1
@@ -1624,13 +1677,12 @@ class DecodeScheduler:
                     "decode worker died mid-prefill; request aborted"))
             raise
         done = time.perf_counter()
-        _prefill_timer.observe(done - now)
         # TTFT: admission -> first sampled token, the number an
         # interactive-decode SLO is written against
         _ttft_hist.observe(done - req.enqueue_ts)
         if tel.span_active() and req.trace is not None:
             tel.record_span(
-                "serving.execute", prefill_wall, done - now,
+                "serving.execute", prefill_wall, prefill.duration,
                 tags=req.trace.child().tags(phase="prefill", bucket=bucket,
                                             rows=req.prompt_len))
         self._cache.k_pool, self._cache.v_pool = k_pool, v_pool
@@ -1755,42 +1807,44 @@ class DecodeScheduler:
         import jax.numpy as jnp
 
         cfg = self.config
-        # shed actives whose deadline passed before burning a step on
-        # them — checked BETWEEN chunks too, so a doomed long prompt
-        # frees its budget early instead of prefilling to completion
-        now0 = time.perf_counter()
-        # cancellation reaps at the iteration boundary: the slot retires
-        # and its pages free before the next step dispatches, so an
-        # abandoned future stops burning decode capacity immediately
-        for i, slot in enumerate(self._slots):
-            if slot is not None and slot.req.cancelled:
-                _cancelled.inc()
-                self._retire(i, error=ServingCancelled(
-                    "request cancelled after %d/%d generated tokens"
-                    % (len(slot.generated), slot.req.max_new_tokens)))
-        for i, slot in enumerate(self._slots):
-            if slot is not None and slot.req.expired(now0):
-                req = slot.req
-                queued_s = ((req.dispatch_ts or now0) - req.enqueue_ts
-                            if req.enqueue_ts is not None else 0.0)
-                running_s = (now0 - req.dispatch_ts
-                             if req.dispatch_ts is not None else 0.0)
-                _expired.inc()
-                if slot.prefilling:
-                    _expired_mid_prefill.inc()
-                    err = ServingTimeout(
-                        "deadline expired mid-prefill after %d/%d prompt "
-                        "tokens (%.3fs in queue, %.3fs in prefill)"
-                        % (slot.prefill_pos, slot.prompt_len,
-                           max(0.0, queued_s), max(0.0, running_s)))
-                else:
-                    _expired_mid_decode.inc()
-                    err = ServingTimeout(
-                        "deadline expired mid-decode after %d/%d generated "
-                        "tokens (%.3fs in queue, %.3fs decoding)"
-                        % (len(slot.generated), req.max_new_tokens,
-                           max(0.0, queued_s), max(0.0, running_s)))
-                self._retire(i, error=err)
+        tel = self._telemetry
+        with tel.span("serving.decode.sweep"):
+            # shed actives whose deadline passed before burning a step on
+            # them — checked BETWEEN chunks too, so a doomed long prompt
+            # frees its budget early instead of prefilling to completion
+            now0 = time.perf_counter()
+            # cancellation reaps at the iteration boundary: the slot retires
+            # and its pages free before the next step dispatches, so an
+            # abandoned future stops burning decode capacity immediately
+            for i, slot in enumerate(self._slots):
+                if slot is not None and slot.req.cancelled:
+                    _cancelled.inc()
+                    self._retire(i, error=ServingCancelled(
+                        "request cancelled after %d/%d generated tokens"
+                        % (len(slot.generated), slot.req.max_new_tokens)))
+            for i, slot in enumerate(self._slots):
+                if slot is not None and slot.req.expired(now0):
+                    req = slot.req
+                    queued_s = ((req.dispatch_ts or now0) - req.enqueue_ts
+                                if req.enqueue_ts is not None else 0.0)
+                    running_s = (now0 - req.dispatch_ts
+                                 if req.dispatch_ts is not None else 0.0)
+                    _expired.inc()
+                    if slot.prefilling:
+                        _expired_mid_prefill.inc()
+                        err = ServingTimeout(
+                            "deadline expired mid-prefill after %d/%d prompt "
+                            "tokens (%.3fs in queue, %.3fs in prefill)"
+                            % (slot.prefill_pos, slot.prompt_len,
+                               max(0.0, queued_s), max(0.0, running_s)))
+                    else:
+                        _expired_mid_decode.inc()
+                        err = ServingTimeout(
+                            "deadline expired mid-decode after %d/%d generated "
+                            "tokens (%.3fs in queue, %.3fs decoding)"
+                            % (len(slot.generated), req.max_new_tokens,
+                               max(0.0, queued_s), max(0.0, running_s)))
+                    self._retire(i, error=err)
         # chunked prefill phase: AT MOST ONE chunk per iteration, so
         # prefill work interleaves with (never starves) the decode step
         # below.  Pick order: FEWEST REMAINING CHUNKS first, admission
@@ -1816,30 +1870,30 @@ class DecodeScheduler:
             self._cache.publish_gauges(
                 sum(s.kv_len for s in self._slots if s is not None))
             return
-        tokens = np.zeros((cfg.num_slots,), np.int32)
-        positions = np.zeros((cfg.num_slots,), np.int32)
-        kv_lens = np.zeros((cfg.num_slots,), np.int32)
-        seeds = np.zeros((cfg.num_slots,), np.uint32)
-        temps = np.zeros((cfg.num_slots,), np.float32)
-        for i, slot in active:
-            tokens[i] = slot.generated[-1]   # feed the last sampled token
-            positions[i] = slot.kv_len       # ... at the next cache index
-            kv_lens[i] = slot.kv_len + 1     # visible kv incl. this token
-            temps[i], seeds[i] = self._sampling_params(slot.req)
-        # the decode step scatters EVERY slot's token k/v at
-        # page_tables[s, 0] offset 0 when positions[s] == 0 — a
-        # PREFILLING slot's table already points at real (possibly
-        # SHARED prefix) pages, so its dispatch row must aim at scratch
-        # like any other non-decoding slot or the write corrupts
-        # position 0 of its (or a prefix neighbor's) cache
-        tables = self._tables
-        masked = [i for i, s in enumerate(self._slots)
-                  if s is not None and s.prefilling]
-        if masked:
-            tables = self._tables.copy()
-            tables[masked] = 0
-        fn = self._jit.get(("decode",))
-        t0 = time.perf_counter()
+        with tel.span("serving.decode.step.build"):
+            tokens = np.zeros((cfg.num_slots,), np.int32)
+            positions = np.zeros((cfg.num_slots,), np.int32)
+            kv_lens = np.zeros((cfg.num_slots,), np.int32)
+            seeds = np.zeros((cfg.num_slots,), np.uint32)
+            temps = np.zeros((cfg.num_slots,), np.float32)
+            for i, slot in active:
+                tokens[i] = slot.generated[-1]   # feed the last sampled token
+                positions[i] = slot.kv_len       # ... at the next cache index
+                kv_lens[i] = slot.kv_len + 1     # visible kv incl. this token
+                temps[i], seeds[i] = self._sampling_params(slot.req)
+            # the decode step scatters EVERY slot's token k/v at
+            # page_tables[s, 0] offset 0 when positions[s] == 0 — a
+            # PREFILLING slot's table already points at real (possibly
+            # SHARED prefix) pages, so its dispatch row must aim at scratch
+            # like any other non-decoding slot or the write corrupts
+            # position 0 of its (or a prefix neighbor's) cache
+            tables = self._tables
+            masked = [i for i, s in enumerate(self._slots)
+                      if s is not None and s.prefilling]
+            if masked:
+                tables = self._tables.copy()
+                tables[masked] = 0
+            fn = self._jit.get(("decode",))
 
         def attempt():
             # the chaos choke point is consulted per ATTEMPT (a retry
@@ -1847,18 +1901,19 @@ class DecodeScheduler:
             serve_fault = _resilience._serve_fault
             if serve_fault is not None:
                 serve_fault([s.req for _, s in active])
-            with self._telemetry.timed("serving.decode.step",
-                                       active=len(active)):
+            with tel.span("serving.decode.step.dispatch"):
                 out, kp, vp = fn(
                     jnp.asarray(tokens), jnp.asarray(positions),
                     self._cache.k_pool, self._cache.v_pool,
                     jnp.asarray(tables), jnp.asarray(kv_lens),
                     jnp.asarray(seeds), jnp.asarray(temps))
-                return np.asarray(out), kp, vp
+            with tel.span("serving.decode.step.wait") as wait:
+                sampled = np.asarray(out)
+            self._turn_wait_s += wait.duration
+            return sampled, kp, vp
 
         def note_retry(exc, attempt_n, delay):
             _step_retries.inc()
-            tel = self._telemetry
             if tel.recording:
                 tel.emit({
                     "type": "serving_retry", "ts": time.time(),
@@ -1868,8 +1923,13 @@ class DecodeScheduler:
                 })
 
         try:
-            sampled, k_pool, v_pool = _resilience.call_with_retry(
-                attempt, policy=self._decode_policy, on_retry=note_retry)
+            # the decode program, dispatch to readback (retries
+            # included): the per-iteration step time, and the cell
+            # ``decode_step_ms`` reads
+            with tel.span("serving.decode.step", active=len(active)):
+                sampled, k_pool, v_pool = _resilience.call_with_retry(
+                    attempt, policy=self._decode_policy,
+                    on_retry=note_retry)
         except Exception as exc:  # noqa: BLE001 — worker must survive
             # fatal (or transient past the retry budget): fail the
             # actives typed, un-retried — replay can't fix a
@@ -1880,38 +1940,38 @@ class DecodeScheduler:
             if self._breaker is not None:
                 self._breaker.record_fatal()
             return
-        step_s = time.perf_counter() - t0
-        _decode_timer.observe(step_s)
-        _step_hist.observe(step_s)
-        self._cache.k_pool, self._cache.v_pool = k_pool, v_pool
-        if self._breaker is not None:
-            self._breaker.record_success()
-        tripped = ()
-        if cfg.kv_guard:
-            # sweep each active slot's TAIL page — the one this step's
-            # token write landed in (position = pre-step kv_len)
-            guard_vec = np.zeros((cfg.num_slots,), np.int32)
-            owners = list(range(cfg.num_slots))
+        with tel.span("serving.decode.step.commit"):
+            self._cache.k_pool, self._cache.v_pool = k_pool, v_pool
+            if self._breaker is not None:
+                self._breaker.record_success()
+            tripped = ()
+            if cfg.kv_guard:
+                # sweep each active slot's TAIL page — the one this
+                # step's token write landed in (position = pre-step
+                # kv_len)
+                guard_vec = np.zeros((cfg.num_slots,), np.int32)
+                owners = list(range(cfg.num_slots))
+                for i, slot in active:
+                    guard_vec[i] = slot.pages[slot.kv_len // cfg.page_size]
+                tripped = self._guard_pages(owners, guard_vec,
+                                            phase="decode")
+            now = time.perf_counter()
             for i, slot in active:
-                guard_vec[i] = slot.pages[slot.kv_len // cfg.page_size]
-            tripped = self._guard_pages(owners, guard_vec, phase="decode")
-        now = time.perf_counter()
-        for i, slot in active:
-            if i in tripped:
-                continue           # retired typed by the guard
-            slot.kv_len += 1
-            tok = int(sampled[i])
-            slot.generated.append(tok)
-            slot.req.journal.accepted.append(tok)
-            slot.req.token_times.append(now)
-        _steps.inc()
-        _tokens.inc(len(active) - len(tripped))
-        for i, _ in active:
-            if self._slots[i] is not None:
-                self._finish_if_done(i)
-        _active_slots.set(self._active_count())
-        self._cache.publish_gauges(
-            sum(s.kv_len for s in self._slots if s is not None))
+                if i in tripped:
+                    continue           # retired typed by the guard
+                slot.kv_len += 1
+                tok = int(sampled[i])
+                slot.generated.append(tok)
+                slot.req.journal.accepted.append(tok)
+                slot.req.token_times.append(now)
+            _steps.inc()
+            _tokens.inc(len(active) - len(tripped))
+            for i, _ in active:
+                if self._slots[i] is not None:
+                    self._finish_if_done(i)
+            _active_slots.set(self._active_count())
+            self._cache.publish_gauges(
+                sum(s.kv_len for s in self._slots if s is not None))
 
     def _retire(self, idx, error=None):
         slot = self._slots[idx]

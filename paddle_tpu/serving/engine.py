@@ -68,7 +68,6 @@ _batches = _obs.counter("serving.batches")
 _batched_rows = _obs.counter("serving.batched_rows")
 _padded_rows = _obs.counter("serving.padded_rows")
 _swaps = _obs.counter("serving.swaps")
-_execute_hist = _obs.histogram("serving.execute")
 
 
 def normalize_feed(model, feed, max_batch_size):
@@ -178,13 +177,12 @@ class BatchExecutor:
                     axis=0)
             feed[name] = chunk
         tel = self._telemetry
-        wall0, t0 = time.time(), time.perf_counter()
-        with tel.timed("serving.execute", bucket=bucket, rows=n,
-                       requests=n_requests, version=model.version,
-                       **self._tags):
+        wall0 = time.time()
+        with tel.span("serving.execute", bucket=bucket, rows=n,
+                      requests=n_requests, version=model.version,
+                      **self._tags) as execute:
             outs = model.predict_batch(feed)
-        exec_s = time.perf_counter() - t0
-        _execute_hist.observe(exec_s)
+        exec_s = execute.duration
         if tel.span_active():
             # attribute THIS dispatch to every trace riding in it: the
             # "execute" leaf of each request's tree (a retried dispatch

@@ -85,7 +85,7 @@ class LoadedModel:
             if b in self.warmed_buckets:
                 continue
             feed = self.zeros_feed(b)
-            with _obs.timed("serving.warmup", bucket=b, model=self.kind):
+            with _obs.span("serving.warmup", bucket=b, model=self.kind):
                 outs = self.predict_batch(feed)
                 self.predict_batch(feed)
             self.warmed_buckets.append(b)
@@ -179,8 +179,8 @@ class ModelStore:
                 "backend='program')" % dirname)
         use_aot = has_aot if backend == "auto" else (backend == "aot")
         version = self._next_version()
-        with _obs.timed("serving.model_load", dirname=dirname,
-                        backend="aot" if use_aot else "program"):
+        with _obs.span("serving.model_load", dirname=dirname,
+                       backend="aot" if use_aot else "program"):
             model = (self._load_aot if use_aot else self._load_program)(
                 dirname, version)
         _obs.inc("serving.model_loads")

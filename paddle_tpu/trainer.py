@@ -43,7 +43,6 @@ import numpy as np
 
 from . import io as io_mod
 from . import observability as _obs
-from .observability import xla_stats as _xla_stats
 from . import resilience
 from . import unique_name
 from .data_feeder import DataFeeder
@@ -281,8 +280,8 @@ def save_checkpoint(executor, dirname, main_program, serial, meta, max_num=3):
     os.rename(tmp, cdir)  # the atomic publish
     resilience.fsync_dir(dirname)
     _rotate_checkpoints(dirname, max_num, trusted=serial)
-    # one timing truth for checkpoint IO: the registry timer feeds
-    # format_report-style summaries, the span shows up on the trace
+    # one timing truth for checkpoint IO: the phase cell holds the
+    # durations, the span shows up on the trace
     _obs.observe_span("checkpoint.save", _wall0, _t0, {"serial": serial})
     return cdir
 
@@ -497,13 +496,6 @@ class Trainer:
             "retries": _retries.value,
             "rewinds": self.nan_rewinds,
         }
-        if _xla_stats.active():
-            # THIS program's stats, not the global last-published gauge —
-            # another armed loop in the process (a serving pool, a second
-            # trainer) must not leak its MFU into these records
-            st = _xla_stats.program_stats(prog_tag)
-            if st is not None and st.last_mfu is not None:
-                rec["mfu"] = st.last_mfu
         if ckpt_save_s is not None:
             rec["checkpoint_save_s"] = ckpt_save_s
         if ckpt_load_s is not None:
@@ -569,16 +561,8 @@ class Trainer:
 
     def train(self, num_epochs, event_handler=None, reader=None,
               feed_order=None, nan_guard=False, failure_monitor=None,
-              prefetch=None, prefetch_buffer=2, attribution=None):
+              prefetch=None, prefetch_buffer=2):
         """Run the training loop.
-
-        ``attribution``: a
-        :class:`~paddle_tpu.observability.StepAttribution` to attach for
-        the duration of this call — per-window feed/compute/compile/fetch
-        decomposition plus the input-bound vs compute-bound verdict,
-        fed by this loop's spans, step records and the prefetcher's
-        buffer-occupancy signal.  Detached (with the trailing window
-        closed) on the way out, however the loop ends.
 
         ``prefetch``: route the reader through the async device-feed
         pipeline (``reader.device_prefetch``) so batch N+1's conversion
@@ -627,8 +611,6 @@ class Trainer:
                                            prefetch, prefetch_buffer)
         if failure_monitor is not None:
             failure_monitor.start()
-        if attribution is not None:
-            attribution.attach()
         try:
             with scope_guard(self.scope):
                 for epoch_id in range(self._epoch_start, num_epochs):
@@ -722,8 +704,6 @@ class Trainer:
                             {"epoch": epoch_id + 1, "step": 0}, cfg.max_num_checkpoints,
                         )
         finally:
-            if attribution is not None:
-                attribution.detach()
             if failure_monitor is not None:
                 failure_monitor.stop()
 

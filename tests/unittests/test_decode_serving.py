@@ -220,10 +220,9 @@ class TestDecodeScheduler:
         assert d["retired"] == 5
         assert d["tokens"] == sum(len(o) for o in outs) == 20
         assert d["steps"] >= 3  # batched steps, not one per token
-        for tname in ("serving.decode.prefill_step",
-                      "serving.decode.decode_step",
-                      "serving.decode.queue_wait"):
-            assert obs.timer(tname).stats()[0] > 0, tname
+        for cell in ("serving.decode.prefill", "serving.decode.step",
+                     "serving.decode.queue_wait"):
+            assert obs.histogram(cell).stats()[0] > 0, cell
         assert obs.gauge("serving.decode.active_slots").value == 0
         assert obs.gauge("serving.decode.queue_depth").value == 0
         recs = [r for r in sink.records if r.get("type") == "decode_sequence"]

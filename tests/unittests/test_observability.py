@@ -14,7 +14,6 @@ import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu import observability as obs
-from paddle_tpu.observability import registry as obs_registry
 
 
 # ---------------------------------------------------------------------------
@@ -85,7 +84,9 @@ def test_env_killswitch(monkeypatch):
     assert not tel.recording  # disabled wins over attached sinks
     tel.emit({"type": "step"})
     assert sink.records == []
-    assert tel.span("x") is obs_registry._NULL_CONTEXT
+    with tel.span("x"):     # killswitch: sinks quiet, the cell still counts
+        pass
+    assert sink.spans == [] and tel.histogram("x").count == 1
     monkeypatch.delenv("PADDLE_TPU_TELEMETRY")
     assert tel.configure() is True  # re-reads the env
     assert tel.recording
@@ -100,7 +101,9 @@ def test_counters_count_even_when_disabled():
 
 def test_spans_only_flow_to_span_sinks():
     tel = obs.Telemetry(enabled=True)
-    assert tel.span("x") is obs_registry._NULL_CONTEXT  # no sink: no-op
+    with tel.span("x"):     # no sink: the cell alone
+        pass
+    assert tel.histogram("x").count == 1
     ring = obs.RingBufferSink(record_spans=True)
     tel.add_sink(ring)
     with tel.span("hello", k="v"):
@@ -111,7 +114,9 @@ def test_spans_only_flow_to_span_sinks():
     assert spans[0]["tags"] == {"k": "v"}
     assert spans[1]["dur"] == 0.5
     tel.remove_sink(ring)
-    assert tel.span("x") is obs_registry._NULL_CONTEXT
+    with tel.span("x"):
+        pass
+    assert [s["name"] for s in ring.spans] == ["hello", "manual"]
 
 
 def test_broken_sink_never_raises_into_the_loop():

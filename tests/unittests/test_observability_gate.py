@@ -1,7 +1,7 @@
 """Tier-1 wiring for the observability gate: run tools/check_observability.py
 (JSONL step-record schema over a real training run, Chrome-trace export
 with visible prefetch/dispatch overlap, bitwise telemetry-on/off
-neutrality, disabled-path overhead budget) in a clean subprocess on CPU
+neutrality, the always-on span cost budget) in a clean subprocess on CPU
 and fail on any regression, so the telemetry subsystem can't rot."""
 import os
 import subprocess

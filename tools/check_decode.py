@@ -205,10 +205,10 @@ def scenario_telemetry_schema():
     assert d["tokens"] == n_tokens, (d["tokens"], n_tokens)
     assert 0 < d["steps"] < n_tokens, (
         "steps %d not batched (tokens %d)" % (d["steps"], n_tokens))
-    for tname in ("serving.decode.prefill_step", "serving.decode.decode_step",
-                  "serving.decode.queue_wait", "serving.decode.warmup"):
-        stats = obs.timer(tname).stats()
-        assert stats and stats[0] > 0, "timer %s never observed" % tname
+    for cell in ("serving.decode.prefill", "serving.decode.step",
+                 "serving.decode.queue_wait", "serving.decode.warmup"):
+        stats = obs.histogram(cell).stats()
+        assert stats and stats[0] > 0, "cell %s never observed" % cell
     for gname in ("serving.decode.queue_depth", "serving.decode.active_slots",
                   "serving.decode.kv_pages_used"):
         assert obs.gauge(gname).value == 0, "%s stuck nonzero" % gname

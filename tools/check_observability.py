@@ -22,10 +22,10 @@ Scenario 3 — bitwise neutrality:
   produce bitwise-identical parameters and losses, and the contract
   counters (feed_host_copy_count / transfer_count) must match exactly.
 
-Scenario 4 — disabled-path overhead budget:
-  with no sink attached, span() + the recording check must cost well
-  under a microsecond per step-equivalent (budget: 2us per call pair,
-  ~1000x slack against a real step).
+Scenario 4 — the always-on span's cost:
+  with no sink attached and no profiler session, span() still observes
+  into its cell; it and the recording check must cost a few
+  microseconds at most (budget: 4us per call pair, ~1.5us measured).
 
 Runnable locally:
     python tools/check_observability.py
@@ -219,7 +219,10 @@ def scenario_bitwise_neutrality():
             % (copies_on, transfers_on))
 
 
-def scenario_disabled_overhead():
+def scenario_span_cost():
+    """The always-on price: with no sink and no profiler session a span
+    is one ``perf_counter`` pair, one static TraceMe check and one locked
+    histogram increment; the record gate stays one attribute read."""
     from paddle_tpu import observability as obs
 
     tel = obs.get_telemetry()
@@ -227,25 +230,28 @@ def scenario_disabled_overhead():
         "gate must start with no sinks attached")
     n = 100_000
     span = tel.span
+    c0 = tel.histogram("gate.span_cost").count
     t0 = time.perf_counter()
     for _ in range(n):
         if tel.recording:  # the executor's per-run gate
             raise AssertionError
-        with span("x"):
+        with span("gate.span_cost"):
             pass
     per_call = (time.perf_counter() - t0) / n
-    budget = 2e-6
+    assert tel.histogram("gate.span_cost").count == c0 + n, (
+        "a sink-less span must still observe into its cell")
+    budget = 4e-6   # ~1.5us measured on this box; CI slack
     assert per_call < budget, (
-        "disabled telemetry path costs %.2fus per step-equivalent "
-        "(budget %.2fus)" % (per_call * 1e6, budget * 1e6))
-    return ("disabled-path overhead: %.3fus per gate+span pair "
+        "an always-on span costs %.2fus (budget %.2fus)"
+        % (per_call * 1e6, budget * 1e6))
+    return ("always-on span: %.3fus per gate+span pair, cell counted "
             "(budget %.1fus) OK" % (per_call * 1e6, budget * 1e6))
 
 
 def main():
     failures = []
     for scenario in (scenario_jsonl_schema, scenario_chrome_trace,
-                     scenario_bitwise_neutrality, scenario_disabled_overhead):
+                     scenario_bitwise_neutrality, scenario_span_cost):
         try:
             msg = scenario()
         except AssertionError as e:

@@ -323,10 +323,10 @@ def scenario_telemetry_schema():
         assert sum(bucket_counts.values()) == n_batch, (
             "bucket histogram %s does not sum to %d batches"
             % (bucket_counts, n_batch))
-        for tname in ("serving.queue_wait", "serving.execute",
-                      "serving.model_load", "serving.warmup"):
-            stats = obs.timer(tname).stats()
-            assert stats and stats[0] > 0, "timer %s never observed" % tname
+        for cell in ("serving.queue_wait", "serving.execute",
+                     "serving.model_load", "serving.warmup"):
+            stats = obs.histogram(cell).stats()
+            assert stats and stats[0] > 0, "cell %s never observed" % cell
         assert obs.gauge("serving.queue_depth").value == 0
         span_names = {s["name"] for s in sink.spans}
         assert {"serving.execute", "serving.request"} <= span_names, span_names

@@ -31,6 +31,8 @@ import os
 import sys
 import time
 
+import numpy as np
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 sys.path.insert(0, os.path.join(REPO, "benchmarks"))
@@ -54,8 +56,7 @@ def report_program(program, startup, feed, fetch_list, iters=20,
     from paddle_tpu.observability import xla_stats
 
     xla_stats.reset()
-    xla_stats.enable(peak_flops=peak_flops, peak_membw=peak_membw,
-                     sync_timing=True)
+    xla_stats.enable(peak_flops=peak_flops, peak_membw=peak_membw)
     try:
         exe = fluid.Executor(fluid.CPUPlace())
         scope = fluid.Scope()
@@ -64,7 +65,9 @@ def report_program(program, startup, feed, fetch_list, iters=20,
             times = []
             for i in range(iters):
                 t0 = time.perf_counter()
-                exe.run(program, feed=feed, fetch_list=fetch_list)
+                outs = exe.run(program, feed=feed, fetch_list=fetch_list)
+                for o in outs:      # lazy fetches: the step ends here
+                    np.asarray(o)
                 times.append(time.perf_counter() - t0)
             state = exe._collect_state(program, scope)
         st = xla_stats.program_stats(
