@@ -477,7 +477,7 @@ def serve_lowers_to_kernels(sz, seed=0):
     L, H, Dh = sz["n_layer"], sz["n_head"], sz["d_model"] // sz["n_head"]
     S, ps, C = sz["slots"], sz["page"], sz["chunk"]
     mp = sz["max_seq_len"] // ps
-    pool = jax.ShapeDtypeStruct((L, S * mp + 1, ps, H, Dh), jnp.bfloat16)
+    pool = jax.ShapeDtypeStruct((L, S * mp + 1, ps, H * Dh), jnp.bfloat16)
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
     texts = {
         "decode": jax.jit(dm.decode_fn).lower(

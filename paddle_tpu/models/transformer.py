@@ -764,7 +764,9 @@ def lm_prefill_chunk(params, tokens, start, valid, k_pool, v_pool,
     ``tokens``: [C] int32 — the chunk's token window (pad tail
     arbitrary), absolute positions ``start .. start + C - 1``;
     ``valid``: real tokens in this window (the final chunk's tail is
-    pad); ``chunk_pages``: [C // page_size] int32 page ids this chunk's
+    pad); ``k_pool`` / ``v_pool``: the cache's stored stacks
+    ``[L, num_pages, page_size, H*Dh]`` (heads folded head-major);
+    ``chunk_pages``: [C // page_size] int32 page ids this chunk's
     k/v scatter into (tail entries -> scratch); ``gather_pages``:
     [max_pages] int32 — the sequence's FULL page-table row, what the
     chunk attends over.  Returns ``(last_logits [V], k_pool', v_pool')``
@@ -798,14 +800,15 @@ def lm_prefill_chunk(params, tokens, start, valid, k_pool, v_pool,
     x = emb[tokens] * np.sqrt(d_model) + pos_table[positions]
     for li, lp in enumerate(params["layers"]):
         q = (x @ lp["wq"]).reshape(C, n_head, dh)
-        k = (x @ lp["wk"]).reshape(C, n_head, dh)
-        v = (x @ lp["wv"]).reshape(C, n_head, dh)
+        # k/v rows are already the pool's folded page rows [H*Dh] (head h =
+        # lanes h*dh:(h+1)*dh); scattered into the STACKED pool, which the
+        # attention then addresses in place by (li, page): no k_pool[li]
         k_pool = k_pool.at[li, chunk_pages].set(
-            k.reshape(nb, ps, n_head, dh).astype(k_pool.dtype))
+            (x @ lp["wk"]).reshape(nb, ps, d_model).astype(k_pool.dtype))
         v_pool = v_pool.at[li, chunk_pages].set(
-            v.reshape(nb, ps, n_head, dh).astype(v_pool.dtype))
-        ctx = paged_prefill_attention(q, k_pool[li], v_pool[li],
-                                      gather_pages, start, impl=attn_impl)
+            (x @ lp["wv"]).reshape(nb, ps, d_model).astype(v_pool.dtype))
+        ctx = paged_prefill_attention(q, k_pool, v_pool, gather_pages,
+                                      start, impl=attn_impl, layer=li)
         x = _lm_block_tail(lp, x, ctx.reshape(C, d_model))
     last = jax.lax.dynamic_index_in_dim(x, valid - 1, axis=0,
                                         keepdims=False)
@@ -815,7 +818,8 @@ def lm_prefill_chunk(params, tokens, start, valid, k_pool, v_pool,
 def lm_decode_step(params, tokens, positions, k_pool, v_pool, page_tables,
                    kv_lens, *, n_head, attn_impl=None):
     """One decode iteration: token s of each slot at cache index
-    ``positions[s]``.  Writes k/v into the paged pools, attends over each
+    ``positions[s]``.  Writes k/v into the paged pools (the stored stacks
+    ``[L, num_pages, page_size, H*Dh]``), attends over each
     slot's first ``kv_lens[s]`` cached tokens, returns
     ``(logits [S, V], k_pool', v_pool')``.  ``kv_lens[s] == 0`` =
     inactive slot (scratch-page write, zero attention, garbage logits
@@ -835,12 +839,14 @@ def lm_decode_step(params, tokens, positions, k_pool, v_pool, page_tables,
     offsets = positions % page_size
     for li, lp in enumerate(params["layers"]):
         q = (x @ lp["wq"]).reshape(S, n_head, dh)
-        k = (x @ lp["wk"]).reshape(S, n_head, dh)
-        v = (x @ lp["wv"]).reshape(S, n_head, dh)
-        k_pool = k_pool.at[li, pages, offsets].set(k.astype(k_pool.dtype))
-        v_pool = v_pool.at[li, pages, offsets].set(v.astype(v_pool.dtype))
-        ctx = paged_decode_attention(q, k_pool[li], v_pool[li],
-                                     page_tables, kv_lens, impl=attn_impl)
+        # one folded row [H*Dh] per slot into the STACKED pool, attended in
+        # place by (li, page) — see lm_prefill_chunk
+        k_pool = k_pool.at[li, pages, offsets].set(
+            (x @ lp["wk"]).astype(k_pool.dtype))
+        v_pool = v_pool.at[li, pages, offsets].set(
+            (x @ lp["wv"]).astype(v_pool.dtype))
+        ctx = paged_decode_attention(q, k_pool, v_pool, page_tables,
+                                     kv_lens, impl=attn_impl, layer=li)
         x = _lm_block_tail(lp, x, ctx.reshape(S, d_model))
     return x @ params["out_w"], k_pool, v_pool
 

@@ -724,34 +724,72 @@ flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 # every sequence's keys/values in fixed-size pages of a preallocated pool
 # (vLLM/PagedAttention, Kwon et al. SOSP'23); one decode iteration asks,
 # for each of S slots, "this slot's ONE new query token against its first
-# kv_lens cached tokens".  Two engines:
+# kv_lens cached tokens".
 #
-# * reference (CPU / tests): gather the slot's pages out of the pool
-#   (``pool[page_tables]``) and run the masked-softmax formulation — the
-#   same arithmetic shape as ``mha_reference`` with T_q=1, so tier-1 stays
-#   green without Pallas interpret overhead.
+# The pools are STORED heads-folded and layer-stacked, ``[L, P, ps, H*Dh]``
+# (head h is lanes ``h*Dh:(h+1)*Dh``): that is the shape whose default
+# device layout is row-major with unpadded ``(ps, H*Dh)`` page tiles, so a
+# step program that aliases the pools input->output never re-lays them out
+# (a trailing ``[H, Dh]`` = 8 x 64 would pad in a bf16 tile, and the
+# compiler then picks a pages-minor layout the kernels cannot read).  Both
+# engines take the WHOLE stack plus a static ``layer`` and touch only the
+# pages the page table names — no ``pool[layer]`` slice ever exists:
+#
+# * reference (CPU / tests): gather the slot's pages out of the stack
+#   (``pool[layer, page_tables]``), unfold the heads and run the
+#   masked-softmax formulation — the same arithmetic shape as
+#   ``mha_reference`` with T_q=1, so tier-1 stays green without Pallas
+#   interpret overhead.
 # * pallas (TPU): the page table rides the SCALAR-PREFETCH path (the same
 #   ``PrefetchScalarGridSpec`` machinery ``kv_lens`` already uses): the
-#   kernel's k/v BlockSpec index maps read the prefetched table to DMA
-#   exactly this slot's pages — no gathered [S, max_kv, H, D] intermediate
-#   ever exists in HBM.  A block carries ALL heads of a page (the chip
-#   refuses a one-head block in the second-minor dimension).  Online
-#   softmax across the slot's page walk, fully masked pages skipped via
-#   ``pl.when``.
+#   kernel's k/v BlockSpec index maps pick ``(layer, page, 0, 0)`` from the
+#   prefetched table to DMA exactly this slot's pages — no gathered
+#   [S, max_kv, H, D] intermediate ever exists in HBM.  A block carries ALL
+#   heads of a page in its lanes; the head loop runs inside the kernel over
+#   static lane slices.  Online softmax across the slot's page walk, fully
+#   masked pages skipped via ``pl.when``.
+#
+# The public entry points also accept ONE layer's unfolded
+# ``[P, ps, H, Dh]`` pool (``layer=None``): it is folded into a one-layer
+# stack and runs the SAME kernel, so what the smokes and the benchmark's
+# ``correct`` check is what the step programs serve.
 #
 # Contract (shared by both engines, tested in test_flash_decode.py):
 # ``kv_lens[s] == 0`` (inactive slot) yields EXACT ZEROS for that slot.
 # ---------------------------------------------------------------------------
 
 
-def _paged_reference(q, k_pool, v_pool, page_tables, kv_lens, sm_scale):
+def _stacked_pools(q, k_pool, v_pool, layer):
+    """``(k_stack, v_stack, layer)`` in the stored form ``[L, P, ps, H*Dh]``.
+    ``layer=None`` means ONE layer's unfolded ``[P, ps, H, Dh]`` pool: a
+    free reshape of a row-major array into a one-layer stack."""
+    H, Dh = q.shape[-2:]
+    if layer is None:
+        if k_pool.ndim != 4 or k_pool.shape[2:] != (H, Dh):
+            raise ValueError(
+                "without layer= the pool is one layer's [P, ps, H, Dh] = "
+                "[.., .., %d, %d]; got %s" % (H, Dh, k_pool.shape))
+        fold = (1,) + k_pool.shape[:2] + (H * Dh,)
+        return k_pool.reshape(fold), v_pool.reshape(fold), 0
+    if k_pool.ndim != 4 or k_pool.shape[3] != H * Dh:
+        raise ValueError(
+            "with layer= the pool is the stored stack [L, P, ps, H*Dh] = "
+            "[.., .., .., %d]; got %s" % (H * Dh, k_pool.shape))
+    return k_pool, v_pool, int(layer)
+
+
+def _paged_reference(q, k_pool, v_pool, page_tables, kv_lens, sm_scale, layer):
     import jax.numpy as jnp
 
     S, H, Dh = q.shape
-    ps = k_pool.shape[1]
+    ps = k_pool.shape[2]
     mp = page_tables.shape[1]
-    k = k_pool[page_tables].reshape(S, mp * ps, H, Dh).astype(jnp.float32)
-    v = v_pool[page_tables].reshape(S, mp * ps, H, Dh).astype(jnp.float32)
+    # unfold the heads BEFORE the einsums: their reduction shapes are part
+    # of the bitwise contract (continuous batching == per-sequence)
+    k = k_pool[layer, page_tables].reshape(
+        S, mp * ps, H, Dh).astype(jnp.float32)
+    v = v_pool[layer, page_tables].reshape(
+        S, mp * ps, H, Dh).astype(jnp.float32)
     s = jnp.einsum("shd,skhd->shk", q.astype(jnp.float32), k) * sm_scale
     ok = jnp.arange(mp * ps)[None, :] < kv_lens[:, None]  # [S, K]
     s = jnp.where(ok[:, None, :], s, NEG_INF)
@@ -762,14 +800,14 @@ def _paged_reference(q, k_pool, v_pool, page_tables, kv_lens, sm_scale):
 
 def _paged_decode_kernel(pt_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
                          m_scr, l_scr, acc_scr, *, page_size, num_pages_per_seq,
-                         sm_scale):
-    """One grid step = one slot x one page, ALL heads: the k/v block is the
-    whole page ``[ps, H, Dh]`` and the query block the slot's ``[H, Dh]``,
-    so the last two block dims equal the arrays' own (the only blocking of
-    a one-row-per-head query the TPU lowering accepts).  A single query row
-    per head is a matvec, so scores and p.v run on the VPU as broadcast
-    multiplies + reductions; scores stay ``[ps, H, 1]`` (heads on sublanes)
-    so nothing is ever relaid between lanes and sublanes."""
+                         n_head, head_dim, sm_scale):
+    """One grid step = one slot x one page, ALL heads folded into the lanes:
+    the k/v block is the page ``[ps, H*Dh]`` and the query block the slot's
+    row ``[1, H*Dh]``.  The head loop runs over static lane slices; a single
+    query row per head is a matvec, so scores and p.v stay on the VPU as
+    broadcast multiplies + reductions in f32 (no MXU rounding: the bf16
+    page is the only precision lost), with per-head online-softmax state in
+    the scratch rows."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
@@ -791,38 +829,45 @@ def _paged_decode_kernel(pt_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(visible)
     def _body():
-        q = q_ref[0].astype(jnp.float32)  # [H, Dh]
-        k = k_ref[0].astype(jnp.float32)  # [ps, H, Dh]
-        v = v_ref[0].astype(jnp.float32)
         ok = (j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (page_size, 1, 1), 0)) < kvl
-        k = jnp.where(ok, k, 0.0)  # 0*garbage tail rows stay finite
-        v = jnp.where(ok, v, 0.0)
-        s = jnp.sum(q[None] * k, axis=-1, keepdims=True) * sm_scale
-        s = jnp.where(ok, s, NEG_INF)               # [ps, H, 1]
+            jnp.int32, (page_size, 1), 0)) < kvl
+        for h in range(n_head):
+            lanes = slice(h * head_dim, (h + 1) * head_dim)
+            q = q_ref[:, lanes].astype(jnp.float32)  # [1, Dh]
+            k = k_ref[:, lanes].astype(jnp.float32)  # [ps, Dh]
+            v = v_ref[:, lanes].astype(jnp.float32)
+            k = jnp.where(ok, k, 0.0)  # 0*garbage tail rows stay finite
+            v = jnp.where(ok, v, 0.0)
+            s = jnp.sum(q * k, axis=-1, keepdims=True) * sm_scale
+            s = jnp.where(ok, s, NEG_INF)               # [ps, 1]
 
-        m_prev = m_scr[:, 0:1]                      # [H, 1]
-        m_new = jnp.maximum(m_prev, s.max(axis=0))
-        p = jnp.exp(s - m_new[None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[:] = jnp.broadcast_to(
-            l_scr[:, 0:1] * alpha + p.sum(axis=0), l_scr.shape)
-        acc_scr[:, :] = acc_scr[:, :] * alpha + jnp.sum(p * v, axis=0)
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+            m_prev = m_scr[h:h + 1, 0:1]                # [1, 1]
+            m_new = jnp.maximum(m_prev, s.max(axis=0, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_scr[h:h + 1, :] = jnp.broadcast_to(
+                l_scr[h:h + 1, 0:1] * alpha + p.sum(axis=0, keepdims=True),
+                (1, l_scr.shape[1]))
+            acc_scr[:, lanes] = acc_scr[:, lanes] * alpha + jnp.sum(
+                p * v, axis=0, keepdims=True)
+            m_scr[h:h + 1, :] = jnp.broadcast_to(m_new, (1, m_scr.shape[1]))
 
     @pl.when(j == num_pages_per_seq - 1)
     def _finish():
-        denom = jnp.maximum(l_scr[:, 0:1], 1e-30)
-        o_ref[0] = (acc_scr[:, :] / denom).astype(o_ref.dtype)
+        for h in range(n_head):
+            lanes = slice(h * head_dim, (h + 1) * head_dim)
+            denom = jnp.maximum(l_scr[h:h + 1, 0:1], 1e-30)
+            o_ref[:, lanes] = (acc_scr[:, lanes] / denom).astype(o_ref.dtype)
 
 
-def _paged_pallas(q, k_pool, v_pool, page_tables, kv_lens, sm_scale, interpret):
+def _paged_pallas(q, k_pool, v_pool, page_tables, kv_lens, sm_scale, interpret,
+                  layer):
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     S, H, Dh = q.shape
-    ps = k_pool.shape[1]
+    ps = k_pool.shape[2]
     mp = page_tables.shape[1]
     # flat [S*mp] so the prefetched table indexes with one scalar read
     pt_flat = page_tables.astype(jnp.int32).reshape(S * mp)
@@ -830,33 +875,35 @@ def _paged_pallas(q, k_pool, v_pool, page_tables, kv_lens, sm_scale, interpret):
 
     kernel = functools.partial(
         _paged_decode_kernel, page_size=ps, num_pages_per_seq=mp,
-        sm_scale=sm_scale)
-    # the slot's j-th PAGE, straight out of the pool: the block index comes
-    # from the prefetched page table
-    page = pl.BlockSpec((1, ps, H, Dh),
-                        lambda s, j, pt, kl: (pt[s * mp + j], 0, 0, 0))
-    row = pl.BlockSpec((1, H, Dh), lambda s, j, pt, kl: (s, 0, 0))
+        n_head=H, head_dim=Dh, sm_scale=sm_scale)
+    # the slot's j-th PAGE of this layer, straight out of the stacked pool:
+    # the page index comes from the prefetched table, the layer is static
+    page = pl.BlockSpec((None, None, ps, H * Dh),
+                        lambda s, j, pt, kl: (layer, pt[s * mp + j], 0, 0))
+    # [S, 1, H*Dh]: the block's last two dims equal the array's own (the
+    # only blocking of a one-row query the TPU lowering accepts)
+    row = pl.BlockSpec((None, 1, H * Dh), lambda s, j, pt, kl: (s, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(S, mp),
         in_specs=[row, page, page],
         out_specs=[row],
         scratch_shapes=[
-            pltpu.VMEM((H, 128), jnp.float32),  # running max (lane-replicated)
-            pltpu.VMEM((H, 128), jnp.float32),  # running sum
-            pltpu.VMEM((H, Dh), jnp.float32),   # output accumulator
+            pltpu.VMEM((H, 128), jnp.float32),     # running max (lane-replicated)
+            pltpu.VMEM((H, 128), jnp.float32),     # running sum
+            pltpu.VMEM((1, H * Dh), jnp.float32),  # output accumulator
         ],
     )
     (out,) = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((S, H, Dh), q.dtype)],
+        out_shape=[jax.ShapeDtypeStruct((S, 1, H * Dh), q.dtype)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(pt_flat, lens, q, k_pool, v_pool)
-    return out
+    )(pt_flat, lens, q.reshape(S, 1, H * Dh), k_pool, v_pool)
+    return out.reshape(S, H, Dh)
 
 
 # ---------------------------------------------------------------------------
@@ -884,14 +931,16 @@ def _paged_pallas(q, k_pool, v_pool, page_tables, kv_lens, sm_scale, interpret):
 # ---------------------------------------------------------------------------
 
 
-def _paged_prefill_reference(q, k_pool, v_pool, pages, start, sm_scale):
+def _paged_prefill_reference(q, k_pool, v_pool, pages, start, sm_scale,
+                             layer):
     import jax.numpy as jnp
 
     C, H, Dh = q.shape
-    ps = k_pool.shape[1]
+    ps = k_pool.shape[2]
     mp = pages.shape[0]
-    k = k_pool[pages].reshape(mp * ps, H, Dh).astype(jnp.float32)
-    v = v_pool[pages].reshape(mp * ps, H, Dh).astype(jnp.float32)
+    # heads unfolded before the einsums, as in _paged_reference
+    k = k_pool[layer, pages].reshape(mp * ps, H, Dh).astype(jnp.float32)
+    v = v_pool[layer, pages].reshape(mp * ps, H, Dh).astype(jnp.float32)
     s = jnp.einsum("chd,khd->chk", q.astype(jnp.float32), k) * sm_scale
     # causal over CACHE order: query row i (absolute position start + i)
     # sees keys [0, start + i] — its own prefix, itself included
@@ -908,7 +957,7 @@ def _paged_prefill_kernel(pt_ref, start_ref, q_ref, k_ref, v_ref, o_ref,
                           sm_scale):
     """One grid step = one page against the whole chunk, ALL heads.  Heads
     are folded into the lane dimension (``[C, H*Dh]`` queries, ``[ps,
-    H*Dh]`` pages — free reshapes of the row-major pool), which is what
+    H*Dh]`` pages — the pool's stored form), which is what
     makes the blocks legal on the chip; the head loop runs inside the
     kernel over static lane slices, one ``[C, Dh] x [Dh, ps]`` matmul
     each, with per-head online-softmax state in the leading scratch dim."""
@@ -940,8 +989,8 @@ def _paged_prefill_kernel(pt_ref, start_ref, q_ref, k_ref, v_ref, o_ref,
         for h in range(n_head):
             lanes = slice(h * head_dim, (h + 1) * head_dim)
             q = q_ref[:, lanes].astype(jnp.float32)     # [C, Dh]
-            k = k_ref[0, :, lanes].astype(jnp.float32)  # [ps, Dh]
-            v = v_ref[0, :, lanes].astype(jnp.float32)
+            k = k_ref[:, lanes].astype(jnp.float32)     # [ps, Dh]
+            v = v_ref[:, lanes].astype(jnp.float32)
             # zero key/value rows past the chunk's visibility so stale page
             # tails can't poison the p·v accumulation (0·garbage stays 0)
             k = jnp.where(kcol < start + chunk, k, 0.0)
@@ -969,13 +1018,13 @@ def _paged_prefill_kernel(pt_ref, start_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def _paged_prefill_pallas(q, k_pool, v_pool, pages, start, sm_scale,
-                          interpret):
+                          interpret, layer):
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     C, H, Dh = q.shape
-    P, ps = k_pool.shape[:2]
+    ps = k_pool.shape[2]
     mp = pages.shape[0]
     pt = pages.astype(jnp.int32)
     start_arr = jnp.reshape(jnp.asarray(start, jnp.int32), (1,))
@@ -983,7 +1032,10 @@ def _paged_prefill_pallas(q, k_pool, v_pool, pages, start, sm_scale,
     kernel = functools.partial(
         _paged_prefill_kernel, page_size=ps, num_pages_per_seq=mp,
         chunk=C, n_head=H, head_dim=Dh, sm_scale=sm_scale)
-    page = pl.BlockSpec((1, ps, H * Dh), lambda j, pt, st: (pt[j], 0, 0))
+    # one page of this (static) layer out of the stacked pool, as in
+    # _paged_pallas: the kernel sees [ps, H*Dh]
+    page = pl.BlockSpec((None, None, ps, H * Dh),
+                        lambda j, pt, st: (layer, pt[j], 0, 0))
     rows = pl.BlockSpec((C, H * Dh), lambda j, pt, st: (0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -1004,20 +1056,22 @@ def _paged_prefill_pallas(q, k_pool, v_pool, pages, start, sm_scale,
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
-    )(pt, start_arr, q.reshape(C, H * Dh),
-      k_pool.reshape(P, ps, H * Dh), v_pool.reshape(P, ps, H * Dh))
+    )(pt, start_arr, q.reshape(C, H * Dh), k_pool, v_pool)
     return out.reshape(C, H, Dh)
 
 
 def paged_prefill_attention(q, k_pool, v_pool, pages, start, sm_scale=None,
-                            impl=None, interpret=None):
+                            impl=None, interpret=None, layer=None):
     """Chunk-of-prompt attention against one sequence's paged KV.
 
     q: [C, H, Dh] — one prefill chunk's query tokens, absolute positions
         ``start .. start + C - 1`` (pad tail rows allowed; their outputs
         are garbage the caller ignores).
-    k_pool / v_pool: [num_pages, page_size, H, Dh] — ONE layer's pool;
-        the chunk's OWN k/v must already be scattered in.
+    k_pool / v_pool: with ``layer=li`` (the step programs) the STORED
+        stack ``[L, num_pages, page_size, H*Dh]``, addressed in place;
+        with ``layer=None`` ONE layer's ``[num_pages, page_size, H, Dh]``
+        pool, folded into a one-layer stack for the same kernel.  The
+        chunk's OWN k/v must already be scattered in.
     pages: [max_pages] int32 — the sequence's full page-table row in
         order; unused entries must point at a valid (scratch) page.
     start: int32 scalar — absolute position of the chunk's first row.
@@ -1034,23 +1088,28 @@ def paged_prefill_attention(q, k_pool, v_pool, pages, start, sm_scale=None,
         sm_scale = 1.0 / float(np.sqrt(q.shape[-1]))
     if impl in (None, "auto"):
         impl = "reference" if cpu_backend() else "pallas"
+    k_pool, v_pool, layer = _stacked_pools(q, k_pool, v_pool, layer)
     if impl == "reference":
         return _paged_prefill_reference(q, k_pool, v_pool, pages, start,
-                                        sm_scale)
+                                        sm_scale, layer)
     if impl != "pallas":
         raise ValueError("impl must be auto|reference|pallas, got %r" % impl)
     if interpret is None:
         interpret = cpu_backend()
     return _paged_prefill_pallas(q, k_pool, v_pool, pages, start, sm_scale,
-                                 interpret)
+                                 interpret, layer)
 
 
 def paged_decode_attention(q, k_pool, v_pool, page_tables, kv_lens,
-                           sm_scale=None, impl=None, interpret=None):
+                           sm_scale=None, impl=None, interpret=None,
+                           layer=None):
     """Single-token-query attention against a paged KV pool.
 
     q: [S, H, Dh] — one query token per decode slot.
-    k_pool / v_pool: [num_pages, page_size, H, Dh] — ONE layer's pool.
+    k_pool / v_pool: with ``layer=li`` (the step programs) the STORED
+        stack ``[L, num_pages, page_size, H*Dh]``, addressed in place;
+        with ``layer=None`` ONE layer's ``[num_pages, page_size, H, Dh]``
+        pool, folded into a one-layer stack for the same kernel.
     page_tables: [S, max_pages] int32 — slot s's kv lives in pages
         ``page_tables[s, :ceil(kv_lens[s]/page_size)]`` in order; unused
         entries must point at a valid (scratch) page id.
@@ -1063,15 +1122,16 @@ def paged_decode_attention(q, k_pool, v_pool, page_tables, kv_lens,
         sm_scale = 1.0 / float(np.sqrt(q.shape[-1]))
     if impl in (None, "auto"):
         impl = "reference" if cpu_backend() else "pallas"
+    k_pool, v_pool, layer = _stacked_pools(q, k_pool, v_pool, layer)
     if impl == "reference":
         return _paged_reference(q, k_pool, v_pool, page_tables, kv_lens,
-                                sm_scale)
+                                sm_scale, layer)
     if impl != "pallas":
         raise ValueError("impl must be auto|reference|pallas, got %r" % impl)
     if interpret is None:
         interpret = cpu_backend()
     return _paged_pallas(q, k_pool, v_pool, page_tables, kv_lens, sm_scale,
-                         interpret)
+                         interpret, layer)
 
 
 def paged_kv_finite(k_pool, v_pool, pages):
@@ -1083,7 +1143,7 @@ def paged_kv_finite(k_pool, v_pool, pages):
     projection fails exactly the owning sequence typed instead of
     parking NaNs in pages a prefix-sharing sequence will read later.
 
-    k_pool / v_pool: the cache's stacked ``[L, num_pages, ps, H, D]``
+    k_pool / v_pool: the cache's stacked ``[L, num_pages, ps, H*D]``
     pools (all layers — a bad write in ANY layer must trip).
     pages: ``[N]`` int32 page ids to check (per-slot decode tail pages,
     or the pages a chunk wrote; padding entries may aim at scratch
@@ -1095,8 +1155,8 @@ def paged_kv_finite(k_pool, v_pool, pages):
     """
     import jax.numpy as jnp
 
-    k = k_pool[:, pages]        # [L, N, ps, H, D]
+    k = k_pool[:, pages]        # [L, N, ps, H*D]
     v = v_pool[:, pages]
-    axes = (0, 2, 3, 4)
+    axes = (0, 2, 3)
     return (jnp.isfinite(k).all(axis=axes)
             & jnp.isfinite(v).all(axis=axes))

@@ -186,6 +186,14 @@ class DecodeModel:
     pools, attend over each slot's first ``kv_lens`` cached tokens.
     ``kv_lens[s] == 0`` marks an inactive slot (masked, scratch writes).
 
+    ``k_pool`` / ``v_pool`` are the cache's WHOLE pools in their stored
+    shape ``[L, num_pages, page_size, H*D]`` (``kv_cache.PagedKVCache``:
+    heads folded head-major into the last axis, so a token's k is one
+    ``[H*D]`` row and a page a ``[page_size, H*D]`` tile).  A model
+    scatters rows into the stack and attends through
+    ``paged_*_attention(..., layer=li)``; it must not slice a layer out
+    (``k_pool[li]`` is a layer-sized copy in every step on the chip).
+
     All are jitted by the scheduler (with pool donation on TPU); they
     must be shape-stable in everything but values.
     ``models.transformer.build_decode_model`` is the in-repo producer.
@@ -467,7 +475,7 @@ class HandoffPacket:
     """Host-staged KV of one fully prefilled sequence in transit
     between a prefill-role replica and a decode-role one (roles mode).
 
-    ``k_host``/``v_host`` are numpy ``[L, max_pages_per_seq, ps, H, D]``
+    ``k_host``/``v_host`` are numpy ``[L, max_pages_per_seq, ps, H*D]``
     gathers of the origin cache (rows past ``n_pages`` hold scratch
     content and scatter back into scratch); ``first`` is the first
     sampled token (already journaled on the origin); ``hashes`` the
@@ -806,14 +814,12 @@ class DecodeScheduler:
                     jnp.zeros((mp,), jnp.int32))
                 np.asarray(k), np.asarray(v)
             if self._role == "decode":
-                zero = jnp.zeros(
-                    (self._cache.num_layers, mp, cfg.page_size,
-                     self._cache.num_heads, self._cache.head_dim),
-                    self._cache.dtype)
+                zero = jnp.zeros(self._cache.pages_shape(mp),
+                                 self._cache.dtype)
                 kp, vp = self._jit.get(("hscatter",))(
                     self._cache.k_pool, self._cache.v_pool, zero, zero,
                     jnp.zeros((mp,), jnp.int32))
-                np.asarray(kp[0, 0, 0, 0, 0])
+                np.asarray(kp[0, 0, 0, 0])
                 self._cache.k_pool, self._cache.v_pool = kp, vp
         return self
 

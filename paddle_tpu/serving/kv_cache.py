@@ -4,11 +4,24 @@ The memory half of the continuous-batching decode runtime (vLLM /
 PagedAttention, Kwon et al. SOSP'23): instead of one contiguous
 ``[B, max_seq_len, ...]`` cache slab per sequence — whose worst-case
 reservation is what caps batch size long before compute does — keys and
-values live in fixed-size PAGES of a pool preallocated once per layer,
-``[num_pages, page_size, H, D]``, and each sequence owns an ordered list
+values live in fixed-size PAGES of a pool preallocated once,
+``[L, num_pages, page_size, H*D]``, and each sequence owns an ordered list
 of page ids (its page table).  Admission allocates, retirement frees, and
 the pool's occupancy — not a worst-case rectangle — is what bounds how
 many sequences decode concurrently.
+
+**Stored shape.**  A page row holds all heads FOLDED into its last axis,
+head-major (head ``h`` is ``[..., h*D:(h+1)*D]``), and the layers are
+stacked in front.  That is the shape whose default device layout is
+row-major with unpadded ``(page_size, H*D)`` tiles (``H*D`` a multiple of
+128 at every width that matters), which is what the paged kernels read:
+a trailing ``[H, D]`` = 8 x 64 pads in a bf16 tile, the compiler then
+keeps the pool pages-minor, and every step program re-lays the WHOLE
+pool out twice.  The step programs scatter folded rows into the stack
+and the kernels address it in place by ``(layer, page)``
+(``parallel/flash_attention.py``), so nothing pool-sized or layer-sized
+is ever materialised.  The page axis is axis 1: host offload, sessions
+and migration index ``[:, idx]``.
 
 Allocation discipline (decode_scheduler.py is the only caller):
 
@@ -83,16 +96,16 @@ def write_prompt_kv(k_pool, v_pool, k_new, v_new, pages):
     """Scatter a prefilled prompt's whole-page blocks into the pools.
 
     k_new/v_new: ``[L, T, H, D]`` with ``T % page_size == 0`` (the prefill
-    bucket is a page multiple); ``pages``: ``[T // page_size]`` int32 page
-    ids — entries past the sequence's real need point at the scratch page,
-    so the scatter shape stays static per bucket.  Returns the updated
-    ``(k_pool, v_pool)``.
+    bucket is a page multiple), folded here into the pool's ``H*D`` rows;
+    ``pages``: ``[T // page_size]`` int32 page ids — entries past the
+    sequence's real need point at the scratch page, so the scatter shape
+    stays static per bucket.  Returns the updated ``(k_pool, v_pool)``.
     """
-    L, T, H, D = k_new.shape
-    ps = k_pool.shape[2]
+    L, T = k_new.shape[:2]
+    ps, HD = k_pool.shape[2:]
     n = T // ps
-    kb = k_new.reshape(L, n, ps, H, D)
-    vb = v_new.reshape(L, n, ps, H, D)
+    kb = k_new.reshape(L, n, ps, HD)
+    vb = v_new.reshape(L, n, ps, HD)
     return k_pool.at[:, pages].set(kb), v_pool.at[:, pages].set(vb)
 
 
@@ -104,8 +117,10 @@ def write_token_kv(k_pool, v_pool, k_tok, v_tok, pages, offsets):
     slots aim at the scratch page (duplicate scratch writes are fine:
     nothing ever reads it).  Returns the updated ``(k_pool, v_pool)``.
     """
-    return (k_pool.at[:, pages, offsets].set(k_tok),
-            v_pool.at[:, pages, offsets].set(v_tok))
+    L, S = k_tok.shape[:2]
+    HD = k_pool.shape[3]
+    return (k_pool.at[:, pages, offsets].set(k_tok.reshape(L, S, HD)),
+            v_pool.at[:, pages, offsets].set(v_tok.reshape(L, S, HD)))
 
 
 class PagedKVCache:
@@ -114,7 +129,8 @@ class PagedKVCache:
     Parameters
     ----------
     num_layers / num_heads / head_dim: model dims; the pools are
-        ``[L, num_pages, page_size, H, D]`` (k and v).
+        ``[L, num_pages, page_size, H*D]`` (k and v; ``pool_shape``), heads
+        folded head-major into the last axis — see the module docstring.
     num_pages: pool size INCLUDING the reserved scratch page 0.
     page_size: tokens per page.
     max_seq_len: longest sequence the runtime will hold; fixes the
@@ -141,10 +157,8 @@ class PagedKVCache:
         self.max_seq_len = int(max_seq_len)
         self.max_pages_per_seq = -(-self.max_seq_len // self.page_size)
         self.dtype = jnp.dtype(dtype)
-        shape = (self.num_layers, self.num_pages, self.page_size,
-                 self.num_heads, self.head_dim)
-        self.k_pool = jnp.zeros(shape, self.dtype)
-        self.v_pool = jnp.zeros(shape, self.dtype)
+        self.k_pool = jnp.zeros(self.pool_shape, self.dtype)
+        self.v_pool = jnp.zeros(self.pool_shape, self.dtype)
         # page 0 = scratch; everything else starts free
         self._free = collections.deque(range(1, self.num_pages))
         self._used = 0
@@ -169,6 +183,18 @@ class PagedKVCache:
         self.live_seqs = None
         _pages_total.set(self.num_pages - 1)
         self._publish(0)
+
+    @property
+    def pool_shape(self):
+        """``(L, num_pages, page_size, H*D)`` — the stored shape of each
+        pool."""
+        return self.pages_shape(self.num_pages)
+
+    def pages_shape(self, n):
+        """Shape of ``n`` pages of every layer, ``pool[:, idx]``: what a
+        handoff packet, a parked session or a scrub holds."""
+        return (self.num_layers, int(n), self.page_size,
+                self.num_heads * self.head_dim)
 
     def reset_pools(self, force=False):
         """Reallocate zeroed pools (allocator state untouched).  The
@@ -199,10 +225,8 @@ class PagedKVCache:
                     "reset_pools would zero %d allocated page(s) with no "
                     "live_seqs callback installed; pass force=True if "
                     "their owners are already failed" % self._used)
-        shape = (self.num_layers, self.num_pages, self.page_size,
-                 self.num_heads, self.head_dim)
-        self.k_pool = jnp.zeros(shape, self.dtype)
-        self.v_pool = jnp.zeros(shape, self.dtype)
+        self.k_pool = jnp.zeros(self.pool_shape, self.dtype)
+        self.v_pool = jnp.zeros(self.pool_shape, self.dtype)
         self._index.clear()
         self._hash_of_page.clear()
         for p in self._lru:
@@ -227,8 +251,7 @@ class PagedKVCache:
         if not scrub:
             return
         idx = jnp.asarray(scrub, jnp.int32)
-        zero = jnp.zeros((self.num_layers, len(scrub), self.page_size,
-                          self.num_heads, self.head_dim), self.dtype)
+        zero = jnp.zeros(self.pages_shape(len(scrub)), self.dtype)
         self.k_pool = self.k_pool.at[:, idx].set(zero)
         self.v_pool = self.v_pool.at[:, idx].set(zero)
         for p in scrub:

@@ -427,7 +427,7 @@ def corrupt_kv_page(scheduler, seq=None, after_tokens=1):
             if page == 0:
                 continue
             cache = scheduler._cache
-            cache.k_pool = cache.k_pool.at[:, page, 0, 0, 0].set(jnp.nan)
+            cache.k_pool = cache.k_pool.at[:, page, 0, 0].set(jnp.nan)
             fired[0] += 1
             return
 

@@ -79,10 +79,17 @@ class TestPagedKVCache:
         k = jnp.asarray(np.random.RandomState(0).randn(2, 8, 2, 4)
                         .astype(np.float32))
         v = k + 1
+        # stored shape: layers stacked, heads FOLDED head-major into the
+        # last axis (head h = [..., h*D:(h+1)*D]); the page axis is axis 1
+        assert c.k_pool.shape == c.v_pool.shape == c.pool_shape == (2, 5, 4, 8)
+        assert c.pages_shape(3) == (2, 3, 4, 8)
         kp, vp = serving.write_prompt_kv(c.k_pool, c.v_pool, k, v,
                                          jnp.asarray([2, 3], np.int32))
+        assert kp.shape == c.pool_shape
         np.testing.assert_array_equal(
             np.asarray(kp)[:, 2:4].reshape(2, 8, 2, 4), np.asarray(k))
+        np.testing.assert_array_equal(          # head 1 of token 5 = page 3
+            np.asarray(kp)[:, 3, 1, 4:8], np.asarray(k)[:, 5, 1])
         tok_k = jnp.ones((2, 3, 2, 4), jnp.float32)  # S=3 slots
         kp2, vp2 = serving.write_token_kv(
             kp, vp, tok_k, tok_k * 2,
@@ -90,6 +97,28 @@ class TestPagedKVCache:
                                                           np.int32))
         assert (np.asarray(kp2)[:, 1, 2] == 1).all()
         assert (np.asarray(vp2)[:, 4, 0] == 2).all()
+
+    @pytest.mark.parametrize("op", ["reset", "scrub"])
+    def test_pool_shape_survives_reset_and_scrub(self, op):
+        import jax.numpy as jnp
+
+        from paddle_tpu.parallel.flash_attention import paged_kv_finite
+
+        c = serving.PagedKVCache(2, num_pages=5, page_size=4, num_heads=2,
+                                 head_dim=4, max_seq_len=16)
+        pages = c.alloc(2)
+        c.k_pool = c.k_pool.at[1, pages[0], 3, 7].set(jnp.nan)
+        sweep = jnp.asarray(pages + [0], jnp.int32)
+        assert np.asarray(paged_kv_finite(c.k_pool, c.v_pool, sweep)
+                          ).tolist() == [False, True, True]
+        if op == "reset":
+            c.reset_pools(force=True)
+        else:
+            c.scrub_pages(pages)
+        assert c.k_pool.shape == c.v_pool.shape == c.pool_shape
+        assert c.k_pool.dtype == c.dtype
+        assert np.asarray(paged_kv_finite(c.k_pool, c.v_pool, sweep)).all()
+        assert not np.asarray(c.k_pool).any()
 
 
 # -- scheduler ---------------------------------------------------------------

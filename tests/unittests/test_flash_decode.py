@@ -214,3 +214,125 @@ class TestPagedPrefillAttention:
             jnp.asarray(inv[np.asarray(pages)].astype(np.int32)),
             start, impl="reference"))
         assert out1.tobytes() == out2.tobytes()
+
+
+class TestStackedFoldedPools:
+    """The form the step programs use: the cache's STORED pools
+    ``[L, P, ps, H*Dh]`` (heads folded head-major into the lanes) plus a
+    static ``layer``, addressed in place by (layer, page) — against the
+    reference over that layer's unfolded pool, for every layer of a
+    3-layer stack whose layers all differ (a wrong ``layer`` reads another
+    layer's pages and fails)."""
+
+    L, P, ps, H, Dh = 3, 11, 4, 2, 8
+
+    def _stack(self, seed, dtype=jnp.float32):
+        rng = np.random.RandomState(seed)
+        shape = (self.L, self.P, self.ps, self.H, self.Dh)
+        k5 = jnp.asarray(rng.randn(*shape).astype(np.float32)).astype(dtype)
+        v5 = jnp.asarray(rng.randn(*shape).astype(np.float32)).astype(dtype)
+        fold = shape[:3] + (self.H * self.Dh,)
+        return k5, v5, k5.reshape(fold), v5.reshape(fold)
+
+    def _decode_args(self, seed, qdtype):
+        rng = np.random.RandomState(100 + seed)
+        q = jnp.asarray(rng.randn(4, self.H, self.Dh).astype(np.float32))
+        pt = jnp.asarray(np.array([[1, 2, 3], [4, 0, 0], [5, 6, 7],
+                                   [0, 0, 0]], np.int32))
+        lens = jnp.asarray(np.array([11, 3, 12, 0], np.int32))
+        return q.astype(qdtype), pt, lens
+
+    def _prefill_args(self, seed, qdtype):
+        rng = np.random.RandomState(200 + seed)
+        q = jnp.asarray(rng.randn(8, self.H, self.Dh).astype(np.float32))
+        return (q.astype(qdtype), jnp.asarray(np.array([1, 3, 5, 7],
+                                                       np.int32)), 4)
+
+    @pytest.mark.parametrize("qdtype", [jnp.float32, jnp.bfloat16],
+                             ids=["q-f32", "q-bf16"])
+    @pytest.mark.parametrize("layer", [0, 1, 2])
+    def test_decode_kernel_every_layer(self, layer, qdtype):
+        k5, v5, kf, vf = self._stack(seed=layer)
+        q, pt, lens = self._decode_args(layer, qdtype)
+        ref = np.asarray(paged_decode_attention(
+            q.astype(jnp.float32), k5[layer], v5[layer], pt, lens,
+            impl="reference"))
+        pal = paged_decode_attention(q, kf, vf, pt, lens, impl="pallas",
+                                     interpret=True, layer=layer)
+        assert pal.dtype == qdtype and pal.shape == q.shape
+        pal = np.asarray(pal.astype(jnp.float32))
+        tol = 2e-6 if qdtype == jnp.float32 else 2e-2
+        np.testing.assert_allclose(pal, ref, atol=tol)
+        assert (pal[3] == 0).all()              # kv_lens == 0: exact zeros
+        # the layers differ, so another layer's pages do not pass
+        other = np.asarray(paged_decode_attention(
+            q, kf, vf, pt, lens, impl="pallas", interpret=True,
+            layer=(layer + 1) % self.L).astype(jnp.float32))
+        assert np.abs(other[:3] - ref[:3]).max() > 0.05
+
+    @pytest.mark.parametrize("qdtype", [jnp.float32, jnp.bfloat16],
+                             ids=["q-f32", "q-bf16"])
+    @pytest.mark.parametrize("layer", [0, 1, 2])
+    def test_prefill_kernel_every_layer(self, layer, qdtype):
+        k5, v5, kf, vf = self._stack(seed=10 + layer)
+        q, pages, start = self._prefill_args(layer, qdtype)
+        ref = np.asarray(paged_prefill_attention(
+            q.astype(jnp.float32), k5[layer], v5[layer], pages, start,
+            impl="reference"))
+        pal = paged_prefill_attention(q, kf, vf, pages, jnp.int32(start),
+                                      impl="pallas", interpret=True,
+                                      layer=layer)
+        assert pal.dtype == qdtype and pal.shape == q.shape
+        pal = np.asarray(pal.astype(jnp.float32))
+        tol = 2e-6 if qdtype == jnp.float32 else 2e-2
+        np.testing.assert_allclose(pal, ref, atol=tol)
+        other = np.asarray(paged_prefill_attention(
+            q, kf, vf, pages, jnp.int32(start), impl="pallas",
+            interpret=True, layer=(layer + 1) % self.L).astype(jnp.float32))
+        assert np.abs(other - ref).max() > 0.05
+
+    @pytest.mark.parametrize("impl", ["reference", "pallas"])
+    @pytest.mark.parametrize("kv_dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["kv-f32", "kv-bf16"])
+    def test_one_layer_entry_equals_stacked_bitwise_decode(self, kv_dtype,
+                                                           impl):
+        # the 4-D entry folds into a one-layer stack and runs the SAME
+        # engine: what the smokes check is what the step programs serve
+        k5, v5, kf, vf = self._stack(seed=20, dtype=kv_dtype)
+        q, pt, lens = self._decode_args(0, jnp.float32)
+        for layer in range(self.L):
+            one = np.asarray(paged_decode_attention(
+                q, k5[layer], v5[layer], pt, lens, impl=impl))
+            stacked = np.asarray(paged_decode_attention(
+                q, kf, vf, pt, lens, impl=impl, layer=layer))
+            assert one.tobytes() == stacked.tobytes()
+
+    @pytest.mark.parametrize("impl", ["reference", "pallas"])
+    @pytest.mark.parametrize("kv_dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["kv-f32", "kv-bf16"])
+    def test_one_layer_entry_equals_stacked_bitwise_prefill(self, kv_dtype,
+                                                            impl):
+        k5, v5, kf, vf = self._stack(seed=21, dtype=kv_dtype)
+        q, pages, start = self._prefill_args(0, jnp.float32)
+        for layer in range(self.L):
+            one = np.asarray(paged_prefill_attention(
+                q, k5[layer], v5[layer], pages, start, impl=impl))
+            stacked = np.asarray(paged_prefill_attention(
+                q, kf, vf, pages, start, impl=impl, layer=layer))
+            assert one.tobytes() == stacked.tobytes()
+
+    @pytest.mark.parametrize("fn", ["decode", "prefill"])
+    def test_pool_shape_must_match_the_form(self, fn):
+        k5, v5, kf, vf = self._stack(seed=22)
+        if fn == "decode":
+            q, pt, lens = self._decode_args(0, jnp.float32)
+            call = lambda k, v, **kw: paged_decode_attention(  # noqa: E731
+                q, k, v, pt, lens, impl="reference", **kw)
+        else:
+            q, pages, start = self._prefill_args(0, jnp.float32)
+            call = lambda k, v, **kw: paged_prefill_attention(  # noqa: E731
+                q, k, v, pages, start, impl="reference", **kw)
+        with pytest.raises(ValueError, match="one layer's"):
+            call(kf, vf)                       # a stack without layer=
+        with pytest.raises(ValueError, match="stored stack"):
+            call(k5[0], v5[0], layer=0)        # an unfolded pool with layer=

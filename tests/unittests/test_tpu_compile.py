@@ -11,6 +11,9 @@ tests call the kernel entry points with ``interpret=False``.  The topology is
 described inside a module-scoped fixture (only the xdist worker that is given
 this file loads the TPU library), and everything compiles in this process.
 """
+import contextlib
+import re
+
 import numpy as np
 import pytest
 
@@ -33,8 +36,8 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture()
-def no_persistent_cache():
+@contextlib.contextmanager
+def _persistent_cache_off():
     """A compile for a described chip is written to the persistent cache but
     cannot be read back without the chip; keep it off around these."""
     from jax.experimental.compilation_cache import compilation_cache as cc
@@ -42,9 +45,17 @@ def no_persistent_cache():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", was)
-    cc.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+
+
+@pytest.fixture()
+def no_persistent_cache():
+    with _persistent_cache_off():
+        yield
 
 
 def _kernel_calls(fn, *args):
@@ -127,3 +138,99 @@ def test_interpret_follows_the_backend_in_one_place():
     with pytest.raises(Exception):
         jax.block_until_ready(FA.paged_decode_attention(
             *args, impl="pallas", interpret=False))
+
+
+# ---------------------------------------------------------------------------
+# The REAL step programs at the widths of the benchmark's `tfbase_lm_chat`
+# cell: 64 slots, 6 layers, 8193 pages of 16 tokens, H*Dh = 512, bf16 pools,
+# donated.  What is checked is that a step never materialises anything
+# pool-sized or layer-sized: the pools are stored `[L, P, ps, H*Dh]`, whose
+# device layout is the row-major one the kernels read, and the kernels
+# address the stack in place by (layer, page).  A 5-D `[.., H, Dh]` pool made
+# every step copy both 805 MB pools twice and slice out 12 layers (PERF.md).
+# ---------------------------------------------------------------------------
+_L, _P, _V, _DM, _DI = 6, _S * _MP + 1, 30000, _H * _DH, 2048
+_POOL_ELEMS = _L * _P * _PS * _DM
+_STEP_PROGRAMS = ("decode", "chunk128", "chunk512")
+
+
+@pytest.fixture(scope="module")
+def step_programs(one_chip):
+    """name -> compiled step program, each compiled once for the module.
+    The pools are parameters 0 and 1, donated as the scheduler donates
+    them; the kernels compile (no interpret) because the one predicate
+    says "not the CPU" while these lower."""
+    from paddle_tpu.models import transformer as T
+
+    def sds(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def decode(k_pool, v_pool, params, tokens, positions, tables, kv_lens):
+        return T.lm_decode_step(params, tokens, positions, k_pool, v_pool,
+                                tables, kv_lens, n_head=_H)
+
+    def chunk(k_pool, v_pool, params, tokens, start, valid, chunk_pages,
+              gather_pages):
+        return T.lm_prefill_chunk(params, tokens, start, valid, k_pool,
+                                  v_pool, chunk_pages, gather_pages,
+                                  n_head=_H)
+
+    pool = sds((_L, _P, _PS, _DM), jnp.bfloat16)
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        T.lm_params(vocab_size=_V, n_layer=_L, n_head=_H, d_model=_DM,
+                    d_inner=_DI, max_length=_MP * _PS)[0])
+    args = {"decode": (decode, (sds((_S,)), sds((_S,)), sds((_S, _MP)),
+                                sds((_S,))))}
+    for c in (128, 512):   # _chunk_widths() of chunk 512, buckets 128/512/2048
+        args["chunk%d" % c] = (chunk, (sds((c,)), sds(()), sds(()),
+                                       sds((c // _PS,)), sds((_MP,))))
+    with _persistent_cache_off(), pytest.MonkeyPatch.context() as mp:
+        mp.setattr(FA, "cpu_backend", lambda: False)
+        return {name: jax.jit(fn, donate_argnums=(0, 1)).lower(
+                    pool, pool, params, *rest).compile()
+                for name, (fn, rest) in args.items()}
+
+
+def _pool_sized(hlo_text):
+    """Instructions that copy or slice something of the whole pool's or one
+    layer's element count: `(name, opcode, dims)` each."""
+    found = []
+    for m in re.finditer(
+            r"%(\S+) = \w+\[([0-9,]+)\]\S* ([\w-]+)\(", hlo_text):
+        name, dims, opcode = m.groups()
+        n = int(np.prod([int(d) for d in dims.split(",")]))
+        if n in (_POOL_ELEMS, _POOL_ELEMS // _L) and (
+                opcode in ("copy", "slice", "dynamic-slice")
+                or "copy" in name or "slice" in name):
+            found.append((name, opcode, dims))
+    return found
+
+
+@pytest.mark.parametrize("program", _STEP_PROGRAMS)
+def test_step_program_has_no_pool_sized_copy_or_slice(step_programs, program):
+    text = step_programs[program].as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == _L
+    assert _pool_sized(text) == []
+
+
+@pytest.mark.parametrize("program", _STEP_PROGRAMS)
+def test_step_program_aliases_both_pools(step_programs, program):
+    head = step_programs[program].as_text().split("\n", 1)[0]
+    alias = re.search(r"input_output_alias=\{(.*?)\}, entry", head).group(1)
+    assert sorted(int(p) for p in re.findall(r"\((\d+), \{\}", alias)) == [0, 1]
+
+
+@pytest.mark.parametrize("program", _STEP_PROGRAMS)
+def test_step_program_pool_layout_is_row_major(step_programs, program):
+    layouts = re.findall(r"bf16\[%d,%d,%d,%d\]\{([0-9,]+)" % (
+        _L, _P, _PS, _DM), step_programs[program].as_text())
+    # 2 parameters and 2 results at least, and the in-place scatters between
+    assert len(layouts) >= 4 and set(layouts) == {"3,2,1,0"}
+
+
+@pytest.mark.parametrize("program", _STEP_PROGRAMS)
+def test_step_program_temporaries_are_small(step_programs, program):
+    mem = step_programs[program].memory_analysis()
+    assert mem.alias_size_in_bytes == 2 * 2 * _POOL_ELEMS   # both bf16 pools
+    assert mem.temp_size_in_bytes < 64 * 2 ** 20
