@@ -1,11 +1,11 @@
 """The chipbench harness on the CPU at toy widths: both drivers with the
 place passed in (``run.main`` is what refuses a CPU), the data-driven look-up,
 the traffic generator, the trace reduction on a recorded chip trace, the FLOP
-count against a hand count, and BENCHMARK.json against the contract's limits.
-No test needs a chip."""
+count against a hand count, and ``chipbench/contract.py`` on the repo's root, on
+a toy checkout that ADDS a configuration of another family by new files alone,
+and on copies of it that each break one rule.  No test needs a chip."""
 import json
 import os
-import re
 import shutil
 import sys
 
@@ -16,38 +16,126 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 sys.path.insert(0, ROOT)
 
 import paddle_tpu as fluid  # noqa: E402
-from chipbench import flops, peaks, run, trace_reduce, traffic  # noqa: E402
+from chipbench import contract, flops, peaks, run, trace_reduce, traffic  # noqa: E402
 from chipbench.registry import Registry  # noqa: E402
 
 TOY = dict(n_layer=2, n_head=2, d_model=32, d_inner=64, vocab=64)
+# a toy is not the model: its cut of the two real files cuts their published
+# blocks with their widths (the pin by name is on the repo's root, below)
+TOY_PUBLISHED = dict(TOY, vocab=37000)
 RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+SERVE_PHASES = {"sched_iteration_ms", "sched_host_ms", "decode_wait_ms",
+                "prefill_chunk_ms", "chunk_iteration_share_pct", "admit_ms",
+                "setup_warmup_s"}
+FIRST_CELLS = ("tfbase_train_s256", "tfbase_lm_chat", "tfbase_train_s2048")
+FAMILY = "chipbench/configs/toy_family.json"
+# a family of its own: other key names for its sizes, a model file that builds
+# the system from them (here by handing them to the LM's builders)
+TOY_FAMILY_MODEL = '''
+from chipbench.models import transformer_lm as lm
+
+PAGED_RTOL, TIE_TOL = lm.PAGED_RTOL, lm.TIE_TOL
+
+
+def _sizes(cfg):
+    return dict(cfg, n_layer=cfg["layers"], n_head=cfg["heads"],
+                d_model=cfg["hidden"], d_inner=cfg["ffn"])
+
+
+def make_params(cfg, seed):
+    return lm.make_params(_sizes(cfg), seed)
+
+
+def build_engine(cfg, params, meta, max_new_tokens):
+    return lm.build_engine(_sizes(cfg), params, meta, max_new_tokens)
+
+
+def paged_kernel_errors(cfg, seed, reference):
+    return lm.paged_kernel_errors(_sizes(cfg), seed, reference)
+
+
+def token_gaps(cfg, params, samples, reference):
+    return lm.token_gaps(_sizes(cfg), params, samples, reference)
+'''
+TOY_FAMILY = {
+    "source": "test", "driver": "serve", "model": "toy_family",
+    "layers": 2, "heads": 2, "hidden": 32, "ffn": 64, "vocab": 64,
+    "slots": 2, "max_seq_len": 128, "page": 16, "num_pages": None,
+    "kv_dtype": "bfloat16", "chunk": 32, "buckets": [16, 32, 128],
+    "prefix_cache": True, "queue_capacity": 4096,
+    "published": {"layers": 4, "heads": 2, "hidden": 32, "ffn": 64, "vocab": 64},
+    "reduced": ["layers"],
+    "reduced_why": {"layers": "2 of the 4: one period of its pattern"},
+    "assumed": {"slots": "the source fixes no number of sequences"},
+}
+
+
+DROP = object()
 
 
 def _edit(root, rel, **changes):
+    """Set keys of the JSON file ``rel``; the value ``DROP`` takes a key out."""
     path = os.path.join(root, rel)
     with open(path) as f:
         data = json.load(f)
     data.update(changes)
     with open(path, "w") as f:
-        json.dump(data, f)
+        json.dump({k: v for k, v in data.items() if v is not DROP}, f)
+
+
+def _edit_bench(root, change):
+    path = os.path.join(root, "BENCHMARK.json")
+    with open(path) as f:
+        bench = json.load(f)
+    change(bench)
+    with open(path, "w") as f:
+        json.dump(bench, f)
+
+
+def _entry(bench, table, name):
+    return next(e for e in bench[table] if e["name"] == name)
+
+
+def _keep_cells(bench, keep):
+    """Cut ``bench`` to the cells ``keep`` and to the metrics and the
+    configurations they use."""
+    bench["workloads"] = [w for w in bench["workloads"] if w["name"] in keep]
+    for table in ("end_to_end", "per_layer"):
+        for m in bench[table]:
+            if "workloads" in m:
+                m["workloads"] = [c for c in m["workloads"] if c in keep]
+        bench[table] = [m for m in bench[table] if m.get("workloads", True)]
+    used = {w["config"] for w in bench["workloads"]}
+    bench["configs"] = [c for c in bench["configs"] if c["name"] in used]
+
+
+def _metrics_of(bench, cell):
+    """The metric entries that list ``cell`` by name."""
+    return [m for m in bench["end_to_end"] + bench["per_layer"]
+            if cell in m.get("workloads", ())]
 
 
 @pytest.fixture(scope="module")
 def toy_root(tmp_path_factory):
-    """A checkout in miniature: BENCHMARK.json and a copy of chipbench/ whose
-    configuration and traffic files are cut to toy sizes, plus one cell, one
-    configuration and one per-layer metric ADDED as new files only."""
+    """A checkout in miniature: the benchmark's first three cells (what a
+    later PR has added stays on the repo's root, at its own sizes) over a copy
+    of chipbench/ whose configuration and traffic files are cut to toy sizes,
+    plus what later PRs ADD as new files and entries only: a cell, a
+    configuration and a per-layer metric; and a configuration of ANOTHER
+    FAMILY with its model file, its reference and a serving cell that lists
+    itself under every metric ``tfbase_lm_chat`` reports."""
     root = str(tmp_path_factory.mktemp("toy_checkout"))
     shutil.copytree(os.path.join(ROOT, "chipbench"),
                     os.path.join(root, "chipbench"),
                     ignore=shutil.ignore_patterns("__pycache__"))
     shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root)
     _edit(root, "chipbench/configs/transformer_base_train.json", **TOY,
-          first_loss_rtol=0.15)
+          published=TOY_PUBLISHED, first_loss_rtol=0.15)
     _edit(root, "chipbench/traffic/b64_s256.json", batch=4, seq=16,
           warmup_steps=2, sync_every=4, trace_after_s=0.1, trace_steps=4)
-    _edit(root, "chipbench/configs/transformer_base_lm.json", **TOY, slots=4,
-          max_seq_len=128, page=16, chunk=32, buckets=[16, 32, 128])
+    _edit(root, "chipbench/configs/transformer_base_lm.json", **TOY,
+          published=TOY_PUBLISHED, slots=4, max_seq_len=128, page=16, chunk=32,
+          buckets=[16, 32, 128])
     _edit(root, "chipbench/traffic/chat.json", rate_rps=20.0, max_prompt=80,
           prompt_len={"dist": "lognormal", "median": 20, "sigma": 0.9,
                       "min": 4, "max": 60},
@@ -67,21 +155,35 @@ def toy_root(tmp_path_factory):
     with open(os.path.join(root, "chipbench/layer_metrics/completed_share_pct.py"), "w") as f:
         f.write("def read(observed):\n"
                 "    return 100.0 * observed['completed'] / observed['attempted']\n")
-    with open(os.path.join(root, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    bench["configs"].append({"name": "toy_lm", "source": "test", "reduced": [],
-                             "file": "chipbench/configs/toy_lm.json", "why": "test"})
-    bench["workloads"].append({"name": "toy_lm_trickle", "config": "toy_lm",
-                               "traffic": "trickle", "chips": 1, "why": "test"})
-    for m in bench["end_to_end"]:
-        if m["name"] in ("serve_tokens_per_s", "itl_p95_ms"):
-            m["workloads"].append("toy_lm_trickle")
-    bench["per_layer"].append({
-        "name": "completed_share_pct", "unit": "%", "better": "higher",
-        "source": "program_counter", "layer": "serving front door",
-        "moves": "serve_tokens_per_s", "workloads": ["toy_lm_trickle"]})
-    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
-        json.dump(bench, f)
+    with open(os.path.join(root, "chipbench/models/toy_family.py"), "w") as f:
+        f.write(TOY_FAMILY_MODEL)
+    with open(os.path.join(root, FAMILY), "w") as f:
+        json.dump(TOY_FAMILY, f)
+    shutil.copy(os.path.join(root, "chipbench/configs/transformer_base_lm.reference.py"),
+                os.path.join(root, "chipbench/configs/toy_family.reference.py"))
+
+    def add(bench):
+        _keep_cells(bench, FIRST_CELLS)
+        bench["configs"] += [
+            {"name": "toy_lm", "source": "test", "reduced": ["vocab"],
+             "file": "chipbench/configs/toy_lm.json", "why": "test"},
+            {"name": "toy_family", "source": "test", "reduced": ["layers"],
+             "file": FAMILY, "why": "test"}]
+        bench["workloads"] += [
+            {"name": "toy_lm_trickle", "config": "toy_lm",
+             "traffic": "trickle", "chips": 1, "why": "test"},
+            {"name": "toy_family_trickle", "config": "toy_family",
+             "traffic": "trickle", "chips": 1, "why": "test"}]
+        for m in _metrics_of(bench, "tfbase_lm_chat"):
+            m["workloads"].append("toy_family_trickle")
+        for name in ("serve_tokens_per_s", "itl_p95_ms"):
+            _entry(bench, "end_to_end", name)["workloads"].append("toy_lm_trickle")
+        bench["per_layer"].append({
+            "name": "completed_share_pct", "unit": "%", "better": "higher",
+            "source": "program_counter", "layer": "serving front door",
+            "moves": "serve_tokens_per_s", "workloads": ["toy_lm_trickle"]})
+
+    _edit_bench(root, add)
     return root
 
 
@@ -133,6 +235,21 @@ def test_new_cell_config_and_metric_are_found_as_new_files(toy_root):
     assert out["attempted"] == 8 and out["correct"]
     assert out["metrics"]["completed_share_pct"] == {"value": 100.0, "unit": "%"}
     assert "prefix_hit_pct" not in out["metrics"]       # not this cell's
+
+
+@pytest.mark.parametrize("trace", [0, 1])
+def test_a_configuration_of_a_new_family_is_served_from_new_files_alone(
+        toy_root, trace):
+    out = run.run_cell("toy_family_trickle", 2 ** 31 + 11, 1.0, trace,
+                       fluid.CPUPlace(), root=toy_root)
+    names = _check_line(out, Registry(toy_root), "toy_family_trickle",
+                        "per_layer" if trace else "end_to_end")
+    assert out["attempted"] == 8
+    if trace:
+        assert SERVE_PHASES | {"decode_step_ms", "prefix_hit_pct"} <= names
+        assert "completed_share_pct" not in names       # not this cell's
+    else:
+        assert names == {"serve_tokens_per_s", "itl_p95_ms", "setup_s"}
 
 
 def test_token_check_sees_a_wrong_token_from_the_decode_loop(toy_root):
@@ -256,63 +373,167 @@ def test_flops_against_the_hand_count():
         peaks.peak("cpu", "bf16_flops")
 
 
-NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
-UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+# Vaswani et al. 2017, table 3, row "base", and section 5.1's EN-DE vocabulary:
+# the two configurations are held to these through their own files' published
+# blocks, which the contract compares with the sizes as run
+PUBLISHED_BASE = {"n_layer": 6, "n_head": 8, "d_model": 512, "d_inner": 2048,
+                  "vocab": 37000}
+BASE_REDUCED = ["vocab"]
 
 
-def test_benchmark_json_is_within_the_contract():
+def test_benchmark_json_is_within_the_contract(toy_root):
+    assert contract.violations(ROOT) == []
+    assert contract.violations(toy_root) == []
     reg = Registry(ROOT)
-    b = reg.bench
-    assert set(b) == {"command", "paths", "run_seconds", "configs",
-                      "workloads", "end_to_end", "per_layer"}
-    assert 1 <= b["run_seconds"] <= 51 and isinstance(b["run_seconds"], int)
-    names = [e["name"] for t in ("configs", "workloads", "end_to_end",
-                                 "per_layer") for e in b[t]]
-    assert all(NAME.match(n) for n in names)
-    for t in ("configs", "workloads"):
-        assert len({e["name"] for e in b[t]}) == len(b[t])
-    metrics = b["end_to_end"] + b["per_layer"]
-    assert len({m["name"] for m in metrics}) == len(metrics)
-    e2e = {m["name"] for m in b["end_to_end"]}
-    assert "setup_s" in e2e
-    cells = {w["name"] for w in b["workloads"]}
-    for m in metrics:
-        assert UNIT.match(m["unit"]) and m["better"] in ("lower", "higher")
-        assert set(m.get("workloads", cells)) <= cells
-    for m in b["end_to_end"]:
-        assert set(m) <= {"name", "unit", "better", "bound", "source", "workloads"}
-        assert 0.01 <= m["bound"] <= 0.1
-        assert m["source"] in ("host_clock", "device_trace")
-    for m in b["per_layer"]:
-        assert set(m) <= {"name", "unit", "better", "source", "layer", "moves",
-                          "workloads"}
-        assert m["moves"] in e2e
-        assert m["source"] in ("device_trace", "program_span",
-                               "program_counter", "host_clock")
-        # its reader is a file of its own, found by name
-        assert callable(reg.module("layer_metrics", m["name"]).read)
-    for w in b["workloads"]:
-        assert set(w) == {"name", "config", "traffic", "chips", "why"}
-        assert w["chips"] in (1, 4) and len(w["why"]) <= 200
-        assert NAME.match(w["traffic"]) and reg.traffic(w["traffic"])
-        cell = w["name"]
-        own = {m["name"] for m in reg.metrics("end_to_end", cell)}
-        assert "setup_s" in own and len(own) >= 2
-        assert reg.metrics("per_layer", cell)
-        # a per-layer metric moves an end-to-end metric its cells report
-        for m in reg.metrics("per_layer", cell):
-            assert m["moves"] in own, (cell, m["name"])
-    for c in b["configs"]:
-        assert set(c) == {"name", "source", "file", "reduced", "why"}
-        assert len(c["why"]) <= 200 and len(c["source"]) <= 200
-        assert c["file"].startswith(tuple(p + "/" for p in b["paths"]))
-        cfg = reg.config(c["name"])
-        # the vocabulary is the repo's 30000 against the paper's 37000, and
-        # says so; widths as published (Vaswani et al. 2017, table 3, "base")
-        assert cfg["reduced"] == c["reduced"] == ["vocab"]
-        assert set(cfg["reduced_why"]) == {"vocab"}
-        assert (cfg["n_layer"], cfg["n_head"], cfg["d_model"],
-                cfg["d_inner"]) == (6, 8, 512, 2048)
-        assert reg.reference(c["name"])
-        assert any(w["config"] == c["name"] for w in b["workloads"])
-    assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) < 64 * 1024
+    for name in ("transformer_base_train", "transformer_base_lm"):
+        cfg = reg.config(name)
+        assert cfg["published"] == PUBLISHED_BASE
+        assert cfg["reduced"] == BASE_REDUCED and cfg["vocab"] == 30000
+
+
+def _more_cells(bench, n, root):
+    """``n`` cells more, each like ``toy_lm_trickle`` under a mix of its own."""
+    for i in range(n):
+        shutil.copy(os.path.join(root, "chipbench/traffic/trickle.json"),
+                    os.path.join(root, "chipbench/traffic/trickle_%d.json" % i))
+        cell = "toy_lm_trickle_%d" % i
+        bench["workloads"].append(dict(_entry(bench, "workloads", "toy_lm_trickle"),
+                                       name=cell, traffic="trickle_%d" % i))
+        for m in _metrics_of(bench, "toy_lm_trickle"):
+            m["workloads"].append(cell)
+
+
+def _rename_metric(bench, root, old, new):
+    _entry(bench, "per_layer", old)["name"] = new
+    os.rename(os.path.join(root, "chipbench/layer_metrics", old + ".py"),
+              os.path.join(root, "chipbench/layer_metrics", new + ".py"))
+
+
+def _drop_cell(bench, cell):
+    bench["workloads"].remove(_entry(bench, "workloads", cell))
+    for m in _metrics_of(bench, cell):
+        m["workloads"].remove(cell)
+
+
+def _json(rel, **changes):
+    return lambda root: _edit(root, rel, **changes)
+
+
+def _bench(change):
+    """``change(bench, root)`` on the copy's BENCHMARK.json."""
+    return lambda root: _edit_bench(root, lambda b: change(b, root))
+
+
+def _set(table, name, **changes):
+    return _bench(lambda b, _: _entry(b, table, name).update(changes))
+
+
+# one rule broken in a copy of the toy checkout -> the one violation it gives
+BREAKS = {
+    "a width off published and not in reduced": (
+        _json(FAMILY, hidden=48),
+        "toy_family.json: hidden is 48, published 32, and is not in reduced"),
+    "a Transformer-base width off its published block": (
+        _json("chipbench/configs/transformer_base_train.json", d_inner=128),
+        "transformer_base_train.json: d_inner is 128, published 64"),
+    "a reduced name with no reduced_why": (
+        _json(FAMILY, reduced_why={}),
+        "reduced names layers, reduced_why has no line for it"),
+    "a reduced_why for a key that is not reduced": (
+        _json(FAMILY, reduced_why=dict(TOY_FAMILY["reduced_why"], ffn="x")),
+        "reduced_why has ffn, which reduced does not name"),
+    "a reduced key that is as published": (
+        _json(FAMILY, layers=4), "reduced names layers, which is as published"),
+    "the file's reduced is not its entry's": (
+        _json(FAMILY, reduced=[]), "reduced [], its BENCHMARK.json entry has"),
+    "a key both assumed and published": (
+        _json(FAMILY, assumed={"hidden": "guessed"}),
+        "hidden is both assumed and published"),
+    "no published block": (
+        _json(FAMILY, published=DROP), "toy_family.json: no published block"),
+    "a second 4-chip cell among fewer than eight": (
+        _bench(lambda b, _: [w.update(chips=4) for w in b["workloads"][:2]]),
+        "2 of 5 cells ask for 4 chips"),
+    "a 25th cell": (
+        _bench(lambda b, root: _more_cells(b, 20, root)),
+        "workloads: 25 entries, not 1 to 24"),
+    "a per-layer metric with no list": (
+        _bench(lambda b, _: _entry(b, "per_layer", "sched_host_ms").pop("workloads")),
+        "per_layer sched_host_ms: lacks ['workloads']"),
+    "a per-layer metric with an empty list": (
+        _set("per_layer", "prefix_hit_pct", workloads=[]),
+        "per_layer prefix_hit_pct: workloads is []"),
+    "a train cell under a phase that moves itl_p95_ms": (
+        _bench(lambda b, _: _entry(b, "per_layer", "sched_iteration_ms")[
+            "workloads"].append("tfbase_train_s256")),
+        "sched_iteration_ms: lists tfbase_train_s256, which does not report "
+        "itl_p95_ms"),
+    "a metric that lists no cell of the benchmark": (
+        _bench(lambda b, _: _entry(b, "per_layer", "admit_ms")[
+            "workloads"].append("tfbase_lm_longctx")),
+        "admit_ms: lists ['tfbase_lm_longctx'], no cells of the benchmark"),
+    "a 65-letter name": (
+        _bench(lambda b, root: _rename_metric(b, root, "completed_share_pct",
+                                              "c" * 65)),
+        "per_layer: '%s' is not a name" % ("c" * 65)),
+    "a per-layer metric with no reader file": (
+        _bench(lambda b, _: b["per_layer"].append(dict(
+            _entry(b, "per_layer", "admit_ms"), name="evict_ms"))),
+        "per_layer evict_ms: no reader layer_metrics/evict_ms.py loads"),
+    "a key the contract does not have": (
+        _set("per_layer", "admit_ms", why="x"),
+        "per_layer admit_ms: keys ['why'] are not the contract's"),
+    "a bound over 0.1": (
+        _set("end_to_end", "itl_p95_ms", bound=0.2),
+        "end_to_end itl_p95_ms: bound 0.2 is not within 0.01 to 0.1"),
+    "a why of 201 characters": (
+        _set("workloads", "tfbase_lm_chat", why="y" * 201),
+        "workloads tfbase_lm_chat: why is not 1 to 200 characters"),
+    "a cell whose traffic file is missing": (
+        lambda root: os.remove(os.path.join(root, "chipbench/traffic/chat.json")),
+        "workloads tfbase_lm_chat: no file traffic/chat.json"),
+    "a configuration with no cell": (
+        _bench(lambda b, _: _drop_cell(b, "toy_family_trickle")),
+        "configs toy_family: no cell uses it"),
+    "a missing reference": (
+        lambda root: os.remove(os.path.join(
+            root, "chipbench/configs/toy_family.reference.py")),
+        "toy_family.json: no plain reference"),
+    "a driver that names no file": (
+        _json(FAMILY, driver="serve_sessions"),
+        "toy_family.json: driver 'serve_sessions' names no file"),
+    "a model that names no file": (
+        _json(FAMILY, model="hybrid_lm"),
+        "toy_family.json: model 'hybrid_lm' names no file"),
+    "a key beside the contract's seven": (
+        _bench(lambda b, _: b.update(notes="x")), "BENCHMARK.json: top-level keys"),
+    "run_seconds over what 24 cells leave room for": (
+        _bench(lambda b, _: b.update(run_seconds=52)),
+        "run_seconds: 52 is not a whole number from 1 to 51"),
+}
+
+
+@pytest.fixture
+def toy_copy(toy_root, tmp_path):
+    """A copy of the toy checkout that a test may break."""
+    root = str(tmp_path / "broken")
+    shutil.copytree(toy_root, root,
+                    ignore=shutil.ignore_patterns("__pycache__", "testdata"))
+    return root
+
+
+@pytest.mark.parametrize("case", sorted(BREAKS))
+def test_contract_names_the_one_rule_that_is_broken(toy_copy, case):
+    change, want = BREAKS[case]
+    change(toy_copy)
+    found = contract.violations(toy_copy)
+    assert len(found) == 1 and want in found[0], found
+
+
+def test_contract_main_prints_the_violations_and_exits_1(toy_root, toy_copy, capsys):
+    assert contract.main([toy_root]) == 0
+    assert capsys.readouterr().out == "0 violations\n"
+    _edit(toy_copy, FAMILY, hidden=48, driver="serve_sessions")
+    assert contract.main([toy_copy]) == 1
+    lines = capsys.readouterr().out.splitlines()
+    assert len(lines) == 3 and lines[-1] == "2 violations"
