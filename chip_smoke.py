@@ -478,12 +478,14 @@ def serve_lowers_to_kernels(sz, seed=0):
     S, ps, C = sz["slots"], sz["page"], sz["chunk"]
     mp = sz["max_seq_len"] // ps
     pool = jax.ShapeDtypeStruct((L, S * mp + 1, ps, H * Dh), jnp.bfloat16)
+    cache = {"k": pool, "v": pool}
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
     texts = {
         "decode": jax.jit(dm.decode_fn).lower(
-            i32(S), i32(S), pool, pool, i32(S, mp), i32(S)).as_text(),
+            dm.params, i32(S), i32(S), cache, i32(S, mp), i32(S)).as_text(),
         "prefill": jax.jit(dm.prefill_chunk_fn).lower(
-            i32(C), i32(), i32(), pool, pool, i32(C // ps), i32(mp)).as_text(),
+            dm.params, i32(C), i32(), i32(), cache, i32(C // ps), i32(mp),
+            i32()).as_text(),
     }
     return {k: t.count("tpu_custom_call") for k, t in texts.items()}
 

@@ -739,7 +739,7 @@ def lm_prefill(params, tokens, length, *, n_head, use_flash=False):
     x = emb[tokens] * np.sqrt(d_model) + params["pos_table"][:T]
     lens1 = jnp.reshape(jnp.asarray(length, jnp.int32), (1,))
     ks, vs = [], []
-    for lp in params["layers"]:
+    for lp in _lm_layers(params):
         q = (x @ lp["wq"]).reshape(T, n_head, dh)
         k = (x @ lp["wk"]).reshape(T, n_head, dh)
         v = (x @ lp["wv"]).reshape(T, n_head, dh)
@@ -757,21 +757,69 @@ def lm_prefill(params, tokens, length, *, n_head, use_flash=False):
     return last @ params["out_w"], jnp.stack(ks), jnp.stack(vs)
 
 
-def lm_prefill_chunk(params, tokens, start, valid, k_pool, v_pool,
-                     chunk_pages, gather_pages, *, n_head, attn_impl=None):
+# The served pytree holds EIGHT arrays whatever the depth: a dispatch on the
+# chip pays about 13 us an argument (45 arrays a dispatch read +5.5 to +7.7%
+# on `tfbase_lm_chat`'s `itl_p95_ms`, PERF.md PR 28), while slicing these small
+# matrices out of a stack costs the device a copy of 12 MB a layer.  (At
+# MiniCPM-SALA's widths the same slice copies 134 MB: there the matrices stay
+# one array a layer.)
+_LM_ATTN = ("wq", "wk", "wv", "wo")                      # [L, 4, d, d]
+_LM_VEC_D = ("ln1_s", "ln1_b", "ffn_b2", "ln2_s", "ln2_b")   # [L, 5, d]
+
+
+def lm_serving_params(params):
+    """``lm_params``' pytree as the serving steps take it: every per-layer
+    weight stacked by kind (``attn`` ``[L, 4, d, d]``, ``ffn_w1``, ``ffn_w2``,
+    ``vec_d`` ``[L, 5, d]``, ``ffn_b1``) beside the three tables."""
+    import jax.numpy as jnp
+
+    layers = params["layers"]
+
+    def stack(names):
+        return jnp.stack([jnp.stack([jnp.asarray(lp[n]) for n in names])
+                          for lp in layers])
+
+    return {
+        "tok_emb": params["tok_emb"], "pos_table": params["pos_table"],
+        "out_w": params["out_w"],
+        "attn": stack(_LM_ATTN), "vec_d": stack(_LM_VEC_D),
+        "ffn_w1": jnp.stack([jnp.asarray(lp["ffn_w1"]) for lp in layers]),
+        "ffn_w2": jnp.stack([jnp.asarray(lp["ffn_w2"]) for lp in layers]),
+        "ffn_b1": jnp.stack([jnp.asarray(lp["ffn_b1"]) for lp in layers]),
+    }
+
+
+def _lm_layers(params):
+    """Per-layer dicts whatever the form: ``lm_params``' own list, or the
+    stacks of ``lm_serving_params`` indexed by the (static) layer."""
+    if "attn" not in params:
+        return params["layers"]
+    out = []
+    for li in range(params["attn"].shape[0]):
+        lp = {n: params["attn"][li, j] for j, n in enumerate(_LM_ATTN)}
+        lp.update({n: params["vec_d"][li, j] for j, n in enumerate(_LM_VEC_D)})
+        lp.update({n: params[n][li] for n in ("ffn_w1", "ffn_w2", "ffn_b1")})
+        out.append(lp)
+    return out
+
+
+def lm_prefill_chunk(params, tokens, start, valid, cache, chunk_pages,
+                     gather_pages, slot=None, *, n_head, attn_impl=None):
     """One chunk of a prompt's prefill, resumable at any page boundary.
 
     ``tokens``: [C] int32 — the chunk's token window (pad tail
     arbitrary), absolute positions ``start .. start + C - 1``;
     ``valid``: real tokens in this window (the final chunk's tail is
-    pad); ``k_pool`` / ``v_pool``: the cache's stored stacks
+    pad); ``cache``: the cache's pytree, of which this model keeps
+    ``"k"`` and ``"v"``, the stored stacks
     ``[L, num_pages, page_size, H*Dh]`` (heads folded head-major);
     ``chunk_pages``: [C // page_size] int32 page ids this chunk's
     k/v scatter into (tail entries -> scratch); ``gather_pages``:
     [max_pages] int32 — the sequence's FULL page-table row, what the
-    chunk attends over.  Returns ``(last_logits [V], k_pool', v_pool')``
-    with ``last_logits`` at row ``valid - 1`` (position
-    ``start + valid - 1`` — only the final chunk's is meaningful).
+    chunk attends over; ``slot`` is unused (no slot state).  Returns
+    ``(last_logits [V], cache')`` with ``last_logits`` at row
+    ``valid - 1`` (position ``start + valid - 1`` — only the final
+    chunk's is meaningful).
 
     Per layer the chunk's k/v are scattered into the pool FIRST, then
     attention gathers through the page table
@@ -788,6 +836,7 @@ def lm_prefill_chunk(params, tokens, start, valid, k_pool, v_pool,
 
     from ..parallel.flash_attention import paged_prefill_attention
 
+    k_pool, v_pool = cache["k"], cache["v"]
     C = tokens.shape[0]
     ps = k_pool.shape[2]
     nb = C // ps
@@ -798,7 +847,7 @@ def lm_prefill_chunk(params, tokens, start, valid, k_pool, v_pool,
     positions = jnp.minimum(start + jnp.arange(C, dtype=jnp.int32),
                             pos_table.shape[0] - 1)
     x = emb[tokens] * np.sqrt(d_model) + pos_table[positions]
-    for li, lp in enumerate(params["layers"]):
+    for li, lp in enumerate(_lm_layers(params)):
         q = (x @ lp["wq"]).reshape(C, n_head, dh)
         # k/v rows are already the pool's folded page rows [H*Dh] (head h =
         # lanes h*dh:(h+1)*dh); scattered into the STACKED pool, which the
@@ -812,22 +861,23 @@ def lm_prefill_chunk(params, tokens, start, valid, k_pool, v_pool,
         x = _lm_block_tail(lp, x, ctx.reshape(C, d_model))
     last = jax.lax.dynamic_index_in_dim(x, valid - 1, axis=0,
                                         keepdims=False)
-    return last @ params["out_w"], k_pool, v_pool
+    return last @ params["out_w"], dict(cache, k=k_pool, v=v_pool)
 
 
-def lm_decode_step(params, tokens, positions, k_pool, v_pool, page_tables,
-                   kv_lens, *, n_head, attn_impl=None):
+def lm_decode_step(params, tokens, positions, cache, page_tables, kv_lens,
+                   *, n_head, attn_impl=None):
     """One decode iteration: token s of each slot at cache index
-    ``positions[s]``.  Writes k/v into the paged pools (the stored stacks
-    ``[L, num_pages, page_size, H*Dh]``), attends over each
-    slot's first ``kv_lens[s]`` cached tokens, returns
-    ``(logits [S, V], k_pool', v_pool')``.  ``kv_lens[s] == 0`` =
-    inactive slot (scratch-page write, zero attention, garbage logits
-    the scheduler ignores)."""
+    ``positions[s]``.  Writes k/v into the paged pools (``cache["k"]`` /
+    ``cache["v"]``, the stored stacks ``[L, num_pages, page_size, H*Dh]``),
+    attends over each slot's first ``kv_lens[s]`` cached tokens, returns
+    ``(logits [S, V], cache')``.  ``kv_lens[s] == 0`` = inactive slot
+    (scratch-page write, zero attention, garbage logits the scheduler
+    ignores)."""
     import jax.numpy as jnp
 
     from ..parallel.flash_attention import paged_decode_attention
 
+    k_pool, v_pool = cache["k"], cache["v"]
     S = tokens.shape[0]
     page_size = k_pool.shape[2]
     d_model = params["tok_emb"].shape[1]
@@ -837,7 +887,7 @@ def lm_decode_step(params, tokens, positions, k_pool, v_pool, page_tables,
     x = emb[tokens] * np.sqrt(d_model) + pos_table[positions]
     pages = page_tables[jnp.arange(S), positions // page_size]
     offsets = positions % page_size
-    for li, lp in enumerate(params["layers"]):
+    for li, lp in enumerate(_lm_layers(params)):
         q = (x @ lp["wq"]).reshape(S, n_head, dh)
         # one folded row [H*Dh] per slot into the STACKED pool, attended in
         # place by (li, page) — see lm_prefill_chunk
@@ -848,12 +898,14 @@ def lm_decode_step(params, tokens, positions, k_pool, v_pool, page_tables,
         ctx = paged_decode_attention(q, k_pool, v_pool, page_tables,
                                      kv_lens, impl=attn_impl, layer=li)
         x = _lm_block_tail(lp, x, ctx.reshape(S, d_model))
-    return x @ params["out_w"], k_pool, v_pool
+    return x @ params["out_w"], dict(cache, k=k_pool, v=v_pool)
 
 
 def build_decode_model(params, meta, eos_id=None, use_flash=None,
                        attn_impl=None):
-    """Wrap LM weights as a serving ``DecodeModel``.
+    """Wrap LM weights as a serving ``DecodeModel``: the weights ride in
+    ``DecodeModel.params`` (:func:`lm_serving_params`) and every step takes
+    them as an argument.
 
     ``use_flash``: LEGACY whole-prompt prefill attention engine (default:
     flash on TPU, mha_reference elsewhere) — kept for ``prefill_fn``
@@ -861,30 +913,21 @@ def build_decode_model(params, meta, eos_id=None, use_flash=None,
     paged attention engine is ``attn_impl`` ("auto"/"reference"/
     "pallas", shared with the decode step's paged_decode_attention).
     """
+    import functools
+
     from ..core import cpu_backend
     from ..serving.decode_scheduler import DecodeModel
 
     if use_flash is None:
         use_flash = not cpu_backend()
     n_head = meta["n_head"]
-
-    def prefill_fn(tokens, length):
-        return lm_prefill(params, tokens, length, n_head=n_head,
-                          use_flash=use_flash)
-
-    def prefill_chunk_fn(tokens, start, valid, k_pool, v_pool, chunk_pages,
-                         gather_pages):
-        return lm_prefill_chunk(params, tokens, start, valid, k_pool,
-                                v_pool, chunk_pages, gather_pages,
-                                n_head=n_head, attn_impl=attn_impl)
-
-    def decode_fn(tokens, positions, k_pool, v_pool, page_tables, kv_lens):
-        return lm_decode_step(params, tokens, positions, k_pool, v_pool,
-                              page_tables, kv_lens, n_head=n_head,
-                              attn_impl=attn_impl)
-
     return DecodeModel(
-        prefill_fn, decode_fn, prefill_chunk_fn=prefill_chunk_fn,
+        functools.partial(lm_prefill, n_head=n_head, use_flash=use_flash),
+        functools.partial(lm_decode_step, n_head=n_head,
+                          attn_impl=attn_impl),
+        prefill_chunk_fn=functools.partial(
+            lm_prefill_chunk, n_head=n_head, attn_impl=attn_impl),
+        params=lm_serving_params(params),
         num_layers=meta["n_layer"], num_heads=n_head,
         head_dim=meta["head_dim"], vocab_size=meta["vocab_size"],
         eos_id=eos_id, name="transformer-lm")

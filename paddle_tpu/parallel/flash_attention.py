@@ -760,22 +760,34 @@ flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
 def _stacked_pools(q, k_pool, v_pool, layer):
-    """``(k_stack, v_stack, layer)`` in the stored form ``[L, P, ps, H*Dh]``.
-    ``layer=None`` means ONE layer's unfolded ``[P, ps, H, Dh]`` pool: a
-    free reshape of a row-major array into a one-layer stack."""
-    H, Dh = q.shape[-2:]
+    """``(k_stack, v_stack, layer, n_kv)`` in the stored form
+    ``[L, P, ps, Hkv*Dh]``.  ``layer=None`` means ONE layer's unfolded
+    ``[P, ps, Hkv, Dh]`` pool: a free reshape of a row-major array into a
+    one-layer stack.  ``q`` has ``Hq = g * Hkv`` heads of the pool's width
+    (query head ``i`` reads KV head ``i // g``)."""
+    Hq, Dh = q.shape[-2:]
     if layer is None:
-        if k_pool.ndim != 4 or k_pool.shape[2:] != (H, Dh):
+        if k_pool.ndim != 4 or k_pool.shape[3] != Dh:
             raise ValueError(
-                "without layer= the pool is one layer's [P, ps, H, Dh] = "
-                "[.., .., %d, %d]; got %s" % (H, Dh, k_pool.shape))
-        fold = (1,) + k_pool.shape[:2] + (H * Dh,)
-        return k_pool.reshape(fold), v_pool.reshape(fold), 0
-    if k_pool.ndim != 4 or k_pool.shape[3] != H * Dh:
-        raise ValueError(
-            "with layer= the pool is the stored stack [L, P, ps, H*Dh] = "
-            "[.., .., .., %d]; got %s" % (H * Dh, k_pool.shape))
-    return k_pool, v_pool, int(layer)
+                "without layer= the pool is one layer's [P, ps, Hkv, Dh] = "
+                "[.., .., .., %d]; got %s" % (Dh, k_pool.shape))
+        n_kv = k_pool.shape[2]
+        fold = (1,) + k_pool.shape[:2] + (n_kv * Dh,)
+        k_pool, v_pool, layer = k_pool.reshape(fold), v_pool.reshape(fold), 0
+    else:
+        # an UNFOLDED [P, ps, H, Dh] pool would read as a one-KV-head stack
+        # of page size H: refused (a real stack of that shape does not
+        # occur: its page size would equal the number of query heads)
+        if k_pool.ndim != 4 or k_pool.shape[3] % Dh or (
+                k_pool.shape[3] == Dh and k_pool.shape[2] == Hq > 1):
+            raise ValueError(
+                "with layer= the pool is the stored stack [L, P, ps, Hkv*Dh] "
+                "with Dh = %d; got %s" % (Dh, k_pool.shape))
+        n_kv = k_pool.shape[3] // Dh
+    if Hq % n_kv:
+        raise ValueError("%d query heads do not group over %d KV heads"
+                         % (Hq, n_kv))
+    return k_pool, v_pool, int(layer), n_kv
 
 
 def _paged_reference(q, k_pool, v_pool, page_tables, kv_lens, sm_scale, layer):
@@ -904,6 +916,170 @@ def _paged_pallas(q, k_pool, v_pool, page_tables, kv_lens, sm_scale, interpret,
         interpret=interpret,
     )(pt_flat, lens, q.reshape(S, 1, H * Dh), k_pool, v_pool)
     return out.reshape(S, H, Dh)
+
+
+# ---------------------------------------------------------------------------
+# Grouped query heads and a SELECTION of pages (block-sparse attention), in
+# decode.  ``Hq = g * Hkv`` query heads read ``Hkv``-head pools, and each
+# (slot, KV head) may bring its own short list of pages in place of the
+# slot's whole page-table row: ``sel_pages [S, Hkv, NS]`` in cache order, of
+# which the first ``sel_tokens [S, Hkv]`` tokens, counted page by page, are
+# valid (so every listed page but the last is whole, and entries past the
+# count are never read).  Without a selection the list is the slot's row and
+# the count ``kv_lens``, for every KV head.  One grid step = one (slot, KV
+# head, ``DECODE_PAGES_PER_STEP`` listed pages): the ``g`` query rows of the
+# group against those pages' ``[ps, Dh]`` lanes of that head, so ``Dh`` must be a whole number of lane
+# tiles (128) on the chip.  ``g = 1`` without a selection is NOT routed here:
+# it is the kernel above, bitwise what it was.
+# ---------------------------------------------------------------------------
+
+
+def _head_lists(page_tables, kv_lens, n_kv, selection):
+    """``(pages [S, Hkv, NS], tokens [S, Hkv])``: the selection, or the
+    slot's whole row for every KV head."""
+    import jax.numpy as jnp
+
+    if selection is not None:
+        pages, tokens = selection
+        return pages.astype(jnp.int32), tokens.astype(jnp.int32)
+    S, mp = page_tables.shape
+    return (jnp.broadcast_to(page_tables.astype(jnp.int32)[:, None, :],
+                             (S, n_kv, mp)),
+            jnp.broadcast_to(kv_lens.astype(jnp.int32)[:, None], (S, n_kv)))
+
+
+def _paged_gqa_reference(q, k_pool, v_pool, pages, tokens, sm_scale, layer):
+    import jax.numpy as jnp
+
+    S, Hq, Dh = q.shape
+    n_kv, ns = pages.shape[1:]
+    g = Hq // n_kv
+    ps = k_pool.shape[2]
+    outs = []
+    for h in range(n_kv):
+        lanes = slice(h * Dh, (h + 1) * Dh)
+        k = k_pool[layer, pages[:, h]][..., lanes].reshape(
+            S, ns * ps, Dh).astype(jnp.float32)
+        v = v_pool[layer, pages[:, h]][..., lanes].reshape(
+            S, ns * ps, Dh).astype(jnp.float32)
+        qh = q[:, h * g:(h + 1) * g].astype(jnp.float32)
+        s = jnp.einsum("sgd,skd->sgk", qh, k,
+                       precision=jax.lax.Precision.HIGHEST) * sm_scale
+        ok = (jnp.arange(ns * ps)[None, :] < tokens[:, h, None])[:, None, :]
+        p = jax.nn.softmax(jnp.where(ok, s, NEG_INF), axis=-1)
+        p = jnp.where(ok, p, 0.0)          # a slot with no token -> zeros
+        outs.append(jnp.einsum("sgk,skd->sgd", p, jnp.where(
+            ok[:, 0, :, None], v, 0.0), precision=jax.lax.Precision.HIGHEST))
+    return jnp.concatenate(outs, axis=1).astype(q.dtype)
+
+
+DECODE_PAGES_PER_STEP = 8
+
+
+def _paged_gqa_decode_kernel(pt_ref, lens_ref, q_ref, *refs, page_size,
+                             n_steps, n_kv, per_step, sm_scale):
+    """One grid step = one slot x one KV head x ``per_step`` listed pages
+    (each its own block of the pool, side by side in VMEM as one
+    ``[per_step * ps, Dh]`` tile): the group's ``[g, Dh]`` query rows against
+    them, online softmax over the walk in the scratch rows.  A grid step
+    costs about a third of a microsecond whatever it does, so a page a step
+    made the walk the decode step's largest cost (13 of 29 ms, PERF.md)."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    k_refs, v_refs = refs[:per_step], refs[per_step:2 * per_step]
+    o_ref, m_scr, l_scr, acc_scr = refs[2 * per_step:]
+    s_idx, h, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    width = per_step * page_size
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    n_tok = lens_ref[s_idx * n_kv + h]
+
+    @pl.when(j * width < n_tok)
+    def _body():
+        okc = (j * width + jax.lax.broadcasted_iota(
+            jnp.int32, (width, 1), 0)) < n_tok
+        okr = (j * width + jax.lax.broadcasted_iota(
+            jnp.int32, (1, width), 1)) < n_tok
+        q = q_ref[...].astype(jnp.float32)                       # [g, Dh]
+        k = jnp.concatenate([r[...] for r in k_refs], axis=0)    # [width, Dh]
+        v = jnp.concatenate([r[...] for r in v_refs], axis=0)
+        k = jnp.where(okc, k.astype(jnp.float32), 0.0)
+        v = jnp.where(okc, v.astype(jnp.float32), 0.0)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale       # [g, width]
+        s = jnp.where(okr, s, NEG_INF)
+        m_prev = m_scr[:, 0:1]
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.where(okr, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_scr[...] = jnp.broadcast_to(
+            l_scr[:, 0:1] * alpha + p.sum(axis=1, keepdims=True), l_scr.shape)
+        acc_scr[...] = acc_scr[...] * alpha + jnp.dot(
+            p, v, preferred_element_type=jnp.float32)
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+
+    @pl.when(j == n_steps - 1)
+    def _finish():
+        o_ref[...] = (acc_scr[...] / jnp.maximum(l_scr[:, 0:1], 1e-30)
+                      ).astype(o_ref.dtype)
+
+
+def _paged_gqa_pallas(q, k_pool, v_pool, pages, tokens, sm_scale, interpret,
+                      layer):
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    S, Hq, Dh = q.shape
+    n_kv, ns = pages.shape[1:]
+    g = Hq // n_kv
+    ps = k_pool.shape[2]
+    per = next(p for p in (DECODE_PAGES_PER_STEP, 4, 2, 1) if ns % p == 0)
+    pt_flat = pages.reshape(S * n_kv * ns)
+    lens = tokens.reshape(S * n_kv)
+    kernel = functools.partial(
+        _paged_gqa_decode_kernel, page_size=ps, n_steps=ns // per, n_kv=n_kv,
+        per_step=per, sm_scale=sm_scale)
+
+    def page_of(i):
+        def index(s, h, j, pt, kl):
+            # entries past the count are never read: stay on the last listed
+            # page, whose block is already in VMEM (no DMA for a skip)
+            last = jnp.maximum((kl[s * n_kv + h] + ps - 1) // ps - 1, 0)
+            return (layer, pt[(s * n_kv + h) * ns
+                              + jnp.minimum(j * per + i, last)], 0, h)
+        return pl.BlockSpec((None, None, ps, Dh), index)
+
+    page_specs = [page_of(i) for i in range(per)]
+    rows = pl.BlockSpec((None, None, g, Dh),
+                        lambda s, h, j, pt, kl: (s, h, 0, 0))
+    (out,) = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(S, n_kv, ns // per),
+            in_specs=[rows] + page_specs + page_specs,
+            out_specs=[rows],
+            scratch_shapes=[
+                pltpu.VMEM((g, 128), jnp.float32),   # running max
+                pltpu.VMEM((g, 128), jnp.float32),   # running sum
+                pltpu.VMEM((g, Dh), jnp.float32),    # output accumulator
+            ]),
+        out_shape=[jax.ShapeDtypeStruct((S, n_kv, g, Dh), q.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name="paged_gqa_decode_attention",
+    )(pt_flat, lens, q.reshape(S, n_kv, g, Dh), *([k_pool] * per),
+      *([v_pool] * per))
+    return out.reshape(S, Hq, Dh)
 
 
 # ---------------------------------------------------------------------------
@@ -1060,13 +1236,190 @@ def _paged_prefill_pallas(q, k_pool, v_pool, pages, start, sm_scale,
     return out.reshape(C, H, Dh)
 
 
+# ---------------------------------------------------------------------------
+# Grouped query heads and a per-row BLOCK MASK, in chunked prefill.  Every
+# query row of a chunk selects its own pages, so the kernel still walks the
+# sequence's pages in order and ``block_mask [Hkv, C, MP]`` says, for the
+# rows of each KV head's group, which pages a row may read (causality by
+# absolute position still applies inside them).  The grid is (KV head,
+# row block, ``PREFILL_PAGES_PER_STEP`` pages); the mask block ``[cb, MP]``
+# stays in VMEM over the page walk and each page's column is picked out of
+# its lanes.  A row must have
+# the FIRST page it can see selected (block-sparse attention forces block
+# 0), so its running max is finite before any masked page.
+# ---------------------------------------------------------------------------
+
+PREFILL_ROW_BLOCK = 128
+
+
+def _paged_gqa_prefill_reference(q, k_pool, v_pool, pages, start, sm_scale,
+                                 layer, n_kv, block_mask):
+    import jax.numpy as jnp
+
+    C, Hq, Dh = q.shape
+    g = Hq // n_kv
+    ps = k_pool.shape[2]
+    mp = pages.shape[0]
+    lens = start + jnp.arange(C, dtype=jnp.int32) + 1
+    ok = jnp.arange(mp * ps)[None, :] < lens[:, None]            # [C, K]
+    outs = []
+    for h in range(n_kv):
+        lanes = slice(h * Dh, (h + 1) * Dh)
+        k = k_pool[layer, pages][..., lanes].reshape(
+            mp * ps, Dh).astype(jnp.float32)
+        v = v_pool[layer, pages][..., lanes].reshape(
+            mp * ps, Dh).astype(jnp.float32)
+        okh = ok if block_mask is None else (
+            ok & jnp.repeat(block_mask[h], ps, axis=1))
+        qh = q[:, h * g:(h + 1) * g].astype(jnp.float32)
+        s = jnp.einsum("cgd,kd->cgk", qh, k,
+                       precision=jax.lax.Precision.HIGHEST) * sm_scale
+        p = jax.nn.softmax(jnp.where(okh[:, None, :], s, NEG_INF), axis=-1)
+        p = jnp.where(okh[:, None, :], p, 0.0)
+        outs.append(jnp.einsum("cgk,kd->cgd", p, v,
+                               precision=jax.lax.Precision.HIGHEST))
+    return jnp.concatenate(outs, axis=1).astype(q.dtype)
+
+
+PREFILL_PAGES_PER_STEP = 4
+
+
+def _paged_gqa_prefill_kernel(pt_ref, start_ref, q_ref, *refs, page_size,
+                              n_steps, per_step, rows, group, head_dim,
+                              sm_scale, masked):
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    k_refs, v_refs = refs[:per_step], refs[per_step:2 * per_step]
+    rest = refs[2 * per_step:]
+    if masked:
+        mask_ref, o_ref, m_scr, l_scr, acc_scr = rest
+    else:
+        o_ref, m_scr, l_scr, acc_scr = rest
+    c, j = pl.program_id(1), pl.program_id(2)
+    first = start_ref[0] + c * rows       # absolute position of row 0
+    width = per_step * page_size          # keys a grid step covers
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    @pl.when(j * width < first + rows)
+    def _body():
+        kcol = j * width + jax.lax.broadcasted_iota(jnp.int32, (width, 1), 0)
+        row = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 0)
+        local = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1)
+        ok = j * width + local <= first + row   # causal by absolute position
+        if masked:
+            lane = jax.lax.broadcasted_iota(jnp.int32, mask_ref.shape, 1)
+            for i in range(per_step):
+                picked = jnp.sum(
+                    jnp.where(lane == j * per_step + i, mask_ref[...], 0.0),
+                    axis=1, keepdims=True) > 0.5                 # [rows, 1]
+                ok = ok & (picked | (local // page_size != i))
+        k = jnp.concatenate([r[...] for r in k_refs], axis=0)    # [width, Dh]
+        v = jnp.concatenate([r[...] for r in v_refs], axis=0)
+        k = jnp.where(kcol < first + rows, k.astype(jnp.float32), 0.0)
+        v = jnp.where(kcol < first + rows, v.astype(jnp.float32), 0.0)
+        for i in range(group):
+            lanes = slice(i * head_dim, (i + 1) * head_dim)
+            q = q_ref[:, lanes].astype(jnp.float32)              # [rows, Dh]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale
+            s = jnp.where(ok, s, NEG_INF)
+            m_prev = m_scr[i, :, 0:1]
+            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+            p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            l_scr[i] = jnp.broadcast_to(
+                l_scr[i, :, 0:1] * alpha + p.sum(axis=1, keepdims=True),
+                l_scr.shape[1:])
+            acc_scr[i] = acc_scr[i] * alpha + jnp.dot(
+                p, v, preferred_element_type=jnp.float32)
+            m_scr[i] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+
+    @pl.when(j == n_steps - 1)
+    def _finish():
+        for i in range(group):
+            denom = jnp.maximum(l_scr[i, :, 0:1], 1e-30)
+            o_ref[:, i * head_dim:(i + 1) * head_dim] = (
+                acc_scr[i] / denom).astype(o_ref.dtype)
+
+
+def _paged_gqa_prefill_pallas(q, k_pool, v_pool, pages, start, sm_scale,
+                              interpret, layer, n_kv, block_mask):
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    C, Hq, Dh = q.shape
+    g = Hq // n_kv
+    ps = k_pool.shape[2]
+    mp = pages.shape[0]
+    cb = PREFILL_ROW_BLOCK if C % PREFILL_ROW_BLOCK == 0 else C
+    per = next(p for p in (PREFILL_PAGES_PER_STEP, 2, 1) if mp % p == 0)
+    masked = block_mask is not None
+    kernel = functools.partial(
+        _paged_gqa_prefill_kernel, page_size=ps, n_steps=mp // per,
+        per_step=per, rows=cb, group=g, head_dim=Dh, sm_scale=sm_scale,
+        masked=masked)
+
+    def page_of(i):
+        def index(h, c, j, pt, st):
+            # pages past the row block's last visible key are skipped: stay
+            # on the last visible one (in VMEM already; no DMA for a skip)
+            last = jnp.minimum((st[0] + (c + 1) * cb - 1) // ps, mp - 1)
+            return (layer, pt[jnp.minimum(j * per + i, last)], 0, h)
+        return pl.BlockSpec((None, None, ps, Dh), index)
+
+    page_specs = [page_of(i) for i in range(per)]
+    rows = pl.BlockSpec((cb, g * Dh), lambda h, c, j, pt, st: (c, h))
+    in_specs = [rows] + page_specs + page_specs
+    args = [q.reshape(C, Hq * Dh)] + [k_pool] * per + [v_pool] * per
+    if masked:
+        lanes = -(-mp // 128) * 128
+        in_specs.append(pl.BlockSpec((None, cb, lanes),
+                                     lambda h, c, j, pt, st: (h, c, 0)))
+        args.append(jnp.pad(block_mask.astype(jnp.float32),
+                            ((0, 0), (0, 0), (0, lanes - mp))))
+    (out,) = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n_kv, C // cb, mp // per),
+            in_specs=in_specs,
+            out_specs=[rows],
+            scratch_shapes=[
+                pltpu.VMEM((g, cb, 128), jnp.float32),   # running max
+                pltpu.VMEM((g, cb, 128), jnp.float32),   # running sum
+                pltpu.VMEM((g, cb, Dh), jnp.float32),    # output accumulator
+            ]),
+        out_shape=[jax.ShapeDtypeStruct((C, Hq * Dh), q.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name="paged_gqa_prefill_attention",
+    )(pages.astype(jnp.int32),
+      jnp.reshape(jnp.asarray(start, jnp.int32), (1,)), *args)
+    return out.reshape(C, Hq, Dh)
+
+
 def paged_prefill_attention(q, k_pool, v_pool, pages, start, sm_scale=None,
-                            impl=None, interpret=None, layer=None):
+                            impl=None, interpret=None, layer=None,
+                            block_mask=None):
     """Chunk-of-prompt attention against one sequence's paged KV.
 
-    q: [C, H, Dh] — one prefill chunk's query tokens, absolute positions
+    q: [C, Hq, Dh] — one prefill chunk's query tokens, absolute positions
         ``start .. start + C - 1`` (pad tail rows allowed; their outputs
-        are garbage the caller ignores).
+        are garbage the caller ignores).  ``Hq = g * Hkv`` heads group
+        over the pool's ``Hkv`` (query head ``i`` reads KV head ``i // g``).
+    block_mask: None, or ``[Hkv, C, max_pages]`` bool — the pages each row
+        of a KV head's group may read (block-sparse attention; causality
+        still applies inside a page).  Every row must have page 0
+        selected.  ``g = 1`` without a mask is the kernel it always was.
     k_pool / v_pool: with ``layer=li`` (the step programs) the STORED
         stack ``[L, num_pages, page_size, H*Dh]``, addressed in place;
         with ``layer=None`` ONE layer's ``[num_pages, page_size, H, Dh]``
@@ -1088,24 +1441,40 @@ def paged_prefill_attention(q, k_pool, v_pool, pages, start, sm_scale=None,
         sm_scale = 1.0 / float(np.sqrt(q.shape[-1]))
     if impl in (None, "auto"):
         impl = "reference" if cpu_backend() else "pallas"
-    k_pool, v_pool, layer = _stacked_pools(q, k_pool, v_pool, layer)
-    if impl == "reference":
-        return _paged_prefill_reference(q, k_pool, v_pool, pages, start,
-                                        sm_scale, layer)
-    if impl != "pallas":
+    k_pool, v_pool, layer, n_kv = _stacked_pools(q, k_pool, v_pool, layer)
+    plain = n_kv == q.shape[1] and block_mask is None
+    if impl not in ("reference", "pallas"):
         raise ValueError("impl must be auto|reference|pallas, got %r" % impl)
+    if impl == "reference":
+        if plain:
+            return _paged_prefill_reference(q, k_pool, v_pool, pages, start,
+                                            sm_scale, layer)
+        return _paged_gqa_prefill_reference(q, k_pool, v_pool, pages, start,
+                                            sm_scale, layer, n_kv, block_mask)
     if interpret is None:
         interpret = cpu_backend()
-    return _paged_prefill_pallas(q, k_pool, v_pool, pages, start, sm_scale,
-                                 interpret, layer)
+    if plain:
+        return _paged_prefill_pallas(q, k_pool, v_pool, pages, start,
+                                     sm_scale, interpret, layer)
+    return _paged_gqa_prefill_pallas(q, k_pool, v_pool, pages, start,
+                                     sm_scale, interpret, layer, n_kv,
+                                     block_mask)
 
 
 def paged_decode_attention(q, k_pool, v_pool, page_tables, kv_lens,
                            sm_scale=None, impl=None, interpret=None,
-                           layer=None):
+                           layer=None, selection=None):
     """Single-token-query attention against a paged KV pool.
 
-    q: [S, H, Dh] — one query token per decode slot.
+    q: [S, Hq, Dh] — one query token per decode slot; ``Hq = g * Hkv``
+        heads group over the pool's ``Hkv`` (query head ``i`` reads KV
+        head ``i // g``).
+    selection: None, or ``(sel_pages [S, Hkv, NS], sel_tokens [S, Hkv])``
+        — per slot and KV head a short list of pages in cache order in
+        place of the slot's whole row, of which the first ``sel_tokens``
+        tokens (page by page) are valid; ``page_tables`` / ``kv_lens`` are
+        then not read.  ``g = 1`` without a selection is the kernel it
+        always was.
     k_pool / v_pool: with ``layer=li`` (the step programs) the STORED
         stack ``[L, num_pages, page_size, H*Dh]``, addressed in place;
         with ``layer=None`` ONE layer's ``[num_pages, page_size, H, Dh]``
@@ -1122,16 +1491,24 @@ def paged_decode_attention(q, k_pool, v_pool, page_tables, kv_lens,
         sm_scale = 1.0 / float(np.sqrt(q.shape[-1]))
     if impl in (None, "auto"):
         impl = "reference" if cpu_backend() else "pallas"
-    k_pool, v_pool, layer = _stacked_pools(q, k_pool, v_pool, layer)
-    if impl == "reference":
-        return _paged_reference(q, k_pool, v_pool, page_tables, kv_lens,
-                                sm_scale, layer)
-    if impl != "pallas":
+    k_pool, v_pool, layer, n_kv = _stacked_pools(q, k_pool, v_pool, layer)
+    plain = n_kv == q.shape[1] and selection is None
+    if impl not in ("reference", "pallas"):
         raise ValueError("impl must be auto|reference|pallas, got %r" % impl)
     if interpret is None:
         interpret = cpu_backend()
-    return _paged_pallas(q, k_pool, v_pool, page_tables, kv_lens, sm_scale,
-                         interpret, layer)
+    if plain and impl == "reference":
+        return _paged_reference(q, k_pool, v_pool, page_tables, kv_lens,
+                                sm_scale, layer)
+    if plain:
+        return _paged_pallas(q, k_pool, v_pool, page_tables, kv_lens,
+                             sm_scale, interpret, layer)
+    pages, tokens = _head_lists(page_tables, kv_lens, n_kv, selection)
+    if impl == "reference":
+        return _paged_gqa_reference(q, k_pool, v_pool, pages, tokens,
+                                    sm_scale, layer)
+    return _paged_gqa_pallas(q, k_pool, v_pool, pages, tokens, sm_scale,
+                             interpret, layer)
 
 
 def paged_kv_finite(k_pool, v_pool, pages):

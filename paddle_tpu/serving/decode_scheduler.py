@@ -105,6 +105,7 @@ _tokens = _obs.counter("serving.decode.tokens")
 _prefills = _obs.counter("serving.decode.prefills")
 _steps = _obs.counter("serving.decode.steps")
 _retired = _obs.counter("serving.decode.retired")
+_state_resets = _obs.counter("serving.cache.state_resets")
 _expired = _obs.counter("serving.decode.expired")
 _expired_mid_decode = _obs.counter("serving.decode.expired_mid_decode")
 _queue_full = _obs.counter("serving.decode.queue_full")
@@ -162,55 +163,86 @@ def _sample_token(logits, key, temp, top_k):
 
 
 class DecodeModel:
-    """The pure-jax callables a decode-capable model exposes.
+    """The pure-jax callables a decode-capable model exposes, its weights,
+    and what it keeps in the cache.
 
-    ``prefill_fn(tokens[T], length) -> (last_logits[V], k[L,T,H,D],
-    v[L,T,H,D])`` — run the whole (padded) prompt; ``length`` is the real
-    token count, ``last_logits`` the logits at position ``length - 1``.
-    LEGACY: used only by models that don't provide ``prefill_chunk_fn``.
+    ``params`` is the model's weights as ONE pytree of arrays.  Every
+    callable takes it first and the scheduler passes it to every jitted
+    step as an ARGUMENT (never donated): a step executable holds no
+    weights, so it is small enough for the compile cache and a model of
+    any size is on the device once.  Keep the pytree to few arrays (a
+    dispatch on the chip pays about 13 us an argument): stack by kind
+    what is small, keep a matrix per layer where slicing a stack would
+    copy a layer-sized matrix (docs/serving.md, "The cache contract").
 
-    ``prefill_chunk_fn(tokens[C], start, valid, k_pool, v_pool,
-    chunk_pages[C // page_size], gather_pages[MP]) ->
-    (last_logits[V], k_pool', v_pool')`` — one resumable prefill CHUNK:
-    scatter the window's k/v into ``chunk_pages``, attend over the
-    sequence's ``gather_pages`` causally by absolute position
-    (``start + row``); ``last_logits`` sits at row ``valid - 1``.  When
+    ``prefill_fn(params, tokens[T], length) -> (last_logits[V],
+    k[L,T,H,D], v[L,T,H,D])`` — run the whole (padded) prompt; ``length``
+    is the real token count, ``last_logits`` the logits at position
+    ``length - 1``.  LEGACY: used only by models that don't provide
+    ``prefill_chunk_fn`` (and keep nothing but K/V).
+
+    ``prefill_chunk_fn(params, tokens[C], start, valid, cache,
+    chunk_pages[C // page_size], gather_pages[MP], slot) ->
+    (last_logits[V], cache')`` — one resumable prefill CHUNK of the
+    sequence seated in ``slot``: scatter the window's k/v (and further
+    page-indexed rows) into ``chunk_pages``, attend over the sequence's
+    ``gather_pages`` causally by absolute position (``start + row``);
+    ``last_logits`` sits at row ``valid - 1``.  Slot-indexed state is read
+    at ``slot`` and written back there; a chunk with ``start == 0`` opens a
+    sequence and must take the state as ZERO whatever the slot held (that
+    is the reset of a reused slot: it costs no dispatch of its own).  When
     present the scheduler prefills EVERY prompt through this step
     (monolithic = one bucket-wide chunk), which is what makes chunked,
     monolithic, and prefix-cache-resumed prefill bitwise interchangeable
     — and what ``prefill_chunk_tokens`` / ``prefix_cache`` require.
 
-    ``decode_fn(tokens[S], positions[S], k_pool, v_pool,
-    page_tables[S,MP], kv_lens[S]) -> (logits[S,V], k_pool', v_pool')`` —
-    one token per slot: write its k/v at ``positions`` into the paged
-    pools, attend over each slot's first ``kv_lens`` cached tokens.
-    ``kv_lens[s] == 0`` marks an inactive slot (masked, scratch writes).
+    ``decode_fn(params, tokens[S], positions[S], cache, page_tables[S,MP],
+    kv_lens[S]) -> (logits[S,V], cache')`` — one token per slot: write its
+    k/v at ``positions`` into the paged pools, attend over each slot's
+    first ``kv_lens`` cached tokens.  ``kv_lens[s] == 0`` marks a slot that
+    does not decode (masked, scratch writes, slot state left as it is).
+    A model with ``step_counters`` returns a third value, one int32 per
+    name: the scheduler reads them with the tokens (one array a step) and
+    adds each to the counter ``serving.decode.<name>``.
 
-    ``k_pool`` / ``v_pool`` are the cache's WHOLE pools in their stored
-    shape ``[L, num_pages, page_size, H*D]`` (``kv_cache.PagedKVCache``:
-    heads folded head-major into the last axis, so a token's k is one
-    ``[H*D]`` row and a page a ``[page_size, H*D]`` tile).  A model
-    scatters rows into the stack and attends through
+    ``cache`` is the cache's pytree, a dict of arrays
+    (``kv_cache.PagedKVCache.pools``): ``"k"`` and ``"v"`` in the stored
+    shape ``[num_layers, num_pages, page_size, num_heads * head_dim]``
+    (heads folded head-major into the last axis, so a token's k is one
+    row and a page a ``[page_size, H*D]`` tile), each of ``page_pools``
+    ``{name: dict(layers=, tokens_per_row=, width=, dtype=)}`` as
+    ``[layers, num_pages, page_size // tokens_per_row, width]``, and each
+    of ``slot_state`` ``{name: dict(layers=, shape=, dtype=)}`` as
+    ``[layers, num_slots, *shape]``.  ``num_layers`` / ``num_heads`` /
+    ``head_dim`` describe the layers that hold paged K/V (their count,
+    KV heads and head width), not the model's depth.  A model scatters
+    rows into a leaf and attends through
     ``paged_*_attention(..., layer=li)``; it must not slice a layer out
-    (``k_pool[li]`` is a layer-sized copy in every step on the chip).
+    (``cache["k"][li]`` is a layer-sized copy in every step on the chip).
 
-    All are jitted by the scheduler (with pool donation on TPU); they
+    All are jitted by the scheduler (the cache donated on TPU); they
     must be shape-stable in everything but values.
-    ``models.transformer.build_decode_model`` is the in-repo producer.
+    ``models.transformer.build_decode_model`` and
+    ``models.minicpm_sala.build_decode_model`` are the in-repo producers.
     """
 
     def __init__(self, prefill_fn, decode_fn, prefill_chunk_fn=None, *,
-                 num_layers, num_heads, head_dim, vocab_size, eos_id=None,
-                 name="decode-model"):
+                 params=None, num_layers, num_heads, head_dim, vocab_size,
+                 eos_id=None, name="decode-model", page_pools=None,
+                 slot_state=None, step_counters=()):
         self.prefill_fn = prefill_fn
         self.decode_fn = decode_fn
         self.prefill_chunk_fn = prefill_chunk_fn
+        self.params = params
         self.num_layers = int(num_layers)
         self.num_heads = int(num_heads)
         self.head_dim = int(head_dim)
         self.vocab_size = int(vocab_size)
         self.eos_id = eos_id
         self.name = name
+        self.page_pools = dict(page_pools or {})
+        self.slot_state = dict(slot_state or {})
+        self.step_counters = tuple(step_counters)
 
 
 class DecodeConfig:
@@ -475,21 +507,21 @@ class HandoffPacket:
     """Host-staged KV of one fully prefilled sequence in transit
     between a prefill-role replica and a decode-role one (roles mode).
 
-    ``k_host``/``v_host`` are numpy ``[L, max_pages_per_seq, ps, H*D]``
-    gathers of the origin cache (rows past ``n_pages`` hold scratch
-    content and scatter back into scratch); ``first`` is the first
+    ``pages_host`` holds, for each page-indexed leaf of the cache, a numpy
+    ``[L, max_pages_per_seq, ...]`` gather of the origin cache (rows past
+    ``n_pages`` hold scratch content and scatter back into scratch);
+    ``first`` is the first
     sampled token (already journaled on the origin); ``hashes`` the
     prompt chain hashes so the destination can re-register the prefix.
     """
 
-    __slots__ = ("req", "k_host", "v_host", "n_pages", "kv_len",
+    __slots__ = ("req", "pages_host", "n_pages", "kv_len",
                  "hashes", "origin", "first")
 
-    def __init__(self, req, k_host, v_host, n_pages, kv_len, hashes,
+    def __init__(self, req, pages_host, n_pages, kv_len, hashes,
                  origin, first):
         self.req = req
-        self.k_host = k_host
-        self.v_host = v_host
+        self.pages_host = pages_host
         self.n_pages = int(n_pages)
         self.kv_len = int(kv_len)
         self.hashes = hashes
@@ -516,12 +548,14 @@ class DecodeScheduler:
     provably dead and re-admits the journals to sibling replicas.
     ``breaker=`` (a :class:`~.resilient.CircuitBreaker`) records decode
     dispatch outcomes; the pool's gate consults it for admission.
+    ``device=`` commits this scheduler's weights and cache to one device
+    (a pool gives each replica its own).
     """
 
     def __init__(self, model, config=None, autostart=True, queue=None,
                  gate=None, name=None, evict_on_death=False, breaker=None,
                  sessions=None, replica_index=0, role="both",
-                 on_handoff=None, claim=None):
+                 on_handoff=None, claim=None, device=None):
         self.model = model
         cfg = self.config = config or DecodeConfig()
         self._use_chunks = model.prefill_chunk_fn is not None
@@ -539,6 +573,17 @@ class DecodeScheduler:
             raise ServingError(
                 "role='prefill' requires the chunked prefill path "
                 "(a model with prefill_chunk_fn)")
+        if model.slot_state and (cfg.prefix_cache or sessions is not None
+                                 or role != "both"
+                                 or not self._use_chunks):
+            raise ServingError(
+                "%r keeps slot-indexed state (%s): prefix_cache, sessions "
+                "and prefill/decode roles map or move PAGES, and a page "
+                "says nothing of the state at its boundary. A state "
+                "snapshot per checkpointed boundary is missing; serve it "
+                "with prefix_cache=False, no sessions, role='both' and a "
+                "prefill_chunk_fn" % (model.name, ", ".join(
+                    sorted(model.slot_state))))
         if sessions is not None and not cfg.prefix_cache:
             raise ServingError(
                 "sessions require prefix_cache=True: a session pin is an "
@@ -564,7 +609,25 @@ class DecodeScheduler:
             cfg.num_pages or (
                 cfg.num_slots * -(-cfg.max_seq_len // cfg.page_size) + 1),
             cfg.page_size, model.num_heads, model.head_dim,
-            cfg.max_seq_len, dtype=cfg.kv_dtype)
+            cfg.max_seq_len, dtype=cfg.kv_dtype,
+            page_pools=model.page_pools, slot_state=model.slot_state,
+            num_slots=cfg.num_slots, device=device)
+        self._step_counters = [
+            _obs.counter("serving.decode." + name)
+            for name in model.step_counters]
+        # this scheduler's copy of the weights, on the device once: every
+        # step takes it as an argument.  ``device`` (a pool's replica)
+        # COMMITS weights and cache there, which is what keeps the worker
+        # thread's dispatches on that device
+        import jax
+
+        with _obs.span("serving.model_load", model=model.name):
+            if device is None:
+                self._params = jax.tree_util.tree_map(jax.numpy.asarray,
+                                                      model.params)
+            else:
+                self._params = jax.device_put(model.params, device)
+            jax.block_until_ready(self._params)
         if cfg.prefill_buckets:
             buckets = sorted(set(int(b) for b in cfg.prefill_buckets))
             bad = [b for b in buckets
@@ -603,8 +666,8 @@ class DecodeScheduler:
         self._telemetry = _obs.get_telemetry()
         # pool donation saves an HBM copy per step on chip; CPU jax has no
         # donation and would warn every dispatch
-        donate = () if cpu_backend() else (2, 3)
-        self._donated = bool(donate)
+        donate = not cpu_backend()
+        self._donated = donate
         # the prefill leg is replayable (its pool inputs survive a failed
         # attempt — KV writes are functional), so transient dispatch
         # faults retry instead of fail-typing the request.  NOT with
@@ -656,44 +719,40 @@ class DecodeScheduler:
     # -- compiled steps ------------------------------------------------------
     def _build_step(self, key, donate):
         import jax
+        import jax.numpy as jnp
 
         model = self.model
         # static truncation menu; never wider than the vocabulary
         top_k = self.config.top_k
         if top_k is not None:
             top_k = min(top_k, model.vocab_size)
+        cache = self._cache
+        # every step takes (params, pools, ...): the weights as an argument
+        # that is never donated, the cache's whole pytree donated on TPU
+        pools_arg = (1,) if donate else ()
         if key[0] == "kvguard":
             # fused isfinite sweep over the pages a step just wrote;
             # one compiled program per page-vector length (key[1])
-            from ..parallel.flash_attention import paged_kv_finite
-
-            return jax.jit(paged_kv_finite)
+            return jax.jit(cache.pages_finite)
         if key[0] == "hgather":
             # roles mode, prefill side: pull one sequence's pages to the
             # host for handoff.  Fixed shape [L, max_pages_per_seq, ...]
             # whatever the prompt length — pad index entries point at
             # scratch page 0, whose gathered rows are simply ignored
-            def hgather(k_pool, v_pool, idx):
-                return k_pool[:, idx], v_pool[:, idx]
-
-            return jax.jit(hgather)
+            return jax.jit(cache.gather_pages)
         if key[0] == "hscatter":
             # roles mode, decode side: land a handoff packet's staged
             # pages into this cache.  Pad target entries aim at scratch
             # page 0 (duplicate scatter indices all write scratch —
-            # whichever lands, scratch content is don't-care).  Pools
-            # donated on TPU like every other in-place pool update.
-            def hscatter(k_pool, v_pool, k_new, v_new, idx):
-                return (k_pool.at[:, idx].set(k_new),
-                        v_pool.at[:, idx].set(v_new))
-
-            return jax.jit(hscatter,
-                           donate_argnums=(0, 1) if donate else ())
+            # whichever lands, scratch content is don't-care).  The cache
+            # donated on TPU like every other in-place update.
+            return jax.jit(cache.scatter_pages,
+                           donate_argnums=(0,) if donate else ())
         if key[0] == "decode":
-            def decode(tokens, positions, k_pool, v_pool, tables, kv_lens,
+            def decode(params, pools, tokens, positions, tables, kv_lens,
                        seeds, temps):
-                logits, k_pool, v_pool = model.decode_fn(
-                    tokens, positions, k_pool, v_pool, tables, kv_lens)
+                logits, pools, *counts = model.decode_fn(
+                    params, tokens, positions, pools, tables, kv_lens)
 
                 def samp(logit, seed, pos, temp):
                     # the carried per-request key, folded with the
@@ -704,16 +763,20 @@ class DecodeScheduler:
                     return _sample_token(logit, k, temp, top_k)
 
                 toks = jax.vmap(samp)(logits, seeds, kv_lens, temps)
-                return toks, k_pool, v_pool
+                if counts:
+                    # the model's step counters ride the tokens' readback
+                    toks = jnp.concatenate(
+                        [toks, counts[0].astype(jnp.int32)])
+                return toks, pools
 
-            return jax.jit(decode, donate_argnums=donate)
+            return jax.jit(decode, donate_argnums=pools_arg)
 
         if key[0] == "chunk":
-            def chunk(tokens, start, valid, k_pool, v_pool, chunk_pages,
-                      gather_pages, seed, temp):
-                logits, k_pool, v_pool = model.prefill_chunk_fn(
-                    tokens, start, valid, k_pool, v_pool, chunk_pages,
-                    gather_pages)
+            def chunk(params, pools, tokens, start, valid, chunk_pages,
+                      gather_pages, slot, seed, temp):
+                logits, pools = model.prefill_chunk_fn(
+                    params, tokens, start, valid, pools, chunk_pages,
+                    gather_pages, slot)
                 # the first generated token sits at absolute position
                 # start + valid; only the FINAL chunk's sample is used,
                 # and there it folds exactly like the legacy prefill's
@@ -721,21 +784,20 @@ class DecodeScheduler:
                 # chunked and monolithic first tokens match bitwise
                 kk = jax.random.fold_in(jax.random.PRNGKey(seed),
                                         start + valid)
-                return (_sample_token(logits, kk, temp, top_k),
-                        k_pool, v_pool)
+                return _sample_token(logits, kk, temp, top_k), pools
 
-            # donate the pools (positions 3, 4) on TPU, as elsewhere
-            return jax.jit(chunk,
-                           donate_argnums=(3, 4) if donate else ())
+            return jax.jit(chunk, donate_argnums=pools_arg)
 
-        def prefill(tokens, length, k_pool, v_pool, pages, seed, temp):
-            logits, k, v = model.prefill_fn(tokens, length)
-            k_pool, v_pool = write_prompt_kv(k_pool, v_pool, k, v, pages)
+        def prefill(params, pools, tokens, length, pages, seed, temp):
+            logits, k, v = model.prefill_fn(params, tokens, length)
+            pools = dict(pools)
+            pools["k"], pools["v"] = write_prompt_kv(
+                pools["k"], pools["v"], k, v, pages)
             # first sampled token sits at absolute position `length`
             kk = jax.random.fold_in(jax.random.PRNGKey(seed), length)
-            return _sample_token(logits, kk, temp, top_k), k_pool, v_pool
+            return _sample_token(logits, kk, temp, top_k), pools
 
-        return jax.jit(prefill, donate_argnums=donate)
+        return jax.jit(prefill, donate_argnums=pools_arg)
 
     def _chunk_widths(self):
         """The prefill-chunk widths this config can dispatch.
@@ -755,44 +817,42 @@ class DecodeScheduler:
     def warmup(self):
         """Compile the decode step and every prefill width against the
         scratch page, so no live sequence ever pays a compile."""
+        import jax
         import jax.numpy as jnp
 
         cfg = self.config
+        cache, params = self._cache, self._params
         with _obs.span("serving.decode.warmup", slots=cfg.num_slots):
             step = self._jit.get(("decode",))
-            toks, k_pool, v_pool = step(
+            toks, cache.pools = step(
+                params, cache.pools,
                 jnp.zeros((cfg.num_slots,), jnp.int32),
                 jnp.zeros((cfg.num_slots,), jnp.int32),
-                self._cache.k_pool, self._cache.v_pool,
                 jnp.asarray(self._tables),
                 jnp.zeros((cfg.num_slots,), jnp.int32),
                 jnp.zeros((cfg.num_slots,), jnp.uint32),
                 jnp.zeros((cfg.num_slots,), jnp.float32))
             np.asarray(toks)
-            self._cache.k_pool, self._cache.v_pool = k_pool, v_pool
             if self._use_chunks:
                 for w in self._chunk_widths():
                     fn = self._jit.get(("chunk", w))
-                    toks, k_pool, v_pool = fn(
+                    toks, cache.pools = fn(
+                        params, cache.pools,
                         jnp.zeros((w,), jnp.int32), jnp.int32(0),
                         jnp.int32(1),
-                        self._cache.k_pool, self._cache.v_pool,
                         jnp.zeros((w // cfg.page_size,), jnp.int32),
-                        jnp.zeros((self._cache.max_pages_per_seq,),
-                                  jnp.int32),
-                        jnp.uint32(0), jnp.float32(0))
+                        jnp.zeros((cache.max_pages_per_seq,), jnp.int32),
+                        np.int32(0), jnp.uint32(0), jnp.float32(0))
                     np.asarray(toks)
-                    self._cache.k_pool, self._cache.v_pool = k_pool, v_pool
             else:
                 for b in self.prefill_buckets:
                     fn = self._jit.get(("prefill", b))
-                    toks, k_pool, v_pool = fn(
+                    toks, cache.pools = fn(
+                        params, cache.pools,
                         jnp.zeros((b,), jnp.int32), jnp.int32(1),
-                        self._cache.k_pool, self._cache.v_pool,
                         jnp.zeros((b // cfg.page_size,), jnp.int32),
                         jnp.uint32(0), jnp.float32(0))
                     np.asarray(toks)
-                    self._cache.k_pool, self._cache.v_pool = k_pool, v_pool
             if cfg.kv_guard:
                 # one guard program per page-vector length the runtime
                 # dispatches: the decode tail sweep ([num_slots]) and
@@ -802,25 +862,22 @@ class DecodeScheduler:
                 for n in sorted({cfg.num_slots}
                                 | {w // cfg.page_size for w in widths}):
                     np.asarray(self._jit.get(("kvguard", n))(
-                        self._cache.k_pool, self._cache.v_pool,
-                        jnp.zeros((n,), jnp.int32)))
+                        cache.pools, jnp.zeros((n,), jnp.int32)))
             # roles mode: compile the handoff leg this replica
             # dispatches (all-scratch indices — real pages see the same
             # program), so the first conversation never pays a compile
-            mp = self._cache.max_pages_per_seq
+            mp = cache.max_pages_per_seq
             if self._role == "prefill" and self._on_handoff is not None:
-                k, v = self._jit.get(("hgather",))(
-                    self._cache.k_pool, self._cache.v_pool,
-                    jnp.zeros((mp,), jnp.int32))
-                np.asarray(k), np.asarray(v)
+                jax.block_until_ready(self._jit.get(("hgather",))(
+                    cache.pools, jnp.zeros((mp,), jnp.int32)))
             if self._role == "decode":
-                zero = jnp.zeros(self._cache.pages_shape(mp),
-                                 self._cache.dtype)
-                kp, vp = self._jit.get(("hscatter",))(
-                    self._cache.k_pool, self._cache.v_pool, zero, zero,
-                    jnp.zeros((mp,), jnp.int32))
-                np.asarray(kp[0, 0, 0, 0])
-                self._cache.k_pool, self._cache.v_pool = kp, vp
+                idx = jnp.zeros((mp,), jnp.int32)
+                zero = jax.tree_util.tree_map(
+                    jnp.zeros_like, jax.eval_shape(
+                        cache.gather_pages, cache.pools, idx))
+                cache.pools = self._jit.get(("hscatter",))(
+                    cache.pools, zero, idx)
+                jax.block_until_ready(cache.pools)
         return self
 
     # -- lifecycle -----------------------------------------------------------
@@ -846,6 +903,28 @@ class DecodeScheduler:
     @property
     def stopping(self):
         return self._worker.stopping
+
+    @property
+    def cache(self):
+        """This scheduler's :class:`PagedKVCache`.  The worker owns it
+        while alive; anyone else reads it only after :meth:`stop`."""
+        return self._cache
+
+    def run_step(self, key, *args):
+        """One dispatch of this scheduler's OWN compiled step program
+        ``key`` (``("decode",)`` or ``("chunk", width)``, as warmed up and
+        served) on its own weights and cache: ``program(params,
+        cache.pools, *args)`` with ``args`` as :meth:`warmup` gives them,
+        the cache updated in place as the loop does.  Returns the
+        program's first output (the tokens).  For a check or a tool that
+        must read what the SERVED executables leave in the SERVED cache;
+        refused while the worker, which owns the cache, is alive."""
+        if self.alive:
+            raise ServingError(
+                "run_step: the worker thread owns the cache; stop() first")
+        out, self._cache.pools = self._jit.get(tuple(key))(
+            self._params, self._cache.pools, *args)
+        return out
 
     def fail_pending(self, exc):
         """Fail every queued and active request with ``exc`` — the
@@ -1274,11 +1353,11 @@ class DecodeScheduler:
         idxvec[:packet.n_pages] = pages[:packet.n_pages]
         fn = self._jit.get(("hscatter",))
         with _handoff_stage_timer.time():
-            kp, vp = fn(self._cache.k_pool, self._cache.v_pool,
-                        jnp.asarray(packet.k_host),
-                        jnp.asarray(packet.v_host),
-                        jnp.asarray(idxvec))
-            self._cache.k_pool, self._cache.v_pool = kp, vp
+            self._cache.pools = fn(
+                self._cache.pools,
+                {name: jnp.asarray(a)
+                 for name, a in packet.pages_host.items()},
+                jnp.asarray(idxvec))
         slot = _Slot(req, pages, hashes=packet.hashes)
         slot.kv_len = packet.kv_len
         slot.generated.append(packet.first)
@@ -1389,6 +1468,12 @@ class DecodeScheduler:
                 tags=req.trace.child().tags(priority=req.priority,
                                             seq=req.seq))
         slot = _Slot(req, pages, prefill_pos=cached_tokens, hashes=hashes)
+        if self._cache.slot_leaf_names:
+            # a reused slot's state is void from here on: the sequence's
+            # first chunk (start == 0) takes it as zero inside the chunk
+            # program, so the reset is no dispatch and has no time of its
+            # own: a counter, not a span
+            _state_resets.inc()
         self._slots[idx] = slot
         self._tables[idx] = self._cache.table_row(pages)
         _active_slots.set(self._active_count())
@@ -1474,23 +1559,23 @@ class DecodeScheduler:
             if serve_fault is not None:
                 serve_fault([req])
             with tel.span("serving.decode.prefill.dispatch"):
-                tok, kp, vp = fn(
+                tok, pools = fn(
+                    self._params, self._cache.pools,
                     jnp.asarray(tokens), jnp.int32(start),
-                    jnp.int32(valid),
-                    self._cache.k_pool, self._cache.v_pool,
-                    jnp.asarray(chunk_vec),
-                    jnp.asarray(self._tables[idx]), seed, temp)
+                    jnp.int32(valid), jnp.asarray(chunk_vec),
+                    jnp.asarray(self._tables[idx]), np.int32(idx),
+                    seed, temp)
             with tel.span("serving.decode.prefill.wait") as wait:
                 first = int(np.asarray(tok))
             self._turn_wait_s += wait.duration
-            return first, kp, vp
+            return first, pools
 
         try:
             chunk_wall = time.time()
             # the chunk program, dispatch to readback (retries included)
             with tel.span("serving.decode.prefill", bucket=width,
                           rows=valid, start=start, seq=req.seq) as prefill:
-                first, k_pool, v_pool = _resilience.call_with_retry(
+                first, pools = _resilience.call_with_retry(
                     attempt, policy=self._prefill_policy,
                     on_retry=self._note_prefill_retry(req))
         except Exception as exc:  # noqa: BLE001 — worker must survive
@@ -1520,7 +1605,7 @@ class DecodeScheduler:
                     tags=req.trace.child().tags(
                         phase="prefill", bucket=width, rows=valid,
                         start=start))
-            self._cache.k_pool, self._cache.v_pool = k_pool, v_pool
+            self._cache.pools = pools
             if self._breaker is not None:
                 self._breaker.record_success()
             if self.config.kv_guard and self._guard_pages(
@@ -1571,11 +1656,11 @@ class DecodeScheduler:
         idxvec[:n_pages] = slot.pages[:n_pages]
         fn = self._jit.get(("hgather",))
         with _handoff_stage_timer.time():
-            k, v = fn(self._cache.k_pool, self._cache.v_pool,
-                      jnp.asarray(idxvec))
-            k_host, v_host = np.asarray(k), np.asarray(v)
+            pages_host = {
+                name: np.asarray(a) for name, a in fn(
+                    self._cache.pools, jnp.asarray(idxvec)).items()}
         packet = HandoffPacket(
-            req, k_host, v_host, n_pages=n_pages, kv_len=slot.kv_len,
+            req, pages_host, n_pages=n_pages, kv_len=slot.kv_len,
             hashes=slot.hashes, origin=self._replica_index,
             first=slot.generated[-1])
         req.handoff_origin = self._replica_index
@@ -1585,7 +1670,7 @@ class DecodeScheduler:
         _active_slots.set(self._active_count())
         _handoff_packets.inc()
         _handoff_pages.inc(n_pages)
-        _handoff_bytes.inc(k_host.nbytes + v_host.nbytes)
+        _handoff_bytes.inc(sum(a.nbytes for a in pages_host.values()))
         tel = self._telemetry
         if tel.recording:
             tel.emit({
@@ -1640,14 +1725,14 @@ class DecodeScheduler:
             if serve_fault is not None:
                 serve_fault([req])
             with tel.span("serving.decode.prefill.dispatch"):
-                tok, kp, vp = fn(
+                tok, pools = fn(
+                    self._params, self._cache.pools,
                     jnp.asarray(tokens), jnp.int32(req.prompt_len),
-                    self._cache.k_pool, self._cache.v_pool,
                     jnp.asarray(page_vec), seed, temp)
             with tel.span("serving.decode.prefill.wait") as wait:
                 first = int(np.asarray(tok))
             self._turn_wait_s += wait.duration
-            return first, kp, vp
+            return first, pools
 
         try:
             prefill_wall = time.time()
@@ -1655,7 +1740,7 @@ class DecodeScheduler:
             # included); it runs inside ``serving.decode.admit``
             with tel.span("serving.decode.prefill", bucket=bucket,
                           rows=req.prompt_len, seq=req.seq) as prefill:
-                first, k_pool, v_pool = _resilience.call_with_retry(
+                first, pools = _resilience.call_with_retry(
                     attempt, policy=self._prefill_policy,
                     on_retry=self._note_prefill_retry(req))
         except Exception as exc:  # noqa: BLE001 — worker must survive
@@ -1691,7 +1776,7 @@ class DecodeScheduler:
                 "serving.execute", prefill_wall, prefill.duration,
                 tags=req.trace.child().tags(phase="prefill", bucket=bucket,
                                             rows=req.prompt_len))
-        self._cache.k_pool, self._cache.v_pool = k_pool, v_pool
+        self._cache.pools = pools
         if self._breaker is not None:
             self._breaker.record_success()
         slot = _Slot(req, pages)
@@ -1719,7 +1804,7 @@ class DecodeScheduler:
         import jax.numpy as jnp
 
         fn = self._jit.get(("kvguard", len(page_vec)))
-        ok = np.asarray(fn(self._cache.k_pool, self._cache.v_pool,
+        ok = np.asarray(fn(self._cache.pools,
                            jnp.asarray(page_vec, np.int32)))
         bad = [j for j in range(len(page_vec))
                if page_vec[j] and not ok[j]]
@@ -1908,15 +1993,15 @@ class DecodeScheduler:
             if serve_fault is not None:
                 serve_fault([s.req for _, s in active])
             with tel.span("serving.decode.step.dispatch"):
-                out, kp, vp = fn(
+                out, pools = fn(
+                    self._params, self._cache.pools,
                     jnp.asarray(tokens), jnp.asarray(positions),
-                    self._cache.k_pool, self._cache.v_pool,
                     jnp.asarray(tables), jnp.asarray(kv_lens),
                     jnp.asarray(seeds), jnp.asarray(temps))
             with tel.span("serving.decode.step.wait") as wait:
                 sampled = np.asarray(out)
             self._turn_wait_s += wait.duration
-            return sampled, kp, vp
+            return sampled, pools
 
         def note_retry(exc, attempt_n, delay):
             _step_retries.inc()
@@ -1933,7 +2018,7 @@ class DecodeScheduler:
             # included): the per-iteration step time, and the cell
             # ``decode_step_ms`` reads
             with tel.span("serving.decode.step", active=len(active)):
-                sampled, k_pool, v_pool = _resilience.call_with_retry(
+                sampled, pools = _resilience.call_with_retry(
                     attempt, policy=self._decode_policy,
                     on_retry=note_retry)
         except Exception as exc:  # noqa: BLE001 — worker must survive
@@ -1947,7 +2032,10 @@ class DecodeScheduler:
                 self._breaker.record_fatal()
             return
         with tel.span("serving.decode.step.commit"):
-            self._cache.k_pool, self._cache.v_pool = k_pool, v_pool
+            self._cache.pools = pools
+            # the model's step counters came back behind the tokens
+            for c, n in zip(self._step_counters, sampled[cfg.num_slots:]):
+                c.inc(int(n))
             if self._breaker is not None:
                 self._breaker.record_success()
             tripped = ()

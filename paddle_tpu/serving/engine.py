@@ -607,6 +607,13 @@ class InferenceEngine:
         return self._metrics_server
 
     @property
+    def decoder(self):
+        """The :class:`DecodeScheduler` behind ``generate`` (None without a
+        ``decode_model``): its ``cache`` and ``run_step`` are how a check
+        reads what the served programs leave in the served cache."""
+        return self._decoder
+
+    @property
     def model_version(self):
         return None if self._model is None else self._model.version
 

@@ -1,4 +1,17 @@
-"""Paged KV cache: a preallocated page pool + per-sequence page tables.
+"""Paged KV cache: preallocated page pools + per-sequence page tables, and
+whatever else a model keeps per page or per slot, as ONE pytree.
+
+**One cache, described by the model.**  ``PagedKVCache.pools`` is a dict of
+arrays by leaf name that the scheduler threads whole through every step
+program (donated on a TPU).  *Page-indexed* leaves share ONE allocator and
+ONE page table and have the page axis at 1: ``"k"`` and ``"v"`` (the layers
+that hold paged K/V) and a model's further ``page_pools`` (one row per
+``tokens_per_row`` tokens of a page, e.g. pooled keys for block selection).
+*Slot-indexed* leaves (``slot_state``, the slot axis at 1) hold recurrent
+state of the sequence seated in a slot; the cache only allocates and resets
+them — a sequence's first prefill chunk takes its slot's state as zero inside
+the chunk program, so nothing is zeroed per admission here.  The text below is
+about the K/V leaves; every page-indexed leaf follows the same allocator.
 
 The memory half of the continuous-batching decode runtime (vLLM /
 PagedAttention, Kwon et al. SOSP'23): instead of one contiguous
@@ -10,7 +23,7 @@ of page ids (its page table).  Admission allocates, retirement frees, and
 the pool's occupancy — not a worst-case rectangle — is what bounds how
 many sequences decode concurrently.
 
-**Stored shape.**  A page row holds all heads FOLDED into its last axis,
+**Stored shape of K and V.**  A page row holds all heads FOLDED into its last axis,
 head-major (head ``h`` is ``[..., h*D:(h+1)*D]``), and the layers are
 stacked in front.  That is the shape whose default device layout is
 row-major with unpadded ``(page_size, H*D)`` tiles (``H*D`` a multiple of
@@ -90,6 +103,8 @@ _miss_pages = _obs.counter("serving.decode.kv_miss_pages")
 _evictions = _obs.counter("serving.decode.kv_evictions")
 _shared_pages = _obs.gauge("serving.decode.kv_shared_pages")
 _cached_pages = _obs.gauge("serving.decode.kv_cached_pages")
+_page_bytes = _obs.gauge("serving.cache.page_bytes")
+_state_bytes = _obs.gauge("serving.cache.state_bytes")
 
 
 def write_prompt_kv(k_pool, v_pool, k_new, v_new, pages):
@@ -137,10 +152,21 @@ class PagedKVCache:
         per-slot page-table width ``max_pages_per_seq``.
     dtype: pool dtype (bf16 halves HBM on chip; f32 default for the
         bitwise CPU contract).
+    page_pools: further page-indexed leaves, ``{name: dict(layers=,
+        tokens_per_row=, width=, dtype=)}`` -> ``[layers, num_pages,
+        page_size // tokens_per_row, width]`` (``dtype`` None = ``dtype``).
+    slot_state / num_slots: slot-indexed leaves, ``{name: dict(layers=,
+        shape=, dtype=)}`` -> ``[layers, num_slots, *shape]``.
+    device: commit every leaf there (None: jax's default placement).
+
+    ``pools`` is the whole cache as ONE pytree (a dict of arrays by leaf
+    name): ONE allocator and ONE page table address all page-indexed
+    leaves, the scheduler threads the dict through every step, donated.
     """
 
     def __init__(self, num_layers, num_pages, page_size, num_heads,
-                 head_dim, max_seq_len, dtype="float32"):
+                 head_dim, max_seq_len, dtype="float32", page_pools=None,
+                 slot_state=None, num_slots=0, device=None):
         import jax.numpy as jnp
 
         if num_pages < 2:
@@ -155,10 +181,41 @@ class PagedKVCache:
         self.num_heads = int(num_heads)
         self.head_dim = int(head_dim)
         self.max_seq_len = int(max_seq_len)
+        self.num_slots = int(num_slots)
+        self.device = device
         self.max_pages_per_seq = -(-self.max_seq_len // self.page_size)
         self.dtype = jnp.dtype(dtype)
-        self.k_pool = jnp.zeros(self.pool_shape, self.dtype)
-        self.v_pool = jnp.zeros(self.pool_shape, self.dtype)
+        # every leaf's (shape, dtype) by name; the page-indexed ones have
+        # the page axis at 1, the slot-indexed ones the slot axis at 1
+        self._page_leaves = {
+            "k": (self.pool_shape, self.dtype),
+            "v": (self.pool_shape, self.dtype)}
+        for name, spec in (page_pools or {}).items():
+            if self.page_size % int(spec["tokens_per_row"]):
+                raise ServingError(
+                    "page pool %r keeps one row per %d tokens, which does "
+                    "not divide page_size %d"
+                    % (name, spec["tokens_per_row"], self.page_size))
+            self._page_leaves[name] = (
+                (int(spec["layers"]), self.num_pages,
+                 self.page_size // int(spec["tokens_per_row"]),
+                 int(spec["width"])),
+                jnp.dtype(spec.get("dtype") or self.dtype))
+        self._slot_leaves = {
+            name: ((int(spec["layers"]), self.num_slots)
+                   + tuple(int(d) for d in spec["shape"]),
+                   jnp.dtype(spec["dtype"]))
+            for name, spec in (slot_state or {}).items()}
+        if set(self._page_leaves) & set(self._slot_leaves):
+            raise ServingError("a cache leaf is page-indexed or slot-indexed, "
+                               "not both: %s" % sorted(
+                                   set(self._page_leaves)
+                                   & set(self._slot_leaves)))
+        if self._slot_leaves and self.num_slots < 1:
+            raise ServingError("slot-indexed state needs num_slots >= 1")
+        self.pools = self._zeros()
+        _page_bytes.set(self.page_bytes)
+        _state_bytes.set(self.state_bytes)
         # page 0 = scratch; everything else starts free
         self._free = collections.deque(range(1, self.num_pages))
         self._used = 0
@@ -184,20 +241,100 @@ class PagedKVCache:
         _pages_total.set(self.num_pages - 1)
         self._publish(0)
 
+    def _zeros(self):
+        import jax
+        import jax.numpy as jnp
+
+        pools = {name: jnp.zeros(shape, dtype) for name, (shape, dtype) in
+                 list(self._page_leaves.items())
+                 + list(self._slot_leaves.items())}
+        # committed to ``device`` where one was given (a pool's replica)
+        return (pools if self.device is None
+                else jax.device_put(pools, self.device))
+
+    @property
+    def page_leaf_names(self):
+        """Names of the page-indexed leaves of :attr:`pools` (page axis 1):
+        ``k``, ``v`` and the model's further page pools."""
+        return tuple(self._page_leaves)
+
+    @property
+    def slot_leaf_names(self):
+        """Names of the slot-indexed leaves of :attr:`pools` (slot axis 1)."""
+        return tuple(self._slot_leaves)
+
+    @staticmethod
+    def _nbytes(leaves):
+        return int(sum(int(np.prod(shape)) * dtype.itemsize
+                       for shape, dtype in leaves.values()))
+
+    @property
+    def page_bytes(self):
+        """Bytes of all page-indexed leaves together."""
+        return self._nbytes(self._page_leaves)
+
+    @property
+    def state_bytes(self):
+        """Bytes of all slot-indexed leaves together."""
+        return self._nbytes(self._slot_leaves)
+
+    # the two leaves every model has, by name (tools, tests and the fault
+    # injectors read and poke them; the scheduler threads ``pools`` whole)
+    @property
+    def k_pool(self):
+        return self.pools["k"]
+
+    @k_pool.setter
+    def k_pool(self, value):
+        self.pools["k"] = value
+
+    @property
+    def v_pool(self):
+        return self.pools["v"]
+
+    @v_pool.setter
+    def v_pool(self, value):
+        self.pools["v"] = value
+
     @property
     def pool_shape(self):
-        """``(L, num_pages, page_size, H*D)`` — the stored shape of each
-        pool."""
+        """``(L, num_pages, page_size, H*D)`` — the stored shape of the
+        ``k`` and ``v`` pools."""
         return self.pages_shape(self.num_pages)
 
     def pages_shape(self, n):
-        """Shape of ``n`` pages of every layer, ``pool[:, idx]``: what a
-        handoff packet, a parked session or a scrub holds."""
+        """Shape of ``n`` pages of every K/V layer, ``pool[:, idx]``."""
         return (self.num_layers, int(n), self.page_size,
                 self.num_heads * self.head_dim)
 
+    def gather_pages(self, pools, idx):
+        """``{name: leaf[:, idx]}`` over the page-indexed leaves of
+        ``pools``: what a handoff packet or a scrub holds (pure; jitted by
+        the scheduler)."""
+        return {name: pools[name][:, idx] for name in self._page_leaves}
+
+    def scatter_pages(self, pools, pages, idx):
+        """``pools`` with ``pages`` (a :meth:`gather_pages` tree) written at
+        page ids ``idx``; slot-indexed leaves pass through."""
+        out = dict(pools)
+        for name in self._page_leaves:
+            out[name] = pools[name].at[:, idx].set(pages[name])
+        return out
+
+    def pages_finite(self, pools, idx):
+        """``[N]`` bool: page ``idx[j]`` holds only finite values in every
+        page-indexed leaf and layer (the ``kv_guard`` sweep; pure)."""
+        import jax.numpy as jnp
+
+        ok = None
+        for name in self._page_leaves:
+            fin = jnp.isfinite(pools[name][:, idx]).all(axis=(0, 2, 3))
+            ok = fin if ok is None else ok & fin
+        return ok
+
     def reset_pools(self, force=False):
-        """Reallocate zeroed pools (allocator state untouched).  The
+        """Reallocate every leaf zeroed, slot state included (allocator
+        state untouched).  The
         recovery path after a failed DONATED dispatch, whose consumed
         input buffers are gone either way.  The prefix index is FLUSHED —
         its entries describe page contents that no longer exist.
@@ -209,8 +346,6 @@ class PagedKVCache:
         this raises a typed :class:`ServingError` listing them unless
         ``force=True`` — recovery paths that have already evicted or
         failed their sequences pass ``force=True``."""
-        import jax.numpy as jnp
-
         if not force:
             live = (sorted(self.live_seqs())
                     if self.live_seqs is not None else None)
@@ -225,8 +360,7 @@ class PagedKVCache:
                     "reset_pools would zero %d allocated page(s) with no "
                     "live_seqs callback installed; pass force=True if "
                     "their owners are already failed" % self._used)
-        self.k_pool = jnp.zeros(self.pool_shape, self.dtype)
-        self.v_pool = jnp.zeros(self.pool_shape, self.dtype)
+        self.pools = self._zeros()
         self._index.clear()
         self._hash_of_page.clear()
         for p in self._lru:
@@ -235,7 +369,7 @@ class PagedKVCache:
         _cached_pages.set(0)
 
     def scrub_pages(self, pages):
-        """Zero the given pages in both pools and drop their prefix-index
+        """Zero the given pages in every page-indexed leaf and drop their prefix-index
         entries — the hygiene step after the KV integrity sweep trips.
         Unlike normal retirement (where stale values are unreachable
         because reads mask by ``kv_lens``), a NON-FINITE stale value is
@@ -251,9 +385,9 @@ class PagedKVCache:
         if not scrub:
             return
         idx = jnp.asarray(scrub, jnp.int32)
-        zero = jnp.zeros(self.pages_shape(len(scrub)), self.dtype)
-        self.k_pool = self.k_pool.at[:, idx].set(zero)
-        self.v_pool = self.v_pool.at[:, idx].set(zero)
+        for name, (shape, dtype) in self._page_leaves.items():
+            zero = jnp.zeros((shape[0], len(scrub)) + shape[2:], dtype)
+            self.pools[name] = self.pools[name].at[:, idx].set(zero)
         for p in scrub:
             h = self._hash_of_page.pop(p, None)
             if h is not None:

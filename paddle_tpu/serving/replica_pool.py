@@ -549,11 +549,12 @@ class ReplicaPool:
                     cooldown_s=self._breaker_cooldown_s,
                     state_gauge=_obs.gauge(
                         "serving.replica.decode_breaker_%d" % rep.index))
-                # build + warm INSIDE the device scope so the KV pools,
-                # compiled steps, and warmup dispatches all land on this
-                # replica's device; then COMMIT the pools — the worker
-                # thread dispatches outside any scope, and committed
-                # pool args are what keep the step on this device
+                # build + warm INSIDE the device scope so the compiled
+                # steps and warmup dispatches land on this replica's
+                # device; ``device=`` COMMITS the cache and the replica's
+                # copy of the weights there — the worker thread dispatches
+                # outside any scope, and committed arguments are what keep
+                # the step on this device
                 with jax.default_device(rep.device):
                     rep.decoder = DecodeScheduler(
                         decode_model, config=dcfg, autostart=False,
@@ -568,10 +569,8 @@ class ReplicaPool:
                                 self._dispatch_handoff(r, packet))
                             if rep.role == "prefill" else None),
                         claim=(lambda req, r=rep:
-                               self._may_claim(r, req)))
-                    cache = rep.decoder._cache
-                    cache.k_pool = jax.device_put(cache.k_pool, rep.device)
-                    cache.v_pool = jax.device_put(cache.v_pool, rep.device)
+                               self._may_claim(r, req)),
+                        device=rep.device)
         self._supervisor = None
         if supervise:
             sup = WorkerSupervisor(interval_s=supervisor_interval_s,

@@ -426,8 +426,10 @@ def corrupt_kv_page(scheduler, seq=None, after_tokens=1):
             page = int(slot.pages[slot.kv_len // ps])
             if page == 0:
                 continue
-            cache = scheduler._cache
-            cache.k_pool = cache.k_pool.at[:, page, 0, 0].set(jnp.nan)
+            # the "k" leaf of the cache pytree, which every model has (the
+            # guard sweeps every page-indexed leaf)
+            pools = scheduler._cache.pools
+            pools["k"] = pools["k"].at[:, page, 0, 0].set(jnp.nan)
             fired[0] += 1
             return
 

@@ -423,6 +423,7 @@ class TestChunkedPrefill:
         # chunking / prefix caching need a chunk-capable model
         legacy = serving.DecodeModel(
             decode_model.prefill_fn, decode_model.decode_fn,
+            params=decode_model.params,
             num_layers=decode_model.num_layers,
             num_heads=decode_model.num_heads,
             head_dim=decode_model.head_dim,
@@ -439,6 +440,7 @@ class TestChunkedPrefill:
     def test_legacy_model_without_chunk_fn_still_serves(self, decode_model):
         legacy = serving.DecodeModel(
             decode_model.prefill_fn, decode_model.decode_fn,
+            params=decode_model.params,
             num_layers=decode_model.num_layers,
             num_heads=decode_model.num_heads,
             head_dim=decode_model.head_dim,

@@ -107,6 +107,31 @@ def test_paged_decode_compiles_for_v5e(one_chip, no_persistent_cache, qdtype):
         jax.ShapeDtypeStruct((_S,), jnp.int32, sharding=one_chip)) == 1
 
 
+def test_the_grouped_kernel_is_refused_at_heads_of_64_lanes(
+        one_chip, no_persistent_cache):
+    """Why ``paged_decode_attention`` keeps two kernels: the grouped one
+    (``paged_gqa_decode_attention``, eight listed pages a grid step) picks a
+    KV head's lanes of the folded page by its BlockSpec, which the TPU
+    lowering takes only in multiples of 128 lanes.  Transformer-base's heads
+    are 64 wide: with one query head a KV head and the whole table listed it
+    does not lower, so the kernel that slices lanes inside the page serves it
+    (PERF.md section 6, PR 28; a grouped kernel that slices lanes is S6a's)."""
+    def grouped(q, k_pool, v_pool, tables, lens):
+        k_pool, v_pool, layer, n_kv = FA._stacked_pools(q, k_pool, v_pool, 0)
+        pages, tokens = FA._head_lists(tables, lens, n_kv, None)
+        return FA._paged_gqa_pallas(q, k_pool, v_pool, pages, tokens,
+                                    _DH ** -0.5, False, layer)
+
+    stack = jax.ShapeDtypeStruct((1, _P, _PS, _H * _DH), jnp.bfloat16,
+                                 sharding=one_chip)
+    with pytest.raises(Exception, match="divisible by 8 and 128"):
+        jax.jit(grouped).lower(
+            jax.ShapeDtypeStruct((_S, _H, _DH), jnp.float32, sharding=one_chip),
+            stack, stack,
+            jax.ShapeDtypeStruct((_S, _MP), jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct((_S,), jnp.int32, sharding=one_chip))
+
+
 @pytest.mark.parametrize("chunk", [16, 512], ids=["chunk16", "chunk512"])
 def test_paged_prefill_compiles_for_v5e(one_chip, no_persistent_cache, chunk):
     def f(q, k_pool, v_pool, pages, start):
@@ -165,21 +190,22 @@ def step_programs(one_chip):
     def sds(shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    def decode(k_pool, v_pool, params, tokens, positions, tables, kv_lens):
-        return T.lm_decode_step(params, tokens, positions, k_pool, v_pool,
-                                tables, kv_lens, n_head=_H)
+    def decode(cache, params, tokens, positions, tables, kv_lens):
+        return T.lm_decode_step(params, tokens, positions, cache, tables,
+                                kv_lens, n_head=_H)
 
-    def chunk(k_pool, v_pool, params, tokens, start, valid, chunk_pages,
+    def chunk(cache, params, tokens, start, valid, chunk_pages,
               gather_pages):
-        return T.lm_prefill_chunk(params, tokens, start, valid, k_pool,
-                                  v_pool, chunk_pages, gather_pages,
-                                  n_head=_H)
+        return T.lm_prefill_chunk(params, tokens, start, valid, cache,
+                                  chunk_pages, gather_pages, n_head=_H)
 
     pool = sds((_L, _P, _PS, _DM), jnp.bfloat16)
+    cache = {"k": pool, "v": pool}   # flattens to parameters 0 and 1
     params = jax.tree_util.tree_map(
         lambda a: sds(a.shape, a.dtype),
-        T.lm_params(vocab_size=_V, n_layer=_L, n_head=_H, d_model=_DM,
-                    d_inner=_DI, max_length=_MP * _PS)[0])
+        T.lm_serving_params(T.lm_params(
+            vocab_size=_V, n_layer=_L, n_head=_H, d_model=_DM, d_inner=_DI,
+            max_length=_MP * _PS)[0]))
     args = {"decode": (decode, (sds((_S,)), sds((_S,)), sds((_S, _MP)),
                                 sds((_S,))))}
     for c in (128, 512):   # _chunk_widths() of chunk 512, buckets 128/512/2048
@@ -187,8 +213,8 @@ def step_programs(one_chip):
                                        sds((c // _PS,)), sds((_MP,))))
     with _persistent_cache_off(), pytest.MonkeyPatch.context() as mp:
         mp.setattr(FA, "cpu_backend", lambda: False)
-        return {name: jax.jit(fn, donate_argnums=(0, 1)).lower(
-                    pool, pool, params, *rest).compile()
+        return {name: jax.jit(fn, donate_argnums=(0,)).lower(
+                    cache, params, *rest).compile()
                 for name, (fn, rest) in args.items()}
 
 
