@@ -3,13 +3,25 @@
 Reference analog: the reference computes attention as separate
 matmul/softmax/matmul ops (nets.py scaled_dot_product_attention,
 operators/math/softmax.cu) — O(T²) HBM traffic.  Here the forward is a
-single Pallas kernel (online softmax, O(T) HBM per row block, MXU-shaped
-q·kᵀ and p·v tiles in VMEM).  Two backward engines exist (FLASH_BWD_IMPL):
-the default lax.scan-over-key-blocks formulation, which XLA fuses into a
-single-pass pipeline and which measured fastest on v5e at every T up to
-2048, and a two-Pallas-kernel pair (dk/dv accumulated over query blocks,
-dq over key blocks, p recomputed per tile from q·kᵀ and lse in VMEM) kept
-as a lowering-tested alternative.  Neither materializes a [T, S] tensor.
+single Pallas kernel (online softmax, O(T) HBM per row block, q·kᵀ and p·v
+tiles in VMEM).  Three backward engines exist (FLASH_BWD_IMPL): the
+lax.scan-over-key-blocks formulation in plain XLA, a fused one-grid Pallas
+kernel, which "auto" picks where it fits VMEM at T >= 2048, and a
+two-Pallas-kernel pair kept as a lowering-tested alternative.  None
+materializes a [T, S] tensor.
+
+The forward's tiles are CHOSEN from the shape (``_fwd_tiles``; PR 29).  Until
+then every call walked a grid of 128 x 128 tiles, and on v5e such a grid step
+cost 0.65-0.73 µs whatever it held (ledger, PR 28: 10.7 ms a non-causal call
+on f32[64,2048,64], 3% of its roofline); July's kernel-only sweeps at blocks
+of 128, which the choices below used to quote, measured that overhead and
+not the kernels.  Now a step takes a query block of up to 512 rows against
+the K and V of its (batch, head) held WHOLE in VMEM (fetched once a head),
+walks them 512 keys a turn inside the step up to the last key a row of the
+block can see, and at T <= 512 takes several heads: 1.08 ms for the same
+call (traced chip run, PR 29; PERF.md sections 5 and 6 have what a step and
+a turn cost).
+The backward keeps blocks of 128, independent of the forward's.
 
 Supports causal masking and per-sequence key lengths (`kv_lens`) — the
 padding-mask case of the Fluid transformer — without materializing any
@@ -18,11 +30,17 @@ padding-mask case of the Fluid transformer — without materializing any
 * `kv_lens` rides the scalar-prefetch path (`pltpu.PrefetchScalarGridSpec`,
   SMEM) — a (1, 1)-blocked VMEM operand is not a legal Mosaic block for a
   [B·H]-shaped array.
-* m/l scratch are lane-padded to (block_q, 128); Mosaic vector layouts
-  want the minor dim to be a multiple of 128 (or the full array dim).
+* m/l scratch are lane-replicated (block_q, 128) and stay so through the
+  softmax update (``_lanes``): a [block_q, 1] statistic costs a lane
+  broadcast at every use, which was 40% of a turn.
 * causal masking matches ``mha_reference``'s ``tril(k=S-T)`` — query row t
-  attends keys up to ``t + S - T`` — and fully-masked key blocks are
-  skipped via ``pl.when`` on the grid indices (≈2× on long causal seqs).
+  attends keys up to ``t + S - T`` — and key chunks no row of the query
+  block sees are neither stepped over, computed nor (where S is not held
+  whole) fetched; chunks every row sees whole skip the mask arithmetic.
+* an f32 ``jnp.dot`` in a kernel is ONE bf16 MXU pass at default precision
+  (the chosen tiles differ from blocks of 128 by 1e-3, blocks of 128 from
+  the parent's by 0): operands narrower than f32 would save DMA bytes, not
+  MXU passes.
 
 On CPU (tests) the same kernel runs under ``interpret=True``; the mode is
 inferred from the *input arrays'* platform when they are concrete, falling
@@ -41,6 +59,8 @@ from ..core import cpu_backend
 __all__ = ["flash_attention", "mha_reference", "paged_decode_attention",
            "paged_prefill_attention", "paged_kv_finite"]
 
+# the BACKWARD's blocks (and what tools pass explicitly); the forward chooses
+# its own from the shape (_fwd_tiles)
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 NEG_INF = -1e30
@@ -72,72 +92,179 @@ def mha_reference(q, k, v, causal=False, sm_scale=None, kv_lens=None):
     return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32)).astype(q.dtype)
 
 
+def _lanes(x, n):
+    """A lane-replicated ``[rows, 128]`` statistic at ``n`` lanes, without a
+    lane broadcast where ``n`` is whole vregs or a part of one."""
+    import jax.numpy as jnp
+
+    if n % x.shape[1] == 0:
+        return jnp.tile(x, (1, n // x.shape[1]))
+    if n < x.shape[1]:
+        return x[:, :n]
+    return jnp.broadcast_to(x[:, 0:1], (x.shape[0], n))
+
+
 def _fwd_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, sm_scale, causal, block_q, block_k, num_k_blocks, q_len, kv_len):
+                *, sm_scale, causal, heads, block_q, block_k, chunks, num_k_blocks,
+                q_len, kv_len):
+    """One grid step = ``heads`` (batch, head) pairs x one query block x one
+    RESIDENT key span of ``chunks * block_k`` rows (all of S where it fits
+    VMEM: then K and V are fetched once a (batch, head) and the grid has no
+    key axis to step over).  The key loop runs INSIDE the step, ``block_k``
+    rows a turn, and is bounded by the last chunk a row of this query block
+    can see — a masked chunk is neither stepped over nor computed — and the
+    chunks every row sees whole take the turn without the mask arithmetic."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    b = pl.program_id(0)
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    g = pl.program_id(0)
+    q0 = pl.program_id(1) * block_q
+    kj = pl.program_id(2)
+    k0 = kj * (chunks * block_k)
+    shift = kv_len - q_len  # causal: row t sees keys [0, t + shift] — tril(k=S-T)
 
-    @pl.when(ki == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    def chunk(h, kvl, q, last, c, masked):
+        start = pl.multiple_of(c * block_k, block_k)
+        k = k_ref[h, pl.ds(start, block_k), :].astype(jnp.float32)  # [bk, d]
+        v = v_ref[h, pl.ds(start, block_k), :].astype(jnp.float32)
+        if masked:
+            # zero invalid v rows: 0·NaN from OOB-padded tail tiles would
+            # poison the p·v accumulation even where p is 0 (a score off
+            # such a k row is replaced below, whatever it is)
+            kcol = k0 + start + jax.lax.broadcasted_iota(jnp.int32, (block_k, 1), 0)
+            v = jnp.where(kcol < kvl, v, 0.0)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)  # [bq, bk]
+        if masked:
+            # one compare a score: the chunk's own column index against each
+            # row's last visible one (lane-replicated, as m and l are)
+            col = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+            s = jnp.where(col <= _lanes(last - (k0 + start), block_k), s, NEG_INF)
 
-    kvl = lens_ref[b]  # valid key length for this (batch, head)
-
-    # Skip key blocks that are entirely masked: past the sequence's valid
-    # length, or (causal) strictly above this query block's last visible
-    # diagonal.  Correctness doesn't depend on this — NEG_INF masking
-    # below zeroes their contribution — it only saves the work.
-    visible = ki * block_k < kvl
-    if causal:
-        visible = jnp.logical_and(
-            visible, ki * block_k <= qi * block_q + block_q - 1 + (kv_len - q_len)
-        )
-
-    @pl.when(visible)
-    def _body():
-        q = q_ref[0].astype(jnp.float32)  # [bq, d]
-        k = k_ref[0].astype(jnp.float32)  # [bk, d]
-        v = v_ref[0].astype(jnp.float32)  # [bk, d]
-        # zero invalid k/v rows: 0·NaN from OOB-padded tail tiles would
-        # poison the p·v accumulation even where p is 0
-        kcol = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_k, 1), 0)
-        k = jnp.where(kcol < kvl, k, 0.0)
-        v = jnp.where(kcol < kvl, v, 0.0)
-
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale  # [bq, bk]
-        row = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-        col = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-        ok = col < kvl
-        if causal:
-            # query row t sees keys [0, t + S - T] — tril(k=S-T), matching
-            # mha_reference for T != S (bottom-right aligned)
-            ok = ok & (row + (kv_len - q_len) >= col)
-        s = jnp.where(ok, s, NEG_INF)
-
-        m_prev = m_scr[:, 0:1]  # [bq, 1]
+        # m, l and alpha stay lane-replicated [bq, 128] through the update:
+        # the only lane traffic a turn is the two row reductions
+        m_prev = m_scr[...]
         m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
+        p = jnp.exp(s - _lanes(m_new, block_k))
         alpha = jnp.exp(m_prev - m_new)
-        l_new = l_scr[:, 0:1] * alpha + p.sum(axis=1, keepdims=True)
-        acc_scr[:, :] = acc_scr[:, :] * alpha + jnp.dot(
+        l_scr[...] = l_scr[...] * alpha + p.sum(axis=1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * _lanes(alpha, acc_scr.shape[1]) + jnp.dot(
             p, v, preferred_element_type=jnp.float32
         )
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        m_scr[...] = m_new
 
-    @pl.when(ki == num_k_blocks - 1)
-    def _finish():
-        denom = jnp.maximum(l_scr[:, 0:1], 1e-30)
-        o_ref[0] = (acc_scr[:, :] / denom).astype(o_ref.dtype)
-        # lane-replicated: a (1, bq)-blocked rank-2 output is not a legal
-        # Mosaic block, so lse ships as [bh, T, 128] and lane 0 is read back
-        lse_ref[0] = jnp.broadcast_to(m_scr[:, 0:1] + jnp.log(denom), lse_ref.shape[1:])
+    def head(h, carry):
+        kvl = lens_ref[g * heads + h]  # valid key length of this (batch, head)
+
+        # m/l/acc are one query block's; several heads a step take turns at
+        # them (the chooser gives heads > 1 only with the whole of S resident)
+        @pl.when(kj == 0)
+        def _init():
+            m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+            l_scr[...] = jnp.zeros_like(l_scr)
+            acc_scr[...] = jnp.zeros_like(acc_scr)
+
+        # keys [0, some) are seen by SOME row of the block, [0, every) by
+        # EVERY row: chunks under `every` need no mask, chunks past `some`
+        # no turn.  Correctness rests on the mask alone (NEG_INF zeroes a
+        # masked score); the bounds only save the work — except for the
+        # kv_lens == 0 contract, where no turn is taken and _finish emits 0.
+        some = every = kvl
+        if causal:
+            some = jnp.minimum(kvl, q0 + block_q + shift)
+            every = jnp.minimum(kvl, q0 + shift + 1)
+        n_some = jnp.clip((some - k0 + block_k - 1) // block_k, 0, chunks)
+        n_every = jnp.clip((every - k0) // block_k, 0, n_some)
+
+        # each row's last visible key, [bq, 128] lane-replicated: query row t
+        # sees keys [0, t + S - T] — tril(k=S-T), matching mha_reference for
+        # T != S (bottom-right aligned) — and none from kv_lens on
+        last = jnp.full(m_scr.shape, kvl - 1, jnp.int32)
+        if causal:
+            row = q0 + jax.lax.broadcasted_iota(jnp.int32, m_scr.shape, 0)
+            last = jnp.minimum(last, row + shift)
+
+        # the softmax scale goes into q once a step, not into every score tile
+        q = q_ref[h].astype(jnp.float32) * sm_scale  # [bq, d]
+        jax.lax.fori_loop(
+            0, n_every, lambda c, _: chunk(h, kvl, q, last, c, False), None)
+        jax.lax.fori_loop(
+            n_every, n_some, lambda c, _: chunk(h, kvl, q, last, c, True), None)
+
+        @pl.when(kj == num_k_blocks - 1)
+        def _finish():
+            denom = jnp.maximum(l_scr[...], 1e-30)
+            o_ref[h] = (acc_scr[...] / _lanes(denom, acc_scr.shape[1])).astype(o_ref.dtype)
+            # lane-replicated: a (1, bq)-blocked rank-2 output is not a legal
+            # Mosaic block, so lse ships as [bh, T, 128] and lane 0 is read back
+            lse_ref[h] = m_scr[...] + jnp.log(denom)
+
+        return carry
+
+    jax.lax.fori_loop(0, heads, head, None)
+
+
+# Scoped-VMEM budget of the forward: the chip's 16 MB a core less the margin
+# the backward's model keeps (_FUSED_VMEM_BUDGET).  The only selector: where
+# the model is wrong at some shape the step's compile error says so.
+_FWD_VMEM_BUDGET = 13 * 1024 * 1024
+# A turn of the key loop is one [block_q, block_k] score tile; 512 x 512 is
+# where a turn's fixed costs (the m/l/acc read-modify-write, the loop) stop
+# showing on v5e (PERF.md section 6, PR 29).
+_FWD_BLOCK = 512
+# heads a step at short T: enough that a step holds about a 512 x 512 tile
+_FWD_MAX_HEADS = 8
+
+
+def _fwd_vmem_bytes(heads, block_q, block_k, chunks, D, in_itemsize):
+    """Scoped-VMEM residency of one forward grid step, calibrated against
+    the compiler (the least ``vmem_limit_bytes`` at which a described v5e
+    compiles the kernel, 18 tile choices at the cells' shapes): at T = S =
+    2048, D = 64, f32 it takes 5.25 MB at blocks 256 x 256, 7.0 at 512 x
+    512, 10.25 at 1024 x 512, 13.0 at 1024 x 1024, 4.0 with one 512-row
+    chunk resident instead of all four, and 11.0 at S = 4096 whole.  A VMEM
+    row is 128 lanes wide whatever D is.  Terms:
+      k, v resident span, double-buffered .... 2 * 2 * span * lanes * isz
+      q, out blocks, double-buffered ......... 2 * 2 * block_q * lanes * isz
+      lse block, lane-replicated f32 ......... 2 * block_q * 128 * 4
+      m, l, acc scratch ...................... block_q * (2 * 128 + lanes) * 4
+      one key-loop turn: 1.5 [block_q, block_k] f32 tiles (s and p, partly
+      fused) and the f32 k and v chunks
+    It reads 10-20% over the compiler at every point measured (bf16 more)."""
+    lanes = -(-D // 128) * 128
+    span = chunks * block_k
+    streamed = heads * (4 * span * lanes * in_itemsize
+                        + block_q * (4 * lanes * in_itemsize + 2 * 128 * 4))
+    scratch = block_q * (2 * 128 + lanes) * 4
+    turn = 6 * block_q * block_k + 2 * block_k * lanes * 4
+    return streamed + scratch + turn
+
+
+def _fwd_tiles(bh, T, S, D, in_itemsize):
+    """``(heads, block_q, block_k, chunks)`` of the forward, chosen from the
+    shape alone (no probe, no fallback): a query block and a key chunk of up
+    to ``_FWD_BLOCK`` rows, as much of S resident a step as the budget holds
+    (all of it at the cells' shapes), and at one query block a (batch,
+    head) several heads a step.  ``causal`` does not enter: on v5e 512 x 512
+    was the fastest tile with and without it (a smaller query block trims
+    the masked diagonal and loses more to the step's fixed costs)."""
+    block_q = min(_FWD_BLOCK, T)
+    block_k = min(_FWD_BLOCK, S)
+
+    def fits(heads, chunks):
+        return _fwd_vmem_bytes(heads, block_q, block_k, chunks, D,
+                               in_itemsize) <= _FWD_VMEM_BUDGET
+
+    chunks = max(1, S // block_k)
+    while chunks > 1 and not fits(1, chunks):
+        chunks = -(-chunks // 2)
+    heads = 1
+    if block_q == T and chunks * block_k >= S:
+        for n in range(2, _FWD_MAX_HEADS + 1):
+            if bh % n == 0 and n * block_q * block_k * chunks <= 2 * _FWD_BLOCK ** 2 \
+                    and fits(n, chunks):
+                heads = n
+    return heads, block_q, block_k, chunks
 
 
 def _flash_fwd(q, k, v, kv_lens, causal, sm_scale, block_q, block_k, interpret):
@@ -145,13 +272,22 @@ def _flash_fwd(q, k, v, kv_lens, causal, sm_scale, block_q, block_k, interpret):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    from .. import observability as obs
+
     B, H, T, D = q.shape
     S = k.shape[2]
-    bq = min(block_q, T)
-    bk = min(block_k, S)
-    nq = -(-T // bq)
-    nk = -(-S // bk)
     bh = B * H
+    heads, bq, bk, chunks = _fwd_tiles(bh, T, S, D, q.dtype.itemsize)
+    if block_q is not None or block_k is not None:
+        # explicit blocks are taken as given: one head a step, and as many
+        # whole chunks of S resident as the array holds
+        bq = min(block_q or bq, T)
+        bk = min(block_k or bk, S)
+        heads, chunks = 1, max(1, S // bk)
+    span = chunks * bk
+    nq = -(-T // bq)
+    nk = -(-S // span)
+    assert heads == 1 or nk == 1, (heads, bq, bk, chunks)  # one m/l/acc a step
     qr = q.reshape(bh, T, D)
     kr = k.reshape(bh, S, D)
     vr = v.reshape(bh, S, D)
@@ -160,21 +296,40 @@ def _flash_fwd(q, k, v, kv_lens, causal, sm_scale, block_q, block_k, interpret):
     else:
         lens_bh = jnp.repeat(kv_lens.astype(jnp.int32), H)
 
+    # what was chosen, once per compiled shape (this runs at trace time): a
+    # reader of a device trace divides the kernel's time by its grid steps
+    steps = obs.counter("flash.fwd.grid_steps", labels={
+        "T": T, "S": S, "block": "%dx%d" % (bq, bk), "bh": bh,
+        "causal": int(bool(causal))})
+    if not steps.value:
+        steps.inc((bh // heads) * nq * nk)
+
+    def kv_block(g, i, j, lens):
+        if nk == 1:
+            return (g, 0, 0)
+        # a key span no row of the query block sees keeps the index of the
+        # last one seen: the pipeline then issues no copy for it (nk > 1
+        # comes with heads == 1, so lens[g] is the step's one length)
+        some = lens[g]
+        if causal:
+            some = jnp.minimum(some, i * bq + bq + (S - T))
+        return (g, jnp.minimum(j, jnp.maximum((some - 1) // span, 0)), 0)
+
     kernel = functools.partial(
-        _fwd_kernel, sm_scale=sm_scale, causal=causal,
-        block_q=bq, block_k=bk, num_k_blocks=nk, q_len=T, kv_len=S,
+        _fwd_kernel, sm_scale=sm_scale, causal=causal, heads=heads,
+        block_q=bq, block_k=bk, chunks=chunks, num_k_blocks=nk, q_len=T, kv_len=S,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(bh, nq, nk),
+        grid=(bh // heads, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, i, j, lens: (b, i, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j, lens: (b, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j, lens: (b, j, 0)),
+            pl.BlockSpec((heads, bq, D), lambda g, i, j, lens: (g, i, 0)),
+            pl.BlockSpec((heads, span, D), kv_block),
+            pl.BlockSpec((heads, span, D), kv_block),
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, i, j, lens: (b, i, 0)),
-            pl.BlockSpec((1, bq, 128), lambda b, i, j, lens: (b, i, 0)),
+            pl.BlockSpec((heads, bq, D), lambda g, i, j, lens: (g, i, 0)),
+            pl.BlockSpec((heads, bq, 128), lambda g, i, j, lens: (g, i, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 128), jnp.float32),  # running max (lane-replicated)
@@ -199,8 +354,8 @@ def _flash_fwd(q, k, v, kv_lens, causal, sm_scale, block_q, block_k, interpret):
 
 def _flash_bwd_scan(causal, sm_scale, block_k, res, do):
     """Blockwise flash backward in plain JAX (lax.scan over key blocks) —
-    the default engine; see FLASH_BWD_IMPL for the v5e measurements that
-    picked it over the Pallas kernel pair."""
+    what "auto" picks where the fused kernel does not fit VMEM or T is
+    under _FUSED_MIN_T; see FLASH_BWD_IMPL."""
     import jax.numpy as jnp
 
     q, k, v, kv_lens, out, lse = res
@@ -361,22 +516,23 @@ def _bwd_dq_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
         dq_ref[0] = dq_scr[:, :].astype(dq_ref.dtype)
 
 
-# Backward engine switch.  Measured on v5e.  Round 3 (fwd+bwd, causal,
-# H=8 D=64, tokens held at 16k): scan 9.9/11.6/14.7/20.8 ms vs the
-# two-kernel pallas pair 11.1/13.2/18.1/27.6 ms at T=256/512/1024/2048 —
-# XLA fuses the scan's per-block einsums into a single-pass pipeline (p
-# computed once feeds dv/dq/dk), while the pair recomputes the score
-# matmuls in each pass (7 matmuls vs 5).  The third engine, "fused", is
-# the dq+dkv-in-ONE-grid kernel: full-T q/do/lse stay resident in VMEM,
-# the grid walks key blocks, each step emits that block's dk/dv AND
-# accumulates dq in a VMEM scratch — 5 matmuls and every tensor touches
-# HBM exactly once.  Round 5 on-chip sweep (tools/bench_flash_bwd.py,
-# 16k tokens, B adjusted): T=2048 scan 22.0 / fused 16.95 / pair 27.6 ms
-# (fused wins by 23%); T=4096 the fused kernel FAILS to compile — scoped
-# VMEM 16.70M vs the 16.00M/core limit — so scan carries long T.
-# "auto" (the default) picks: fused where the calibrated VMEM model fits
-# AND T >= _FUSED_MIN_T (short T is latency-bound and scan wins), scan
-# elsewhere.
+# Backward engine switch.  "scan" is lax.scan over key blocks in plain XLA
+# (p computed once a block feeds dv/dq/dk: 5 matmuls); "pallas" the
+# two-kernel pair, which recomputes the score matmuls in each pass (7
+# matmuls); "fused" the dq+dkv-in-ONE-grid kernel: full-T q/do/lse stay
+# resident in VMEM, the grid walks key blocks, each step emits that block's
+# dk/dv AND accumulates dq in a VMEM scratch — 5 matmuls and every tensor
+# touches HBM exactly once.  "auto" (the default) picks fused where the
+# calibrated VMEM model fits AND T >= _FUSED_MIN_T, scan elsewhere.
+# What this rests on: the rounds 3 and 5 sweeps (tools/bench_flash_bwd.py,
+# fwd+bwd, causal, bf16, 16k tokens) timed the engines BEHIND the old
+# forward, whose 128 x 128 grid steps were most of every figure (T=2048:
+# scan 22.0 / fused 16.95 / pair 27.6 ms with a ~9 ms forward in each), so
+# their absolute numbers are replaced by the ledger's and PR 29's.  What
+# stands: their ORDER at T=2048 (fused < scan < pair) and the fused
+# kernel's compile-time OOM at T=4096 (scoped VMEM 16.70M of 16.00M).
+# Whether scan still wins under T=2048, now that the forward no longer
+# hides the difference, is ROADMAP S2's to read.
 FLASH_BWD_IMPL = os.environ.get("PADDLE_TPU_FLASH_BWD", "auto").strip().lower()
 if FLASH_BWD_IMPL not in ("auto", "scan", "fused", "pallas"):
     import warnings
@@ -420,14 +576,14 @@ def _fused_bwd_vmem_bytes(T, D, in_itemsize, block_k):
 
 
 def _flash_bwd(causal, sm_scale, block_q, block_k, interpret, res, do):
+    # the backward's blocks are its own: the "auto" rule and the fused
+    # kernel's VMEM model are calibrated at 128, whatever the forward chose
+    block_q = block_q or DEFAULT_BLOCK_Q
+    block_k = block_k or DEFAULT_BLOCK_K
     if FLASH_BWD_BLOCK_K:
         block_k = int(FLASH_BWD_BLOCK_K)
-    impl = FLASH_BWD_IMPL
-    if impl == "auto":
-        q = res[0]
-        T, D = q.shape[2], q.shape[3]
-        fits = _fused_bwd_vmem_bytes(T, D, q.dtype.itemsize, min(block_k, k_len(res))) <= _FUSED_VMEM_BUDGET
-        impl = "fused" if (T >= _FUSED_MIN_T and fits) else "scan"
+    q, k = res[0], res[1]
+    impl = _bwd_engine(q.shape[2], k.shape[2], q.shape[3], q.dtype.itemsize, block_k)
     if impl == "fused":
         return _flash_bwd_fused(causal, sm_scale, block_k, interpret, res, do)
     if impl == "pallas":
@@ -435,8 +591,13 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, interpret, res, do):
     return _flash_bwd_scan(causal, sm_scale, block_k, res, do)
 
 
-def k_len(res):
-    return res[1].shape[2]
+def _bwd_engine(T, S, D, in_itemsize, block_k=DEFAULT_BLOCK_K):
+    """The backward engine FLASH_BWD_IMPL names for a shape; under "auto",
+    fused where its VMEM model fits and T >= _FUSED_MIN_T, scan elsewhere."""
+    if FLASH_BWD_IMPL != "auto":
+        return FLASH_BWD_IMPL
+    fits = _fused_bwd_vmem_bytes(T, D, in_itemsize, min(block_k, S)) <= _FUSED_VMEM_BUDGET
+    return "fused" if (T >= _FUSED_MIN_T and fits) else "scan"
 
 
 def _fused_bwd_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, ld_ref,
@@ -673,9 +834,12 @@ def _flash_bwd_pallas(causal, sm_scale, block_q, block_k, interpret, res, do):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
 def flash_attention(q, k, v, kv_lens=None, causal=False, sm_scale=None,
-                    block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K, interpret=None):
+                    block_q=None, block_k=None, interpret=None):
     """Fused attention, [B, H, T, D] → [B, H, T, D].  ``kv_lens`` ([B] int32)
-    masks keys past each sequence's length (padding mask)."""
+    masks keys past each sequence's length (padding mask).  ``block_q`` /
+    ``block_k`` of None mean "chosen from the shape" (``_fwd_tiles``; the
+    backward then keeps its own 128); a given value is taken as given by
+    the forward and the backward both."""
     out, _ = _flash_impl(q, k, v, kv_lens, causal, sm_scale, block_q, block_k, interpret)
     return out
 

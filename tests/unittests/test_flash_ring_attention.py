@@ -131,6 +131,124 @@ def test_flash_uneven_tail_block():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-4, atol=2e-4)
 
 
+def _small_chooser(monkeypatch, vmem_budget=None):
+    """The chooser at toy widths: blocks of at most 16 rows (and, with a
+    small budget, only part of S resident a step), so the interpret-mode
+    shapes below walk the same forms the cells' shapes do."""
+    monkeypatch.setattr(FA, "_FWD_BLOCK", 16)
+    if vmem_budget is not None:
+        monkeypatch.setattr(FA, "_FWD_VMEM_BUDGET", vmem_budget)
+
+
+# (T, S): T = S in several query blocks, T < S (bottom-right-aligned causal),
+# an uneven tail in both, and a T of one query block (several heads a step)
+_CHOSEN_SHAPES = {"T=S": (64, 64), "T<S": (24, 56), "tail": (40, 40),
+                  "heads": (16, 16)}
+_CHOSEN_LENS = {"full": None, "ragged": lambda S: [S, S // 2 + 1, 3],
+                "zero-row": lambda S: [S - 5, 0, S]}
+
+
+@pytest.mark.parametrize("bwd_impl", ["scan", "pallas", "fused"])
+@pytest.mark.parametrize("lens", list(_CHOSEN_LENS))
+@pytest.mark.parametrize("shape", list(_CHOSEN_SHAPES))
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+def test_flash_chosen_tiles_match_reference(causal, shape, lens, bwd_impl,
+                                            monkeypatch):
+    """block_q = block_k = None: the forward's tiles come from the shape
+    (``_fwd_tiles``) and the backward keeps its own; output and the three
+    gradients against the plain reference."""
+    _small_chooser(monkeypatch)
+    monkeypatch.setattr(FA, "FLASH_BWD_IMPL", bwd_impl)
+    monkeypatch.setattr(FA, "DEFAULT_BLOCK_Q", 16)
+    monkeypatch.setattr(FA, "DEFAULT_BLOCK_K", 16)
+    T, S = _CHOSEN_SHAPES[shape]
+    B, H, D = 3, 2, 8
+    ks = jax.random.split(jax.random.PRNGKey(11), 4)
+    q = jax.random.normal(ks[0], (B, H, T, D), jnp.float32)
+    k = jax.random.normal(ks[1], (B, H, S, D), jnp.float32)
+    v = jax.random.normal(ks[2], (B, H, S, D), jnp.float32)
+    w = jax.random.normal(ks[3], (B, H, T, D), jnp.float32)
+    kv_lens = _CHOSEN_LENS[lens]
+    if kv_lens is not None:
+        kv_lens = jnp.array(kv_lens(S), jnp.int32)
+    heads, bq, bk, chunks = FA._fwd_tiles(B * H, T, S, D, 4)
+    if shape == "heads":
+        assert heads > 1 and bq == T
+    else:
+        assert bq < T and (chunks > 1 or bk * chunks < S)
+
+    def run(attn):
+        def f(q, k, v):
+            out = attn(q, k, v, kv_lens=kv_lens, causal=causal)
+            return jnp.sum(out * w), out
+        (_, out), grads = jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        return (out,) + grads
+
+    got, want = run(flash_attention), run(mha_reference)
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]), rtol=2e-4, atol=2e-4)
+    if kv_lens is not None and lens == "zero-row":
+        assert not np.asarray(got[0])[1].any()  # kv_lens == 0 -> exact zeros
+    for a, b in zip(got[1:], want[1:]):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-3, atol=2e-3)
+
+
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+def test_flash_chosen_tiles_with_part_of_S_resident(causal, monkeypatch):
+    """A budget that holds only part of S a step: the grid gets its key axis
+    back, and a key span no row of the query block sees is clamped to the
+    last one seen (no copy, no turn)."""
+    _small_chooser(monkeypatch, vmem_budget=100 * 1024)
+    B, H, T, S, D = 3, 1, 48, 80, 8
+    heads, bq, bk, chunks = FA._fwd_tiles(B * H, T, S, D, 4)
+    assert heads == 1 and -(-S // (bk * chunks)) > 2
+    ks = jax.random.split(jax.random.PRNGKey(12), 3)
+    q = jax.random.normal(ks[0], (B, H, T, D), jnp.float32)
+    k = jax.random.normal(ks[1], (B, H, S, D), jnp.float32)
+    v = jax.random.normal(ks[2], (B, H, S, D), jnp.float32)
+    lens = jnp.array([S, 21, 0], jnp.int32)
+    out = flash_attention(q, k, v, lens, causal)
+    ref = mha_reference(q, k, v, causal=causal, kv_lens=lens)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-4, atol=2e-4)
+
+
+# the benchmark's three training shapes [B*H, T, D] and the backward engine
+# "auto" picked for them before the forward chose its own tiles (PR 28)
+_CELL_SHAPES = [((512, 256, 64), "scan"), ((64, 2048, 64), "fused"),
+                ((32, 4096, 64), "scan")]
+
+
+@pytest.mark.parametrize("itemsize", [4, 2], ids=["f32", "bf16"])
+@pytest.mark.parametrize("shape,engine", _CELL_SHAPES,
+                         ids=["s256", "s2048", "s4096"])
+def test_flash_chooser_at_the_cells_shapes(shape, engine, itemsize):
+    bh, T, D = shape
+    heads, bq, bk, chunks = FA._fwd_tiles(bh, T, T, D, itemsize)
+    assert FA._fwd_vmem_bytes(heads, bq, bk, chunks, D, itemsize) <= FA._FWD_VMEM_BUDGET
+    assert bh % heads == 0 and bq <= T and bk * chunks == T  # all of S resident
+    # a step worth taking: at least sixteen of the old 128 x 128 tiles
+    assert heads * bq * bk * chunks >= 16 * 128 * 128
+    # a short sequence does not pay for a long one's tiles
+    assert (heads > 1) == (T <= FA._FWD_BLOCK)
+    # the backward is the parent's: its blocks stay 128 whatever came out above
+    assert FA._bwd_engine(T, T, D, itemsize) == engine
+
+
+def test_flash_fwd_grid_steps_recorded_once_per_compiled_shape():
+    from paddle_tpu import observability as obs
+
+    B, H, T, D = 2, 2, 32, 8
+    q, k, v = _rand_qkv(B=B, H=H, T=T, D=D, seed=13)
+    heads, bq, bk, chunks = FA._fwd_tiles(B * H, T, T, D, 4)
+    labels = {"T": T, "S": T, "block": "%dx%d" % (bq, bk), "bh": B * H, "causal": 1}
+    cell = obs.counter("flash.fwd.grid_steps", labels=labels)
+    before = cell.value
+    f = jax.jit(lambda q, k, v: flash_attention(q, k, v, None, True))
+    for _ in range(3):
+        f(q, k, v).block_until_ready()
+    steps = (B * H // heads) * -(-T // bq) * -(-T // (bk * chunks))
+    assert cell.value == (before or steps) == steps
+
+
 @pytest.mark.parametrize("causal", [False, True])
 def test_ring_attention_matches_full(causal):
     assert jax.device_count() >= 8, "conftest must force 8 cpu devices"

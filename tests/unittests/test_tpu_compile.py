@@ -83,6 +83,31 @@ def test_flash_fwd_bwd_compiles_for_v5e(one_chip, no_persistent_cache, shape,
     assert _kernel_calls(jax.grad(loss, argnums=(0, 1, 2)), x, x, x) == kernels
 
 
+# What the three training cells hand the kernel: ``decorate`` leaves the
+# activations f32 (twice the tile bytes of the bf16 cases above), every call
+# brings ``kv_lens``, and the forward's tiles are the chooser's own
+# (``block_q = block_k = None``).  A tile that fits scoped VMEM on paper
+# (``_fwd_vmem_bytes``) has to fit it here.
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("shape,kernels", [
+    ((64, 8, 256, 64), 1),
+    ((8, 8, 2048, 64), 2),
+    ((4, 8, 4096, 64), 1),
+], ids=["b64xT256", "b8xT2048-fused", "b4xT4096-scan"])
+def test_flash_chosen_tiles_compile_for_v5e(one_chip, no_persistent_cache,
+                                            shape, kernels, dtype, causal):
+    x = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    lens = jax.ShapeDtypeStruct(shape[:1], jnp.int32, sharding=one_chip)
+
+    def loss(q, k, v, lens):
+        out = FA.flash_attention(q, k, v, lens, causal, interpret=False)
+        return out.astype(jnp.float32).sum()
+
+    assert _kernel_calls(jax.grad(loss, argnums=(0, 1, 2)), x, x, x, lens) == kernels
+
+
 # Transformer-base serving widths: 64 slots, 8 heads x 64, 16-token pages,
 # bf16 pools holding a 2048-token context per slot
 _S, _H, _DH, _PS, _MP = 64, 8, 64, 16, 128

@@ -3,7 +3,10 @@
 Times fwd+bwd for scan vs the fused one-grid Pallas backward (and the
 two-kernel pair) at long sequence lengths, tokens held constant.  Run on
 a healthy TPU:  python tools/bench_flash_bwd.py
-Prints a markdown table for PERF.md.
+Prints a markdown table for PERF.md.  The forward in every figure is the
+one the program runs (tiles chosen from the shape, PR 29: under 1 ms at
+T=2048 causal where the rounds 3-5 tables carried a ~9 ms forward); the
+backward's blocks stay 128 (64 for fused64).
 """
 from __future__ import annotations
 
@@ -47,7 +50,7 @@ def main():
             FA.FLASH_BWD_BLOCK_K = 64 if impl == "fused64" else None
 
             def loss(q, k, v):
-                o = FA.flash_attention(q, k, v, None, True, None, 128, 128,
+                o = FA.flash_attention(q, k, v, None, True, None, None, None,
                                        None if on_tpu else True)
                 return (o.astype(jnp.float32) ** 2).sum()
 
