@@ -393,8 +393,8 @@ def _reference_logits(params, sz, prompt, accepted):
     padded[:len(seq)] = seq
     with jax.default_matmul_precision("highest"):
         logits, _, _ = jax.jit(
-            lambda t, n: T.lm_prefill(params, t, n, n_head=sz["n_head"],
-                                      use_flash=False))(padded, len(seq))
+            lambda t, n: T.lm_prefill(params, t, n,
+                                      n_head=sz["n_head"]))(padded, len(seq))
     return np.asarray(logits, np.float64)
 
 

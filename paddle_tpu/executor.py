@@ -29,8 +29,7 @@ conformed only when it disagrees with the compiled step's shardings — so
 a prefetched batch costs zero host copies at dispatch
 (``feed_host_copy_count`` instruments the contract).
 Invalidation: ``program.version`` bump, any public
-scope mutation, feed shape/dtype drift.  ``PADDLE_TPU_FAST_PATH=0`` /
-``PADDLE_TPU_LAZY_FETCH=0`` are killswitches.
+scope mutation, feed shape/dtype drift.
 A persistent XLA compile cache (``$JAX_COMPILATION_CACHE_DIR``, else
 ``<checkout>/.jax_cache``) lets warm-up survive process restarts
 (enable_compilation_cache).
@@ -1081,10 +1080,10 @@ class Executor:
         # device-side result of the last nan_guard finiteness check; None
         # when the last run had no guard (see last_step_ok)
         self._last_guard_flag = None
-        # fast-path dispatch (bound-program cache + lazy fetches); both
-        # default on, killswitch via env for A/B and debugging
-        self.fast_path = os.environ.get("PADDLE_TPU_FAST_PATH", "1") != "0"
-        self.lazy_fetches = os.environ.get("PADDLE_TPU_LAZY_FETCH", "1") != "0"
+        # fast-path dispatch (bound-program cache + lazy fetches); tests
+        # and bench_dispatch.py turn one off to compare with the rebind path
+        self.fast_path = True
+        self.lazy_fetches = True
         # set by ParallelExecutor: jax.sharding.Mesh for data-parallel SPMD;
         # a 2-D ("dp","tp") mesh additionally Megatron-shards parameters
         # (see parallel/tp.py), optionally refined by _sharding_rules

@@ -2,7 +2,7 @@
 
 Each model module exposes the reference's builder signature: a function that
 constructs the program (layers only — training wiring is up to the caller)
-plus a ``get_model``-style helper used by bench.py.
+plus a ``get_model``-style helper used by benchmarks/fluid_benchmark.py.
 """
 from . import mnist  # noqa: F401
 from . import vgg  # noqa: F401

@@ -540,10 +540,8 @@ def build_decode_model(params, cfg, eos_id=None, attn_impl=None):
 
     _dims(cfg)
     return DecodeModel(
-        None,
         functools.partial(sala_decode_step, cfg=cfg, attn_impl=attn_impl),
-        prefill_chunk_fn=functools.partial(sala_prefill_chunk, cfg=cfg,
-                                           attn_impl=attn_impl),
+        functools.partial(sala_prefill_chunk, cfg=cfg, attn_impl=attn_impl),
         params=params, vocab_size=cfg["vocab_size"], eos_id=eos_id,
         name="minicpm-sala", step_counters=STEP_COUNTERS,
         **cache_layout(cfg))

@@ -645,10 +645,10 @@ def get_inference_model(
 # scaled embedding + sinusoid positions, bias-free projections), exposed
 # through ``build_decode_model`` as the ``DecodeModel`` pair:
 #
-# * ``lm_prefill``: the padded prompt in one causal pass (flash kernel on
-#   TPU, mha_reference elsewhere), returning per-layer K/V for the
-#   scheduler to scatter into pages + the last real token's logits.
-#   LEGACY — kept for chunk-less DecodeModels; the scheduler prefers:
+# * ``lm_prefill``: the padded prompt in one plain causal pass
+#   (``mha_reference``, no cache) to the last real token's logits and the
+#   per-layer K/V.  Not served: the reference that ``chip_smoke.py``
+#   holds the served tokens to.
 # * ``lm_prefill_chunk``: one resumable prefill CHUNK over the paged
 #   pool — scatter the window's k/v into the sequence's pages, attend
 #   through the page table over everything cached so far
@@ -720,15 +720,15 @@ def _lm_block_tail(lp, x, attn_out):
                   lp["ln2_s"], lp["ln2_b"])
 
 
-def lm_prefill(params, tokens, length, *, n_head, use_flash=False):
-    """Causal pass over one padded prompt.  ``tokens``: [T] int32 (pad
-    tail arbitrary), ``length``: real token count.  Returns
-    ``(last_logits [V], k [L, T, H, Dh], v [L, T, H, Dh])`` — k/v in the
-    page-scatter layout, pad-tail rows masked downstream by kv_lens."""
+def lm_prefill(params, tokens, length, *, n_head):
+    """Plain causal pass over one padded prompt, the reference beside the
+    served chunk program.  ``tokens``: [T] int32 (pad tail arbitrary),
+    ``length``: real token count.  Returns ``(last_logits [V],
+    k [L, T, H, Dh], v [L, T, H, Dh])``."""
     import jax
     import jax.numpy as jnp
 
-    from ..parallel.flash_attention import flash_attention, mha_reference
+    from ..parallel.flash_attention import mha_reference
 
     T = tokens.shape[0]
     d_model = params["tok_emb"].shape[1]
@@ -748,8 +748,7 @@ def lm_prefill(params, tokens, length, *, n_head, use_flash=False):
         q4 = q.transpose(1, 0, 2)[None]  # [1, H, T, Dh]
         k4 = k.transpose(1, 0, 2)[None]
         v4 = v.transpose(1, 0, 2)[None]
-        attn = flash_attention if use_flash else mha_reference
-        ctx = attn(q4, k4, v4, causal=True, kv_lens=lens1)
+        ctx = mha_reference(q4, k4, v4, causal=True, kv_lens=lens1)
         ctx = ctx[0].transpose(1, 0, 2).reshape(T, d_model)
         x = _lm_block_tail(lp, x, ctx)
     last = jax.lax.dynamic_index_in_dim(x, length - 1, axis=0,
@@ -901,32 +900,22 @@ def lm_decode_step(params, tokens, positions, cache, page_tables, kv_lens,
     return x @ params["out_w"], dict(cache, k=k_pool, v=v_pool)
 
 
-def build_decode_model(params, meta, eos_id=None, use_flash=None,
-                       attn_impl=None):
+def build_decode_model(params, meta, eos_id=None, attn_impl=None):
     """Wrap LM weights as a serving ``DecodeModel``: the weights ride in
     ``DecodeModel.params`` (:func:`lm_serving_params`) and every step takes
-    them as an argument.
-
-    ``use_flash``: LEGACY whole-prompt prefill attention engine (default:
-    flash on TPU, mha_reference elsewhere) — kept for ``prefill_fn``
-    compatibility; the scheduler prefers ``prefill_chunk_fn``, whose
-    paged attention engine is ``attn_impl`` ("auto"/"reference"/
-    "pallas", shared with the decode step's paged_decode_attention).
+    them as an argument.  ``attn_impl`` ("auto"/"reference"/"pallas") is
+    the paged attention engine of the chunk and the decode step both.
     """
     import functools
 
-    from ..core import cpu_backend
     from ..serving.decode_scheduler import DecodeModel
 
-    if use_flash is None:
-        use_flash = not cpu_backend()
     n_head = meta["n_head"]
     return DecodeModel(
-        functools.partial(lm_prefill, n_head=n_head, use_flash=use_flash),
         functools.partial(lm_decode_step, n_head=n_head,
                           attn_impl=attn_impl),
-        prefill_chunk_fn=functools.partial(
-            lm_prefill_chunk, n_head=n_head, attn_impl=attn_impl),
+        functools.partial(lm_prefill_chunk, n_head=n_head,
+                          attn_impl=attn_impl),
         params=lm_serving_params(params),
         num_layers=meta["n_layer"], num_heads=n_head,
         head_dim=meta["head_dim"], vocab_size=meta["vocab_size"],

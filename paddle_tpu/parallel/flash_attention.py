@@ -4,11 +4,10 @@ Reference analog: the reference computes attention as separate
 matmul/softmax/matmul ops (nets.py scaled_dot_product_attention,
 operators/math/softmax.cu) — O(T²) HBM traffic.  Here the forward is a
 single Pallas kernel (online softmax, O(T) HBM per row block, q·kᵀ and p·v
-tiles in VMEM).  Three backward engines exist (FLASH_BWD_IMPL): the
-lax.scan-over-key-blocks formulation in plain XLA, a fused one-grid Pallas
-kernel, which "auto" picks where it fits VMEM at T >= 2048, and a
-two-Pallas-kernel pair kept as a lowering-tested alternative.  None
-materializes a [T, S] tensor.
+tiles in VMEM).  Two backward engines exist, chosen from the shape
+(``_bwd_engine``): a fused one-grid Pallas kernel where it fits VMEM at
+T >= 2048, and the lax.scan-over-key-blocks formulation in plain XLA
+elsewhere.  Neither materializes a [T, S] tensor.
 
 The forward's tiles are CHOSEN from the shape (``_fwd_tiles``; PR 29).  Until
 then every call walked a grid of 128 x 128 tiles, and on v5e such a grid step
@@ -49,7 +48,6 @@ back to the default backend under tracing.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import numpy as np
@@ -59,8 +57,8 @@ from ..core import cpu_backend
 __all__ = ["flash_attention", "mha_reference", "paged_decode_attention",
            "paged_prefill_attention", "paged_kv_finite"]
 
-# the BACKWARD's blocks (and what tools pass explicitly); the forward chooses
-# its own from the shape (_fwd_tiles)
+# the BACKWARD's key block, and what tools and tests pass explicitly; the
+# forward chooses its own from the shape (_fwd_tiles)
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 NEG_INF = -1e30
@@ -354,8 +352,8 @@ def _flash_fwd(q, k, v, kv_lens, causal, sm_scale, block_q, block_k, interpret):
 
 def _flash_bwd_scan(causal, sm_scale, block_k, res, do):
     """Blockwise flash backward in plain JAX (lax.scan over key blocks) —
-    what "auto" picks where the fused kernel does not fit VMEM or T is
-    under _FUSED_MIN_T; see FLASH_BWD_IMPL."""
+    what ``_bwd_engine`` picks where the fused kernel does not fit VMEM or T
+    is under _FUSED_MIN_T."""
     import jax.numpy as jnp
 
     q, k, v, kv_lens, out, lse = res
@@ -404,149 +402,18 @@ def _flash_bwd_scan(causal, sm_scale, block_k, res, do):
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
-def _bwd_tiles(lens_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *,
-               b, qi, ki, sm_scale, causal, block_q, block_k, q_len, kv_len):
-    """Shared per-tile recomputation for both backward kernels: returns
-    (p, ds, q, k, v, do) for one (q block, k block) pair, with every
-    invalid row/column already zeroed (OOB-padded tiles read garbage that
-    would otherwise poison the accumulators)."""
-    import jax.numpy as jnp
-
-    kvl = lens_ref[b]
-    q = q_ref[0].astype(jnp.float32)
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
-    o = o_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0][:, 0:1]  # lane-replicated; lane 0 is the value
-
-    rowv = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0) < q_len
-    colv = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_k, 1), 0) < kvl
-    q = jnp.where(rowv, q, 0.0)
-    o = jnp.where(rowv, o, 0.0)
-    do = jnp.where(rowv, do, 0.0)
-    k = jnp.where(colv, k, 0.0)
-    v = jnp.where(colv, v, 0.0)
-
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale  # [bq, bk]
-    row = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-    col = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-    ok = (col < kvl) & (row < q_len)
-    if causal:
-        ok = ok & (row + (kv_len - q_len) >= col)
-
-    p = jnp.where(ok, jnp.exp(s - lse), 0.0)
-    delta = jnp.sum(do * o, axis=1, keepdims=True)  # [bq, 1], local to q rows
-    dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-    ds = jnp.where(ok, p * (dp - delta) * sm_scale, 0.0)
-    return p, ds, q, k, v, do
-
-
-def _bwd_dkv_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
-                    dk_ref, dv_ref, dk_scr, dv_scr, *, sm_scale, causal,
-                    block_q, block_k, num_q_blocks, q_len, kv_len):
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    b = pl.program_id(0)
-    ki = pl.program_id(1)
-    qi = pl.program_id(2)
-
-    @pl.when(qi == 0)
-    def _init():
-        dk_scr[:] = jnp.zeros_like(dk_scr)
-        dv_scr[:] = jnp.zeros_like(dv_scr)
-
-    # skip q blocks that cannot see this key block (causal: blocks strictly
-    # above the last visible diagonal), and key blocks past the valid length
-    visible = ki * block_k < lens_ref[b]
-    if causal:
-        visible = jnp.logical_and(
-            visible, qi * block_q + block_q - 1 + (kv_len - q_len) >= ki * block_k
-        )
-
-    @pl.when(visible)
-    def _body():
-        p, ds, q, _, _, do = _bwd_tiles(
-            lens_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
-            b=b, qi=qi, ki=ki, sm_scale=sm_scale, causal=causal,
-            block_q=block_q, block_k=block_k, q_len=q_len, kv_len=kv_len)
-        dv_scr[:, :] = dv_scr[:, :] + jnp.dot(
-            p.T, do, preferred_element_type=jnp.float32)
-        dk_scr[:, :] = dk_scr[:, :] + jnp.dot(
-            ds.T, q, preferred_element_type=jnp.float32)
-
-    @pl.when(qi == num_q_blocks - 1)
-    def _finish():
-        dk_ref[0] = dk_scr[:, :].astype(dk_ref.dtype)
-        dv_ref[0] = dv_scr[:, :].astype(dv_ref.dtype)
-
-
-def _bwd_dq_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
-                   dq_ref, dq_scr, *, sm_scale, causal, block_q, block_k,
-                   num_k_blocks, q_len, kv_len):
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    b = pl.program_id(0)
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-
-    @pl.when(ki == 0)
-    def _init():
-        dq_scr[:] = jnp.zeros_like(dq_scr)
-
-    visible = ki * block_k < lens_ref[b]
-    if causal:
-        visible = jnp.logical_and(
-            visible, ki * block_k <= qi * block_q + block_q - 1 + (kv_len - q_len)
-        )
-
-    @pl.when(visible)
-    def _body():
-        _, ds, _, k, _, _ = _bwd_tiles(
-            lens_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
-            b=b, qi=qi, ki=ki, sm_scale=sm_scale, causal=causal,
-            block_q=block_q, block_k=block_k, q_len=q_len, kv_len=kv_len)
-        dq_scr[:, :] = dq_scr[:, :] + jnp.dot(
-            ds, k, preferred_element_type=jnp.float32)
-
-    @pl.when(ki == num_k_blocks - 1)
-    def _finish():
-        dq_ref[0] = dq_scr[:, :].astype(dq_ref.dtype)
-
-
-# Backward engine switch.  "scan" is lax.scan over key blocks in plain XLA
-# (p computed once a block feeds dv/dq/dk: 5 matmuls); "pallas" the
-# two-kernel pair, which recomputes the score matmuls in each pass (7
-# matmuls); "fused" the dq+dkv-in-ONE-grid kernel: full-T q/do/lse stay
-# resident in VMEM, the grid walks key blocks, each step emits that block's
-# dk/dv AND accumulates dq in a VMEM scratch — 5 matmuls and every tensor
-# touches HBM exactly once.  "auto" (the default) picks fused where the
-# calibrated VMEM model fits AND T >= _FUSED_MIN_T, scan elsewhere.
-# What this rests on: the rounds 3 and 5 sweeps (tools/bench_flash_bwd.py,
-# fwd+bwd, causal, bf16, 16k tokens) timed the engines BEHIND the old
-# forward, whose 128 x 128 grid steps were most of every figure (T=2048:
-# scan 22.0 / fused 16.95 / pair 27.6 ms with a ~9 ms forward in each), so
-# their absolute numbers are replaced by the ledger's and PR 29's.  What
-# stands: their ORDER at T=2048 (fused < scan < pair) and the fused
-# kernel's compile-time OOM at T=4096 (scoped VMEM 16.70M of 16.00M).
-# Whether scan still wins under T=2048, now that the forward no longer
-# hides the difference, is ROADMAP S2's to read.
-FLASH_BWD_IMPL = os.environ.get("PADDLE_TPU_FLASH_BWD", "auto").strip().lower()
-if FLASH_BWD_IMPL not in ("auto", "scan", "fused", "pallas"):
-    import warnings
-
-    warnings.warn(
-        "PADDLE_TPU_FLASH_BWD=%r is not one of auto/scan/fused/pallas; "
-        "using 'auto'" % FLASH_BWD_IMPL)
-    FLASH_BWD_IMPL = "auto"
-# Backward-only key-block override (None = use the forward's block_k).
-# Shrinking ONLY the backward's block halves its [T, block_k] f32
-# intermediates without touching the forward kernel — the knob that could
-# let the fused engine fit scoped VMEM at T=4096 (tools/bench_flash_bwd.py
-# measures whether the half-width lanes pay for themselves).
-FLASH_BWD_BLOCK_K = None
+# Two backward engines, chosen from the shape by ``_bwd_engine`` and by
+# nothing else.  "scan" is lax.scan over key blocks in plain XLA (p computed
+# once a block feeds dv/dq/dk: 5 matmuls); "fused" the dq+dkv-in-ONE-grid
+# kernel: full-T q/do/lse stay resident in VMEM, the grid walks key blocks,
+# each step emits that block's dk/dv AND accumulates dq in a VMEM scratch —
+# 5 matmuls and every tensor touches HBM exactly once.
+# What the choice rests on: fused < scan at T=2048 (kernel-only sweeps of
+# rounds 3 and 5, fwd+bwd, causal, bf16, 16k tokens, timed behind the old
+# forward, so only their order stands), and the fused kernel's compile-time
+# OOM at T=4096 (scoped VMEM 16.70M of 16.00M).  Whether scan still wins
+# under T=2048, now that the forward no longer hides the difference, is
+# ROADMAP S2's to read.
 _FUSED_MIN_T = 2048
 # 16MB/core scoped limit − margin.  14MB left only ~3% headroom on the one
 # calibrated shape (T=2048 D=64 bf16 bk=128 reports 16.70M/16M at T=4096);
@@ -575,29 +442,22 @@ def _fused_bwd_vmem_bytes(T, D, in_itemsize, block_k):
     return T * per_token + kv
 
 
-def _flash_bwd(causal, sm_scale, block_q, block_k, interpret, res, do):
-    # the backward's blocks are its own: the "auto" rule and the fused
-    # kernel's VMEM model are calibrated at 128, whatever the forward chose
-    block_q = block_q or DEFAULT_BLOCK_Q
-    block_k = block_k or DEFAULT_BLOCK_K
-    if FLASH_BWD_BLOCK_K:
-        block_k = int(FLASH_BWD_BLOCK_K)
-    q, k = res[0], res[1]
-    impl = _bwd_engine(q.shape[2], k.shape[2], q.shape[3], q.dtype.itemsize, block_k)
-    if impl == "fused":
-        return _flash_bwd_fused(causal, sm_scale, block_k, interpret, res, do)
-    if impl == "pallas":
-        return _flash_bwd_pallas(causal, sm_scale, block_q, block_k, interpret, res, do)
-    return _flash_bwd_scan(causal, sm_scale, block_k, res, do)
-
-
 def _bwd_engine(T, S, D, in_itemsize, block_k=DEFAULT_BLOCK_K):
-    """The backward engine FLASH_BWD_IMPL names for a shape; under "auto",
-    fused where its VMEM model fits and T >= _FUSED_MIN_T, scan elsewhere."""
-    if FLASH_BWD_IMPL != "auto":
-        return FLASH_BWD_IMPL
+    """The backward engine for a shape: "fused" where T >= _FUSED_MIN_T and
+    its VMEM model fits _FUSED_VMEM_BUDGET, "scan" elsewhere.  The one place
+    the decision lives."""
     fits = _fused_bwd_vmem_bytes(T, D, in_itemsize, min(block_k, S)) <= _FUSED_VMEM_BUDGET
     return "fused" if (T >= _FUSED_MIN_T and fits) else "scan"
+
+
+def _flash_bwd(causal, sm_scale, block_k, interpret, res, do):
+    # the backward's key block is its own: the chooser and the fused
+    # kernel's VMEM model are calibrated at 128, whatever the forward chose
+    block_k = block_k or DEFAULT_BLOCK_K
+    q, k = res[0], res[1]
+    if _bwd_engine(q.shape[2], k.shape[2], q.shape[3], q.dtype.itemsize, block_k) == "fused":
+        return _flash_bwd_fused(causal, sm_scale, block_k, interpret, res, do)
+    return _flash_bwd_scan(causal, sm_scale, block_k, res, do)
 
 
 def _fused_bwd_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, ld_ref,
@@ -666,7 +526,7 @@ def _fused_bwd_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, ld_ref,
 
 
 def _flash_bwd_fused(causal, sm_scale, block_k, interpret, res, do):
-    """dq + dk + dv in ONE Pallas grid (see FLASH_BWD_IMPL)."""
+    """dq + dk + dv in ONE Pallas grid (see ``_bwd_engine``)."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -730,116 +590,14 @@ def _flash_bwd_fused(causal, sm_scale, block_k, interpret, res, do):
     )
 
 
-def _flash_bwd_pallas(causal, sm_scale, block_q, block_k, interpret, res, do):
-    """Fused flash backward: two Pallas kernels (dk/dv accumulated over q
-    blocks, dq accumulated over key blocks), p/ds recomputed per tile in
-    VMEM — no [T, S] materialization and no per-block HBM roundtrip the
-    lax.scan formulation pays per key block."""
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    q, k, v, kv_lens, out, lse = res
-    B, H, T, D = q.shape
-    S = k.shape[2]
-    bq = min(block_q, T)
-    bk = min(block_k, S)
-    nq = -(-T // bq)
-    nk = -(-S // bk)
-    bh = B * H
-
-    qr = q.reshape(bh, T, D)
-    kr = k.reshape(bh, S, D)
-    vr = v.reshape(bh, S, D)
-    orr = out.reshape(bh, T, D)
-    dor = do.reshape(bh, T, D)
-    lse_rep = jnp.broadcast_to(lse.reshape(bh, T, 1), (bh, T, 128))
-    if kv_lens is None:
-        lens_bh = jnp.full((bh,), S, jnp.int32)
-    else:
-        lens_bh = jnp.repeat(kv_lens.astype(jnp.int32), H)
-
-    # dk/dv kernel: grid (bh, key block, q block) — q-side tiles advance
-    # with the LAST grid dim, k/v tiles with the middle one
-    dkv_in = [
-        pl.BlockSpec((1, bq, D), lambda b, i, j, lens: (b, j, 0)),    # q
-        pl.BlockSpec((1, bk, D), lambda b, i, j, lens: (b, i, 0)),    # k
-        pl.BlockSpec((1, bk, D), lambda b, i, j, lens: (b, i, 0)),    # v
-        pl.BlockSpec((1, bq, D), lambda b, i, j, lens: (b, j, 0)),    # o
-        pl.BlockSpec((1, bq, D), lambda b, i, j, lens: (b, j, 0)),    # do
-        pl.BlockSpec((1, bq, 128), lambda b, i, j, lens: (b, j, 0)),  # lse
-    ]
-    dk, dv = pl.pallas_call(
-        functools.partial(
-            _bwd_dkv_kernel, sm_scale=sm_scale, causal=causal, block_q=bq,
-            block_k=bk, num_q_blocks=nq, q_len=T, kv_len=S),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(bh, nk, nq),
-            in_specs=dkv_in,
-            out_specs=[
-                pl.BlockSpec((1, bk, D), lambda b, i, j, lens: (b, i, 0)),
-                pl.BlockSpec((1, bk, D), lambda b, i, j, lens: (b, i, 0)),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((bk, D), jnp.float32),
-                pltpu.VMEM((bk, D), jnp.float32),
-            ],
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, S, D), k.dtype),
-            jax.ShapeDtypeStruct((bh, S, D), v.dtype),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(lens_bh, qr, kr, vr, orr, dor, lse_rep)
-
-    # dq kernel: grid (bh, q block, key block)
-    dq_in = [
-        pl.BlockSpec((1, bq, D), lambda b, i, j, lens: (b, i, 0)),    # q
-        pl.BlockSpec((1, bk, D), lambda b, i, j, lens: (b, j, 0)),    # k
-        pl.BlockSpec((1, bk, D), lambda b, i, j, lens: (b, j, 0)),    # v
-        pl.BlockSpec((1, bq, D), lambda b, i, j, lens: (b, i, 0)),    # o
-        pl.BlockSpec((1, bq, D), lambda b, i, j, lens: (b, i, 0)),    # do
-        pl.BlockSpec((1, bq, 128), lambda b, i, j, lens: (b, i, 0)),  # lse
-    ]
-    (dq,) = pl.pallas_call(
-        functools.partial(
-            _bwd_dq_kernel, sm_scale=sm_scale, causal=causal, block_q=bq,
-            block_k=bk, num_k_blocks=nk, q_len=T, kv_len=S),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(bh, nq, nk),
-            in_specs=dq_in,
-            out_specs=[
-                pl.BlockSpec((1, bq, D), lambda b, i, j, lens: (b, i, 0)),
-            ],
-            scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        ),
-        out_shape=[jax.ShapeDtypeStruct((bh, T, D), q.dtype)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(lens_bh, qr, kr, vr, orr, dor, lse_rep)
-
-    return (
-        dq.reshape(B, H, T, D),
-        dk.reshape(B, H, S, D),
-        dv.reshape(B, H, S, D),
-    )
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
 def flash_attention(q, k, v, kv_lens=None, causal=False, sm_scale=None,
                     block_q=None, block_k=None, interpret=None):
     """Fused attention, [B, H, T, D] → [B, H, T, D].  ``kv_lens`` ([B] int32)
     masks keys past each sequence's length (padding mask).  ``block_q`` /
     ``block_k`` of None mean "chosen from the shape" (``_fwd_tiles``; the
-    backward then keeps its own 128); a given value is taken as given by
-    the forward and the backward both."""
+    backward then keeps its own key block of 128); a given value is taken as
+    given, ``block_k`` by the backward too (it has no query block)."""
     out, _ = _flash_impl(q, k, v, kv_lens, causal, sm_scale, block_q, block_k, interpret)
     return out
 
@@ -870,7 +628,7 @@ def _flash_vjp_bwd(causal, sm_scale, block_q, block_k, interpret, res, do):
         sm_scale = 1.0 / float(np.sqrt(res[0].shape[-1]))
     if interpret is None:
         interpret = cpu_backend()
-    dq, dk, dv = _flash_bwd(causal, sm_scale, block_q, block_k, interpret, res, do)
+    dq, dk, dv = _flash_bwd(causal, sm_scale, block_k, interpret, res, do)
     kv_lens = res[3]
     dlens = None
     if kv_lens is not None:

@@ -131,7 +131,7 @@ from .errors import (
     ServingQuotaExceeded,
     ServingTimeout,
 )
-from .kv_cache import PagedKVCache, write_prompt_kv, write_token_kv
+from .kv_cache import PagedKVCache, write_token_kv
 from .model_store import LoadedModel, ModelStore
 from .replica_pool import ReplicaPool
 from .request_queue import PRIORITY_CLASSES, Request, RequestQueue
@@ -166,7 +166,6 @@ __all__ = [
     "SessionRecord",
     "scoped_session",
     "PagedKVCache",
-    "write_prompt_kv",
     "write_token_kv",
     "ServingError",
     "ServingTimeout",

@@ -93,7 +93,7 @@ from .errors import (
     ServingError,
     ServingTimeout,
 )
-from .kv_cache import PagedKVCache, write_prompt_kv
+from .kv_cache import PagedKVCache
 from .request_queue import Request, RequestQueue
 from .worker import RestartableWorker
 
@@ -175,12 +175,6 @@ class DecodeModel:
     what is small, keep a matrix per layer where slicing a stack would
     copy a layer-sized matrix (docs/serving.md, "The cache contract").
 
-    ``prefill_fn(params, tokens[T], length) -> (last_logits[V],
-    k[L,T,H,D], v[L,T,H,D])`` — run the whole (padded) prompt; ``length``
-    is the real token count, ``last_logits`` the logits at position
-    ``length - 1``.  LEGACY: used only by models that don't provide
-    ``prefill_chunk_fn`` (and keep nothing but K/V).
-
     ``prefill_chunk_fn(params, tokens[C], start, valid, cache,
     chunk_pages[C // page_size], gather_pages[MP], slot) ->
     (last_logits[V], cache')`` — one resumable prefill CHUNK of the
@@ -190,11 +184,10 @@ class DecodeModel:
     ``last_logits`` sits at row ``valid - 1``.  Slot-indexed state is read
     at ``slot`` and written back there; a chunk with ``start == 0`` opens a
     sequence and must take the state as ZERO whatever the slot held (that
-    is the reset of a reused slot: it costs no dispatch of its own).  When
-    present the scheduler prefills EVERY prompt through this step
-    (monolithic = one bucket-wide chunk), which is what makes chunked,
-    monolithic, and prefix-cache-resumed prefill bitwise interchangeable
-    — and what ``prefill_chunk_tokens`` / ``prefix_cache`` require.
+    is the reset of a reused slot: it costs no dispatch of its own).  The
+    scheduler prefills EVERY prompt through this step (monolithic = one
+    bucket-wide chunk), which is what makes chunked, monolithic, and
+    prefix-cache-resumed prefill bitwise interchangeable.
 
     ``decode_fn(params, tokens[S], positions[S], cache, page_tables[S,MP],
     kv_lens[S]) -> (logits[S,V], cache')`` — one token per slot: write its
@@ -226,11 +219,10 @@ class DecodeModel:
     ``models.minicpm_sala.build_decode_model`` are the in-repo producers.
     """
 
-    def __init__(self, prefill_fn, decode_fn, prefill_chunk_fn=None, *,
-                 params=None, num_layers, num_heads, head_dim, vocab_size,
+    def __init__(self, decode_fn, prefill_chunk_fn, *, params=None,
+                 num_layers, num_heads, head_dim, vocab_size,
                  eos_id=None, name="decode-model", page_pools=None,
                  slot_state=None, step_counters=()):
-        self.prefill_fn = prefill_fn
         self.decode_fn = decode_fn
         self.prefill_chunk_fn = prefill_chunk_fn
         self.params = params
@@ -285,14 +277,12 @@ class DecodeConfig:
         first (admission order on ties), interleaved with decode — TTFT
         of short prompts and inter-token latency of active decodes
         become bounded by the chunk size.  One compiled chunk program per width, so the
-        zero-recompile contract holds.  Requires the model to provide
-        ``prefill_chunk_fn``.
+        zero-recompile contract holds.
     prefix_cache: probe the KV pool's content-hash page index at
         admission and map cached prompt-prefix pages read-only instead
         of recomputing them (refcounted sharing, LRU eviction of
-        refcount-zero pages — see kv_cache.py).  Requires
-        ``prefill_chunk_fn`` (a hit resumes prefill mid-prompt).
-        Generated tokens are bitwise identical warm vs cold.
+        refcount-zero pages — see kv_cache.py); a hit resumes prefill
+        mid-prompt.  Generated tokens are bitwise identical warm vs cold.
     decode_retries: transient DECODE-step dispatch faults retry this
         many times before failing the active sequences typed.  The
         decode step is replayable for the same reason prefill is — the
@@ -478,8 +468,8 @@ class _Slot:
     ``prefill_pos`` tracks prompt tokens already cached (starting past
     any prefix-cache hit) and advances one chunk per scheduled
     iteration; the first sampled token (produced by the final chunk)
-    flips it to decoding.  The legacy whole-prompt path constructs the
-    slot already past prefill.
+    flips it to decoding.  A slot made without ``prefill_pos`` (a
+    handed-off sequence) is already past prefill.
     """
 
     __slots__ = ("req", "pages", "prompt_len", "kv_len", "generated",
@@ -558,32 +548,19 @@ class DecodeScheduler:
                  on_handoff=None, claim=None, device=None):
         self.model = model
         cfg = self.config = config or DecodeConfig()
-        self._use_chunks = model.prefill_chunk_fn is not None
-        if not self._use_chunks and (cfg.prefill_chunk_tokens is not None
-                                     or cfg.prefix_cache):
-            raise ServingError(
-                "prefill_chunk_tokens / prefix_cache require a model with "
-                "prefill_chunk_fn (see models.transformer."
-                "build_decode_model); %r has none" % (model.name,))
         if role not in ("both", "prefill", "decode"):
             raise ServingError(
                 "role must be 'both', 'prefill', or 'decode', got %r"
                 % (role,))
-        if role == "prefill" and not self._use_chunks:
-            raise ServingError(
-                "role='prefill' requires the chunked prefill path "
-                "(a model with prefill_chunk_fn)")
         if model.slot_state and (cfg.prefix_cache or sessions is not None
-                                 or role != "both"
-                                 or not self._use_chunks):
+                                 or role != "both"):
             raise ServingError(
                 "%r keeps slot-indexed state (%s): prefix_cache, sessions "
                 "and prefill/decode roles map or move PAGES, and a page "
                 "says nothing of the state at its boundary. A state "
                 "snapshot per checkpointed boundary is missing; serve it "
-                "with prefix_cache=False, no sessions, role='both' and a "
-                "prefill_chunk_fn" % (model.name, ", ".join(
-                    sorted(model.slot_state))))
+                "with prefix_cache=False, no sessions and role='both'"
+                % (model.name, ", ".join(sorted(model.slot_state))))
         if sessions is not None and not cfg.prefix_cache:
             raise ServingError(
                 "sessions require prefix_cache=True: a session pin is an "
@@ -778,26 +755,17 @@ class DecodeScheduler:
                     params, tokens, start, valid, pools, chunk_pages,
                     gather_pages, slot)
                 # the first generated token sits at absolute position
-                # start + valid; only the FINAL chunk's sample is used,
-                # and there it folds exactly like the legacy prefill's
-                # fold at `length` — same logits row, same key, so
-                # chunked and monolithic first tokens match bitwise
+                # start + valid = the prompt's length at the FINAL chunk,
+                # the only one whose sample is used: the same logits row
+                # and the same key however the prompt was cut, so chunked
+                # and monolithic first tokens match bitwise
                 kk = jax.random.fold_in(jax.random.PRNGKey(seed),
                                         start + valid)
                 return _sample_token(logits, kk, temp, top_k), pools
 
             return jax.jit(chunk, donate_argnums=pools_arg)
 
-        def prefill(params, pools, tokens, length, pages, seed, temp):
-            logits, k, v = model.prefill_fn(params, tokens, length)
-            pools = dict(pools)
-            pools["k"], pools["v"] = write_prompt_kv(
-                pools["k"], pools["v"], k, v, pages)
-            # first sampled token sits at absolute position `length`
-            kk = jax.random.fold_in(jax.random.PRNGKey(seed), length)
-            return _sample_token(logits, kk, temp, top_k), pools
-
-        return jax.jit(prefill, donate_argnums=pools_arg)
+        raise KeyError(key)
 
     def _chunk_widths(self):
         """The prefill-chunk widths this config can dispatch.
@@ -833,34 +801,23 @@ class DecodeScheduler:
                 jnp.zeros((cfg.num_slots,), jnp.uint32),
                 jnp.zeros((cfg.num_slots,), jnp.float32))
             np.asarray(toks)
-            if self._use_chunks:
-                for w in self._chunk_widths():
-                    fn = self._jit.get(("chunk", w))
-                    toks, cache.pools = fn(
-                        params, cache.pools,
-                        jnp.zeros((w,), jnp.int32), jnp.int32(0),
-                        jnp.int32(1),
-                        jnp.zeros((w // cfg.page_size,), jnp.int32),
-                        jnp.zeros((cache.max_pages_per_seq,), jnp.int32),
-                        np.int32(0), jnp.uint32(0), jnp.float32(0))
-                    np.asarray(toks)
-            else:
-                for b in self.prefill_buckets:
-                    fn = self._jit.get(("prefill", b))
-                    toks, cache.pools = fn(
-                        params, cache.pools,
-                        jnp.zeros((b,), jnp.int32), jnp.int32(1),
-                        jnp.zeros((b // cfg.page_size,), jnp.int32),
-                        jnp.uint32(0), jnp.float32(0))
-                    np.asarray(toks)
+            for w in self._chunk_widths():
+                fn = self._jit.get(("chunk", w))
+                toks, cache.pools = fn(
+                    params, cache.pools,
+                    jnp.zeros((w,), jnp.int32), jnp.int32(0),
+                    jnp.int32(1),
+                    jnp.zeros((w // cfg.page_size,), jnp.int32),
+                    jnp.zeros((cache.max_pages_per_seq,), jnp.int32),
+                    np.int32(0), jnp.uint32(0), jnp.float32(0))
+                np.asarray(toks)
             if cfg.kv_guard:
                 # one guard program per page-vector length the runtime
                 # dispatches: the decode tail sweep ([num_slots]) and
                 # each prefill width's written-page sweep
-                widths = (self._chunk_widths() if self._use_chunks
-                          else self.prefill_buckets)
                 for n in sorted({cfg.num_slots}
-                                | {w // cfg.page_size for w in widths}):
+                                | {w // cfg.page_size
+                                   for w in self._chunk_widths()}):
                     np.asarray(self._jit.get(("kvguard", n))(
                         cache.pools, jnp.zeros((n,), jnp.int32)))
             # roles mode: compile the handoff leg this replica
@@ -1443,19 +1400,16 @@ class DecodeScheduler:
                 # frees its reservation
                 self._park_hol(req, cached_pages, hashes)
                 return
-            if self._use_chunks:
-                self._place(req, cached_pages + pages,
-                            len(cached_pages) * cfg.page_size, hashes)
-            else:
-                self._prefill(req, pages)
+            self._place(req, cached_pages + pages,
+                        len(cached_pages) * cfg.page_size, hashes)
 
     def _place(self, req, pages, cached_tokens, hashes):
         """Seat one admitted request in a free slot in the PREFILLING
-        state (chunk path): pages are reserved (``cached_tokens`` of
+        state: pages are reserved (``cached_tokens`` of
         them already hold a shared prompt prefix), but no model compute
         happens here — chunks run one per iteration in ``_iterate``,
         so a burst of long-prompt admissions can't stall active
-        decodes behind back-to-back whole-prompt prefills."""
+        decodes behind back-to-back prefills."""
         idx = next(i for i, s in enumerate(self._slots) if s is None)
         now = time.perf_counter()
         wait = now - req.enqueue_ts
@@ -1479,10 +1433,8 @@ class DecodeScheduler:
         _active_slots.set(self._active_count())
 
     def _note_prefill_retry(self, req):
-        """The shared on_retry callback for BOTH prefill legs (legacy
-        whole-prompt and chunk): count, record, and trace one retried
-        transient prefill dispatch fault — one place so the record
-        shape can't drift between the legs."""
+        """The on_retry callback of a prefill chunk's dispatch: count,
+        record, and trace one retried transient fault."""
         def note_retry(exc, attempt_n, delay):
             _prefill_retries.inc()
             tel = self._telemetry
@@ -1693,105 +1645,6 @@ class DecodeScheduler:
                 % ("" if exc_repr is None else (": " + exc_repr))))
             self._completed += 1
         return True
-
-    def _prefill(self, req, pages):
-        import jax.numpy as jnp
-
-        cfg = self.config
-        idx = next(i for i, s in enumerate(self._slots) if s is None)
-        bucket = next(b for b in self.prefill_buckets if b >= req.prompt_len)
-        tokens = np.zeros((bucket,), np.int32)
-        tokens[:req.prompt_len] = req.prompt
-        page_vec = np.zeros((bucket // cfg.page_size,), np.int32)
-        n_prompt_pages = self._cache.pages_for(req.prompt_len)
-        page_vec[:n_prompt_pages] = pages[:n_prompt_pages]
-        fn = self._jit.get(("prefill", bucket))
-        now = time.perf_counter()
-        wait = now - req.enqueue_ts
-        _queue_wait_hist.observe(wait)
-        req.dispatch_ts = now
-        tel = self._telemetry
-        if tel.span_active() and req.trace is not None:
-            tel.record_span(
-                "serving.queue_wait", req.enqueue_wall, wait,
-                tags=req.trace.child().tags(priority=req.priority,
-                                            seq=req.seq))
-        temp, seed = self._sampling_params(req)
-
-        def attempt():
-            # the chaos choke point is consulted per ATTEMPT (a retry is
-            # a fresh dispatch, exactly like the predict path's)
-            serve_fault = _resilience._serve_fault
-            if serve_fault is not None:
-                serve_fault([req])
-            with tel.span("serving.decode.prefill.dispatch"):
-                tok, pools = fn(
-                    self._params, self._cache.pools,
-                    jnp.asarray(tokens), jnp.int32(req.prompt_len),
-                    jnp.asarray(page_vec), seed, temp)
-            with tel.span("serving.decode.prefill.wait") as wait:
-                first = int(np.asarray(tok))
-            self._turn_wait_s += wait.duration
-            return first, pools
-
-        try:
-            prefill_wall = time.time()
-            # the whole-prompt program, dispatch to readback (retries
-            # included); it runs inside ``serving.decode.admit``
-            with tel.span("serving.decode.prefill", bucket=bucket,
-                          rows=req.prompt_len, seq=req.seq) as prefill:
-                first, pools = _resilience.call_with_retry(
-                    attempt, policy=self._prefill_policy,
-                    on_retry=self._note_prefill_retry(req))
-        except Exception as exc:  # noqa: BLE001 — worker must survive
-            self._cache.free(pages)
-            self._completed += 1
-            req.fail(exc)
-            self._recover_pools(exc)
-            if self._breaker is not None:
-                self._breaker.record_fatal()
-            return
-        except BaseException:
-            # worker killed mid-prefill: the request is in neither the
-            # queue nor a slot — release its reservation before the
-            # death propagates.  Solo mode: fail it typed or its future
-            # hangs forever (ServingDegraded, not ServingError: the
-            # engine is sick, the request was fine).  Pool mode: park
-            # it head-of-line instead — evict_inflight harvests the HOL
-            # and the pool replays it on a sibling
-            self._cache.free(pages)
-            if self._evict_on_death:
-                self._park_hol(req, [], None)
-            else:
-                self._completed += 1
-                req.fail(ServingDegraded(
-                    "decode worker died mid-prefill; request aborted"))
-            raise
-        done = time.perf_counter()
-        # TTFT: admission -> first sampled token, the number an
-        # interactive-decode SLO is written against
-        _ttft_hist.observe(done - req.enqueue_ts)
-        if tel.span_active() and req.trace is not None:
-            tel.record_span(
-                "serving.execute", prefill_wall, prefill.duration,
-                tags=req.trace.child().tags(phase="prefill", bucket=bucket,
-                                            rows=req.prompt_len))
-        self._cache.pools = pools
-        if self._breaker is not None:
-            self._breaker.record_success()
-        slot = _Slot(req, pages)
-        slot.generated.append(first)
-        req.journal.accepted.append(first)
-        req.token_times.append(time.perf_counter())
-        self._slots[idx] = slot
-        self._tables[idx] = self._cache.table_row(pages)
-        _prefills.inc()
-        _tokens.inc()
-        _active_slots.set(self._active_count())
-        if self.config.kv_guard and self._guard_pages(
-                [idx] * len(page_vec), page_vec, phase="prefill"):
-            return
-        self._finish_if_done(idx)
 
     def _guard_pages(self, owners, page_vec, phase):
         """KV integrity sweep over ``page_vec`` (``owners[j]`` = the slot

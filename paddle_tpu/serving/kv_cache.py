@@ -92,7 +92,7 @@ import numpy as np
 from .. import observability as _obs
 from .errors import ServingError
 
-__all__ = ["PagedKVCache", "write_prompt_kv", "write_token_kv"]
+__all__ = ["PagedKVCache", "write_token_kv"]
 
 _pages_total = _obs.gauge("serving.decode.kv_pages_total")
 _pages_used = _obs.gauge("serving.decode.kv_pages_used")
@@ -105,23 +105,6 @@ _shared_pages = _obs.gauge("serving.decode.kv_shared_pages")
 _cached_pages = _obs.gauge("serving.decode.kv_cached_pages")
 _page_bytes = _obs.gauge("serving.cache.page_bytes")
 _state_bytes = _obs.gauge("serving.cache.state_bytes")
-
-
-def write_prompt_kv(k_pool, v_pool, k_new, v_new, pages):
-    """Scatter a prefilled prompt's whole-page blocks into the pools.
-
-    k_new/v_new: ``[L, T, H, D]`` with ``T % page_size == 0`` (the prefill
-    bucket is a page multiple), folded here into the pool's ``H*D`` rows;
-    ``pages``: ``[T // page_size]`` int32 page ids — entries past the
-    sequence's real need point at the scratch page, so the scatter shape
-    stays static per bucket.  Returns the updated ``(k_pool, v_pool)``.
-    """
-    L, T = k_new.shape[:2]
-    ps, HD = k_pool.shape[2:]
-    n = T // ps
-    kb = k_new.reshape(L, n, ps, HD)
-    vb = v_new.reshape(L, n, ps, HD)
-    return k_pool.at[:, pages].set(kb), v_pool.at[:, pages].set(vb)
 
 
 def write_token_kv(k_pool, v_pool, k_tok, v_tok, pages, offsets):

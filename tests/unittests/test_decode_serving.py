@@ -71,32 +71,26 @@ class TestPagedKVCache:
         assert abs(obs.gauge("serving.decode.kv_fragmentation").value
                    - (1 - 12 / 20)) < 1e-9
 
-    def test_write_token_and_prompt_kv(self):
+    def test_write_token_kv_into_the_stored_shape(self):
         import jax.numpy as jnp
 
         c = serving.PagedKVCache(2, num_pages=5, page_size=4, num_heads=2,
                                  head_dim=4, max_seq_len=16)
-        k = jnp.asarray(np.random.RandomState(0).randn(2, 8, 2, 4)
-                        .astype(np.float32))
-        v = k + 1
         # stored shape: layers stacked, heads FOLDED head-major into the
         # last axis (head h = [..., h*D:(h+1)*D]); the page axis is axis 1
         assert c.k_pool.shape == c.v_pool.shape == c.pool_shape == (2, 5, 4, 8)
         assert c.pages_shape(3) == (2, 3, 4, 8)
-        kp, vp = serving.write_prompt_kv(c.k_pool, c.v_pool, k, v,
-                                         jnp.asarray([2, 3], np.int32))
-        assert kp.shape == c.pool_shape
-        np.testing.assert_array_equal(
-            np.asarray(kp)[:, 2:4].reshape(2, 8, 2, 4), np.asarray(k))
-        np.testing.assert_array_equal(          # head 1 of token 5 = page 3
-            np.asarray(kp)[:, 3, 1, 4:8], np.asarray(k)[:, 5, 1])
-        tok_k = jnp.ones((2, 3, 2, 4), jnp.float32)  # S=3 slots
-        kp2, vp2 = serving.write_token_kv(
-            kp, vp, tok_k, tok_k * 2,
+        tok_k = jnp.asarray(np.random.RandomState(0).randn(2, 3, 2, 4)
+                            .astype(np.float32))  # S=3 slots
+        kp, vp = serving.write_token_kv(
+            c.k_pool, c.v_pool, tok_k, tok_k * 2,
             jnp.asarray([1, 4, 0], np.int32), jnp.asarray([2, 0, 0],
                                                           np.int32))
-        assert (np.asarray(kp2)[:, 1, 2] == 1).all()
-        assert (np.asarray(vp2)[:, 4, 0] == 2).all()
+        assert kp.shape == c.pool_shape
+        np.testing.assert_array_equal(          # slot 0 -> page 1, offset 2
+            np.asarray(kp)[:, 1, 2].reshape(2, 2, 4), np.asarray(tok_k)[:, 0])
+        np.testing.assert_array_equal(          # head 1 of slot 1 = page 4
+            np.asarray(vp)[:, 4, 0, 4:8], 2 * np.asarray(tok_k)[:, 1, 1])
 
     @pytest.mark.parametrize("op", ["reset", "scrub"])
     def test_pool_shape_survives_reset_and_scrub(self, op):
@@ -420,36 +414,24 @@ class TestChunkedPrefill:
             serving.DecodeConfig(page_size=8, prefill_chunk_tokens=12)
         with pytest.raises(ValueError, match="prefill_chunk_tokens"):
             serving.DecodeConfig(page_size=8, prefill_chunk_tokens=4)
-        # chunking / prefix caching need a chunk-capable model
-        legacy = serving.DecodeModel(
-            decode_model.prefill_fn, decode_model.decode_fn,
-            params=decode_model.params,
-            num_layers=decode_model.num_layers,
-            num_heads=decode_model.num_heads,
-            head_dim=decode_model.head_dim,
-            vocab_size=decode_model.vocab_size)
-        with pytest.raises(serving.ServingError, match="prefill_chunk_fn"):
-            serving.DecodeScheduler(
-                legacy, _cfg(prefill_chunk_tokens=8, warmup=False),
-                autostart=False)
-        with pytest.raises(serving.ServingError, match="prefill_chunk_fn"):
-            serving.DecodeScheduler(
-                legacy, _cfg(prefix_cache=True, warmup=False),
-                autostart=False)
 
-    def test_legacy_model_without_chunk_fn_still_serves(self, decode_model):
-        legacy = serving.DecodeModel(
-            decode_model.prefill_fn, decode_model.decode_fn,
-            params=decode_model.params,
-            num_layers=decode_model.num_layers,
-            num_heads=decode_model.num_heads,
-            head_dim=decode_model.head_dim,
-            vocab_size=decode_model.vocab_size)
-        sched = serving.DecodeScheduler(legacy, _cfg())
-        out = sched.generate(np.array([4, 5, 6], np.int32),
-                             max_new_tokens=3, timeout=120)
-        sched.stop()
-        assert out.shape == (3,)
+    def test_decode_model_needs_a_chunk_function(self, decode_model):
+        """Every model prefills through the chunk program: a model without
+        one is refused where it is made, not by a scheduler later."""
+        sizes = dict(params=decode_model.params,
+                     num_layers=decode_model.num_layers,
+                     num_heads=decode_model.num_heads,
+                     head_dim=decode_model.head_dim,
+                     vocab_size=decode_model.vocab_size)
+        with pytest.raises(TypeError, match="prefill_chunk_fn"):
+            serving.DecodeModel(decode_model.decode_fn, **sizes)
+        whole = serving.DecodeModel(
+            decode_model.decode_fn, decode_model.prefill_chunk_fn, **sizes)
+        # the two step programs and no third
+        sched = serving.DecodeScheduler(whole, _cfg(warmup=False),
+                                        autostart=False)
+        with pytest.raises(KeyError):
+            sched._build_step(("prefill", 16), donate=False)
 
     def test_mid_prefill_deadline_shed(self, decode_model):
         from paddle_tpu.testing import faults

@@ -249,14 +249,6 @@ def test_lazy_fetch_materializes_correct_numpy():
     assert (out * 2 == expected * 2).all()
 
 
-def test_fast_path_killswitch_env(monkeypatch):
-    monkeypatch.setenv("PADDLE_TPU_FAST_PATH", "0")
-    exe = fluid.Executor()
-    assert exe.fast_path is False
-    monkeypatch.setenv("PADDLE_TPU_FAST_PATH", "1")
-    assert fluid.Executor().fast_path is True
-
-
 def test_pinned_output_fallback_only_on_structure_change():
     """Mesh path: a step that CREATES a persistable (new_state keys differ
     from state keys) falls back to unpinned outputs and succeeds; the

@@ -21,6 +21,35 @@ def _rand_qkv(B=2, H=2, T=64, D=16, seed=0):
     return q, k, v
 
 
+def _force_bwd(monkeypatch, engine):
+    """The backward is chosen from the shape and from nothing else; a test
+    that needs one engine at a toy shape replaces the chooser."""
+    monkeypatch.setattr(FA, "_bwd_engine", lambda *a, **kw: engine)
+
+
+def _out_and_grads(attn, q, k, v, w, **kw):
+    """``attn``'s output and the gradients of ``sum(out * w)`` in q, k, v."""
+    def f(q, k, v):
+        out = attn(q, k, v, **kw)
+        return jnp.sum(out.astype(jnp.float32) * w), out
+    (_, out), grads = jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    return (out,) + grads
+
+
+def _assert_out_and_grads_close(got, want):
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]), rtol=2e-4, atol=2e-4)
+    for a, b in zip(got[1:], want[1:]):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-3, atol=2e-3)
+
+
+def _rand_qkvw(B, H, T, S, D, seed):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return (jax.random.normal(ks[0], (B, H, T, D), jnp.float32),
+            jax.random.normal(ks[1], (B, H, S, D), jnp.float32),
+            jax.random.normal(ks[2], (B, H, S, D), jnp.float32),
+            jax.random.normal(ks[3], (B, H, T, D), jnp.float32))
+
+
 @pytest.mark.parametrize("causal", [False, True])
 def test_flash_matches_reference(causal):
     q, k, v = _rand_qkv()
@@ -30,9 +59,9 @@ def test_flash_matches_reference(causal):
 
 
 @pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize("bwd_impl", ["scan", "pallas", "fused"])
+@pytest.mark.parametrize("bwd_impl", ["scan", "fused"])
 def test_flash_grads_match(causal, bwd_impl, monkeypatch):
-    monkeypatch.setattr(FA, "FLASH_BWD_IMPL", bwd_impl)
+    _force_bwd(monkeypatch, bwd_impl)
     q, k, v = _rand_qkv(T=32, D=8, seed=1)
 
     def loss_flash(q, k, v):
@@ -83,21 +112,14 @@ def test_flash_lowers_for_tpu(causal, with_lens, monkeypatch):
     exported = jax_export.export(jax.jit(f), platforms=["tpu"])(q, q, q)
     assert "tpu_custom_call" in exported.mlir_module()
 
-    # the alternative Pallas backward pair (dk/dv + dq kernels) must lower
-    # for TPU as well (the default scan backward is plain XLA)
-    monkeypatch.setattr(FA, "FLASH_BWD_IMPL", "pallas")
+    # the fused one-grid backward (dq+dkv in a single kernel) lowers too
+    # (the scan backward, which this T gets, is plain XLA)
+    _force_bwd(monkeypatch, "fused")
 
     def g(q, k, v):
         return (flash_attention(q, k, v, lens, causal, None, 128, 128, False)
                 .astype(jnp.float32) ** 2).sum()
 
-    exported_bwd = jax.export.export(
-        jax.jit(jax.grad(g, argnums=(0, 1, 2))), platforms=["tpu"])(q, q, q)
-    # forward + 2 backward pallas_calls
-    assert exported_bwd.mlir_module().count("tpu_custom_call") >= 3
-
-    # the fused one-grid backward (dq+dkv in a single kernel) lowers too
-    monkeypatch.setattr(FA, "FLASH_BWD_IMPL", "fused")
     exported_fused = jax.export.export(
         jax.jit(jax.grad(g, argnums=(0, 1, 2))), platforms=["tpu"])(q, q, q)
     # forward + 1 backward pallas_call
@@ -106,7 +128,7 @@ def test_flash_lowers_for_tpu(causal, with_lens, monkeypatch):
 
 def test_flash_fused_bwd_kv_lens_and_cross_length(monkeypatch):
     """Fused one-grid backward under key padding masks and T != S."""
-    monkeypatch.setattr(FA, "FLASH_BWD_IMPL", "fused")
+    _force_bwd(monkeypatch, "fused")
     B, H, T, S, D = 2, 2, 24, 40, 8
     ks = jax.random.split(jax.random.PRNGKey(9), 3)
     q = jax.random.normal(ks[0], (B, H, T, D), jnp.float32)
@@ -148,7 +170,21 @@ _CHOSEN_LENS = {"full": None, "ragged": lambda S: [S, S // 2 + 1, 3],
                 "zero-row": lambda S: [S - 5, 0, S]}
 
 
-@pytest.mark.parametrize("bwd_impl", ["scan", "pallas", "fused"])
+def _check_chosen_tiles(monkeypatch, bwd_impl, B, H, T, S, D, lens, causal, seed):
+    """block_q = block_k = None at toy widths, one backward engine: output and
+    the three gradients against the plain reference; a sequence with no
+    visible key (``lens[b] == 0``) comes out as exact zeros."""
+    _force_bwd(monkeypatch, bwd_impl)
+    monkeypatch.setattr(FA, "DEFAULT_BLOCK_K", 16)
+    q, k, v, w = _rand_qkvw(B, H, T, S, D, seed)
+    kw = dict(kv_lens=lens and jnp.array(lens, jnp.int32), causal=causal)
+    got = _out_and_grads(flash_attention, q, k, v, w, **kw)
+    _assert_out_and_grads_close(got, _out_and_grads(mha_reference, q, k, v, w, **kw))
+    for b, n in enumerate(lens or ()):
+        assert n or not np.asarray(got[0])[b].any()
+
+
+@pytest.mark.parametrize("bwd_impl", ["scan", "fused"])
 @pytest.mark.parametrize("lens", list(_CHOSEN_LENS))
 @pytest.mark.parametrize("shape", list(_CHOSEN_SHAPES))
 @pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
@@ -158,57 +194,103 @@ def test_flash_chosen_tiles_match_reference(causal, shape, lens, bwd_impl,
     (``_fwd_tiles``) and the backward keeps its own; output and the three
     gradients against the plain reference."""
     _small_chooser(monkeypatch)
-    monkeypatch.setattr(FA, "FLASH_BWD_IMPL", bwd_impl)
-    monkeypatch.setattr(FA, "DEFAULT_BLOCK_Q", 16)
-    monkeypatch.setattr(FA, "DEFAULT_BLOCK_K", 16)
     T, S = _CHOSEN_SHAPES[shape]
     B, H, D = 3, 2, 8
-    ks = jax.random.split(jax.random.PRNGKey(11), 4)
-    q = jax.random.normal(ks[0], (B, H, T, D), jnp.float32)
-    k = jax.random.normal(ks[1], (B, H, S, D), jnp.float32)
-    v = jax.random.normal(ks[2], (B, H, S, D), jnp.float32)
-    w = jax.random.normal(ks[3], (B, H, T, D), jnp.float32)
-    kv_lens = _CHOSEN_LENS[lens]
-    if kv_lens is not None:
-        kv_lens = jnp.array(kv_lens(S), jnp.int32)
     heads, bq, bk, chunks = FA._fwd_tiles(B * H, T, S, D, 4)
     if shape == "heads":
         assert heads > 1 and bq == T
     else:
         assert bq < T and (chunks > 1 or bk * chunks < S)
-
-    def run(attn):
-        def f(q, k, v):
-            out = attn(q, k, v, kv_lens=kv_lens, causal=causal)
-            return jnp.sum(out * w), out
-        (_, out), grads = jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)(q, k, v)
-        return (out,) + grads
-
-    got, want = run(flash_attention), run(mha_reference)
-    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]), rtol=2e-4, atol=2e-4)
-    if kv_lens is not None and lens == "zero-row":
-        assert not np.asarray(got[0])[1].any()  # kv_lens == 0 -> exact zeros
-    for a, b in zip(got[1:], want[1:]):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-3, atol=2e-3)
+    kv_lens = _CHOSEN_LENS[lens] and _CHOSEN_LENS[lens](S)
+    _check_chosen_tiles(monkeypatch, bwd_impl, B, H, T, S, D, kv_lens, causal,
+                        seed=11)
 
 
+@pytest.mark.parametrize("bwd_impl", ["scan", "fused"])
+@pytest.mark.parametrize("lens", list(_CHOSEN_LENS))
+def test_flash_chosen_tiles_cross_attention_T_gt_S(lens, bwd_impl, monkeypatch):
+    """A target longer than its source (the encoder-decoder cross attention,
+    non-causal): more query rows than keys, in both engines."""
+    _small_chooser(monkeypatch)
+    B, H, T, S, D = 3, 2, 56, 24, 8
+    kv_lens = _CHOSEN_LENS[lens] and _CHOSEN_LENS[lens](S)
+    _check_chosen_tiles(monkeypatch, bwd_impl, B, H, T, S, D, kv_lens, False,
+                        seed=14)
+
+
+@pytest.mark.parametrize("bwd_impl", ["scan", "fused"])
 @pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
-def test_flash_chosen_tiles_with_part_of_S_resident(causal, monkeypatch):
+def test_flash_chosen_tiles_with_part_of_S_resident(causal, bwd_impl, monkeypatch):
     """A budget that holds only part of S a step: the grid gets its key axis
     back, and a key span no row of the query block sees is clamped to the
-    last one seen (no copy, no turn)."""
+    last one seen (no copy, no turn).  The ``lse`` that form leaves feeds
+    each backward."""
     _small_chooser(monkeypatch, vmem_budget=100 * 1024)
     B, H, T, S, D = 3, 1, 48, 80, 8
     heads, bq, bk, chunks = FA._fwd_tiles(B * H, T, S, D, 4)
     assert heads == 1 and -(-S // (bk * chunks)) > 2
-    ks = jax.random.split(jax.random.PRNGKey(12), 3)
-    q = jax.random.normal(ks[0], (B, H, T, D), jnp.float32)
-    k = jax.random.normal(ks[1], (B, H, S, D), jnp.float32)
-    v = jax.random.normal(ks[2], (B, H, S, D), jnp.float32)
-    lens = jnp.array([S, 21, 0], jnp.int32)
-    out = flash_attention(q, k, v, lens, causal)
-    ref = mha_reference(q, k, v, causal=causal, kv_lens=lens)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-4, atol=2e-4)
+    _check_chosen_tiles(monkeypatch, bwd_impl, B, H, T, S, D, [S, 21, 0],
+                        causal, seed=12)
+
+
+# (T, S) under _FUSED_MIN_T; over it with the fused kernel's residency inside
+# the budget; over it and outside: the chooser's three ways, at toy widths
+_BOUNDARY_SHAPES = {"under-min-T": ((24, 40), "scan"),
+                    "fits": ((32, 40), "fused"),
+                    "over-budget": ((64, 64), "scan")}
+
+
+@pytest.mark.parametrize("lens", ["full", "ragged"])
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("shape", list(_BOUNDARY_SHAPES))
+def test_flash_bwd_auto_on_both_sides_of_its_boundary(shape, causal, lens,
+                                                      monkeypatch):
+    """``_bwd_engine`` left to choose, its two constants set small: the engine
+    it names is the one that runs, and its gradients match the reference."""
+    (T, S), engine = _BOUNDARY_SHAPES[shape]
+    B, H, D = 3, 2, 8
+    monkeypatch.setattr(FA, "_FUSED_MIN_T", 32)
+    monkeypatch.setattr(FA, "_FUSED_VMEM_BUDGET", 50_000)
+    assert FA._bwd_engine(T, S, D, 4, 16) == engine
+    ran = []
+
+    def spy(name):
+        inner = getattr(FA, "_flash_bwd_" + name)
+
+        def run(*args):
+            ran.append(name)
+            return inner(*args)
+        monkeypatch.setattr(FA, "_flash_bwd_" + name, run)
+
+    spy("scan")
+    spy("fused")
+    q, k, v, w = _rand_qkvw(B, H, T, S, D, seed=15)
+    kv_lens = _CHOSEN_LENS[lens] and jnp.array(_CHOSEN_LENS[lens](S), jnp.int32)
+    kw = dict(kv_lens=kv_lens, causal=causal)
+    got = _out_and_grads(flash_attention, q, k, v, w, block_q=16, block_k=16, **kw)
+    assert ran == [engine]
+    _assert_out_and_grads_close(got, _out_and_grads(mha_reference, q, k, v, w, **kw))
+
+
+@pytest.mark.parametrize("bwd_impl", ["scan", "fused"])
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+def test_flash_grads_match_bf16_inputs(causal, bwd_impl, monkeypatch):
+    """bf16 q, k, v (a caller who casts before the kernel, ROADMAP S2c):
+    gradients come back bf16 and within bf16's rounding of the f32
+    reference on the same (rounded) values."""
+    _force_bwd(monkeypatch, bwd_impl)
+    q, k, v, w = _rand_qkvw(2, 2, 32, 32, 8, seed=16)
+    qb, kb, vb = (x.astype(jnp.bfloat16) for x in (q, k, v))
+    lens = jnp.array([32, 19], jnp.int32)
+    got = _out_and_grads(flash_attention, qb, kb, vb, w, kv_lens=lens,
+                         causal=causal, block_q=16, block_k=16)
+    want = _out_and_grads(mha_reference, *(x.astype(jnp.float32) for x in (qb, kb, vb)),
+                          w, kv_lens=lens, causal=causal)
+    for a, b in zip(got, want):
+        assert a.dtype == jnp.bfloat16
+        # one bf16 rounding of the result (2^-8 relative) on values of order 1
+        np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(b),
+                                   rtol=2e-2, atol=2e-2)
 
 
 # the benchmark's three training shapes [B*H, T, D] and the backward engine
@@ -338,31 +420,3 @@ def test_transformer_flash_matches_reference_path():
             (lv,) = exe.run(main, feed={"s": src, "t": trg, "l": lbl}, fetch_list=[avg])
         results[use_flash] = float(np.ravel(lv)[0])
     np.testing.assert_allclose(results[True], results[False], rtol=2e-4)
-
-
-def test_flash_bwd_env_override(tmp_path):
-    """PADDLE_TPU_FLASH_BWD seeds the engine choice at import (normalized,
-    invalid values warn and fall back to auto)."""
-    import os
-    import subprocess
-    import sys
-
-    root = os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    code = ("from paddle_tpu.parallel import flash_attention as FA;"
-            "print('IMPL=' + FA.FLASH_BWD_IMPL)")
-
-    def run(val):
-        env = dict(os.environ, PADDLE_TPU_FLASH_BWD=val, JAX_PLATFORMS="cpu",
-                   PYTHONPATH=os.pathsep.join(
-                       [root] + [p for p in (os.environ.get("PYTHONPATH"),) if p]))
-        out = subprocess.run([sys.executable, "-W", "always", "-c", code],
-                             env=env, capture_output=True, text=True, timeout=300)
-        assert out.returncode == 0, out.stderr[-1000:]
-        impl = [l for l in out.stdout.splitlines() if l.startswith("IMPL=")][0]
-        return impl[len("IMPL="):], out.stderr
-
-    impl, _ = run(" Fused ")
-    assert impl == "fused"
-    impl, err = run("bogus")
-    assert impl == "auto" and "PADDLE_TPU_FLASH_BWD" in err

@@ -46,8 +46,6 @@ sys.path.insert(0, os.path.join(REPO, "benchmarks"))
 
 if "JAX_PLATFORMS" not in os.environ and "JAX_PLATFORM_NAME" not in os.environ:
     os.environ["JAX_PLATFORMS"] = "cpu"
-# the invariants assume the default dispatch configuration
-os.environ.pop("PADDLE_TPU_FAST_PATH", None)
 
 DEFAULT_BASELINE = os.path.join(REPO, "PERF_BASELINE.json")
 
