@@ -67,8 +67,9 @@ SIZES = {
 KERNEL_RTOL = 2e-2   # max |kernel - ref| <= KERNEL_RTOL * max |ref|, per tensor
 # Paged (Pallas) vs gather-reference serving engines: NOT bitwise.  The
 # reference contracts q.k on the MXU at default precision (operands rounded
-# to bf16) while the decode kernel forms f32 products on the VPU, and the
-# online softmax sums in page order.  Claimed instead: (a) each paged kernel
+# to bf16) while the decode kernel's products are f32-exact (the MXU over
+# exact bf16 parts of q and p), and the online softmax sums a turn of pages
+# at a time.  Claimed instead: (a) each paged kernel
 # alone matches its reference within PAGED_RTOL on the chip; (b) generated
 # tokens match the reference engine's exactly, except that a request may
 # part ways at a step where an independent f32 forward (lm_prefill,

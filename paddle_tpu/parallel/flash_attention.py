@@ -662,14 +662,15 @@ flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 #   masked-softmax formulation — the same arithmetic shape as
 #   ``mha_reference`` with T_q=1, so tier-1 stays green without Pallas
 #   interpret overhead.
-# * pallas (TPU): the page table rides the SCALAR-PREFETCH path (the same
-#   ``PrefetchScalarGridSpec`` machinery ``kv_lens`` already uses): the
-#   kernel's k/v BlockSpec index maps pick ``(layer, page, 0, 0)`` from the
-#   prefetched table to DMA exactly this slot's pages — no gathered
-#   [S, max_kv, H, D] intermediate ever exists in HBM.  A block carries ALL
-#   heads of a page in its lanes; the head loop runs inside the kernel over
-#   static lane slices.  Online softmax across the slot's page walk, fully
-#   masked pages skipped via ``pl.when``.
+# * pallas (TPU): the page table and ``kv_lens`` ride the SCALAR-PREFETCH
+#   path (``PrefetchScalarGridSpec``) and the stack stays in HBM: one grid
+#   step a slot, which copies the slot's OWN ``ceil(kv_len / ps)`` pages
+#   ``(layer, page)`` by the prefetched table into a VMEM tile, many pages a
+#   turn (``_decode_turn_pages``: 32 of the chat cell's 16-token pages), the
+#   next turn's copies in flight — no gathered [S, max_kv, H, D]
+#   intermediate ever exists in HBM, and no page past ``kv_len`` is copied,
+#   stepped over or computed.  A tile carries ALL heads of its pages in its
+#   lanes; one online-softmax update a turn serves every head.
 #
 # The public entry points also accept ONE layer's unfolded
 # ``[P, ps, H, Dh]`` pool (``layer=None``): it is folded into a one-layer
@@ -732,66 +733,180 @@ def _paged_reference(q, k_pool, v_pool, page_tables, kv_lens, sm_scale, layer):
     return jnp.einsum("shk,skhd->shd", p, v).astype(q.dtype)
 
 
-def _paged_decode_kernel(pt_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
-                         m_scr, l_scr, acc_scr, *, page_size, num_pages_per_seq,
-                         n_head, head_dim, sm_scale):
-    """One grid step = one slot x one page, ALL heads folded into the lanes:
-    the k/v block is the page ``[ps, H*Dh]`` and the query block the slot's
-    row ``[1, H*Dh]``.  The head loop runs over static lane slices; a single
-    query row per head is a matvec, so scores and p.v stay on the VPU as
-    broadcast multiplies + reductions in f32 (no MXU rounding: the bf16
-    page is the only precision lost), with per-head online-softmax state in
-    the scratch rows."""
+# A turn of the walk is one online-softmax update over ``pages * ps`` keys
+# gathered into a VMEM tile.  On v5e 256 / 512 / 1024 keys read 0.093 /
+# 0.095 / 0.105 ms a call on the chat cell's caches (most fit one 512-key
+# turn) and 0.59 / 0.51 / 0.47 with every cache full (PERF.md section 6,
+# PR 31): 512 is near the best of both.
+_DECODE_TURN_KEYS = 512
+# Scoped-VMEM budget of the walk: as the forward's.
+_DECODE_VMEM_BUDGET = 13 * 1024 * 1024
+
+
+def _decode_vmem_bytes(pages, ps, lanes, kv_itemsize, n_head):
+    """Scoped-VMEM residency of one slot's walk at ``pages`` pages a turn:
+      k, v tiles, double-buffered ............ 2 * 2 * turn * lanes * isz
+      one turn's operands: the bf16 parts of a tile that is not bf16 (three
+      a tile) and the masked v tile ........... up to 4 * turn * lanes * 4
+      scores and probabilities [3 * Hp, turn] . 4 * 3 * Hp * turn * 4
+      query rows, their parts, acc and the output 16 * Hp * lanes * 4
+    An upper estimate, not calibrated against the compiler as the forward's
+    is: every turn it lets through at the shapes tried compiles for a
+    described v5e under the default scoped limit.  A VMEM row is 128 lanes
+    wide whatever ``lanes`` is."""
+    turn = pages * ps
+    lanes = -(-lanes // 128) * 128
+    hp = -(-n_head // 8) * 8
+    return (4 * turn * lanes * kv_itemsize + 4 * turn * lanes * 4
+            + 12 * hp * turn * 4 + 16 * hp * lanes * 4)
+
+
+def _decode_turn_pages(ps, lanes, mp, kv_itemsize, n_head):
+    """Pages a turn of the decode walk, from the shapes alone (no probe, no
+    fallback): as many as make ``_DECODE_TURN_KEYS`` keys, no more than the
+    table has, halved until the VMEM model fits its budget."""
+    pages = max(1, min(mp, _DECODE_TURN_KEYS // ps))
+    while pages > 1 and _decode_vmem_bytes(
+            pages, ps, lanes, kv_itemsize, n_head) > _DECODE_VMEM_BUDGET:
+        pages = -(-pages // 2)
+    return pages
+
+
+def _bf16_parts(x):
+    """bf16 arrays whose sum is ``x`` to f32's last bit: ``x`` itself where
+    it is bf16, else three (8 + 8 + 8 bits of an f32 mantissa).  A product
+    of two bf16 values is exact in f32 and the MXU accumulates in f32, so a
+    matmul over the parts is the f32 result — where a bare ``jnp.dot`` of
+    f32 operands in a Mosaic kernel is ONE bf16 pass (PERF.md, PR 29)."""
+    import jax.numpy as jnp
+
+    if x.dtype == jnp.bfloat16:
+        return [x]
+    x = x.astype(jnp.float32)
+    hi = x.astype(jnp.bfloat16)
+    r = x - hi.astype(jnp.float32)
+    mid = r.astype(jnp.bfloat16)
+    return [hi, mid, (r - mid.astype(jnp.float32)).astype(jnp.bfloat16)]
+
+
+def _part_rows(x):
+    """The three bf16 parts of f32 ``x [rows, n]`` stacked ``[3 * rows, n]``
+    (stacked as f32, whose sublane tile ``rows`` is a multiple of, then cast:
+    each part is a bf16 value already)."""
+    import jax.numpy as jnp
+
+    return jnp.concatenate([p.astype(jnp.float32) for p in _bf16_parts(x)],
+                           axis=0).astype(jnp.bfloat16)
+
+
+def _paged_decode_kernel(pt_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
+                         k_buf, v_buf, sem, *, layer, page_size, pages,
+                         num_pages_per_seq, n_head, head_dim, sm_scale):
+    """One grid step = one SLOT, and inside it the walk over the slot's OWN
+    ``ceil(kv_len / ps)`` pages, ``pages`` of them a turn: each page is
+    copied from the stored stack (left in HBM) into its rows of a
+    ``[turn, H*Dh]`` VMEM tile by the prefetched page table, the next turn's
+    copies in flight while this turn computes.  No page past ``kv_len`` is
+    copied, stepped over or computed; ``kv_len == 0`` takes no turn and
+    emits zeros.
+
+    A turn is one online-softmax update for all heads at once, keys on the
+    lanes: scores ``[H, turn]`` = the block-diagonal query rows ``[H, H*Dh]``
+    (row h holds head h's lanes) against the tile, p.v ``[H, H*Dh]`` of which
+    row h's own lanes are kept at the end; m, l and alpha stay ``[H, 128]``
+    lane-replicated.  Both products run on the MXU over exact bf16 parts of
+    their operands (``_bf16_parts``): f32 results, the bf16 page the only
+    precision lost."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     s_idx = pl.program_id(0)
-    j = pl.program_id(1)  # page walk for this slot
-
-    @pl.when(j == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
+    ps, turn = page_size, pages * page_size
+    lanes = n_head * head_dim
+    hp = -(-n_head // 8) * 8            # query rows, whole f32 sublane tiles
     kvl = lens_ref[s_idx]
-    # pages wholly past the slot's length are skipped: with the page walk
-    # as the LAST grid dim the skip saves the compute, and — unlike the
-    # cross-length fwd kernel — correctness additionally leans on it for
-    # the kv_lens == 0 contract (nothing accumulates; _finish emits 0).
-    visible = j * page_size < kvl
+    # lax.div / lax.rem, not // and %: the operands are never negative, and
+    # the floor forms trace to a nested jit apiece (six kernels a step
+    # program are traced and lowered at every process start: set-up time)
+    div, rem = jax.lax.div, jax.lax.rem
+    n_pages = div(kvl + (ps - 1), ps)
+    n_turns = div(kvl + (turn - 1), turn)
+    n_whole = div(kvl, turn)            # turns with every key visible
 
-    @pl.when(visible)
-    def _body():
-        ok = (j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (page_size, 1), 0)) < kvl
-        for h in range(n_head):
-            lanes = slice(h * head_dim, (h + 1) * head_dim)
-            q = q_ref[:, lanes].astype(jnp.float32)  # [1, Dh]
-            k = k_ref[:, lanes].astype(jnp.float32)  # [ps, Dh]
-            v = v_ref[:, lanes].astype(jnp.float32)
-            k = jnp.where(ok, k, 0.0)  # 0*garbage tail rows stay finite
-            v = jnp.where(ok, v, 0.0)
-            s = jnp.sum(q * k, axis=-1, keepdims=True) * sm_scale
-            s = jnp.where(ok, s, NEG_INF)               # [ps, 1]
+    def copies(t, slot, i):
+        page = pt_ref[s_idx * num_pages_per_seq + t * pages + i]
+        rows = pl.ds(pl.multiple_of(i * ps, ps), ps)
+        return (pltpu.make_async_copy(k_hbm.at[layer, page],
+                                      k_buf.at[slot, rows], sem.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[layer, page],
+                                      v_buf.at[slot, rows], sem.at[1, slot]))
 
-            m_prev = m_scr[h:h + 1, 0:1]                # [1, 1]
-            m_new = jnp.maximum(m_prev, s.max(axis=0, keepdims=True))
-            p = jnp.exp(s - m_new)
-            alpha = jnp.exp(m_prev - m_new)
-            l_scr[h:h + 1, :] = jnp.broadcast_to(
-                l_scr[h:h + 1, 0:1] * alpha + p.sum(axis=0, keepdims=True),
-                (1, l_scr.shape[1]))
-            acc_scr[:, lanes] = acc_scr[:, lanes] * alpha + jnp.sum(
-                p * v, axis=0, keepdims=True)
-            m_scr[h:h + 1, :] = jnp.broadcast_to(m_new, (1, m_scr.shape[1]))
+    def each_page(t, slot, what):
+        def one(i, _):
+            for c in copies(t, slot, i):
+                what(c)
+        jax.lax.fori_loop(0, jnp.minimum(pages, n_pages - t * pages), one,
+                          None)
 
-    @pl.when(j == num_pages_per_seq - 1)
-    def _finish():
-        for h in range(n_head):
-            lanes = slice(h * head_dim, (h + 1) * head_dim)
-            denom = jnp.maximum(l_scr[h:h + 1, 0:1], 1e-30)
-            o_ref[:, lanes] = (acc_scr[:, lanes] / denom).astype(o_ref.dtype)
+    # row h of q_rows is head h's lanes of the (scaled) query, zero elsewhere
+    own = (div(jax.lax.broadcasted_iota(jnp.int32, (hp, lanes), 1), head_dim)
+           == jax.lax.broadcasted_iota(jnp.int32, (hp, lanes), 0))
+    q = q_ref[...].astype(jnp.float32) * sm_scale          # [1, lanes]
+    q_rows = _part_rows(
+        jnp.where(own, jnp.broadcast_to(q, (hp, lanes)), 0.0))  # [3 * hp, lanes]
+
+    def parts_dot(a_rows, b, dims):
+        """``a . b`` in f32: ``a_rows`` is ``_part_rows(a)``."""
+        out = None
+        for part in _bf16_parts(b):
+            r = jax.lax.dot_general(a_rows, part, (dims, ((), ())),
+                                    preferred_element_type=jnp.float32)
+            r = r[2 * hp:] + r[hp:2 * hp] + r[:hp]      # least part first
+            out = r if out is None else out + r
+        return out
+
+    def update(t, carry, masked):
+        m_prev, l_prev, acc = carry
+        slot = rem(t, 2)
+
+        @pl.when(t + 1 < n_turns)
+        def _next():
+            each_page(t + 1, 1 - slot, lambda c: c.start())
+
+        each_page(t, slot, lambda c: c.wait())
+        k, v = k_buf[slot], v_buf[slot]                    # [turn, lanes]
+        s = parts_dot(q_rows, k, ((1,), (1,)))             # [hp, turn]
+        if masked:
+            # rows past kv_len hold what an earlier turn or nobody left:
+            # a score off them is replaced whatever it is, and their v rows
+            # are zeroed (0 * garbage must stay finite)
+            left = kvl - t * turn
+            s = jnp.where(jax.lax.broadcasted_iota(
+                jnp.int32, (hp, turn), 1) < left, s, NEG_INF)
+            v = jnp.where(jax.lax.broadcasted_iota(
+                jnp.int32, (turn, 1), 0) < left, v, jnp.zeros_like(v))
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - _lanes(m_new, turn))
+        alpha = jnp.exp(m_prev - m_new)
+        return (m_new, l_prev * alpha + p.sum(axis=1, keepdims=True),
+                acc * _lanes(alpha, lanes)
+                + parts_dot(_part_rows(p), v, ((1,), (0,))))
+
+    @pl.when(n_turns > 0)
+    def _first():
+        each_page(0, 0, lambda c: c.start())
+
+    carry = (jnp.full((hp, 128), NEG_INF, jnp.float32),
+             jnp.zeros((hp, 128), jnp.float32),
+             jnp.zeros((hp, lanes), jnp.float32))
+    carry = jax.lax.fori_loop(
+        0, n_whole, lambda t, c: update(t, c, False), carry)
+    _, l, acc = jax.lax.fori_loop(
+        n_whole, n_turns, lambda t, c: update(t, c, True), carry)
+    out = acc / _lanes(jnp.maximum(l, 1e-30), lanes)
+    o_ref[...] = jnp.sum(jnp.where(own, out, 0.0), axis=0,
+                         keepdims=True).astype(o_ref.dtype)
 
 
 def _paged_pallas(q, k_pool, v_pool, page_tables, kv_lens, sm_scale, interpret,
@@ -800,32 +915,40 @@ def _paged_pallas(q, k_pool, v_pool, page_tables, kv_lens, sm_scale, interpret,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    from .. import observability as obs
+
     S, H, Dh = q.shape
     ps = k_pool.shape[2]
     mp = page_tables.shape[1]
+    pages = _decode_turn_pages(ps, H * Dh, mp, k_pool.dtype.itemsize, H)
     # flat [S*mp] so the prefetched table indexes with one scalar read
     pt_flat = page_tables.astype(jnp.int32).reshape(S * mp)
     lens = kv_lens.astype(jnp.int32)
 
+    # what was chosen, once per compiled shape (this runs at trace time): a
+    # reader of a device trace divides the kernel's time by its grid steps
+    steps = obs.counter("paged.decode.grid_steps", labels={
+        "S": S, "mp": mp, "ps": ps, "turn": pages * ps})
+    if not steps.value:
+        steps.inc(S)
+
     kernel = functools.partial(
-        _paged_decode_kernel, page_size=ps, num_pages_per_seq=mp,
-        n_head=H, head_dim=Dh, sm_scale=sm_scale)
-    # the slot's j-th PAGE of this layer, straight out of the stacked pool:
-    # the page index comes from the prefetched table, the layer is static
-    page = pl.BlockSpec((None, None, ps, H * Dh),
-                        lambda s, j, pt, kl: (layer, pt[s * mp + j], 0, 0))
+        _paged_decode_kernel, layer=layer, page_size=ps, pages=pages,
+        num_pages_per_seq=mp, n_head=H, head_dim=Dh, sm_scale=sm_scale)
     # [S, 1, H*Dh]: the block's last two dims equal the array's own (the
     # only blocking of a one-row query the TPU lowering accepts)
-    row = pl.BlockSpec((None, 1, H * Dh), lambda s, j, pt, kl: (s, 0, 0))
+    row = pl.BlockSpec((None, 1, H * Dh), lambda s, pt, kl: (s, 0, 0))
+    # the stacked pools stay where they are: the walk copies pages out
+    stack = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(S, mp),
-        in_specs=[row, page, page],
+        grid=(S,),
+        in_specs=[row, stack, stack],
         out_specs=[row],
         scratch_shapes=[
-            pltpu.VMEM((H, 128), jnp.float32),     # running max (lane-replicated)
-            pltpu.VMEM((H, 128), jnp.float32),     # running sum
-            pltpu.VMEM((1, H * Dh), jnp.float32),  # output accumulator
+            pltpu.VMEM((2, pages * ps, H * Dh), k_pool.dtype),  # k tiles
+            pltpu.VMEM((2, pages * ps, H * Dh), v_pool.dtype),  # v tiles
+            pltpu.SemaphoreType.DMA((2, 2)),                    # [k|v, tile]
         ],
     )
     (out,) = pl.pallas_call(
@@ -833,7 +956,7 @@ def _paged_pallas(q, k_pool, v_pool, page_tables, kv_lens, sm_scale, interpret,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((S, 1, H * Dh), q.dtype)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            dimension_semantics=("parallel",),
         ),
         interpret=interpret,
     )(pt_flat, lens, q.reshape(S, 1, H * Dh), k_pool, v_pool)
@@ -852,7 +975,8 @@ def _paged_pallas(q, k_pool, v_pool, page_tables, kv_lens, sm_scale, interpret,
 # head, ``DECODE_PAGES_PER_STEP`` listed pages): the ``g`` query rows of the
 # group against those pages' ``[ps, Dh]`` lanes of that head, so ``Dh`` must be a whole number of lane
 # tiles (128) on the chip.  ``g = 1`` without a selection is NOT routed here:
-# it is the kernel above, bitwise what it was.
+# it is the kernel above, which walks a slot's own pages 512 keys a turn;
+# this one steps over the whole list, eight pages a step (ROADMAP D17, S9).
 # ---------------------------------------------------------------------------
 
 
@@ -903,9 +1027,10 @@ def _paged_gqa_decode_kernel(pt_ref, lens_ref, q_ref, *refs, page_size,
     """One grid step = one slot x one KV head x ``per_step`` listed pages
     (each its own block of the pool, side by side in VMEM as one
     ``[per_step * ps, Dh]`` tile): the group's ``[g, Dh]`` query rows against
-    them, online softmax over the walk in the scratch rows.  A grid step
-    costs about a third of a microsecond whatever it does, so a page a step
-    made the walk the decode step's largest cost (13 of 29 ms, PERF.md)."""
+    them, online softmax over the walk in the scratch rows.  A grid step of
+    THIS kernel costs about a third of a microsecond whatever it does, so a
+    page a step made the walk the largest cost of MiniCPM-SALA's decode step
+    (13 of 29 ms with one page a step, 7.4 with eight; PERF.md, PR 28)."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
@@ -1395,8 +1520,8 @@ def paged_decode_attention(q, k_pool, v_pool, page_tables, kv_lens,
         — per slot and KV head a short list of pages in cache order in
         place of the slot's whole row, of which the first ``sel_tokens``
         tokens (page by page) are valid; ``page_tables`` / ``kv_lens`` are
-        then not read.  ``g = 1`` without a selection is the kernel it
-        always was.
+        then not read.  ``g = 1`` without a selection is the plain kernel:
+        one grid step a slot, the slot's own pages many to a turn.
     k_pool / v_pool: with ``layer=li`` (the step programs) the STORED
         stack ``[L, num_pages, page_size, H*Dh]``, addressed in place;
         with ``layer=None`` ONE layer's ``[num_pages, page_size, H, Dh]``
@@ -1405,7 +1530,9 @@ def paged_decode_attention(q, k_pool, v_pool, page_tables, kv_lens,
         ``page_tables[s, :ceil(kv_lens[s]/page_size)]`` in order; unused
         entries must point at a valid (scratch) page id.
     kv_lens: [S] int32 — tokens of valid kv per slot; 0 = inactive slot,
-        whose output row is exactly zero.
+        whose output row is exactly zero.  The plain kernel reads no page,
+        and no row of a page, past it: a slot's output depends on its own
+        query, pages and length alone.
     impl: None/"auto" (pallas on TPU, reference elsewhere), "reference",
         or "pallas" (tests drive the kernel under interpret=True on CPU).
     """

@@ -106,6 +106,10 @@ _prefills = _obs.counter("serving.decode.prefills")
 _steps = _obs.counter("serving.decode.steps")
 _retired = _obs.counter("serving.decode.retired")
 _state_resets = _obs.counter("serving.cache.state_resets")
+# pages a decode step's slots hold against the pages its tables span: the
+# share of the whole-table walk that the slot-bounded one still takes
+_walked_pages = _obs.counter("serving.decode.paged.walked_pages")
+_table_pages = _obs.counter("serving.decode.paged.table_pages")
 _expired = _obs.counter("serving.decode.expired")
 _expired_mid_decode = _obs.counter("serving.decode.expired_mid_decode")
 _queue_full = _obs.counter("serving.decode.queue_full")
@@ -1825,6 +1829,8 @@ class DecodeScheduler:
                 positions[i] = slot.kv_len       # ... at the next cache index
                 kv_lens[i] = slot.kv_len + 1     # visible kv incl. this token
                 temps[i], seeds[i] = self._sampling_params(slot.req)
+            _walked_pages.inc(int(np.sum(-(-kv_lens // cfg.page_size))))
+            _table_pages.inc(self._tables.size)
             # the decode step scatters EVERY slot's token k/v at
             # page_tables[s, 0] offset 0 when positions[s] == 0 — a
             # PREFILLING slot's table already points at real (possibly

@@ -258,6 +258,29 @@ class TestDecodeScheduler:
             "serving.decode.sequence", "serving.decode.prefill",
             "serving.decode.step"}
 
+    @pytest.mark.parametrize("prompt_len,new", [(3, 2), (8, 6), (21, 9)])
+    def test_walked_and_table_pages_counters(self, decode_model, prompt_len,
+                                             new):
+        """What the slot-bounded decode walk visits against what the whole
+        table spans, summed over the decode steps (PR 31): their ratio is
+        the share of the old one-page-a-step walk that is left."""
+        names = ("steps", "paged.walked_pages", "paged.table_pages")
+        c0 = {n: obs.counter("serving.decode." + n).value for n in names}
+        cfg = _cfg(max_new_tokens=new)
+        sched = serving.DecodeScheduler(decode_model, cfg)
+        sched.generate(np.arange(1, prompt_len + 1, dtype=np.int32),
+                       timeout=120)
+        sched.stop()
+        d = {n: obs.counter("serving.decode." + n).value - c0[n]
+             for n in names}
+        # the first token comes out of prefill; step j sees the prompt, the
+        # j tokens fed before it and the one it feeds
+        assert d["steps"] == new - 1
+        assert d["paged.walked_pages"] == sum(
+            -(-(prompt_len + j + 1) // cfg.page_size) for j in range(new - 1))
+        assert d["paged.table_pages"] == d["steps"] * cfg.num_slots * (
+            cfg.max_seq_len // cfg.page_size)
+
     def test_stop_drain_false_fails_pending(self, decode_model):
         sched = serving.DecodeScheduler(decode_model,
                                         _cfg(warmup=False), autostart=False)

@@ -336,3 +336,134 @@ class TestStackedFoldedPools:
             call(kf, vf)                       # a stack without layer=
         with pytest.raises(ValueError, match="stored stack"):
             call(k5[0], v5[0], layer=0)        # an unfolded pool with layer=
+
+
+class TestTheDecodeWalk:
+    """The plain decode kernel walks a slot's OWN pages, many to a turn
+    (PR 31): a slot visits ``ceil(kv_len / ps)`` pages, one online-softmax
+    update a turn of ``FA._decode_turn_pages(..) * ps`` keys, and nothing
+    past ``kv_len`` is copied or computed.  Interpret mode against
+    ``_paged_reference`` on a 3-layer stack whose table spans more than a
+    turn, at the chooser's own turn (one or two turns a slot) and at a turn
+    patched small (up to 20 turns a slot)."""
+
+    L, S, H, Dh, ps, MP = 3, 8, 2, 8, 8, 80
+
+    @pytest.fixture(params=[None, 32], ids=["turn-chosen", "turn32"])
+    def turn(self, request, monkeypatch):
+        from paddle_tpu.parallel import flash_attention as FA
+
+        if request.param is not None:
+            monkeypatch.setattr(FA, "_DECODE_TURN_KEYS", request.param)
+        turn = self.ps * FA._decode_turn_pages(
+            self.ps, self.H * self.Dh, self.MP, 4, self.H)
+        assert turn == (request.param or 512) < self.MP * self.ps
+        return turn
+
+    def _stack(self, seed, kv_dtype=jnp.float32):
+        rng = np.random.RandomState(seed)
+        P = self.S * self.MP + 1
+        shape = (self.L, P, self.ps, self.H * self.Dh)
+        k = jnp.asarray(rng.randn(*shape).astype(np.float32)).astype(kv_dtype)
+        v = jnp.asarray(rng.randn(*shape).astype(np.float32)).astype(kv_dtype)
+        # every slot its own pages, in an order of the seed's
+        pt = 1 + rng.permutation(self.S * self.MP).reshape(self.S, self.MP)
+        q = jnp.asarray(rng.randn(self.S, self.H, self.Dh).astype(np.float32))
+        return q, k, v, jnp.asarray(pt.astype(np.int32))
+
+    def _edge_lens(self, turn):
+        ps = self.ps
+        return jnp.asarray(np.array(
+            [0, 1, ps - 1, ps, turn - 1, turn, turn + 1, self.MP * ps],
+            np.int32))
+
+    @staticmethod
+    def _kernel(q, k, v, pt, lens, layer):
+        return paged_decode_attention(q, k, v, pt, lens, impl="pallas",
+                                      interpret=True, layer=layer)
+
+    @staticmethod
+    def _reference(q, k, v, pt, lens, layer):
+        return np.asarray(paged_decode_attention(
+            q.astype(jnp.float32), k, v, pt, lens, impl="reference",
+            layer=layer))
+
+    @pytest.mark.parametrize("kv_dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["kv-f32", "kv-bf16"])
+    @pytest.mark.parametrize("qdtype", [jnp.float32, jnp.bfloat16],
+                             ids=["q-f32", "q-bf16"])
+    @pytest.mark.parametrize("layer", [0, 2], ids=["layer0", "layerL-1"])
+    def test_edge_lengths_mixed_in_one_batch(self, turn, layer, qdtype,
+                                             kv_dtype):
+        q, k, v, pt = self._stack(seed=layer, kv_dtype=kv_dtype)
+        lens = self._edge_lens(turn)
+        q = q.astype(qdtype)
+        ref = self._reference(q, k, v, pt, lens, layer)
+        pal = self._kernel(q, k, v, pt, lens, layer)
+        assert pal.dtype == qdtype and pal.shape == q.shape
+        pal = np.asarray(pal.astype(jnp.float32))
+        # the kernel is f32 whatever the pool holds: only q's own rounding
+        np.testing.assert_allclose(
+            pal, ref, atol=3e-6 if qdtype == jnp.float32 else 2e-2)
+        assert not pal[0].any()                 # kv_lens == 0: exact zeros
+
+    @pytest.mark.parametrize("where", ["past_kv_len", "tail_of_partial_page"])
+    @pytest.mark.parametrize("bad", [np.nan, np.inf, -np.inf],
+                             ids=["nan", "inf", "-inf"])
+    def test_garbage_outside_kv_len_is_never_read(self, turn, bad, where):
+        q, k, v, pt = self._stack(seed=5)
+        ps = self.ps
+        lens = np.array([0, 3, ps + 1, turn - 3, turn + 2, 2 * ps, 5,
+                         self.MP * ps - 2], np.int32)
+        clean = np.asarray(self._kernel(q, k, v, pt, jnp.asarray(lens), 1))
+        kn, vn = np.array(k), np.array(v)
+        for s, n in enumerate(lens):
+            used = -(-int(n) // ps)
+            if where == "past_kv_len":
+                for arr in (kn, vn):
+                    arr[1, np.asarray(pt)[s, used:]] = bad
+            elif n % ps:
+                for arr in (kn, vn):
+                    arr[1, int(pt[s, used - 1]), n % ps:] = bad
+        dirty = np.asarray(self._kernel(q, jnp.asarray(kn), jnp.asarray(vn),
+                                        pt, jnp.asarray(lens), 1))
+        assert np.isfinite(dirty).all()
+        assert dirty.tobytes() == clean.tobytes()
+
+    @pytest.mark.parametrize("neighbours", ["empty", "short", "full"])
+    @pytest.mark.parametrize("own", ["one_key", "a_turn_and_a_bit", "full"])
+    def test_row_independence_bitwise(self, turn, own, neighbours):
+        """A slot's output depends on its own query, pages and length only:
+        continuous batching equals per-sequence serving."""
+        q, k, v, pt = self._stack(seed=9)
+        full = self.MP * self.ps
+        mine = {"one_key": 1, "a_turn_and_a_bit": turn + 5, "full": full}[own]
+        theirs = {"empty": 0, "short": 3, "full": full}[neighbours]
+        outs = []
+        for others in (theirs, turn // 2 + 1):
+            lens = np.full(self.S, others, np.int32)
+            lens[3] = mine
+            outs.append(np.asarray(
+                self._kernel(q, k, v, pt, jnp.asarray(lens), 0))[3])
+        assert outs[0].tobytes() == outs[1].tobytes()
+
+    def test_shuffled_tables_read_pages_not_offsets(self, turn):
+        q, k, v, pt = self._stack(seed=11)
+        lens = self._edge_lens(turn)
+        out1 = np.asarray(self._kernel(q, k, v, pt, lens, 2))
+        perm = np.random.RandomState(12).permutation(k.shape[1])
+        inv = np.argsort(perm)
+        out2 = np.asarray(self._kernel(
+            q, k[:, perm], v[:, perm],
+            jnp.asarray(inv[np.asarray(pt)].astype(np.int32)), lens, 2))
+        assert out1.tobytes() == out2.tobytes()
+
+    def test_grid_steps_counter_is_one_step_a_slot(self, turn):
+        from paddle_tpu import observability as obs
+
+        q, k, v, pt = self._stack(seed=13)
+        self._kernel(q, k, v, pt, self._edge_lens(turn), 0)
+        steps = obs.counter("paged.decode.grid_steps", labels={
+            "S": self.S, "mp": self.MP, "ps": self.ps, "turn": turn})
+        assert steps.value == self.S            # was S * mp = 640
+

@@ -132,6 +132,50 @@ def test_paged_decode_compiles_for_v5e(one_chip, no_persistent_cache, qdtype):
         jax.ShapeDtypeStruct((_S,), jnp.int32, sharding=one_chip)) == 1
 
 
+@pytest.mark.parametrize("qdtype", [jnp.float32, jnp.bfloat16],
+                         ids=["q-f32", "q-bf16"])
+@pytest.mark.parametrize("layer", [0, 5], ids=["layer0", "layer5"])
+def test_paged_decode_walk_compiles_on_the_stored_stack(
+        one_chip, no_persistent_cache, layer, qdtype):
+    """The chat cell's own call: the stored ``[6, 8193, 16, 512]`` bf16
+    stack left in HBM, a static layer, and the turn the chooser picks there
+    (32 pages = 512 keys: two double-buffered ``[512, 512]`` tiles)."""
+    assert FA._decode_turn_pages(_PS, _H * _DH, _MP, 2, _H) * _PS == 512
+
+    def f(q, k_pool, v_pool, tables, lens):
+        return FA.paged_decode_attention(q, k_pool, v_pool, tables, lens,
+                                         impl="pallas", interpret=False,
+                                         layer=layer)
+
+    stack = jax.ShapeDtypeStruct((_L, _P, _PS, _H * _DH), jnp.bfloat16,
+                                 sharding=one_chip)
+    assert _kernel_calls(
+        f, jax.ShapeDtypeStruct((_S, _H, _DH), qdtype, sharding=one_chip),
+        stack, stack,
+        jax.ShapeDtypeStruct((_S, _MP), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((_S,), jnp.int32, sharding=one_chip)) == 1
+
+
+@pytest.mark.parametrize("ps,lanes,mp,itemsize,n_head,pages", [
+    (16, 512, 128, 2, 8, 32),     # the chat cell: 512 keys a turn, 4 turns
+    (16, 512, 128, 4, 8, 32),     # an f32 pool of the same shape
+    (64, 256, 608, 2, 2, 8),      # 64-token pages: 8 of them
+    (16, 512, 8, 2, 8, 8),        # a table shorter than a turn: all of it
+    (4, 16, 3, 4, 2, 3),          # the toy shapes of test_flash_decode.py
+    (1024, 512, 4, 2, 8, 1),      # a page wider than a turn: one page
+    (16, 2048, 128, 4, 16, 8),    # lanes so wide the budget halves it twice
+])
+def test_decode_turn_is_a_pure_function_of_the_shapes(ps, lanes, mp, itemsize,
+                                                      n_head, pages):
+    got = FA._decode_turn_pages(ps, lanes, mp, itemsize, n_head)
+    assert got == pages and 1 <= got <= mp
+    assert got == 1 or FA._decode_vmem_bytes(
+        got, ps, lanes, itemsize, n_head) <= FA._DECODE_VMEM_BUDGET
+    # 256 keys a turn at least, where the table and the budget allow
+    if mp * ps >= 256 and lanes <= 512:
+        assert got * ps >= 256
+
+
 def test_the_grouped_kernel_is_refused_at_heads_of_64_lanes(
         one_chip, no_persistent_cache):
     """Why ``paged_decode_attention`` keeps two kernels: the grouped one
