@@ -48,6 +48,7 @@ back to the default backend under tracing.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import numpy as np
@@ -55,7 +56,8 @@ import numpy as np
 from ..core import cpu_backend
 
 __all__ = ["flash_attention", "mha_reference", "paged_decode_attention",
-           "paged_prefill_attention", "paged_kv_finite"]
+           "paged_prefill_attention", "paged_mla_decode_attention",
+           "paged_mla_prefill_attention", "paged_kv_finite"]
 
 # the BACKWARD's key block, and what tools and tests pass explicitly; the
 # forward chooses its own from the shape (_fwd_tiles)
@@ -790,20 +792,114 @@ def _bf16_parts(x):
 
 
 def _part_rows(x):
-    """The three bf16 parts of f32 ``x [rows, n]`` stacked ``[3 * rows, n]``
-    (stacked as f32, whose sublane tile ``rows`` is a multiple of, then cast:
-    each part is a bf16 value already)."""
+    """``(rows, n)``: the ``n`` exact bf16 parts of ``x [r, c]`` stacked ``[n *
+    r, c]`` (bf16 ``x`` is its own one part; f32 gives three, stacked as f32,
+    whose sublane tile ``r`` is a multiple of, then cast: each part is a bf16
+    value already)."""
     import jax.numpy as jnp
 
-    return jnp.concatenate([p.astype(jnp.float32) for p in _bf16_parts(x)],
-                           axis=0).astype(jnp.bfloat16)
+    parts = _bf16_parts(x)
+    if len(parts) == 1:
+        return parts[0], 1
+    return jnp.concatenate([p.astype(jnp.float32) for p in parts],
+                           axis=0).astype(jnp.bfloat16), len(parts)
+
+
+def _parts_dot(a_rows, n, b, dims):
+    """``a . b`` in f32 on the MXU: ``a_rows, n = _part_rows(a)``, ``b`` split
+    in its own exact parts; the parts' products summed least part first."""
+    r = a_rows.shape[0] // n
+    out = None
+    for part in _bf16_parts(b):
+        y = jax.lax.dot_general(a_rows, part, (dims, ((), ())),
+                                preferred_element_type=jax.numpy.float32)
+        if n > 1:
+            rows = y[(n - 1) * r:]
+            for i in range(n - 2, -1, -1):
+                rows = rows + y[i * r:(i + 1) * r]
+            y = rows
+        out = y if out is None else out + y
+    return out
+
+
+def _walk_pages(kvl, counts, *, page_size, pages, rows, width, copies,
+                tiles, scores, limit=None):
+    """The walk of ONE slot's own pages that the paged decode kernels share:
+    ``pages`` pages a turn are copied whole into tile ``slot`` of two (the
+    next turn's copies in flight while this turn computes), and a turn is one
+    online-softmax update of ``rows`` query rows, keys on the lanes.  No page
+    past ``kvl`` is copied, stepped over or computed; ``kvl == 0`` takes no
+    turn and gives zeros.
+
+    counts: ``(n_pages, n_turns, n_whole)`` — the slot's pages, its turns,
+        and the first turns in which every row sees every key (unmasked).
+    copies(t, slot, i): the async copies of the ``i``-th page of turn ``t``.
+    tiles(slot): ``(k, v)`` of the turn, ``v [turn, width]``.
+    scores(k): ``[rows, turn]`` float32.
+    limit: keys each row sees ``[rows, 1]`` where rows differ (a chunk's
+        tokens); every row sees ``kvl`` otherwise.
+    A masked turn replaces the scores past a row's keys whatever they are
+    and zeroes the value rows past ``kvl`` (they hold what an earlier turn
+    or nobody left: 0 * garbage must stay finite).  Returns the normalised
+    ``[rows, width]`` float32; m, l and alpha stay ``[rows, 128]``
+    lane-replicated."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    n_pages, n_turns, n_whole = counts
+    turn = pages * page_size
+
+    def each_page(t, slot, what):
+        def one(i, _):
+            for c in copies(t, slot, i):
+                what(c)
+        jax.lax.fori_loop(0, jnp.minimum(pages, n_pages - t * pages), one,
+                          None)
+
+    def update(t, carry, masked):
+        m_prev, l_prev, acc = carry
+        slot = jax.lax.rem(t, 2)
+
+        @pl.when(t + 1 < n_turns)
+        def _next():
+            each_page(t + 1, 1 - slot, lambda c: c.start())
+
+        each_page(t, slot, lambda c: c.wait())
+        k, v = tiles(slot)
+        s = scores(k)                                      # [rows, turn]
+        if masked:
+            left = kvl - t * turn
+            seen = left if limit is None else limit - t * turn
+            s = jnp.where(jax.lax.broadcasted_iota(
+                jnp.int32, (rows, turn), 1) < seen, s, NEG_INF)
+            v = jnp.where(jax.lax.broadcasted_iota(
+                jnp.int32, (turn, 1), 0) < left, v, jnp.zeros_like(v))
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - _lanes(m_new, turn))
+        alpha = jnp.exp(m_prev - m_new)
+        return (m_new, l_prev * alpha + p.sum(axis=1, keepdims=True),
+                acc * _lanes(alpha, width)
+                + _parts_dot(*_part_rows(p), v, ((1,), (0,))))
+
+    @pl.when(n_turns > 0)
+    def _first():
+        each_page(0, 0, lambda c: c.start())
+
+    carry = (jnp.full((rows, 128), NEG_INF, jnp.float32),
+             jnp.zeros((rows, 128), jnp.float32),
+             jnp.zeros((rows, width), jnp.float32))
+    carry = jax.lax.fori_loop(
+        0, n_whole, lambda t, c: update(t, c, False), carry)
+    _, l, acc = jax.lax.fori_loop(
+        n_whole, n_turns, lambda t, c: update(t, c, True), carry)
+    return acc / _lanes(jnp.maximum(l, 1e-30), width)
 
 
 def _paged_decode_kernel(pt_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
                          k_buf, v_buf, sem, *, layer, page_size, pages,
                          num_pages_per_seq, n_head, head_dim, sm_scale):
-    """One grid step = one SLOT, and inside it the walk over the slot's OWN
-    ``ceil(kv_len / ps)`` pages, ``pages`` of them a turn: each page is
+    """One grid step = one SLOT, and inside it the walk (``_walk_pages``)
+    over the slot's OWN ``ceil(kv_len / ps)`` pages, ``pages`` a turn: each is
     copied from the stored stack (left in HBM) into its rows of a
     ``[turn, H*Dh]`` VMEM tile by the prefetched page table, the next turn's
     copies in flight while this turn computes.  No page past ``kv_len`` is
@@ -829,7 +925,7 @@ def _paged_decode_kernel(pt_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
     # lax.div / lax.rem, not // and %: the operands are never negative, and
     # the floor forms trace to a nested jit apiece (six kernels a step
     # program are traced and lowered at every process start: set-up time)
-    div, rem = jax.lax.div, jax.lax.rem
+    div = jax.lax.div
     n_pages = div(kvl + (ps - 1), ps)
     n_turns = div(kvl + (turn - 1), turn)
     n_whole = div(kvl, turn)            # turns with every key visible
@@ -842,69 +938,18 @@ def _paged_decode_kernel(pt_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
                 pltpu.make_async_copy(v_hbm.at[layer, page],
                                       v_buf.at[slot, rows], sem.at[1, slot]))
 
-    def each_page(t, slot, what):
-        def one(i, _):
-            for c in copies(t, slot, i):
-                what(c)
-        jax.lax.fori_loop(0, jnp.minimum(pages, n_pages - t * pages), one,
-                          None)
-
     # row h of q_rows is head h's lanes of the (scaled) query, zero elsewhere
     own = (div(jax.lax.broadcasted_iota(jnp.int32, (hp, lanes), 1), head_dim)
            == jax.lax.broadcasted_iota(jnp.int32, (hp, lanes), 0))
     q = q_ref[...].astype(jnp.float32) * sm_scale          # [1, lanes]
-    q_rows = _part_rows(
+    q_rows, nq = _part_rows(
         jnp.where(own, jnp.broadcast_to(q, (hp, lanes)), 0.0))  # [3 * hp, lanes]
 
-    def parts_dot(a_rows, b, dims):
-        """``a . b`` in f32: ``a_rows`` is ``_part_rows(a)``."""
-        out = None
-        for part in _bf16_parts(b):
-            r = jax.lax.dot_general(a_rows, part, (dims, ((), ())),
-                                    preferred_element_type=jnp.float32)
-            r = r[2 * hp:] + r[hp:2 * hp] + r[:hp]      # least part first
-            out = r if out is None else out + r
-        return out
-
-    def update(t, carry, masked):
-        m_prev, l_prev, acc = carry
-        slot = rem(t, 2)
-
-        @pl.when(t + 1 < n_turns)
-        def _next():
-            each_page(t + 1, 1 - slot, lambda c: c.start())
-
-        each_page(t, slot, lambda c: c.wait())
-        k, v = k_buf[slot], v_buf[slot]                    # [turn, lanes]
-        s = parts_dot(q_rows, k, ((1,), (1,)))             # [hp, turn]
-        if masked:
-            # rows past kv_len hold what an earlier turn or nobody left:
-            # a score off them is replaced whatever it is, and their v rows
-            # are zeroed (0 * garbage must stay finite)
-            left = kvl - t * turn
-            s = jnp.where(jax.lax.broadcasted_iota(
-                jnp.int32, (hp, turn), 1) < left, s, NEG_INF)
-            v = jnp.where(jax.lax.broadcasted_iota(
-                jnp.int32, (turn, 1), 0) < left, v, jnp.zeros_like(v))
-        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-        p = jnp.exp(s - _lanes(m_new, turn))
-        alpha = jnp.exp(m_prev - m_new)
-        return (m_new, l_prev * alpha + p.sum(axis=1, keepdims=True),
-                acc * _lanes(alpha, lanes)
-                + parts_dot(_part_rows(p), v, ((1,), (0,))))
-
-    @pl.when(n_turns > 0)
-    def _first():
-        each_page(0, 0, lambda c: c.start())
-
-    carry = (jnp.full((hp, 128), NEG_INF, jnp.float32),
-             jnp.zeros((hp, 128), jnp.float32),
-             jnp.zeros((hp, lanes), jnp.float32))
-    carry = jax.lax.fori_loop(
-        0, n_whole, lambda t, c: update(t, c, False), carry)
-    _, l, acc = jax.lax.fori_loop(
-        n_whole, n_turns, lambda t, c: update(t, c, True), carry)
-    out = acc / _lanes(jnp.maximum(l, 1e-30), lanes)
+    out = _walk_pages(
+        kvl, (n_pages, n_turns, n_whole), page_size=ps, pages=pages, rows=hp,
+        width=lanes, copies=copies,
+        tiles=lambda slot: (k_buf[slot], v_buf[slot]),     # [turn, lanes]
+        scores=lambda k: _parts_dot(q_rows, nq, k, ((1,), (1,))))
     o_ref[...] = jnp.sum(jnp.where(own, out, 0.0), axis=0,
                          keepdims=True).astype(o_ref.dtype)
 
@@ -961,6 +1006,219 @@ def _paged_pallas(q, k_pool, v_pool, page_tables, kv_lens, sm_scale, interpret,
         interpret=interpret,
     )(pt_flat, lens, q.reshape(S, 1, H * Dh), k_pool, v_pool)
     return out.reshape(S, H, Dh)
+
+
+# ---------------------------------------------------------------------------
+# LATENT (multi-head latent attention, MLA) rows in the paged cache.  A token
+# keeps ONE row ``[c | k_pe | 0]`` for all heads (the normalised compressed
+# KV, the one shared rotary key, zeros up to ``W`` lanes: on the chip ``W`` is
+# whole lane tiles, because an HBM row is padded to them anyway and a page
+# copy must take whole tiles: 512 + 64 -> 640), the page pool is
+# ``[L, P, ps, W]`` and there is no V pool: a value is the first ``v_width``
+# lanes of the same row.  In the ABSORBED form every head's query
+# is carried into the latent space (``[q_nope W_uk | q_pe]``, ``W`` wide), so
+# all ``H`` heads score against the same row and ``P . c`` comes back
+# ``v_width`` wide a head for the caller's ``W_uv``: each page is read ONCE
+# for all heads.  The kernel is the plain walk above (one grid step a slot,
+# the slot's own pages copied whole, many to a turn, the next turn's copies
+# in flight) with ``H`` plain query rows in place of the block-diagonal ones
+# and V a lane slice of the K tile.  A grid step may carry ``q_tokens``
+# consecutive tokens of one sequence (``q_tokens * H`` rows; token ``j`` sees
+# ``kv_len - (q_tokens - 1 - j)`` keys): that is a prefill chunk, whose rows
+# are slots of one page table, ``q_tokens`` to a step.
+# ---------------------------------------------------------------------------
+
+_MLA_PREFILL_TOKENS = 8     # chunk rows a grid step of the prefill form
+
+
+def _mla_limits(kv_lens, n_rows, n_head, q_tokens):
+    """``[.., n_rows]`` keys each query row sees: row ``r`` is token
+    ``r // n_head`` of its step's ``q_tokens``."""
+    import jax.numpy as jnp
+
+    tok = jnp.minimum(jnp.arange(n_rows) // n_head, q_tokens - 1)
+    return kv_lens[..., None] - (q_tokens - 1 - tok)
+
+
+def _paged_mla_reference(q, pool, page_tables, kv_lens, v_width, n_head,
+                         q_tokens, sm_scale, layer):
+    import jax.numpy as jnp
+
+    S, R, W = q.shape
+    ps = pool.shape[2]
+    mp = page_tables.shape[-1]
+    # one page-table row for every slot (a prefill chunk's rows) or one each
+    keys = "kw" if page_tables.ndim == 1 else "skw"
+    lat = pool[layer, page_tables].reshape(
+        page_tables.shape[:-1] + (mp * ps, W)).astype(jnp.float32)
+    s = jnp.einsum("srw,%s->srk" % keys, q.astype(jnp.float32), lat) * sm_scale
+    ok = (jnp.arange(mp * ps)[None, None, :]
+          < _mla_limits(kv_lens, R, n_head, q_tokens)[:, :, None])
+    p = jax.nn.softmax(jnp.where(ok, s, NEG_INF), axis=-1)
+    p = jnp.where(ok, p, 0.0)              # a row with no key -> zeros
+    return jnp.einsum("srk,%s->srv" % keys.replace("w", "v"), p,
+                      lat[..., :v_width])
+
+
+def _mla_turn_pages(ps, lanes, mp, itemsize, rows):
+    """Pages a turn of the latent walk: ``_DECODE_TURN_KEYS`` keys, halved
+    until tiles (double-buffered), scores, probabilities and their parts,
+    query rows and the accumulator fit the walk's VMEM budget."""
+    lanes = -(-lanes // 128) * 128
+    pages = max(1, min(mp, _DECODE_TURN_KEYS // ps))
+    while pages > 1 and (2 * pages * ps * lanes * (itemsize + 4)
+                         + 24 * rows * pages * ps
+                         + 16 * rows * lanes) > _DECODE_VMEM_BUDGET:
+        pages = -(-pages // 2)
+    return pages
+
+
+def _paged_mla_kernel(pt_ref, lens_ref, q_ref, lat_hbm, o_ref, buf, sem, *,
+                      layer, page_size, pages, num_pages_per_seq, n_head,
+                      q_tokens, v_width, sm_scale):
+    """One grid step = one slot's ``q_tokens * H`` query rows against the
+    slot's own ``ceil(kv_len / ps)`` latent pages, ``pages`` a turn
+    (``_walk_pages``).  A turn: scores ``[rows, turn]``
+    = the rows against the tile over all ``W`` lanes, an online-softmax
+    update, ``p . tile[:, :v_width]``.  Products run on the MXU over exact
+    bf16 parts of an operand that is not bf16 (``_bf16_parts``)."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    s_idx = pl.program_id(0)
+    ps, turn = page_size, pages * page_size
+    hp = q_ref.shape[0]
+    kvl = lens_ref[s_idx]
+    div = jax.lax.div
+    n_pages = div(kvl + (ps - 1), ps)
+    n_turns = div(kvl + (turn - 1), turn)
+    # turns in which every row sees every key
+    n_whole = div(jnp.maximum(kvl - (q_tokens - 1), 0), turn)
+    tok = jnp.minimum(div(jax.lax.broadcasted_iota(jnp.int32, (hp, 1), 0),
+                          n_head), q_tokens - 1)
+    limit = kvl - (q_tokens - 1 - tok)                       # [hp, 1]
+
+    def copies(t, slot, i):
+        page = pt_ref[s_idx * num_pages_per_seq + t * pages + i]
+        return (pltpu.make_async_copy(
+            lat_hbm.at[layer, page],
+            buf.at[slot, pl.ds(pl.multiple_of(i * ps, ps), ps)],
+            sem.at[slot]),)
+
+    q_rows, nq = _part_rows(q_ref[...])                      # [nq * hp, W]
+
+    def tiles(slot):
+        tile = buf[slot]                                     # [turn, W]
+        return tile, tile[:, :v_width]
+
+    o_ref[...] = _walk_pages(
+        kvl, (n_pages, n_turns, n_whole), page_size=ps, pages=pages, rows=hp,
+        width=v_width, copies=copies, tiles=tiles, limit=limit,
+        scores=lambda k: _parts_dot(q_rows, nq, k, ((1,), (1,))) * sm_scale
+    ).astype(o_ref.dtype)
+
+
+def _paged_mla_pallas(q, pool, page_tables, kv_lens, v_width, n_head,
+                      q_tokens, sm_scale, interpret, layer):
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    S, R, W = q.shape
+    ps = pool.shape[2]
+    mp = page_tables.shape[1]
+    hp = -(-R // 16) * 16               # whole sublane tiles of either dtype
+    if hp != R:
+        q = jnp.pad(q, ((0, 0), (0, hp - R), (0, 0)))
+    pages = _mla_turn_pages(ps, W, mp, pool.dtype.itemsize, hp)
+    kernel = functools.partial(
+        _paged_mla_kernel, layer=layer, page_size=ps, pages=pages,
+        num_pages_per_seq=mp, n_head=n_head, q_tokens=q_tokens,
+        v_width=v_width, sm_scale=sm_scale)
+    (out,) = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(S,),
+            in_specs=[pl.BlockSpec((None, hp, W), lambda s, pt, kl: (s, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[pl.BlockSpec((None, hp, v_width),
+                                    lambda s, pt, kl: (s, 0, 0))],
+            scratch_shapes=[
+                pltpu.VMEM((2, pages * ps, W), pool.dtype),     # latent tiles
+                pltpu.SemaphoreType.DMA((2,)),
+            ]),
+        out_shape=[jax.ShapeDtypeStruct((S, hp, v_width), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+        name="paged_mla_attention",
+    )(page_tables.astype(jnp.int32).reshape(S * mp),
+      kv_lens.astype(jnp.int32), q, pool)
+    return out[:, :R]
+
+
+def _mla_impl(impl, interpret):
+    if impl in (None, "auto"):
+        impl = "reference" if cpu_backend() else "pallas"
+    if impl not in ("reference", "pallas"):
+        raise ValueError("impl must be auto|reference|pallas, got %r" % impl)
+    return impl, cpu_backend() if interpret is None else interpret
+
+
+def paged_mla_decode_attention(q, latent_pool, page_tables, kv_lens, *,
+                               v_width, sm_scale, layer, impl=None,
+                               interpret=None):
+    """Absorbed MLA decode: one query token per slot against its latent rows.
+
+    q: ``[S, H, W]`` — every head's query in the latent space, ``[q_nope
+        W_uk | q_pe]`` (bf16 on the chip: one MXU pass; f32 is split in
+        exact bf16 parts).
+    latent_pool: the stored stack ``[L, num_pages, page_size, W]``, one row
+        ``[c | k_pe | 0]`` a token, addressed in place by ``(layer, page)``;
+        ``q`` carries zeros on the padding lanes too.
+    page_tables / kv_lens: as :func:`paged_decode_attention`; ``kv_lens[s]
+        == 0`` gives exact zeros and reads no page.
+    Returns ``[S, H, v_width]`` float32: ``softmax(q . row * sm_scale) .
+    row[:v_width]`` a head, for the caller's ``W_uv``.
+    """
+    S, H, W = q.shape
+    impl, interpret = _mla_impl(impl, interpret)
+    if impl == "reference":
+        return _paged_mla_reference(q, latent_pool, page_tables, kv_lens,
+                                    v_width, H, 1, sm_scale, layer)
+    return _paged_mla_pallas(q, latent_pool, page_tables, kv_lens, v_width,
+                             H, 1, sm_scale, interpret, layer)
+
+
+def paged_mla_prefill_attention(q, latent_pool, pages, start, valid, *,
+                                v_width, sm_scale, layer, impl=None,
+                                interpret=None):
+    """Absorbed MLA attention of one prefill chunk: ``q [C, H, W]`` at
+    absolute positions ``start ..`` against the sequence's ``pages [MP]``
+    (the chunk's own rows already scattered in), causal by position; rows at
+    or past ``valid`` are padding (garbage out).  Returns ``[C, H, v_width]``
+    float32.  The reference reduces every row over the full page-table span
+    (chunked == one bucket, bitwise); the kernel is the decode walk with
+    ``_MLA_PREFILL_TOKENS`` rows of the chunk a grid step."""
+    import jax.numpy as jnp
+
+    C, H, W = q.shape
+    impl, interpret = _mla_impl(impl, interpret)
+    if impl == "reference":
+        lens = jnp.where(jnp.arange(C) < valid,
+                         start + jnp.arange(C, dtype=jnp.int32) + 1, 0)
+        return _paged_mla_reference(q, latent_pool, pages, lens, v_width, H,
+                                    1, sm_scale, layer)
+    nt = math.gcd(C, _MLA_PREFILL_TOKENS)
+    first = jnp.arange(C // nt, dtype=jnp.int32) * nt
+    lens = jnp.where(first < valid, start + first + nt, 0)
+    out = _paged_mla_pallas(
+        q.reshape(C // nt, nt * H, W), latent_pool,
+        jnp.broadcast_to(pages[None, :], (C // nt, pages.shape[0])), lens,
+        v_width, H, nt, sm_scale, interpret, layer)
+    return out.reshape(C, H, v_width)
 
 
 # ---------------------------------------------------------------------------

@@ -1,7 +1,14 @@
-"""Expert parallelism: Switch-style Mixture-of-Experts over an ``ep``
-mesh axis.
+"""Mixture-of-Experts layers.
 
-Reference analog: none — Fluid v0.15 predates MoE.  TPU-native design
+Two layers live here.  ``moe_topk`` (below ``switch_moe``) is the DROPLESS
+top-k layer that knows which experts it holds: it routes every token over ALL
+experts, sorts the chosen (token, expert) pairs by expert and computes the
+pairs of the experts in ``experts_held`` with a grouped matrix product — no
+capacity, no drop; the pairs of experts held elsewhere are left to whoever
+holds them, and the parts of disjoint ranges (the shared experts counted
+once) add up to the whole layer.  It is what ``models/deepseek_v3.py``
+serves.  ``switch_moe`` is the Switch-style layer over an ``ep`` mesh axis that
+the Fluid op trains.  Reference analog: none — Fluid v0.15 predates MoE.  TPU-native design
 (the Switch-Transformer recipe): each device owns ONE expert FFN, tokens
 are data-sharded over the same ``ep`` axis, and routing is two
 ``all_to_all``s around the expert application:
@@ -20,10 +27,12 @@ are data-sharded over the same ``ep`` axis, and routing is two
 from __future__ import annotations
 
 import functools
+import math
 
 import numpy as np
 
-__all__ = ["switch_moe", "moe_expert_params", "switch_moe_dense_reference"]
+__all__ = ["switch_moe", "moe_expert_params", "switch_moe_dense_reference",
+           "moe_topk", "route_topk", "grouped_matmul", "MOE_COUNTERS"]
 
 
 def switch_moe_dense_reference(x, gate_w, expert_params, expert_fn):
@@ -125,3 +134,212 @@ def switch_moe(x, gate_w, expert_params, expert_fn, mesh, axis_name="ep",
         return out * (gate * keep.astype(gate.dtype))[:, None]
 
     return run(x, gate_w, expert_params)
+
+
+# ---------------------------------------------------------------------------
+# Dropless top-k experts over the experts held here.
+# ---------------------------------------------------------------------------
+
+MOE_COUNTERS = ("pairs", "experts_touched", "max_load")
+_GMM_ROWS = 128         # rows of one product inside a grid step
+_GMM_TILE_M = 512       # rows of the sorted pairs a grid step holds
+_GMM_TILE_N = 512       # output columns a grid step computes
+
+
+def route_topk(x, router_w, router_bias, *, top_k, scale=1.0):
+    """The router: ``(experts [T, k] int32, weights [T, k] float32)``.
+
+    Scores ``sigmoid(x W_g)`` in float32 at the highest matmul precision; the
+    ``top_k`` experts of ``score + bias`` are chosen (the selection bias
+    steers the choice only; on a tie the lower expert wins, as
+    ``lax.top_k``), the weights are the chosen experts' SCORES, normalised to
+    sum to one and scaled."""
+    import jax
+    import jax.numpy as jnp
+
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    biased = scores if router_bias is None else scores + router_bias
+    _, experts = jax.lax.top_k(biased, top_k)
+    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-20)
+    return experts.astype(jnp.int32), weights * scale
+
+
+def _gmm_items(group_sizes, tile_m, n_tiles):
+    """The (group, row tile) pairs a grouped product visits, row tile major:
+    ``(item_group, item_tile, offsets [G + 1], n_items)`` with ``G + n_tiles
+    - 1`` entries (the most there can be); entries past ``n_items`` repeat
+    the last one, so their blocks are already there and they do nothing."""
+    import jax.numpy as jnp
+
+    G = group_sizes.shape[0]
+    offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                               jnp.cumsum(group_sizes).astype(jnp.int32)])
+    lo, hi = offsets[:-1], offsets[1:]
+    first = lo // tile_m
+    count = jnp.where(hi > lo, (hi - 1) // tile_m - first + 1, 0)
+    starts = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                              jnp.cumsum(count).astype(jnp.int32)])
+    n_items = starts[-1]
+    i = jnp.minimum(jnp.arange(G + n_tiles - 1, dtype=jnp.int32),
+                    jnp.maximum(n_items - 1, 0))
+    group = jnp.clip(jnp.searchsorted(starts, i, side="right") - 1, 0, G - 1)
+    tile = jnp.clip(first[group] + i - starts[group], 0, n_tiles - 1)
+    return group.astype(jnp.int32), tile.astype(jnp.int32), offsets, n_items
+
+
+def _gmm_kernel(group_ref, tile_ref, off_ref, n_ref, x_ref, w_ref, o_ref, *,
+                tile_m, rows):
+    """One grid step = one (column tile, item): the rows of item's group that
+    lie in item's row tile, against that group's ``[K, tn]`` block, ``rows``
+    at a time (a product of fewer rows costs the MXU as much: it is the
+    block's load that takes the time).  Consecutive items of one row tile
+    keep the output block; its first item zeroes it."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    i = pl.program_id(1)
+    g, t = group_ref[i], tile_ref[i]
+    opens = (i == 0) | (tile_ref[jnp.maximum(i - 1, 0)] != t)
+
+    @pl.when(opens)
+    def _zero():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(i < n_ref[0])
+    def _item():
+        lo = jnp.maximum(off_ref[g], t * tile_m) - t * tile_m
+        hi = jnp.minimum(off_ref[g + 1], (t + 1) * tile_m) - t * tile_m
+        w = w_ref[...]
+
+        def part(j, _):
+            at = pl.ds(pl.multiple_of(j * rows, rows), rows)
+            y = jnp.dot(x_ref[at, :], w, preferred_element_type=jnp.float32)
+            r = j * rows + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+            o_ref[at, :] = jnp.where((r >= lo) & (r < hi), y,
+                                     o_ref[at, :]).astype(o_ref.dtype)
+
+        jax.lax.fori_loop(jax.lax.div(lo, rows),
+                          jax.lax.div(hi + rows - 1, rows), part, None)
+
+
+def grouped_matmul(x, w, group_sizes, *, layer=None, impl=None,
+                   interpret=None):
+    """``out[r] = x[r] @ w[group of row r]`` for rows sorted by group.
+
+    x ``[M, K]``; w ``[G, K, N]``, or a stack ``[L, G, K, N]`` addressed in
+    place by the static ``layer`` (no layer-sized slice is made on the chip);
+    group_sizes ``[G]`` int32: the first ``group_sizes[0]`` rows belong to
+    group 0, and so on.  Rows past ``sum(group_sizes)`` come back ZERO.
+    Returns ``[M, N]`` float32.  A group with no row costs nothing: its
+    weights are not read.  ``impl``: None/"auto" (the Pallas kernel on a TPU,
+    ``lax.ragged_dot`` elsewhere), "reference", "pallas"."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from ..core import cpu_backend
+
+    if impl in (None, "auto"):
+        impl = "reference" if cpu_backend() else "pallas"
+    if impl not in ("reference", "pallas"):
+        raise ValueError("impl must be auto|reference|pallas, got %r" % impl)
+    if (w.ndim == 4) != (layer is not None):
+        raise ValueError("w is [G, K, N], or [L, G, K, N] with layer=")
+    M, K = x.shape
+    G, N = w.shape[-3], w.shape[-1]
+    group_sizes = group_sizes.astype(jnp.int32)
+    live = (jnp.arange(M) < group_sizes.sum())[:, None]
+    if impl == "reference":
+        out = jax.lax.ragged_dot(
+            x.astype(w.dtype), w if layer is None else w[layer], group_sizes,
+            preferred_element_type=jnp.float32)
+        return jnp.where(live, out, 0.0)
+    if interpret is None:
+        interpret = cpu_backend()
+    rows = min(_GMM_ROWS, -(-M // 16) * 16)
+    tile_m = min(_GMM_TILE_M, -(-M // rows) * rows)
+    m_pad = -(-M // tile_m) * tile_m
+    tn = math.gcd(N, _GMM_TILE_N)
+    if m_pad != M:
+        x = jnp.pad(x, ((0, m_pad - M), (0, 0)))
+    n_tiles = m_pad // tile_m
+    group, tile, offsets, n_items = _gmm_items(group_sizes, tile_m, n_tiles)
+    lead = () if layer is None else (None,)
+    at = () if layer is None else (int(layer),)
+    (out,) = pl.pallas_call(
+        functools.partial(_gmm_kernel, tile_m=tile_m, rows=rows),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(N // tn, G + n_tiles - 1),
+            in_specs=[
+                pl.BlockSpec((tile_m, K),
+                             lambda j, i, g, t, o, n: (t[i], 0)),
+                pl.BlockSpec(lead + (None, K, tn),
+                             lambda j, i, g, t, o, n: at + (g[i], 0, j))],
+            out_specs=[pl.BlockSpec((tile_m, tn),
+                                    lambda j, i, g, t, o, n: (t[i], j))]),
+        out_shape=[jax.ShapeDtypeStruct((m_pad, N), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="moe_grouped_matmul",
+    )(group, tile, offsets, n_items.reshape(1), x.astype(w.dtype), w)
+    # a row tile no group reaches was never written
+    return jnp.where(live, out[:M], 0.0)
+
+
+def moe_topk(x, router, experts, shared, *, top_k, experts_held, scale=1.0,
+             token_mask=None, layer=None, impl=None, interpret=None):
+    """Dropless top-k experts, the part of ``experts_held``: ``(y [T, D]
+    float32, counts [3] int32, chosen [T, k] int32)``.
+
+    x ``[T, D]``.  router: ``{"w": [D, E], "bias": [E] or None}`` over ALL
+    ``E`` experts (:func:`route_topk`).  experts: ``{"w_gu": [.., H, D, 2F],
+    "w_down": [.., H, F, D]}``, the ``H = hi - lo`` SwiGLU experts ``lo ..
+    hi - 1`` this caller holds (gate | up fused column-wise; a stack of
+    layers with ``layer=``).  shared: None, or ``{"w_gu": [D, 2Fs], "w_down":
+    [Fs, D]}``, added ONCE by whoever passes it.  ``token_mask [T]`` bool:
+    rows that are padding route nowhere.  Every chosen pair of a held expert
+    is computed (no capacity); a pair of an expert held elsewhere is left out
+    here: the parts of disjoint ranges sum to the layer.  ``counts``
+    (``MOE_COUNTERS``): held pairs, held experts with a pair, the most pairs
+    one expert took.  ``chosen``: the router's choice over all ``E`` that the
+    layer computed with (padding rows' too, though they go nowhere)."""
+    import jax
+    import jax.numpy as jnp
+
+    lo, hi = experts_held
+    T, D = x.shape
+    F = experts["w_down"].shape[-2]
+    chosen, weights = route_topk(x, router["w"], router.get("bias"),
+                                 top_k=top_k, scale=scale)
+    held = (chosen >= lo) & (chosen < hi)
+    if token_mask is not None:
+        held = held & token_mask[:, None]
+    # pairs sorted by expert, the pairs left out after them
+    key = jnp.where(held, chosen - lo, hi - lo).reshape(T * top_k)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.bincount(key, length=hi - lo + 1)[:hi - lo].astype(jnp.int32)
+    xs = x[order // top_k]
+    kw = dict(layer=layer, impl=impl, interpret=interpret)
+    gu = grouped_matmul(xs, experts["w_gu"], sizes, **kw)
+    act = (jax.nn.silu(gu[:, :F]) * gu[:, F:]).astype(x.dtype)
+    down = grouped_matmul(act, experts["w_down"], sizes, **kw)
+    w_sorted = jnp.where(held, weights, 0.0).reshape(T * top_k)[order]
+    # back to (token, choice) order: a gather by the inverse permutation
+    back = jnp.argsort(order)
+    y = (down * w_sorted[:, None])[back].reshape(T, top_k, D).sum(axis=1)
+    if shared is not None:
+        sgu = jnp.dot(x.astype(shared["w_gu"].dtype), shared["w_gu"],
+                      preferred_element_type=jnp.float32)
+        fs = shared["w_down"].shape[0]
+        y = y + jnp.dot(
+            (jax.nn.silu(sgu[:, :fs]) * sgu[:, fs:]).astype(x.dtype),
+            shared["w_down"], preferred_element_type=jnp.float32)
+    counts = jnp.stack([sizes.sum(), (sizes > 0).sum(), sizes.max()])
+    return y, counts.astype(jnp.int32), chosen

@@ -212,19 +212,22 @@ class DecodeModel:
     of ``slot_state`` ``{name: dict(layers=, shape=, dtype=)}`` as
     ``[layers, num_slots, *shape]``.  ``num_layers`` / ``num_heads`` /
     ``head_dim`` describe the layers that hold paged K/V (their count,
-    KV heads and head width), not the model's depth.  A model scatters
+    KV heads and head width), not the model's depth; left out (0) the
+    cache has NO ``"k"`` / ``"v"`` leaf and every page-indexed leaf is one
+    of ``page_pools`` (an MLA model's one latent row a token).  A model scatters
     rows into a leaf and attends through
     ``paged_*_attention(..., layer=li)``; it must not slice a layer out
     (``cache["k"][li]`` is a layer-sized copy in every step on the chip).
 
     All are jitted by the scheduler (the cache donated on TPU); they
     must be shape-stable in everything but values.
-    ``models.transformer.build_decode_model`` and
-    ``models.minicpm_sala.build_decode_model`` are the in-repo producers.
+    ``models.transformer.build_decode_model``,
+    ``models.minicpm_sala.build_decode_model`` and
+    ``models.deepseek_v3.build_decode_model`` are the in-repo producers.
     """
 
     def __init__(self, decode_fn, prefill_chunk_fn, *, params=None,
-                 num_layers, num_heads, head_dim, vocab_size,
+                 num_layers=0, num_heads=0, head_dim=0, vocab_size,
                  eos_id=None, name="decode-model", page_pools=None,
                  slot_state=None, step_counters=()):
         self.decode_fn = decode_fn

@@ -5,8 +5,10 @@ whatever else a model keeps per page or per slot, as ONE pytree.
 arrays by leaf name that the scheduler threads whole through every step
 program (donated on a TPU).  *Page-indexed* leaves share ONE allocator and
 ONE page table and have the page axis at 1: ``"k"`` and ``"v"`` (the layers
-that hold paged K/V) and a model's further ``page_pools`` (one row per
-``tokens_per_row`` tokens of a page, e.g. pooled keys for block selection).
+that hold paged K/V; a model with ``num_layers == 0`` has neither) and a
+model's further ``page_pools`` (one row per ``tokens_per_row`` tokens of a
+page, e.g. pooled keys for block selection, or an MLA model's one latent row
+a token: its ONLY page-indexed leaf).
 *Slot-indexed* leaves (``slot_state``, the slot axis at 1) hold recurrent
 state of the sequence seated in a slot; the cache only allocates and resets
 them — a sequence's first prefill chunk takes its slot's state as zero inside
@@ -129,6 +131,9 @@ class PagedKVCache:
     num_layers / num_heads / head_dim: model dims; the pools are
         ``[L, num_pages, page_size, H*D]`` (k and v; ``pool_shape``), heads
         folded head-major into the last axis — see the module docstring.
+        ``num_layers == 0``: no layer holds per-head K/V, and the cache has
+        no ``"k"`` / ``"v"`` leaf at all (every page-indexed leaf is one of
+        ``page_pools``; there must be one).
     num_pages: pool size INCLUDING the reserved scratch page 0.
     page_size: tokens per page.
     max_seq_len: longest sequence the runtime will hold; fixes the
@@ -170,9 +175,14 @@ class PagedKVCache:
         self.dtype = jnp.dtype(dtype)
         # every leaf's (shape, dtype) by name; the page-indexed ones have
         # the page axis at 1, the slot-indexed ones the slot axis at 1
-        self._page_leaves = {
-            "k": (self.pool_shape, self.dtype),
-            "v": (self.pool_shape, self.dtype)}
+        self._page_leaves = {}
+        if self.num_layers:
+            self._page_leaves = {"k": (self.pool_shape, self.dtype),
+                                 "v": (self.pool_shape, self.dtype)}
+        elif not page_pools:
+            raise ServingError(
+                "a cache with no K/V layers (num_layers == 0) needs a "
+                "page_pools leaf: it would hold nothing a page addresses")
         for name, spec in (page_pools or {}).items():
             if self.page_size % int(spec["tokens_per_row"]):
                 raise ServingError(
@@ -238,7 +248,8 @@ class PagedKVCache:
     @property
     def page_leaf_names(self):
         """Names of the page-indexed leaves of :attr:`pools` (page axis 1):
-        ``k``, ``v`` and the model's further page pools."""
+        ``k`` and ``v`` where the model has K/V layers, and its further
+        page pools."""
         return tuple(self._page_leaves)
 
     @property
@@ -261,8 +272,9 @@ class PagedKVCache:
         """Bytes of all slot-indexed leaves together."""
         return self._nbytes(self._slot_leaves)
 
-    # the two leaves every model has, by name (tools, tests and the fault
-    # injectors read and poke them; the scheduler threads ``pools`` whole)
+    # the two leaves of a model with K/V layers, by name (tools, tests and
+    # the fault injectors read and poke them; the scheduler threads
+    # ``pools`` whole)
     @property
     def k_pool(self):
         return self.pools["k"]

@@ -215,6 +215,77 @@ def test_paged_prefill_compiles_for_v5e(one_chip, no_persistent_cache, chunk):
         jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)) == 1
 
 
+def _latent_shapes():
+    """The latent cell's shapes, from its configuration file."""
+    import json
+    import os
+
+    from paddle_tpu.models import deepseek_v3 as M
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "chipbench/configs/kanana2_30b_a3b.json")) as f:
+        cfg = json.load(f)
+    return cfg, M._dims(cfg)
+
+
+@pytest.mark.parametrize("qdtype", [jnp.float32, jnp.bfloat16],
+                         ids=["q-f32", "q-bf16"])
+@pytest.mark.parametrize("form", ["decode", "prefill"])
+def test_latent_attention_compiles_for_v5e(one_chip, no_persistent_cache,
+                                           form, qdtype):
+    """The absorbed kernel at the cell's slots, heads, page, lanes and page
+    table, on the stored stack (a layer past the first), in both forms."""
+    cfg, d = _latent_shapes()
+    mp = cfg["max_seq_len"] // cfg["page"]
+
+    def sds(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((d["L"], cfg["num_pages"], cfg["page"], d["W"]), jnp.bfloat16)
+    kw = dict(v_width=d["R"], sm_scale=d["sm_scale"], layer=d["L"] - 1,
+              impl="pallas", interpret=False)
+    if form == "decode":
+        calls = _kernel_calls(
+            lambda q, pool, t, n: FA.paged_mla_decode_attention(
+                q, pool, t, n, **kw),
+            sds((cfg["slots"], d["H"], d["W"]), qdtype), pool,
+            sds((cfg["slots"], mp)), sds((cfg["slots"],)))
+    else:
+        calls = _kernel_calls(
+            lambda q, pool, pages, start, valid:
+            FA.paged_mla_prefill_attention(q, pool, pages, start, valid, **kw),
+            sds((cfg["chunk"], d["H"], d["W"]), qdtype), pool, sds((mp,)),
+            sds(()), sds(()))
+    assert calls == 1
+
+
+@pytest.mark.parametrize("rows", ["decode", "chunk"])
+def test_grouped_matmul_compiles_for_v5e(one_chip, no_persistent_cache, rows):
+    """Both products of an expert layer (gate-and-up, down) on the stacked
+    experts, at a decode step's and at a chunk's sorted pairs."""
+    from paddle_tpu.parallel import moe
+
+    cfg, d = _latent_shapes()
+    pairs = d["k"] * (cfg["slots"] if rows == "decode" else cfg["chunk"])
+    n_moe = d["L"] - d["n_dense"]
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def both(x, w_gu, w_down, sizes):
+        gu = moe.grouped_matmul(x, w_gu, sizes, layer=n_moe - 1,
+                                impl="pallas", interpret=False)
+        act = (jax.nn.silu(gu[:, :d["Fm"]]) * gu[:, d["Fm"]:]).astype(x.dtype)
+        return moe.grouped_matmul(act, w_down, sizes, layer=n_moe - 1,
+                                  impl="pallas", interpret=False)
+
+    assert _kernel_calls(
+        both, sds((pairs, d["D"])), sds((n_moe, d["E"], d["D"], 2 * d["Fm"])),
+        sds((n_moe, d["E"], d["Fm"], d["D"])),
+        sds((d["E"],), jnp.int32)) == 2
+
+
 def test_interpret_follows_the_backend_in_one_place():
     """On this (CPU) backend the default is interpret / the reference
     engine; a kernel asked to compile here (interpret=False) must fail
