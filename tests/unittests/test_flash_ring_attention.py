@@ -94,17 +94,25 @@ def test_flash_causal_offset_when_T_ne_S():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-3, atol=2e-3)
 
 
+# explicit 128 x 128 blocks at a toy length, and the chooser's own tiles at the
+# longest training cell's [32, 4096, 64] f32, where the backward's query side
+# is resident and walked inside the step
+_LOWERED = {"T256-bf16-128x128": ((2, 4, 256, 64), jnp.bfloat16, 128),
+            "s4096-f32-chosen": ((4, 8, 4096, 64), jnp.float32, None)}
+
+
+@pytest.mark.parametrize("case", list(_LOWERED))
 @pytest.mark.parametrize("causal,with_lens", [(False, False), (True, False), (True, True)])
-def test_flash_lowers_for_tpu(causal, with_lens, monkeypatch):
+def test_flash_lowers_for_tpu(causal, with_lens, case, monkeypatch):
     """Compile gate: the Pallas kernels must produce a valid Mosaic TPU
     module (block specs, scalar prefetch) — lowered cross-platform from the
     CPU test host via jax.export, no TPU execution."""
-    B, H, T, D = 2, 4, 256, 64
-    q = jnp.zeros((B, H, T, D), jnp.bfloat16)
+    (B, H, T, D), dtype, block = _LOWERED[case]
+    q = jax.ShapeDtypeStruct((B, H, T, D), dtype)
     lens = jnp.full((B,), T, jnp.int32) if with_lens else None
 
     def f(q, k, v):
-        return flash_attention(q, k, v, lens, causal, None, 128, 128, False)
+        return flash_attention(q, k, v, lens, causal, None, block, block, False)
 
     from jax import export as jax_export  # plain `jax.export` attribute is
     # version-dependent; the submodule import works on every release in use
@@ -112,12 +120,15 @@ def test_flash_lowers_for_tpu(causal, with_lens, monkeypatch):
     exported = jax_export.export(jax.jit(f), platforms=["tpu"])(q, q, q)
     assert "tpu_custom_call" in exported.mlir_module()
 
-    # the fused one-grid backward (dq+dkv in a single kernel) lowers too
-    # (the scan backward, which this T gets, is plain XLA)
+    # the fused one-grid backward (dq+dkv in a single kernel) lowers too: it
+    # is what the chooser gives the cell's shape (the toy length gets the
+    # scan, which is plain XLA)
+    if block is None:
+        assert FA._bwd_engine(B * H, T, T, D, q.dtype.itemsize) == "fused"
     _force_bwd(monkeypatch, "fused")
 
     def g(q, k, v):
-        return (flash_attention(q, k, v, lens, causal, None, 128, 128, False)
+        return (flash_attention(q, k, v, lens, causal, None, block, block, False)
                 .astype(jnp.float32) ** 2).sum()
 
     exported_fused = jax.export.export(
@@ -154,10 +165,13 @@ def test_flash_uneven_tail_block():
 
 
 def _small_chooser(monkeypatch, vmem_budget=None):
-    """The chooser at toy widths: blocks of at most 16 rows (and, with a
-    small budget, only part of S resident a step), so the interpret-mode
-    shapes below walk the same forms the cells' shapes do."""
+    """The choosers at toy widths: blocks of at most 16 rows in the forward
+    and in the backward (and, with a small budget, only part of S resident a
+    forward step), so the interpret-mode shapes below walk the same forms the
+    cells' shapes do: several query blocks x several key blocks a head."""
     monkeypatch.setattr(FA, "_FWD_BLOCK", 16)
+    monkeypatch.setattr(FA, "_BWD_BLOCK_Q", 16)
+    monkeypatch.setattr(FA, "_BWD_BLOCK_K", 16)
     if vmem_budget is not None:
         monkeypatch.setattr(FA, "_FWD_VMEM_BUDGET", vmem_budget)
 
@@ -173,7 +187,8 @@ _CHOSEN_LENS = {"full": None, "ragged": lambda S: [S, S // 2 + 1, 3],
 def _check_chosen_tiles(monkeypatch, bwd_impl, B, H, T, S, D, lens, causal, seed):
     """block_q = block_k = None at toy widths, one backward engine: output and
     the three gradients against the plain reference; a sequence with no
-    visible key (``lens[b] == 0``) comes out as exact zeros."""
+    visible key (``lens[b] == 0``) comes out as exact zeros, in the output
+    and in dq, dk and dv."""
     _force_bwd(monkeypatch, bwd_impl)
     monkeypatch.setattr(FA, "DEFAULT_BLOCK_K", 16)
     q, k, v, w = _rand_qkvw(B, H, T, S, D, seed)
@@ -181,7 +196,7 @@ def _check_chosen_tiles(monkeypatch, bwd_impl, B, H, T, S, D, lens, causal, seed
     got = _out_and_grads(flash_attention, q, k, v, w, **kw)
     _assert_out_and_grads_close(got, _out_and_grads(mha_reference, q, k, v, w, **kw))
     for b, n in enumerate(lens or ()):
-        assert n or not np.asarray(got[0])[b].any()
+        assert n or not any(np.asarray(x)[b].any() for x in got)
 
 
 @pytest.mark.parametrize("bwd_impl", ["scan", "fused"])
@@ -191,16 +206,21 @@ def _check_chosen_tiles(monkeypatch, bwd_impl, B, H, T, S, D, lens, causal, seed
 def test_flash_chosen_tiles_match_reference(causal, shape, lens, bwd_impl,
                                             monkeypatch):
     """block_q = block_k = None: the forward's tiles come from the shape
-    (``_fwd_tiles``) and the backward keeps its own; output and the three
-    gradients against the plain reference."""
+    (``_fwd_tiles``); output and the three
+    gradients against the plain reference; the backward's come from the shape
+    too (``_bwd_tiles``)."""
     _small_chooser(monkeypatch)
     T, S = _CHOSEN_SHAPES[shape]
     B, H, D = 3, 2, 8
     heads, bq, bk, chunks = FA._fwd_tiles(B * H, T, S, D, 4)
+    bwd_heads, bwd_bq, bwd_bk = FA._bwd_blocks(B * H, T, S, D, 4)
     if shape == "heads":
         assert heads > 1 and bq == T
+        assert bwd_heads > 1 and (bwd_bq, bwd_bk) == (T, S)
     else:
         assert bq < T and (chunks > 1 or bk * chunks < S)
+        # several query blocks x several key blocks a head, one head a step
+        assert bwd_heads == 1 and bwd_bq < T and 2 * bwd_bk <= S
     kv_lens = _CHOSEN_LENS[lens] and _CHOSEN_LENS[lens](S)
     _check_chosen_tiles(monkeypatch, bwd_impl, B, H, T, S, D, kv_lens, causal,
                         seed=11)
@@ -233,8 +253,8 @@ def test_flash_chosen_tiles_with_part_of_S_resident(causal, bwd_impl, monkeypatc
                         causal, seed=12)
 
 
-# (T, S) under _FUSED_MIN_T; over it with the fused kernel's residency inside
-# the budget; over it and outside: the chooser's three ways, at toy widths
+# (T, S) under _BWD_MIN_T; from it on with the kernel's residency inside the
+# budget; and outside it: the chooser's three ways, at toy widths
 _BOUNDARY_SHAPES = {"under-min-T": ((24, 40), "scan"),
                     "fits": ((32, 40), "fused"),
                     "over-budget": ((64, 64), "scan")}
@@ -249,9 +269,9 @@ def test_flash_bwd_auto_on_both_sides_of_its_boundary(shape, causal, lens,
     it names is the one that runs, and its gradients match the reference."""
     (T, S), engine = _BOUNDARY_SHAPES[shape]
     B, H, D = 3, 2, 8
-    monkeypatch.setattr(FA, "_FUSED_MIN_T", 32)
-    monkeypatch.setattr(FA, "_FUSED_VMEM_BUDGET", 50_000)
-    assert FA._bwd_engine(T, S, D, 4, 16) == engine
+    monkeypatch.setattr(FA, "_BWD_MIN_T", 32)
+    monkeypatch.setattr(FA, "_BWD_VMEM_BUDGET", 250_000)
+    assert FA._bwd_engine(B * H, T, S, D, 4, 16, 16) == engine
     ran = []
 
     def spy(name):
@@ -272,18 +292,22 @@ def test_flash_bwd_auto_on_both_sides_of_its_boundary(shape, causal, lens,
     _assert_out_and_grads_close(got, _out_and_grads(mha_reference, q, k, v, w, **kw))
 
 
+@pytest.mark.parametrize("blocks", [16, None], ids=["16x16", "chosen"])
 @pytest.mark.parametrize("bwd_impl", ["scan", "fused"])
 @pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
-def test_flash_grads_match_bf16_inputs(causal, bwd_impl, monkeypatch):
+def test_flash_grads_match_bf16_inputs(causal, bwd_impl, blocks, monkeypatch):
     """bf16 q, k, v (a caller who casts before the kernel, ROADMAP S2c):
     gradients come back bf16 and within bf16's rounding of the f32
-    reference on the same (rounded) values."""
+    reference on the same (rounded) values, at explicit blocks and at the
+    choosers' own (set small: an uneven T in several query blocks)."""
     _force_bwd(monkeypatch, bwd_impl)
-    q, k, v, w = _rand_qkvw(2, 2, 32, 32, 8, seed=16)
+    _small_chooser(monkeypatch)
+    T = 32 if blocks else 40
+    q, k, v, w = _rand_qkvw(2, 2, T, T, 8, seed=16)
     qb, kb, vb = (x.astype(jnp.bfloat16) for x in (q, k, v))
-    lens = jnp.array([32, 19], jnp.int32)
+    lens = jnp.array([T, 19], jnp.int32)
     got = _out_and_grads(flash_attention, qb, kb, vb, w, kv_lens=lens,
-                         causal=causal, block_q=16, block_k=16)
+                         causal=causal, block_q=blocks, block_k=blocks)
     want = _out_and_grads(mha_reference, *(x.astype(jnp.float32) for x in (qb, kb, vb)),
                           w, kv_lens=lens, causal=causal)
     for a, b in zip(got, want):
@@ -293,16 +317,19 @@ def test_flash_grads_match_bf16_inputs(causal, bwd_impl, monkeypatch):
                                    rtol=2e-2, atol=2e-2)
 
 
-# the benchmark's three training shapes [B*H, T, D] and the backward engine
-# "auto" picked for them before the forward chose its own tiles (PR 28)
-_CELL_SHAPES = [((512, 256, 64), "scan"), ((64, 2048, 64), "fused"),
-                ((32, 4096, 64), "scan")]
+# the benchmark's three training shapes [B*H, T, D], the backward engine each
+# takes and the kernel's tiles (heads, query rows, keys) there: ONE kernel,
+# several heads a step where one tile holds all of T; at T = 256 the chip
+# read the scan as fast (PR 34), so the kernel starts at _BWD_MIN_T
+_CELL_SHAPES = [((512, 256, 64), "scan", (8, 256, 256)),
+                ((64, 2048, 64), "fused", (1, 512, 512)),
+                ((32, 4096, 64), "fused", (1, 512, 512))]
 
 
 @pytest.mark.parametrize("itemsize", [4, 2], ids=["f32", "bf16"])
-@pytest.mark.parametrize("shape,engine", _CELL_SHAPES,
+@pytest.mark.parametrize("shape,engine,bwd_tiles", _CELL_SHAPES,
                          ids=["s256", "s2048", "s4096"])
-def test_flash_chooser_at_the_cells_shapes(shape, engine, itemsize):
+def test_flash_chooser_at_the_cells_shapes(shape, engine, bwd_tiles, itemsize):
     bh, T, D = shape
     heads, bq, bk, chunks = FA._fwd_tiles(bh, T, T, D, itemsize)
     assert FA._fwd_vmem_bytes(heads, bq, bk, chunks, D, itemsize) <= FA._FWD_VMEM_BUDGET
@@ -311,8 +338,18 @@ def test_flash_chooser_at_the_cells_shapes(shape, engine, itemsize):
     assert heads * bq * bk * chunks >= 16 * 128 * 128
     # a short sequence does not pay for a long one's tiles
     assert (heads > 1) == (T <= FA._FWD_BLOCK)
-    # the backward is the parent's: its blocks stay 128 whatever came out above
-    assert FA._bwd_engine(T, T, D, itemsize) == engine
+    # the backward: the engine, the kernel's tiles, and a residency inside the
+    # budget and inside the limit the kernel is compiled with (under half of
+    # v5e's 128 MiB of VMEM a core)
+    assert FA._bwd_engine(bh, T, T, D, itemsize) == engine
+    assert (engine == "fused") == (T >= FA._BWD_MIN_T)
+    assert FA._bwd_blocks(bh, T, T, D, itemsize) == bwd_tiles
+    need = FA._bwd_vmem_bytes(*bwd_tiles, T, D, itemsize)
+    limit = FA._bwd_vmem_limit(*bwd_tiles, T, D, itemsize)
+    assert need <= FA._BWD_VMEM_BUDGET and need < limit <= 64 * 2 ** 20
+    assert bh % bwd_tiles[0] == 0 and T % bwd_tiles[1] == 0 == T % bwd_tiles[2]
+    # the residency is the query side's: at this many rows the scan takes over
+    assert FA._bwd_engine(bh, 16 * 4096, 16 * 4096, D, itemsize) == "scan"
 
 
 def test_flash_fwd_grid_steps_recorded_once_per_compiled_shape():
@@ -328,6 +365,30 @@ def test_flash_fwd_grid_steps_recorded_once_per_compiled_shape():
     for _ in range(3):
         f(q, k, v).block_until_ready()
     steps = (B * H // heads) * -(-T // bq) * -(-T // (bk * chunks))
+    assert cell.value == (before or steps) == steps
+
+
+@pytest.mark.parametrize("engine", ["fused", "scan"])
+def test_flash_bwd_grid_steps_recorded_once_per_compiled_shape(engine, monkeypatch):
+    from paddle_tpu import observability as obs
+
+    _force_bwd(monkeypatch, engine)
+    _small_chooser(monkeypatch)
+    B, H, T, D = 2, 2, 48, 8
+    q, k, v = _rand_qkv(B=B, H=H, T=T, D=D, seed=17)
+    heads, bq, bk = FA._bwd_blocks(B * H, T, T, D, 4)
+    if engine == "scan":  # a turn is every (batch, head)'s [T, block_k] strip
+        heads, bq, bk = B * H, T, FA.DEFAULT_BLOCK_K
+    labels = {"T": T, "S": T, "block": "%dx%d" % (bq, min(bk, T)), "bh": B * H,
+              "causal": 1, "engine": engine}
+    cell = obs.counter("flash.bwd.grid_steps", labels=labels)
+    before = cell.value
+    f = jax.jit(jax.grad(lambda q, k, v: flash_attention(q, k, v, None, True).sum(),
+                         argnums=(0, 1, 2)))
+    for _ in range(3):
+        jax.block_until_ready(f(q, k, v))
+    steps = (B * H // heads) * -(-T // min(bk, T))
+    assert steps == {"fused": 12, "scan": 1}[engine]
     assert cell.value == (before or steps) == steps
 
 

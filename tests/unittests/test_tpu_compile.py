@@ -64,13 +64,14 @@ def _kernel_calls(fn, *args):
 
 
 # (shape [B, H, T, D], compiled kernels expected in fwd+bwd): the forward is
-# always one; the backward is the XLA scan (no kernel) except where the
-# "auto" rule picks the fused one-grid kernel (T >= 2048 and it fits VMEM)
+# always one, and the backward the fused one-grid kernel from T = 512 on (the
+# XLA scan, no kernel, under it and past the kernel's VMEM budget); here at
+# explicit blocks of 128, the query side resident all the same
 @pytest.mark.parametrize("shape,kernels", [
-    ((64, 8, 256, 64), 1),     # Transformer-base training shape
-    ((8, 8, 2048, 64), 2),     # fused backward
-    ((4, 8, 4096, 64), 1),     # fused would not fit: scan backward
-], ids=["b64xT256", "b8xT2048-fused", "b4xT4096-scan"])
+    ((64, 8, 256, 64), 1),     # Transformer-base training shape: scan
+    ((8, 8, 2048, 64), 2),
+    ((4, 8, 4096, 64), 2),     # 16.70M of 16.00M scoped before PR 34
+], ids=["b64xT256-scan", "b8xT2048-fused", "b4xT4096-fused"])
 def test_flash_fwd_bwd_compiles_for_v5e(one_chip, no_persistent_cache, shape,
                                         kernels):
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
@@ -85,17 +86,21 @@ def test_flash_fwd_bwd_compiles_for_v5e(one_chip, no_persistent_cache, shape,
 
 # What the three training cells hand the kernel: ``decorate`` leaves the
 # activations f32 (twice the tile bytes of the bf16 cases above), every call
-# brings ``kv_lens``, and the forward's tiles are the chooser's own
-# (``block_q = block_k = None``).  A tile that fits scoped VMEM on paper
-# (``_fwd_vmem_bytes``) has to fit it here.
+# brings ``kv_lens``, and the tiles are the choosers' own (``block_q =
+# block_k = None``).  A tile that fits scoped VMEM on paper (``_fwd_vmem_bytes``;
+# the backward's ``_bwd_vmem_bytes`` inside the limit it is compiled with,
+# ``_bwd_vmem_limit``) has to fit it here.
 @pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
 @pytest.mark.parametrize("shape,kernels", [
     ((64, 8, 256, 64), 1),
+    ((32, 8, 512, 64), 2),     # the least T of the kernel: two heads a step
     ((8, 8, 2048, 64), 2),
-    ((4, 8, 4096, 64), 1),
-], ids=["b64xT256", "b8xT2048-fused", "b4xT4096-scan"])
+    ((4, 8, 4096, 64), 2),
+    ((1, 8, 65536, 64), 1),    # the query side past the budget: scan backward
+], ids=["b64xT256-scan", "b32xT512-fused", "b8xT2048-fused", "b4xT4096-fused",
+        "b1xT65536-scan"])
 def test_flash_chosen_tiles_compile_for_v5e(one_chip, no_persistent_cache,
                                             shape, kernels, dtype, causal):
     x = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
