@@ -1,0 +1,9 @@
+"""Device time of the sliding-window layers' grouped-query page walk per
+decode step in the traced part of the window: the custom calls named
+``paged_gqa_window_attention``, every sliding layer's summed
+(``chipbench/mellum_decode.py``)."""
+from chipbench import kanana_decode, mellum_decode
+
+
+def read(observed):
+    return kanana_decode.kernel_ms(observed, mellum_decode.WINDOW_KERNEL)

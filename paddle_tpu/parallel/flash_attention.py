@@ -65,7 +65,8 @@ from ..core import cpu_backend
 
 __all__ = ["flash_attention", "mha_reference", "paged_decode_attention",
            "paged_prefill_attention", "paged_mla_decode_attention",
-           "paged_mla_prefill_attention", "paged_kv_finite"]
+           "paged_mla_prefill_attention", "paged_gqa_decode_attention",
+           "paged_gqa_prefill_attention", "paged_kv_finite"]
 
 # what tools and tests pass explicitly, and the scan backward's key block;
 # the kernels choose their own from the shape (_fwd_tiles, _bwd_tiles)
@@ -946,7 +947,7 @@ def _parts_dot(a_rows, n, b, dims):
 
 
 def _walk_pages(kvl, counts, *, page_size, pages, rows, width, copies,
-                tiles, scores, limit=None):
+                tiles, scores, limit=None, first=None, pv=None):
     """The walk of ONE slot's own pages that the paged decode kernels share:
     ``pages`` pages a turn are copied whole into tile ``slot`` of two (the
     next turn's copies in flight while this turn computes), and a turn is one
@@ -961,6 +962,15 @@ def _walk_pages(kvl, counts, *, page_size, pages, rows, width, copies,
     scores(k): ``[rows, turn]`` float32.
     limit: keys each row sees ``[rows, 1]`` where rows differ (a chunk's
         tokens); every row sees ``kvl`` otherwise.
+    first: None, or ``(base, lo, n_head)`` for a walk that does not start at
+        the sequence's first key (a WINDOW): the walk's first key is the
+        sequence's key ``base`` (``kvl``, ``limit`` and ``lo`` count from the
+        sequence's start; ``counts`` from the walk's), a row sees no key
+        before ``lo`` (``[rows, 1]`` or a scalar), and the first ``n_head``
+        turns hold such keys: they are masked, on both sides, as the last
+        ones are.
+    pv: None (``p . v`` of all rows against the whole ``v`` tile), or
+        ``pv(p, v) -> [rows, width]``.
     A masked turn replaces the scores past a row's keys whatever they are
     and zeroes the value rows past ``kvl`` (they hold what an earlier turn
     or nobody left: 0 * garbage must stay finite).  Returns the normalised
@@ -990,11 +1000,19 @@ def _walk_pages(kvl, counts, *, page_size, pages, rows, width, copies,
         each_page(t, slot, lambda c: c.wait())
         k, v = tiles(slot)
         s = scores(k)                                      # [rows, turn]
-        if masked:
+        if masked and first is None:
             left = kvl - t * turn
             seen = left if limit is None else limit - t * turn
             s = jnp.where(jax.lax.broadcasted_iota(
                 jnp.int32, (rows, turn), 1) < seen, s, NEG_INF)
+            v = jnp.where(jax.lax.broadcasted_iota(
+                jnp.int32, (turn, 1), 0) < left, v, jnp.zeros_like(v))
+        elif masked:
+            key0 = first[0] + t * turn
+            left = kvl - key0
+            seen = left if limit is None else limit - key0
+            col = jax.lax.broadcasted_iota(jnp.int32, (rows, turn), 1)
+            s = jnp.where((col < seen) & (col >= first[1] - key0), s, NEG_INF)
             v = jnp.where(jax.lax.broadcasted_iota(
                 jnp.int32, (turn, 1), 0) < left, v, jnp.zeros_like(v))
         m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
@@ -1002,7 +1020,8 @@ def _walk_pages(kvl, counts, *, page_size, pages, rows, width, copies,
         alpha = jnp.exp(m_prev - m_new)
         return (m_new, l_prev * alpha + p.sum(axis=1, keepdims=True),
                 acc * _lanes(alpha, width)
-                + _parts_dot(*_part_rows(p), v, ((1,), (0,))))
+                + (_parts_dot(*_part_rows(p), v, ((1,), (0,)))
+                   if pv is None else pv(p, v)))
 
     @pl.when(n_turns > 0)
     def _first():
@@ -1011,8 +1030,16 @@ def _walk_pages(kvl, counts, *, page_size, pages, rows, width, copies,
     carry = (jnp.full((rows, 128), NEG_INF, jnp.float32),
              jnp.zeros((rows, 128), jnp.float32),
              jnp.zeros((rows, width), jnp.float32))
-    carry = jax.lax.fori_loop(
-        0, n_whole, lambda t, c: update(t, c, False), carry)
+    if first is not None:
+        n_head = jnp.minimum(first[2], n_turns)
+        carry = jax.lax.fori_loop(
+            0, n_head, lambda t, c: update(t, c, True), carry)
+        n_whole = jnp.maximum(n_whole, n_head)
+        carry = jax.lax.fori_loop(
+            n_head, n_whole, lambda t, c: update(t, c, False), carry)
+    else:
+        carry = jax.lax.fori_loop(
+            0, n_whole, lambda t, c: update(t, c, False), carry)
     _, l, acc = jax.lax.fori_loop(
         n_whole, n_turns, lambda t, c: update(t, c, True), carry)
     return acc / _lanes(jnp.maximum(l, 1e-30), width)
@@ -1342,6 +1369,300 @@ def paged_mla_prefill_attention(q, latent_pool, pages, start, valid, *,
         jnp.broadcast_to(pages[None, :], (C // nt, pages.shape[0])), lens,
         v_width, H, nt, sm_scale, interpret, layer)
     return out.reshape(C, H, v_width)
+
+
+# ---------------------------------------------------------------------------
+# GROUPED query heads on the walk, with an optional WINDOW.  ``Hq = g * Hkv``
+# query heads read the stored ``[L, P, ps, Hkv*Dh]`` stacks (query head ``i``
+# reads KV head ``i // g``): the same walk as the plain kernel (one grid step a
+# slot, the slot's own pages copied whole, many to a turn, the next turn's
+# copies in flight), with each KV head's ``g`` query rows scored against that
+# head's ``Dh`` lanes of the tile and ``p . v`` taken over the same lanes, so
+# no product is wasted on another head's lanes.  A grid step may carry
+# ``q_tokens`` consecutive tokens of one sequence (a prefill chunk's rows, as
+# the latent kernel does).  With ``window = W`` a query at position ``t`` sees
+# keys ``t - W + 1 .. t``: the walk starts at the page that holds the oldest
+# visible key and masks that page's rows before it, and the table is read as
+# a RING (logical page ``p`` in column ``p % width``; a table as wide as the
+# sequence is the same thing), because the cache has freed the pages before
+# the window (``serving/kv_cache.py``, "Page groups").  The custom call is
+# named by kind, ``paged_gqa_full_attention`` / ``paged_gqa_window_attention``,
+# so that a device trace tells a model's two kinds of layer apart.
+# ---------------------------------------------------------------------------
+
+_GQA_PREFILL_TOKENS = 8     # chunk rows a grid step of the prefill form
+
+
+def _gqa_row_tokens(n_rows, per_head, group, q_tokens):
+    """Token of its step's ``q_tokens`` that each query row belongs to: rows
+    are KV-head-major, ``per_head`` a head (token-major, ``group`` rows a
+    token, then padding that follows the last token)."""
+    import jax.numpy as jnp
+
+    return jnp.minimum((jnp.arange(n_rows) % per_head) // group, q_tokens - 1)
+
+
+def _paged_gqa_walk_reference(q, k_pool, v_pool, page_tables, kv_lens, n_kv,
+                              q_tokens, window, sm_scale, layer):
+    """``q [S, Hkv * q_tokens * g, Dh]`` (KV-head-major, then token, then
+    group member) against each slot's pages; ``page_tables [S, MP]`` or one
+    row ``[MP]`` for every slot.  Gathers only the pages a window can reach."""
+    import jax.numpy as jnp
+
+    S, R, Dh = q.shape
+    ps = k_pool.shape[2]
+    mp = page_tables.shape[-1]
+    per = R // n_kv
+    g = per // q_tokens
+    tok = _gqa_row_tokens(R, per, g, q_tokens)
+    limit = kv_lens[:, None] - (q_tokens - 1 - tok)[None, :]        # [S, R]
+    if window is None:
+        first = jnp.zeros((S,), jnp.int32)
+        n_walk = mp
+        lo = jnp.zeros_like(limit)
+    else:
+        lo = jnp.maximum(limit - window, 0)
+        first = jnp.maximum(kv_lens - (q_tokens - 1) - window, 0) // ps
+        n_walk = min(mp, (window + q_tokens + ps - 2) // ps + 1)
+    cols = (first[:, None] + jnp.arange(n_walk)[None, :]) % mp      # [S, NW]
+    pages = (page_tables[cols] if page_tables.ndim == 1
+             else jnp.take_along_axis(page_tables, cols, axis=1))
+    pos = (first[:, None] * ps + jnp.arange(n_walk * ps)[None, :])  # [S, K]
+    ok = ((pos[:, None, :] < limit[:, :, None])
+          & (pos[:, None, :] >= lo[:, :, None]))                    # [S, R, K]
+    outs = []
+    for h in range(n_kv):
+        lanes = slice(h * Dh, (h + 1) * Dh)
+        rows = slice(h * per, (h + 1) * per)
+        k = k_pool[layer, pages][..., lanes].reshape(
+            S, n_walk * ps, Dh).astype(jnp.float32)
+        v = v_pool[layer, pages][..., lanes].reshape(
+            S, n_walk * ps, Dh).astype(jnp.float32)
+        s = jnp.einsum("srd,skd->srk", q[:, rows].astype(jnp.float32), k,
+                       precision=jax.lax.Precision.HIGHEST) * sm_scale
+        okh = ok[:, rows]
+        p = jax.nn.softmax(jnp.where(okh, s, NEG_INF), axis=-1)
+        p = jnp.where(okh, p, 0.0)          # a row with no key -> zeros
+        seen = okh.any(axis=1)[:, :, None]  # keys no row reads may be garbage
+        outs.append(jnp.einsum("srk,skd->srd", p, jnp.where(seen, v, 0.0),
+                               precision=jax.lax.Precision.HIGHEST))
+    return jnp.concatenate(outs, axis=1)
+
+
+def _paged_gqa_walk_kernel(pt_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
+                           k_buf, v_buf, sem, *, layer, page_size, pages,
+                           table_width, n_kv, per_head, group, q_tokens,
+                           head_dim, window, sm_scale):
+    """One grid step = one slot's ``n_kv * per_head`` query rows against the
+    slot's live pages, ``pages`` a turn (``_walk_pages``): the pages up to
+    ``kv_len``, from the sequence's first or, with a ``window``, from the one
+    that holds the oldest key any row of the step sees."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    s_idx = pl.program_id(0)
+    ps, turn = page_size, pages * page_size
+    rows = n_kv * per_head
+    kvl = lens_ref[s_idx]
+    div = jax.lax.div
+    r = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    tok = jnp.minimum(div(jax.lax.rem(r, per_head), group), q_tokens - 1)
+    limit = kvl - (q_tokens - 1 - tok)                       # [rows, 1]
+    least = jnp.maximum(kvl - (q_tokens - 1), 0)             # the first token's
+    if window is None:
+        first_page, first = 0, None
+    else:
+        first_page = div(jnp.maximum(least - window, 0), ps)
+        base = first_page * ps
+        lo = jnp.maximum(limit - window, 0)
+        first = (base, lo,
+                 div(jnp.maximum(kvl - window, 0) - base, turn) + 1)
+    n_pages = jnp.maximum(div(kvl + (ps - 1), ps) - first_page, 0)
+    n_turns = div(n_pages + (pages - 1), pages)
+    # turns in which every row sees every key up to the tile's end
+    n_whole = div(jnp.maximum(least - first_page * ps, 0), turn)
+
+    def copies(t, slot, i):
+        col = first_page + t * pages + i
+        if window is not None:
+            col = jax.lax.rem(col, table_width)
+        page = pt_ref[s_idx * table_width + col]
+        at = pl.ds(pl.multiple_of(i * ps, ps), ps)
+        return (pltpu.make_async_copy(k_hbm.at[layer, page],
+                                      k_buf.at[slot, at], sem.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[layer, page],
+                                      v_buf.at[slot, at], sem.at[1, slot]))
+
+    heads = [(slice(h * per_head, (h + 1) * per_head),
+              slice(h * head_dim, (h + 1) * head_dim)) for h in range(n_kv)]
+    q_parts = [_part_rows(q_ref[rs, :]) for rs, _ in heads]
+
+    def scores(k):
+        return jnp.concatenate([
+            _parts_dot(qr, nq, k[:, ls], ((1,), (1,)))
+            for (qr, nq), (_, ls) in zip(q_parts, heads)], axis=0) * sm_scale
+
+    def pv(p, v):
+        return jnp.concatenate([
+            _parts_dot(*_part_rows(p[rs, :]), v[:, ls], ((1,), (0,)))
+            for rs, ls in heads], axis=0)
+
+    o_ref[...] = _walk_pages(
+        kvl, (n_pages, n_turns, n_whole), page_size=ps, pages=pages,
+        rows=rows, width=head_dim, copies=copies,
+        tiles=lambda slot: (k_buf[slot], v_buf[slot]), scores=scores,
+        limit=limit, first=first, pv=pv).astype(o_ref.dtype)
+
+
+def _gqa_turn_pages(ps, lanes, mp, itemsize, rows):
+    """Pages a turn of the grouped walk: ``_DECODE_TURN_KEYS`` keys, halved
+    until the K and V tiles (double-buffered, and a float32 copy of a tile
+    that is not bfloat16), the scores, the probabilities and their parts fit
+    the walk's VMEM budget."""
+    pages = max(1, min(mp, _DECODE_TURN_KEYS // ps))
+    while pages > 1 and (4 * pages * ps * lanes * (itemsize + 2)
+                         + 24 * rows * pages * ps
+                         + 16 * rows * 128) > _DECODE_VMEM_BUDGET:
+        pages = -(-pages // 2)
+    return pages
+
+
+def _paged_gqa_walk_pallas(q, k_pool, v_pool, page_tables, kv_lens, n_kv,
+                           q_tokens, window, sm_scale, interpret, layer):
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from .. import observability as obs
+
+    S, R, Dh = q.shape
+    ps = k_pool.shape[2]
+    lanes = k_pool.shape[3]
+    mp = page_tables.shape[1]
+    per = R // n_kv
+    g = per // q_tokens
+    # a KV head's rows in whole sublane tiles of either dtype
+    per_pad = -(-per // 16) * 16
+    if per_pad != per:
+        q = jnp.pad(q.reshape(S, n_kv, per, Dh),
+                    ((0, 0), (0, 0), (0, per_pad - per), (0, 0))
+                    ).reshape(S, n_kv * per_pad, Dh)
+    rows = n_kv * per_pad
+    pages = _gqa_turn_pages(ps, lanes, mp, k_pool.dtype.itemsize, rows)
+    steps = obs.counter("paged.gqa.grid_steps", labels={
+        "S": S, "mp": mp, "ps": ps, "turn": pages * ps,
+        "window": window or 0})
+    if not steps.value:
+        steps.inc(S)
+    kernel = functools.partial(
+        _paged_gqa_walk_kernel, layer=layer, page_size=ps, pages=pages,
+        table_width=mp, n_kv=n_kv, per_head=per_pad, group=g,
+        q_tokens=q_tokens, head_dim=Dh, window=window, sm_scale=sm_scale)
+    block = pl.BlockSpec((None, rows, Dh), lambda s, pt, kl: (s, 0, 0))
+    stack = pl.BlockSpec(memory_space=pl.ANY)
+    (out,) = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(S,),
+            in_specs=[block, stack, stack],
+            out_specs=[block],
+            scratch_shapes=[
+                pltpu.VMEM((2, pages * ps, lanes), k_pool.dtype),   # k tiles
+                pltpu.VMEM((2, pages * ps, lanes), v_pool.dtype),   # v tiles
+                pltpu.SemaphoreType.DMA((2, 2)),                    # [k|v, tile]
+            ]),
+        out_shape=[jax.ShapeDtypeStruct((S, rows, Dh), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+        name=("paged_gqa_full_attention" if window is None
+              else "paged_gqa_window_attention"),
+    )(page_tables.astype(jnp.int32).reshape(S * mp),
+      kv_lens.astype(jnp.int32), q, k_pool, v_pool)
+    return out.reshape(S, n_kv, per_pad, Dh)[:, :, :per].reshape(S, R, Dh)
+
+
+def _gqa_heads(q, k_pool):
+    """KV heads of the stored stack ``[L, P, ps, Hkv*Dh]`` under ``q [.., Hq,
+    Dh]``."""
+    Hq, Dh = q.shape[-2:]
+    if k_pool.ndim != 4 or k_pool.shape[3] % Dh or Hq % (
+            k_pool.shape[3] // Dh):
+        raise ValueError(
+            "the pool is the stored stack [L, P, ps, Hkv*Dh] with Dh = %d "
+            "and Hkv dividing %d query heads; got %s"
+            % (Dh, Hq, k_pool.shape))
+    return k_pool.shape[3] // Dh
+
+
+def _gqa_walk(q, k_pool, v_pool, page_tables, kv_lens, n_kv, q_tokens,
+              window, sm_scale, impl, interpret, layer):
+    impl, interpret = _mla_impl(impl, interpret)
+    if window is not None and int(window) < 1:
+        raise ValueError("window must be >= 1, got %r" % (window,))
+    if sm_scale is None:
+        sm_scale = 1.0 / float(np.sqrt(q.shape[-1]))
+    if impl == "reference":
+        tables = page_tables[0] if q_tokens > 1 else page_tables
+        return _paged_gqa_walk_reference(
+            q, k_pool, v_pool, tables, kv_lens, n_kv, q_tokens, window,
+            sm_scale, int(layer))
+    return _paged_gqa_walk_pallas(
+        q, k_pool, v_pool, page_tables, kv_lens, n_kv, q_tokens, window,
+        sm_scale, interpret, int(layer))
+
+
+def paged_gqa_decode_attention(q, k_pool, v_pool, page_tables, kv_lens, *,
+                               layer, window=None, sm_scale=None, impl=None,
+                               interpret=None):
+    """Grouped-query decode on the walk: one query token per slot.
+
+    q: ``[S, Hq, Dh]``; k_pool / v_pool: the stored stacks ``[L, num_pages,
+        page_size, Hkv*Dh]`` addressed in place by ``(layer, page)``, ``Hq =
+        g * Hkv`` (query head ``i`` reads KV head ``i // g``).
+    page_tables ``[S, MP]`` / kv_lens ``[S]``: as
+        :func:`paged_decode_attention`; ``kv_lens[s] == 0`` gives exact zeros
+        and reads no page.
+    window: None (keys ``0 .. kv_len - 1``) or ``W``: keys ``kv_len - W ..
+        kv_len - 1``, the table read as a RING — the sequence's logical page
+        ``p`` in column ``p % MP`` — of which only the columns of the pages
+        that hold those keys are read: a column of an older page may name
+        any page, or scratch.
+    Returns ``[S, Hq, Dh]`` float32.
+    """
+    return _gqa_walk(q, k_pool, v_pool, page_tables, kv_lens,
+                     _gqa_heads(q, k_pool), 1, window, sm_scale, impl,
+                     interpret, layer)
+
+
+def paged_gqa_prefill_attention(q, k_pool, v_pool, pages, start, valid, *,
+                                layer, window=None, sm_scale=None, impl=None,
+                                interpret=None):
+    """Grouped-query attention of one prefill chunk on the walk: ``q [C, Hq,
+    Dh]`` at absolute positions ``start ..`` against the sequence's ``pages
+    [MP]`` (the chunk's own rows already scattered in), causal by position
+    and, with ``window = W``, no further back than ``W - 1`` (``pages`` then
+    a ring, as in :func:`paged_gqa_decode_attention`); rows at or past
+    ``valid`` are padding (garbage out).  ``_GQA_PREFILL_TOKENS`` rows of the
+    chunk a grid step.  Returns ``[C, Hq, Dh]`` float32."""
+    import jax.numpy as jnp
+
+    C, Hq, Dh = q.shape
+    n_kv = _gqa_heads(q, k_pool)
+    g = Hq // n_kv
+    nt = math.gcd(C, _GQA_PREFILL_TOKENS)
+    first = jnp.arange(C // nt, dtype=jnp.int32) * nt
+    lens = jnp.where(first < valid, start + first + nt, 0)
+    # rows of a step KV-head-major: [C/nt, nt, Hkv, g, Dh] -> [.., Hkv, nt, g]
+    rows = q.reshape(C // nt, nt, n_kv, g, Dh).transpose(0, 2, 1, 3, 4)
+    out = _gqa_walk(
+        rows.reshape(C // nt, n_kv * nt * g, Dh), k_pool, v_pool,
+        jnp.broadcast_to(pages[None, :], (C // nt, pages.shape[0])), lens,
+        n_kv, nt, window, sm_scale, impl, interpret, layer)
+    return out.reshape(C // nt, n_kv, nt, g, Dh).transpose(
+        0, 2, 1, 3, 4).reshape(C, Hq, Dh)
 
 
 # ---------------------------------------------------------------------------

@@ -146,20 +146,28 @@ _GMM_TILE_M = 512       # rows of the sorted pairs a grid step holds
 _GMM_TILE_N = 512       # output columns a grid step computes
 
 
-def route_topk(x, router_w, router_bias, *, top_k, scale=1.0):
+SCORINGS = ("sigmoid", "softmax")
+
+
+def route_topk(x, router_w, router_bias, *, top_k, scale=1.0,
+               scoring="sigmoid"):
     """The router: ``(experts [T, k] int32, weights [T, k] float32)``.
 
-    Scores ``sigmoid(x W_g)`` in float32 at the highest matmul precision; the
-    ``top_k`` experts of ``score + bias`` are chosen (the selection bias
-    steers the choice only; on a tie the lower expert wins, as
-    ``lax.top_k``), the weights are the chosen experts' SCORES, normalised to
-    sum to one and scaled."""
+    Scores in float32 at the highest matmul precision, in the form the MODEL
+    states (``scoring``): ``sigmoid(x W_g)`` an expert, or ``softmax(x W_g)``
+    over all experts.  The ``top_k`` experts of ``score + bias`` are chosen
+    (the selection bias steers the choice only; on a tie the lower expert
+    wins, as ``lax.top_k``), the weights are the chosen experts' SCORES,
+    normalised to sum to one and scaled."""
     import jax
     import jax.numpy as jnp
 
-    scores = jax.nn.sigmoid(jnp.dot(
-        x.astype(jnp.float32), router_w.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))
+    if scoring not in SCORINGS:
+        raise ValueError("scoring is one of %s, got %r" % (SCORINGS, scoring))
+    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = (jax.nn.softmax(logits, axis=-1) if scoring == "softmax"
+              else jax.nn.sigmoid(logits))
     biased = scores if router_bias is None else scores + router_bias
     _, experts = jax.lax.top_k(biased, top_k)
     weights = jnp.take_along_axis(scores, experts, axis=-1)
@@ -294,13 +302,14 @@ def grouped_matmul(x, w, group_sizes, *, layer=None, impl=None,
 
 
 def moe_topk(x, router, experts, shared, *, top_k, experts_held, scale=1.0,
-             token_mask=None, layer=None, impl=None, interpret=None):
+             token_mask=None, layer=None, impl=None, interpret=None,
+             scoring="sigmoid"):
     """Dropless top-k experts, the part of ``experts_held``: ``(y [T, D]
     float32, counts [3] int32, chosen [T, k] int32)``.
 
     x ``[T, D]``.  router: ``{"w": [D, E], "bias": [E] or None}`` over ALL
-    ``E`` experts (:func:`route_topk`).  experts: ``{"w_gu": [.., H, D, 2F],
-    "w_down": [.., H, F, D]}``, the ``H = hi - lo`` SwiGLU experts ``lo ..
+    ``E`` experts (:func:`route_topk`, whose ``scoring`` the model states).
+    experts: ``{"w_gu": [.., H, D, 2F], "w_down": [.., H, F, D]}``, the ``H = hi - lo`` SwiGLU experts ``lo ..
     hi - 1`` this caller holds (gate | up fused column-wise; a stack of
     layers with ``layer=``).  shared: None, or ``{"w_gu": [D, 2Fs], "w_down":
     [Fs, D]}``, added ONCE by whoever passes it.  ``token_mask [T]`` bool:
@@ -317,7 +326,7 @@ def moe_topk(x, router, experts, shared, *, top_k, experts_held, scale=1.0,
     T, D = x.shape
     F = experts["w_down"].shape[-2]
     chosen, weights = route_topk(x, router["w"], router.get("bias"),
-                                 top_k=top_k, scale=scale)
+                                 top_k=top_k, scale=scale, scoring=scoring)
     held = (chosen >= lo) & (chosen < hi)
     if token_mask is not None:
         held = held & token_mask[:, None]

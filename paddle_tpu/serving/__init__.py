@@ -131,7 +131,7 @@ from .errors import (
     ServingQuotaExceeded,
     ServingTimeout,
 )
-from .kv_cache import PagedKVCache, write_token_kv
+from .kv_cache import PagedKVCache, PageGroup, write_token_kv
 from .model_store import LoadedModel, ModelStore
 from .replica_pool import ReplicaPool
 from .request_queue import PRIORITY_CLASSES, Request, RequestQueue
@@ -166,6 +166,7 @@ __all__ = [
     "SessionRecord",
     "scoped_session",
     "PagedKVCache",
+    "PageGroup",
     "write_token_kv",
     "ServingError",
     "ServingTimeout",

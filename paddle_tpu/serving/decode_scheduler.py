@@ -106,6 +106,7 @@ _prefills = _obs.counter("serving.decode.prefills")
 _steps = _obs.counter("serving.decode.steps")
 _retired = _obs.counter("serving.decode.retired")
 _state_resets = _obs.counter("serving.cache.state_resets")
+_window_released = _obs.counter("serving.cache.window.pages_released")
 # pages a decode step's slots hold against the pages its tables span: the
 # share of the whole-table walk that the slot-bounded one still takes
 _walked_pages = _obs.counter("serving.decode.paged.walked_pages")
@@ -219,17 +220,31 @@ class DecodeModel:
     ``paged_*_attention(..., layer=li)``; it must not slice a layer out
     (``cache["k"][li]`` is a layer-sized copy in every step on the chip).
 
+    ``page_groups``: None, or an ordered ``{group: dict(window=None | W)}``
+    for a model whose layers do not all keep the same positions
+    (``kv_cache.py``, "Page groups"); each ``page_pools`` leaf then names its
+    ``group``.  The first group keeps every position; a group with a
+    ``window`` keeps a sequence's last ``W`` (a query at position ``t`` reads
+    ``t - W + 1 .. t``) and its pages return to the allocator as they fall
+    out of it.  Such a model's step functions receive ``page_tables``,
+    ``chunk_pages`` and ``gather_pages`` as ``{group: array}``; a window
+    group's table is a RING (logical page ``p`` of a sequence in column ``p
+    % width``, released entries at scratch).  ``DecodeConfig.num_pages`` is
+    then ``{group: pages}``.  Without it a model has one group and receives
+    the arrays themselves, as every model did.
+
     All are jitted by the scheduler (the cache donated on TPU); they
     must be shape-stable in everything but values.
     ``models.transformer.build_decode_model``,
-    ``models.minicpm_sala.build_decode_model`` and
-    ``models.deepseek_v3.build_decode_model`` are the in-repo producers.
+    ``models.minicpm_sala.build_decode_model``,
+    ``models.deepseek_v3.build_decode_model`` and
+    ``models.mellum.build_decode_model`` are the in-repo producers.
     """
 
     def __init__(self, decode_fn, prefill_chunk_fn, *, params=None,
                  num_layers=0, num_heads=0, head_dim=0, vocab_size,
                  eos_id=None, name="decode-model", page_pools=None,
-                 slot_state=None, step_counters=()):
+                 slot_state=None, step_counters=(), page_groups=None):
         self.decode_fn = decode_fn
         self.prefill_chunk_fn = prefill_chunk_fn
         self.params = params
@@ -242,6 +257,7 @@ class DecodeModel:
         self.page_pools = dict(page_pools or {})
         self.slot_state = dict(slot_state or {})
         self.step_counters = tuple(step_counters)
+        self.page_groups = dict(page_groups or {})
 
 
 class DecodeConfig:
@@ -252,7 +268,8 @@ class DecodeConfig:
         ``prompt_len + max_new_tokens`` per sequence.
     num_pages: pool size (+1 scratch).  Default reserves full worst-case
         occupancy for every slot — raise/lower to trade HBM for the
-        admission-blocking rate.
+        admission-blocking rate.  ``{group: pages}`` for a model with
+        ``page_groups`` (a group left out gets its worst case).
     prefill_buckets: page-multiple prompt-length ladder; default doubles
         from ``page_size`` up to ``max_seq_len``.
     max_new_tokens: default per-request generation cap (requests may pass
@@ -480,7 +497,7 @@ class _Slot:
     """
 
     __slots__ = ("req", "pages", "prompt_len", "kv_len", "generated",
-                 "prefill_pos", "hashes")
+                 "prefill_pos", "hashes", "more")
 
     def __init__(self, req, pages, prefill_pos=None, hashes=None):
         self.req = req
@@ -493,11 +510,25 @@ class _Slot:
         self.prefill_pos = (req.prompt_len if prefill_pos is None
                             else int(prefill_pos))
         self.hashes = hashes           # prompt chain hashes (prefix cache)
+        self.more = {}                 # {further page group: _HeldPages}
 
     @property
     def prefilling(self):
         """True until the final chunk has produced the first token."""
         return self.prefill_pos < self.prompt_len or not self.generated
+
+
+class _HeldPages:
+    """A slot's pages in one further page group: ``pages`` hold its logical
+    pages ``first ..`` in order (the ones before fell out of the group's
+    window and went back), under a reservation of ``reserved`` pages."""
+
+    __slots__ = ("first", "pages", "reserved")
+
+    def __init__(self, reserved):
+        self.first = 0
+        self.pages = collections.deque()
+        self.reserved = int(reserved)
 
 
 class HandoffPacket:
@@ -568,6 +599,17 @@ class DecodeScheduler:
                 "snapshot per checkpointed boundary is missing; serve it "
                 "with prefix_cache=False, no sessions and role='both'"
                 % (model.name, ", ".join(sorted(model.slot_state))))
+        if len(model.page_groups) > 1 and (
+                cfg.prefix_cache or sessions is not None or role != "both"
+                or cfg.kv_guard):
+            raise ServingError(
+                "%r keeps its pages in groups (%s): prefix_cache, sessions, "
+                "prefill/decode roles and kv_guard map, move or sweep the "
+                "FIRST group's pages, and a window group has freed the "
+                "pages at a hit's boundary. Pinning a window's pages with a "
+                "prefix is missing; serve it with prefix_cache=False, no "
+                "sessions, role='both' and kv_guard=False"
+                % (model.name, ", ".join(model.page_groups)))
         if sessions is not None and not cfg.prefix_cache:
             raise ServingError(
                 "sessions require prefix_cache=True: a session pin is an "
@@ -588,14 +630,27 @@ class DecodeScheduler:
         self._pending_lock = threading.Lock()
         self._pending_release = []
         self._pending_handoffs = collections.deque()
+        worst = cfg.num_slots * -(-cfg.max_seq_len // cfg.page_size) + 1
+        if model.page_groups:
+            sizes = cfg.num_pages if isinstance(cfg.num_pages, dict) else {}
+            unknown = set(sizes) - set(model.page_groups)
+            if unknown or (cfg.num_pages and not sizes):
+                raise ServingError(
+                    "%r keeps its pages in groups %s: num_pages is {group: "
+                    "pages} over them, got %r"
+                    % (model.name, list(model.page_groups), cfg.num_pages))
+            groups = {g: dict(window=spec.get("window"),
+                              num_pages=sizes.get(g, worst))
+                      for g, spec in model.page_groups.items()}
+            num_pages = None
+        else:
+            groups, num_pages = None, cfg.num_pages or worst
         self._cache = PagedKVCache(
-            model.num_layers,
-            cfg.num_pages or (
-                cfg.num_slots * -(-cfg.max_seq_len // cfg.page_size) + 1),
+            model.num_layers, num_pages,
             cfg.page_size, model.num_heads, model.head_dim,
             cfg.max_seq_len, dtype=cfg.kv_dtype,
             page_pools=model.page_pools, slot_state=model.slot_state,
-            num_slots=cfg.num_slots, device=device)
+            num_slots=cfg.num_slots, device=device, page_groups=groups)
         self._step_counters = [
             _obs.counter("serving.decode." + name)
             for name in model.step_counters]
@@ -674,6 +729,15 @@ class DecodeScheduler:
         self._slots = [None] * cfg.num_slots
         self._tables = np.zeros(
             (cfg.num_slots, self._cache.max_pages_per_seq), np.int32)
+        # a table a further page group: the whole sequence's pages, or with
+        # a window a RING as wide as the most a slot holds live at once
+        # (logical page p in column p % width; released entries at scratch)
+        widest = max(self._chunk_widths())
+        self._more_tables = {
+            g: np.zeros((cfg.num_slots,
+                         grp.slot_bound(cfg.max_seq_len, widest)), np.int32)
+            for g, grp in self._cache.groups.items()}
+        self._widest_chunk = widest
         self._hol = None               # head-of-line request awaiting pages
         # seconds this turn of the serve loop spent in ``*.wait`` spans
         # (blocked on the device): what ``iteration.host`` subtracts
@@ -803,19 +867,22 @@ class DecodeScheduler:
                 params, cache.pools,
                 jnp.zeros((cfg.num_slots,), jnp.int32),
                 jnp.zeros((cfg.num_slots,), jnp.int32),
-                jnp.asarray(self._tables),
+                self._by_group(self._tables, self._more_tables),
                 jnp.zeros((cfg.num_slots,), jnp.int32),
                 jnp.zeros((cfg.num_slots,), jnp.uint32),
                 jnp.zeros((cfg.num_slots,), jnp.float32))
             np.asarray(toks)
             for w in self._chunk_widths():
                 fn = self._jit.get(("chunk", w))
+                written = np.zeros((w // cfg.page_size,), np.int32)
                 toks, cache.pools = fn(
                     params, cache.pools,
                     jnp.zeros((w,), jnp.int32), jnp.int32(0),
                     jnp.int32(1),
-                    jnp.zeros((w // cfg.page_size,), jnp.int32),
-                    jnp.zeros((cache.max_pages_per_seq,), jnp.int32),
+                    self._by_group(written,
+                                   {g: written for g in self._more_tables}),
+                    self._by_group(self._tables[0], {
+                        g: t[0] for g, t in self._more_tables.items()}),
                     np.int32(0), jnp.uint32(0), jnp.float32(0))
                 np.asarray(toks)
             if cfg.kv_guard:
@@ -873,6 +940,71 @@ class DecodeScheduler:
         """This scheduler's :class:`PagedKVCache`.  The worker owns it
         while alive; anyone else reads it only after :meth:`stop`."""
         return self._cache
+
+    # -- page groups ---------------------------------------------------------
+    def _by_group(self, first, more):
+        """What a step program receives for a per-group argument: ``first``
+        itself for a model that states no groups (the programs every model
+        had), else ``{group: array}`` with ``first`` under the first
+        group's name."""
+        import jax.numpy as jnp
+
+        if not self.model.page_groups:
+            return jnp.asarray(first)
+        out = {self._cache.primary_group: jnp.asarray(first)}
+        out.update((g, jnp.asarray(a)) for g, a in more.items())
+        return out
+
+    def _group_needs(self, req):
+        """Pages ``req`` reserves in each further group."""
+        return {g: grp.slot_bound(req.prompt_len + req.max_new_tokens,
+                                  self._widest_chunk)
+                for g, grp in self._cache.groups.items()}
+
+    def _ensure_pages(self, idx, slot, end):
+        """Hand the slot the further groups' pages that positions below
+        ``end`` reach (under its reservation: this cannot fail)."""
+        ps = self.config.page_size
+        for g, held in slot.more.items():
+            table = self._more_tables[g]
+            for p in range(held.first + len(held.pages), -(-end // ps)):
+                page = self._cache.groups[g].alloc(1)[0]
+                held.pages.append(page)
+                table[idx, p % table.shape[1]] = page
+
+    def _release_window(self, idx, slot):
+        """Give back each window group's pages on which every position is
+        out of the window of the slot's NEXT position (and of every later
+        one): the table names them no more, and the next ``alloc`` may hand
+        them to another slot."""
+        released = 0
+        for g, held in slot.more.items():
+            grp = self._cache.groups[g]
+            live = grp.first_live_page(slot.kv_len)
+            if live <= held.first or not held.pages:
+                continue
+            with self._telemetry.span("serving.decode.window.release"):
+                table = self._more_tables[g]
+                dead = [held.pages.popleft() for _ in range(
+                    min(live - held.first, len(held.pages)))]
+                for p in range(held.first, held.first + len(dead)):
+                    table[idx, p % table.shape[1]] = 0
+                held.first += len(dead)
+                grp.free(dead, released=True)
+                released += len(dead)
+        if released:
+            _window_released.inc(released)
+
+    def _free_slot_pages(self, idx, slot):
+        """Every page and reservation of a slot that leaves, in every
+        group."""
+        self._tables[idx] = 0
+        self._cache.free(slot.pages)
+        for g, held in slot.more.items():
+            self._more_tables[g][idx] = 0
+            self._cache.groups[g].free(held.pages)
+            self._cache.groups[g].unreserve(held.reserved)
+        slot.more = {}
 
     def run_step(self, key, *args):
         """One dispatch of this scheduler's OWN compiled step program
@@ -1020,6 +1152,14 @@ class DecodeScheduler:
         }
         if self.config.prefix_cache:
             st["prefix"] = self._cache.prefix_stats()
+        if self._cache.groups:
+            # the ``kv_*`` keys above are the first group's; each further
+            # group under its own name
+            st["kv_groups"] = {
+                g: {"pages_free": grp.free_pages, "pages_used": grp.used_pages,
+                    "pages_reserved": grp.reserved,
+                    "occupancy": grp.occupancy()}
+                for g, grp in self._cache.groups.items()}
         return st
 
     def cache_stats(self):
@@ -1387,20 +1527,30 @@ class DecodeScheduler:
                 # request carries its probe result instead of
                 # re-counting hits every exhausted iteration)
                 cached_pages, hashes = cache.lookup_prefix(req.prompt)
-            pages = cache.alloc(need - len(cached_pages))
+            # one admission waits for every group: a further group short of
+            # its reservation parks the head as a short first group does
+            more = self._group_needs(req)
+            short = [g for g, n in more.items()
+                     if not cache.groups[g].can_reserve(n)]
+            pages = (None if short
+                     else cache.alloc(need - len(cached_pages)))
             if pages is None:
                 # pinned hit pages are NOT in free_pages — count them
                 # toward what this reservation can ever assemble
                 if (not self._active_count()
-                        and need > cache.free_pages + len(cached_pages)):
+                        and (need > cache.free_pages + len(cached_pages)
+                             or short)):
                     # nothing will ever free enough: the reservation is
                     # larger than the whole (idle) pool
                     if cached_pages:
                         cache.release_prefix(cached_pages)
                     req.fail(ServingError(
                         "sequence needs %d pages but the pool has %d "
-                        "usable; raise num_pages or shrink the request"
-                        % (need, cache.free_pages)))
+                        "usable%s; raise num_pages or shrink the request"
+                        % (need, cache.free_pages, "".join(
+                            " (and %d of group %r's %d)" % (
+                                more[g], g, cache.groups[g].num_pages - 1)
+                            for g in short))))
                     self._completed += 1
                     continue
                 # pool exhausted: hold the head (FIFO) until a retirement
@@ -1408,9 +1558,9 @@ class DecodeScheduler:
                 self._park_hol(req, cached_pages, hashes)
                 return
             self._place(req, cached_pages + pages,
-                        len(cached_pages) * cfg.page_size, hashes)
+                        len(cached_pages) * cfg.page_size, hashes, more)
 
-    def _place(self, req, pages, cached_tokens, hashes):
+    def _place(self, req, pages, cached_tokens, hashes, more=None):
         """Seat one admitted request in a free slot in the PREFILLING
         state: pages are reserved (``cached_tokens`` of
         them already hold a shared prompt prefix), but no model compute
@@ -1429,6 +1579,9 @@ class DecodeScheduler:
                 tags=req.trace.child().tags(priority=req.priority,
                                             seq=req.seq))
         slot = _Slot(req, pages, prefill_pos=cached_tokens, hashes=hashes)
+        for g, n in (more or {}).items():
+            self._cache.groups[g].reserve(n)
+            slot.more[g] = _HeldPages(n)
         if self._cache.slot_leaf_names:
             # a reused slot's state is void from here on: the sequence's
             # first chunk (start == 0) takes it as zero inside the chunk
@@ -1508,6 +1661,18 @@ class DecodeScheduler:
             for i in range(width // ps):
                 if p0 + i < n_prompt_pages:
                     chunk_vec[i] = slot.pages[p0 + i]
+            # the further groups: the pages this chunk's positions reach are
+            # handed out now; what it writes, by the group's own table
+            self._ensure_pages(idx, slot, start + valid)
+            more_vecs = {}
+            for g, table in self._more_tables.items():
+                vec = np.zeros((width // ps,), np.int32)
+                for i in range(min(width // ps, n_prompt_pages - p0)):
+                    vec[i] = table[idx, (p0 + i) % table.shape[1]]
+                more_vecs[g] = vec
+            written = self._by_group(chunk_vec, more_vecs)
+            gathered = self._by_group(self._tables[idx], {
+                g: t[idx] for g, t in self._more_tables.items()})
             fn = self._jit.get(("chunk", width))
             temp, seed = self._sampling_params(req)
 
@@ -1521,8 +1686,7 @@ class DecodeScheduler:
                 tok, pools = fn(
                     self._params, self._cache.pools,
                     jnp.asarray(tokens), jnp.int32(start),
-                    jnp.int32(valid), jnp.asarray(chunk_vec),
-                    jnp.asarray(self._tables[idx]), np.int32(idx),
+                    jnp.int32(valid), written, gathered, np.int32(idx),
                     seed, temp)
             with tel.span("serving.decode.prefill.wait") as wait:
                 first = int(np.asarray(tok))
@@ -1572,6 +1736,8 @@ class DecodeScheduler:
                 return
             slot.prefill_pos = start + valid
             slot.kv_len = slot.prefill_pos
+            if slot.more:
+                self._release_window(idx, slot)
             _prefills.inc()
             _prefill_tokens.inc(valid)
             if cfg.prefix_cache and slot.hashes:
@@ -1624,8 +1790,7 @@ class DecodeScheduler:
             first=slot.generated[-1])
         req.handoff_origin = self._replica_index
         self._slots[idx] = None
-        self._tables[idx] = 0
-        self._cache.free(slot.pages)
+        self._free_slot_pages(idx, slot)
         _active_slots.set(self._active_count())
         _handoff_packets.inc()
         _handoff_pages.inc(n_pages)
@@ -1719,8 +1884,7 @@ class DecodeScheduler:
             if slot is None:
                 continue
             self._slots[i] = None
-            self._tables[i] = 0
-            self._cache.free(slot.pages)
+            self._free_slot_pages(i, slot)
             if not slot.req.done():
                 harvested.append(slot.req)
         if self._donated:
@@ -1840,12 +2004,20 @@ class DecodeScheduler:
             # SHARED prefix) pages, so its dispatch row must aim at scratch
             # like any other non-decoding slot or the write corrupts
             # position 0 of its (or a prefix neighbor's) cache
-            tables = self._tables
+            tables, more_tables = self._tables, self._more_tables
+            if more_tables:
+                # the page each slot's new token lands on, in every group
+                for i, slot in active:
+                    self._ensure_pages(i, slot, slot.kv_len + 1)
             masked = [i for i, s in enumerate(self._slots)
                       if s is not None and s.prefilling]
             if masked:
                 tables = self._tables.copy()
                 tables[masked] = 0
+                more_tables = {g: t.copy() for g, t in more_tables.items()}
+                for t in more_tables.values():
+                    t[masked] = 0
+            step_tables = self._by_group(tables, more_tables)
             fn = self._jit.get(("decode",))
 
         def attempt():
@@ -1858,7 +2030,7 @@ class DecodeScheduler:
                 out, pools = fn(
                     self._params, self._cache.pools,
                     jnp.asarray(tokens), jnp.asarray(positions),
-                    jnp.asarray(tables), jnp.asarray(kv_lens),
+                    step_tables, jnp.asarray(kv_lens),
                     jnp.asarray(seeds), jnp.asarray(temps))
             with tel.span("serving.decode.step.wait") as wait:
                 sampled = np.asarray(out)
@@ -1916,6 +2088,8 @@ class DecodeScheduler:
                 if i in tripped:
                     continue           # retired typed by the guard
                 slot.kv_len += 1
+                if slot.more:
+                    self._release_window(i, slot)
                 tok = int(sampled[i])
                 slot.generated.append(tok)
                 slot.req.journal.accepted.append(tok)
@@ -1932,13 +2106,12 @@ class DecodeScheduler:
     def _retire(self, idx, error=None):
         slot = self._slots[idx]
         self._slots[idx] = None
-        self._tables[idx] = 0
         if (error is None and self._sessions is not None
                 and getattr(slot.req, "session", None) is not None):
             # pin BEFORE the free below: every history page stays
             # rc >= 1 throughout, so nothing can evict it in between
             self._park_session(slot)
-        self._cache.free(slot.pages)
+        self._free_slot_pages(idx, slot)
         self._completed += 1
         if error is None:
             # only SERVED sequences feed the rate EMA: a fault or

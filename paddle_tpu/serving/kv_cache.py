@@ -15,6 +15,23 @@ them — a sequence's first prefill chunk takes its slot's state as zero inside
 the chunk program, so nothing is zeroed per admission here.  The text below is
 about the K/V leaves; every page-indexed leaf follows the same allocator.
 
+**Page groups.**  A model whose layers do not all keep the same positions
+states ``page_groups``: an ordered ``{name: dict(window=None | W,
+num_pages=N)}``, and for each ``page_pools`` leaf the ``group`` it belongs to.
+The FIRST group is the cache's own allocator (everything below; it keeps every
+position, and ``k`` / ``v`` and leaves that name no group are its).  Every
+further group (:class:`PageGroup`) has its own pool length, scratch page 0,
+free list, refcounts and RESERVATION count, and the scheduler keeps a page
+table a group.  A group with a ``window`` keeps a sequence's last ``W``
+positions: a slot reserves a bound that does not grow with the sequence
+(``slot_bound``), pages are handed out as its positions reach them and go back
+to the group's free list the moment every position on them is out of every
+later query's window, so its table is a RING of the bound's width (logical
+page ``p`` in column ``p % width``).  The prefix cache, sessions and handoff
+address the first group's pages only and are refused for a model with a
+further group (``decode_scheduler.py``).  One group and no window is every
+model that states nothing: the same leaves, table and programs as before.
+
 The memory half of the continuous-batching decode runtime (vLLM /
 PagedAttention, Kwon et al. SOSP'23): instead of one contiguous
 ``[B, max_seq_len, ...]`` cache slab per sequence — whose worst-case
@@ -94,7 +111,7 @@ import numpy as np
 from .. import observability as _obs
 from .errors import ServingError
 
-__all__ = ["PagedKVCache", "write_token_kv"]
+__all__ = ["PagedKVCache", "PageGroup", "write_token_kv"]
 
 _pages_total = _obs.gauge("serving.decode.kv_pages_total")
 _pages_used = _obs.gauge("serving.decode.kv_pages_used")
@@ -123,6 +140,113 @@ def write_token_kv(k_pool, v_pool, k_tok, v_tok, pages, offsets):
             v_pool.at[:, pages, offsets].set(v_tok.reshape(L, S, HD)))
 
 
+class PageGroup:
+    """The allocator of one FURTHER group of page-indexed leaves (module
+    docstring, "Page groups"): ``num_pages`` pages with scratch page 0, a free
+    list, a refcount a page, and a count of pages RESERVED to seated
+    sequences.  A sequence reserves at admission (``reserve``; admission
+    waits while ``can_reserve`` is false), takes pages one at a time as its
+    positions reach them (``alloc``, which cannot fail under a reservation)
+    and, in a group with a ``window``, gives back each page that fell out of
+    it (``free``); retirement frees the rest and ``unreserve``s."""
+
+    def __init__(self, name, num_pages, page_size, window=None):
+        if num_pages < 2:
+            raise ServingError(
+                "page group %r: num_pages must be >= 2 (page 0 is the "
+                "scratch page), got %d" % (name, num_pages))
+        if window is not None and int(window) < 1:
+            raise ServingError("page group %r: window must be >= 1" % name)
+        self.name = name
+        self.num_pages = int(num_pages)
+        self.page_size = int(page_size)
+        self.window = None if window is None else int(window)
+        self._free = collections.deque(range(1, self.num_pages))
+        self._rc = [0] * self.num_pages
+        self._used = 0
+        self.reserved = 0
+        self.released = 0       # pages given back by a window, ever
+
+    @property
+    def free_pages(self):
+        return len(self._free)
+
+    @property
+    def used_pages(self):
+        return self._used
+
+    def occupancy(self):
+        usable = self.num_pages - 1
+        return self._used / usable if usable else 0.0
+
+    def slot_bound(self, tokens, widest_chunk):
+        """Pages a sequence of ``tokens`` positions reserves: every page of
+        them, or with a window the most a slot can hold live at once — the
+        window plus a chunk in flight, unaligned — whatever its length."""
+        if self.window is None:
+            return -(-int(tokens) // self.page_size)
+        return -(-(self.window + int(widest_chunk)) // self.page_size) + 1
+
+    def first_live_page(self, next_pos):
+        """The first logical page that a query at ``next_pos`` or later can
+        still read: every position on the pages before it is more than
+        ``window - 1`` behind ``next_pos``."""
+        if self.window is None:
+            return 0
+        return max(0, int(next_pos) - self.window + 1) // self.page_size
+
+    def can_reserve(self, n):
+        return self.reserved + int(n) <= self.num_pages - 1
+
+    def reserve(self, n):
+        if not self.can_reserve(n):
+            raise ServingError(
+                "page group %r: %d reserved + %d > %d usable pages"
+                % (self.name, self.reserved, n, self.num_pages - 1))
+        self.reserved += int(n)
+
+    def unreserve(self, n):
+        self.reserved -= int(n)
+
+    def alloc(self, n=1):
+        n = int(n)
+        if n > len(self._free):
+            return None
+        pages = [self._free.popleft() for _ in range(n)]
+        for p in pages:
+            self._rc[p] = 1
+        self._used += n
+        return pages
+
+    def free(self, pages, released=False):
+        for p in pages:
+            if p == 0:
+                raise ServingError("page 0 is the scratch page; never owned")
+            if self._rc[p] != 1:
+                raise ServingError("page group %r: double free of page %d"
+                                   % (self.name, p))
+            self._rc[p] = 0
+            self._free.append(p)
+        self._used -= len(pages)
+        if released:
+            self.released += len(pages)
+
+    def stats(self):
+        """The group's allocator snapshot with the same partition sweep as
+        :meth:`PagedKVCache.stats` (a page is referenced or free, never
+        both, never neither)."""
+        free = set(self._free)
+        errors = [(p, self._rc[p], "referenced page also in free list"
+                   if self._rc[p] else "leaked: rc=0 but not in free list")
+                  for p in range(1, self.num_pages)
+                  if (self._rc[p] > 0) == (p in free)]
+        return {"num_pages": self.num_pages, "window": self.window,
+                "used_pages": self._used, "free_pages": len(self._free),
+                "reserved_pages": self.reserved,
+                "released_pages": self.released, "rc_errors": errors,
+                "rc_sum_matches": sum(self._rc) == self._used}
+
+
 class PagedKVCache:
     """Preallocated paged pools + the host-side refcounting allocator.
 
@@ -142,21 +266,38 @@ class PagedKVCache:
         bitwise CPU contract).
     page_pools: further page-indexed leaves, ``{name: dict(layers=,
         tokens_per_row=, width=, dtype=)}`` -> ``[layers, num_pages,
-        page_size // tokens_per_row, width]`` (``dtype`` None = ``dtype``).
+        page_size // tokens_per_row, width]`` (``dtype`` None = ``dtype``);
+        with ``page_groups`` a leaf may name its ``group`` (whose
+        ``num_pages`` its page axis then has).
+    page_groups: None (one group, every position kept: ``num_pages`` is its
+        length), or an ordered ``{name: dict(window=, num_pages=)}`` — the
+        first is this cache's own allocator (``num_pages`` is then read from
+        it), each further one a :class:`PageGroup` in :attr:`groups`.
     slot_state / num_slots: slot-indexed leaves, ``{name: dict(layers=,
         shape=, dtype=)}`` -> ``[layers, num_slots, *shape]``.
     device: commit every leaf there (None: jax's default placement).
 
     ``pools`` is the whole cache as ONE pytree (a dict of arrays by leaf
     name): ONE allocator and ONE page table address all page-indexed
-    leaves, the scheduler threads the dict through every step, donated.
+    leaves of a group (one group unless ``page_groups`` says otherwise), the
+    scheduler threads the dict through every step, donated.
     """
 
     def __init__(self, num_layers, num_pages, page_size, num_heads,
                  head_dim, max_seq_len, dtype="float32", page_pools=None,
-                 slot_state=None, num_slots=0, device=None):
+                 slot_state=None, num_slots=0, device=None, page_groups=None):
         import jax.numpy as jnp
 
+        page_groups = dict(page_groups or {})
+        self.primary_group = next(iter(page_groups), "pages")
+        first = page_groups.pop(self.primary_group, None)
+        if first is not None:
+            if first.get("window") is not None:
+                raise ServingError(
+                    "the first page group (%r) is the cache's own allocator "
+                    "and keeps every position; state the window group after "
+                    "it" % self.primary_group)
+            num_pages = first["num_pages"]
         if num_pages < 2:
             raise ServingError(
                 "num_pages must be >= 2 (page 0 is the reserved scratch "
@@ -176,6 +317,13 @@ class PagedKVCache:
         # every leaf's (shape, dtype) by name; the page-indexed ones have
         # the page axis at 1, the slot-indexed ones the slot axis at 1
         self._page_leaves = {}
+        # the further groups, and the leaves of each (the first group's are
+        # the rest of ``_page_leaves``)
+        self.groups = {
+            name: PageGroup(name, spec["num_pages"], self.page_size,
+                            spec.get("window"))
+            for name, spec in page_groups.items()}
+        self._group_of = {}
         if self.num_layers:
             self._page_leaves = {"k": (self.pool_shape, self.dtype),
                                  "v": (self.pool_shape, self.dtype)}
@@ -189,8 +337,17 @@ class PagedKVCache:
                     "page pool %r keeps one row per %d tokens, which does "
                     "not divide page_size %d"
                     % (name, spec["tokens_per_row"], self.page_size))
+            group = spec.get("group", self.primary_group)
+            if group != self.primary_group and group not in self.groups:
+                raise ServingError(
+                    "page pool %r names group %r; the cache has %s"
+                    % (name, group,
+                       [self.primary_group] + sorted(self.groups)))
+            self._group_of[name] = group
+            pages = (self.num_pages if group == self.primary_group
+                     else self.groups[group].num_pages)
             self._page_leaves[name] = (
-                (int(spec["layers"]), self.num_pages,
+                (int(spec["layers"]), pages,
                  self.page_size // int(spec["tokens_per_row"]),
                  int(spec["width"])),
                 jnp.dtype(spec.get("dtype") or self.dtype))
@@ -209,6 +366,13 @@ class PagedKVCache:
         self.pools = self._zeros()
         _page_bytes.set(self.page_bytes)
         _state_bytes.set(self.state_bytes)
+        # by group where there is more than one: ``kv_*`` are the first's
+        self._group_used = {}
+        for name in (self.group_names if self.groups else ()):
+            _obs.gauge("serving.cache.group_bytes",
+                       labels={"group": name}).set(self.group_bytes(name))
+            self._group_used[name] = _obs.gauge(
+                "serving.cache.group_pages_used", labels={"group": name})
         # page 0 = scratch; everything else starts free
         self._free = collections.deque(range(1, self.num_pages))
         self._used = 0
@@ -252,6 +416,16 @@ class PagedKVCache:
         page pools."""
         return tuple(self._page_leaves)
 
+    def group_leaf_names(self, group):
+        """The page-indexed leaves of ``group``."""
+        return tuple(n for n in self._page_leaves
+                     if self._group_of.get(n, self.primary_group) == group)
+
+    @property
+    def group_names(self):
+        """Every page group's name, the cache's own first."""
+        return (self.primary_group,) + tuple(self.groups)
+
     @property
     def slot_leaf_names(self):
         """Names of the slot-indexed leaves of :attr:`pools` (slot axis 1)."""
@@ -266,6 +440,11 @@ class PagedKVCache:
     def page_bytes(self):
         """Bytes of all page-indexed leaves together."""
         return self._nbytes(self._page_leaves)
+
+    def group_bytes(self, group):
+        """Bytes of ``group``'s leaves."""
+        return self._nbytes({n: self._page_leaves[n]
+                             for n in self.group_leaf_names(group)})
 
     @property
     def state_bytes(self):
@@ -304,15 +483,16 @@ class PagedKVCache:
 
     def gather_pages(self, pools, idx):
         """``{name: leaf[:, idx]}`` over the page-indexed leaves of
-        ``pools``: what a handoff packet or a scrub holds (pure; jitted by
-        the scheduler)."""
-        return {name: pools[name][:, idx] for name in self._page_leaves}
+        ``pools`` (the first group's: ``idx`` are its pages): what a handoff
+        packet or a scrub holds (pure; jitted by the scheduler)."""
+        return {name: pools[name][:, idx]
+                for name in self.group_leaf_names(self.primary_group)}
 
     def scatter_pages(self, pools, pages, idx):
         """``pools`` with ``pages`` (a :meth:`gather_pages` tree) written at
         page ids ``idx``; slot-indexed leaves pass through."""
         out = dict(pools)
-        for name in self._page_leaves:
+        for name in self.group_leaf_names(self.primary_group):
             out[name] = pools[name].at[:, idx].set(pages[name])
         return out
 
@@ -322,7 +502,7 @@ class PagedKVCache:
         import jax.numpy as jnp
 
         ok = None
-        for name in self._page_leaves:
+        for name in self.group_leaf_names(self.primary_group):
             fin = jnp.isfinite(pools[name][:, idx]).all(axis=(0, 2, 3))
             ok = fin if ok is None else ok & fin
         return ok
@@ -380,7 +560,8 @@ class PagedKVCache:
         if not scrub:
             return
         idx = jnp.asarray(scrub, jnp.int32)
-        for name, (shape, dtype) in self._page_leaves.items():
+        for name in self.group_leaf_names(self.primary_group):
+            shape, dtype = self._page_leaves[name]
             zero = jnp.zeros((shape[0], len(scrub)) + shape[2:], dtype)
             self.pools[name] = self.pools[name].at[:, idx].set(zero)
         for p in scrub:
@@ -653,6 +834,14 @@ class PagedKVCache:
                                and n_shared == self._shared),
         }
         st.update(self.prefix_stats())
+        if self.groups:
+            # one entry a group, this allocator's own under its name: a
+            # number over both would mean neither
+            own = {k: st[k] for k in ("num_pages", "used_pages", "free_pages",
+                                      "rc_errors", "rc_sum_matches")}
+            st["groups"] = dict({self.primary_group: dict(own, window=None)},
+                                **{n: g.stats() for n, g in
+                                   self.groups.items()})
         return st
 
     # -- telemetry -----------------------------------------------------------
@@ -668,6 +857,9 @@ class PagedKVCache:
         # ratio negative
         _fragmentation.set(max(0.0, 1.0 - live_tokens / cap) if cap
                            else 0.0)
+        for name, gauge in self._group_used.items():
+            gauge.set(self._used if name == self.primary_group
+                      else self.groups[name].used_pages)
 
     def publish_gauges(self, live_tokens):
         """Refresh occupancy/fragmentation gauges; the scheduler calls this

@@ -605,3 +605,112 @@ def test_weights_are_few_arrays_and_the_step_programs_hold_none(params):
         _cache().pools, jnp.zeros((SLOTS, MAX_LEN // PAGE), jnp.int32),
         jnp.zeros(SLOTS, jnp.int32)).as_text()
     assert len(text) < 2 ** 20                  # no weight is a constant
+
+
+# 8. the second form of the router: softmax over all experts (the Mellum
+# family's; ``chipbench/configs/mellum2_12b_a2_5b.reference.py`` is its
+# plain form) -----------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def softmax_reference():
+    spec = importlib.util.spec_from_file_location(
+        "mellum_reference",
+        os.path.join(ROOT, "chipbench/configs/mellum2_12b_a2_5b.reference.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_the_softmax_router_is_softmax_then_top_k_then_renormalise(
+        softmax_reference):
+    x, router, ex, _ = _expert_case(5, experts=16)
+    picked, weights = moe.route_topk(x, router["w"], None, top_k=8,
+                                     scoring="softmax")
+    p = np.asarray(jax.nn.softmax(jnp.dot(x, router["w"],
+                                          precision="highest"), axis=-1))
+    order = np.argsort(-p, axis=1, kind="stable")[:, :8]
+    np.testing.assert_array_equal(np.sort(picked, axis=1),
+                                  np.sort(order, axis=1))
+    chosen = np.take_along_axis(p, np.asarray(picked), axis=1)
+    np.testing.assert_allclose(
+        weights, chosen / chosen.sum(axis=1, keepdims=True), rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(weights).sum(axis=1), 1.0,
+                               rtol=1e-6)
+    # another function than the sigmoid form over the same logits: the
+    # weights differ though the chosen sets (both monotone in the logit,
+    # no bias) are the same
+    same, other = moe.route_topk(x, router["w"], None, top_k=8)
+    np.testing.assert_array_equal(np.sort(same, axis=1),
+                                  np.sort(picked, axis=1))
+    assert np.abs(np.asarray(other) - np.asarray(weights)).max() > 1e-3
+    mask, w = softmax_reference.route(x, router["w"], 8)
+    np.testing.assert_array_equal(
+        np.sort(picked, axis=1), np.nonzero(np.asarray(mask))[1].reshape(-1, 8))
+    np.testing.assert_allclose(
+        np.take_along_axis(np.asarray(w), np.asarray(picked), axis=1),
+        weights, rtol=1e-6)
+    with pytest.raises(ValueError, match="scoring"):
+        moe.route_topk(x, router["w"], None, top_k=8, scoring="tanh")
+
+
+def test_the_softmax_router_breaks_a_tie_for_the_lower_expert(
+        softmax_reference):
+    x, router, _, _ = _expert_case(6)
+    col = router["w"][:, 3] + 1.0
+    w = router["w"].at[:, 7].set(col).at[:, 11].set(col).at[:, 3].set(col)
+    picked, _ = moe.route_topk(x, w, None, top_k=2, scoring="softmax")
+    mask, _ = softmax_reference.route(x, w, 2)
+    np.testing.assert_array_equal(
+        np.sort(picked, axis=1), np.nonzero(np.asarray(mask))[1].reshape(-1, 2))
+    p = np.asarray(jax.nn.softmax(jnp.dot(x, w, precision="highest"), axis=-1))
+    tied = p[:, 3] >= np.delete(p, [3, 7, 11], axis=1).max(axis=1)
+    assert tied.any()
+    np.testing.assert_array_equal(np.sort(np.asarray(picked)[tied], axis=1),
+                                  np.tile([3, 7], (tied.sum(), 1)))
+
+
+@pytest.mark.parametrize("impl", ["reference", "pallas"])
+def test_shares_of_disjoint_ranges_sum_to_the_softmax_layer(softmax_reference,
+                                                            impl):
+    """The same as for the sigmoid form, with no shared expert: four holders
+    of a quarter of the experts each add up to the whole layer, which is the
+    plain loop over all experts with a mask."""
+    x, router, ex, _ = _expert_case(7)
+    kw = dict(top_k=8, scoring="softmax", impl=impl)
+    want, mask = softmax_reference.moe_layer(x, router["w"], ex["w_gu"],
+                                             ex["w_down"], 8)
+    whole, counts, ids = moe.moe_topk(x, {"w": router["w"], "bias": None}, ex,
+                                      None, experts_held=(0, 16), **kw)
+    np.testing.assert_allclose(whole, want, rtol=2e-5, atol=2e-5)
+    np.testing.assert_array_equal(
+        np.sort(ids, axis=1), np.nonzero(np.asarray(mask))[1].reshape(-1, 8))
+    total, pairs, touched = 0.0, 0, 0
+    for lo, hi in ((0, 4), (4, 8), (8, 12), (12, 16)):
+        part, c, held_ids = moe.moe_topk(
+            x, {"w": router["w"], "bias": None},
+            {n: a[lo:hi] for n, a in ex.items()}, None,
+            experts_held=(lo, hi), **kw)
+        np.testing.assert_array_equal(held_ids, ids)
+        held, _ = softmax_reference.moe_layer(
+            x, router["w"], ex["w_gu"][lo:hi], ex["w_down"][lo:hi], 8,
+            (lo, hi))
+        np.testing.assert_allclose(part, held, rtol=2e-5, atol=2e-5)
+        total, pairs, touched = total + part, pairs + int(c[0]), touched + int(c[1])
+    np.testing.assert_allclose(total, whole, rtol=2e-5, atol=2e-5)
+    assert pairs == int(counts[0]) == x.shape[0] * 8
+    assert touched == int(counts[1])
+
+
+def test_the_sigmoid_routers_program_is_the_one_it_was():
+    """``scoring`` is a Python-level choice: the sigmoid form traces to the
+    same jaxpr whether or not it is named."""
+    x, router, _, _ = _expert_case(8)
+    named = jax.make_jaxpr(lambda x, w, b: moe.route_topk(
+        x, w, b, top_k=3, scale=2.448, scoring="sigmoid"))(
+            x, router["w"], router["bias"])
+    plain = jax.make_jaxpr(lambda x, w, b: moe.route_topk(
+        x, w, b, top_k=3, scale=2.448))(x, router["w"], router["bias"])
+    assert str(named) == str(plain)
+    assert "logistic" in str(plain) and "logistic" not in str(jax.make_jaxpr(
+        lambda x, w: moe.route_topk(x, w, None, top_k=3, scoring="softmax"))(
+            x, router["w"]))

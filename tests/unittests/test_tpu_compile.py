@@ -265,6 +265,61 @@ def test_latent_attention_compiles_for_v5e(one_chip, no_persistent_cache,
     assert calls == 1
 
 
+def _grouped_shapes():
+    """The sizes of the configuration whose attention is the grouped walk in
+    two page groups, from its own file."""
+    import json
+    import os
+
+    from paddle_tpu.models import mellum as M
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root,
+                           "chipbench/configs/mellum2_12b_a2_5b.json")) as f:
+        cfg = json.load(f)
+    return cfg, M._dims(cfg)
+
+
+@pytest.mark.parametrize("kind", ["full", "window"])
+@pytest.mark.parametrize("form", ["decode", "prefill128", "prefill512"])
+def test_grouped_walk_compiles_for_v5e(one_chip, no_persistent_cache, form,
+                                       kind):
+    """The grouped-query walk at the cell's slots, heads, page and lanes on
+    the stored stacks (a layer past the first), full layers over the whole
+    page table and sliding layers over the ring of a slot's bound, in both
+    forms; the custom call carries the kind's name."""
+    cfg, d = _grouped_shapes()
+    n = d["kinds"].count(kind + "_attention" if kind == "full"
+                         else "sliding_attention")
+    window = None if kind == "full" else d["W"]
+    mp = (cfg["max_seq_len"] // cfg["page"] if kind == "full"
+          else -(-(d["W"] + cfg["chunk"]) // cfg["page"]) + 1)
+
+    def sds(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((n, cfg["num_pages"][kind], cfg["page"], d["Hkv"] * d["Dh"]),
+               jnp.bfloat16)
+    kw = dict(layer=n - 1, window=window, sm_scale=d["sm_scale"],
+              impl="pallas", interpret=False)
+    if form == "decode":
+        fn, args = (
+            lambda q, k, v, t, n_: FA.paged_gqa_decode_attention(
+                q, k, v, t, n_, **kw),
+            (sds((cfg["slots"], d["H"], d["Dh"]), jnp.bfloat16), pool, pool,
+             sds((cfg["slots"], mp)), sds((cfg["slots"],))))
+    else:
+        fn, args = (
+            lambda q, k, v, pages, start, valid:
+            FA.paged_gqa_prefill_attention(q, k, v, pages, start, valid, **kw),
+            (sds((int(form[7:]), d["H"], d["Dh"]), jnp.bfloat16), pool, pool,
+             sds((mp,)), sds(()), sds(())))
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "paged_gqa_%s_attention" % kind in text
+
+
 @pytest.mark.parametrize("rows", ["decode", "chunk"])
 def test_grouped_matmul_compiles_for_v5e(one_chip, no_persistent_cache, rows):
     """Both products of an expert layer (gate-and-up, down) on the stacked
