@@ -50,6 +50,29 @@ path's bucket ladder):
   bit-identical tokens to serving the same request alone
   (``max_active=1``), which is what tools/check_decode.py gates.
 
+**One decode step in flight** (ISSUE 36): the loop dispatches step n+1
+before it reads step n.  The sampled tokens stay on the device — the decode
+program takes the previous step's output beside the host's ``tokens`` and a
+per-slot mask, and feeds ``where(mask, previous, tokens)`` — while
+positions, ``kv_lens``, seeds and page tables depend on LENGTHS alone,
+which the host knows ahead.  So the host's build, dispatch and commit run
+under the device's step instead of between two of them.  Decisions that
+need token VALUES run one step late and change no served token: a slot
+that hit EOS in step n rode step n+1, and that token is dropped at commit
+(``serving.decode.tokens_discarded``).  A commit matches a result to the
+``_Slot`` OBJECT captured at its plan, never to the slot index.  Page reuse
+stays safe by the device's program order: a page freed on the host is only
+rewritten by a program dispatched later; a prefill chunk or a hand-off's
+scatter simply goes out behind the step in flight.  Where a step must be
+read before the next may go it is — ``kv_guard`` (the sweep must see the
+page), a lost readback (both unread steps are dropped, the cache is put back
+and they are planned again from the journal), a fatal fault, ``stop()`` —
+and that is the same code with nothing left in flight.  An iteration that
+finds nothing in flight plans and sends TWO steps before it reads the first
+(the pipeline is full from its first iteration), one that finds no slot to
+decode reads what is in flight and sends nothing: every iteration with a
+decode step opens one ``serving.decode.step`` span, and there is one a step.
+
 Admission reuses the serving contracts: bounded queue with typed
 ``ServingQueueFull`` backpressure, per-request deadlines shed with
 ``ServingTimeout`` (in queue AND mid-decode), ``ServingClosed`` after
@@ -104,6 +127,11 @@ _requests = _obs.counter("serving.decode.requests")
 _tokens = _obs.counter("serving.decode.tokens")
 _prefills = _obs.counter("serving.decode.prefills")
 _steps = _obs.counter("serving.decode.steps")
+# steps dispatched while the one before was still unread (over ``steps``: the
+# share of steps the one-step pipeline engaged), and tokens computed for a
+# slot that EOS, a cancel or a deadline had already ended (never served)
+_steps_overlapped = _obs.counter("serving.decode.steps_overlapped")
+_tokens_discarded = _obs.counter("serving.decode.tokens_discarded")
 _retired = _obs.counter("serving.decode.retired")
 _state_resets = _obs.counter("serving.cache.state_resets")
 _window_released = _obs.counter("serving.cache.window.pages_released")
@@ -497,7 +525,7 @@ class _Slot:
     """
 
     __slots__ = ("req", "pages", "prompt_len", "kv_len", "generated",
-                 "prefill_pos", "hashes", "more")
+                 "prefill_pos", "hashes", "more", "inflight")
 
     def __init__(self, req, pages, prefill_pos=None, hashes=None):
         self.req = req
@@ -511,11 +539,34 @@ class _Slot:
                             else int(prefill_pos))
         self.hashes = hashes           # prompt chain hashes (prefix cache)
         self.more = {}                 # {further page group: _HeldPages}
+        # decode steps dispatched for this slot and not yet committed: the
+        # DISPATCHED length is ``kv_len + inflight``
+        self.inflight = 0
 
     @property
     def prefilling(self):
         """True until the final chunk has produced the first token."""
         return self.prefill_pos < self.prompt_len or not self.generated
+
+
+class _Step:
+    """One decode step from its plan to its commit.  ``entries`` are the
+    ``(index, _Slot)`` pairs that decode in it — the OBJECTS, because the
+    index may be reseated before the commit; ``args`` the program's
+    arguments but for ``previous`` (gone once dispatched); ``out`` the
+    dispatched step's output, still on the device (tokens, then the model's
+    step counters); ``pools_before`` the cache pytree it took — what a retry
+    rolls back to when the readback is lost, None under donation (consumed)
+    and once another program has written the cache behind it."""
+
+    __slots__ = ("entries", "args", "out", "pools_before", "sampled")
+
+    def __init__(self, entries, args):
+        self.entries = entries
+        self.args = args
+        self.out = None
+        self.pools_before = None
+        self.sampled = None            # ``out`` read back, until committed
 
 
 class _HeldPages:
@@ -562,7 +613,10 @@ class DecodeScheduler:
 
     One worker thread owns the loop (admit -> decode step -> retire);
     clients only touch the bounded queue and their request futures —
-    the same single-dispatcher discipline as the predict batcher.
+    the same single-dispatcher discipline as the predict batcher.  The
+    loop keeps ONE decode step in flight: it dispatches step n+1, then
+    reads and commits step n (the module docstring says what that moves
+    and what it cannot).
 
     Pool mode (ReplicaPool): ``queue=`` injects the SHARED admission
     queue (the scheduler then never closes or drains it — the pool
@@ -654,6 +708,16 @@ class DecodeScheduler:
         self._step_counters = [
             _obs.counter("serving.decode." + name)
             for name in model.step_counters]
+        # decode steps dispatched and not yet read, oldest first: one
+        # between iterations, two for a moment inside one (step n+1 goes
+        # out, then step n is read); and what the decode program takes in
+        # its ``previous`` / ``from_previous`` places when nothing is in
+        # flight: every slot feeds the host's token
+        self._unread = collections.deque()
+        self._planned = []             # planned and not yet sent (None: replan)
+        self._no_previous = (
+            np.zeros((cfg.num_slots + len(model.step_counters),), np.int32),
+            np.zeros((cfg.num_slots,), np.bool_))
         # this scheduler's copy of the weights, on the device once: every
         # step takes it as an argument.  ``device`` (a pool's replica)
         # COMMITS weights and cache there, which is what keeps the worker
@@ -797,8 +861,16 @@ class DecodeScheduler:
             return jax.jit(cache.scatter_pages,
                            donate_argnums=(0,) if donate else ())
         if key[0] == "decode":
+            num_slots = self.config.num_slots
+
             def decode(params, pools, tokens, positions, tables, kv_lens,
-                       seeds, temps):
+                       seeds, temps, previous, from_previous):
+                # a slot that decoded in the step before takes its token
+                # from that step's output, still on the device; one whose
+                # token the host holds (a prefill's first token, a hand-off,
+                # a step already read) takes ``tokens``
+                tokens = jnp.where(from_previous, previous[:num_slots],
+                                   tokens)
                 logits, pools, *counts = model.decode_fn(
                     params, tokens, positions, pools, tables, kv_lens)
 
@@ -863,14 +935,20 @@ class DecodeScheduler:
         cache, params = self._cache, self._params
         with _obs.span("serving.decode.warmup", slots=cfg.num_slots):
             step = self._jit.get(("decode",))
-            toks, cache.pools = step(
-                params, cache.pools,
-                jnp.zeros((cfg.num_slots,), jnp.int32),
-                jnp.zeros((cfg.num_slots,), jnp.int32),
-                self._by_group(self._tables, self._more_tables),
-                jnp.zeros((cfg.num_slots,), jnp.int32),
-                jnp.zeros((cfg.num_slots,), jnp.uint32),
-                jnp.zeros((cfg.num_slots,), jnp.float32))
+            # twice: ``previous`` is a host array when nothing is in flight
+            # and the step before's own output when one is, and jax keys an
+            # executable on where an argument lives as well as on its shape
+            toks, nobody = self._no_previous
+            for _ in range(2):
+                toks, cache.pools = step(
+                    params, cache.pools,
+                    jnp.zeros((cfg.num_slots,), jnp.int32),
+                    jnp.zeros((cfg.num_slots,), jnp.int32),
+                    self._by_group(self._tables, self._more_tables),
+                    jnp.zeros((cfg.num_slots,), jnp.int32),
+                    jnp.zeros((cfg.num_slots,), jnp.uint32),
+                    jnp.zeros((cfg.num_slots,), jnp.float32),
+                    toks, nobody)
             np.asarray(toks)
             for w in self._chunk_widths():
                 fn = self._jit.get(("chunk", w))
@@ -1011,13 +1089,17 @@ class DecodeScheduler:
         ``key`` (``("decode",)`` or ``("chunk", width)``, as warmed up and
         served) on its own weights and cache: ``program(params,
         cache.pools, *args)`` with ``args`` as :meth:`warmup` gives them,
-        the cache updated in place as the loop does.  Returns the
-        program's first output (the tokens).  For a check or a tool that
-        must read what the SERVED executables leave in the SERVED cache;
-        refused while the worker, which owns the cache, is alive."""
+        the cache updated in place as the loop does; a decode step given
+        without its last two arguments feeds every slot ``tokens`` (no step
+        in flight before it).  Returns the program's first output (the
+        tokens).  For a check or a tool that must read what the SERVED
+        executables leave in the SERVED cache; refused while the worker,
+        which owns the cache, is alive."""
         if self.alive:
             raise ServingError(
                 "run_step: the worker thread owns the cache; stop() first")
+        if tuple(key) == ("decode",) and len(args) == 6:
+            args += self._no_previous
         out, self._cache.pools = self._jit.get(tuple(key))(
             self._params, self._cache.pools, *args)
         return out
@@ -1288,6 +1370,8 @@ class DecodeScheduler:
         for i, slot in enumerate(self._slots):
             if slot is not None:
                 self._retire(i, error=exc)
+        # whatever was in flight was computed for slots that are gone
+        self._unread.clear()
 
     def _serve_loop(self):
         # (BaseException escaping this loop is the death path: the
@@ -1300,11 +1384,14 @@ class DecodeScheduler:
         self._note_retired = self._retired_total
         tel = self._telemetry
         while True:
-            if self._active_count() or self._has_admissible():
+            if (self._active_count() or self._unread
+                    or self._has_admissible()):
                 # one turn: admit, then one iteration over the active
-                # slots.  Its phases are children of this span on the
-                # worker's line of a profiler trace; a turn that could
-                # seat nothing is no iteration and closes into no cell
+                # slots (or over the step still in flight for slots that
+                # have all left: it is read and dropped).  Its phases are
+                # children of this span on the worker's line of a profiler
+                # trace; a turn that could seat nothing is no iteration and
+                # closes into no cell
                 self._turn_wait_s = 0.0
                 with tel.span("serving.decode.iteration") as turn:
                     with tel.span("serving.decode.admit") as admit:
@@ -1312,7 +1399,8 @@ class DecodeScheduler:
                         # may be exactly what this admission needs
                         self._drain_pending()
                         self._admit()
-                        iterated = self._active_count() > 0
+                        iterated = (self._active_count() > 0
+                                    or bool(self._unread))
                         if not iterated:
                             turn.name = admit.name = None
                     if iterated:
@@ -1320,7 +1408,10 @@ class DecodeScheduler:
                             # non-drain stop: fail the actives after the
                             # in-flight iteration instead of decoding
                             # every sequence to completion (unbounded
-                            # shutdown)
+                            # shutdown); the step in flight is read and
+                            # committed first, so the cache and the
+                            # journals agree
+                            self._settle()
                             self._fail_all(
                                 ServingClosed("decode scheduler stopped"))
                             return
@@ -1457,11 +1548,11 @@ class DecodeScheduler:
         idxvec[:packet.n_pages] = pages[:packet.n_pages]
         fn = self._jit.get(("hscatter",))
         with _handoff_stage_timer.time():
-            self._cache.pools = fn(
+            self._wrote_cache(fn(
                 self._cache.pools,
                 {name: jnp.asarray(a)
                  for name, a in packet.pages_host.items()},
-                jnp.asarray(idxvec))
+                jnp.asarray(idxvec)))
         slot = _Slot(req, pages, hashes=packet.hashes)
         slot.kv_len = packet.kv_len
         slot.generated.append(packet.first)
@@ -1633,16 +1724,21 @@ class DecodeScheduler:
         remaining = slot.prompt_len - slot.prefill_pos
         return -(-remaining // self._chunk_width_for(remaining))
 
-    def _chunk_step(self, idx):
-        """Run ONE prefill chunk for the slot at ``idx``: scatter the
-        next page-multiple token window's k/v, attend over everything
-        cached so far, and — on the final chunk — sample the first
-        token (flipping the slot to decoding)."""
+    def _chunk_step(self):
+        """Run ONE prefill chunk, for the prefilling slot with the fewest
+        chunks left (admission order on ties): scatter the next
+        page-multiple token window's k/v, attend over everything cached so
+        far, and — on the final chunk — sample the first token (flipping
+        the slot to decoding)."""
         import jax.numpy as jnp
 
         cfg = self.config
         tel = self._telemetry
         with tel.span("serving.decode.chunk.build"):
+            idx = min((i for i, s in enumerate(self._slots)
+                       if s is not None and s.prefilling),
+                      key=lambda i: (self._chunks_left(self._slots[i]),
+                                     self._slots[i].req.seq))
             slot = self._slots[idx]
             req = slot.req
             start = slot.prefill_pos
@@ -1728,7 +1824,7 @@ class DecodeScheduler:
                     tags=req.trace.child().tags(
                         phase="prefill", bucket=width, rows=valid,
                         start=start))
-            self._cache.pools = pools
+            self._wrote_cache(pools)
             if self._breaker is not None:
                 self._breaker.record_success()
             if self.config.kv_guard and self._guard_pages(
@@ -1887,6 +1983,9 @@ class DecodeScheduler:
             self._free_slot_pages(i, slot)
             if not slot.req.done():
                 harvested.append(slot.req)
+        # a token still in flight was never journalled: the sibling that
+        # replays the journal computes it again
+        self._unread.clear()
         if self._donated:
             self._cache.reset_pools(force=True)
         _active_slots.set(0)
@@ -1907,7 +2006,8 @@ class DecodeScheduler:
     def idle(self):
         """No active sequence and no parked head-of-line request (the
         pool's decode-drain probe)."""
-        return self._active_count() == 0 and self._hol is None
+        return (self._active_count() == 0 and self._hol is None
+                and not self._unread)
 
     def _finish_if_done(self, idx):
         slot = self._slots[idx]
@@ -1919,9 +2019,6 @@ class DecodeScheduler:
         return False
 
     def _iterate(self):
-        import jax.numpy as jnp
-
-        cfg = self.config
         tel = self._telemetry
         with tel.span("serving.decode.sweep"):
             # shed actives whose deadline passed before burning a step on
@@ -1972,119 +2069,274 @@ class DecodeScheduler:
         # longer one (bounded by the seat cap: each shorter request
         # holds a slot and runs exactly one winning chunk per iteration);
         # admission stays FIFO-per-priority-lane either way.
-        prefilling = [i for i, s in enumerate(self._slots)
-                      if s is not None and s.prefilling]
-        if prefilling:
-            self._chunk_step(min(
-                prefilling,
-                key=lambda i: (self._chunks_left(self._slots[i]),
-                               self._slots[i].req.seq)))
-        active = [(i, s) for i, s in enumerate(self._slots)
-                  if s is not None and not s.prefilling]
-        if not active:
-            self._cache.publish_gauges(
-                sum(s.kv_len for s in self._slots if s is not None))
-            return
-        with tel.span("serving.decode.step.build"):
+        if any(s is not None and s.prefilling for s in self._slots):
+            self._chunk_step()
+        self._decode_step()
+
+    def _wrote_cache(self, pools):
+        """Another program than a decode step (a prefill chunk, a hand-off's
+        scatter) wrote the cache: a decode step still unread can no longer
+        be rolled back past it."""
+        self._cache.pools = pools
+        for sent in self._unread:
+            sent.pools_before = None
+
+    def _plan_step(self):
+        """The next decode step's slots and arguments, from LENGTHS alone
+        (None when no slot decodes).  A slot with a step in flight stands at
+        its dispatched length ``kv_len + inflight`` and takes its token from
+        that step's output on the device; a slot whose committed and
+        in-flight tokens reach ``max_new_tokens`` is not in the step.  The
+        planned step counts as in flight for its slots from here on."""
+        import jax.numpy as jnp
+
+        cfg = self.config
+        with self._telemetry.span("serving.decode.step.build") as build:
+            entries = [(i, s) for i, s in enumerate(self._slots)
+                       if s is not None and not s.prefilling
+                       and (len(s.generated) + s.inflight
+                            < s.req.max_new_tokens)]
+            if not entries:
+                build.name = None      # no step: the span closes into no cell
+                return None
             tokens = np.zeros((cfg.num_slots,), np.int32)
             positions = np.zeros((cfg.num_slots,), np.int32)
             kv_lens = np.zeros((cfg.num_slots,), np.int32)
             seeds = np.zeros((cfg.num_slots,), np.uint32)
             temps = np.zeros((cfg.num_slots,), np.float32)
-            for i, slot in active:
-                tokens[i] = slot.generated[-1]   # feed the last sampled token
-                positions[i] = slot.kv_len       # ... at the next cache index
-                kv_lens[i] = slot.kv_len + 1     # visible kv incl. this token
+            from_previous = np.zeros((cfg.num_slots,), np.bool_)
+            for i, slot in entries:
+                at = slot.kv_len + slot.inflight
+                if slot.inflight:
+                    from_previous[i] = True      # its token is on the device
+                else:
+                    tokens[i] = slot.generated[-1]   # the last sampled token
+                positions[i] = at                # ... at the next cache index
+                kv_lens[i] = at + 1              # visible kv incl. this token
                 temps[i], seeds[i] = self._sampling_params(slot.req)
+                # the page the new token lands on, in every further group
+                self._ensure_pages(i, slot, at + 1)
+                slot.inflight += 1
             _walked_pages.inc(int(np.sum(-(-kv_lens // cfg.page_size))))
             _table_pages.inc(self._tables.size)
-            # the decode step scatters EVERY slot's token k/v at
-            # page_tables[s, 0] offset 0 when positions[s] == 0 — a
-            # PREFILLING slot's table already points at real (possibly
-            # SHARED prefix) pages, so its dispatch row must aim at scratch
-            # like any other non-decoding slot or the write corrupts
-            # position 0 of its (or a prefix neighbor's) cache
-            tables, more_tables = self._tables, self._more_tables
-            if more_tables:
-                # the page each slot's new token lands on, in every group
-                for i, slot in active:
-                    self._ensure_pages(i, slot, slot.kv_len + 1)
-            masked = [i for i, s in enumerate(self._slots)
-                      if s is not None and s.prefilling]
-            if masked:
-                tables = self._tables.copy()
-                tables[masked] = 0
-                more_tables = {g: t.copy() for g, t in more_tables.items()}
-                for t in more_tables.values():
-                    t[masked] = 0
-            step_tables = self._by_group(tables, more_tables)
-            fn = self._jit.get(("decode",))
+            # COPIES: the program may run behind the host (on the CPU it
+            # reads a numpy argument in place), and the tables are rewritten
+            # while it is in flight.  The decode step scatters EVERY slot's
+            # token k/v at page_tables[s, 0] offset 0 when positions[s] == 0
+            # — a seated slot that does not decode in this step (prefilling,
+            # or at its length with its last token in flight) points at real
+            # (possibly SHARED prefix) pages, so its dispatch row must aim
+            # at scratch like an empty slot's or the write corrupts position
+            # 0 of its (or a prefix neighbor's) cache
+            idle = [i for i, s in enumerate(self._slots)
+                    if s is not None and not kv_lens[i]]
+            tables = self._tables.copy()
+            tables[idle] = 0
+            more_tables = {g: t.copy() for g, t in self._more_tables.items()}
+            for t in more_tables.values():
+                t[idle] = 0
+            return _Step(entries, (
+                jnp.asarray(tokens), jnp.asarray(positions),
+                self._by_group(tables, more_tables), jnp.asarray(kv_lens),
+                jnp.asarray(seeds), jnp.asarray(temps), from_previous))
 
-        def attempt():
+    def _plan_steps(self):
+        """What this iteration dispatches: the next step, and with nothing
+        in flight (and no ``kv_guard``) the one after it as well, so that
+        from the first iteration on a step runs while the one before it is
+        read."""
+        plans = []
+        while len(plans) + len(self._unread) < (1 if self.config.kv_guard
+                                                else 2):
+            plan = self._plan_step()
+            if plan is None:
+                break
+            plans.append(plan)
+        return plans
+
+    def _dispatch_step(self, plan):
+        """Send one planned step behind whatever is in flight."""
+        with self._telemetry.span("serving.decode.step.dispatch"):
+            *args, from_previous = plan.args
+            previous = (self._unread[-1].out if self._unread
+                        else self._no_previous[0])
+            before = self._cache.pools
+            plan.out, pools = self._jit.get(("decode",))(
+                self._params, before, *args, previous, from_previous)
+            # the donated pytree always belongs to the newest dispatch
+            self._cache.pools = pools
+            plan.args = None
+            plan.pools_before = None if self._donated else before
+            if self._unread:
+                _steps_overlapped.inc()
+            self._unread.append(plan)
+
+    def _decode_step(self, dispatch=True):
+        """Dispatch the next decode step, THEN read and commit the one in
+        flight before it: the host's work for step n+1 runs while the device
+        runs step n.  With ``kv_guard`` the step just dispatched is the one
+        read (the sweep must see the page before another write lands): the
+        same code with nothing left in flight.  When no slot decodes next
+        (or ``dispatch`` is False), what is in flight is read with nothing
+        behind it."""
+        planned = self._planned = self._plan_steps() if dispatch else []
+        if not planned and not self._unread:
+            self._cache.publish_gauges(
+                sum(s.kv_len for s in self._slots if s is not None))
+            return
+        try:
+            # the dispatch of step n+1 to the readback of step n (retries
+            # included): the per-iteration step time, and the cell
+            # ``decode_step_ms`` reads
+            with self._telemetry.span(
+                    "serving.decode.step",
+                    active=len((planned or self._unread)[0].entries)):
+                done = _resilience.call_with_retry(
+                    self._send_then_read, policy=self._decode_policy,
+                    on_retry=self._note_step_retry)
+        except Exception as exc:  # noqa: BLE001 — worker must survive
+            # fatal (or transient past the retry budget): fail the
+            # decoding sequences typed, un-retried — replay can't fix a
+            # deterministic fault
+            self._fail_decoding(exc)
+            return
+        except BaseException:
+            # the worker is being killed: what was planned and never sent
+            # must not count as in flight when the loop is resumed
+            self._abandon(*(self._planned or ()))
+            self._planned = []
+            raise
+        if done is not None:
+            self._commit_step(done)
+
+    def _send_then_read(self):
+        """One attempt of the iteration's decode phase: send what is
+        planned, then read the oldest unread step.  Returns that step for
+        the commit (None with nothing to read)."""
+        if self._planned is None:
+            # the readback of the attempt before was lost: every unread
+            # step was dropped, and they are planned again from the
+            # journal's tokens
+            self._planned = self._plan_steps()
+        planned = self._planned
+        if planned:
             # the chaos choke point is consulted per ATTEMPT (a retry
             # is a fresh dispatch, exactly like the prefill legs')
             serve_fault = _resilience._serve_fault
             if serve_fault is not None:
-                serve_fault([s.req for _, s in active])
-            with tel.span("serving.decode.step.dispatch"):
-                out, pools = fn(
-                    self._params, self._cache.pools,
-                    jnp.asarray(tokens), jnp.asarray(positions),
-                    step_tables, jnp.asarray(kv_lens),
-                    jnp.asarray(seeds), jnp.asarray(temps))
-            with tel.span("serving.decode.step.wait") as wait:
-                sampled = np.asarray(out)
-            self._turn_wait_s += wait.duration
-            return sampled, pools
-
-        def note_retry(exc, attempt_n, delay):
-            _step_retries.inc()
-            if tel.recording:
-                tel.emit({
-                    "type": "serving_retry", "ts": time.time(),
-                    "source": "serving", "leg": "decode_step",
-                    "error": repr(exc)[:200], "attempt": attempt_n,
-                    "delay_s": delay, "active": len(active),
-                })
-
+                serve_fault([s.req for _, s in planned[0].entries])
+        while planned:
+            self._dispatch_step(planned[0])
+            del planned[0]
+        if not self._unread:
+            return None
+        # two are unread now (or one, where nobody decodes behind it):
+        # the older is read while the newer runs
+        oldest = self._unread[0]
         try:
-            # the decode program, dispatch to readback (retries
-            # included): the per-iteration step time, and the cell
-            # ``decode_step_ms`` reads
-            with tel.span("serving.decode.step", active=len(active)):
-                sampled, pools = _resilience.call_with_retry(
-                    attempt, policy=self._decode_policy,
-                    on_retry=note_retry)
-        except Exception as exc:  # noqa: BLE001 — worker must survive
-            # fatal (or transient past the retry budget): fail the
-            # actives typed, un-retried — replay can't fix a
-            # deterministic fault
-            for i, _ in active:
+            oldest.sampled = self._read_step(oldest)
+        except BaseException as exc:
+            # what was to be read is lost, and every step behind it was
+            # computed from it: drop them all, stand on the cache as the
+            # lost step found it, and let the retry plan them anew
+            rollback = oldest.pools_before
+            self._abandon(*self._unread)
+            if rollback is None:
+                if (self._decode_policy.max_retries
+                        and self._decode_policy.classify(exc)):
+                    raise ServingDegraded(
+                        "a decode step's tokens were lost after the "
+                        "cache had moved on: nothing to retry against"
+                    ) from exc
+                raise
+            self._cache.pools = rollback
+            self._planned = None
+            raise
+        return self._unread.popleft()
+
+    def _note_step_retry(self, exc, attempt_n, delay):
+        _step_retries.inc()
+        tel = self._telemetry
+        if tel.recording:
+            tel.emit({
+                "type": "serving_retry", "ts": time.time(),
+                "source": "serving", "leg": "decode_step",
+                "error": repr(exc)[:200], "attempt": attempt_n,
+                "delay_s": delay,
+                "active": sum(1 for s in self._slots
+                              if s is not None and not s.prefilling),
+            })
+
+    def _read_step(self, sent):
+        """The readback of one dispatched step: its tokens, and behind them
+        the model's step counters.  Blocks until the device has run it."""
+        with self._telemetry.span("serving.decode.step.wait") as wait:
+            sampled = np.asarray(sent.out)
+        self._turn_wait_s += wait.duration
+        return sampled
+
+    def _abandon(self, *steps):
+        """Forget steps, planned or dispatched, that will not be committed."""
+        for step in steps:
+            for _, slot in step.entries:
+                slot.inflight -= 1
+            if step in self._unread:
+                self._unread.remove(step)
+
+    def _settle(self):
+        """Read and commit whatever is in flight and dispatch nothing: what
+        a non-drain ``stop()`` does before it fails the actives."""
+        while self._unread:
+            self._decode_step(dispatch=False)
+
+    def _fail_decoding(self, exc):
+        """A decode step failed for good: retire every decoding sequence
+        typed, drop what is in flight for them, and (under donation, where
+        the failed dispatch consumed the pools) start from zeroed pools."""
+        self._abandon(*self._unread, *(self._planned or ()))
+        self._planned = []
+        for i, slot in enumerate(self._slots):
+            if slot is not None and not slot.prefilling:
                 self._retire(i, error=exc)
-            self._recover_pools(exc)
-            if self._breaker is not None:
-                self._breaker.record_fatal()
-            return
-        with tel.span("serving.decode.step.commit"):
-            self._cache.pools = pools
+        self._recover_pools(exc)
+        if self._breaker is not None:
+            self._breaker.record_fatal()
+
+    def _commit_step(self, sent):
+        """Take one read step's tokens into the slots that decoded in it —
+        the ``_Slot`` objects captured at its plan: a slot that left in
+        between (EOS seen a step late, a cancel, a deadline) drops its
+        token, whoever sits at its index now — then run the decisions that
+        need token values."""
+        cfg = self.config
+        with self._telemetry.span("serving.decode.step.commit"):
+            # the cache as this step found it and the step's output are
+            # let go inside this span (where pools are not donated it is a
+            # whole pytree of buffers to free, and the step behind this one
+            # is still reading the output)
+            sampled = np.array(sent.sampled)
+            sent.pools_before = sent.out = sent.sampled = None
             # the model's step counters came back behind the tokens
             for c, n in zip(self._step_counters, sampled[cfg.num_slots:]):
                 c.inc(int(n))
             if self._breaker is not None:
                 self._breaker.record_success()
+            for _, slot in sent.entries:
+                slot.inflight -= 1
+            live = [(i, slot) for i, slot in sent.entries
+                    if self._slots[i] is slot]
             tripped = ()
             if cfg.kv_guard:
-                # sweep each active slot's TAIL page — the one this
-                # step's token write landed in (position = pre-step
-                # kv_len)
+                # sweep each slot's TAIL page — the one this step's token
+                # write landed in (position = pre-step kv_len)
                 guard_vec = np.zeros((cfg.num_slots,), np.int32)
                 owners = list(range(cfg.num_slots))
-                for i, slot in active:
+                for i, slot in live:
                     guard_vec[i] = slot.pages[slot.kv_len // cfg.page_size]
                 tripped = self._guard_pages(owners, guard_vec,
                                             phase="decode")
             now = time.perf_counter()
-            for i, slot in active:
+            for i, slot in live:
                 if i in tripped:
                     continue           # retired typed by the guard
                 slot.kv_len += 1
@@ -2095,9 +2347,11 @@ class DecodeScheduler:
                 slot.req.journal.accepted.append(tok)
                 slot.req.token_times.append(now)
             _steps.inc()
-            _tokens.inc(len(active) - len(tripped))
-            for i, _ in active:
-                if self._slots[i] is not None:
+            _tokens.inc(len(live) - len(tripped))
+            if len(live) < len(sent.entries):
+                _tokens_discarded.inc(len(sent.entries) - len(live))
+            for i, slot in live:
+                if self._slots[i] is slot:
                     self._finish_if_done(i)
             _active_slots.set(self._active_count())
             self._cache.publish_gauges(

@@ -301,6 +301,209 @@ class TestDecodeScheduler:
         assert threading.active_count() <= before
 
 
+# -- one decode step in flight (ISSUE 36) -------------------------------------
+
+_PIPE = ("steps", "steps_overlapped", "tokens", "tokens_discarded")
+
+
+def _pipe_counters():
+    return {n: obs.counter("serving.decode." + n).value for n in _PIPE}
+
+
+def _pipe_delta(c0):
+    return {n: v - c0[n] for n, v in _pipe_counters().items()}
+
+
+def _free_run(model, prompts, **kw):
+    """Each prompt's tokens served ALONE (``max_active=1``: the naive loop,
+    one sequence at a time, so nothing joins or leaves beside it)."""
+    naive = serving.DecodeScheduler(model, _cfg(max_active=1))
+    try:
+        return [naive.generate(p, timeout=120, **kw) for p in prompts]
+    finally:
+        naive.stop()
+
+
+@pytest.fixture(scope="module")
+def lm():
+    return T.lm_params(seed=7, vocab_size=50, n_layer=2, n_head=2,
+                       d_model=32, d_inner=64, max_length=128)
+
+
+class TestOneStepInFlight:
+    @pytest.mark.parametrize("temperature", [0.0, 0.9])
+    def test_tokens_equal_the_naive_loop_with_slots_joining_and_leaving(
+            self, decode_model, temperature):
+        # more sequences than slots and every length different: slots are
+        # reseated mid-run, each beside neighbours at other positions
+        rng = np.random.RandomState(11)
+        prompts = _prompts(9, rng)
+        news = [int(m) for m in rng.randint(1, 14, size=9)]
+        kw = [dict(max_new_tokens=m, temperature=temperature, seed=100 + i)
+              for i, m in enumerate(news)]
+        c0 = _pipe_counters()
+        sched = serving.DecodeScheduler(decode_model, _cfg(num_slots=3))
+        futs = [sched.submit(p, **k) for p, k in zip(prompts, kw)]
+        got = [f.result(timeout=120) for f in futs]
+        sched.stop()
+        d = _pipe_delta(c0)
+        naive = serving.DecodeScheduler(decode_model, _cfg(max_active=1))
+        want = [naive.generate(p, timeout=120, **k)
+                for p, k in zip(prompts, kw)]
+        naive.stop()
+        for i, (g, w) in enumerate(zip(got, want)):
+            assert g.tobytes() == w.tobytes(), i
+            assert len(g) == news[i]
+        # the pipeline was engaged while they were served, and with no EOS,
+        # cancel or deadline no computed token was dropped
+        assert d["steps_overlapped"] >= d["steps"] // 2 > 0
+        assert d["tokens"] == sum(news) and d["tokens_discarded"] == 0
+
+    def test_the_token_after_eos_is_never_served_journalled_or_counted(
+            self, lm):
+        params, meta = lm
+        free = T.build_decode_model(params, meta)
+        rng = np.random.RandomState(5)
+        prompts = _prompts(3, rng)
+        kw = dict(max_new_tokens=20, temperature=1.0, seed=3)
+        runs = _free_run(free, prompts, **kw)
+        # an EOS that the first prompt samples in a decode step, mid-run
+        eos = next(int(t) for k, t in enumerate(runs[0])
+                   if 2 <= k <= 15 and t not in runs[0][:k])
+        want = [r[:list(r).index(eos) + 1] if eos in r else r for r in runs]
+        # a slot that samples EOS in a decode step with room left rode the
+        # step behind it: one token a slot, computed and dropped
+        riders = sum(1 for w in want if w[-1] == eos and 1 < len(w) < 20)
+        assert riders >= 1
+        capped = T.build_decode_model(params, meta, eos_id=eos)
+        c0 = _pipe_counters()
+        sched = serving.DecodeScheduler(capped, _cfg())
+        futs = [sched.submit(p, **kw) for p in prompts]
+        got = [f.result(timeout=120) for f in futs]
+        sched.stop()
+        d = _pipe_delta(c0)
+        for f, g, w in zip(futs, got, want):
+            assert g.tobytes() == np.asarray(w, np.int32).tobytes()
+            assert f.journal.tokens().tobytes() == g.tobytes()
+            assert len(f.token_times) == len(g)
+        assert d["tokens"] == sum(len(w) for w in want)
+        assert d["tokens_discarded"] == riders
+        st = sched.stats()
+        assert st["kv_pages_used"] == 0 and st["active"] == 0
+
+    @pytest.mark.parametrize("how", ["cancel", "deadline"])
+    def test_a_cancel_or_a_deadline_with_a_step_in_flight(self, decode_model,
+                                                          how):
+        from paddle_tpu.testing import faults
+
+        c0 = _pipe_counters()
+        sched = serving.DecodeScheduler(decode_model, _cfg(max_new_tokens=56))
+        prompt = np.arange(1, 7, dtype=np.int32)
+        with faults.slow_execute(0.01):
+            req = sched.submit(prompt,
+                               deadline_ms=400 if how == "deadline" else None)
+            if how == "cancel":
+                while len(req.token_times) < 4:
+                    time.sleep(0.002)
+                assert req.cancel()
+            with pytest.raises(serving.ServingCancelled if how == "cancel"
+                               else serving.ServingTimeout):
+                req.result(timeout=120)
+            # result() gives up at the deadline by the CLIENT's clock; the
+            # worker sheds the slot at its next iteration boundary
+            while not req.done():
+                time.sleep(0.002)
+        served = len(req.journal.accepted)
+        assert 0 < served < 56
+        time.sleep(0.05)
+        sched.stop()
+        d = _pipe_delta(c0)
+        # the step in flight when the request left was read and dropped: no
+        # token reached the journal, the stamps or the counter after it
+        assert len(req.journal.accepted) == len(req.token_times) == served
+        assert d["tokens"] == served and d["tokens_discarded"] == 1
+        assert sched.stats()["kv_pages_used"] == 0
+        want = _free_run(decode_model, [prompt], max_new_tokens=56)[0]
+        assert req.journal.tokens().tobytes() == want[:served].tobytes()
+
+    def test_a_slot_index_reseated_between_dispatch_and_commit(self, lm):
+        params, meta = lm
+        free = T.build_decode_model(params, meta)
+        first = np.arange(1, 6, dtype=np.int32)
+        second = np.arange(9, 20, dtype=np.int32)
+        kw = dict(max_new_tokens=20, temperature=1.0, seed=3)
+        runs = _free_run(free, [first, second], **kw)
+        eos = next(int(t) for k, t in enumerate(runs[0])
+                   if 2 <= k <= 15 and t not in runs[0][:k]
+                   and t not in runs[1])
+        capped = T.build_decode_model(params, meta, eos_id=eos)
+        c0 = _pipe_counters()
+        # ONE slot: the second request takes index 0 while the step the
+        # first one rode past its EOS is still unread
+        sched = serving.DecodeScheduler(capped, _cfg(num_slots=1),
+                                        autostart=False)
+        futs = [sched.submit(first, **kw), sched.submit(second, **kw)]
+        sched.start()
+        got = [f.result(timeout=120) for f in futs]
+        sched.stop()
+        d = _pipe_delta(c0)
+        k = list(runs[0]).index(eos)
+        assert got[0].tobytes() == runs[0][:k + 1].tobytes()
+        assert got[1].tobytes() == runs[1].tobytes()
+        assert futs[1].journal.tokens().tobytes() == runs[1].tobytes()
+        assert d["tokens_discarded"] == 1
+        assert d["tokens"] == k + 1 + 20
+
+    @pytest.mark.parametrize("kv_guard", [False, True])
+    def test_share_of_steps_overlapped_over_a_standing_run(self, decode_model,
+                                                           kv_guard):
+        cfg = _cfg(max_seq_len=128, max_new_tokens=100, kv_guard=kv_guard)
+        sched = serving.DecodeScheduler(decode_model, cfg, autostart=False)
+        rng = np.random.RandomState(4)
+        futs = [sched.submit(p) for p in _prompts(4, rng, lo=3, hi=9)]
+        c0 = _pipe_counters()
+        sched.start()
+        outs = [f.result(timeout=300) for f in futs]
+        sched.stop()
+        d = _pipe_delta(c0)
+        assert all(len(o) == 100 for o in outs)
+        assert d["tokens"] == 400 and d["tokens_discarded"] == 0
+        if kv_guard:
+            # the sweep must see a step's page before another write lands:
+            # every step is read before the next goes out
+            assert d["steps_overlapped"] == 0 and d["steps"] >= 99
+        else:
+            assert d["steps_overlapped"] / d["steps"] >= 0.95
+
+    def test_no_compile_after_warmup_with_either_previous(self, decode_model):
+        """``previous`` is a host array when nothing is in flight and the
+        step before's output on the device when one is: warm-up compiles
+        for both, so neither the first step of a run nor the ones behind it
+        meet the compiler."""
+        compiles = []
+
+        def on_duration(event, duration, **_):
+            if event == "/jax/core/compile/backend_compile_duration":
+                compiles.append(event)
+
+        sched = serving.DecodeScheduler(decode_model, _cfg())
+        c0 = compile_count()
+        jax.monitoring.register_event_duration_secs_listener(on_duration)
+        try:
+            rng = np.random.RandomState(6)
+            d0 = _pipe_counters()
+            for _ in range(2):          # two runs: two first steps
+                futs = [sched.submit(p) for p in _prompts(5, rng)]
+                for f in futs:
+                    f.result(timeout=120)
+            d = _pipe_delta(d0)
+        finally:
+            jax.monitoring.unregister_event_duration_listener(on_duration)
+            sched.stop()
+        assert 0 < d["steps_overlapped"] < d["steps"]
+        assert compiles == [] and compile_count() == c0
+
+
 # -- engine integration ------------------------------------------------------
 
 class TestEngineGenerate:

@@ -235,6 +235,57 @@ def test_the_scheduler_serves_a_window_group_through_many_releases():
                      labels={"group": "window"}).value == 11 * PS * V * 4
 
 
+def test_pages_one_step_ahead_stay_under_the_bound_and_the_reservation():
+    """With a step in flight ``_ensure_pages`` is called with the DISPATCHED
+    length, one past the committed one, and a release acts on the committed
+    length while the step behind it runs: a slot never holds more window
+    pages than its reservation (= the ring's width), at every alloc and at
+    every release, and releases and re-uses are what a loop that reads each
+    step before the next one does."""
+    runs = {}
+    for loop in ("in flight", "in order"):
+        c0 = {c: obs.counter(c).value for c in (
+            "serving.cache.window.pages_released",
+            "serving.decode.steps_overlapped")}
+        sched = serving.DecodeScheduler(_model(), _config())
+        if loop == "in order":
+            def plans(sched=sched):
+                plan = None if sched._unread else sched._plan_step()
+                return [] if plan is None else [plan]
+            sched._plan_steps = plans
+        bound = sched._more_tables["window"].shape[1]
+        grp, held, handed = sched.cache.groups["window"], [], []
+        ensure, alloc = sched._ensure_pages, grp.alloc
+
+        def watch(idx, slot, end, ensure=ensure, held=held):
+            ensure(idx, slot, end)
+            held.append(len(slot.more["window"].pages))
+            assert len(slot.more["window"].pages) <= slot.more[
+                "window"].reserved
+
+        sched._ensure_pages = watch
+        grp.alloc = lambda n=1, alloc=alloc, handed=handed: (
+            handed.extend(alloc(n) or ()) or handed[-n:])
+        rng = np.random.RandomState(0)
+        prompts = [rng.randint(1, V, size=n).astype(np.int32)
+                   for n in (29, 3, 18)]
+        outs = [sched.submit(p, max_new_tokens=30) for p in prompts]
+        for p, f in zip(prompts, outs):
+            assert list(f.result(timeout=120)) == _expected(p, 30)
+        sched.stop()
+        assert held and max(held) <= bound
+        runs[loop] = dict(
+            released=obs.counter("serving.cache.window.pages_released").value
+            - c0["serving.cache.window.pages_released"],
+            handed=len(handed), reused=len(handed) - len(set(handed)),
+            overlapped=obs.counter("serving.decode.steps_overlapped").value
+            - c0["serving.decode.steps_overlapped"])
+    assert runs["in flight"].pop("overlapped") > 0 == runs["in order"].pop(
+        "overlapped")
+    assert runs["in flight"] == runs["in order"]
+    assert runs["in flight"]["reused"] > 0
+
+
 def test_a_released_page_poisoned_in_anothers_hands_changes_nothing():
     """Slot 0 decodes far past its window; every page it has released is
     poisoned with NaN the moment it is released (another slot's write could

@@ -285,6 +285,53 @@ def test_the_scheduler_serves_the_references_tokens(reference, params, tokens):
                    - before["kv.full_tokens_read"]))
 
 
+def _read_each_step_before_the_next(sched):
+    """The loop as it ran before a step stayed in flight: plan one step only
+    when nothing is unread, so each is read before the next is built."""
+    def plans():
+        plan = None if sched._unread else sched._plan_step()
+        return [] if plan is None else [plan]
+
+    sched._plan_steps = plans
+
+
+def test_a_step_in_flight_reads_and_reuses_what_the_in_order_loop_did(
+        params, tokens):
+    """``_ensure_pages`` runs one step ahead and a window page is released
+    while the step behind the commit is in flight: the served tokens, what
+    both kinds of layer read (``window_read_share_pct``'s two counters), the
+    pages released and the pages handed out again are the in-order loop's."""
+    names = ("kv.full_tokens_read", "kv.window_tokens_read",
+             "steps_overlapped")
+    runs = {}
+    for loop in ("in flight", "in order"):
+        before = {c: obs.counter("serving.decode." + c).value for c in names}
+        released0 = obs.counter("serving.cache.window.pages_released").value
+        sched = _scheduler(params)
+        if loop == "in order":
+            _read_each_step_before_the_next(sched)
+        grp, handed = sched.cache.groups["window"], []
+        real = grp.alloc
+        grp.alloc = lambda n=1: handed.extend(real(n) or ()) or handed[-n:]
+        prompts = [tokens[:n] for n in (77, 5, 40)]
+        futs = [sched.submit(p, max_new_tokens=STEPS) for p in prompts]
+        outs = [f.result(timeout=300).tobytes() for f in futs]
+        sched.stop()
+        st = sched.cache_stats()["groups"]["window"]
+        assert st["used_pages"] == 0 and st["rc_errors"] == []
+        runs[loop] = dict(
+            outs=outs, released=st["released_pages"],
+            counted=obs.counter("serving.cache.window.pages_released").value
+            - released0,
+            handed=len(handed), reused=len(handed) - len(set(handed)),
+            **{c: obs.counter("serving.decode." + c).value - before[c]
+               for c in names})
+    a, b = runs["in flight"], runs["in order"]
+    assert a.pop("steps_overlapped") > 0 == b.pop("steps_overlapped")
+    assert a == b
+    assert a["released"] == a["counted"] > 10 and a["reused"] > 0
+
+
 @pytest.mark.parametrize("what", ["prefix_cache", "sessions", "role"])
 def test_a_window_group_refuses_what_it_cannot_do(params, what):
     kw, cfg = {}, {}

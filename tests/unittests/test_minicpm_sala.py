@@ -432,7 +432,9 @@ def _lowered_steps(sched, chunk):
     yield sched._jit.get(("decode",)).lower(
         p, c.pools, i32(S), i32(S), i32(S, mp), i32(S),
         jax.ShapeDtypeStruct((S,), jnp.uint32),
-        jax.ShapeDtypeStruct((S,), jnp.float32)).as_text()
+        jax.ShapeDtypeStruct((S,), jnp.float32),
+        i32(S + len(sched.model.step_counters)),
+        jax.ShapeDtypeStruct((S,), jnp.bool_)).as_text()
     yield sched._jit.get(("chunk", chunk)).lower(
         p, c.pools, i32(chunk), i32(), i32(), i32(chunk // cfg.page_size),
         i32(mp), i32(), jax.ShapeDtypeStruct((), jnp.uint32),

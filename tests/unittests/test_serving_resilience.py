@@ -780,3 +780,149 @@ class TestDecodeMidDecodeShed:
                 break
             time.sleep(0.05)
         assert not sched.alive
+
+
+# -- a fault with a decode step in flight (ISSUE 36) --------------------------
+
+class TestFaultWithAStepInFlight:
+    """The loop dispatches step n+1 before it reads step n: a fault can
+    land on either, and both ways the served tokens are a clean run's."""
+
+    PROMPT = np.arange(1, 9, dtype=np.int32)
+    KW = dict(max_new_tokens=24, temperature=0.7, seed=11)
+
+    def _clean(self):
+        sched = _decode_scheduler()
+        sched.start()
+        try:
+            return sched.generate(self.PROMPT, timeout=120, **self.KW)
+        finally:
+            sched.stop(timeout=10)
+
+    @pytest.mark.parametrize("where", ["dispatch", "readback"])
+    def test_a_transient_fault_retries_to_a_clean_runs_tokens(self, where):
+        want = self._clean()
+        sched = _decode_scheduler()
+        r0 = obs.counter("serving.decode.step_retries").value
+        d0 = obs.counter("serving.decode.tokens_discarded").value
+        fired = [0]
+        if where == "readback":
+            # the readback of step n, with step n+1 already dispatched
+            # behind it: both are dropped, the cache is what step n took
+            read = sched._read_step
+
+            def lossy(sent):
+                if (not fired[0] and len(sched._unread) == 2
+                        and len(sent.entries[0][1].generated) >= 5):
+                    fired[0] += 1
+                    raise faults.FaultInjected("injected lost readback")
+                return read(sent)
+
+            sched._read_step = lossy
+        sched.start()
+        try:
+            if where == "dispatch":
+                # the dispatch of step n+1 while step n is unread
+                mid_run = lambda rs: (  # noqa: E731
+                    len(rs[0].journal.accepted) >= 5 and bool(sched._unread))
+                with faults.flaky_execute(times=1, match=mid_run) as hit:
+                    got = sched.generate(self.PROMPT, timeout=120, **self.KW)
+                fired = hit
+            else:
+                got = sched.generate(self.PROMPT, timeout=120, **self.KW)
+            assert sched.stats()["kv_pages_used"] == 0
+        finally:
+            sched.stop(timeout=10)
+        assert fired[0] == 1
+        assert obs.counter("serving.decode.step_retries").value == r0 + 1
+        assert got.tobytes() == want.tobytes()
+        # a dropped step is computed again, not served twice or discarded
+        assert obs.counter("serving.decode.tokens_discarded").value == d0
+
+    @pytest.mark.parametrize("where", ["dispatch", "readback"])
+    def test_a_fatal_fault_fails_the_actives_and_the_next_request_is_served(
+            self, where):
+        """As on the chip, where the failed dispatch has consumed the
+        donated pools: the actives fail typed, the pools come back zeroed
+        and the next request is served a clean run's tokens."""
+        want = self._clean()
+        sched = _decode_scheduler()
+        sched._donated = True            # the host's side of donation
+        fired = [0]
+        if where == "readback":
+            read = sched._read_step
+
+            def broken(sent):
+                if not fired[0] and len(sched._unread) == 2:
+                    fired[0] += 1
+                    raise ValueError("injected fatal readback")
+                return read(sent)
+
+            sched._read_step = broken
+        sched.start()
+        try:
+            fut = sched.submit(self.PROMPT, **self.KW)
+            other = sched.submit(self.PROMPT[:5], **self.KW)
+            if where == "dispatch":
+                fatal = lambda rs: ValueError("injected fatal dispatch")  # noqa
+                mid_run = lambda rs: (  # noqa: E731
+                    len(rs[0].journal.accepted) >= 3 and bool(sched._unread))
+                with faults.flaky_execute(times=1, match=mid_run,
+                                          exc_factory=fatal) as fired:
+                    for f in (fut, other):
+                        with pytest.raises(ValueError, match="injected"):
+                            f.result(timeout=120)
+            else:
+                for f in (fut, other):
+                    with pytest.raises(ValueError, match="injected"):
+                        f.result(timeout=120)
+            assert fired[0] == 1
+            # the futures fail first, the pools are zeroed behind them
+            deadline = time.time() + 10
+            while (np.asarray(sched._cache.k_pool).any()
+                   and time.time() < deadline):
+                time.sleep(0.01)
+            assert not np.asarray(sched._cache.k_pool).any()
+            assert not sched._unread
+            st = sched.stats()
+            assert st["active"] == 0 and st["kv_pages_used"] == 0
+            got = sched.generate(self.PROMPT, timeout=120, **self.KW)
+        finally:
+            sched.stop(timeout=10)
+        assert got.tobytes() == want.tobytes()
+
+    def test_a_readback_lost_past_a_chunks_write_is_not_retried(self):
+        """A prefill chunk wrote the cache behind the unread step: there is
+        nothing to roll back to, so the lost step is not retried.  The
+        decoding sequences (the one whose chunk it was has its first token
+        by then) fail typed, and the next request is served a clean run's
+        tokens from the same pools."""
+        want = self._clean()
+        sched = _decode_scheduler()
+        fired = [0]
+        read = sched._read_step
+
+        def lossy(sent):
+            if not fired[0] and sent.pools_before is None:
+                fired[0] += 1
+                raise faults.FaultInjected("injected lost readback")
+            return read(sent)
+
+        sched._read_step = lossy
+        r0 = obs.counter("serving.decode.step_retries").value
+        sched.start()
+        try:
+            first = sched.submit(self.PROMPT[:5], **self.KW)
+            while len(first.token_times) < 3:
+                time.sleep(0.002)
+            second = sched.submit(self.PROMPT, **self.KW)
+            for f in (first, second):
+                with pytest.raises(serving.ServingDegraded, match="moved on"):
+                    f.result(timeout=120)
+            got = sched.generate(self.PROMPT, timeout=120, **self.KW)
+            assert sched.stats()["kv_pages_used"] == 0
+        finally:
+            sched.stop(timeout=10)
+        assert fired[0] == 1
+        assert obs.counter("serving.decode.step_retries").value == r0
+        assert got.tobytes() == want.tobytes()
