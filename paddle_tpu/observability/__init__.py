@@ -65,10 +65,12 @@ from .export import (
 from .histogram import Histogram, HistogramSnapshot, default_bounds
 from .registry import (
     Counter,
+    Frame,
     Gauge,
     Telemetry,
     Timer,
     add_sink,
+    close_frame,
     counter,
     emit,
     enabled,
@@ -79,6 +81,7 @@ from .registry import (
     labeled_name,
     observe,
     observe_span,
+    open_frame,
     record_span,
     remove_sink,
     reset,
@@ -86,6 +89,8 @@ from .registry import (
     split_labels,
     timed,
     timer,
+    unwatch_gc,
+    watch_gc,
 )
 from .sinks import (
     ChromeTraceSink,
@@ -124,6 +129,11 @@ __all__ = [
     "reset",
     "add_sink",
     "remove_sink",
+    "Frame",
+    "open_frame",
+    "close_frame",
+    "watch_gc",
+    "unwatch_gc",
     "Sink",
     "JsonlSink",
     "RingBufferSink",

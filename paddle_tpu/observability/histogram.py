@@ -204,10 +204,16 @@ class Histogram:
         self._max = None
         self._lock = threading.Lock()
 
-    def observe(self, value):
+    def observe(self, value, wait=True):
+        """``wait=False`` is for a caller that may not wait for the cell
+        (the collector's callback, which can start on a thread that
+        stands inside this cell's own ``snapshot``): False, and nothing
+        observed, where the cell's lock is held."""
         v = float(value)
         idx = bisect.bisect_left(self.bounds, v)
-        with self._lock:
+        if not self._lock.acquire(wait):
+            return False
+        try:
             self._counts[idx] += 1
             self._count += 1
             self._sum += v
@@ -215,6 +221,9 @@ class Histogram:
                 self._min = v
             if self._max is None or v > self._max:
                 self._max = v
+        finally:
+            self._lock.release()
+        return True
 
     @property
     def count(self):

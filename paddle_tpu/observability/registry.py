@@ -30,6 +30,7 @@ Design constraints (see docs/observability.md for the measured numbers):
 from __future__ import annotations
 
 import contextlib
+import gc
 import os
 import threading
 import time
@@ -60,6 +61,11 @@ __all__ = [
     "reset",
     "add_sink",
     "remove_sink",
+    "Frame",
+    "open_frame",
+    "close_frame",
+    "watch_gc",
+    "unwatch_gc",
 ]
 
 
@@ -241,14 +247,73 @@ def _resolve_annotation():
     return TraceAnnotation
 
 
+class Frame:
+    """What one thread's spans add up to while the frame is open on it
+    (:func:`open_frame`): every span that closes there adds its duration
+    under its name, so a loop can account for its own time without a
+    second clock.  ``depth`` counts the spans open on the thread; the
+    span opened first under the frame (the loop's turn) closes at depth
+    0, its direct children at depth 1.  ``children_s`` and ``wait_s`` are
+    running totals (the depth-1 spans; the spans named ``*.wait``, which
+    never nest in one another): a loop reads them at both ends of a turn.
+    ``phases`` is ``{name: [seconds, spans, depth]}`` since the last
+    :meth:`cut`.  A span that closes into no cell is in neither."""
+
+    __slots__ = ("phases", "depth", "children_s", "wait_s")
+
+    def __init__(self):
+        self.phases = {}
+        self.depth = 0
+        self.children_s = 0.0
+        self.wait_s = 0.0
+
+    def add(self, name, dur, depth):
+        entry = self.phases.get(name)
+        if entry is None:
+            self.phases[name] = [dur, 1, depth]
+        else:
+            entry[0] += dur
+            entry[1] += 1
+        if depth == 1:
+            self.children_s += dur
+        if name.endswith(".wait"):
+            self.wait_s += dur
+
+    def cut(self):
+        """The phases gathered so far; the frame starts over."""
+        phases, self.phases = self.phases, {}
+        return phases
+
+
+class _Frames(threading.local):
+    frame = None        # a thread with no frame reads the class's None
+
+
+_frames = _Frames()
+
+
+def open_frame():
+    """Open a :class:`Frame` on the calling thread (in place of any that
+    was open) and return it."""
+    frame = _frames.frame = Frame()
+    return frame
+
+
+def close_frame():
+    _frames.frame = None
+
+
 class _Span:
     """One timed phase (see :meth:`Telemetry.span`).  ``name`` may be
     reassigned before the block exits: the span then closes into that
     cell (``executor.run`` -> ``executor.first_run``), or into none when
     set to None (the extent stays in a running profiler trace under the
-    name it was opened with).  ``duration`` is set on exit."""
+    name it was opened with).  ``duration`` is set on exit.  ``tags`` None
+    (the collector's span alone) closes it into the thread's frame and
+    nothing else: no cell and no sink, so no lock (:class:`_GcWatch`)."""
 
-    __slots__ = ("name", "tags", "duration", "_telemetry", "_t0", "_ann")
+    __slots__ = ("name", "tags", "duration", "_telemetry", "_t0", "_ann",
+                 "_frame")
 
     def __init__(self, telemetry, name, tags):
         self._telemetry = telemetry
@@ -265,6 +330,10 @@ class _Span:
             ann.__enter__()
         else:
             self._ann = None
+        # the thread's frame, if one is open: one attribute read otherwise
+        frame = self._frame = _frames.frame
+        if frame is not None:
+            frame.depth += 1
         self._t0 = time.perf_counter()
         return self
 
@@ -273,7 +342,12 @@ class _Span:
         if self._ann is not None:
             self._ann.__exit__(*exc)
         name = self.name
-        if name is not None:
+        frame = self._frame
+        if frame is not None:
+            frame.depth -= 1
+            if name is not None:
+                frame.add(name, dur, frame.depth)
+        if name is not None and self.tags is not None:
             tel = self._telemetry
             cell = tel._histograms.get(name)
             (cell or tel.histogram(name)).observe(dur)
@@ -535,6 +609,78 @@ def emit(record):
 
 def reset(prefix=None):
     _global.reset(prefix)
+
+
+class _GcWatch:
+    """The ``gc.callbacks`` entry of :func:`watch_gc`: a collection is a
+    span ``host.gc`` on the collecting thread, observed into the cell
+    ``host.gc{gen="0|1|2"}``; ``seconds`` is the running total over all
+    threads (whoever collects holds the GIL, so every thread stands still
+    for it).
+
+    A collection starts wherever its thread allocates or passes the eval
+    breaker: inside a sink's ``with self._lock:``, or inside ``snapshot()``
+    of the very cell it is about to close into.  So the callback WAITS FOR
+    NO LOCK: the span goes to no sink and closes into no cell by itself
+    (``tags`` None), and its seconds go to the cell through
+    ``Histogram.observe(wait=False)``; where another thread, or this one,
+    holds the cell they are kept in ``_pending`` until the next collection
+    finds it free.  (A callback that blocked there would never return: the
+    collector's ``collecting`` flag would stay set and the process would
+    collect nothing from then on.)"""
+
+    def __init__(self):
+        self.seconds = 0.0
+        self._span = None
+        self._pending = []          # (generation, seconds) not yet in a cell
+        # made here, so that a collection never takes the registry's lock
+        self._cells = tuple(
+            _global.histogram("host.gc", {"gen": g}) for g in range(3))
+
+    def __call__(self, phase, info):
+        if phase == "start":
+            sp = self._span = _Span(_global, "host.gc", None)
+            sp.__enter__()
+            return
+        sp, self._span = self._span, None
+        if sp is None:
+            return
+        gen = info["generation"]
+        sp.name = self._cells[gen].name     # the frame's name for it
+        sp.__exit__(None, None, None)
+        self.seconds += sp.duration
+        # collections run one at a time (the collector's own flag), so
+        # nothing else touches the list
+        pending = self._pending
+        pending.append((gen, sp.duration))
+        while pending and self._cells[pending[-1][0]].observe(
+                pending[-1][1], wait=False):
+            pending.pop()
+
+
+_gc_watch = None
+_gc_lock = threading.Lock()
+
+
+def watch_gc():
+    """Time the collector: from here on every garbage collection is a span
+    (cell ``host.gc{gen=...}``, annotation ``paddle_tpu.host.gc`` on the
+    collecting thread's line of a running profiler trace).  Idempotent;
+    returns the watcher, whose ``seconds`` is the running total."""
+    global _gc_watch
+    with _gc_lock:
+        if _gc_watch is None:
+            _gc_watch = _GcWatch()
+        if _gc_watch not in gc.callbacks:
+            gc.callbacks.append(_gc_watch)
+        return _gc_watch
+
+
+def unwatch_gc():
+    """Take :func:`watch_gc`'s callback out again (tests)."""
+    with _gc_lock:
+        if _gc_watch is not None and _gc_watch in gc.callbacks:
+            gc.callbacks.remove(_gc_watch)
 
 
 def add_sink(sink):

@@ -99,6 +99,7 @@ the next iteration boundary instead of decoding to max_len for nobody.
 from __future__ import annotations
 
 import collections
+import contextlib
 import threading
 import time
 
@@ -151,10 +152,34 @@ _active_slots = _obs.gauge("serving.decode.active_slots")
 # cell of its own name, see docs/observability.md "Phases"
 _queue_wait_hist = _obs.histogram("serving.decode.queue_wait")
 _ttft_hist = _obs.histogram("serving.decode.ttft")
-# one observation per iteration: its duration less the ``*.wait`` spans
-# inside it — host time during which this scheduler has nothing queued
-# on the device
+# two observations per iteration, both from the worker's frame
+# (``observability.Frame``: what the spans that closed on the thread add up
+# to): its duration less the ``*.wait`` spans inside it — host time during
+# which this scheduler has nothing queued on the device — and its duration
+# less its direct children: what lies between spans
 _iteration_host = _obs.histogram("serving.decode.iteration.host")
+_iteration_unspanned = _obs.histogram("serving.decode.iteration.unspanned")
+# commit to commit, by whether a prefill chunk rode the interval; and of the
+# intervals judged a stall (``DecodeScheduler._note_commit``) the excess
+# over the kind's baseline, with ``serving.decode.stall_seconds{where=...}``
+# putting the same seconds down to a phase
+_interval = tuple(_obs.histogram("serving.decode.interval", {"chunk": c})
+                  for c in (0, 1))
+_stall = _obs.histogram("serving.decode.stall")
+# a stall is an interval above max(floor, factor x its kind's baseline); the
+# baseline is a mean of the first 1 / weight quiet intervals and
+# exponential from there, and nothing is judged before ``MIN_SAMPLES``
+STALL_FLOOR_S = 0.05
+STALL_FACTOR = 3.0
+STALL_WEIGHT = 1.0 / 64
+STALL_MIN_SAMPLES = 32
+STALL_RING = 64
+STALL_CPU_EVERY_S = 0.02
+# cells that per-layer metrics read exist from the import on, so that a
+# reader tells "nothing ran" (0) from "this program has no such span"
+for _cell in ("prefill.behind", "prefill.chunk", "step.build",
+              "step.dispatch", "step.commit"):
+    _obs.histogram("serving.decode." + _cell)
 _prefill_retries = _obs.counter("serving.decode.prefill_retries")
 _prefill_tokens = _obs.counter("serving.decode.prefill_tokens")
 _expired_mid_prefill = _obs.counter("serving.decode.expired_mid_prefill")
@@ -171,7 +196,6 @@ _handoff_pages = _obs.counter("serving.handoff.pages")
 _handoff_bytes = _obs.counter("serving.handoff.bytes")
 _handoff_injected = _obs.counter("serving.handoff.injected")
 _handoff_failed = _obs.counter("serving.handoff.failed")
-_handoff_stage_timer = _obs.timer("serving.handoff.stage")
 _session_parked_pages = _obs.counter("serving.session.pinned")
 
 
@@ -803,9 +827,24 @@ class DecodeScheduler:
             for g, grp in self._cache.groups.items()}
         self._widest_chunk = widest
         self._hol = None               # head-of-line request awaiting pages
-        # seconds this turn of the serve loop spent in ``*.wait`` spans
-        # (blocked on the device): what ``iteration.host`` subtracts
-        self._turn_wait_s = 0.0
+        # the loop's own account (``_note_commit``): the worker's frame and
+        # the collector's watcher while the loop runs; where the last commit
+        # ended (None: nothing to measure an interval from); what rode the
+        # interval under way; a baseline and its samples per kind of
+        # interval (without / with a prefill chunk); the stalls
+        self._frame = None
+        self._gc = None
+        self._last_commit = None
+        self._cpu_at = (0.0, 0.0)      # newest reading of the CPU clock
+        self._committed = False
+        self._chunk_rode = False
+        self._retried = False
+        self._baseline = [0.0, 0.0]
+        self._samples = [0, 0]
+        self._stall_run = [[0, 0.0], [0, 0.0]]   # stalls in a row: n, seconds
+        self._stalls = collections.deque(maxlen=STALL_RING)
+        self._stall_count = 0
+        self._stall_seconds = 0.0
         # serializes _hol handoff between the worker (_admit/_fail_all)
         # and a stop() that timed out joining a wedged-but-alive worker
         # — an unsynchronized claim could fail AND decode one request
@@ -991,6 +1030,8 @@ class DecodeScheduler:
 
     # -- lifecycle -----------------------------------------------------------
     def start(self):
+        # a collection stops every thread: the loop counts them as its own
+        self._gc = _obs.watch_gc()
         self._worker.start()
         return self
 
@@ -1231,6 +1272,11 @@ class DecodeScheduler:
             "prefill_chunk_tokens": self.config.prefill_chunk_tokens,
             "prefix_cache": self.config.prefix_cache,
             "role": self._role,
+            # commit-to-commit intervals judged a stall (docs/
+            # observability.md, "Debugging a stalled replica")
+            "stalls": {"count": self._stall_count,
+                       "seconds": self._stall_seconds,
+                       "last": self._stalls[-1] if self._stalls else None},
         }
         if self.config.prefix_cache:
             st["prefix"] = self._cache.prefix_stats()
@@ -1243,6 +1289,10 @@ class DecodeScheduler:
                     "occupancy": grp.occupancy()}
                 for g, grp in self._cache.groups.items()}
         return st
+
+    def stalls(self):
+        """The journal: the last ``STALL_RING`` stalls, oldest first."""
+        return list(self._stalls)
 
     def cache_stats(self):
         """The cache allocator snapshot incl. the leaked-refcount sweep
@@ -1378,6 +1428,17 @@ class DecodeScheduler:
         # RestartableWorker choke counts it, emits the worker_death
         # record/trace event, and the supervisor restarts the thread —
         # slots and KV carry over — or fails pending requests fast.)
+        # While the loop runs every span that closes on this thread also
+        # lands in its frame: the loop's account of its own time
+        self._frame = _obs.open_frame()
+        self._last_commit = None
+        try:
+            self._serve_turns(self._frame)
+        finally:
+            self._frame = None
+            _obs.close_frame()
+
+    def _serve_turns(self, frame):
         # anchors for the queue's service-rate EMA (deadline-aware
         # admission): retirements per second of BUSY wall time
         self._note_ts = time.perf_counter()
@@ -1392,7 +1453,8 @@ class DecodeScheduler:
                 # children of this span on the worker's line of a profiler
                 # trace; a turn that could seat nothing is no iteration and
                 # closes into no cell
-                self._turn_wait_s = 0.0
+                wait0, children0 = frame.wait_s, frame.children_s
+                self._committed = False
                 with tel.span("serving.decode.iteration") as turn:
                     with tel.span("serving.decode.admit") as admit:
                         # queued session-pin releases first: freed pages
@@ -1419,13 +1481,22 @@ class DecodeScheduler:
                         self._note_throughput()
                 if iterated:
                     _iteration_host.observe(
-                        turn.duration - self._turn_wait_s)
+                        turn.duration - (frame.wait_s - wait0))
+                    _iteration_unspanned.observe(
+                        turn.duration - (frame.children_s - children0))
+                    # out here, behind the turn: nothing of the loop's
+                    # account lies between two spans of an iteration
+                    if self._committed:
+                        self._note_commit(frame)
+                    else:
+                        self._lose_anchor()
                     continue
             else:
                 self._await_request()
                 if self._has_admissible():
                     continue
             # idle: re-anchor so idle gaps don't dilute the rate
+            self._lose_anchor()
             self._note_ts = time.perf_counter()
             self._note_retired = self._retired_total
             if self._worker.stopping and (not self._drain
@@ -1446,6 +1517,7 @@ class DecodeScheduler:
         line for the next turn's ``_admit``.  The wait is the cell
         ``serving.decode.idle``: it is no part of ``admit`` or of an
         iteration."""
+        self._lose_anchor()
         with self._telemetry.span("serving.decode.idle"):
             self._drain_pending()
             if self._worker.stopping and not self._drain:
@@ -1547,7 +1619,7 @@ class DecodeScheduler:
         idxvec = np.zeros((self._cache.max_pages_per_seq,), np.int32)
         idxvec[:packet.n_pages] = pages[:packet.n_pages]
         fn = self._jit.get(("hscatter",))
-        with _handoff_stage_timer.time():
+        with self._telemetry.span("serving.handoff.stage"):
             self._wrote_cache(fn(
                 self._cache.pools,
                 {name: jnp.asarray(a)
@@ -1688,6 +1760,7 @@ class DecodeScheduler:
         record, and trace one retried transient fault."""
         def note_retry(exc, attempt_n, delay):
             _prefill_retries.inc()
+            self._retried = True
             tel = self._telemetry
             if tel.recording:
                 tel.emit({
@@ -1734,6 +1807,7 @@ class DecodeScheduler:
 
         cfg = self.config
         tel = self._telemetry
+        self._chunk_rode = True
         with tel.span("serving.decode.chunk.build"):
             idx = min((i for i, s in enumerate(self._slots)
                        if s is not None and s.prefilling),
@@ -1778,15 +1852,30 @@ class DecodeScheduler:
             serve_fault = _resilience._serve_fault
             if serve_fault is not None:
                 serve_fault([req])
-            with tel.span("serving.decode.prefill.dispatch"):
-                tok, pools = fn(
-                    self._params, self._cache.pools,
-                    jnp.asarray(tokens), jnp.int32(start),
-                    jnp.int32(valid), written, gathered, np.int32(idx),
-                    seed, temp)
-            with tel.span("serving.decode.prefill.wait") as wait:
-                first = int(np.asarray(tok))
-            self._turn_wait_s += wait.duration
+            # ``prefill.chunk`` is the chunk program's own time.  It goes
+            # out BEHIND a decode step still in flight and cannot start
+            # before that step ends: the rest of the step is waited out
+            # first (``prefill.behind``: no transfer, the device's order and
+            # the whole wait are what they were), and the chunk's time runs
+            # from there; with nothing in flight it runs from the dispatch
+            ahead = self._unread[-1].out if self._unread else None
+            own = (contextlib.nullcontext() if ahead is not None
+                   else tel.span("serving.decode.prefill.chunk"))
+            with own:
+                with tel.span("serving.decode.prefill.dispatch"):
+                    tok, pools = fn(
+                        self._params, self._cache.pools,
+                        jnp.asarray(tokens), jnp.int32(start),
+                        jnp.int32(valid), written, gathered, np.int32(idx),
+                        seed, temp)
+                with tel.span("serving.decode.prefill.wait"):
+                    if ahead is None:
+                        first = int(np.asarray(tok))
+                    else:
+                        with tel.span("serving.decode.prefill.behind"):
+                            ahead.block_until_ready()
+                        with tel.span("serving.decode.prefill.chunk"):
+                            first = int(np.asarray(tok))
             return first, pools
 
         try:
@@ -1876,7 +1965,7 @@ class DecodeScheduler:
         idxvec = np.zeros((self._cache.max_pages_per_seq,), np.int32)
         idxvec[:n_pages] = slot.pages[:n_pages]
         fn = self._jit.get(("hgather",))
-        with _handoff_stage_timer.time():
+        with self._telemetry.span("serving.handoff.stage"):
             pages_host = {
                 name: np.asarray(a) for name, a in fn(
                     self._cache.pools, jnp.asarray(idxvec)).items()}
@@ -2256,6 +2345,7 @@ class DecodeScheduler:
 
     def _note_step_retry(self, exc, attempt_n, delay):
         _step_retries.inc()
+        self._retried = True
         tel = self._telemetry
         if tel.recording:
             tel.emit({
@@ -2270,10 +2360,8 @@ class DecodeScheduler:
     def _read_step(self, sent):
         """The readback of one dispatched step: its tokens, and behind them
         the model's step counters.  Blocks until the device has run it."""
-        with self._telemetry.span("serving.decode.step.wait") as wait:
-            sampled = np.asarray(sent.out)
-        self._turn_wait_s += wait.duration
-        return sampled
+        with self._telemetry.span("serving.decode.step.wait"):
+            return np.asarray(sent.out)
 
     def _abandon(self, *steps):
         """Forget steps, planned or dispatched, that will not be committed."""
@@ -2356,6 +2444,120 @@ class DecodeScheduler:
             _active_slots.set(self._active_count())
             self._cache.publish_gauges(
                 sum(s.kv_len for s in self._slots if s is not None))
+            self._committed = True
+
+    # -- the loop's account of its own time ------------------------------------
+    def _lose_anchor(self):
+        """Nothing to measure the next interval from (an idle wait, a turn
+        that committed no step): the next commit observes nothing, and what
+        the frame gathered belongs to no interval."""
+        self._last_commit = None
+        self._chunk_rode = self._retried = False
+        if self._frame is not None:
+            self._frame.cut()
+
+    def _note_commit(self, frame):
+        """The turn that just closed committed a decode step (worker thread;
+        behind a commit there is nothing of a turn but the throughput note):
+        observe the time since the turn before it did into
+        ``serving.decode.interval{chunk}``, and judge it.  The frame is cut
+        HERE, so that what it holds is the interval's own extent: the loop's
+        conditions between two turns, and this turn."""
+        now, gc_s = time.perf_counter(), self._gc.seconds
+        last, self._last_commit = self._last_commit, (now, gc_s)
+        phases = frame.cut()
+        chunk, self._chunk_rode = int(self._chunk_rode), False
+        retried, self._retried = self._retried, False
+        if last is None:
+            self._cpu_at = (now, time.thread_time())
+            return
+        interval = now - last[0]
+        _interval[chunk].observe(interval)
+        n, base = self._samples[chunk], self._baseline[chunk]
+        run = self._stall_run[chunk]
+        stalled = (n >= STALL_MIN_SAMPLES
+                   and interval > max(STALL_FLOOR_S, STALL_FACTOR * base))
+        # the thread's CPU clock is a system call, and on a sandboxed host
+        # a dear one (tens of microseconds of a 3 ms iteration): read at a
+        # commit, at most once in ``STALL_CPU_EVERY_S``, and at every stall
+        cpu_at = self._cpu_at
+        if stalled or now - cpu_at[0] >= STALL_CPU_EVERY_S:
+            self._cpu_at = (now, time.thread_time())
+        if stalled:
+            self._note_stall(interval, base, phases, chunk,
+                             self._cpu_at[1] - cpu_at[1], now - cpu_at[0],
+                             gc_s - last[1])
+            # a stall never feeds the baseline, but a kind that has done
+            # nothing else for as long as it took to trust the baseline is
+            # in a new regime (a batch or a context many times larger):
+            # the run's mean is the baseline from here
+            run[0] += 1
+            run[1] += interval
+            if run[0] >= STALL_MIN_SAMPLES:
+                self._baseline[chunk] = run[1] / run[0]
+                run[:] = 0, 0.0
+            return
+        run[:] = 0, 0.0
+        if not retried:
+            self._samples[chunk] = n = n + 1
+            self._baseline[chunk] = base + (interval - base) * max(
+                STALL_WEIGHT, 1.0 / n)
+
+    def _note_stall(self, interval, base, phases, chunk, cpu_s, cpu_over_s,
+                    gc_s):
+        """One stall: its excess over the baseline into the cell and, under
+        the phase it is put down to, into the counter; an entry into the
+        journal (and to the record sinks).  ``cpu_s`` is the worker's CPU
+        time over the last ``cpu_over_s`` seconds: the interval, and at
+        most ``STALL_CPU_EVERY_S`` and one quiet interval before it."""
+        hists = self._telemetry.histograms()
+
+        def above_its_mean(name, seconds, spans):
+            # the cell's mean over the process, this frame's own spans apart
+            # (they have closed, so the cell holds them)
+            cell = hists.get(name)
+            if cell is None or cell.count <= spans:
+                return seconds
+            return seconds - spans * (cell.sum - seconds) / (cell.count - spans)
+
+        # the phases below the turn, the collector's own span apart
+        below = {name: e for name, e in phases.items()
+                 if e[2] >= 1 and not name.startswith("host.gc")}
+        unspanned = interval - sum(e[0] for e in below.values() if e[2] == 1)
+        over = {name: above_its_mean(name, e[0], e[1])
+                for name, e in below.items()}
+        most = max(over.values(), default=0.0)
+        between = _iteration_unspanned.snapshot().mean or 0.0
+        if gc_s > 0.5 * interval:
+            where = "gc"
+        elif not over or unspanned - between > most:
+            where = "outside"
+        else:
+            # a parent stands as far above its mean as the child that was
+            # slow: of those close to the most, the innermost
+            where = max((n for n, o in over.items()
+                         if o >= most - 0.1 * abs(most)),
+                        key=lambda n: below[n][2])
+        excess = interval - base
+        entry = {
+            "ts": time.time(), "interval_s": interval, "baseline_s": base,
+            "excess_s": excess, "where": where, "chunk": bool(chunk),
+            "frame": {name: e[0] for name, e in below.items()},
+            "unspanned_s": unspanned,
+            "wait_s": sum(e[0] for name, e in below.items()
+                          if name.endswith(".wait")),
+            "cpu_s": cpu_s, "cpu_over_s": cpu_over_s, "gc_s": gc_s,
+            "active": self._active_count(), "in_flight": len(self._unread),
+        }
+        _stall.observe(excess)
+        _obs.counter("serving.decode.stall_seconds",
+                     {"where": where}).inc(excess)
+        self._stalls.append(entry)
+        self._stall_count += 1
+        self._stall_seconds += excess
+        tel = self._telemetry
+        if tel.recording:
+            tel.emit(dict(entry, type="serving_stall", source="serving"))
 
     def _retire(self, idx, error=None):
         slot = self._slots[idx]

@@ -605,6 +605,9 @@ class Trainer:
         serial = self._serial_start
         global_step = 0
         tel = _obs.get_telemetry()
+        # a collection stops the loop's thread like any other: from here on
+        # each is a span (cell ``host.gc{gen}``), beside the steps in a trace
+        _obs.watch_gc()
         run_id = "train-%d" % next(_run_seq)
         prog_tag = self._program_tag(self.train_program)
         feed_creator = self._feed_pipeline(reader, feeder, self.train_program,
