@@ -1452,16 +1452,26 @@ def _paged_gqa_walk_reference(q, k_pool, v_pool, page_tables, kv_lens, n_kv,
 def _paged_gqa_walk_kernel(pt_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
                            k_buf, v_buf, sem, *, layer, page_size, pages,
                            table_width, n_kv, per_head, group, q_tokens,
-                           head_dim, window, sm_scale):
+                           head_dim, window, sm_scale, listed=False):
     """One grid step = one slot's ``n_kv * per_head`` query rows against the
     slot's live pages, ``pages`` a turn (``_walk_pages``): the pages up to
     ``kv_len``, from the sequence's first or, with a ``window``, from the one
-    that holds the oldest key any row of the step sees."""
+    that holds the oldest key any row of the step sees.
+
+    ``listed``: one grid step = one (slot, KV head) of a ``(S, n_kv)`` grid,
+    which walks its OWN row of the table (``table_width`` listed pages, of
+    which ``lens_ref``'s tokens count, page by page) and copies that head's
+    ``head_dim`` lanes of each page alone."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     s_idx = pl.program_id(0)
+    lanes = None                        # the whole row of a page
+    if listed:
+        lanes = pl.ds(pl.multiple_of(pl.program_id(1) * head_dim, head_dim),
+                      head_dim)
+        s_idx, n_kv = s_idx * n_kv + pl.program_id(1), 1
     ps, turn = page_size, pages * page_size
     rows = n_kv * per_head
     kvl = lens_ref[s_idx]
@@ -1489,9 +1499,11 @@ def _paged_gqa_walk_kernel(pt_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
             col = jax.lax.rem(col, table_width)
         page = pt_ref[s_idx * table_width + col]
         at = pl.ds(pl.multiple_of(i * ps, ps), ps)
-        return (pltpu.make_async_copy(k_hbm.at[layer, page],
+        src = ((layer, page) if lanes is None
+               else (layer, page, slice(None), lanes))
+        return (pltpu.make_async_copy(k_hbm.at[src],
                                       k_buf.at[slot, at], sem.at[0, slot]),
-                pltpu.make_async_copy(v_hbm.at[layer, page],
+                pltpu.make_async_copy(v_hbm.at[src],
                                       v_buf.at[slot, at], sem.at[1, slot]))
 
     heads = [(slice(h * per_head, (h + 1) * per_head),
@@ -1515,12 +1527,12 @@ def _paged_gqa_walk_kernel(pt_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
         limit=limit, first=first, pv=pv).astype(o_ref.dtype)
 
 
-def _gqa_turn_pages(ps, lanes, mp, itemsize, rows):
-    """Pages a turn of the grouped walk: ``_DECODE_TURN_KEYS`` keys, halved
-    until the K and V tiles (double-buffered, and a float32 copy of a tile
-    that is not bfloat16), the scores, the probabilities and their parts fit
-    the walk's VMEM budget."""
-    pages = max(1, min(mp, _DECODE_TURN_KEYS // ps))
+def _gqa_turn_pages(ps, lanes, mp, itemsize, rows, keys=None):
+    """Pages a turn of the grouped walk: ``keys`` (``_DECODE_TURN_KEYS``)
+    keys, halved until the K and V tiles (double-buffered, and a float32 copy
+    of a tile that is not bfloat16), the scores, the probabilities and their
+    parts fit the walk's VMEM budget."""
+    pages = max(1, min(mp, (keys or _DECODE_TURN_KEYS) // ps))
     while pages > 1 and (4 * pages * ps * lanes * (itemsize + 2)
                          + 24 * rows * pages * ps
                          + 16 * rows * 128) > _DECODE_VMEM_BUDGET:
@@ -1671,14 +1683,17 @@ def paged_gqa_prefill_attention(q, k_pool, v_pool, pages, start, valid, *,
 # (slot, KV head) may bring its own short list of pages in place of the
 # slot's whole page-table row: ``sel_pages [S, Hkv, NS]`` in cache order, of
 # which the first ``sel_tokens [S, Hkv]`` tokens, counted page by page, are
-# valid (so every listed page but the last is whole, and entries past the
-# count are never read).  Without a selection the list is the slot's row and
-# the count ``kv_lens``, for every KV head.  One grid step = one (slot, KV
-# head, ``DECODE_PAGES_PER_STEP`` listed pages): the ``g`` query rows of the
-# group against those pages' ``[ps, Dh]`` lanes of that head, so ``Dh`` must be a whole number of lane
-# tiles (128) on the chip.  ``g = 1`` without a selection is NOT routed here:
-# it is the kernel above, which walks a slot's own pages 512 keys a turn;
-# this one steps over the whole list, eight pages a step (ROADMAP D17, S9).
+# valid (so every listed page but the last is whole, no entry past the count
+# is read, and a count of 0 gives exact zeros and reads no page).  Without a
+# selection the list is the slot's row and the count ``kv_lens``, for every
+# KV head.  The kernel is the grouped walk above over that LIST: one grid
+# step = one (slot, KV head), whose ``g`` query rows meet its listed pages
+# many to a turn, the next turn's copies in flight (``_walk_pages``).  A
+# copy takes that head's ``[ps, Dh]`` lanes of a page out of HBM, and a copy
+# is whole lane tiles, so ``Dh`` must be a multiple of 128 on the chip.
+# ``g = 1`` without a selection is NOT routed here: it is the plain kernel,
+# which copies a page's whole ``[ps, H*Dh]`` row for all heads at once and so
+# serves 64-lane heads too (ROADMAP D17 has what is left of the fold).
 # ---------------------------------------------------------------------------
 
 
@@ -1721,114 +1736,79 @@ def _paged_gqa_reference(q, k_pool, v_pool, pages, tokens, sm_scale, layer):
     return jnp.concatenate(outs, axis=1).astype(q.dtype)
 
 
-DECODE_PAGES_PER_STEP = 8
-
-
-def _paged_gqa_decode_kernel(pt_ref, lens_ref, q_ref, *refs, page_size,
-                             n_steps, n_kv, per_step, sm_scale):
-    """One grid step = one slot x one KV head x ``per_step`` listed pages
-    (each its own block of the pool, side by side in VMEM as one
-    ``[per_step * ps, Dh]`` tile): the group's ``[g, Dh]`` query rows against
-    them, online softmax over the walk in the scratch rows.  A grid step of
-    THIS kernel costs about a third of a microsecond whatever it does, so a
-    page a step made the walk the largest cost of MiniCPM-SALA's decode step
-    (13 of 29 ms with one page a step, 7.4 with eight; PERF.md, PR 28)."""
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    k_refs, v_refs = refs[:per_step], refs[per_step:2 * per_step]
-    o_ref, m_scr, l_scr, acc_scr = refs[2 * per_step:]
-    s_idx, h, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    width = per_step * page_size
-
-    @pl.when(j == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    n_tok = lens_ref[s_idx * n_kv + h]
-
-    @pl.when(j * width < n_tok)
-    def _body():
-        okc = (j * width + jax.lax.broadcasted_iota(
-            jnp.int32, (width, 1), 0)) < n_tok
-        okr = (j * width + jax.lax.broadcasted_iota(
-            jnp.int32, (1, width), 1)) < n_tok
-        q = q_ref[...].astype(jnp.float32)                       # [g, Dh]
-        k = jnp.concatenate([r[...] for r in k_refs], axis=0)    # [width, Dh]
-        v = jnp.concatenate([r[...] for r in v_refs], axis=0)
-        k = jnp.where(okc, k.astype(jnp.float32), 0.0)
-        v = jnp.where(okc, v.astype(jnp.float32), 0.0)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale       # [g, width]
-        s = jnp.where(okr, s, NEG_INF)
-        m_prev = m_scr[:, 0:1]
-        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-        p = jnp.where(okr, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = jnp.broadcast_to(
-            l_scr[:, 0:1] * alpha + p.sum(axis=1, keepdims=True), l_scr.shape)
-        acc_scr[...] = acc_scr[...] * alpha + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-
-    @pl.when(j == n_steps - 1)
-    def _finish():
-        o_ref[...] = (acc_scr[...] / jnp.maximum(l_scr[:, 0:1], 1e-30)
-                      ).astype(o_ref.dtype)
+def _listed_turn_pages(ps, head_dim, ns, itemsize, rows):
+    """Pages a turn of the LISTED walk, from the shapes alone.  A tile row
+    is one head's ``head_dim`` lanes, not the page's whole row, so
+    ``_DECODE_TURN_KEYS`` keys (tuned on rows of 512 to 1024 lanes) would be
+    a tile of an eighth of those bytes and a turn's fixed cost most of it.
+    The turn grows until a tile holds what 512 keys of a 1024-lane row do:
+    4096 keys at 128 lanes, where 512 / 1024 / 2048 / 4096 / 8192 keys read
+    1.15 / 0.92 / 0.84 / 0.80 / 0.86 ms a call at MiniCPM-SALA's shapes
+    (PERF.md section 6, PR 39).  No longer than the list, and under the
+    grouped walk's VMEM model (``_gqa_turn_pages``)."""
+    lanes = -(-head_dim // 128) * 128
+    return _gqa_turn_pages(ps, lanes, ns, itemsize, rows,
+                           keys=_DECODE_TURN_KEYS * max(1, 1024 // lanes))
 
 
 def _paged_gqa_pallas(q, k_pool, v_pool, pages, tokens, sm_scale, interpret,
                       layer):
+    """The grouped walk (``_paged_gqa_walk_kernel``) over a LIST a (slot, KV
+    head): a grid of ``(S, Hkv)`` walks, each the group's ``g`` query rows
+    against its own listed pages, that head's lanes of them, many pages a
+    turn.  The custom call is ``paged_gqa_decode_attention``."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
+
+    from .. import observability as obs
 
     S, Hq, Dh = q.shape
     n_kv, ns = pages.shape[1:]
     g = Hq // n_kv
     ps = k_pool.shape[2]
-    per = next(p for p in (DECODE_PAGES_PER_STEP, 4, 2, 1) if ns % p == 0)
-    pt_flat = pages.reshape(S * n_kv * ns)
-    lens = tokens.reshape(S * n_kv)
+    if not interpret and Dh % 128:
+        raise ValueError(
+            "the listed walk copies one KV head's lanes of a page out of "
+            "HBM: head_dim must be whole lane tiles (128), got %d" % Dh)
+    q = q.reshape(S, n_kv, g, Dh)
+    # a KV head's rows in whole sublane tiles of either dtype
+    g_pad = -(-g // 16) * 16
+    if g_pad != g:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, g_pad - g), (0, 0)))
+    turn_pages = _listed_turn_pages(ps, Dh, ns, k_pool.dtype.itemsize, g_pad)
+    steps = obs.counter("paged.gqa.grid_steps", labels={
+        "S": S, "heads": n_kv, "listed": ns, "ps": ps,
+        "turn": turn_pages * ps})
+    if not steps.value:
+        steps.inc(S * n_kv)
     kernel = functools.partial(
-        _paged_gqa_decode_kernel, page_size=ps, n_steps=ns // per, n_kv=n_kv,
-        per_step=per, sm_scale=sm_scale)
-
-    def page_of(i):
-        def index(s, h, j, pt, kl):
-            # entries past the count are never read: stay on the last listed
-            # page, whose block is already in VMEM (no DMA for a skip)
-            last = jnp.maximum((kl[s * n_kv + h] + ps - 1) // ps - 1, 0)
-            return (layer, pt[(s * n_kv + h) * ns
-                              + jnp.minimum(j * per + i, last)], 0, h)
-        return pl.BlockSpec((None, None, ps, Dh), index)
-
-    page_specs = [page_of(i) for i in range(per)]
-    rows = pl.BlockSpec((None, None, g, Dh),
-                        lambda s, h, j, pt, kl: (s, h, 0, 0))
+        _paged_gqa_walk_kernel, layer=layer, page_size=ps, pages=turn_pages,
+        table_width=ns, n_kv=n_kv, per_head=g_pad, group=g, q_tokens=1,
+        head_dim=Dh, window=None, sm_scale=sm_scale, listed=True)
+    rows = pl.BlockSpec((None, None, g_pad, Dh),
+                        lambda s, h, pt, kl: (s, h, 0, 0))
+    stack = pl.BlockSpec(memory_space=pl.ANY)
     (out,) = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(S, n_kv, ns // per),
-            in_specs=[rows] + page_specs + page_specs,
+            grid=(S, n_kv),
+            in_specs=[rows, stack, stack],
             out_specs=[rows],
             scratch_shapes=[
-                pltpu.VMEM((g, 128), jnp.float32),   # running max
-                pltpu.VMEM((g, 128), jnp.float32),   # running sum
-                pltpu.VMEM((g, Dh), jnp.float32),    # output accumulator
+                pltpu.VMEM((2, turn_pages * ps, Dh), k_pool.dtype),  # k tiles
+                pltpu.VMEM((2, turn_pages * ps, Dh), v_pool.dtype),  # v tiles
+                pltpu.SemaphoreType.DMA((2, 2)),                     # [k|v, tile]
             ]),
-        out_shape=[jax.ShapeDtypeStruct((S, n_kv, g, Dh), q.dtype)],
+        out_shape=[jax.ShapeDtypeStruct((S, n_kv, g_pad, Dh), q.dtype)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
         name="paged_gqa_decode_attention",
-    )(pt_flat, lens, q.reshape(S, n_kv, g, Dh), *([k_pool] * per),
-      *([v_pool] * per))
-    return out.reshape(S, Hq, Dh)
+    )(pages.reshape(S * n_kv * ns), tokens.reshape(S * n_kv), q, k_pool,
+      v_pool)
+    return out[:, :, :g].reshape(S, Hq, Dh)
 
 
 # ---------------------------------------------------------------------------

@@ -362,6 +362,47 @@ def test_grouped_kernels_in_interpret_mode(reference, g, selected):
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
 
 
+@pytest.mark.parametrize("turn_pages", [1, 2, 4])
+def test_a_decode_selection_walks_its_list_in_turns(reference, monkeypatch,
+                                                    turn_pages):
+    """The model's own list (``_listed``: the selected pages in cache order,
+    the count over them) through the listed walk at a turn shorter than the
+    list, as long and longer: the f32 reference's attention over the
+    selected blocks, and a slot with nothing selected exact zeros.  Pages
+    that no list names are NaN."""
+    monkeypatch.setattr(FA, "_listed_turn_pages", lambda *a: turn_pages)
+    rng = np.random.RandomState(8)
+    Hkv, Dh, ps, mp, S, g = 2, 16, 8, 6, 4, 16
+    lens = np.asarray([0, 17, 48, 33], np.int32)
+    tables = 1 + rng.permutation(S * mp).reshape(S, mp)
+    blocks = rng.rand(S, Hkv, mp) > 0.5
+    blocks[:, :, 0] = True
+    blocks &= (np.arange(mp)[None, None, :] * ps < lens[:, None, None])
+    for s in range(S):
+        if lens[s]:
+            blocks[s, :, (lens[s] - 1) // ps] = True
+    pool = rng.randn(2, 40, ps, Hkv, Dh).astype(np.float32)
+    named = np.zeros((40, Hkv), bool)
+    for s in range(S):
+        named[tables[s]] |= blocks[s].T
+    pool.transpose(0, 1, 3, 2, 4)[:, ~named] = np.nan
+    kp, vp = jnp.asarray(pool[0]), jnp.asarray(pool[1])
+    tables = jnp.asarray(tables, jnp.int32)
+    q = jnp.asarray(rng.randn(S, g * Hkv, Dh), jnp.float32)
+    d = dict(M._dims(CFG), n_listed=mp, B=ps)
+    sel = M._listed(d, jnp.asarray(blocks), jnp.asarray(lens), tables)
+    got = np.asarray(FA.paged_decode_attention(
+        q, kp, vp, tables, jnp.asarray(lens), impl="pallas", selection=sel))
+    assert np.isfinite(got).all() and not got[0].any()
+    for s in range(1, S):
+        k = jnp.nan_to_num(kp[tables[s]].reshape(mp * ps, Hkv, Dh))
+        v = jnp.nan_to_num(vp[tables[s]].reshape(mp * ps, Hkv, Dh))
+        want = reference.sparse_attention(
+            q[s:s + 1], k, v, jnp.asarray([lens[s] - 1]),
+            jnp.asarray(blocks[s:s + 1]), ps)
+        np.testing.assert_allclose(got[s], want[0], rtol=2e-5, atol=2e-6)
+
+
 def test_one_head_a_group_without_a_selection_is_the_kernel_it_was(monkeypatch):
     rng = np.random.RandomState(7)
     kp = jnp.asarray(rng.randn(20, 8, 2, 16), jnp.float32)

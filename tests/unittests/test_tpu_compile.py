@@ -183,13 +183,15 @@ def test_decode_turn_is_a_pure_function_of_the_shapes(ps, lanes, mp, itemsize,
 
 def test_the_grouped_kernel_is_refused_at_heads_of_64_lanes(
         one_chip, no_persistent_cache):
-    """Why ``paged_decode_attention`` keeps two kernels: the grouped one
-    (``paged_gqa_decode_attention``, eight listed pages a grid step) picks a
-    KV head's lanes of the folded page by its BlockSpec, which the TPU
-    lowering takes only in multiples of 128 lanes.  Transformer-base's heads
-    are 64 wide: with one query head a KV head and the whole table listed it
-    does not lower, so the kernel that slices lanes inside the page serves it
-    (PERF.md section 6, PR 28; a grouped kernel that slices lanes is S6a's)."""
+    """Why ``paged_decode_attention`` keeps two kernels: the listed walk
+    (``paged_gqa_decode_attention``, one walk a (slot, KV head)) copies ONE
+    head's lanes of a page out of HBM, and a copy takes whole lane tiles
+    (Mosaic: "Slice shape along dimension 3 must be aligned to tiling
+    (128)").  Transformer-base's heads are 64 wide: with one query head a KV
+    head and the whole table listed it is refused, in the kernel's own
+    words and before Mosaic is asked, so the kernel that copies whole
+    ``[ps, H*Dh]`` rows serves it (ROADMAP D17 has what is left of the
+    fold)."""
     def grouped(q, k_pool, v_pool, tables, lens):
         k_pool, v_pool, layer, n_kv = FA._stacked_pools(q, k_pool, v_pool, 0)
         pages, tokens = FA._head_lists(tables, lens, n_kv, None)
@@ -198,12 +200,45 @@ def test_the_grouped_kernel_is_refused_at_heads_of_64_lanes(
 
     stack = jax.ShapeDtypeStruct((1, _P, _PS, _H * _DH), jnp.bfloat16,
                                  sharding=one_chip)
-    with pytest.raises(Exception, match="divisible by 8 and 128"):
+    with pytest.raises(ValueError, match="whole lane tiles"):
         jax.jit(grouped).lower(
             jax.ShapeDtypeStruct((_S, _H, _DH), jnp.float32, sharding=one_chip),
             stack, stack,
             jax.ShapeDtypeStruct((_S, _MP), jnp.int32, sharding=one_chip),
             jax.ShapeDtypeStruct((_S,), jnp.int32, sharding=one_chip))
+
+
+@pytest.mark.parametrize("qdtype", [jnp.float32, jnp.bfloat16],
+                         ids=["q-f32", "q-bf16"])
+def test_listed_walk_compiles_for_v5e(one_chip, no_persistent_cache, qdtype):
+    """The selected-page decode attention of ``sala_longctx_decode`` at the
+    cell's own shapes — 64 slots, 32 query / 2 KV heads of 128, 64-token
+    pages, 128 listed pages a (slot, KV head), the stored bf16 stacks
+    ``[2, 24577, 64, 256]`` left in HBM, the second layer — is ONE custom
+    call, named as the benchmark's reader finds it, at the turn the chooser
+    picks there (64 pages = 4096 keys)."""
+    S, Hq, Hkv, Dh, ps, NS = 64, 32, 2, 128, 64, 128
+    assert FA._listed_turn_pages(ps, Dh, NS, 2, Hq // Hkv) == 64
+
+    def sds(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def f(q, k_pool, v_pool, pages, tokens):
+        return FA.paged_decode_attention(
+            q, k_pool, v_pool, None, None, impl="pallas", interpret=False,
+            layer=1, selection=(pages, tokens))
+
+    pool = sds((2, 24577, ps, Hkv * Dh), jnp.bfloat16)
+    text = jax.jit(f).lower(sds((S, Hq, Dh), qdtype), pool, pool,
+                            sds((S, Hkv, NS)), sds((S, Hkv))
+                            ).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "paged_gqa_decode_attention" in text
+    # the pools are passed once each, and nothing pool-sized is copied
+    call = next(line for line in text.splitlines()
+                if "tpu_custom_call" in line and " custom-call(" in line)
+    assert len(re.findall(r"%[\w.-]+", call.split(" custom-call(")[1]
+                          .split(")")[0])) == 5
 
 
 @pytest.mark.parametrize("chunk", [16, 512], ids=["chunk16", "chunk512"])
