@@ -144,6 +144,7 @@ MOE_COUNTERS = ("pairs", "experts_touched", "max_load")
 _GMM_ROWS = 128         # rows of one product inside a grid step
 _GMM_TILE_M = 512       # rows of the sorted pairs a grid step holds
 _GMM_TILE_N = 512       # output columns a grid step computes
+_GMM_VMEM_DEFAULT = 14 * 2 ** 20   # what fits the compiler's own scoped limit
 
 
 SCORINGS = ("sigmoid", "softmax")
@@ -279,6 +280,15 @@ def grouped_matmul(x, w, group_sizes, *, layer=None, impl=None,
     group, tile, offsets, n_items = _gmm_items(group_sizes, tile_m, n_tiles)
     lead = () if layer is None else (None,)
     at = () if layer is None else (int(layer),)
+    # both operand blocks twice (the next step's copy in flight), the output
+    # block twice, and the weight block once more as the value the products
+    # read; stated to the compiler only where it passes its default (a
+    # contraction of 4096: the blocks of narrower models fit as they are)
+    item = jnp.dtype(w.dtype).itemsize
+    need = (2 * item * (tile_m * K + K * tn) + 2 * 4 * tile_m * tn
+            + item * K * tn)
+    limit = ({} if need <= _GMM_VMEM_DEFAULT
+             else dict(vmem_limit_bytes=int(need + 16 * 2 ** 20)))
     (out,) = pl.pallas_call(
         functools.partial(_gmm_kernel, tile_m=tile_m, rows=rows),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -293,7 +303,7 @@ def grouped_matmul(x, w, group_sizes, *, layer=None, impl=None,
                                     lambda j, i, g, t, o, n: (t[i], j))]),
         out_shape=[jax.ShapeDtypeStruct((m_pad, N), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary"), **limit),
         interpret=interpret,
         name="moe_grouped_matmul",
     )(group, tile, offsets, n_items.reshape(1), x.astype(w.dtype), w)

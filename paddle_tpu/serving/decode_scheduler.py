@@ -289,8 +289,9 @@ class DecodeModel:
     must be shape-stable in everything but values.
     ``models.transformer.build_decode_model``,
     ``models.minicpm_sala.build_decode_model``,
-    ``models.deepseek_v3.build_decode_model`` and
-    ``models.mellum.build_decode_model`` are the in-repo producers.
+    ``models.deepseek_v3.build_decode_model``,
+    ``models.mellum.build_decode_model`` and
+    ``models.solar_open2.build_decode_model`` are the in-repo producers.
     """
 
     def __init__(self, decode_fn, prefill_chunk_fn, *, params=None,

@@ -366,6 +366,11 @@ class PagedKVCache:
         self.pools = self._zeros()
         _page_bytes.set(self.page_bytes)
         _state_bytes.set(self.state_bytes)
+        # by leaf where there is one: a model may keep several kinds of
+        # slot state, of shapes and dtypes of their own
+        for name, n in self.state_leaf_bytes().items():
+            _obs.gauge("serving.cache.state_leaf_bytes",
+                       labels={"leaf": name}).set(n)
         # by group where there is more than one: ``kv_*`` are the first's
         self._group_used = {}
         for name in (self.group_names if self.groups else ()):
@@ -450,6 +455,11 @@ class PagedKVCache:
     def state_bytes(self):
         """Bytes of all slot-indexed leaves together."""
         return self._nbytes(self._slot_leaves)
+
+    def state_leaf_bytes(self):
+        """Bytes of each slot-indexed leaf, by name."""
+        return {name: self._nbytes({name: leaf})
+                for name, leaf in self._slot_leaves.items()}
 
     # the two leaves of a model with K/V layers, by name (tools, tests and
     # the fault injectors read and poke them; the scheduler threads

@@ -714,3 +714,44 @@ def test_the_sigmoid_routers_program_is_the_one_it_was():
     assert "logistic" in str(plain) and "logistic" not in str(jax.make_jaxpr(
         lambda x, w: moe.route_topk(x, w, None, top_k=3, scoring="softmax"))(
             x, router["w"]))
+
+
+# sha256[:16] of ``moe_topk``'s jaxpr with every expert held, at the parent
+# commit (7cceb06), made by the same function on a checkout of it
+_WHOLE_LAYER_DIGESTS = {
+    ("sigmoid", "float32"): "da857afa94481356",
+    ("sigmoid", "bfloat16"): "39703a16a0a7bced",
+    ("softmax", "float32"): "377b82bc5a6f18e5",
+    ("softmax", "bfloat16"): "49f95ca8d4655be7",
+}
+
+
+@pytest.mark.parametrize("scoring,dtype", sorted(_WHOLE_LAYER_DIGESTS))
+def test_a_layer_that_holds_every_expert_traces_as_it_did(scoring, dtype):
+    """PR 40 serves a TRUE share through ``moe_topk`` and gave
+    ``grouped_matmul`` a stated VMEM limit at contractions of 4096: a caller
+    that holds every expert of a narrower model (``models/deepseek_v3.py``
+    with a sigmoid router, a bias and shared experts; ``models/mellum.py``
+    with a softmax router and neither) traces to the jaxpr it traced to, the
+    kernel's included."""
+    import hashlib
+    import re
+
+    from paddle_tpu.parallel import moe
+
+    E, D, F, T, k = 8, 64, 32, 12, 3
+    sig = scoring == "sigmoid"
+    x = jnp.zeros((T, D), dtype)
+    router = {"w": jnp.zeros((D, E), jnp.float32),
+              "bias": jnp.zeros((E,), jnp.float32) if sig else None}
+    experts = {"w_gu": jnp.zeros((2, E, D, 2 * F), dtype),
+               "w_down": jnp.zeros((2, E, F, D), dtype)}
+    shared = {"w_gu": jnp.zeros((D, 2 * F), dtype),
+              "w_down": jnp.zeros((F, D), dtype)} if sig else None
+    jaxpr = jax.make_jaxpr(lambda x, r, e, s: moe.moe_topk(
+        x, r, e, s, top_k=k, experts_held=(0, E), scale=2.5 if sig else 1.0,
+        token_mask=jnp.arange(T) < 10, layer=1, impl="pallas",
+        interpret=True, scoring=scoring))(x, router, experts, shared)
+    text = re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
+    assert (hashlib.sha256(text.encode()).hexdigest()[:16]
+            == _WHOLE_LAYER_DIGESTS[scoring, dtype])

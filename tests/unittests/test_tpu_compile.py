@@ -381,6 +381,61 @@ def test_grouped_matmul_compiles_for_v5e(one_chip, no_persistent_cache, rows):
         sds((d["E"],), jnp.int32)) == 2
 
 
+def test_kda_state_decode_compiles_for_v5e(one_chip, no_persistent_cache):
+    """The delta-rule state update at the shapes of the benchmark's
+    ``solar2_standing_decode`` cell (128 slots, 64 heads of 128 x 128 float32,
+    the stack of 3 layers addressed in place): one kernel, the 1.6 GB stack
+    aliased in and out with no copy of it and no layer sliced out."""
+    from paddle_tpu.parallel import kda
+
+    S, H, d = 128, 64, 128
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(state, q, k, v, g, beta, live):
+        return kda.kda_state_decode(state, q, k, v, g, beta, live, layer=1,
+                                    impl="pallas", interpret=False)
+
+    vec = sds((S, H, d))
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(
+        sds((3, S, H, d, d)), vec, vec, vec, vec, sds((S, H)),
+        sds((S,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "kda_state_decode" in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 3 * S * H * d * d * 4
+    assert mem.temp_size_in_bytes < 64 * 2 ** 20
+
+
+def test_grouped_matmul_compiles_at_a_contraction_of_4096(one_chip,
+                                                          no_persistent_cache):
+    """A hidden size of 4096 puts the two operand blocks past the compiler's
+    own scoped VMEM limit: the kernel states what it needs (and states
+    nothing where the blocks fit, so the narrower models' programs are as
+    they were)."""
+    from paddle_tpu.parallel import moe
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def product(x, w, sizes):
+        return moe.grouped_matmul(x, w, sizes, layer=3, impl="pallas",
+                                  interpret=False)
+
+    for rows in (1024, 4096):       # a decode step's and a chunk's pairs
+        assert _kernel_calls(product, sds((rows, 4096)),
+                             sds((4, 40, 4096, 2560)),
+                             sds((40,), jnp.int32)) == 1
+    narrow = jax.make_jaxpr(lambda x, w, s: moe.grouped_matmul(
+        x, w, s, impl="pallas", interpret=False))(
+            jnp.zeros((512, 2304), jnp.bfloat16),
+            jnp.zeros((8, 2304, 1792), jnp.bfloat16),
+            jnp.zeros((8,), jnp.int32))
+    assert "vmem_limit_bytes=None" in str(narrow)
+
+
 def test_interpret_follows_the_backend_in_one_place():
     """On this (CPU) backend the default is interpret / the reference
     engine; a kernel asked to compile here (interpret=False) must fail
