@@ -8,8 +8,11 @@ to XLA fusion, which is what the cuDNN fused kernels hand-coded.
 """
 from __future__ import annotations
 
+import weakref
+
 import numpy as np
 
+from .. import observability as obs
 from ..registry import register
 from .common import mixed_dtypes
 
@@ -263,9 +266,39 @@ def _lrn(ctx, op):
     del jnp
 
 
+# training dropouts whose mask has been counted: an op is lowered more than
+# once (the executor's discovery trace, a retrace at a new feed shape)
+_MASKS_COUNTED = weakref.WeakSet()
+
+
+def _keep_mask(ctx, op, keep_prob, shape):
+    """A training dropout's keep bits, drawn ONCE as a one-byte array that the
+    forward select and every backward consumer read.
+
+    The bits come from XLA's ``RngBitGenerator`` (a key of implementation
+    ``rbg``), which the compiler neither fuses nor duplicates.  A threefry
+    key's bits are a pure elementwise function of a counter, so XLA
+    re-derived all 20 rounds over the whole mask inside every fusion that read
+    it: three to five times a mask in a Transformer's step.  The seed is what
+    ``op_key`` gives every random op (positional, so a replay under
+    ``jax.checkpoint`` draws the same bits; a new key every step; pinned by a
+    ``seed`` attr), and the draw is ``jax.random.bernoulli``'s own: a 32-bit
+    uniform against ``keep_prob``."""
+    import jax
+
+    key = ctx.op_key(op, op.attrs.get("seed", 0) or 0)
+    words = jax.random.split(key).reshape(4)  # an rbg key is four words
+    keep = jax.random.bernoulli(
+        jax.random.wrap_key_data(words, impl="rbg"), keep_prob, shape)
+    if op not in _MASKS_COUNTED:
+        _MASKS_COUNTED.add(op)
+        obs.counter("dropout.masks", labels={"impl": "rbg"}).inc()
+        obs.counter("dropout.mask_elements").inc(int(np.prod(shape)))
+    return keep
+
+
 @register("dropout")
 def _dropout(ctx, op):
-    import jax
     import jax.numpy as jnp
 
     x = ctx.get_input(op, "X")
@@ -276,14 +309,11 @@ def _dropout(ctx, op):
         out = x * (1.0 - p) if impl == "downgrade_in_infer" else x
         ctx.set_output(op, "Out", out)
         return
-    key = ctx.op_key(op, op.attrs.get("seed", 0) or 0)
-    mask = jax.random.bernoulli(key, 1.0 - p, x.shape)
-    if impl == "upscale_in_train":
-        out = jnp.where(mask, x / max(1.0 - p, 1e-8), 0.0).astype(x.dtype)
-    else:
-        out = jnp.where(mask, x, 0.0).astype(x.dtype)
-    ctx.set_output(op, "Out", out)
-    ctx.set_output(op, "Mask", mask.astype(x.dtype))
+    keep = _keep_mask(ctx, op, 1.0 - p, x.shape)
+    kept = x / max(1.0 - p, 1e-8) if impl == "upscale_in_train" else x
+    ctx.set_output(op, "Out", jnp.where(keep, kept, 0.0).astype(x.dtype))
+    # for whoever fetches it; nothing in the step reads this copy
+    ctx.set_output(op, "Mask", keep.astype(x.dtype))
     ctx.copy_lengths(op.inputs["X"][0], op.outputs["Out"][0])
 
 

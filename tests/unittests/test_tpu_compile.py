@@ -436,6 +436,33 @@ def test_grouped_matmul_compiles_at_a_contraction_of_4096(one_chip,
     assert "vmem_limit_bytes=None" in str(narrow)
 
 
+def test_ffn_block_draws_each_dropout_mask_once_on_v5e(one_chip,
+                                                        no_persistent_cache):
+    """Transformer-base's FFN block at the training cells' widths, forward
+    and backward: one ``rng-bit-generator`` a mask, and no fusion re-derives
+    a mask with a counter hash (a threefry key's 20 rounds over
+    ``u32[8,2048,2048]`` sat in five fusions of this block)."""
+    import paddle_tpu as fluid
+    from paddle_tpu.jax_bridge import program_to_fn
+    from paddle_tpu.models import transformer as T
+
+    B, S, D, DI = 8, 2048, 512, 2048
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        x = fluid.layers.data(name="x", shape=[S, D], dtype="float32")
+        y = T.post_process(x, T.positionwise_feed_forward(x, DI, D, 0.1), 0.1)
+        fluid.optimizer.SGD(0.1).minimize(fluid.layers.reduce_mean(y))
+    params = {v.name: jax.ShapeDtypeStruct(v.shape, jnp.float32, sharding=one_chip)
+              for v in main.list_vars() if v.persistable and v.shape}
+    text = jax.jit(program_to_fn(main, [], return_state=True)).lower(
+        params, {"x": jax.ShapeDtypeStruct((B, S, D), jnp.float32, sharding=one_chip)},
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip),
+    ).compile().as_text()
+    drawn = re.findall(r"= u32\[([\d,]+)\]\S* rng-bit-generator\(", text)
+    assert sorted(drawn) == ["8,2048,2048", "8,2048,512"], drawn
+    assert not re.findall(r"= u32\[8,2048,\d+\]\S* xor\(", text)
+
+
 def test_interpret_follows_the_backend_in_one_place():
     """On this (CPU) backend the default is interpret / the reference
     engine; a kernel asked to compile here (interpret=False) must fail
