@@ -46,7 +46,8 @@ import numpy as np
 from .minicpm_sala import _logits, _mm, _rms
 
 __all__ = ["params", "prefill_chunk", "decode_step", "build_decode_model",
-           "cache_layout", "rope_inverse_frequencies", "STEP_COUNTERS"]
+           "cache_layout", "group_layout", "rope_inverse_frequencies",
+           "STEP_COUNTERS"]
 
 STEP_COUNTERS = ("moe.pairs", "moe.experts_touched", "moe.max_load",
                  "kv.full_tokens_read", "kv.window_tokens_read")
@@ -119,27 +120,34 @@ def _dims(cfg):
     return d
 
 
+def group_layout(kinds, window, width):
+    """The page groups and leaves of a model whose ``kinds`` of layers keep K
+    and V rows of ``width`` values: each kind's in a group of its own, the
+    sliding layers' with ``window`` (``DecodeModel``'s ``page_groups`` and
+    ``page_pools``)."""
+    groups, pools = {}, {}
+    for kind in KINDS:           # the full group first: the cache's own
+        n = list(kinds).count(kind)
+        if not n:
+            continue
+        group, k, v = GROUPS[kind]
+        groups[group] = dict(
+            window=window if kind == "sliding_attention" else None)
+        for leaf in (k, v):
+            pools[leaf] = dict(layers=n, tokens_per_row=1, width=width,
+                               dtype=None, group=group)
+    if "full" not in groups:
+        raise ValueError("a model of sliding layers alone is not written "
+                         "here: the cache's first group keeps every position")
+    return dict(page_groups=groups, page_pools=pools)
+
+
 def cache_layout(cfg):
     """What the model keeps in the cache, as ``DecodeModel`` states it: K and
     V rows of each kind of layer in a page group of its own, the sliding
     layers' with the window."""
     d = _dims(cfg)
-    groups, pools = {}, {}
-    for kind in KINDS:           # the full group first: the cache's own
-        n = d["kinds"].count(kind)
-        if not n:
-            continue
-        group, k, v = GROUPS[kind]
-        groups[group] = dict(
-            window=d["W"] if kind == "sliding_attention" else None)
-        for leaf in (k, v):
-            pools[leaf] = dict(layers=n, tokens_per_row=1,
-                               width=d["Hkv"] * d["Dh"], dtype=None,
-                               group=group)
-    if "full" not in groups:
-        raise ValueError("a model of sliding layers alone is not written "
-                         "here: the cache's first group keeps every position")
-    return dict(page_groups=groups, page_pools=pools)
+    return group_layout(d["kinds"], d["W"], d["Hkv"] * d["Dh"])
 
 
 def params(cfg, seed, dtype="bfloat16"):

@@ -283,7 +283,9 @@ def grouped_matmul(x, w, group_sizes, *, layer=None, impl=None,
     # both operand blocks twice (the next step's copy in flight), the output
     # block twice, and the weight block once more as the value the products
     # read; stated to the compiler only where it passes its default (a
-    # contraction of 4096: the blocks of narrower models fit as they are)
+    # contraction of 4096, or of 3072 under a chunk's 512-row tiles: 17.8 MB;
+    # the blocks of narrower models, and 3072 at a decode step's 256 rows,
+    # fit as they are)
     item = jnp.dtype(w.dtype).itemsize
     need = (2 * item * (tile_m * K + K * tn) + 2 * 4 * tile_m * tn
             + item * K * tn)

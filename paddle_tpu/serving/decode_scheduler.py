@@ -253,7 +253,12 @@ class DecodeModel:
     does not decode (masked, scratch writes, slot state left as it is).
     A model with ``step_counters`` returns a third value, one int32 per
     name: the scheduler reads them with the tokens (one array a step) and
-    adds each to the counter ``serving.decode.<name>``.
+    adds each to the counter ``serving.decode.<name>``.  Its
+    ``prefill_chunk_fn`` may return the same third value (the scheduler sees
+    it when it traces the chunk program): it is read with the chunk's token,
+    and the counters are then told apart by the label the loop's intervals
+    have: ``serving.decode.<name>{chunk="0"}`` the decode steps',
+    ``{chunk="1"}`` the chunk programs'.
 
     ``cache`` is the cache's pytree, a dict of arrays
     (``kv_cache.PagedKVCache.pools``): ``"k"`` and ``"v"`` in the stored
@@ -290,8 +295,9 @@ class DecodeModel:
     ``models.transformer.build_decode_model``,
     ``models.minicpm_sala.build_decode_model``,
     ``models.deepseek_v3.build_decode_model``,
-    ``models.mellum.build_decode_model`` and
-    ``models.solar_open2.build_decode_model`` are the in-repo producers.
+    ``models.mellum.build_decode_model``,
+    ``models.solar_open2.build_decode_model`` and
+    ``models.afmoe.build_decode_model`` are the in-repo producers.
     """
 
     def __init__(self, decode_fn, prefill_chunk_fn, *, params=None,
@@ -730,9 +736,14 @@ class DecodeScheduler:
             cfg.max_seq_len, dtype=cfg.kv_dtype,
             page_pools=model.page_pools, slot_state=model.slot_state,
             num_slots=cfg.num_slots, device=device, page_groups=groups)
-        self._step_counters = [
-            _obs.counter("serving.decode." + name)
-            for name in model.step_counters]
+        self._admit_waits = {
+            g: _obs.counter("serving.decode.admit_waits_for_pages",
+                            {"group": g})
+            for g in self._cache.group_names}
+        # whether the chunk program returns the model's step counters too
+        # (seen when it is traced), and the counters' cells by program
+        self._chunk_counts = False
+        self._count_cells = {}
         # decode steps dispatched and not yet read, oldest first: one
         # between iterations, two for a moment inside one (step n+1 goes
         # out, then step n is read); and what the decode program takes in
@@ -934,7 +945,7 @@ class DecodeScheduler:
         if key[0] == "chunk":
             def chunk(params, pools, tokens, start, valid, chunk_pages,
                       gather_pages, slot, seed, temp):
-                logits, pools = model.prefill_chunk_fn(
+                logits, pools, *counts = model.prefill_chunk_fn(
                     params, tokens, start, valid, pools, chunk_pages,
                     gather_pages, slot)
                 # the first generated token sits at absolute position
@@ -944,11 +955,32 @@ class DecodeScheduler:
                 # and monolithic first tokens match bitwise
                 kk = jax.random.fold_in(jax.random.PRNGKey(seed),
                                         start + valid)
-                return _sample_token(logits, kk, temp, top_k), pools
+                tok = _sample_token(logits, kk, temp, top_k)
+                if counts:
+                    # the model's chunk counters ride the token's readback
+                    self._chunk_counts = True
+                    tok = jnp.concatenate(
+                        [tok[None], counts[0].astype(jnp.int32)])
+                return tok, pools
 
             return jax.jit(chunk, donate_argnums=pools_arg)
 
         raise KeyError(key)
+
+    def _step_counters(self, chunk):
+        """The cells of the model's step counters for a decode step
+        (``chunk`` 0) or a chunk program (1).  Where the chunk program
+        counts too, the label ``chunk`` tells the two apart; a model whose
+        decode step alone counts keeps the unlabelled cells.  A slot decodes
+        only behind its own chunk, so the chunk program has been traced
+        before the first counts are read."""
+        cells = self._count_cells.get(chunk)
+        if cells is None:
+            labels = {"chunk": chunk} if self._chunk_counts else None
+            cells = self._count_cells[chunk] = [
+                _obs.counter("serving.decode." + name, labels)
+                for name in self.model.step_counters]
+        return cells
 
     def _chunk_widths(self):
         """The prefill-chunk widths this config can dispatch.
@@ -1718,7 +1750,11 @@ class DecodeScheduler:
                     self._completed += 1
                     continue
                 # pool exhausted: hold the head (FIFO) until a retirement
-                # frees its reservation
+                # frees its reservation; an admission is counted once, against
+                # each group that was short when it first waited
+                if hol is None:
+                    for g in short or [cache.primary_group]:
+                        self._admit_waits[g].inc()
                 self._park_hol(req, cached_pages, hashes)
                 return
             self._place(req, cached_pages + pages,
@@ -1871,22 +1907,23 @@ class DecodeScheduler:
                         seed, temp)
                 with tel.span("serving.decode.prefill.wait"):
                     if ahead is None:
-                        first = int(np.asarray(tok))
+                        read = np.asarray(tok)
                     else:
                         with tel.span("serving.decode.prefill.behind"):
                             ahead.block_until_ready()
                         with tel.span("serving.decode.prefill.chunk"):
-                            first = int(np.asarray(tok))
-            return first, pools
+                            read = np.asarray(tok)
+            return read.reshape(-1), pools
 
         try:
             chunk_wall = time.time()
             # the chunk program, dispatch to readback (retries included)
             with tel.span("serving.decode.prefill", bucket=width,
                           rows=valid, start=start, seq=req.seq) as prefill:
-                first, pools = _resilience.call_with_retry(
+                read, pools = _resilience.call_with_retry(
                     attempt, policy=self._prefill_policy,
                     on_retry=self._note_prefill_retry(req))
+                first = int(read[0])
         except Exception as exc:  # noqa: BLE001 — worker must survive
             self._retire(idx, error=exc)
             self._recover_pools(exc)
@@ -1924,6 +1961,9 @@ class DecodeScheduler:
             slot.kv_len = slot.prefill_pos
             if slot.more:
                 self._release_window(idx, slot)
+            # the model's chunk counters came back behind the token
+            for c, n in zip(self._step_counters(1), read[1:]):
+                c.inc(int(n))
             _prefills.inc()
             _prefill_tokens.inc(valid)
             if cfg.prefix_cache and slot.hashes:
@@ -2406,7 +2446,7 @@ class DecodeScheduler:
             sampled = np.array(sent.sampled)
             sent.pools_before = sent.out = sent.sampled = None
             # the model's step counters came back behind the tokens
-            for c, n in zip(self._step_counters, sampled[cfg.num_slots:]):
+            for c, n in zip(self._step_counters(0), sampled[cfg.num_slots:]):
                 c.inc(int(n))
             if self._breaker is not None:
                 self._breaker.record_success()

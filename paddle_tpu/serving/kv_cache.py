@@ -23,9 +23,9 @@ position, and ``k`` / ``v`` and leaves that name no group are its).  Every
 further group (:class:`PageGroup`) has its own pool length, scratch page 0,
 free list, refcounts and RESERVATION count, and the scheduler keeps a page
 table a group.  A group with a ``window`` keeps a sequence's last ``W``
-positions: a slot reserves a bound that does not grow with the sequence
-(``slot_bound``), pages are handed out as its positions reach them and go back
-to the group's free list the moment every position on them is out of every
+positions: a slot reserves a bound that does not grow with the sequence (or
+its own pages, where it is shorter than the bound: ``slot_bound``), pages are
+handed out as its positions reach them and go back to the group's free list the moment every position on them is out of every
 later query's window, so its table is a RING of the bound's width (logical
 page ``p`` in column ``p % width``).  The prefix cache, sessions and handoff
 address the first group's pages only and are refused for a model with a
@@ -126,6 +126,14 @@ _page_bytes = _obs.gauge("serving.cache.page_bytes")
 _state_bytes = _obs.gauge("serving.cache.state_bytes")
 
 
+def _group_counters(group):
+    """``(pages_taken, pages_released)`` of one page group: pages its
+    allocator handed out, and pages a LIVE sequence gave back because they
+    fell out of the group's window (a retirement's pages are in neither)."""
+    return tuple(_obs.counter("serving.cache." + name, {"group": group})
+                 for name in ("pages_taken", "pages_released"))
+
+
 def write_token_kv(k_pool, v_pool, k_tok, v_tok, pages, offsets):
     """Scatter one decode step's per-slot token k/v into the pools.
 
@@ -166,6 +174,8 @@ class PageGroup:
         self._used = 0
         self.reserved = 0
         self.released = 0       # pages given back by a window, ever
+        self.taken = 0          # pages handed out, ever
+        self._taken, self._released = _group_counters(name)
 
     @property
     def free_pages(self):
@@ -181,11 +191,14 @@ class PageGroup:
 
     def slot_bound(self, tokens, widest_chunk):
         """Pages a sequence of ``tokens`` positions reserves: every page of
-        them, or with a window the most a slot can hold live at once — the
-        window plus a chunk in flight, unaligned — whatever its length."""
+        them, and with a window no more than a slot can hold live at once —
+        the window plus a chunk in flight, unaligned — however long it is (a
+        sequence shorter than that never holds more than its own pages)."""
+        whole = -(-int(tokens) // self.page_size)
         if self.window is None:
-            return -(-int(tokens) // self.page_size)
-        return -(-(self.window + int(widest_chunk)) // self.page_size) + 1
+            return whole
+        return min(whole, -(-(self.window + int(widest_chunk))
+                            // self.page_size) + 1)
 
     def first_live_page(self, next_pos):
         """The first logical page that a query at ``next_pos`` or later can
@@ -216,6 +229,8 @@ class PageGroup:
         for p in pages:
             self._rc[p] = 1
         self._used += n
+        self.taken += n
+        self._taken.inc(n)
         return pages
 
     def free(self, pages, released=False):
@@ -230,6 +245,7 @@ class PageGroup:
         self._used -= len(pages)
         if released:
             self.released += len(pages)
+            self._released.inc(len(pages))
 
     def stats(self):
         """The group's allocator snapshot with the same partition sweep as
@@ -243,7 +259,8 @@ class PageGroup:
         return {"num_pages": self.num_pages, "window": self.window,
                 "used_pages": self._used, "free_pages": len(self._free),
                 "reserved_pages": self.reserved,
-                "released_pages": self.released, "rc_errors": errors,
+                "released_pages": self.released, "taken_pages": self.taken,
+                "rc_errors": errors,
                 "rc_sum_matches": sum(self._rc) == self._used}
 
 
@@ -323,6 +340,9 @@ class PagedKVCache:
             name: PageGroup(name, spec["num_pages"], self.page_size,
                             spec.get("window"))
             for name, spec in page_groups.items()}
+        # the first group's own count of pages handed out (it releases none)
+        self.taken = 0
+        self._taken = _group_counters(self.primary_group)[0]
         self._group_of = {}
         if self.num_layers:
             self._page_leaves = {"k": (self.pool_shape, self.dtype),
@@ -631,6 +651,8 @@ class PagedKVCache:
             self._rc[p] = 1
             pages.append(p)
         self._used += n
+        self.taken += n
+        self._taken.inc(n)
         _cached_pages.set(len(self._lru))
         return pages
 
@@ -849,7 +871,9 @@ class PagedKVCache:
             # number over both would mean neither
             own = {k: st[k] for k in ("num_pages", "used_pages", "free_pages",
                                       "rc_errors", "rc_sum_matches")}
-            st["groups"] = dict({self.primary_group: dict(own, window=None)},
+            # the first group keeps every position: it releases nothing
+            own.update(window=None, taken_pages=self.taken, released_pages=0)
+            st["groups"] = dict({self.primary_group: own},
                                 **{n: g.stats() for n, g in
                                    self.groups.items()})
         return st

@@ -28,6 +28,7 @@ PS, W, V = 4, 6, 32     # page, window, vocabulary
 def test_a_group_reserves_allocates_frees_and_sweeps():
     g = kv_cache.PageGroup("window", num_pages=6, page_size=PS, window=W)
     assert g.slot_bound(10 ** 6, widest_chunk=8) == -(-(W + 8) // PS) + 1 == 5
+    assert g.slot_bound(2 * PS - 1, widest_chunk=8) == 2    # its own pages
     assert g.can_reserve(5) and not g.can_reserve(6)
     g.reserve(5)
     assert not g.can_reserve(1)
@@ -349,7 +350,10 @@ def test_a_request_no_group_can_ever_hold_fails_at_once():
         _model(), _config(num_pages={"full": 33, "window": 4}))
     with pytest.raises(ServingError, match="group 'window'"):
         sched.submit(np.arange(1, 6, dtype=np.int32),
-                     max_new_tokens=2).result(timeout=60)
+                     max_new_tokens=20).result(timeout=60)
+    # one that fits its own pages under the bound reserves those alone
+    assert len(sched.submit(np.arange(1, 6, dtype=np.int32),
+                            max_new_tokens=2).result(timeout=60)) == 2
     sched.stop()
 
 

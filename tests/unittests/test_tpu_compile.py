@@ -577,3 +577,151 @@ def test_step_program_temporaries_are_small(step_programs, program):
     mem = step_programs[program].memory_analysis()
     assert mem.alias_size_in_bytes == 2 * 2 * _POOL_ELEMS   # both bf16 pools
     assert mem.temp_size_in_bytes < 64 * 2 ** 20
+
+
+# ---------------------------------------------------------------------------
+# The AFMoE family's step programs at the sizes of `trinity_open_mixedlen`
+# (chipbench/configs/trinity_large_preview.json): 48 query heads over 8 KV
+# heads (6 a group, where the other grouped cells run 8), a window of 4096
+# over pages of 64 (a ring of 73 columns), and expert matrices whose
+# contraction AND width are 3072: the two operand blocks of the grouped
+# product pass the compiler's own scoped VMEM limit, so the kernel states
+# what it needs.
+# ---------------------------------------------------------------------------
+
+def _afmoe_shapes():
+    import json
+    import os
+
+    from paddle_tpu.models import afmoe as A
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(
+            root, "chipbench/configs/trinity_large_preview.json")) as f:
+        cfg = json.load(f)
+    return cfg, A._dims(cfg)
+
+
+def test_grouped_matmul_states_its_vmem_at_a_contraction_of_3072(
+        one_chip, no_persistent_cache):
+    """Both products of a held expert share at a decode step's 256 pairs
+    (13.6 MB of operand and output blocks: under the scoped default of 14
+    MiB, nothing stated) and at a chunk's 2048 (512-row tiles, 17.8 MB: the
+    kernel states what it needs)."""
+    from paddle_tpu.parallel import moe
+
+    cfg, d = _afmoe_shapes()
+    held = d["held"][1] - d["held"][0]
+    n_moe = d["L"] - d["n_dense"]
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def both(x, w_gu, w_down, sizes):
+        gu = moe.grouped_matmul(x, w_gu, sizes, layer=n_moe - 1,
+                                impl="pallas", interpret=False)
+        act = (jax.nn.silu(gu[:, :d["Fm"]]) * gu[:, d["Fm"]:]).astype(x.dtype)
+        return moe.grouped_matmul(act, w_down, sizes, layer=n_moe - 1,
+                                  impl="pallas", interpret=False)
+
+    for rows in (cfg["slots"], cfg["chunk"]):
+        assert _kernel_calls(
+            both, sds((rows * d["k"], d["D"])),
+            sds((n_moe, held, d["D"], 2 * d["Fm"])),
+            sds((n_moe, held, d["Fm"], d["D"])),
+            sds((held,), jnp.int32)) == 2
+    for rows, stated in ((256, False), (2048, True)):
+        text = str(jax.make_jaxpr(lambda x, w, s: moe.grouped_matmul(
+            x, w, s, impl="pallas", interpret=False))(
+                jnp.zeros((rows, 3072), jnp.bfloat16),
+                jnp.zeros((2, 3072, 512), jnp.bfloat16),
+                jnp.zeros((2,), jnp.int32)))
+        assert ("vmem_limit_bytes=None" not in text) == stated
+
+
+_AFMOE_PROGRAMS = ("decode", "chunk128", "chunk512")
+
+
+@pytest.fixture(scope="module")
+def afmoe_programs(one_chip):
+    """name -> the family's compiled step program at the cell's sizes, the
+    cache (parameter 1) donated as the scheduler donates it."""
+    from paddle_tpu import core
+    from paddle_tpu.models import afmoe as A
+
+    cfg, d = _afmoe_shapes()
+    bf, f32 = jnp.bfloat16, jnp.float32
+
+    def sds(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    D, L, Le = d["D"], d["L"], d["L"] - d["n_dense"]
+    n_q, n_kv = d["H"] * d["Dh"], d["Hkv"] * d["Dh"]
+    held = d["held"][1] - d["held"][0]
+
+    def layer(i):
+        wide, names = ((d["F"], ("d_gu", "d_down")) if i < d["n_dense"]
+                       else (d["Fs"], ("s_gu", "s_down")))
+        return {"w_in": sds((D, 2 * n_q + 2 * n_kv), bf),
+                "wo": sds((n_q, D), bf), names[0]: sds((D, 2 * wide), bf),
+                names[1]: sds((wide, D), bf)}
+
+    params = dict(
+        {n: sds((L, D), f32) for n in ("ln_in", "ln_post_attn", "ln_pre_mlp",
+                                       "ln_post_mlp")},
+        embed=sds((d["V"], D), bf), head=sds((D, d["V"]), bf),
+        norm_f=sds((D,), f32), qn=sds((L, d["Dh"]), f32),
+        kn=sds((L, d["Dh"]), f32), router_w=sds((Le, D, d["E"]), f32),
+        router_b=sds((Le, d["E"]), f32), layers=[layer(i) for i in range(L)],
+        e_gu=sds((Le, held, D, 2 * d["Fm"]), bf),
+        e_down=sds((Le, held, d["Fm"], D), bf))
+    S, ps, C = cfg["slots"], cfg["page"], cfg["chunk"]
+    cache = {leaf: sds((spec["layers"], cfg["num_pages"][spec["group"]], ps,
+                        spec["width"]), bf)
+             for leaf, spec in A.cache_layout(cfg)["page_pools"].items()}
+    mp = {"full": cfg["max_seq_len"] // ps,
+          "window": -(-(d["W"] + C) // ps) + 1}
+
+    def by_group(make):
+        return {g: make(n) for g, n in mp.items()}
+
+    def decode(p, cache, tokens, positions, tables, kv_lens):
+        return A.decode_step(p, tokens, positions, cache, tables, kv_lens,
+                             cfg=cfg)
+
+    def chunk(p, cache, tokens, start, valid, written, gathered, slot):
+        return A.prefill_chunk(p, tokens, start, valid, cache, written,
+                               gathered, slot, cfg=cfg)
+
+    args = {"decode": (decode, (sds((S,)), sds((S,)),
+                                by_group(lambda n: sds((S, n))), sds((S,))))}
+    for w in (128, 512):    # _chunk_widths() of chunk 512, buckets 128/512/..
+        args["chunk%d" % w] = (chunk, (
+            sds((w,)), sds(()), sds(()), by_group(lambda n: sds((w // ps,))),
+            by_group(lambda n: sds((n,))), sds(())))
+    pool_bytes = sum(2 * int(np.prod(a.shape)) for a in cache.values())
+    with _persistent_cache_off(), pytest.MonkeyPatch.context() as patch:
+        patch.setattr(FA, "cpu_backend", lambda: False)
+        patch.setattr(core, "cpu_backend", lambda: False)
+        compiled = {name: jax.jit(fn, donate_argnums=(1,)).lower(
+                        params, cache, *rest).compile()
+                    for name, (fn, rest) in args.items()}
+    return compiled, pool_bytes
+
+
+@pytest.mark.parametrize("program", _AFMOE_PROGRAMS)
+def test_afmoe_step_program_compiles_for_v5e(afmoe_programs, program):
+    """Five walks (four over the ring, one over the whole table, each named
+    after its kind) and two grouped products an expert layer; the cache is
+    aliased whole, and what the program adds to weights and cache fits the
+    chip beside them."""
+    compiled, pool_bytes = afmoe_programs
+    text = compiled[program].as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 5 + 2 * 4
+    assert "paged_gqa_full_attention" in text
+    assert "paged_gqa_window_attention" in text
+    mem = compiled[program].memory_analysis()
+    assert mem.alias_size_in_bytes == pool_bytes
+    assert mem.temp_size_in_bytes < 128 * 2 ** 20
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14.5e9
