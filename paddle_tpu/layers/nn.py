@@ -1643,11 +1643,17 @@ def hsigmoid(input, label, num_classes, param_attr=None, bias_attr=None, name=No
 
 
 def flash_attention(q, k, v, kv_lens=None, causal=False, sequence_parallel=True,
-                    sp_engine="auto", name=None):
-    """Fused flash attention over [batch, heads, time, head_dim] tensors
-    (pallas TPU kernel; see parallel/flash_attention.py).  ``kv_lens``
-    ([batch] int) applies a key padding mask without building a [T, S]
-    bias.  No reference analog — the reference composes matmul+softmax.
+                    sp_engine="auto", name=None, n_head=None):
+    """Fused flash attention (pallas TPU kernel; see
+    parallel/flash_attention.py).  With ``n_head`` q, k, v are the
+    projections' own rows, [batch, time, n_head * head_dim] (head h the lanes
+    ``h * head_dim : (h + 1) * head_dim``, what ``fc`` gives), and so is the
+    result: no head is split off or merged back around the kernel, which on
+    a TPU is a pass over every tensor each way.  Without it they are [batch,
+    heads, time, head_dim], and the same kernel runs behind a transpose.
+    ``kv_lens`` ([batch] int) applies a key padding mask without building a
+    [T, S] bias.  No reference analog — the reference composes
+    matmul+softmax.
 
     Under a ``ParallelExecutor`` whose ``mesh_shape`` carries a
     non-trivial ``sp`` axis, this op runs sequence-parallel: the time
@@ -1662,12 +1668,19 @@ def flash_attention(q, k, v, kv_lens=None, causal=False, sequence_parallel=True,
     inputs = {"Q": [q], "K": [k], "V": [v]}
     if kv_lens is not None:
         inputs["KVLens"] = [kv_lens]
+    attrs = {"causal": causal, "sequence_parallel": bool(sequence_parallel),
+             "sp_engine": sp_engine}
+    if n_head:
+        if len(q.shape) != 3 or int(q.shape[-1]) % int(n_head):
+            raise ValueError(
+                "flash_attention(n_head=%d) takes [batch, time, n_head * "
+                "head_dim] rows, got q of shape %s" % (n_head, list(q.shape)))
+        attrs["n_head"] = int(n_head)
     helper.append_op(
         type="flash_attention",
         inputs=inputs,
         outputs={"Out": [out]},
-        attrs={"causal": causal, "sequence_parallel": bool(sequence_parallel),
-               "sp_engine": sp_engine},
+        attrs=attrs,
     )
     return out
 

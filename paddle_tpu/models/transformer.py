@@ -82,7 +82,12 @@ def multi_head_attention(
     kv_lens=None,
 ):
     """Reference transformer_model.py:45 multi_head_attention.  [B,T,D] in,
-    [B,T,D] out; heads split via reshape+transpose (layout-only, free on TPU).
+    [B,T,D] out.  With ``use_flash`` (and no ``cache``) the flash kernels read
+    the projections' own ``[B, T, H * d]`` rows and write the output
+    projection's: no reshape and no transpose on that path (around a Mosaic
+    kernel a transpose is a real pass over the tensor in HBM, 180 of them a
+    training step before PR 43).  The matmul-softmax path splits heads via
+    reshape+transpose, which XLA folds into the matmuls' layouts.
     ``cache`` (dict with 'k','v' variables) enables incremental decode."""
     keys = queries if keys is None else keys
     values = keys if values is None else values
@@ -90,6 +95,12 @@ def multi_head_attention(
     q = layers.fc(input=queries, size=d_key * n_head, num_flatten_dims=2, bias_attr=False)
     k = layers.fc(input=keys, size=d_key * n_head, num_flatten_dims=2, bias_attr=False)
     v = layers.fc(input=values, size=d_value * n_head, num_flatten_dims=2, bias_attr=False)
+
+    if use_flash and cache is None:
+        # fused pallas kernel: padding via kv_lens, no [T,S] bias tensor
+        ctx = layers.flash_attention(q, k, v, kv_lens=kv_lens, causal=flash_causal,
+                                     n_head=n_head)
+        return layers.fc(input=ctx, size=d_model, num_flatten_dims=2, bias_attr=False)
 
     def split_heads(x, d):
         b, t = x.shape[0], x.shape[1]
@@ -104,17 +115,13 @@ def multi_head_attention(
         k = cache["k"] = layers.concat([cache["k"], k], axis=2)
         v = cache["v"] = layers.concat([cache["v"], v], axis=2)
 
-    if use_flash and cache is None:
-        # fused pallas kernel: padding via kv_lens, no [T,S] bias tensor
-        ctx = layers.flash_attention(q, k, v, kv_lens=kv_lens, causal=flash_causal)
-    else:
-        product = layers.matmul(x=q, y=k, transpose_y=True, alpha=d_key**-0.5)
-        if attn_bias is not None:
-            product = layers.elementwise_add(x=product, y=attn_bias)
-        weights = layers.softmax(product)
-        if dropout_rate:
-            weights = layers.dropout(weights, dropout_prob=dropout_rate, is_test=False)
-        ctx = layers.matmul(weights, v)  # [B,H,Tq,dv]
+    product = layers.matmul(x=q, y=k, transpose_y=True, alpha=d_key**-0.5)
+    if attn_bias is not None:
+        product = layers.elementwise_add(x=product, y=attn_bias)
+    weights = layers.softmax(product)
+    if dropout_rate:
+        weights = layers.dropout(weights, dropout_prob=dropout_rate, is_test=False)
+    ctx = layers.matmul(weights, v)  # [B,H,Tq,dv]
     ctx = layers.transpose(ctx, perm=[0, 2, 1, 3])
     b, t = queries.shape[0], queries.shape[1]
     ctx = layers.reshape(x=ctx, shape=[b if b and b > 0 else -1, t, n_head * d_value])

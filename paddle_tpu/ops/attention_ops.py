@@ -14,9 +14,16 @@ from ..registry import register
 def _flash_attention(ctx, op):
     import jax.numpy as jnp
 
-    from ..parallel.flash_attention import flash_attention
+    from ..parallel.flash_attention import (_from_rows, _to_rows,
+                                            flash_attention,
+                                            flash_attention_rows)
 
-    q = ctx.get_input(op, "Q")  # [B, H, T, D]
+    # with an ``n_head`` attribute q, k, v and the result are the projections'
+    # own rows [B, T, H * D] (head h the lanes h * D : (h + 1) * D) and no
+    # head is split off or merged back around the kernels; without it (a
+    # program saved before the attribute existed) they are [B, H, T, D]
+    n_head = int(op.attrs.get("n_head") or 0)
+    q = ctx.get_input(op, "Q")
     k = ctx.get_input(op, "K")
     v = ctx.get_input(op, "V")
     kv_lens = ctx.get_input(op, "KVLens", None)  # [B] int, optional
@@ -38,7 +45,12 @@ def _flash_attention(ctx, op):
         mesh = ctx.mesh
         axis_sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
         sp = int(axis_sizes.get("sp", 1))
-        if sp > 1 and kv_lens is None and q.shape[2] % sp == 0:
+        T = q.shape[1] if n_head else q.shape[2]
+        if sp > 1 and kv_lens is None and T % sp == 0:
+            if n_head:
+                # the sequence-parallel engines keep [B, H, T, D], behind a
+                # transpose of their own
+                q, k, v = (_from_rows(x, n_head) for x in (q, k, v))
             engine = op.attrs.get("sp_engine", "auto")
             if engine == "auto":
                 engine = "ulysses" if q.shape[1] % sp == 0 else "ring"
@@ -50,26 +62,31 @@ def _flash_attention(ctx, op):
                 from ..parallel.ring_attention import ring_attention_sharded
 
                 out = ring_attention_sharded(q, k, v, mesh, axis_name="sp", causal=causal)
-            ctx.set_output(op, "Out", out)
+            ctx.set_output(op, "Out", _to_rows(out) if n_head else out)
             return
 
     if ctx.mesh is not None:
-        out = _flash_on_mesh(q, k, v, kv_lens, causal, ctx.mesh)
+        out = _flash_on_mesh(q, k, v, kv_lens, n_head, causal, ctx.mesh)
+    elif n_head:
+        out = flash_attention_rows(q, k, v, kv_lens, n_head, causal)
     else:
         out = flash_attention(q, k, v, kv_lens, causal)
     ctx.set_output(op, "Out", out)
 
 
-def _flash_on_mesh(q, k, v, kv_lens, causal, mesh):
+def _flash_on_mesh(q, k, v, kv_lens, n_head, causal, mesh):
     """The single-shard kernel under an executor mesh.  XLA's partitioner
     cannot split a Mosaic kernel ("cannot be automatically partitioned"), so
     the call runs inside ``shard_map``: attention is independent across
     batch and heads, so the batch splits on ``dp`` and the heads on ``tp``
-    (an axis that does not divide stays replicated, as do all others)."""
+    (an axis that does not divide stays replicated, as do all others).  Rows
+    (``n_head`` > 0) split their ``H * D`` axis where whole head groups — the
+    heads one block of lanes holds — divide."""
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from ..parallel.flash_attention import flash_attention
+    from ..parallel.flash_attention import (_lane_heads, flash_attention,
+                                            flash_attention_rows)
 
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
 
@@ -77,11 +94,19 @@ def _flash_on_mesh(q, k, v, kv_lens, causal, mesh):
         n = int(sizes.get(name, 1))
         return name if n > 1 and dim % n == 0 else None
 
-    spec = P(axis("dp", q.shape[0]), axis("tp", q.shape[1]), None, None)
+    if n_head:
+        groups = n_head // _lane_heads(n_head, q.shape[2] // n_head)
+        spec = P(axis("dp", q.shape[0]), None, axis("tp", groups))
+        local_heads = n_head // (int(sizes["tp"]) if spec[2] else 1)
+    else:
+        spec = P(axis("dp", q.shape[0]), axis("tp", q.shape[1]), None, None)
     lens = () if kv_lens is None else (kv_lens,)
 
     def shard(q, k, v, *lens):
-        return flash_attention(q, k, v, lens[0] if lens else None, causal)
+        lens = lens[0] if lens else None
+        if n_head:
+            return flash_attention_rows(q, k, v, lens, local_heads, causal)
+        return flash_attention(q, k, v, lens, causal)
 
     return jax.shard_map(
         shard, mesh=mesh, in_specs=(spec,) * 3 + (P(spec[0]),) * len(lens),

@@ -5,10 +5,33 @@ matmul/softmax/matmul ops (nets.py scaled_dot_product_attention,
 operators/math/softmax.cu) — O(T²) HBM traffic.  Here the forward is a
 single Pallas kernel (online softmax, O(T) HBM per row block, q·kᵀ and p·v
 tiles in VMEM).  Two backward engines exist, chosen from the shape
-(``_bwd_engine``): a fused one-grid Pallas kernel from T = 512 on wherever
-its query side fits the VMEM it may ask for (T = 2048 and 4096 of the cells),
-and the lax.scan-over-key-blocks formulation in plain XLA elsewhere (T = 256,
-where the two are as fast).  Neither materializes a [T, S] tensor.
+(``_bwd_engine``): a fused one-grid Pallas kernel wherever its query side
+fits the VMEM it may ask for, from ``_BWD_MIN_T`` = 256 query rows on (every
+shape a cell runs), and the lax.scan-over-key-blocks formulation in plain XLA
+elsewhere.  Neither materializes a [T, S] tensor.
+
+LAYOUT (PR 43).  The kernels read and write the projections' OWN rows: q
+``[B, T, H * D]``, k and v ``[B, S, H * D]``, head ``h`` the lanes ``h * D :
+(h + 1) * D`` — what ``fc`` gives and what the output ``fc`` takes
+(``flash_attention_rows``).  Until then they took ``[B * H, T, D]``, a layout
+XLA cannot change for a Mosaic call, so every head split and merge around
+them was a real pass over the tensor in HBM: 180 copies and casts a training
+step, 18.8 ms of 136.9 at T = 2048 (PERF.md section 5).  A BLOCK of rows is
+the fewest heads whose lanes are whole 128-lane tiles (``_lane_heads``: two
+heads at D = 64, which a ``[.., 64]`` block padded to twice their bytes in
+VMEM and moved in 256-byte DMA rows; one at D = 128; all of ``H * D`` where
+no count of heads makes whole tiles).  A head meets the MXU as the block's
+full lane width with the other heads' lanes zeroed on ONE operand (q in the
+forward, k and v in the backward): the contraction then sums its own lanes
+only, at no more MXU passes than a depth-64 product took, and every store
+(out, dq, dk, dv) is one lane-dense row of all the block's heads.  The
+softmax statistics travel as lane-dense ROWS too: the forward emits ``lse``
+as ``[B, H, T]`` floats (one ``[1, block_q]`` row a head and query block; it
+used to ship a lane-replicated ``[.., T, 128]``), and the backward makes
+``delta = rowsum(do * out)`` itself from the resident ``do`` and ``out`` —
+both through a tiny f32-precision MXU product (``_lane_sums_as_rows``), so
+no XLA glue stands between the two kernels.  ``flash_attention`` on ``[B, H,
+T, D]`` is the same kernels behind a transpose at its edge.
 
 The forward's tiles are CHOSEN from the shape (``_fwd_tiles``; PR 29).  Until
 then every call walked a grid of 128 x 128 tiles, and on v5e such a grid step
@@ -16,19 +39,22 @@ cost 0.65-0.73 µs whatever it held (ledger, PR 28: 10.7 ms a non-causal call
 on f32[64,2048,64], 3% of its roofline); July's kernel-only sweeps at blocks
 of 128, which the choices below used to quote, measured that overhead and
 not the kernels.  Now a step takes a query block of up to 512 rows against
-the K and V of its (batch, head) held WHOLE in VMEM (fetched once a head),
+the K and V of its (batch, head group) held WHOLE in VMEM (fetched once),
 walks them 512 keys a turn inside the step up to the last key a row of the
-block can see, and at T <= 512 takes several heads: 1.08 ms for the same
-call (traced chip run, PR 29; PERF.md sections 5 and 6 have what a step and
-a turn cost).
+block can see, and at T <= 512 takes several batch rows: 1.08 ms for the
+same call (traced chip run, PR 29; PERF.md sections 5 and 6 have what a step
+and a turn cost).
 The backward's tiles are chosen from the shape too (``_bwd_tiles``; PR 34):
-a grid step is one 512-key block against the query side of its (batch, head),
-RESIDENT in VMEM and walked inside the step 512 rows a turn, several heads a
-step at T <= 512; tiles a causal mask or ``kv_lens`` hides take no turn.
-Until then a step was one 128-key block against ALL of T at once, four
-[T, 128] intermediates a step: 3.91 ms a call on f32[64,2048,64], and past
-the scoped VMEM limit at T = 4096, which ran the scan at 16.8 ms a call; now
-2.03 and 3.94 ms (kernel-only chip runs, PR 34; PERF.md section 6).
+a grid step is one 512-key block against the query side of its (batch, head
+group), RESIDENT in VMEM and walked inside the step 512 rows a turn, several
+batch rows a step at T <= 512; tiles a causal mask or ``kv_lens`` hides take
+no turn.  Until then a step was one 128-key block against ALL of T at once,
+four [T, 128] intermediates a step: 3.91 ms a call on f32[64,2048,64], and
+past the scoped VMEM limit at T = 4096, which ran the scan at 16.8 ms a call;
+then 2.03 and 3.94 ms (kernel-only chip runs, PR 34; PERF.md section 6).
+Both kernels are compiled with the VMEM limit their residency models ask for
+(``_fwd_vmem_bytes``, ``_bwd_vmem_bytes``: calibrated against the compiler,
+their docstrings have the points); the models are the only selectors.
 
 Supports causal masking and per-sequence key lengths (`kv_lens`) — the
 padding-mask case of the Fluid transformer — without materializing any
@@ -36,7 +62,7 @@ padding-mask case of the Fluid transformer — without materializing any
 
 * `kv_lens` rides the scalar-prefetch path (`pltpu.PrefetchScalarGridSpec`,
   SMEM) — a (1, 1)-blocked VMEM operand is not a legal Mosaic block for a
-  [B·H]-shaped array.
+  [B]-shaped array.
 * m/l scratch are lane-replicated (block_q, 128) and stay so through the
   softmax update (``_lanes``): a [block_q, 1] statistic costs a lane
   broadcast at every use, which was 40% of a turn.
@@ -63,10 +89,11 @@ import numpy as np
 
 from ..core import cpu_backend
 
-__all__ = ["flash_attention", "mha_reference", "paged_decode_attention",
-           "paged_prefill_attention", "paged_mla_decode_attention",
-           "paged_mla_prefill_attention", "paged_gqa_decode_attention",
-           "paged_gqa_prefill_attention", "paged_kv_finite"]
+__all__ = ["flash_attention", "flash_attention_rows", "mha_reference",
+           "paged_decode_attention", "paged_prefill_attention",
+           "paged_mla_decode_attention", "paged_mla_prefill_attention",
+           "paged_gqa_decode_attention", "paged_gqa_prefill_attention",
+           "paged_kv_finite"]
 
 # what tools and tests pass explicitly, and the scan backward's key block;
 # the kernels choose their own from the shape (_fwd_tiles, _bwd_tiles)
@@ -113,70 +140,143 @@ def _lanes(x, n):
     return jnp.broadcast_to(x[:, 0:1], (x.shape[0], n))
 
 
-def _lens_per_head(kv_lens, B, H, S):
-    """``[B * H]`` int32 key lengths for the scalar-prefetch path (S where
-    the caller gave none)."""
+def _lane_heads(H, D):
+    """Heads a block of ``[B, T, H * D]`` rows holds in its lanes: the fewest
+    whose lanes make whole 128-lane tiles and that divide H (two at D = 64,
+    one at D = 128), all H where no such count exists (an odd H at D = 64, or
+    ``H * D`` under 128: a block as wide as the array is always a legal one)."""
+    for n in range(1, H):
+        if H % n == 0 and (n * D) % 128 == 0:
+            return n
+    return H
+
+
+def _head_lanes(shape, h, D):
+    """Which lanes of a ``[rows, heads * D]`` tile are head ``h``'s."""
+    import jax.numpy as jnp
+
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return (lane >= h * D) & (lane < (h + 1) * D)
+
+
+def _merge_heads(tiles, D):
+    """One ``[rows, heads * D]`` tile that takes head ``h``'s lanes from
+    ``tiles[h]``: what makes the stores of a step lane-dense."""
+    import jax.numpy as jnp
+
+    out = tiles[0]
+    for h in range(1, len(tiles)):
+        out = jnp.where(_head_lanes(out.shape, h, D), tiles[h], out)
+    return out
+
+
+def _lane_sums_as_rows(x, weights):
+    """``weights [8, lanes] . x [rows, lanes]^T -> [8, rows]``: sums over the
+    lanes of every row of ``x``, delivered as lane-dense ROWS (what the
+    backward's ``[keys, query rows]`` tiles broadcast over their sublanes)
+    with no transpose and no ``[rows, 1]`` statistic.  Through the MXU, and
+    exact in f32: ``weights`` are 0 or 1 and ``x`` goes in as its three bf16
+    parts (8 + 8 + 8 significand bits), one pass each, so every product is
+    exact and the sum is f32's (the compiler's own f32 product takes six
+    passes for weights it cannot know are exact)."""
+    import jax.numpy as jnp
+
+    w = weights.astype(jnp.bfloat16)
+    out = None
+    for _ in range(3):
+        part = x.astype(jnp.bfloat16)
+        x = x - part.astype(jnp.float32)
+        term = jax.lax.dot_general(w, part, (((1,), (1,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+        out = term if out is None else out + term
+    return out
+
+
+def _to_rows(x):
+    """``[B, H, T, D]`` as the projections' rows ``[B, T, H * D]``."""
+    B, H, T, D = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(B, T, H * D)
+
+
+def _from_rows(x, H):
+    B, T, HD = x.shape
+    return x.reshape(B, T, H, HD // H).transpose(0, 2, 1, 3)
+
+
+def _lens_per_batch(kv_lens, B, S):
+    """``[B]`` int32 key lengths for the scalar-prefetch path (S where the
+    caller gave none)."""
     import jax.numpy as jnp
 
     if kv_lens is None:
-        return jnp.full((B * H,), S, jnp.int32)
-    return jnp.repeat(kv_lens.astype(jnp.int32), H)
+        return jnp.full((B,), S, jnp.int32)
+    return kv_lens.astype(jnp.int32)
 
 
 def _fwd_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, sm_scale, causal, heads, block_q, block_k, chunks, num_k_blocks,
-                q_len, kv_len):
-    """One grid step = ``heads`` (batch, head) pairs x one query block x one
-    RESIDENT key span of ``chunks * block_k`` rows (all of S where it fits
-    VMEM: then K and V are fetched once a (batch, head) and the grid has no
-    key axis to step over).  The key loop runs INSIDE the step, ``block_k``
-    rows a turn, and is bounded by the last chunk a row of this query block
-    can see — a masked chunk is neither stepped over nor computed — and the
-    chunks every row sees whole take the turn without the mask arithmetic."""
+                *, sm_scale, causal, batches, heads, head_dim, block_q, block_k,
+                chunks, num_k_blocks, q_len, kv_len):
+    """One grid step = ``batches`` batch rows x the ``heads`` heads whose
+    lanes make one block of the ``[B, T, H * D]`` rows x one query block x
+    one RESIDENT key span of ``chunks * block_k`` rows (all of S where it fits
+    VMEM: then K and V are fetched once a (batch, head group) and the grid has
+    no key axis to step over).  A head is a lane slice of the block and meets
+    the MXU as the block's FULL lane width: its q has the other heads' lanes
+    zeroed, so ``q . k^T`` contracts over all the lanes and sums only its own
+    (at D = 64 a depth-64 product was half an MXU pass anyway), ``p . v``
+    gives every lane and the head's own are taken at the end, where the
+    heads' tiles are merged into ONE lane-dense store.  The key loop runs
+    INSIDE the step, ``block_k`` rows a turn (K and V loaded once a turn for
+    all the heads), and is bounded by the last chunk a row of this query
+    block can see — a masked chunk is neither stepped over nor computed — and
+    the chunks every row sees whole take the turn without the mask
+    arithmetic."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
     g = pl.program_id(0)
-    q0 = pl.program_id(1) * block_q
-    kj = pl.program_id(2)
+    q0 = pl.program_id(2) * block_q
+    kj = pl.program_id(3)
     k0 = kj * (chunks * block_k)
     shift = kv_len - q_len  # causal: row t sees keys [0, t + shift] — tril(k=S-T)
+    width = acc_scr.shape[2]
 
-    def chunk(h, kvl, q, last, c, masked):
+    def chunk(n, kvl, qs, last, c, masked):
         start = pl.multiple_of(c * block_k, block_k)
-        k = k_ref[h, pl.ds(start, block_k), :].astype(jnp.float32)  # [bk, d]
-        v = v_ref[h, pl.ds(start, block_k), :].astype(jnp.float32)
+        k = k_ref[n, pl.ds(start, block_k), :].astype(jnp.float32)  # [bk, width]
+        v = v_ref[n, pl.ds(start, block_k), :].astype(jnp.float32)
         if masked:
             # zero invalid v rows: 0·NaN from OOB-padded tail tiles would
             # poison the p·v accumulation even where p is 0 (a score off
             # such a k row is replaced below, whatever it is)
             kcol = k0 + start + jax.lax.broadcasted_iota(jnp.int32, (block_k, 1), 0)
             v = jnp.where(kcol < kvl, v, 0.0)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # [bq, bk]
-        if masked:
             # one compare a score: the chunk's own column index against each
             # row's last visible one (lane-replicated, as m and l are)
             col = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(col <= _lanes(last - (k0 + start), block_k), s, NEG_INF)
+            seen = col <= _lanes(last - (k0 + start), block_k)
+        for h, q in enumerate(qs):
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)  # [bq, bk]
+            if masked:
+                s = jnp.where(seen, s, NEG_INF)
+            # m, l and alpha stay lane-replicated [bq, 128] through the
+            # update: the only lane traffic a turn is the two row reductions
+            m_prev = m_scr[h]
+            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+            p = jnp.exp(s - _lanes(m_new, block_k))
+            alpha = jnp.exp(m_prev - m_new)
+            l_scr[h] = l_scr[h] * alpha + p.sum(axis=1, keepdims=True)
+            acc_scr[h] = acc_scr[h] * _lanes(alpha, width) + jnp.dot(
+                p, v, preferred_element_type=jnp.float32)
+            m_scr[h] = m_new
 
-        # m, l and alpha stay lane-replicated [bq, 128] through the update:
-        # the only lane traffic a turn is the two row reductions
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-        p = jnp.exp(s - _lanes(m_new, block_k))
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * alpha + p.sum(axis=1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * _lanes(alpha, acc_scr.shape[1]) + jnp.dot(
-            p, v, preferred_element_type=jnp.float32
-        )
-        m_scr[...] = m_new
+    def batch(n, carry):
+        kvl = lens_ref[g * batches + n]  # valid key length of this batch row
 
-    def head(h, carry):
-        kvl = lens_ref[g * heads + h]  # valid key length of this (batch, head)
-
-        # m/l/acc are one query block's; several heads a step take turns at
-        # them (the chooser gives heads > 1 only with the whole of S resident)
+        # m/l/acc are one query block's, a set a head; several batch rows a
+        # step take turns at them (the chooser gives batches > 1 only with
+        # the whole of S resident)
         @pl.when(kj == 0)
         def _init():
             m_scr[...] = jnp.full_like(m_scr, NEG_INF)
@@ -198,369 +298,434 @@ def _fwd_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc
         # each row's last visible key, [bq, 128] lane-replicated: query row t
         # sees keys [0, t + S - T] — tril(k=S-T), matching mha_reference for
         # T != S (bottom-right aligned) — and none from kv_lens on
-        last = jnp.full(m_scr.shape, kvl - 1, jnp.int32)
+        last = jnp.full(m_scr.shape[1:], kvl - 1, jnp.int32)
         if causal:
-            row = q0 + jax.lax.broadcasted_iota(jnp.int32, m_scr.shape, 0)
+            row = q0 + jax.lax.broadcasted_iota(jnp.int32, last.shape, 0)
             last = jnp.minimum(last, row + shift)
 
-        # the softmax scale goes into q once a step, not into every score tile
-        q = q_ref[h].astype(jnp.float32) * sm_scale  # [bq, d]
+        # the softmax scale goes into q once a step, not into every score
+        # tile, and so do the zeros in the other heads' lanes
+        q = q_ref[n].astype(jnp.float32) * sm_scale  # [bq, width]
+        qs = [q] if heads == 1 else [
+            jnp.where(_head_lanes(q.shape, h, head_dim), q, 0.0) for h in range(heads)]
         jax.lax.fori_loop(
-            0, n_every, lambda c, _: chunk(h, kvl, q, last, c, False), None)
+            0, n_every, lambda c, _: chunk(n, kvl, qs, last, c, False), None)
         jax.lax.fori_loop(
-            n_every, n_some, lambda c, _: chunk(h, kvl, q, last, c, True), None)
+            n_every, n_some, lambda c, _: chunk(n, kvl, qs, last, c, True), None)
 
         @pl.when(kj == num_k_blocks - 1)
         def _finish():
-            denom = jnp.maximum(l_scr[...], 1e-30)
-            o_ref[h] = (acc_scr[...] / _lanes(denom, acc_scr.shape[1])).astype(o_ref.dtype)
-            # lane-replicated: a (1, bq)-blocked rank-2 output is not a legal
-            # Mosaic block, so lse ships as [bh, T, 128] and lane 0 is read back
-            lse_ref[h] = m_scr[...] + jnp.log(denom)
+            # lane 0 of a lane-replicated [bq, 128] statistic, as a row
+            lane0 = (jax.lax.broadcasted_iota(jnp.int32, (8, 128), 1) == 0
+                     ).astype(jnp.float32)
+            outs = []
+            for h in range(heads):
+                denom = jnp.maximum(l_scr[h], 1e-30)
+                outs.append(acc_scr[h] / _lanes(denom, width))
+                # lse leaves as the backward reads it: one lane-dense [1, bq]
+                # row a (head, query block), T floats a head where a lane-
+                # replicated [T, 128] block was 128 times the bytes
+                lse_ref[n, h, 0] = _lane_sums_as_rows(
+                    m_scr[h] + jnp.log(denom), lane0)[0:1]
+            o_ref[n] = _merge_heads(outs, head_dim).astype(o_ref.dtype)
 
         return carry
 
-    jax.lax.fori_loop(0, heads, head, None)
+    jax.lax.fori_loop(0, batches, batch, None)
 
 
-# Scoped-VMEM budget of the forward: the compiler's default limit of 16 MB
-# less a margin for the model's error.  The only selector: where the model is
-# wrong at some shape the step's compile error says so.
-_FWD_VMEM_BUDGET = 13 * 1024 * 1024
-# A turn of the key loop is one [block_q, block_k] score tile; 512 x 512 is
-# where a turn's fixed costs (the m/l/acc read-modify-write, the loop) stop
-# showing on v5e (PERF.md section 6, PR 29).
+# What a forward step may ask of VMEM.  The kernel is compiled with the limit
+# its residency model asks for (``_vmem_limit``), as the backward is: K and V
+# of S = 4096 whole beside two heads' tiles are 13.0 MB, too near the
+# compiler's default of 16 MB to leave to it.  The only selector: where the
+# model is wrong at some shape the step's compile error says so.
+_FWD_VMEM_BUDGET = 24 * 1024 * 1024
+# A turn of the key loop is one [block_q, block_k] score tile a head; 512 x
+# 512 is where a turn's fixed costs (the m/l/acc read-modify-write, the loop)
+# stop showing on v5e (PERF.md section 6, PR 29).
 _FWD_BLOCK = 512
-# heads a step at short T: enough that a step holds about a 512 x 512 tile
+# (batch, head) pairs a step at short T: enough that a step holds about a
+# 512 x 512 tile
 _FWD_MAX_HEADS = 8
 
 
-def _fwd_vmem_bytes(heads, block_q, block_k, chunks, D, in_itemsize):
-    """Scoped-VMEM residency of one forward grid step, calibrated against
-    the compiler (the least ``vmem_limit_bytes`` at which a described v5e
-    compiles the kernel, 18 tile choices at the cells' shapes): at T = S =
-    2048, D = 64, f32 it takes 5.25 MB at blocks 256 x 256, 7.0 at 512 x
-    512, 10.25 at 1024 x 512, 13.0 at 1024 x 1024, 4.0 with one 512-row
-    chunk resident instead of all four, and 11.0 at S = 4096 whole.  A VMEM
-    row is 128 lanes wide whatever D is.  Terms:
+def _fwd_vmem_bytes(batches, heads, block_q, block_k, chunks, D, in_itemsize):
+    """Scoped-VMEM residency of one forward grid step: ``batches`` batch rows
+    of a block of ``heads`` heads' lanes (``_lane_heads``).  A VMEM row is
+    whole 128-lane tiles: two heads of D = 64 fill one, where a ``[.., D]``
+    block padded each head to twice its bytes.  Terms:
       k, v resident span, double-buffered .... 2 * 2 * span * lanes * isz
       q, out blocks, double-buffered ......... 2 * 2 * block_q * lanes * isz
-      lse block, lane-replicated f32 ......... 2 * block_q * 128 * 4
-      m, l, acc scratch ...................... block_q * (2 * 128 + lanes) * 4
-      one key-loop turn: 1.5 [block_q, block_k] f32 tiles (s and p, partly
-      fused) and the f32 k and v chunks
-    It reads 10-20% over the compiler at every point measured (bf16 more)."""
-    lanes = -(-D // 128) * 128
+      lse rows [1 -> 8, block_q] f32 ......... heads * 2 * 8 * block_q * 4
+      m, l, acc scratch, a set a head ........ heads * block_q * (2 * 128 + lanes) * 4
+      the heads' masked f32 q ................ heads * block_q * lanes * 4
+      one key-loop turn: 1.5 [block_q, block_k] f32 tiles a head (s and p,
+      partly fused) and the f32 k and v chunks
+    Calibrated against the compiler (the least ``vmem_limit_bytes`` at which
+    a described v5e compiles the kernel, 36 points forward and backward, PR
+    43): at T = S = 2048, D = 64 (two heads a block), f32 it takes 8.93 MB at
+    blocks 512 x 512 (7.0 for ONE head of a ``[B * H, T, D]`` array before),
+    6.03 at 256 x 256, 13.76 at 1024 x 512, 18.21 at 1024 x 1024; 12.99 at S
+    = 4096 whole and 20.92 at 8192; 5.64 at T = 256 with four batch rows a
+    step; 7.57 at D = 128 (one head a block).  It reads 6-27% over the
+    compiler at every f32 point measured and 5-35% for bf16 inputs."""
+    lanes = -(-heads * D // 128) * 128
     span = chunks * block_k
-    streamed = heads * (4 * span * lanes * in_itemsize
-                        + block_q * (4 * lanes * in_itemsize + 2 * 128 * 4))
-    scratch = block_q * (2 * 128 + lanes) * 4
-    turn = 6 * block_q * block_k + 2 * block_k * lanes * 4
+    streamed = batches * (4 * span * lanes * in_itemsize
+                          + block_q * (4 * lanes * in_itemsize + heads * 2 * 8 * 4))
+    scratch = heads * block_q * (2 * 128 + 2 * lanes) * 4
+    turn = heads * 6 * block_q * block_k + 2 * block_k * lanes * 4
     return streamed + scratch + turn
 
 
-def _fwd_tiles(bh, T, S, D, in_itemsize):
-    """``(heads, block_q, block_k, chunks)`` of the forward, chosen from the
-    shape alone (no probe, no fallback): a query block and a key chunk of up
+def _fwd_tiles(B, H, T, S, D, in_itemsize):
+    """``(batches, heads, block_q, block_k, chunks)`` of the forward, chosen
+    from the shape alone (no probe, no fallback): the heads a block of rows
+    holds in its lanes (``_lane_heads``), a query block and a key chunk of up
     to ``_FWD_BLOCK`` rows, as much of S resident a step as the budget holds
-    (all of it at the cells' shapes), and at one query block a (batch,
-    head) several heads a step.  ``causal`` does not enter: on v5e 512 x 512
+    (all of it at the cells' shapes), and at one query block a batch row
+    several batch rows a step.  ``causal`` does not enter: on v5e 512 x 512
     was the fastest tile with and without it (a smaller query block trims
     the masked diagonal and loses more to the step's fixed costs)."""
+    heads = _lane_heads(H, D)
     block_q = min(_FWD_BLOCK, T)
     block_k = min(_FWD_BLOCK, S)
 
-    def fits(heads, chunks):
-        return _fwd_vmem_bytes(heads, block_q, block_k, chunks, D,
+    def fits(batches, chunks):
+        return _fwd_vmem_bytes(batches, heads, block_q, block_k, chunks, D,
                                in_itemsize) <= _FWD_VMEM_BUDGET
 
     chunks = max(1, S // block_k)
     while chunks > 1 and not fits(1, chunks):
         chunks = -(-chunks // 2)
-    heads = 1
+    batches = 1
     if block_q == T and chunks * block_k >= S:
-        for n in range(2, _FWD_MAX_HEADS + 1):
-            if bh % n == 0 and n * block_q * block_k * chunks <= 2 * _FWD_BLOCK ** 2 \
+        for n in range(2, _FWD_MAX_HEADS // heads + 1):
+            if B % n == 0 and n * heads * block_q * block_k * chunks <= 2 * _FWD_BLOCK ** 2 \
                     and fits(n, chunks):
-                heads = n
-    return heads, block_q, block_k, chunks
+                batches = n
+    return batches, heads, block_q, block_k, chunks
 
 
-def _flash_fwd(q, k, v, kv_lens, causal, sm_scale, block_q, block_k, interpret):
+# The name a device trace shows the forward under, as the backward's below:
+# the trace readers match custom calls whose name holds "flash_attention".
+_FWD_KERNEL_NAME = "flash_attention_fwd"
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8, 9, 10))
+def _flash_fwd(q, k, v, kv_lens, n_head, causal, sm_scale, block_q, block_k,
+               interpret, layout):
+    """``(out, lse)``.  A jit of its own, as the backward is: a step's
+    eighteen calls are two shapes (causal or not), and each is then traced
+    and lowered to Mosaic once, not at every call site (set-up time at every
+    process start)."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     from .. import observability as obs
 
-    B, H, T, D = q.shape
-    S = k.shape[2]
-    bh = B * H
-    heads, bq, bk, chunks = _fwd_tiles(bh, T, S, D, q.dtype.itemsize)
+    B, T, HD = q.shape
+    S = k.shape[1]
+    H, D = n_head, HD // n_head
+    batches, heads, bq, bk, chunks = _fwd_tiles(B, H, T, S, D, q.dtype.itemsize)
     if block_q is not None or block_k is not None:
-        # explicit blocks are taken as given: one head a step, and as many
-        # whole chunks of S resident as the array holds
+        # explicit blocks are taken as given: one batch row a step, and as
+        # many whole chunks of S resident as the array holds
         bq = min(block_q or bq, T)
         bk = min(block_k or bk, S)
-        heads, chunks = 1, max(1, S // bk)
+        batches, chunks = 1, max(1, S // bk)
+    width = heads * D
     span = chunks * bk
     nq = -(-T // bq)
     nk = -(-S // span)
-    assert heads == 1 or nk == 1, (heads, bq, bk, chunks)  # one m/l/acc a step
-    qr = q.reshape(bh, T, D)
-    kr = k.reshape(bh, S, D)
-    vr = v.reshape(bh, S, D)
-    lens_bh = _lens_per_head(kv_lens, B, H, S)
+    assert batches == 1 or nk == 1, (batches, bq, bk, chunks)  # one m/l/acc a step
+    lens = _lens_per_batch(kv_lens, B, S)
 
     # what was chosen, once per compiled shape (this runs at trace time): a
     # reader of a device trace divides the kernel's time by its grid steps
     steps = obs.counter("flash.fwd.grid_steps", labels={
-        "T": T, "S": S, "block": "%dx%d" % (bq, bk), "bh": bh,
-        "causal": int(bool(causal))})
+        "T": T, "S": S, "block": "%dx%d" % (bq, bk), "heads": batches * heads,
+        "bh": B * H, "causal": int(bool(causal)), "layout": layout})
     if not steps.value:
-        steps.inc((bh // heads) * nq * nk)
+        steps.inc((B // batches) * (H // heads) * nq * nk)
 
-    def kv_block(g, i, j, lens):
+    def kv_block(g, c, i, j, lens):
         if nk == 1:
-            return (g, 0, 0)
+            return (g, 0, c)
         # a key span no row of the query block sees keeps the index of the
         # last one seen: the pipeline then issues no copy for it (nk > 1
-        # comes with heads == 1, so lens[g] is the step's one length)
+        # comes with batches == 1, so lens[g] is the step's one length)
         some = lens[g]
         if causal:
             some = jnp.minimum(some, i * bq + bq + (S - T))
-        return (g, jnp.minimum(j, jnp.maximum((some - 1) // span, 0)), 0)
+        return (g, jnp.minimum(j, jnp.maximum((some - 1) // span, 0)), c)
 
     kernel = functools.partial(
-        _fwd_kernel, sm_scale=sm_scale, causal=causal, heads=heads,
-        block_q=bq, block_k=bk, chunks=chunks, num_k_blocks=nk, q_len=T, kv_len=S,
+        _fwd_kernel, sm_scale=sm_scale, causal=causal, batches=batches,
+        heads=heads, head_dim=D, block_q=bq, block_k=bk, chunks=chunks,
+        num_k_blocks=nk, q_len=T, kv_len=S,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(bh // heads, nq, nk),
+        grid=(B // batches, H // heads, nq, nk),
         in_specs=[
-            pl.BlockSpec((heads, bq, D), lambda g, i, j, lens: (g, i, 0)),
-            pl.BlockSpec((heads, span, D), kv_block),
-            pl.BlockSpec((heads, span, D), kv_block),
+            pl.BlockSpec((batches, bq, width), lambda g, c, i, j, lens: (g, i, c)),
+            pl.BlockSpec((batches, span, width), kv_block),
+            pl.BlockSpec((batches, span, width), kv_block),
         ],
         out_specs=[
-            pl.BlockSpec((heads, bq, D), lambda g, i, j, lens: (g, i, 0)),
-            pl.BlockSpec((heads, bq, 128), lambda g, i, j, lens: (g, i, 0)),
+            pl.BlockSpec((batches, bq, width), lambda g, c, i, j, lens: (g, i, c)),
+            pl.BlockSpec((batches, heads, 1, 1, bq),
+                         lambda g, c, i, j, lens: (g, c, i, 0, 0)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq, 128), jnp.float32),  # running max (lane-replicated)
-            pltpu.VMEM((bq, 128), jnp.float32),  # running sum (lane-replicated)
-            pltpu.VMEM((bq, D), jnp.float32),    # output accumulator
+            pltpu.VMEM((heads, bq, 128), jnp.float32),    # running max (lane-replicated)
+            pltpu.VMEM((heads, bq, 128), jnp.float32),    # running sum (lane-replicated)
+            pltpu.VMEM((heads, bq, width), jnp.float32),  # output accumulators
         ],
     )
     out, lse = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((bh, T, D), q.dtype),
-            jax.ShapeDtypeStruct((bh, T, 128), jnp.float32),
+            jax.ShapeDtypeStruct((B, T, HD), q.dtype),
+            jax.ShapeDtypeStruct((B, H, nq, 1, bq), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(_fwd_vmem_bytes(
+                batches, heads, bq, bk, chunks, D, q.dtype.itemsize)),
         ),
         interpret=interpret,
-    )(lens_bh, qr, kr, vr)
-    return out.reshape(B, H, T, D), lse[:, :, 0].reshape(B, H, T)
+        name=_FWD_KERNEL_NAME,
+    )(lens, q, k, v)
+    # [B, H, T]: a free reshape where T is whole query blocks (a tail block's
+    # rows past T hold anything)
+    return out, lse.reshape(B, H, nq * bq)[:, :, :T]
 
 
-def _flash_bwd_scan(causal, sm_scale, block_k, res, do):
-    """Blockwise flash backward in plain JAX (lax.scan over key blocks) —
-    what ``_bwd_engine`` picks under ``_BWD_MIN_T`` query rows (the s256
-    cell) and where the fused kernel's resident query side is past its VMEM
-    budget (no shape a cell runs)."""
+def _flash_bwd_scan(n_head, causal, sm_scale, block_k, res, do):
+    """Blockwise flash backward in plain JAX (lax.scan over key blocks) on the
+    same rows — what ``_bwd_engine`` picks under ``_BWD_MIN_T`` query rows and
+    where the fused kernel's resident query side is past its VMEM budget (no
+    shape a cell runs)."""
     import jax.numpy as jnp
 
     q, k, v, kv_lens, out, lse = res
-    B, H, T, D = q.shape
-    S = k.shape[2]
-    qf = q.astype(jnp.float32)
-    kf = k.astype(jnp.float32)
-    vf = v.astype(jnp.float32)
-    dof = do.astype(jnp.float32)
-    delta = (dof * out.astype(jnp.float32)).sum(-1)  # [B,H,T]
+    B, T, HD = q.shape
+    S = k.shape[1]
+    H, D = n_head, HD // n_head
+
+    def heads(x):  # [B, T, H * D] -> [B, T, H, D] f32: a view, no pass
+        return x.astype(jnp.float32).reshape(x.shape[:2] + (H, D))
+
+    qf, kf, vf, dof = heads(q), heads(k), heads(v), heads(do)
+    delta = jnp.einsum("bqhd,bqhd->bhq", dof, heads(out))  # [B,H,T]
 
     bk = min(block_k, S)
     nk = -(-S // bk)
     pad = nk * bk - S
     if pad:
-        kf = jnp.pad(kf, ((0, 0), (0, 0), (0, pad), (0, 0)))
-        vf = jnp.pad(vf, ((0, 0), (0, 0), (0, pad), (0, 0)))
-    kb = kf.reshape(B, H, nk, bk, D)
-    vb = vf.reshape(B, H, nk, bk, D)
+        kf = jnp.pad(kf, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        vf = jnp.pad(vf, ((0, 0), (0, pad), (0, 0), (0, 0)))
+    kb = kf.reshape(B, nk, bk, H, D)
+    vb = vf.reshape(B, nk, bk, H, D)
 
     col_base = jnp.arange(nk) * bk
     rows = jnp.arange(T)
     klim = jnp.full((B,), S, jnp.int32) if kv_lens is None else kv_lens.astype(jnp.int32)
 
     def kblock(dq, it):
-        kj, vj, j0 = it  # [B,H,bk,D], [B,H,bk,D], scalar col offset
-        s = jnp.einsum("bhqd,bhkd->bhqk", qf, kj) * sm_scale
+        kj, vj, j0 = it  # [B,bk,H,D], [B,bk,H,D], scalar col offset
+        s = jnp.einsum("bqhd,bkhd->bhqk", qf, kj) * sm_scale
         cols = j0 + jnp.arange(bk)
         valid = cols[None, None, None, :] < klim[:, None, None, None]
         if causal:
             # same bottom-right-aligned tril(k=S-T) as the forward kernel
             valid = valid & (rows[:, None] + (S - T) >= cols[None, :])[None, None]
         p = jnp.where(valid, jnp.exp(s - lse[..., :, None]), 0.0)  # [B,H,T,bk]
-        dv_j = jnp.einsum("bhqk,bhqd->bhkd", p, dof)
-        dp = jnp.einsum("bhqd,bhkd->bhqk", dof, vj)
+        dv_j = jnp.einsum("bhqk,bqhd->bkhd", p, dof)
+        dp = jnp.einsum("bqhd,bkhd->bhqk", dof, vj)
         ds = p * (dp - delta[..., :, None]) * sm_scale
-        dq = dq + jnp.einsum("bhqk,bhkd->bhqd", ds, kj)
-        dk_j = jnp.einsum("bhqk,bhqd->bhkd", ds, qf)
+        dq = dq + jnp.einsum("bhqk,bkhd->bqhd", ds, kj)
+        dk_j = jnp.einsum("bhqk,bqhd->bkhd", ds, qf)
         return dq, (dk_j, dv_j)
 
     dq0 = jnp.zeros_like(qf)
-    its = (jnp.moveaxis(kb, 2, 0), jnp.moveaxis(vb, 2, 0), col_base)
+    its = (jnp.moveaxis(kb, 1, 0), jnp.moveaxis(vb, 1, 0), col_base)
     dq, (dk_b, dv_b) = jax.lax.scan(kblock, dq0, its)
-    dk = jnp.moveaxis(dk_b, 0, 2).reshape(B, H, nk * bk, D)[:, :, :S]
-    dv = jnp.moveaxis(dv_b, 0, 2).reshape(B, H, nk * bk, D)[:, :, :S]
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+    dk = jnp.moveaxis(dk_b, 0, 1).reshape(B, nk * bk, HD)[:, :S]
+    dv = jnp.moveaxis(dv_b, 0, 1).reshape(B, nk * bk, HD)[:, :S]
+    return dq.reshape(B, T, HD).astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
 # Two backward engines, chosen from the shape by ``_bwd_engine`` and by
-# nothing else.  "fused" is the dq+dkv-in-ONE-grid Pallas kernel (5 matmuls,
-# p computed once a tile feeds dv, dq and dk; every tensor touches HBM once):
-# a grid step holds one key block of ``heads`` (batch, head) pairs and walks
-# the query side, which stays resident in VMEM for the whole key walk, one
-# query block a turn.  "scan" is lax.scan over key blocks in plain XLA: what
-# is left for short sequences (under ``_BWD_MIN_T`` query rows) and for a
-# shape whose query side the kernel's residency model refuses.
+# nothing else.  "fused" is the dq+dkv-in-ONE-grid Pallas kernel (5 matmuls a
+# head, p computed once a tile feeds dv, dq and dk; every tensor touches HBM
+# once): a grid step holds one key block of ``batches`` batch rows of one
+# head group's lanes and walks the query side, which stays resident in VMEM
+# for the whole key walk, one query block a turn.  "scan" is lax.scan over
+# key blocks in plain XLA: what is left for short sequences (under
+# ``_BWD_MIN_T`` query rows) and for a shape whose query side the kernel's
+# residency model refuses.
 #
-# A turn of the backward is one [block_k, block_q] tile of s, p, dp and ds.
+# A turn of the backward is one [block_k, block_q] tile of s, p, dp and ds a
+# head.
 _BWD_BLOCK_Q = 512
 _BWD_BLOCK_K = 512
-# heads a step at short T, as the forward's
+# (batch, head) pairs a step at short T, as the forward's
 _BWD_MAX_HEADS = 8
 # The kernel is compiled with the scoped-VMEM limit its residency model asks
-# for (``_bwd_vmem_limit``): the 16 MB default is the compiler's default, not
+# for (``_vmem_limit``): the 16 MB default is the compiler's default, not
 # the core's VMEM, which is 128 MiB on v5e.  A shape may ask for up to this
 # budget (and a quarter more as the limit: under half the core's); past it
 # ``_bwd_engine`` answers "scan".  The only selector: where the model is
 # wrong at some shape the step's compile error says so (no probe, no fallback).
 _BWD_VMEM_BUDGET = 48 * 1024 * 1024
-# Under this many query rows the scan is as fast: at [512, 256, 64] the kernel
-# is bound by its bytes (0.62 ms a call; 0.71 inside the step) and XLA cannot
-# fuse into a custom call what it fuses into the scan's last turn (the three
-# gradients' casts to bf16 under ``decorate`` and their relayouts, 0.7 ms a
-# call): tfbase_train_s256 read 156.6 ms a step with the kernel against 155.2
-# with the scan, 103.9 k items/s against 104.9 k (chip runs, PR 34).
-_BWD_MIN_T = 512
+# Query rows from which the kernel runs: the least T the chip has measured it
+# at.  PR 34 found the scan as fast at T = 256 ([512, 256, 64]: 156.6 ms a
+# step with the kernel against 155.2), "the glue the whole difference": the
+# gradients' casts and relayouts, which XLA fused into the scan's last turn
+# and could not fuse into a custom call.  On rows there is no such glue, and
+# tfbase_train_s256 reads 83.1 ms a step with the kernel against 102.8 with
+# the scan, 194.8 k items/s against 157.5 k (chip runs, PR 43: PERF.md
+# section 6).  Under 256 rows nothing is measured (at 64 the chip's compiler
+# refuses the forward's 64-key turn, before PR 43 as after it).
+_BWD_MIN_T = 256
 
 
-def _bwd_vmem_bytes(heads, block_q, block_k, T, D, in_itemsize):
+def _bwd_vmem_bytes(batches, heads, block_q, block_k, T, D, in_itemsize):
     """Scoped-VMEM residency of one backward grid step (``T`` already a
-    multiple of ``block_q``), calibrated against the compiler (the least
-    ``vmem_limit_bytes`` at which a described v5e compiles the kernel, 24
-    points): at D = 64, f32, tiles of 512 x 512 it takes 10.28 / 16.28 /
-    28.46 MB at T = 2048 / 4096 / 8192 (3 KB a query row: six [T, 128-lane]
-    f32 buffers), 14.54 MB at T = 2048 with 1024 keys a tile, 7.77 at 256 x
-    256, and 12.99 at T = 256 with 8 heads a step.  A VMEM row is 128 lanes
-    wide whatever D is.  Terms:
-      q, do resident, double-buffered ........ 2 * 2 * T * lanes * isz
+    multiple of ``block_q``): ``batches`` batch rows of a block of ``heads``
+    heads' lanes.  A VMEM row is whole 128-lane tiles.  Terms:
+      q, do, out resident, double-buffered ... 3 * 2 * T * lanes * isz
       dq output block, f32, double-buffered .. 2 * T * lanes * 4
-      lse and delta rows [T / bq, 2 -> 8, bq] . 2 * 8 * T * 4
+      lse rows [T / bq, 1 -> 8, bq], double-buffered, and the delta scratch
+                                               heads * 3 * 8 * T * 4
       k, v, dk, dv blocks, double-buffered ... 4 * 2 * block_k * lanes * isz
-      dk, dv scratch and the f32 k, v ........ 4 * block_k * lanes * 4
-      one turn: 2.5 [block_k, block_q] f32 tiles (s, p, dp, ds and the
-      transposed ds, partly fused) and the f32 q and do blocks
-    It reads 7-26% over the compiler at every f32 point measured and 1.7-3.5
-    times over it for bf16 inputs (whose resident blocks the compiler counts
-    at a sixth of the f32 ones)."""
-    lanes = -(-D // 128) * 128
-    resident = heads * T * (4 * lanes * in_itemsize + 2 * lanes * 4 + 2 * 8 * 4)
-    streamed = heads * 8 * block_k * lanes * in_itemsize
-    scratch = 4 * block_k * lanes * 4
-    turn = 10 * block_q * block_k + 2 * block_q * lanes * 4
+      dk, dv scratch and the masked f32 k, v, a set a head
+                                               heads * 4 * block_k * lanes * 4
+      one turn: 2.5 [block_k, block_q] f32 tiles a head (s, p, dp, ds and
+      the transposed ds, partly fused), the f32 q and do blocks and the
+      turn's dq
+    Calibrated against the compiler as the forward's: at D = 64, f32, tiles of
+    512 x 512 it takes 14.73 / 22.85 / 39.09 MB at T = 2048 / 4096 / 8192 (4
+    KB a query row: eight [T, 128-lane] f32 buffers, two heads'), 17.05 MB at
+    T = 2048 with 1024 query rows a turn, 10.67 at 256 x 256, 9.89 at T = 256
+    with four batch rows a step, 12.99 at D = 128.  It reads 9-42% over the
+    compiler at every f32 point measured and 11-50% for bf16 inputs."""
+    lanes = -(-heads * D // 128) * 128
+    resident = batches * T * (6 * lanes * in_itemsize + 2 * lanes * 4
+                              + heads * 3 * 8 * 4)
+    streamed = batches * 8 * block_k * lanes * in_itemsize
+    scratch = heads * 4 * block_k * lanes * 4
+    turn = heads * 10 * block_q * block_k + 3 * block_q * lanes * 4
     return resident + streamed + scratch + turn
 
 
-def _bwd_vmem_limit(heads, block_q, block_k, T, D, in_itemsize):
-    """``vmem_limit_bytes`` the kernel is compiled with: what the model says
-    the shape needs and a quarter more, never under the compiler's default."""
-    need = _bwd_vmem_bytes(heads, block_q, block_k, T, D, in_itemsize)
+def _vmem_limit(need):
+    """``vmem_limit_bytes`` a kernel is compiled with: what its model says the
+    shape needs and a quarter more, never under the compiler's default."""
     return max(16 * 1024 * 1024, need + need // 4)
 
 
-def _bwd_tiles(bh, T, S, D, in_itemsize):
-    """``(heads, block_q, block_k)`` of the fused backward, from the shape
-    alone: a tile of up to ``_BWD_BLOCK_K`` keys x ``_BWD_BLOCK_Q`` query rows
-    a turn and, where one tile holds all of T and S, as many heads a step as
-    make about one full tile and fit the budget.  ``causal`` does not enter:
-    it decides which tiles are visited.  On v5e 512 x 512 is within 5% of
-    every larger tile (1024 x 1024 needs twice the VMEM for 3%), and 8 heads
-    a step the fastest at T = 256 (PERF.md section 6, PR 34)."""
+def _bwd_tiles(B, H, T, S, D, in_itemsize):
+    """``(batches, heads, block_q, block_k)`` of the fused backward, from the
+    shape alone: the heads a block of rows holds in its lanes, a tile of up to
+    ``_BWD_BLOCK_K`` keys x ``_BWD_BLOCK_Q`` query rows a turn and, where one
+    tile holds all of T and S, as many batch rows a step as make about one
+    full tile and fit the budget.  ``causal`` does not enter: it decides which
+    tiles are visited.  On v5e 512 x 512 is within 5% of every larger tile
+    (1024 x 1024 needs twice the VMEM for 3%), and 8 (batch, head) pairs a
+    step the fastest at T = 256 (PERF.md section 6, PR 34)."""
+    heads = _lane_heads(H, D)
     block_q = min(_BWD_BLOCK_Q, T)
     block_k = min(_BWD_BLOCK_K, S)
-    heads = 1
+    batches = 1
     if block_q == T and block_k == S:
-        for n in range(2, _BWD_MAX_HEADS + 1):
-            if bh % n == 0 and n * T * S <= 2 * _BWD_BLOCK_Q * _BWD_BLOCK_K \
-                    and _bwd_vmem_bytes(n, T, S, T, D, in_itemsize) <= _BWD_VMEM_BUDGET:
-                heads = n
-    return heads, block_q, block_k
+        for n in range(2, _BWD_MAX_HEADS // heads + 1):
+            if B % n == 0 and n * heads * T * S <= 2 * _BWD_BLOCK_Q * _BWD_BLOCK_K \
+                    and _bwd_vmem_bytes(n, heads, T, S, T, D,
+                                        in_itemsize) <= _BWD_VMEM_BUDGET:
+                batches = n
+    return batches, heads, block_q, block_k
 
 
-def _bwd_blocks(bh, T, S, D, in_itemsize, block_q=None, block_k=None):
-    """``(heads, block_q, block_k)`` as the kernel runs them: the chooser's,
-    or a caller's explicit blocks taken as given, one head a step."""
-    heads, bq, bk = _bwd_tiles(bh, T, S, D, in_itemsize)
+def _bwd_blocks(B, H, T, S, D, in_itemsize, block_q=None, block_k=None):
+    """``(batches, heads, block_q, block_k)`` as the kernel runs them: the
+    chooser's, or a caller's explicit blocks taken as given, one batch row a
+    step."""
+    batches, heads, bq, bk = _bwd_tiles(B, H, T, S, D, in_itemsize)
     if block_q is not None or block_k is not None:
-        heads, bq, bk = 1, min(block_q or bq, T), min(block_k or bk, S)
-    return heads, bq, bk
+        batches, bq, bk = 1, min(block_q or bq, T), min(block_k or bk, S)
+    return batches, heads, bq, bk
 
 
-def _bwd_engine(bh, T, S, D, in_itemsize, block_q=None, block_k=None):
+def _bwd_engine(B, H, T, S, D, in_itemsize, block_q=None, block_k=None):
     """The backward engine for a shape: "fused" from ``_BWD_MIN_T`` query rows
     on wherever the kernel's residency fits what it may ask of the core's
     VMEM, "scan" elsewhere.  The one place the decision lives."""
-    heads, bq, bk = _bwd_blocks(bh, T, S, D, in_itemsize, block_q, block_k)
-    need = _bwd_vmem_bytes(heads, bq, bk, -(-T // bq) * bq, D, in_itemsize)
+    batches, heads, bq, bk = _bwd_blocks(B, H, T, S, D, in_itemsize, block_q, block_k)
+    need = _bwd_vmem_bytes(batches, heads, bq, bk, -(-T // bq) * bq, D, in_itemsize)
     return "fused" if T >= _BWD_MIN_T and need <= _BWD_VMEM_BUDGET else "scan"
 
 
-def _flash_bwd(causal, sm_scale, block_q, block_k, interpret, res, do):
+def _flash_bwd(n_head, causal, sm_scale, block_q, block_k, interpret, layout,
+               res, do):
     from .. import observability as obs
 
     q, k = res[0], res[1]
-    B, H, T, D = q.shape
-    S = k.shape[2]
-    shape = (B * H, T, S, D, q.dtype.itemsize, block_q, block_k)
+    B, T, HD = q.shape
+    S = k.shape[1]
+    H = n_head
+    shape = (B, H, T, S, HD // H, q.dtype.itemsize, block_q, block_k)
     engine = _bwd_engine(*shape)
     if engine == "fused":
-        heads, bq, bk = _bwd_blocks(*shape)
+        batches, heads, bq, bk = _bwd_blocks(*shape)
     else:
         # a turn of the scan is every (batch, head)'s [T, block_k] strip
-        heads, bq, bk = B * H, T, min(block_k or DEFAULT_BLOCK_K, S)
+        batches, heads, bq, bk = B, H, T, min(block_k or DEFAULT_BLOCK_K, S)
     # what was chosen, once per compiled shape (this runs at trace time): a
     # reader of a device trace divides a backward call's time by its grid
     # steps (the scan's turns), and sees which engine the shape took
     steps = obs.counter("flash.bwd.grid_steps", labels={
-        "T": T, "S": S, "block": "%dx%d" % (bq, bk), "bh": B * H,
-        "causal": int(bool(causal)), "engine": engine})
+        "T": T, "S": S, "block": "%dx%d" % (bq, bk), "heads": batches * heads,
+        "bh": B * H, "causal": int(bool(causal)), "engine": engine,
+        "layout": layout})
     if not steps.value:
-        steps.inc((B * H // heads) * -(-S // bk))
+        steps.inc((B // batches) * (H // heads) * -(-S // bk))
     if engine == "fused":
-        return _flash_bwd_fused(causal, sm_scale, heads, bq, bk, interpret, res, do)
-    return _flash_bwd_scan(causal, sm_scale, bk, res, do)
+        return _flash_bwd_fused(n_head, causal, sm_scale, batches, bq, bk,
+                                interpret, res, do)
+    return _flash_bwd_scan(n_head, causal, sm_scale, bk, res, do)
 
 
-def _fused_bwd_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, ld_ref,
-                      dq_ref, dk_ref, dv_ref, dk_scr, dv_scr, *, sm_scale,
-                      causal, heads, block_q, block_k, num_q_blocks, q_len,
-                      kv_len):
-    """One grid step = ``heads`` (batch, head) pairs x one key block against
-    the query side, which is RESIDENT (its blocks' index maps pin them on the
-    key axis, so it is fetched once a (batch, head)) and is walked INSIDE the
-    step, ``block_q`` rows a turn.  A turn is one ``[block_k, block_q]`` tile,
-    keys on the sublanes and query rows on the lanes: s and dp come out of
-    ``k . q^T`` and ``v . do^T`` that way round, p^T . do and ds^T . q need no
+def _fused_bwd_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
+                      dq_ref, dk_ref, dv_ref, dk_scr, dv_scr, delta_scr, *, sm_scale,
+                      causal, batches, heads, head_dim, block_q, block_k,
+                      num_q_blocks, q_len, kv_len):
+    """One grid step = ``batches`` batch rows x the ``heads`` heads whose
+    lanes make one block of the rows x one key block against the query side,
+    which is RESIDENT (its blocks' index maps pin them on the key axis, so it
+    is fetched once a (batch, head group)) and is walked INSIDE the step,
+    ``block_q`` rows a turn, q and do loaded once a turn for all the heads.  A
+    head is a lane slice of the block and meets the MXU as the block's full
+    lane width, as in the forward: its k and v have the other heads' lanes
+    zeroed, so ``k . q^T`` and ``v . do^T`` sum only its own lanes and ``ds .
+    k`` leaves exact zeros in the others (dq is the heads' sum, one
+    read-modify-write a turn); ``p^T . do`` and ``ds^T . q`` give every lane,
+    accumulate in a scratch a head, and the heads' own lanes are merged into
+    ONE lane-dense store of dk and dv at the end.  A turn is one ``[block_k,
+    block_q]`` tile a head, keys on the sublanes and query rows on the lanes:
+    s and dp come out that way round, p^T . do and ds^T . q need no
     transpose, and a query row's lse and delta are a lane-dense ``[1,
     block_q]`` row that broadcasts over sublanes (no ``[rows, 1]`` statistic
-    exists).  Only dq = ds . k transposes its tile.  dq accumulates straight
+    exists): lse arrives so from the forward, and delta = rowsum(do * out)
+    over each head's lanes is made so at the first key block, from the
+    resident do and out, and kept in scratch for the key walk (XLA made it
+    from a relayout of a whole activation).  Only dq = ds . k transposes its
+    tile.  dq accumulates straight
     in its f32 output block across the key walk; dk and dv in scratch across
     the turns.  Query blocks no key of the block can be seen from take no
     turn, and those every row of which sees every key skip the mask."""
@@ -568,7 +733,7 @@ def _fused_bwd_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, ld_ref,
     from jax.experimental import pallas as pl
 
     g = pl.program_id(0)
-    kj = pl.program_id(1)
+    kj = pl.program_id(2)
     k0 = kj * block_k
     shift = kv_len - q_len  # causal: row t sees keys [0, t + shift] — tril(k=S-T)
     div = jax.lax.div       # never-negative operands (see _paged_decode_kernel)
@@ -577,14 +742,10 @@ def _fused_bwd_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, ld_ref,
     def _init():
         dq_ref[...] = jnp.zeros_like(dq_ref)
 
-    def turn(h, kvl, k, v, i, masked):
+    def turn(n, kvl, ks, vs, i, masked):
         rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
-        q = q_ref[h, rows, :].astype(jnp.float32)    # [bq, d]
-        do = do_ref[h, rows, :].astype(jnp.float32)
-        ld = ld_ref[h, i]                            # [2, bq]: lse, delta
-        s = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # [bk, bq]
-        p = jnp.exp(s - ld[0:1, :])
+        q = q_ref[n, rows, :].astype(jnp.float32)    # [bq, width]
+        do = do_ref[n, rows, :].astype(jnp.float32)
         if masked:
             # one compare a score: the tile's own key index against each
             # query row's last visible one (a [1, bq] row)
@@ -593,24 +754,51 @@ def _fused_bwd_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, ld_ref,
                 last = jnp.minimum(last, i * block_q + shift + jax.lax.broadcasted_iota(
                     jnp.int32, (1, block_q), 1))
             key = k0 + jax.lax.broadcasted_iota(jnp.int32, (block_k, block_q), 0)
-            p = jnp.where(key <= last, p, 0.0)
-        dv_scr[...] += jnp.dot(p, do, preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(v, do, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)  # [bk, bq]
-        ds = p * (dp - ld[1:2, :])
-        dk_scr[...] += jnp.dot(ds, q, preferred_element_type=jnp.float32)
-        dq_ref[h, rows, :] += jax.lax.dot_general(
-            ds, k, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            seen = key <= last
+        dq = None
+        for h, (k, v) in enumerate(zip(ks, vs)):
+            s = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)  # [bk, bq]
+            p = jnp.exp(s - lse_ref[n, h, i])            # a [1, bq] row
+            if masked:
+                p = jnp.where(seen, p, 0.0)
+            dv_scr[h] += jnp.dot(p, do, preferred_element_type=jnp.float32)
+            dp = jax.lax.dot_general(v, do, (((1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32)  # [bk, bq]
+            ds = p * (dp - delta_scr[n, h, i][0:1])
+            dk_scr[h] += jnp.dot(ds, q, preferred_element_type=jnp.float32)
+            d = jax.lax.dot_general(
+                ds, k, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            dq = d if dq is None else dq + d
+        dq_ref[n, rows, :] += dq
 
-    def head(h, carry):
-        kvl = lens_ref[g * heads + h]  # valid key length of this (batch, head)
+    def batch(n, carry):
+        kvl = lens_ref[g * batches + n]  # valid key length of this batch row
+
+        @pl.when(kj == 0)
+        def _delta():
+            def block(i, _):
+                rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+                prod = (do_ref[n, rows, :].astype(jnp.float32)
+                        * o_ref[n, rows, :].astype(jnp.float32))  # [bq, width]
+                for h in range(heads):
+                    mine = _head_lanes((8, prod.shape[1]), h, head_dim)
+                    delta_scr[n, h, i] = _lane_sums_as_rows(
+                        prod, mine.astype(jnp.float32))      # [8, bq], rows alike
+            jax.lax.fori_loop(0, num_q_blocks, block, None)
+
         # key rows from kv_len on are zeroed (an OOB-padded tail tile holds
-        # anything: 0 * NaN would poison dq), and the softmax scale goes into
-        # k once a step: s = q . (scale k), dq = ds . (scale k), dk is scaled
-        # once at the end
-        live = k0 + jax.lax.broadcasted_iota(jnp.int32, (block_k, 1), 0) < kvl
-        k = jnp.where(live, k_ref[h].astype(jnp.float32) * sm_scale, 0.0)
-        v = jnp.where(live, v_ref[h].astype(jnp.float32), 0.0)
+        # anything: 0 * NaN would poison dq) and so are the other heads'
+        # lanes, and the softmax scale goes into k once a step: s = q .
+        # (scale k), dq = ds . (scale k), dk is scaled once at the end
+        k = k_ref[n].astype(jnp.float32) * sm_scale
+        v = v_ref[n].astype(jnp.float32)
+        live = k0 + jax.lax.broadcasted_iota(jnp.int32, k.shape, 0) < kvl
+        ks, vs = [], []
+        for h in range(heads):
+            mine = live if heads == 1 else live & _head_lanes(k.shape, h, head_dim)
+            ks.append(jnp.where(mine, k, 0.0))
+            vs.append(jnp.where(mine, v, 0.0))
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
@@ -619,23 +807,25 @@ def _fused_bwd_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, ld_ref,
         # no turn, blocks from `every` on no mask.  A key block that kv_len
         # cuts masks every turn; one past kv_len takes none (kv_len == 0:
         # exact zeros in dq, dk and dv).
-        n = num_q_blocks
+        nq = num_q_blocks
         some = every = 0
         if causal:
-            some = jnp.minimum(div(jnp.maximum(k0 - shift, 0), block_q), n)
+            some = jnp.minimum(div(jnp.maximum(k0 - shift, 0), block_q), nq)
             every = jnp.minimum(div(jnp.maximum(
-                k0 + block_k - 1 - shift, 0) + block_q - 1, block_q), n)
-        some = jnp.where(k0 < kvl, some, n)
-        every = jnp.where(k0 + block_k <= kvl, every, n)
+                k0 + block_k - 1 - shift, 0) + block_q - 1, block_q), nq)
+        some = jnp.where(k0 < kvl, some, nq)
+        every = jnp.where(k0 + block_k <= kvl, every, nq)
         jax.lax.fori_loop(
-            some, every, lambda i, _: turn(h, kvl, k, v, i, True), None)
+            some, every, lambda i, _: turn(n, kvl, ks, vs, i, True), None)
         jax.lax.fori_loop(  # every >= some, whichever way they were cut
-            every, n, lambda i, _: turn(h, kvl, k, v, i, False), None)
-        dk_ref[h] = (dk_scr[...] * sm_scale).astype(dk_ref.dtype)
-        dv_ref[h] = dv_scr[...].astype(dv_ref.dtype)
+            every, nq, lambda i, _: turn(n, kvl, ks, vs, i, False), None)
+        dk = _merge_heads([dk_scr[h] for h in range(heads)], head_dim)
+        dv = _merge_heads([dv_scr[h] for h in range(heads)], head_dim)
+        dk_ref[n] = (dk * sm_scale).astype(dk_ref.dtype)
+        dv_ref[n] = dv.astype(dv_ref.dtype)
         return carry
 
-    jax.lax.fori_loop(0, heads, head, None)
+    jax.lax.fori_loop(0, batches, batch, None)
 
 
 # The name a device trace has always shown this kernel under (the sanitized
@@ -644,9 +834,9 @@ def _fused_bwd_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, ld_ref,
 _BWD_KERNEL_NAME = "transpose_jvp_flash_attention__"
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5))
-def _flash_bwd_fused(causal, sm_scale, heads, block_q, block_k, interpret,
-                     res, do):
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5, 6))
+def _flash_bwd_fused(n_head, causal, sm_scale, batches, block_q, block_k,
+                     interpret, res, do):
     """dq + dk + dv in ONE Pallas grid (see ``_bwd_engine``).  A jit of its
     own: a step's eighteen calls are two shapes (causal or not), and each is
     then traced and lowered to Mosaic once, not at every call site (set-up
@@ -656,9 +846,11 @@ def _flash_bwd_fused(causal, sm_scale, heads, block_q, block_k, interpret,
     from jax.experimental.pallas import tpu as pltpu
 
     q, k, v, kv_lens, out, lse = res
-    B, H, T, D = q.shape
-    S = k.shape[2]
-    bh = B * H
+    B, T, HD = q.shape
+    S = k.shape[1]
+    H, D = n_head, HD // n_head
+    heads = _lane_heads(H, D)
+    width = heads * D
     bq, bk = block_q, block_k
     nq = -(-T // bq)
     nk = -(-S // bk)
@@ -667,94 +859,121 @@ def _flash_bwd_fused(causal, sm_scale, heads, block_q, block_k, interpret,
     # the query side is sliced inside a resident block, so it is padded to
     # whole query blocks: a zero do row adds nothing to dk and dv (p^T . do,
     # and ds = p * (0 - 0)), and its dq row is cut off again
-    def rows(x):
-        x = x.reshape((bh, T) + x.shape[3:])
-        return x if Tp == T else jnp.pad(x, ((0, 0), (0, Tp - T)) + ((0, 0),) * (x.ndim - 2))
+    def padded(x, axis):
+        if Tp == T:
+            return x
+        return jnp.pad(x, [(0, Tp - T if a == axis else 0) for a in range(x.ndim)])
 
-    delta = (do.astype(jnp.float32) * out.astype(jnp.float32)).sum(-1)  # [B,H,T]
-    # each query block's lse and delta as two lane-dense rows
-    ld = jnp.stack([rows(lse).reshape(bh, nq, bq),
-                    rows(delta).reshape(bh, nq, bq)], axis=2)  # [bh, nq, 2, bq]
-    lens_bh = _lens_per_head(kv_lens, B, H, S)
+    # each query block's lse as a lane-dense row: a free reshape where T is
+    # whole query blocks
+    lse_rows = padded(lse, 2).reshape(B, H, nq, 1, bq)
+    lens = _lens_per_batch(kv_lens, B, S)
 
-    q_side = pl.BlockSpec((heads, Tp, D), lambda g, j, lens: (g, 0, 0))
-    k_side = pl.BlockSpec((heads, bk, D), lambda g, j, lens: (g, j, 0))
+    q_side = pl.BlockSpec((batches, Tp, width), lambda g, c, j, lens: (g, 0, c))
+    k_side = pl.BlockSpec((batches, bk, width), lambda g, c, j, lens: (g, j, c))
     dq, dk, dv = pl.pallas_call(
         functools.partial(
-            _fused_bwd_kernel, sm_scale=sm_scale, causal=causal, heads=heads,
-            block_q=bq, block_k=bk, num_q_blocks=nq, q_len=T, kv_len=S),
+            _fused_bwd_kernel, sm_scale=sm_scale, causal=causal, batches=batches,
+            heads=heads, head_dim=D, block_q=bq, block_k=bk, num_q_blocks=nq,
+            q_len=T, kv_len=S),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(bh // heads, nk),
+            grid=(B // batches, H // heads, nk),
             in_specs=[
                 q_side,                                                   # q
                 k_side,                                                   # k
                 k_side,                                                   # v
                 q_side,                                                   # do
-                pl.BlockSpec((heads, nq, 2, bq), lambda g, j, lens: (g, 0, 0, 0)),
+                q_side,                                                   # out
+                pl.BlockSpec((batches, heads, nq, 1, bq),
+                             lambda g, c, j, lens: (g, c, 0, 0, 0)),
             ],
             out_specs=[q_side, k_side, k_side],                           # dq, dk, dv
-            scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
-                            pltpu.VMEM((bk, D), jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((heads, bk, width), jnp.float32),
+                            pltpu.VMEM((heads, bk, width), jnp.float32),
+                            pltpu.VMEM((batches, heads, nq, 8, bq), jnp.float32)],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((bh, Tp, D), jnp.float32),
-            jax.ShapeDtypeStruct((bh, S, D), k.dtype),
-            jax.ShapeDtypeStruct((bh, S, D), v.dtype),
+            jax.ShapeDtypeStruct((B, Tp, HD), jnp.float32),
+            jax.ShapeDtypeStruct((B, S, HD), k.dtype),
+            jax.ShapeDtypeStruct((B, S, HD), v.dtype),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=_bwd_vmem_limit(heads, bq, bk, Tp, D,
-                                             q.dtype.itemsize),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(_bwd_vmem_bytes(
+                batches, heads, bq, bk, Tp, D, q.dtype.itemsize)),
         ),
         interpret=interpret,
         name=_BWD_KERNEL_NAME,
-    )(lens_bh, rows(q), k.reshape(bh, S, D), v.reshape(bh, S, D), rows(do), ld)
-    return (
-        dq[:, :T].astype(q.dtype).reshape(B, H, T, D),
-        dk.reshape(B, H, S, D),
-        dv.reshape(B, H, S, D),
-    )
+    )(lens, padded(q, 1), k, v, padded(do, 1), padded(out, 1), lse_rows)
+    return dq[:, :T].astype(q.dtype), dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def flash_attention(q, k, v, kv_lens=None, causal=False, sm_scale=None,
-                    block_q=None, block_k=None, interpret=None):
-    """Fused attention, [B, H, T, D] → [B, H, T, D].  ``kv_lens`` ([B] int32)
-    masks keys past each sequence's length (padding mask).  ``block_q`` /
-    ``block_k`` of None mean "chosen from the shape" (``_fwd_tiles`` and, for
-    the backward, ``_bwd_tiles``); a given value is taken as given by both."""
-    out, _ = _flash_impl(q, k, v, kv_lens, causal, sm_scale, block_q, block_k, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
+def flash_attention_rows(q, k, v, kv_lens=None, n_head=1, causal=False,
+                         sm_scale=None, block_q=None, block_k=None,
+                         interpret=None, layout="rows"):
+    """Fused attention on the projections' own rows: q ``[B, T, H * D]``, k
+    and v ``[B, S, H * D]`` → ``[B, T, H * D]``, head ``h`` the lanes ``h * D
+    : (h + 1) * D`` (``n_head`` = H).  No head is split off or merged back in
+    HBM on either side of the kernels, forward or backward.  ``kv_lens``
+    ([B] int32) masks keys past each sequence's length (padding mask).
+    ``block_q`` / ``block_k`` of None mean "chosen from the shape"
+    (``_fwd_tiles`` and, for the backward, ``_bwd_tiles``); a given value is
+    taken as given by both.  ``layout`` only labels the grid-step counters
+    with the entry a compiled shape came through."""
+    out, _ = _flash_impl(q, k, v, kv_lens, n_head, causal, sm_scale, block_q,
+                         block_k, interpret, layout)
     return out
 
 
-def _flash_impl(q, k, v, kv_lens, causal, sm_scale, block_q, block_k, interpret):
-    if causal and q.shape[2] > k.shape[2]:
+def flash_attention(q, k, v, kv_lens=None, causal=False, sm_scale=None,
+                    block_q=None, block_k=None, interpret=None):
+    """Fused attention, [B, H, T, D] → [B, H, T, D]: ``flash_attention_rows``
+    behind a transpose at its edge, so the kernels a caller of this entry
+    checks are the kernels a caller of the rows entry runs."""
+    out = flash_attention_rows(_to_rows(q), _to_rows(k), _to_rows(v), kv_lens,
+                               q.shape[1], causal, sm_scale, block_q, block_k,
+                               interpret, "bhtd")
+    return _from_rows(out, q.shape[1])
+
+
+def _flash_impl(q, k, v, kv_lens, n_head, causal, sm_scale, block_q, block_k,
+                interpret, layout):
+    if q.shape[2] % n_head:
+        raise ValueError("flash_attention_rows: rows of %d lanes do not hold "
+                         "n_head=%d whole heads" % (q.shape[2], n_head))
+    if causal and q.shape[1] > k.shape[1]:
         # Bottom-right-aligned tril(k=S-T) leaves rows t < T-S with zero
         # visible keys; the online softmax has no meaningful value there
         # (the reference degenerates to a uniform mean over masked keys).
         raise ValueError(
             "causal flash_attention requires T <= S, got T=%d S=%d"
-            % (q.shape[2], k.shape[2])
+            % (q.shape[1], k.shape[1])
         )
     if sm_scale is None:
-        sm_scale = 1.0 / float(np.sqrt(q.shape[-1]))
+        sm_scale = 1.0 / float(np.sqrt(q.shape[2] // n_head))
     if interpret is None:
         interpret = cpu_backend()
-    return _flash_fwd(q, k, v, kv_lens, causal, sm_scale, block_q, block_k, interpret)
+    return _flash_fwd(q, k, v, kv_lens, n_head, causal, sm_scale, block_q,
+                      block_k, interpret, layout)
 
 
-def _flash_vjp_fwd(q, k, v, kv_lens, causal, sm_scale, block_q, block_k, interpret):
-    out, lse = _flash_impl(q, k, v, kv_lens, causal, sm_scale, block_q, block_k, interpret)
+def _flash_vjp_fwd(q, k, v, kv_lens, n_head, causal, sm_scale, block_q, block_k,
+                   interpret, layout):
+    out, lse = _flash_impl(q, k, v, kv_lens, n_head, causal, sm_scale, block_q,
+                           block_k, interpret, layout)
     return out, (q, k, v, kv_lens, out, lse)
 
 
-def _flash_vjp_bwd(causal, sm_scale, block_q, block_k, interpret, res, do):
+def _flash_vjp_bwd(n_head, causal, sm_scale, block_q, block_k, interpret, layout,
+                   res, do):
     if sm_scale is None:
-        sm_scale = 1.0 / float(np.sqrt(res[0].shape[-1]))
+        sm_scale = 1.0 / float(np.sqrt(res[0].shape[2] // n_head))
     if interpret is None:
         interpret = cpu_backend()
-    dq, dk, dv = _flash_bwd(causal, sm_scale, block_q, block_k, interpret, res, do)
+    dq, dk, dv = _flash_bwd(n_head, causal, sm_scale, block_q, block_k,
+                            interpret, layout, res, do)
     kv_lens = res[3]
     dlens = None
     if kv_lens is not None:
@@ -762,7 +981,7 @@ def _flash_vjp_bwd(causal, sm_scale, block_q, block_k, interpret, res, do):
     return dq, dk, dv, dlens
 
 
-flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
+flash_attention_rows.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
 # ---------------------------------------------------------------------------

@@ -24,16 +24,21 @@ from paddle_tpu.parallel import flash_attention as FA
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def four_chips():
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2").devices
     except Exception as e:  # noqa: BLE001 — no TPU compiler here
         pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
-    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def one_chip(four_chips):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(four_chips[0])
 
 
 @contextlib.contextmanager
@@ -64,14 +69,15 @@ def _kernel_calls(fn, *args):
 
 
 # (shape [B, H, T, D], compiled kernels expected in fwd+bwd): the forward is
-# always one, and the backward the fused one-grid kernel from T = 512 on (the
+# always one, and the backward the fused one-grid kernel from T = 256 on (the
 # XLA scan, no kernel, under it and past the kernel's VMEM budget); here at
 # explicit blocks of 128, the query side resident all the same
 @pytest.mark.parametrize("shape,kernels", [
-    ((64, 8, 256, 64), 1),     # Transformer-base training shape: scan
+    ((128, 8, 128, 64), 1),    # under the kernel's least T: scan
+    ((64, 8, 256, 64), 2),     # Transformer-base training shape
     ((8, 8, 2048, 64), 2),
     ((4, 8, 4096, 64), 2),     # 16.70M of 16.00M scoped before PR 34
-], ids=["b64xT256-scan", "b8xT2048-fused", "b4xT4096-fused"])
+], ids=["b128xT128-scan", "b64xT256-fused", "b8xT2048-fused", "b4xT4096-fused"])
 def test_flash_fwd_bwd_compiles_for_v5e(one_chip, no_persistent_cache, shape,
                                         kernels):
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
@@ -87,19 +93,19 @@ def test_flash_fwd_bwd_compiles_for_v5e(one_chip, no_persistent_cache, shape,
 # What the three training cells hand the kernel: ``decorate`` leaves the
 # activations f32 (twice the tile bytes of the bf16 cases above), every call
 # brings ``kv_lens``, and the tiles are the choosers' own (``block_q =
-# block_k = None``).  A tile that fits scoped VMEM on paper (``_fwd_vmem_bytes``;
-# the backward's ``_bwd_vmem_bytes`` inside the limit it is compiled with,
-# ``_bwd_vmem_limit``) has to fit it here.
+# block_k = None``).  A tile that fits VMEM on paper (``_fwd_vmem_bytes`` and
+# ``_bwd_vmem_bytes``, each inside the limit its kernel is compiled with,
+# ``_vmem_limit``) has to fit it here.
 @pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
 @pytest.mark.parametrize("shape,kernels", [
-    ((64, 8, 256, 64), 1),
-    ((32, 8, 512, 64), 2),     # the least T of the kernel: two heads a step
+    ((64, 8, 256, 64), 2),     # the least T of the kernel: 4 batch rows a step
+    ((32, 8, 512, 64), 2),
     ((8, 8, 2048, 64), 2),
     ((4, 8, 4096, 64), 2),
     ((1, 8, 65536, 64), 1),    # the query side past the budget: scan backward
-], ids=["b64xT256-scan", "b32xT512-fused", "b8xT2048-fused", "b4xT4096-fused",
+], ids=["b64xT256-fused", "b32xT512-fused", "b8xT2048-fused", "b4xT4096-fused",
         "b1xT65536-scan"])
 def test_flash_chosen_tiles_compile_for_v5e(one_chip, no_persistent_cache,
                                             shape, kernels, dtype, causal):
@@ -111,6 +117,104 @@ def test_flash_chosen_tiles_compile_for_v5e(one_chip, no_persistent_cache,
         return out.astype(jnp.float32).sum()
 
     assert _kernel_calls(jax.grad(loss, argnums=(0, 1, 2)), x, x, x, lens) == kernels
+
+
+def _relayouts(text, B, T, H, D):
+    """The compiled program's ``copy`` / ``transpose`` instructions that give
+    an activation-sized array: ``[B, T, H, D]``- or ``[B, H, T, D]``-shaped,
+    or the ``[B, T, H * D]`` rows in another layout."""
+    shapes = "|".join([r"%d,%d,%d,%d" % (B, T, H, D), r"%d,%d,%d,%d" % (B, H, T, D),
+                       r"%d,%d,%d" % (B * H, T, D), r"%d,%d,%d" % (B, T, H * D)])
+    return re.findall(r"= \w+\[(?:%s)\]\S* (?:copy|transpose)\(" % shapes, text)
+
+
+# What the cells' step programs call: the rows entry on the projections' own
+# f32 [B, T, H * D] rows, every call with ``kv_lens``, the tiles the choosers'
+# own.  Beside the custom calls the compiled program moves nothing: a head is
+# never split off or merged back in HBM.
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("B,T", [(64, 256), (8, 2048), (4, 4096)],
+                         ids=["s256", "s2048", "s4096"])
+def test_flash_rows_compile_at_the_cells_shapes(one_chip, no_persistent_cache,
+                                                B, T, causal):
+    H, D = 8, 64
+    x = jax.ShapeDtypeStruct((B, T, H * D), jnp.float32, sharding=one_chip)
+    lens = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)
+
+    def loss(q, k, v, w, lens):
+        out = FA.flash_attention_rows(q, k, v, lens, H, causal, interpret=False)
+        return (out * w).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x, x, lens).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "flash_attention_fwd" in text and FA._BWD_KERNEL_NAME in text
+    assert _relayouts(text, B, T, H, D) == []
+
+
+def test_flash_rows_compile_under_a_dp_x_tp_mesh(four_chips, no_persistent_cache,
+                                                monkeypatch):
+    """What ``ParallelExecutor`` on a 2 x 2 mesh hands the op (the smoke's
+    ``phase_mesh``): rows split on ``dp`` and their ``H * D`` axis on ``tp``
+    by whole head groups, each device its own kernels on its own block, no
+    collective and no relayout around them."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from paddle_tpu.ops.attention_ops import _flash_on_mesh
+
+    monkeypatch.setattr(FA, "cpu_backend", lambda: False)  # compile the kernels
+    mesh = Mesh(np.array(four_chips).reshape(2, 2), ("dp", "tp"))
+    B, T, H, D = 64, 256, 8, 64
+    x = jax.ShapeDtypeStruct((B, T, H * D), jnp.float32,
+                             sharding=NamedSharding(mesh, P("dp", None, "tp")))
+    lens = jax.ShapeDtypeStruct((B,), jnp.int32,
+                                sharding=NamedSharding(mesh, P("dp")))
+
+    def loss(q, k, v, lens):
+        return _flash_on_mesh(q, k, v, lens, H, True, mesh).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x, lens).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert not re.findall(r" (?:all-gather|all-to-all|all-reduce|collective-permute)"
+                          r"(?:-start)?\(", text)
+    assert _relayouts(text, B // 2, T, H // 2, D) == []
+
+
+def test_attention_block_reads_the_projections_rows_on_v5e(
+        one_chip, no_persistent_cache, monkeypatch):
+    """Transformer-base's attention block at ``tfbase_train_s2048``'s widths
+    under ``decorate``, forward and backward: three projections, the flash
+    forward, the output projection, the fused flash backward and the four
+    weight gradients, and between the projections and the two custom calls no
+    ``copy`` or ``transpose`` of an activation (the parent's block held
+    eight: q, k, v split and out merged in f32, their gradients in bf16)."""
+    import paddle_tpu as fluid
+    from paddle_tpu.contrib import mixed_precision
+    from paddle_tpu.jax_bridge import program_to_fn
+    from paddle_tpu.models import transformer as T
+
+    monkeypatch.setattr(FA, "cpu_backend", lambda: False)  # compile the kernels
+    B, S, D, H = 8, 2048, 512, 8
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        x = fluid.layers.data(name="x", shape=[S, D], dtype="float32")
+        lens = fluid.layers.data(name="lens", shape=[], dtype="int32")
+        y = T.multi_head_attention(x, None, None, None, D // H, D // H, D, H,
+                                   use_flash=True, flash_causal=True, kv_lens=lens)
+        mixed_precision.decorate(fluid.optimizer.SGD(0.1)).minimize(
+            fluid.layers.reduce_mean(fluid.layers.square(y)))
+    params = {v.name: jax.ShapeDtypeStruct(v.shape, jnp.float32, sharding=one_chip)
+              for v in main.list_vars() if v.persistable and v.shape}
+    text = jax.jit(program_to_fn(main, [], return_state=True)).lower(
+        params, {"x": jax.ShapeDtypeStruct((B, S, D), jnp.float32, sharding=one_chip),
+                 "lens": jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)},
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip),
+    ).compile().as_text()
+    calls = re.findall(r"%(\S+) = .* custom-call\(.*tpu_custom_call", text)
+    assert sorted(c.rsplit(".", 1)[0] for c in calls) == [
+        "flash_attention_fwd", FA._BWD_KERNEL_NAME], calls
+    assert _relayouts(text, B, S, H, D // H) == []
 
 
 # Transformer-base serving widths: 64 slots, 8 heads x 64, 16-token pages,
