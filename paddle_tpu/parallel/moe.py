@@ -27,7 +27,6 @@ are data-sharded over the same ``ep`` axis, and routing is two
 from __future__ import annotations
 
 import functools
-import math
 
 import numpy as np
 
@@ -143,7 +142,7 @@ def switch_moe(x, gate_w, expert_params, expert_fn, mesh, axis_name="ep",
 MOE_COUNTERS = ("pairs", "experts_touched", "max_load")
 _GMM_ROWS = 128         # rows of one product inside a grid step
 _GMM_TILE_M = 512       # rows of the sorted pairs a grid step holds
-_GMM_TILE_N = 512       # output columns a grid step computes
+_GMM_BLOCK_BYTES = 4 * 2 ** 20     # the most one expert's [K, tn] block holds
 _GMM_VMEM_DEFAULT = 14 * 2 ** 20   # what fits the compiler's own scoped limit
 
 
@@ -199,13 +198,31 @@ def _gmm_items(group_sizes, tile_m, n_tiles):
     return group.astype(jnp.int32), tile.astype(jnp.int32), offsets, n_items
 
 
+def _gmm_tile_n(K, N, itemsize):
+    """Output columns a grid step computes: the widest multiple of 128 that
+    divides ``N`` and whose weight block ``K x tn x itemsize`` is at most
+    ``_GMM_BLOCK_BYTES``; ``N`` itself where it has no such divisor.
+
+    A grid step costs its block's bytes and a cost beside them that is
+    0.3 us up to a block of about 2.6 MB and 0.6 us at 4 MB (``PERF.md``,
+    section 5), and the work is the same whatever the tile: an expert's
+    weights are read once, in ``N / tn`` blocks.  So the fewest steps whose
+    blocks still stream win, up to the budget: from 2 MB a block on every
+    product reads 88-91% of its bytes' speed, and 8 MiB bought 0-2% more."""
+    fit = [tn for tn in range(128, N + 1, 128)
+           if N % tn == 0 and K * tn * itemsize <= _GMM_BLOCK_BYTES]
+    return max(fit, default=N)
+
+
 def _gmm_kernel(group_ref, tile_ref, off_ref, n_ref, x_ref, w_ref, o_ref, *,
                 tile_m, rows):
     """One grid step = one (column tile, item): the rows of item's group that
     lie in item's row tile, against that group's ``[K, tn]`` block, ``rows``
     at a time (a product of fewer rows costs the MXU as much: it is the
-    block's load that takes the time).  Consecutive items of one row tile
-    keep the output block; its first item zeroes it."""
+    block's load that takes the time, and a fixed cost a step beside it,
+    which is why ``_gmm_tile_n`` makes the block as wide as its budget
+    allows).  Consecutive items of one row tile keep the output block; its
+    first item zeroes it."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -245,12 +262,20 @@ def grouped_matmul(x, w, group_sizes, *, layer=None, impl=None,
     group 0, and so on.  Rows past ``sum(group_sizes)`` come back ZERO.
     Returns ``[M, N]`` float32.  A group with no row costs nothing: its
     weights are not read.  ``impl``: None/"auto" (the Pallas kernel on a TPU,
-    ``lax.ragged_dot`` elsewhere), "reference", "pallas"."""
+    ``lax.ragged_dot`` elsewhere), "reference", "pallas".
+
+    The kernel's grid is ``(N / tn, G + row tiles - 1)``: one expert's ``[K,
+    tn]`` block a step, the contraction never tiled.  ``tn`` follows from the
+    block's bytes (:func:`_gmm_tile_n`: a step's fixed cost beside its
+    block's bytes is what a narrow tile pays for), and counter
+    ``moe.gmm.grid_steps{K,N,tn,tile_m,rows}`` (``rows`` = ``M``) says what
+    each compiled shape got."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    from .. import observability as obs
     from ..core import cpu_backend
 
     if impl in (None, "auto"):
@@ -273,29 +298,38 @@ def grouped_matmul(x, w, group_sizes, *, layer=None, impl=None,
     rows = min(_GMM_ROWS, -(-M // 16) * 16)
     tile_m = min(_GMM_TILE_M, -(-M // rows) * rows)
     m_pad = -(-M // tile_m) * tile_m
-    tn = math.gcd(N, _GMM_TILE_N)
+    item = jnp.dtype(w.dtype).itemsize
+    tn = _gmm_tile_n(K, N, item)
     if m_pad != M:
         x = jnp.pad(x, ((0, m_pad - M), (0, 0)))
     n_tiles = m_pad // tile_m
+    # what was chosen, once per compiled shape (this runs at trace time): a
+    # reader of a device trace divides the call's time by its grid steps
+    steps = obs.counter("moe.gmm.grid_steps", labels={
+        "K": K, "N": N, "tn": tn, "tile_m": tile_m, "rows": M})
+    grid = (N // tn, G + n_tiles - 1)
+    if not steps.value:
+        steps.inc(grid[0] * grid[1])
     group, tile, offsets, n_items = _gmm_items(group_sizes, tile_m, n_tiles)
     lead = () if layer is None else (None,)
     at = () if layer is None else (int(layer),)
     # both operand blocks twice (the next step's copy in flight), the output
     # block twice, and the weight block once more as the value the products
-    # read; stated to the compiler only where it passes its default (a
-    # contraction of 4096, or of 3072 under a chunk's 512-row tiles: 17.8 MB;
-    # the blocks of narrower models, and 3072 at a decode step's 256 rows,
-    # fit as they are)
-    item = jnp.dtype(w.dtype).itemsize
+    # read; stated to the compiler only where it passes its default, beside
+    # the [rows, tn] float32 product and its select (any block near the 4 MiB
+    # budget does; 3072 x 512 at a decode step's 256 rows, 13.6 MB, and the
+    # tests' toy shapes fit as they are).  The widest cases under the budget:
+    # mellum2's down product, whose output block is the full [512, 2304],
+    # 23.7 MB; a [512, 4096] row tile against a [4096, 512] block, 23.1 MB
     need = (2 * item * (tile_m * K + K * tn) + 2 * 4 * tile_m * tn
             + item * K * tn)
-    limit = ({} if need <= _GMM_VMEM_DEFAULT
+    limit = ({} if need + 2 * 4 * rows * tn <= _GMM_VMEM_DEFAULT
              else dict(vmem_limit_bytes=int(need + 16 * 2 ** 20)))
     (out,) = pl.pallas_call(
         functools.partial(_gmm_kernel, tile_m=tile_m, rows=rows),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
-            grid=(N // tn, G + n_tiles - 1),
+            grid=grid,
             in_specs=[
                 pl.BlockSpec((tile_m, K),
                              lambda j, i, g, t, o, n: (t[i], 0)),

@@ -570,7 +570,8 @@ def test_a_row_tile_boundary_inside_a_group(monkeypatch):
     reaches, at tile sizes small enough to have several."""
     monkeypatch.setattr(moe, "_GMM_ROWS", 16)
     monkeypatch.setattr(moe, "_GMM_TILE_M", 32)
-    monkeypatch.setattr(moe, "_GMM_TILE_N", 128)
+    monkeypatch.setattr(moe, "_GMM_BLOCK_BYTES", 32 * 128 * 4)
+    assert moe._gmm_tile_n(32, 256, 4) == 128       # two column tiles
     ks = jax.random.split(jax.random.PRNGKey(6), 2)
     x = jax.random.normal(ks[0], (160, 32))
     w = jax.random.normal(ks[1], (6, 32, 256)) * 0.2
@@ -579,6 +580,62 @@ def test_a_row_tile_boundary_inside_a_group(monkeypatch):
         a = moe.grouped_matmul(x, w, sizes, impl="reference")
         b = moe.grouped_matmul(x, w, sizes, impl="pallas", interpret=True)
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+
+
+# (K, N) of gate-and-up and down at the benchmark's four expert
+# configurations, bf16: the column tile the 4 MiB block budget gives each
+_COLUMN_TILES = {
+    "mellum2-gate-up": (2304, 1792, 896), "mellum2-down": (896, 2304, 2304),
+    "kanana2-gate-up": (2048, 1536, 768), "kanana2-down": (768, 2048, 2048),
+    # 4096 x 512 x 2 B is the budget to the byte; 640 would be 5.2 MB
+    "solar2-gate-up": (4096, 2560, 512), "solar2-down": (1280, 4096, 1024),
+    # 768 would be 4.7 MB: `trinity_open_mixedlen` keeps the programs it had
+    "trinity-gate-up": (3072, 6144, 512), "trinity-down": (3072, 3072, 512),
+    # no multiple of 128 divides N: the whole width, one column tile
+    "toy-48": (32, 48, 48), "toy-64": (64, 64, 64), "toy-200": (16, 200, 200),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_COLUMN_TILES))
+def test_the_column_tile_follows_the_weight_blocks_bytes(case):
+    K, N, tn = _COLUMN_TILES[case]
+    assert moe._gmm_tile_n(K, N, 2) == tn
+    assert N % tn == 0
+    assert tn % 128 or K * tn * 2 <= moe._GMM_BLOCK_BYTES
+
+
+@pytest.mark.parametrize("tiles", ["several", "one"])
+@pytest.mark.parametrize("N", [7 * 128, 9 * 128])
+def test_wide_column_tiles_are_ragged_dot(monkeypatch, N, tiles):
+    """Mellum2's widths in 128s (7 and 9 column tiles at the narrowest, one
+    at the widest), groups that straddle row tiles, a tile no group reaches:
+    the same product whatever the budget makes of the columns."""
+    K = 32
+    monkeypatch.setattr(moe, "_GMM_ROWS", 16)
+    monkeypatch.setattr(moe, "_GMM_TILE_M", 32)
+    monkeypatch.setattr(moe, "_GMM_BLOCK_BYTES",
+                        K * 4 * (128 if tiles == "several" else N))
+    assert moe._gmm_tile_n(K, N, 4) == (128 if tiles == "several" else N)
+    ks = jax.random.split(jax.random.PRNGKey(N), 2)
+    x = jax.random.normal(ks[0], (96, K))
+    w = jax.random.normal(ks[1], (5, K, N)) * 0.2
+    sizes = jnp.asarray([37, 0, 3, 29, 1], jnp.int32)
+    a = moe.grouped_matmul(x, w, sizes, impl="reference")
+    b = moe.grouped_matmul(x, w, sizes, impl="pallas", interpret=True)
+    np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+    assert not np.asarray(b)[70:].any()
+
+
+def test_the_grid_steps_counter_says_which_tile_a_shape_got():
+    from paddle_tpu import observability as obs
+
+    labels = {"K": 32, "N": 384, "tn": 384, "tile_m": 32, "rows": 24}
+    x, w = jnp.zeros((24, 32)), jnp.zeros((5, 32, 384))
+    sizes = jnp.asarray([4, 0, 9, 1, 2], jnp.int32)
+    for _ in range(2):      # a second trace of the shape counts nothing more
+        jax.make_jaxpr(lambda x, w, s: moe.grouped_matmul(
+            x, w, s, impl="pallas", interpret=True))(x, w, sizes)
+    assert obs.counter("moe.gmm.grid_steps", labels=labels).value == 5
 
 
 # 6. the family's refusals and the weights' form ------------------------------

@@ -513,6 +513,22 @@ def test_kda_state_decode_compiles_for_v5e(one_chip, no_persistent_cache):
     assert mem.temp_size_in_bytes < 64 * 2 ** 20
 
 
+# the grouped product where the 4 MiB block budget sets its column tile: a
+# decode step's and a chunk's pairs, the layers' weights, the tile, and the
+# blocks' bytes the kernel states (beside its 16 MiB margin) at 512-row tiles
+_MELLUM_GATE_UP = ((8, 64, 2304, 1792), 896, 20774912)
+_MELLUM_DOWN = ((8, 64, 896, 2304), 2304, 23658496)
+_SOLAR_DOWN = ((4, 40, 1280, 4096), 1024, 14 * 2 ** 20)
+_GMM_AT_THE_BUDGET = {
+    "mellum2-gate-up-decode": (512,) + _MELLUM_GATE_UP,
+    "mellum2-gate-up-chunk": (4096,) + _MELLUM_GATE_UP,
+    "mellum2-down-decode": (512,) + _MELLUM_DOWN,
+    "mellum2-down-chunk": (4096,) + _MELLUM_DOWN,
+    "solar2-down-decode": (1024,) + _SOLAR_DOWN,
+    "solar2-down-chunk": (4096,) + _SOLAR_DOWN,
+}
+
+
 def test_grouped_matmul_compiles_at_a_contraction_of_4096(one_chip,
                                                           no_persistent_cache):
     """A hidden size of 4096 puts the two operand blocks past the compiler's
@@ -532,12 +548,49 @@ def test_grouped_matmul_compiles_at_a_contraction_of_4096(one_chip,
         assert _kernel_calls(product, sds((rows, 4096)),
                              sds((4, 40, 4096, 2560)),
                              sds((40,), jnp.int32)) == 1
-    narrow = jax.make_jaxpr(lambda x, w, s: moe.grouped_matmul(
+    # mellum2's gate-and-up at the 896 columns its block budget gives it
+    # states 20.8 MB and the margin; a block of 1 MB states nothing
+    wide = jax.make_jaxpr(lambda x, w, s: moe.grouped_matmul(
         x, w, s, impl="pallas", interpret=False))(
             jnp.zeros((512, 2304), jnp.bfloat16),
             jnp.zeros((8, 2304, 1792), jnp.bfloat16),
             jnp.zeros((8,), jnp.int32))
+    assert ("vmem_limit_bytes=%d" % (_MELLUM_GATE_UP[2] + 16 * 2 ** 20)
+            in str(wide))
+    narrow = jax.make_jaxpr(lambda x, w, s: moe.grouped_matmul(
+        x, w, s, impl="pallas", interpret=False))(
+            jnp.zeros((512, 1024), jnp.bfloat16),
+            jnp.zeros((8, 1024, 512), jnp.bfloat16),
+            jnp.zeros((8,), jnp.int32))
     assert "vmem_limit_bytes=None" in str(narrow)
+
+
+@pytest.mark.parametrize("case", sorted(_GMM_AT_THE_BUDGET))
+def test_grouped_matmul_compiles_at_blocks_of_4_mb(one_chip,
+                                                   no_persistent_cache, case):
+    """A ``[K, tn]`` block at the budget, three copies of it beside the row
+    tile and the float32 output block (the whole ``[512, 2304]`` in mellum2's
+    down product): one custom call, compiled with the VMEM it states.
+    solar_open2's down product states it for the ``[128, 1024]`` float32
+    product and its select alone: its blocks come to 14 MiB to the byte, and
+    the compiler allocated 16.5 MB against its own 16."""
+    from paddle_tpu.parallel import moe
+
+    pairs, weights, tn, need = _GMM_AT_THE_BUDGET[case]
+    K, N = weights[-2:]
+    assert moe._gmm_tile_n(K, N, 2) == tn
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def fn(x, w, sizes):
+        return moe.grouped_matmul(x, w, sizes, layer=weights[0] - 1,
+                                  impl="pallas", interpret=False)
+
+    args = (sds((pairs, K)), sds(weights), sds(weights[1:2], jnp.int32))
+    assert _kernel_calls(fn, *args) == 1
+    assert ("vmem_limit_bytes=%d" % (need + 16 * 2 ** 20)
+            in str(jax.make_jaxpr(fn)(*args)))
 
 
 def test_ffn_block_draws_each_dropout_mask_once_on_v5e(one_chip,
