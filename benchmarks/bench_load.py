@@ -1,8 +1,8 @@
 """Open-loop SLO load harness: Poisson/bursty arrivals, goodput by class.
 
-``bench_serving.py`` measures CLOSED-loop throughput: 8 clients that
-wait for an answer before sending the next request, so offered load
-self-throttles to whatever the engine serves.  "Millions of users" do
+A CLOSED-loop load is clients that wait for an answer before sending the
+next request, so offered load self-throttles to whatever the engine
+serves.  "Millions of users" do
 not behave like that — arrivals are an OPEN-loop process that keeps
 coming whether or not the engine keeps up, and the question stops being
 "how many requests/s" and becomes "what fraction of requests get a

@@ -448,7 +448,10 @@ class JitStepCache:
     The decode runtime (serving/decode_scheduler.py) keys its prefill
     buckets and its one fixed-shape decode step here; because every
     dispatch goes through :meth:`get`, "zero misses after warmup" is
-    exactly "zero recompiles after warmup".
+    exactly "zero recompiles after warmup".  Its ``decode`` and ``chunk``
+    keys hand back the callables the ``DecodeModel`` holds: a miss there
+    (the first sight, or a key evicted and asked for again) still counts
+    here, but gets the model's callable back and jax recompiles nothing.
     """
 
     def __init__(self, build, cap=64, name="jit-step"):
@@ -1081,7 +1084,7 @@ class Executor:
         # when the last run had no guard (see last_step_ok)
         self._last_guard_flag = None
         # fast-path dispatch (bound-program cache + lazy fetches); tests
-        # and bench_dispatch.py turn one off to compare with the rebind path
+        # turn one off to compare with the rebind path
         self.fast_path = True
         self.lazy_fetches = True
         # set by ParallelExecutor: jax.sharding.Mesh for data-parallel SPMD;

@@ -119,6 +119,7 @@ from .errors import (
 )
 from .kv_cache import PagedKVCache
 from .request_queue import Request, RequestQueue
+from .step_programs import StepPrograms
 from .worker import RestartableWorker
 
 __all__ = ["DecodeModel", "DecodeConfig", "DecodeJournal",
@@ -199,26 +200,6 @@ _handoff_failed = _obs.counter("serving.handoff.failed")
 _session_parked_pages = _obs.counter("serving.session.pinned")
 
 
-def _sample_token(logits, key, temp, top_k):
-    """One sampled token id: greedy argmax when ``temp <= 0``, else
-    temperature-scaled (optionally top-k-truncated) categorical draw
-    with ``key``.  Shape-stable and branch-free (``where``, not
-    ``cond``) so greedy and sampling requests share ONE compiled decode
-    step — a slot's sampling mode never changes the dispatched shape."""
-    import jax
-    import jax.numpy as jnp
-
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    z = logits / jnp.maximum(temp, 1e-6)
-    if top_k is not None:
-        # static k (a DecodeConfig knob): lax.top_k needs a compile-time
-        # k, so the menu of sampling truncations is fixed per scheduler
-        kth = jax.lax.top_k(z, top_k)[0][..., -1]
-        z = jnp.where(z < kth, -jnp.inf, z)
-    sampled = jax.random.categorical(key, z).astype(jnp.int32)
-    return jnp.where(temp > 0, sampled, greedy)
-
-
 class DecodeModel:
     """The pure-jax callables a decode-capable model exposes, its weights,
     and what it keeps in the cache.
@@ -254,8 +235,9 @@ class DecodeModel:
     A model with ``step_counters`` returns a third value, one int32 per
     name: the scheduler reads them with the tokens (one array a step) and
     adds each to the counter ``serving.decode.<name>``.  Its
-    ``prefill_chunk_fn`` may return the same third value (the scheduler sees
-    it when it traces the chunk program): it is read with the chunk's token,
+    ``prefill_chunk_fn`` may return the same third value (seen when the chunk
+    program is first traced, ``StepPrograms.chunk_counts``): it is read with
+    the chunk's token,
     and the counters are then told apart by the label the loop's intervals
     have: ``serving.decode.<name>{chunk="0"}`` the decode steps',
     ``{chunk="1"}`` the chunk programs'.
@@ -290,8 +272,9 @@ class DecodeModel:
     then ``{group: pages}``.  Without it a model has one group and receives
     the arrays themselves, as every model did.
 
-    All are jitted by the scheduler (the cache donated on TPU); they
-    must be shape-stable in everything but values.
+    Both are jitted once a model object (``step_programs``, the cache
+    donated on TPU) and every scheduler over it dispatches those callables;
+    they must be shape-stable in everything but values.
     ``models.transformer.build_decode_model``,
     ``models.minicpm_sala.build_decode_model``,
     ``models.deepseek_v3.build_decode_model``,
@@ -317,6 +300,22 @@ class DecodeModel:
         self.slot_state = dict(slot_state or {})
         self.step_counters = tuple(step_counters)
         self.page_groups = dict(page_groups or {})
+        self._programs = {}
+        self._programs_lock = threading.Lock()
+
+    def step_programs(self, top_k, donate):
+        """This model's jitted step programs (``step_programs.py``), built
+        once a ``(top_k, donate)`` and kept: every scheduler over this model
+        object dispatches the same callables, so a shape is traced once."""
+        if top_k is not None:
+            # static truncation menu; never wider than the vocabulary
+            top_k = min(top_k, self.vocab_size)
+        with self._programs_lock:
+            programs = self._programs.get((top_k, donate))
+            if programs is None:
+                programs = self._programs[top_k, donate] = StepPrograms(
+                    self, top_k, donate)
+        return programs
 
 
 class DecodeConfig:
@@ -740,9 +739,7 @@ class DecodeScheduler:
             g: _obs.counter("serving.decode.admit_waits_for_pages",
                             {"group": g})
             for g in self._cache.group_names}
-        # whether the chunk program returns the model's step counters too
-        # (seen when it is traced), and the counters' cells by program
-        self._chunk_counts = False
+        # the counters' cells by program
         self._count_cells = {}
         # decode steps dispatched and not yet read, oldest first: one
         # between iterations, two for a moment inside one (step n+1 goes
@@ -823,6 +820,7 @@ class DecodeScheduler:
             max_retries=0 if self._donated else cfg.decode_retries,
             base_delay=0.02, max_delay=0.25,
             classify=_resilience.is_transient_error)
+        self._programs = model.step_programs(cfg.top_k, donate)
         self._jit = JitStepCache(
             lambda key: self._build_step(key, donate),
             cap=2 * len(self.prefill_buckets) + 12, name="decode-steps")
@@ -881,18 +879,14 @@ class DecodeScheduler:
 
     # -- compiled steps ------------------------------------------------------
     def _build_step(self, key, donate):
+        """The callable behind a key of ``_jit``.  ``decode`` and ``chunk``
+        are the MODEL's (``DecodeModel.step_programs``: one jitted callable
+        a kind for every scheduler over the model, so an evicted key asked
+        for again gets the same callable back and nothing recompiles); the
+        rest are programs over this scheduler's cache."""
         import jax
-        import jax.numpy as jnp
 
-        model = self.model
-        # static truncation menu; never wider than the vocabulary
-        top_k = self.config.top_k
-        if top_k is not None:
-            top_k = min(top_k, model.vocab_size)
         cache = self._cache
-        # every step takes (params, pools, ...): the weights as an argument
-        # that is never donated, the cache's whole pytree donated on TPU
-        pools_arg = (1,) if donate else ()
         if key[0] == "kvguard":
             # fused isfinite sweep over the pages a step just wrote;
             # one compiled program per page-vector length (key[1])
@@ -912,59 +906,9 @@ class DecodeScheduler:
             return jax.jit(cache.scatter_pages,
                            donate_argnums=(0,) if donate else ())
         if key[0] == "decode":
-            num_slots = self.config.num_slots
-
-            def decode(params, pools, tokens, positions, tables, kv_lens,
-                       seeds, temps, previous, from_previous):
-                # a slot that decoded in the step before takes its token
-                # from that step's output, still on the device; one whose
-                # token the host holds (a prefill's first token, a hand-off,
-                # a step already read) takes ``tokens``
-                tokens = jnp.where(from_previous, previous[:num_slots],
-                                   tokens)
-                logits, pools, *counts = model.decode_fn(
-                    params, tokens, positions, pools, tables, kv_lens)
-
-                def samp(logit, seed, pos, temp):
-                    # the carried per-request key, folded with the
-                    # sampled token's ABSOLUTE position (kv_lens = the
-                    # new token's index) — identical between continuous
-                    # batching and solo serving, whatever the slot mix
-                    k = jax.random.fold_in(jax.random.PRNGKey(seed), pos)
-                    return _sample_token(logit, k, temp, top_k)
-
-                toks = jax.vmap(samp)(logits, seeds, kv_lens, temps)
-                if counts:
-                    # the model's step counters ride the tokens' readback
-                    toks = jnp.concatenate(
-                        [toks, counts[0].astype(jnp.int32)])
-                return toks, pools
-
-            return jax.jit(decode, donate_argnums=pools_arg)
-
+            return self._programs.decode
         if key[0] == "chunk":
-            def chunk(params, pools, tokens, start, valid, chunk_pages,
-                      gather_pages, slot, seed, temp):
-                logits, pools, *counts = model.prefill_chunk_fn(
-                    params, tokens, start, valid, pools, chunk_pages,
-                    gather_pages, slot)
-                # the first generated token sits at absolute position
-                # start + valid = the prompt's length at the FINAL chunk,
-                # the only one whose sample is used: the same logits row
-                # and the same key however the prompt was cut, so chunked
-                # and monolithic first tokens match bitwise
-                kk = jax.random.fold_in(jax.random.PRNGKey(seed),
-                                        start + valid)
-                tok = _sample_token(logits, kk, temp, top_k)
-                if counts:
-                    # the model's chunk counters ride the token's readback
-                    self._chunk_counts = True
-                    tok = jnp.concatenate(
-                        [tok[None], counts[0].astype(jnp.int32)])
-                return tok, pools
-
-            return jax.jit(chunk, donate_argnums=pools_arg)
-
+            return self._programs.chunk
         raise KeyError(key)
 
     def _step_counters(self, chunk):
@@ -972,11 +916,13 @@ class DecodeScheduler:
         (``chunk`` 0) or a chunk program (1).  Where the chunk program
         counts too, the label ``chunk`` tells the two apart; a model whose
         decode step alone counts keeps the unlabelled cells.  A slot decodes
-        only behind its own chunk, so the chunk program has been traced
-        before the first counts are read."""
+        only behind its own chunk, so the chunk program has been traced (by
+        this scheduler or by another over the same model) before the first
+        counts are read."""
         cells = self._count_cells.get(chunk)
         if cells is None:
-            labels = {"chunk": chunk} if self._chunk_counts else None
+            labels = ({"chunk": chunk} if self._programs.chunk_counts
+                      else None)
             cells = self._count_cells[chunk] = [
                 _obs.counter("serving.decode." + name, labels)
                 for name in self.model.step_counters]

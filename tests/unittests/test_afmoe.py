@@ -66,6 +66,13 @@ def params():
 
 
 @pytest.fixture(scope="module")
+def decode_model(params):
+    """One model object for the module: every scheduler over it dispatches
+    the model's own step programs, so a shape is traced once."""
+    return A.build_decode_model(params, CFG)
+
+
+@pytest.fixture(scope="module")
 def tokens():
     return np.random.RandomState(1).randint(1, 100, size=T_PAD).astype(np.int32)
 
@@ -386,14 +393,13 @@ def test_take_share_cuts_the_vocabulary_too():
 
 # 3. the scheduler ------------------------------------------------------------
 
-def _scheduler(params, **over):
+def _scheduler(model, **over):
     kw = dict(num_slots=2, page_size=PAGE, max_seq_len=MAX_LEN,
               num_pages={"full": 25, "window": 13},
               prefill_buckets=(8, 16, 96), prefill_chunk_tokens=16,
               max_new_tokens=STEPS, kv_dtype="float32")
     kw.update(over)
-    return serving.DecodeScheduler(A.build_decode_model(params, CFG),
-                                   serving.DecodeConfig(**kw))
+    return serving.DecodeScheduler(model, serving.DecodeConfig(**kw))
 
 
 def _cells(names, labels):
@@ -401,7 +407,7 @@ def _cells(names, labels):
 
 
 def test_one_retires_and_a_third_takes_its_slot_and_its_window_pages(
-        reference, params, tokens):
+        reference, params, decode_model, tokens):
     """Two slots and a window group of twelve pages (two bounds): the third
     request waits for a seat, takes the slot of the one that retired and
     window pages that a LIVE sequence released, and every served token of all
@@ -415,7 +421,7 @@ def test_one_retires_and_a_third_takes_its_slot_and_its_window_pages(
               for g in groups}
     released0 = obs.counter("serving.cache.pages_released",
                             {"group": "window"}).value
-    sched = _scheduler(params)
+    sched = _scheduler(decode_model)
     grp, handed = sched.cache.groups["window"], []
     real = grp.alloc
     grp.alloc = lambda n=1: handed.extend(real(n) or ()) or handed[-n:]
@@ -464,15 +470,15 @@ def test_one_retires_and_a_third_takes_its_slot_and_its_window_pages(
             < 4 * moved[1]["serving.decode.kv.full_tokens_read"])
 
 
-def test_an_admission_that_finds_the_window_group_short_is_counted(params,
-                                                                   tokens):
+def test_an_admission_that_finds_the_window_group_short_is_counted(
+        decode_model, tokens):
     """A window group of one bound and a half: the second request has a free
     slot and waits for the WINDOW group's reservation; it is counted once,
     against that group, however many iterations it stays parked."""
     cells = {g: obs.counter("serving.decode.admit_waits_for_pages",
                             {"group": g}) for g in ("full", "window")}
     before = {g: c.value for g, c in cells.items()}
-    sched = _scheduler(params, num_pages={"full": 25, "window": 9})
+    sched = _scheduler(decode_model, num_pages={"full": 25, "window": 9})
     futs = [sched.submit(tokens[:n], max_new_tokens=6) for n in (40, 33)]
     outs = [f.result(timeout=300) for f in futs]
     sched.stop()
@@ -481,6 +487,6 @@ def test_an_admission_that_finds_the_window_group_short_is_counted(params,
     assert cells["full"].value == before["full"]
 
 
-def test_a_window_group_refuses_the_prefix_cache(params):
+def test_a_window_group_refuses_the_prefix_cache(decode_model):
     with pytest.raises(serving.errors.ServingError):
-        _scheduler(params, prefix_cache=True)
+        _scheduler(decode_model, prefix_cache=True)

@@ -1,25 +1,13 @@
-"""Tier-1 wiring for the decode gate: run tools/check_decode.py (bitwise
-continuous-vs-per-sequence token equality with the zero-recompile and
+"""Tier-1 wiring for the decode gate: the scenarios of tools/check_decode.py
+(bitwise continuous-vs-per-sequence token equality with the zero-recompile and
 free-on-retire asserts, generate-path admission contracts, the
-serving.decode.* telemetry schema, and the bench_decode >=2x
-continuous-batching tokens/s smoke) in a clean subprocess on CPU and
-fail on any regression, so iteration-level decode can't rot."""
-import os
-import subprocess
-import sys
-
-REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+serving.decode.* telemetry schema, chunked prefill and the prefix cache, and
+what makes each fast as COUNTS: tokens a decode step, iterations to a short
+prompt's first token behind a long prefill, prompt tokens prefilled and pages
+hit), one case each, so iteration-level decode can't rot."""
+import _gate
 
 
-def test_decode_gate():
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["JAX_PLATFORM_NAME"] = "cpu"
-    env.pop("PADDLE_TPU_TELEMETRY", None)  # gate needs telemetry enabled
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools", "check_decode.py")],
-        env=env, capture_output=True, text=True, timeout=600)
-    assert proc.returncode == 0, (
-        "check_decode failed:\nstdout:\n%s\nstderr:\n%s"
-        % (proc.stdout, proc.stderr))
-    assert "decode gate OK" in proc.stdout
+@_gate.scenarios("check_decode")
+def test_decode_gate(scenario):
+    assert "OK" in scenario()

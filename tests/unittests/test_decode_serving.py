@@ -2,6 +2,7 @@
 generate() — the unit half of the ISSUE 6 acceptance (the end-to-end
 throughput/bitwise/no-recompile gate lives in test_decode_gate.py).
 """
+import collections
 import threading
 import time
 
@@ -502,6 +503,91 @@ class TestOneStepInFlight:
             sched.stop()
         assert 0 < d["steps_overlapped"] < d["steps"]
         assert compiles == [] and compile_count() == c0
+
+
+# -- step programs are the model's ------------------------------------------
+
+def _counting_model(base, calls, step_counters=()):
+    """``base``'s step functions behind wrappers that count their PYTHON
+    calls (one a trace) in ``calls``; with ``step_counters`` both programs
+    return one count a name beside what they returned."""
+    import jax.numpy as jnp
+
+    def wrap(kind, fn):
+        def counted(*args):
+            calls[kind] += 1
+            out = fn(*args)
+            if step_counters:
+                out += (jnp.ones((len(step_counters),), jnp.int32),)
+            return out
+        return counted
+
+    return serving.DecodeModel(
+        wrap("decode", base.decode_fn), wrap("chunk", base.prefill_chunk_fn),
+        params=base.params, num_layers=base.num_layers,
+        num_heads=base.num_heads, head_dim=base.head_dim,
+        vocab_size=base.vocab_size, step_counters=step_counters)
+
+
+class TestStepProgramsAreTheModels:
+    def test_two_schedulers_over_one_model_trace_once(self, decode_model):
+        """Two schedulers over ONE model object dispatch the model's own
+        jitted callables: the second enters ``decode_fn`` /
+        ``prefill_chunk_fn`` for no shape the first already traced, and both
+        serve what two schedulers over two model objects serve."""
+        rng = np.random.RandomState(11)
+        prompts = _prompts(6, rng)
+        cfg = dict(prefill_chunk_tokens=16)
+
+        def serve(model):
+            sched = serving.DecodeScheduler(model, _cfg(**cfg))
+            try:
+                return sched, [f.result(timeout=120).tobytes() for f in
+                               [sched.submit(p) for p in prompts]]
+            finally:
+                sched.stop()
+
+        calls = collections.Counter()
+        shared = _counting_model(decode_model, calls)
+        first, got_first = serve(shared)
+        shapes = {"decode": 1, "chunk": len(first._chunk_widths())}
+        assert dict(calls) == shapes      # once a shape, warm-up included
+        second, got_second = serve(shared)
+        assert dict(calls) == shapes      # the second traced nothing
+        for key in (("decode",), ("chunk", 16), ("chunk", 8)):
+            assert first._jit.get(key) is second._jit.get(key)
+        assert first._jit.get(("chunk", 8)) is first._jit.get(("chunk", 16))
+        apart = collections.Counter()
+        got_apart = [serve(_counting_model(decode_model, apart))[1]
+                     for _ in range(2)]
+        assert {k: 2 * n for k, n in shapes.items()} == dict(apart)
+        assert got_first == got_second == got_apart[0] == got_apart[1]
+
+    def test_chunk_counts_come_back_with_the_program(self, decode_model):
+        """Whether the chunk program counts is a fact of the model's program,
+        known once ANY scheduler has traced it: a second scheduler over the
+        model, which traces nothing, labels its step counters ``chunk``."""
+        calls = collections.Counter()
+        model = _counting_model(decode_model, calls, ("toy.rides",))
+        assert model.step_programs(None, False).chunk_counts is None
+        serving.DecodeScheduler(model, _cfg(), autostart=False)   # warms up
+        traced = dict(calls)
+        assert model.step_programs(None, False).chunk_counts is True
+        cells = [obs.counter("serving.decode.toy.rides", {"chunk": c})
+                 for c in (0, 1)]
+        before = [c.value for c in cells]
+        plain = obs.counter("serving.decode.toy.rides").value
+        sched = serving.DecodeScheduler(model, _cfg())
+        out = sched.generate(np.arange(1, 12, dtype=np.int32),
+                             max_new_tokens=4, timeout=120)
+        sched.stop()
+        assert dict(calls) == traced and len(out) == 4
+        # one chunk (an 11-token prompt in its 16-wide bucket), and a
+        # decode step a token behind the chunk's own (the last one may ride
+        # a step whose token is dropped)
+        assert cells[1].value - before[1] == 1
+        assert cells[0].value - before[0] >= 3
+        assert obs.counter("serving.decode.toy.rides").value == plain
 
 
 # -- engine integration ------------------------------------------------------

@@ -63,6 +63,13 @@ def params():
 
 
 @pytest.fixture(scope="module")
+def decode_model(params):
+    """One model object for the module: every scheduler over it dispatches
+    the model's own step programs, so a shape is traced once."""
+    return M.build_decode_model(params, CFG)
+
+
+@pytest.fixture(scope="module")
 def tokens():
     return np.random.RandomState(1).randint(1, 100, size=T_PAD).astype(np.int32)
 
@@ -152,12 +159,12 @@ def test_chunked_prefill_then_decode_equals_the_reference(params, tokens, truth,
 
 
 def test_chunked_prefill_serves_the_one_bucket_prefills_tokens_bitwise(
-        params, tokens):
+        decode_model, tokens):
     prompts = [tokens[:70], tokens[5:25], tokens[30:79], tokens[2:50]]
     outs = {}
     for name, kw in (("bucket", {"prefill_chunk_tokens": MAX_LEN}),
                      ("chunked", {"prefill_chunk_tokens": 8})):
-        sched = _scheduler(params, **kw)
+        sched = _scheduler(decode_model, **kw)
         futures = [sched.submit(p, max_new_tokens=8) for p in prompts]
         outs[name] = [f.result(timeout=300) for f in futures]
         assert sched.stats()["kv_pages_used"] == 0
@@ -217,16 +224,16 @@ def test_the_cache_keeps_the_references_latent_rows(reference, params, tokens):
 
 # 2. through the scheduler ----------------------------------------------------
 
-def _scheduler(params, **over):
+def _scheduler(model, **over):
     cfg = dict(num_slots=SLOTS, page_size=PAGE, max_seq_len=MAX_LEN,
                prefill_chunk_tokens=16, prefill_buckets=(8, 16, MAX_LEN),
                max_new_tokens=8)
     cfg.update(over)
-    return serving.DecodeScheduler(M.build_decode_model(params, CFG),
-                                   serving.DecodeConfig(**cfg))
+    return serving.DecodeScheduler(model, serving.DecodeConfig(**cfg))
 
 
-def test_the_scheduler_serves_the_references_tokens(reference, params, tokens):
+def test_the_scheduler_serves_the_references_tokens(
+        reference, params, decode_model, tokens):
     """Prefill-then-decode through ``DecodeScheduler``: every served token is
     the top of the reference's logits given the tokens served before it (to a
     near tie: float32 reordering), for a batch of prompts at once; and the
@@ -234,7 +241,7 @@ def test_the_scheduler_serves_the_references_tokens(reference, params, tokens):
     prompts = [tokens[:70], tokens[5:25], tokens[30:79]]
     before = {c: obs.counter("serving.decode." + c).value
               for c in M.STEP_COUNTERS}
-    sched = _scheduler(params)
+    sched = _scheduler(decode_model)
     futures = [sched.submit(p, max_new_tokens=8) for p in prompts]
     served = [np.asarray(f.result(timeout=300), np.int32) for f in futures]
     assert sched.stats()["kv_pages_used"] == 0
@@ -262,12 +269,12 @@ def test_the_scheduler_serves_the_references_tokens(reference, params, tokens):
 
 
 def test_a_reused_slot_and_mixed_batches_serve_what_a_lone_request_gets(
-        params, tokens):
+        decode_model, tokens):
     prompts = [tokens[:70], tokens[5:25], tokens[30:79], tokens[2:50]]
-    solo = _scheduler(params, max_active=1, num_slots=1)
+    solo = _scheduler(decode_model, max_active=1, num_slots=1)
     want = [solo.generate(p, max_new_tokens=8, timeout=300) for p in prompts]
     solo.stop()
-    batch = _scheduler(params)
+    batch = _scheduler(decode_model)
     futures = [batch.submit(p, max_new_tokens=8) for p in prompts]
     for f, w in zip(futures, want):
         np.testing.assert_array_equal(f.result(timeout=300), w)
@@ -307,7 +314,8 @@ def test_a_cache_with_no_kv_leaves_allocates_frees_and_shares_prefixes():
         serving.PagedKVCache(0, 9, 4, 0, 0, 32)
 
 
-def test_the_prefix_cache_serves_a_latent_model_the_same_tokens(params, tokens):
+def test_the_prefix_cache_serves_a_latent_model_the_same_tokens(
+        decode_model, tokens):
     rng = np.random.RandomState(3)
     prefix = tokens[:40]
     prompts = [np.concatenate([prefix, rng.randint(1, 100, size=n).astype(
@@ -315,7 +323,7 @@ def test_the_prefix_cache_serves_a_latent_model_the_same_tokens(params, tokens):
     hit = obs.counter("serving.decode.kv_hit_pages")
     outs = {}
     for name, kw in (("cold", {}), ("warm", {"prefix_cache": True})):
-        sched = _scheduler(params, **kw)
+        sched = _scheduler(decode_model, **kw)
         h0 = hit.value
         outs[name] = [sched.generate(p, max_new_tokens=6, timeout=300)
                       for p in prompts]

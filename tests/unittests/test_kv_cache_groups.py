@@ -7,6 +7,7 @@ free every group; a released page that another slot took, poisoned, changes
 nothing for the slot that gave it back; what a window group cannot do is
 refused.  A toy model whose logits depend on every K row its window should
 see, and on nothing else, makes each of those visible on the CPU."""
+import functools
 import time
 
 import numpy as np
@@ -164,7 +165,9 @@ def _toy_chunk(params, tokens, start, valid, cache, chunk_pages, gather_pages,
     return (full + 100.0 * win)[0], cache
 
 
+@functools.lru_cache(maxsize=None)
 def _model(window=W):
+    """One model object a window: its schedulers share its step programs."""
     leaf = dict(layers=1, tokens_per_row=1, width=V, dtype=None)
     return serving.DecodeModel(
         _toy_decode, _toy_chunk, params={"unused": np.zeros((1,), np.float32)},

@@ -67,6 +67,13 @@ def params():
 
 
 @pytest.fixture(scope="module")
+def decode_model(params):
+    """One model object for the module: every scheduler over it dispatches
+    the model's own step programs, so a shape is traced once."""
+    return M.build_decode_model(params, CFG)
+
+
+@pytest.fixture(scope="module")
 def tokens():
     return np.random.RandomState(1).randint(1, 100, size=T_PAD).astype(np.int32)
 
@@ -242,24 +249,24 @@ def test_chunked_prefill_serves_the_one_bucket_prefills_tokens(params, tokens):
 
 # 2. the scheduler ------------------------------------------------------------
 
-def _scheduler(params, **over):
+def _scheduler(model, **over):
     kw = dict(num_slots=SLOTS, page_size=PAGE, max_seq_len=MAX_LEN,
               num_pages={"full": 37, "window": 16},
               prefill_buckets=(8, 16, 96), prefill_chunk_tokens=16,
               max_new_tokens=STEPS, kv_dtype="float32")
     kw.update(over)
-    return serving.DecodeScheduler(M.build_decode_model(params, CFG),
-                                   serving.DecodeConfig(**kw))
+    return serving.DecodeScheduler(model, serving.DecodeConfig(**kw))
 
 
-def test_the_scheduler_serves_the_references_tokens(reference, params, tokens):
+def test_the_scheduler_serves_the_references_tokens(
+        reference, params, decode_model, tokens):
     """Four requests over three slots and a window pool of fifteen pages (two
     bounds and a half: the third seat waits for the window group), contexts
     from under the window to four times it: every served token is the
     reference's argmax given the tokens before it."""
     before = {c: obs.counter("serving.decode." + c).value
               for c in M.STEP_COUNTERS}
-    sched = _scheduler(params)
+    sched = _scheduler(decode_model)
     prompts = [tokens[:n] for n in (77, 5, 40, 61)]
     futs = [sched.submit(p, max_new_tokens=STEPS) for p in prompts]
     outs = [f.result(timeout=300) for f in futs]
@@ -296,7 +303,7 @@ def _read_each_step_before_the_next(sched):
 
 
 def test_a_step_in_flight_reads_and_reuses_what_the_in_order_loop_did(
-        params, tokens):
+        decode_model, tokens):
     """``_ensure_pages`` runs one step ahead and a window page is released
     while the step behind the commit is in flight: the served tokens, what
     both kinds of layer read (``window_read_share_pct``'s two counters), the
@@ -307,7 +314,7 @@ def test_a_step_in_flight_reads_and_reuses_what_the_in_order_loop_did(
     for loop in ("in flight", "in order"):
         before = {c: obs.counter("serving.decode." + c).value for c in names}
         released0 = obs.counter("serving.cache.window.pages_released").value
-        sched = _scheduler(params)
+        sched = _scheduler(decode_model)
         if loop == "in order":
             _read_each_step_before_the_next(sched)
         grp, handed = sched.cache.groups["window"], []

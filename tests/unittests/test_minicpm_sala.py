@@ -64,6 +64,13 @@ def params():
 
 
 @pytest.fixture(scope="module")
+def decode_model(params):
+    """One model object for the module: every scheduler over it dispatches
+    the model's own step programs, so a shape is traced once."""
+    return M.build_decode_model(params, CFG)
+
+
+@pytest.fixture(scope="module")
 def tokens():
     return np.random.RandomState(1).randint(1, 100, size=T_PAD).astype(np.int32)
 
@@ -243,29 +250,28 @@ def test_chunked_lightning_is_the_recurrence(reference, width):
 
 # 4. slots and batching -------------------------------------------------------
 
-def _scheduler(params, **over):
+def _scheduler(model, **over):
     cfg = dict(num_slots=SLOTS, page_size=PAGE, max_seq_len=MAX_LEN,
                prefill_chunk_tokens=16, prefill_buckets=(8, 16, MAX_LEN),
                max_new_tokens=8)
     cfg.update(over)
-    return serving.DecodeScheduler(M.build_decode_model(params, CFG),
-                                   serving.DecodeConfig(**cfg))
+    return serving.DecodeScheduler(model, serving.DecodeConfig(**cfg))
 
 
 def test_a_reused_slot_and_mixed_batches_serve_what_a_fresh_engine_serves(
-        params, tokens):
+        decode_model, tokens):
     prompts = [tokens[:70], tokens[5:25], tokens[30:79], tokens[2:50]]
-    solo = _scheduler(params, max_active=1, num_slots=1)
+    solo = _scheduler(decode_model, max_active=1, num_slots=1)
     want = [solo.generate(p, max_new_tokens=8, timeout=300) for p in prompts]
     # slot 0 of the one-slot engine served four sequences in turn: each must
     # equal a FRESH engine's answer (the state reset)
     for p, w in list(zip(prompts, want))[-1:]:
-        fresh = _scheduler(params, max_active=1, num_slots=1)
+        fresh = _scheduler(decode_model, max_active=1, num_slots=1)
         np.testing.assert_array_equal(
             fresh.generate(p, max_new_tokens=8, timeout=300), w)
         fresh.stop()
     solo.stop()
-    batch = _scheduler(params)
+    batch = _scheduler(decode_model)
     futures = [batch.submit(p, max_new_tokens=8) for p in prompts]
     for f, w in zip(futures, want):
         np.testing.assert_array_equal(f.result(timeout=300), w)   # bitwise
@@ -273,13 +279,14 @@ def test_a_reused_slot_and_mixed_batches_serve_what_a_fresh_engine_serves(
     batch.stop()
 
 
-def test_run_step_is_the_served_program_on_the_served_cache(params, tokens):
+def test_run_step_is_the_served_program_on_the_served_cache(
+        decode_model, tokens):
     """What the benchmark's check reads the cache's guarantees from: a
     stopped scheduler runs its own warmed programs on its own cache, with no
     compile, and gives the token it served."""
     from paddle_tpu import executor
 
-    sched = _scheduler(params)
+    sched = _scheduler(decode_model)
     served = sched.generate(tokens[:16], max_new_tokens=2, timeout=300)
     with pytest.raises(serving.ServingError, match="owns the cache"):
         sched.run_step(("decode",))

@@ -8,22 +8,9 @@ run plus one-call rollback, and cold activate / LRU deactivate under
 live traffic with zero dropped futures and bitwise parked answers) in
 a clean subprocess on CPU and fail on any regression, so the serving
 plane can't rot."""
-import os
-import subprocess
-import sys
-
-REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+import _gate
 
 
-def test_model_router_gate():
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["JAX_PLATFORM_NAME"] = "cpu"
-    env.pop("PADDLE_TPU_TELEMETRY", None)  # gate needs telemetry enabled
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools", "check_router.py")],
-        env=env, capture_output=True, text=True, timeout=600)
-    assert proc.returncode == 0, (
-        "check_router failed:\nstdout:\n%s\nstderr:\n%s"
-        % (proc.stdout, proc.stderr))
-    assert "model router gate OK" in proc.stdout
+@_gate.scenarios("check_router")
+def test_model_router_gate(scenario):
+    assert "OK" in scenario()

@@ -6,6 +6,7 @@ state machine.
 The end-to-end overload choreography (open-loop arrivals, goodput by
 priority class, chaos composition) is gated by tools/check_slo.py via
 test_slo_gate.py; these tests pin the per-component contracts."""
+import functools
 import os
 import threading
 import time
@@ -615,17 +616,23 @@ class TestEngineResilience:
 
 # -- decode: mid-decode deadline shed detail (satellite) ---------------------
 
-def _decode_scheduler(max_new_tokens=40):
+@functools.lru_cache(maxsize=None)
+def _decode_model():
+    """One model object for the module: its schedulers dispatch the model's
+    own step programs, so a shape is traced once."""
     pytest.importorskip("jax")
     from paddle_tpu.models import transformer as T
 
     params, meta = T.lm_params(seed=7, vocab_size=50, n_layer=2,
                                n_head=2, d_model=32, d_inner=64,
                                max_length=128)
-    model = T.build_decode_model(params, meta)
+    return T.build_decode_model(params, meta)
+
+
+def _decode_scheduler(max_new_tokens=40):
     cfg = serving.DecodeConfig(num_slots=2, page_size=8, max_seq_len=64,
                                max_new_tokens=max_new_tokens)
-    return serving.DecodeScheduler(model, cfg, autostart=False)
+    return serving.DecodeScheduler(_decode_model(), cfg, autostart=False)
 
 
 class TestDecodeMidDecodeShed:
@@ -707,16 +714,10 @@ class TestDecodeMidDecodeShed:
             sched.stop(timeout=10)
 
     def test_dual_path_engine_stays_ready_when_breaker_open(self, tmp_path):
-        pytest.importorskip("jax")
-        from paddle_tpu.models import transformer as T
-
-        params, meta = T.lm_params(seed=7, vocab_size=50, n_layer=2,
-                                   n_head=2, d_model=32, d_inner=64,
-                                   max_length=128)
         model_dir = _save_model(str(tmp_path / "m"))
         eng = serving.InferenceEngine(
             model_dir, batch_buckets=BUCKETS,
-            decode_model=T.build_decode_model(params, meta),
+            decode_model=_decode_model(),
             decode_config=serving.DecodeConfig(
                 num_slots=2, page_size=8, max_seq_len=64,
                 max_new_tokens=4),

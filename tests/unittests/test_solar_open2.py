@@ -59,6 +59,13 @@ def params():
 
 
 @pytest.fixture(scope="module")
+def decode_model(params):
+    """One model object for the module: every scheduler over it dispatches
+    the model's own step programs, so a shape is traced once."""
+    return M.build_decode_model(params, CFG)
+
+
+@pytest.fixture(scope="module")
 def tokens():
     return np.random.RandomState(0).randint(0, CFG["vocab_size"], T_PAD
                                             ).astype(np.int32)
@@ -72,17 +79,27 @@ def _cache(cfg=CFG, pages=40):
         num_slots=SLOTS)
 
 
+def _steps(cfg):
+    return (jax.jit(lambda p, c, *a: M.prefill_chunk(
+                p, *a[:3], c, *a[3:], cfg=cfg, with_routing=True)),
+            jax.jit(lambda p, c, *a: M.decode_step(
+                p, *a[:2], c, *a[2:], cfg=cfg, with_routing=True)))
+
+
+# the module's configuration is jitted once: its cases trace a shape once.  A
+# case that patches what a trace reads passes ``steps_fn=_steps(CFG)``
+_STEPS = _steps(CFG)
+
+
 def _through_the_cache(params, tokens, prompt_len, steps, chunk, slot=1,
-                       cfg=CFG, pools=None):
+                       cfg=CFG, pools=None, steps_fn=None):
     """Prefill ``tokens[:prompt_len]`` in chunks of ``chunk``, decode
     ``steps`` more in ``slot``: ``(logits [1 + steps, V], routing, pools)``."""
     pools = _cache(cfg).pools if pools is None else pools
     table = np.zeros(MAX_LEN // PAGE, np.int32)
     table[:12] = np.arange(1, 13)
-    run_chunk = jax.jit(lambda p, c, *a: M.prefill_chunk(
-        p, *a[:3], c, *a[3:], cfg=cfg, with_routing=True))
-    run_step = jax.jit(lambda p, c, *a: M.decode_step(
-        p, *a[:2], c, *a[2:], cfg=cfg, with_routing=True))
+    run_chunk, run_step = steps_fn or (
+        _STEPS if cfg is CFG else _steps(cfg))
     logits, routing = [], []
     for start in range(0, prompt_len, chunk):
         valid = min(chunk, prompt_len - start)
@@ -121,6 +138,16 @@ def _truth(reference, params, tokens, n, cfg=CFG, variant=None):
 PROMPT = 77
 
 
+@pytest.fixture(scope="module")
+def served(params, tokens):
+    """The sound side of the controls, once: what the system serves for the
+    module's prompt (chunks of 32, blocks of 16 as ``small_blocks`` sets them
+    for every test)."""
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(kda, "KDA_BLOCK", 16)
+        return _through_the_cache(params, tokens, PROMPT, STEPS, 32)
+
+
 # 1. the model ----------------------------------------------------------------
 
 # chunks of 24 split a block of 16 and, with 77 = 3 x 24 + 5, a last chunk
@@ -154,11 +181,11 @@ def test_chunked_prefill_then_decode_equals_the_reference(
 @pytest.mark.parametrize("variant", ["beta1", "head_decay", "taps3",
                                      "no_gate", "rotary"])
 def test_a_wrong_mechanism_fails_the_model_test(reference, params, tokens,
-                                                variant):
+                                                served, variant):
     """The controls: the reference with ONE mechanism wrong lies hundreds of
     tolerances from the served logits."""
     n = PROMPT + STEPS
-    logits = _through_the_cache(params, tokens, PROMPT, STEPS, 32)[0]
+    logits = served[0]
     want = np.asarray(_truth(reference, params, tokens, n, variant=variant)[0]
                       )[PROMPT - 1:]
     assert np.abs(logits - want).max() > 100 * LOGIT_TOL * np.abs(want).max()
@@ -169,7 +196,8 @@ def test_the_kernel_in_interpret_mode_serves_the_same_logits(params, tokens,
     a = _through_the_cache(params, tokens, PROMPT, STEPS, 32)
     monkeypatch.setattr(kda, "kda_state_decode", functools.partial(
         kda.kda_state_decode, impl="pallas"))
-    b = _through_the_cache(params, tokens, PROMPT, STEPS, 32)
+    b = _through_the_cache(params, tokens, PROMPT, STEPS, 32,
+                           steps_fn=_steps(CFG))
     assert np.abs(a[0] - b[0]).max() <= LOGIT_TOL
     for name in ("kda", "conv"):
         assert np.abs(a[2][name] - b[2][name]).max() <= 1e-5
@@ -261,14 +289,13 @@ def test_step_counters_tell_held_pairs_from_the_rest(params, tokens):
 
 # 3. the scheduler ------------------------------------------------------------
 
-def _scheduler(params, **over):
+def _scheduler(model, **over):
     kw = dict(num_slots=SLOTS, page_size=PAGE, max_seq_len=MAX_LEN,
               num_pages=41, prefill_buckets=(8, 32, 96),
               prefill_chunk_tokens=32, max_new_tokens=STEPS,
               kv_dtype="float32")
     kw.update(over)
-    return serving.DecodeScheduler(M.build_decode_model(params, CFG),
-                                   serving.DecodeConfig(**kw))
+    return serving.DecodeScheduler(model, serving.DecodeConfig(**kw))
 
 
 def _read_each_step_before_the_next(sched):
@@ -279,7 +306,8 @@ def _read_each_step_before_the_next(sched):
     sched._plan_steps = plans
 
 
-def test_the_scheduler_serves_the_references_tokens(reference, params, tokens):
+def test_the_scheduler_serves_the_references_tokens(
+        reference, params, decode_model, tokens):
     """Five requests over three slots: sequences join while others decode and
     a slot is seated a second time.  Every served token is the reference's
     argmax given the tokens before it, so a reseated slot started from zero
@@ -287,7 +315,7 @@ def test_the_scheduler_serves_the_references_tokens(reference, params, tokens):
     before = {c: obs.counter("serving.decode." + c).value
               for c in M.STEP_COUNTERS + ("steps_overlapped",)}
     resets = obs.counter("serving.cache.state_resets").value
-    sched = _scheduler(params)
+    sched = _scheduler(decode_model)
     prompts = [tokens[:n] for n in (77, 5, 40, 61, 13)]
     futs = [sched.submit(p, max_new_tokens=STEPS) for p in prompts]
     outs = [f.result(timeout=300) for f in futs]
@@ -310,10 +338,11 @@ def test_the_scheduler_serves_the_references_tokens(reference, params, tokens):
     assert sched.stats()["kv_pages_used"] == 0
 
 
-def test_a_step_in_flight_serves_what_the_in_order_loop_did(params, tokens):
+def test_a_step_in_flight_serves_what_the_in_order_loop_did(
+        decode_model, tokens):
     runs = {}
     for loop in ("in flight", "in order"):
-        sched = _scheduler(params)
+        sched = _scheduler(decode_model)
         if loop == "in order":
             _read_each_step_before_the_next(sched)
         futs = [sched.submit(tokens[:n], max_new_tokens=STEPS)
@@ -323,15 +352,15 @@ def test_a_step_in_flight_serves_what_the_in_order_loop_did(params, tokens):
     assert runs["in flight"] == runs["in order"]
 
 
-def test_a_lost_readback_puts_both_state_leaves_back(params, tokens):
+def test_a_lost_readback_puts_both_state_leaves_back(decode_model, tokens):
     """The readback of step n is lost with step n + 1 dispatched behind it:
     both are dropped and the cache — pages AND both slot-state leaves, which
     the dropped steps had already moved — is what step n took; the retry
     serves a clean run's tokens."""
-    clean = _scheduler(params)
+    clean = _scheduler(decode_model)
     want = clean.generate(tokens[:40], max_new_tokens=12, timeout=300)
     clean.stop()
-    sched = _scheduler(params)
+    sched = _scheduler(decode_model)
     read, fired = sched._read_step, [0]
 
     def lossy(sent):

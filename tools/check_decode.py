@@ -23,11 +23,13 @@ Scenario 3 — serving.decode.* telemetry schema:
   counters, prefill/decode/queue-wait timers), emit per-sequence spans,
   and stream decode_sequence records to record sinks.
 
-Scenario 4 — throughput smoke:
-  benchmarks/bench_decode.py --smoke in a subprocess: >= 2x generated
-  tokens/s for continuous batching vs naive per-sequence serving under
-  an open-loop mixed prefill+decode load, bitwise per-sequence equality
-  and the zero-recompile assert enforced inside the bench.
+Scenario 4 — what makes continuous batching fast, counted:
+  the same backlog through ``max_active=1`` and through every slot:
+  decode steps dispatched per generated token and the mean of live slots
+  a step (``serving.decode.steps`` / ``.tokens``; tokens per step IS the
+  mean of live slots).  The per-sequence loop pays a step a token; the
+  batched one at most a step per ``num_slots / 2`` tokens.  Speed itself
+  is the chip's (``chipbench/``), not a CPU wall clock's.
 
 Scenario 5 — chunked prefill (ISSUE 15a):
   the same prompts through chunked (prefill_chunk_tokens) and monolithic
@@ -47,12 +49,19 @@ Scenario 6 — prefix cache (ISSUE 15b):
   still serves bitwise-correctly while evicting LRU refcount-zero
   pages (serving.decode.kv_evictions > 0).
 
-Scenario 7 — head-of-line + repeated-prefix smoke:
-  bench_decode.py --long-prompts --smoke (>= 3x better short-prompt p95
-  TTFT under a mixed long/short open-loop burst at no tokens/s
-  regression) and --repeated-prefix --smoke (>= 50% prefill-token
-  reduction, >= 50% page hit rate) in subprocesses, bitwise equality
-  enforced inside each.
+Scenario 7 — head of line, counted in iterations:
+  short prompts that arrive while a long prompt is mid-prefill get their
+  first token within the short prompts' own chunks (the first in the
+  iteration after it arrived), the long prompt still prefilling behind
+  them; an iteration runs at most one
+  chunk and one decode step (``serving.decode.iteration`` / ``.prefill``
+  / ``.step`` spans in the order they closed); monolithic prefill serves
+  the same tokens with the whole long prompt ahead of every short one.
+
+Scenario 8 — repeated prefix, counted in tokens and pages:
+  a shared-prefix fan-out, prefix cache off and on: prompt tokens
+  prefilled (``serving.decode.prefill_tokens``), ``kv_hit_pages`` /
+  ``kv_miss_pages``, zero recompiles, bitwise warm == cold.
 
 Runnable locally:
     python tools/check_decode.py
@@ -60,9 +69,8 @@ and wired into the tier-1 flow via tests/unittests/test_decode_gate.py.
 
 Exit code 0 = every scenario held.
 """
-import json
+import functools
 import os
-import subprocess
 import sys
 import time
 
@@ -75,7 +83,10 @@ if "JAX_PLATFORMS" not in os.environ and "JAX_PLATFORM_NAME" not in os.environ:
 import numpy as np  # noqa: E402
 
 
+@functools.lru_cache(maxsize=None)
 def _model(vocab=60, eos_id=None, attn_impl=None):
+    """One model object a configuration: the scenarios' schedulers share its
+    step programs (``DecodeModel.step_programs``)."""
     from paddle_tpu.models import transformer as T
 
     params, meta = T.lm_params(seed=31, vocab_size=vocab, n_layer=2,
@@ -227,39 +238,48 @@ def scenario_telemetry_schema():
             % (len(prompts), n_tokens, d["steps"]))
 
 
-def _bench_smoke(flag=None):
-    """Run benchmarks/bench_decode.py [flag] --smoke in a clean CPU
-    subprocess and return its parsed JSON report — ONE launcher for
-    every bench-backed scenario so env/timeout/parsing can't diverge."""
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["JAX_PLATFORM_NAME"] = "cpu"
-    args = [sys.executable,
-            os.path.join(REPO, "benchmarks", "bench_decode.py")]
-    if flag:
-        args.append(flag)
-    args.append("--smoke")
-    proc = subprocess.run(args, env=env, cwd=REPO, capture_output=True,
-                          text=True, timeout=600)
-    assert proc.returncode == 0, (
-        "bench_decode.py %s--smoke failed (rc=%d):\n%s\n%s"
-        % ((flag + " ") if flag else "", proc.returncode, proc.stdout,
-           proc.stderr))
-    return json.loads(proc.stdout[proc.stdout.index("{"):])
+def scenario_batching_counts():
+    from paddle_tpu import observability as obs
+    from paddle_tpu import serving
+    from paddle_tpu.executor import compile_count
 
-
-def scenario_throughput_smoke():
-    report = _bench_smoke()["decode"]
-    assert report["bitwise_equal"]
-    assert report["continuous"]["compiles_during_serve"] == 0
-    assert report["continuous_batching_speedup"] >= 2.0, report
-    return ("throughput: %.0f -> %.0f tokens/s (%.2fx >= 2x), ttft p95 "
-            "%.0f -> %.0fms, 0 recompiles OK"
-            % (report["naive"]["tokens_per_s"],
-               report["continuous"]["tokens_per_s"],
-               report["continuous_batching_speedup"],
-               report["naive"]["p95_ttft_ms"],
-               report["continuous"]["p95_ttft_ms"]))
+    model = _model()
+    rng = np.random.RandomState(5)
+    prompts = [rng.randint(1, 60, size=rng.randint(4, 28)).astype(np.int32)
+               for _ in range(16)]
+    slots, new = 4, 12
+    steps = obs.counter("serving.decode.steps")
+    tokens = obs.counter("serving.decode.tokens")
+    outs, per_step = {}, {}
+    for name, active in (("naive", 1), ("continuous", slots)):
+        # the whole backlog is queued before the loop starts, so what is
+        # counted is the schedule and not the arrival times
+        sched = serving.DecodeScheduler(
+            model, _cfg(num_slots=slots, max_active=active,
+                        max_new_tokens=new), autostart=False)
+        c0, s0, t0 = compile_count(), steps.value, tokens.value
+        futs = [sched.submit(p) for p in prompts]
+        sched.start()
+        outs[name] = [f.result(timeout=300).tobytes() for f in futs]
+        sched.stop()
+        assert compile_count() == c0, "%s leg recompiled" % name
+        # a prompt's first token is its last chunk's; every other token
+        # rode a decode step
+        decoded = tokens.value - t0 - len(prompts)
+        assert decoded == len(prompts) * (new - 1), decoded
+        per_step[name] = decoded / float(steps.value - s0)
+    assert outs["naive"] == outs["continuous"], (
+        "continuous batching changed some sequence's tokens")
+    # tokens per step is the mean of live slots a step: one for the
+    # per-sequence loop (less what rode a step behind a retirement and
+    # was dropped), at least half the slots for the batched one
+    assert 0.9 <= per_step["naive"] <= 1.0, per_step
+    assert per_step["continuous"] >= slots / 2.0, per_step
+    return ("batching: %.2f -> %.2f tokens a decode step (mean live "
+            "slots of %d), %.2f -> %.2f steps a token, bitwise, 0 "
+            "recompiles OK"
+            % (per_step["naive"], per_step["continuous"], slots,
+               1 / per_step["naive"], 1 / per_step["continuous"]))
 
 
 def scenario_chunked_prefill():
@@ -399,44 +419,170 @@ def scenario_prefix_cache():
             % (reduction * 100, warm_hits, evictions))
 
 
-def scenario_long_prompt_smoke():
-    report = _bench_smoke("--long-prompts")["decode_long_prompts"]
-    assert report["bitwise_equal"]
-    assert report["chunked"]["compiles_during_serve"] == 0
-    assert report["p95_short_ttft_gain"] >= 3.0, report
-    assert report["tokens_per_s_ratio"] >= 0.9, report
-    return ("head-of-line: short-prompt p95 TTFT %.0f -> %.0fms "
-            "(%.1fx >= 3x) at %.2fx tokens/s, bitwise OK"
-            % (report["monolithic"]["p95_short_ttft_ms"],
-               report["chunked"]["p95_short_ttft_ms"],
-               report["p95_short_ttft_gain"],
-               report["tokens_per_s_ratio"]))
+class _SubmitBehind:
+    """A span sink that submits ``prompts`` to ``sched`` when the first
+    chunk of sequence ``seq`` closes: the arrivals land while that
+    sequence is mid-prefill, whatever the machine's load."""
+    wants_spans = True
+
+    def __init__(self, sched, seq, prompts):
+        self.sched, self.seq, self.prompts = sched, seq, prompts
+        self.futures, self.spans = [], []
+
+    def emit(self, record):
+        pass
+
+    def emit_span(self, name, ts, dur, thread, tags):
+        self.spans.append((name, dict(tags or {})))
+        if (name == "serving.decode.prefill" and not self.futures
+                and tags.get("seq") == self.seq):
+            self.futures = [self.sched.submit(p) for p in self.prompts]
 
 
-def scenario_repeated_prefix_smoke():
-    report = _bench_smoke("--repeated-prefix")["decode_repeated_prefix"]
-    assert report["bitwise_equal"]
-    assert report["warm"]["compiles_during_serve"] == 0
-    assert report["prefill_token_reduction"] >= 0.5, report
-    assert report["warm"]["hit_rate"] >= 0.5, report
-    return ("repeated prefix: %d -> %d prefill tokens (%.0f%% avoided "
-            ">= 50%%), hit rate %.0f%%, bitwise warm == cold OK"
-            % (report["cold"]["prefill_tokens"],
-               report["warm"]["prefill_tokens"],
-               report["prefill_token_reduction"] * 100,
-               report["warm"]["hit_rate"] * 100))
+def scenario_head_of_line_iterations():
+    from paddle_tpu import observability as obs
+    from paddle_tpu import serving
+    from paddle_tpu.executor import compile_count
+
+    model = _model()
+    rng = np.random.RandomState(13)
+    chunk = 8
+    long_prompt = rng.randint(1, 60, size=100).astype(np.int32)
+    shorts = [rng.randint(1, 60, size=n).astype(np.int32)
+              for n in (5, 11, 7)]
+    outs, ahead = {}, {}
+    for name, kw in (("monolithic", {}),
+                     ("chunked", {"prefill_chunk_tokens": chunk})):
+        sched = serving.DecodeScheduler(
+            model, _cfg(max_seq_len=128, max_new_tokens=6, **kw),
+            autostart=False)
+        c0 = compile_count()
+        first = sched.submit(long_prompt)
+        sink = _SubmitBehind(sched, first.seq, shorts)
+        obs.add_sink(sink)
+        try:
+            sched.start()
+            outs[name] = [first.result(timeout=300).tobytes()]
+            outs[name] += [f.result(timeout=300).tobytes()
+                           for f in sink.futures]
+            sched.stop()
+        finally:
+            obs.remove_sink(sink)
+        assert compile_count() == c0, "%s leg recompiled" % name
+        assert len(sink.futures) == len(shorts)
+        # the spans in the order they closed: a chunk and a step inside
+        # the iteration that ran them, the iteration behind both
+        turn, chunks, steps = 0, [], []
+        for span, tags in sink.spans:
+            if span == "serving.decode.iteration":
+                turn += 1
+            elif span == "serving.decode.prefill":
+                chunks.append((turn, tags["seq"], tags["start"],
+                               tags["rows"]))
+            elif span == "serving.decode.step":
+                steps.append(turn)
+        assert len(set(t for t, _, _, _ in chunks)) == len(chunks), (
+            "%s: two chunks in one iteration" % name)
+        assert len(set(steps)) == len(steps), (
+            "%s: two decode steps in one iteration" % name)
+        arrived = chunks[0][0]         # the long prompt's first chunk
+        long_done = max(t for t, seq, _, _ in chunks if seq == first.seq)
+        ahead[name] = (arrived, long_done, chunks)
+    assert outs["monolithic"] == outs["chunked"], (
+        "chunked prefill changed some sequence's tokens")
+    arrived, long_done, chunks = ahead["chunked"]
+    # a chunk an iteration, whoever's: the long prompt's own and, ahead of
+    # them (fewest chunks left first), the short prompts'
+    n_chunks = sum(-(-len(p) // chunk) for p in [long_prompt] + shorts)
+    assert long_done - arrived == n_chunks - 1 == len(chunks) - 1, (
+        long_done, arrived, n_chunks, len(chunks))
+    # a short prompt waits for the short prompts' chunks at most (its own
+    # and, fewest chunks left first, the others'), never for the long one's
+    short_chunks = n_chunks - -(-len(long_prompt) // chunk)
+    waits = []
+    for fut, prompt in zip(sink.futures, shorts):
+        # its last chunk samples its first token
+        last = max(t for t, seq, _, _ in chunks if seq == fut.seq)
+        waits.append(last - arrived)
+        assert last < long_done, (
+            "a short prompt waited for the long prompt's whole prefill")
+        assert waits[-1] <= short_chunks, (
+            "a %d-token prompt got its first token %d iterations after it "
+            "arrived; the short prompts have %d chunks between them"
+            % (len(prompt), waits[-1], short_chunks))
+    assert min(waits) == 1, waits      # the iteration after it arrived
+    # monolithic: the long prompt is ONE chunk, and it is whole before
+    # any short prompt has a token
+    arrived, long_done, chunks = ahead["monolithic"]
+    assert arrived == long_done and chunks[0][3] == len(long_prompt)
+    return ("head-of-line: %d short prompts behind a %d-token prefill "
+            "got their first token %s iterations after they arrived, "
+            "the long prompt's last chunk %d after; one chunk and one "
+            "step an iteration; bitwise == monolithic OK"
+            % (len(shorts), len(long_prompt), waits, n_chunks - 1))
+
+
+def scenario_repeated_prefix_counts():
+    from paddle_tpu import observability as obs
+    from paddle_tpu import serving
+    from paddle_tpu.executor import compile_count
+
+    model = _model()
+    rng = np.random.RandomState(5)
+    page, n_req, tail = 8, 10, 8
+    prefix = rng.randint(1, 60, size=96).astype(np.int32)
+    prompts = [np.concatenate([prefix, rng.randint(1, 60, size=tail)
+                               .astype(np.int32)]) for _ in range(n_req)]
+    cells = {n: obs.counter("serving.decode." + n)
+             for n in ("prefill_tokens", "kv_hit_pages", "kv_miss_pages")}
+    legs, outs = {}, {}
+    for name, kw in (("cold", {}), ("warm", {"prefix_cache": True})):
+        sched = serving.DecodeScheduler(
+            model, _cfg(max_seq_len=128, max_new_tokens=8, **kw))
+        c0 = compile_count()
+        v0 = {n: c.value for n, c in cells.items()}
+        # sequential: each request completes before the next is admitted,
+        # so every fan-out request after the first sees the prefix cached
+        outs[name] = [sched.generate(p, timeout=300).tobytes()
+                      for p in prompts]
+        sched.stop()
+        legs[name] = {n: c.value - v0[n] for n, c in cells.items()}
+        assert compile_count() == c0, "%s leg recompiled" % name
+    assert outs["cold"] == outs["warm"], (
+        "prefix cache changed some sequence's tokens")
+    whole = len(prefix) + tail
+    shared = len(prefix) // page
+    assert legs["cold"] == {"prefill_tokens": n_req * whole,
+                            "kv_hit_pages": 0, "kv_miss_pages": 0}, legs
+    # the first request fills the index; every one behind it maps the
+    # prefix's full pages and prefills its own tail alone
+    assert legs["warm"]["prefill_tokens"] == whole + (n_req - 1) * tail, legs
+    assert legs["warm"]["kv_hit_pages"] == (n_req - 1) * shared, legs
+    hits, misses = legs["warm"]["kv_hit_pages"], legs["warm"]["kv_miss_pages"]
+    assert hits / float(hits + misses) >= 0.5, legs
+    return ("repeated prefix: %d -> %d prefill tokens, %d page hits / %d "
+            "misses, 0 recompiles, bitwise warm == cold OK"
+            % (legs["cold"]["prefill_tokens"],
+               legs["warm"]["prefill_tokens"], hits, misses))
+
+
+# every scenario of the gate, once: main() runs them in a row, and
+# tests/unittests/test_*_gate.py makes each a case of its own
+SCENARIOS = (
+    scenario_bitwise_and_no_recompile,
+    scenario_admission_contracts,
+    scenario_telemetry_schema,
+    scenario_batching_counts,
+    scenario_chunked_prefill,
+    scenario_prefix_cache,
+    scenario_head_of_line_iterations,
+    scenario_repeated_prefix_counts,
+)
 
 
 def main():
     failures = []
-    for scenario in (scenario_bitwise_and_no_recompile,
-                     scenario_admission_contracts,
-                     scenario_telemetry_schema,
-                     scenario_throughput_smoke,
-                     scenario_chunked_prefill,
-                     scenario_prefix_cache,
-                     scenario_long_prompt_smoke,
-                     scenario_repeated_prefix_smoke):
+    for scenario in SCENARIOS:
         try:
             msg = scenario()
         except AssertionError as e:

@@ -51,6 +51,7 @@ tests/unittests/test_decode_resilience_gate.py.
 
 Exit code 0 = every scenario held.
 """
+import functools
 import os
 import sys
 import time
@@ -71,7 +72,11 @@ import numpy as np  # noqa: E402
 KILLED = 1          # replica index scenario 1/5 murder
 
 
+@functools.lru_cache(maxsize=None)
 def _model(eos_id=None):
+    """One model object for the gate: every pool's replicas dispatch its step
+    programs (``DecodeModel.step_programs``), so a shape is traced once and a
+    device compiles it once, whichever scenario got there first."""
     from paddle_tpu.models import transformer as T
 
     params, meta = T.lm_params(seed=31, vocab_size=60, n_layer=2,
@@ -398,14 +403,21 @@ def scenario_reset_pools_guard():
             "listed), force=True zeroed OK")
 
 
+# every scenario of the gate, once: main() runs them in a row, and
+# tests/unittests/test_*_gate.py makes each a case of its own
+SCENARIOS = (
+    scenario_kill_replica_bitwise,
+    scenario_corrupt_kv_isolation,
+    scenario_decode_step_retry,
+    scenario_cancel,
+    scenario_replay_budget,
+    scenario_reset_pools_guard,
+)
+
+
 def main():
     failures = []
-    for scenario in (scenario_kill_replica_bitwise,
-                     scenario_corrupt_kv_isolation,
-                     scenario_decode_step_retry,
-                     scenario_cancel,
-                     scenario_replay_budget,
-                     scenario_reset_pools_guard):
+    for scenario in SCENARIOS:
         try:
             msg = scenario()
         except AssertionError as e:

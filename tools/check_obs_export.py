@@ -377,39 +377,39 @@ def scenario_slo_monitor():
 def scenario_disabled_overhead():
     from paddle_tpu import observability as obs
     from paddle_tpu.observability import tracing
+    from paddle_tpu.testing.calls import calls_per
 
     tel = obs.get_telemetry()
     assert not tel.span_active(), "gate scenarios must detach their sinks"
     h = obs.Histogram("gate.overhead")
-    n = 20000
-    t0 = time.perf_counter()
-    for _ in range(n):
-        h.observe(1e-3)
-    per_observe = (time.perf_counter() - t0) / n
-    t0 = time.perf_counter()
-    for _ in range(n):
-        tracing.new_trace()
-    per_mint = (time.perf_counter() - t0) / n
-    # PR-4 budget: ~2us per always-on call (2-shared-core CI slack: 10us)
-    budget = 10e-6
-    assert per_observe < budget, (
-        "histogram observe costs %.1fus" % (per_observe * 1e6))
-    assert per_mint < budget, (
-        "trace mint costs %.1fus" % (per_mint * 1e6))
+    per_observe = calls_per(lambda: h.observe(1e-3))
+    per_mint = calls_per(tracing.new_trace)
+    # the always-on calls, priced in function calls (5 and 6 today), not
+    # in microseconds of a shared CPU
+    budget = 8
+    assert per_observe <= budget, (
+        "histogram observe makes %.1f calls" % per_observe)
+    assert per_mint <= budget, "trace mint makes %.1f calls" % per_mint
     # and with no span sink attached, record_span is a no-op
     tel.record_span("gate.should_drop", time.time(), 0.0, tags={"x": 1})
-    return ("disabled-path overhead: observe %.2fus, trace mint %.2fus "
-            "per call (< %.0fus budget) OK"
-            % (per_observe * 1e6, per_mint * 1e6, budget * 1e6))
+    return ("disabled-path overhead: observe %.0f, trace mint %.0f function "
+            "calls (<= %d) OK" % (per_observe, per_mint, budget))
+
+
+# every scenario of the gate, once: main() runs them in a row, and
+# tests/unittests/test_*_gate.py makes each a case of its own
+SCENARIOS = (
+    scenario_histogram_accuracy,
+    scenario_metrics_export,
+    scenario_trace_propagation,
+    scenario_slo_monitor,
+    scenario_disabled_overhead,
+)
 
 
 def main():
     failures = []
-    for scenario in (scenario_histogram_accuracy,
-                     scenario_metrics_export,
-                     scenario_trace_propagation,
-                     scenario_slo_monitor,
-                     scenario_disabled_overhead):
+    for scenario in SCENARIOS:
         try:
             msg = scenario()
         except AssertionError as e:

@@ -38,7 +38,6 @@ import json
 import os
 import sys
 import tempfile
-import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
@@ -222,36 +221,49 @@ def scenario_bitwise_neutrality():
 def scenario_span_cost():
     """The always-on price: with no sink and no profiler session a span
     is one ``perf_counter`` pair, one static TraceMe check and one locked
-    histogram increment; the record gate stays one attribute read."""
+    histogram increment; the record gate stays one attribute read.  Priced
+    in function calls, not microseconds: a CPU's clock under six busy
+    workers says nothing of the path."""
     from paddle_tpu import observability as obs
+    from paddle_tpu.testing.calls import calls_per
 
     tel = obs.get_telemetry()
     assert not tel.recording and not tel.span_active(), (
         "gate must start with no sinks attached")
-    n = 100_000
+    n = 1000
     span = tel.span
     c0 = tel.histogram("gate.span_cost").count
-    t0 = time.perf_counter()
-    for _ in range(n):
+
+    def gate_and_span():
         if tel.recording:  # the executor's per-run gate
             raise AssertionError
         with span("gate.span_cost"):
             pass
-    per_call = (time.perf_counter() - t0) / n
-    assert tel.histogram("gate.span_cost").count == c0 + n, (
+
+    per_call = calls_per(gate_and_span, n)
+    assert tel.histogram("gate.span_cost").count == c0 + n + 1, (
         "a sink-less span must still observe into its cell")
-    budget = 4e-6   # ~1.5us measured on this box; CI slack
-    assert per_call < budget, (
-        "an always-on span costs %.2fus (budget %.2fus)"
-        % (per_call * 1e6, budget * 1e6))
-    return ("always-on span: %.3fus per gate+span pair, cell counted "
-            "(budget %.1fus) OK" % (per_call * 1e6, budget * 1e6))
+    budget = 16   # 12 today: enter, exit, two clocks, the cell's lock
+    assert per_call <= budget, (
+        "an always-on span makes %.1f calls (budget %d)"
+        % (per_call, budget))
+    return ("always-on span: %.0f function calls per gate+span pair, cell "
+            "counted (budget %d) OK" % (per_call, budget))
+
+
+# every scenario of the gate, once: main() runs them in a row, and
+# tests/unittests/test_*_gate.py makes each a case of its own
+SCENARIOS = (
+    scenario_jsonl_schema,
+    scenario_chrome_trace,
+    scenario_bitwise_neutrality,
+    scenario_span_cost,
+)
 
 
 def main():
     failures = []
-    for scenario in (scenario_jsonl_schema, scenario_chrome_trace,
-                     scenario_bitwise_neutrality, scenario_span_cost):
+    for scenario in SCENARIOS:
         try:
             msg = scenario()
         except AssertionError as e:

@@ -167,9 +167,17 @@ def scenario_nan_guard():
     return "nan-guard: bad step skipped bitwise, clean run unchanged OK"
 
 
+# every scenario of the gate, once: main() runs them in a row, and
+# tests/unittests/test_*_gate.py makes each a case of its own
+SCENARIOS = (
+    scenario_torn_checkpoint_resume,
+    scenario_nan_guard,
+)
+
+
 def main():
     failures = []
-    for scenario in (scenario_torn_checkpoint_resume, scenario_nan_guard):
+    for scenario in SCENARIOS:
         try:
             msg = scenario()
         except AssertionError as e:

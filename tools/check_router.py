@@ -307,12 +307,19 @@ def scenario_cold_tier_live():
             % (len(futs), len(cold_futs)))
 
 
+# every scenario of the gate, once: main() runs them in a row, and
+# tests/unittests/test_*_gate.py makes each a case of its own
+SCENARIOS = (
+    scenario_bitwise_per_model,
+    scenario_quota_typed,
+    scenario_canary_split,
+    scenario_cold_tier_live,
+)
+
+
 def main():
     failures = []
-    for scenario in (scenario_bitwise_per_model,
-                     scenario_quota_typed,
-                     scenario_canary_split,
-                     scenario_cold_tier_live):
+    for scenario in SCENARIOS:
         try:
             msg = scenario()
         except AssertionError as e:

@@ -49,6 +49,7 @@ tests/unittests/test_sessions_gate.py.
 
 Exit code 0 = every scenario held.
 """
+import functools
 import os
 import sys
 import time
@@ -67,7 +68,11 @@ os.environ["XLA_FLAGS"] = " ".join(
 import numpy as np  # noqa: E402
 
 
+@functools.lru_cache(maxsize=None)
 def _model():
+    """One model object for the gate: every pool's replicas dispatch its step
+    programs (``DecodeModel.step_programs``), so a shape is traced once and a
+    device compiles it once, whichever scenario got there first."""
     from paddle_tpu.models import transformer as T
 
     params, meta = T.lm_params(seed=31, vocab_size=60, n_layer=2,
@@ -417,13 +422,20 @@ def scenario_roles_handoff():
             "pool, 0 leaks OK" % moved)
 
 
+# every scenario of the gate, once: main() runs them in a row, and
+# tests/unittests/test_*_gate.py makes each a case of its own
+SCENARIOS = (
+    scenario_warm_vs_cold_bitwise,
+    scenario_affinity_beats_least_loaded,
+    scenario_kill_session_owner,
+    scenario_affinity_vs_health,
+    scenario_roles_handoff,
+)
+
+
 def main():
     failures = []
-    for scenario in (scenario_warm_vs_cold_bitwise,
-                     scenario_affinity_beats_least_loaded,
-                     scenario_kill_session_owner,
-                     scenario_affinity_vs_health,
-                     scenario_roles_handoff):
+    for scenario in SCENARIOS:
         try:
             msg = scenario()
         except AssertionError as e:

@@ -295,7 +295,7 @@ def scenario_open_loop_slo():
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "benchmarks", "bench_load.py"),
          "--smoke"],
-        env=env, cwd=REPO, capture_output=True, text=True, timeout=600)
+        env=env, cwd=REPO, capture_output=True, text=True, timeout=120)
     assert proc.returncode == 0, (
         "bench_load.py --smoke failed (rc=%d):\n%s\n%s"
         % (proc.returncode, proc.stdout, proc.stderr))
@@ -315,13 +315,20 @@ def scenario_open_loop_slo():
                "; ".join(lines)))
 
 
+# every scenario of the gate, once: main() runs them in a row, and
+# tests/unittests/test_*_gate.py makes each a case of its own
+SCENARIOS = (
+    scenario_self_healing_chaos,
+    scenario_circuit_breaker,
+    scenario_dead_worker_supervision,
+    scenario_admission_shedding,
+    scenario_open_loop_slo,
+)
+
+
 def main():
     failures = []
-    for scenario in (scenario_self_healing_chaos,
-                     scenario_circuit_breaker,
-                     scenario_dead_worker_supervision,
-                     scenario_admission_shedding,
-                     scenario_open_loop_slo):
+    for scenario in SCENARIOS:
         try:
             msg = scenario()
         except AssertionError as e:
