@@ -1,9 +1,11 @@
 """Share of the device's busy time spent in the flash attention kernels'
 events: the Pallas custom calls, which the trace names after the traced
-function (``jvp_flash_attention_.33`` the forward; a fused backward is a
-custom call under the same function's name).  The scan backward (what ``auto``
-picks under T = 2048) is plain XLA fusions with names of their own and is NOT
-attributed here: at seq 256 this is the forward kernels' share alone."""
+function (``flash_attention_fwd.33`` the forward, ``jvp_flash_attention_.33``
+before PR 43; ``transpose_jvp_flash_attention__.33`` the fused backward).
+Since PR 43 every training cell runs the fused backward (from 256 rows on),
+so this is forward and backward together at every length.  A scan backward
+would be plain XLA fusions with names of their own and would NOT be attributed
+here; no cell runs one."""
 from chipbench import trace_reduce
 
 

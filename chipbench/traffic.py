@@ -3,9 +3,16 @@
 Every seed gets the SAME multiset of gaps between arrivals and the SAME table
 of requests (prompt length, answer length, shares a system prompt or not): the
 ``n`` mid-quantiles of the named distributions, paired once by ``TABLE_SEED``.
-The run's ``--seed`` puts the gaps and the table's rows in an order of its own
-and draws every token.  So the work of a window does not depend on the seed;
-where its bursts fall and which request meets which does.
+The run's ``--seed`` draws every token (and, in the drivers, every weight and
+the sequences ``correct`` checks).  What ORDERS the gaps and the table's rows
+is the mix's: its ``order_seed`` (a whole number) where it has one, so that
+every run of the cell replays ONE schedule and seeds differ in tokens, weights
+and routing alone; the run's ``--seed`` where it has none, so that the work of
+a window does not depend on the seed, but where its bursts fall and which
+request meets which does.  A mix fixes its order where requests stay seconds
+in a window of tens of seconds: how many answers the window's end cuts, and
+where the long prompts fall, is then the order's and would be read as the
+program's spread.
 """
 from __future__ import annotations
 
@@ -45,12 +52,13 @@ def arrivals(mix, seconds, seed, rate=None):
     """Due times (s from the window's start) of an open loop at
     ``rate_rps`` over ``seconds``: ``round(rate x seconds)`` arrivals whose
     gaps are the exponential ("poisson") or constant ("uniform") quantile
-    set, scaled so that the last one falls half a mean gap before the end."""
+    set, scaled so that the last one falls half a mean gap before the end;
+    in the order of the mix's ``order_seed``, or of ``seed`` where it has none."""
     rate = float(mix["rate_rps"] if rate is None else rate)
     n = max(1, int(round(rate * seconds)))
     kind = {"poisson": "exponential", "uniform": "constant"}[mix["arrivals"]]
     gaps = quantile_set({"dist": kind, "mean": 1.0, "value": 1.0}, n)
-    gaps = gaps[_rng(seed, 1).permutation(n)]
+    gaps = gaps[_rng(mix.get("order_seed", seed), 1).permutation(n)]
     due = np.cumsum(gaps)
     return due * ((seconds - 0.5 / rate) / due[-1])
 
@@ -61,13 +69,14 @@ def requests(mix, n, seed, vocab):
     one of ``count`` fixed system prompts of ``tokens`` tokens, prepended
     (the total clipped to ``max_prompt``).  The table of (prompt length,
     answer length, shares or not, which system prompt) is fixed by the mix;
-    the seed orders its rows and draws every token."""
+    its ``order_seed``, or ``seed`` where it has none, orders the rows, and
+    ``seed`` draws every token."""
     sp = mix.get("shared_prefix") or {"share": 0.0, "count": 1, "tokens": 0}
     base = np.random.RandomState(TABLE_SEED)
     p_lens = np.rint(quantile_set(mix["prompt_len"], n))[base.permutation(n)]
     o_lens = np.rint(quantile_set(mix["output_len"], n))[base.permutation(n)]
     shares = (np.arange(n) < int(round(sp["share"] * n)))[base.permutation(n)]
-    order = _rng(seed, 2).permutation(n)
+    order = _rng(mix.get("order_seed", seed), 2).permutation(n)
     rng = _rng(seed, 3)
     systems = [rng.randint(1, vocab, size=sp["tokens"]).astype(np.int32)
                for _ in range(sp["count"])]

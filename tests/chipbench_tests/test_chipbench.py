@@ -4,6 +4,7 @@ the traffic generator, the trace reduction on a recorded chip trace, the FLOP
 count against a hand count, and ``chipbench/contract.py`` on the repo's root, on
 a toy checkout that ADDS a configuration of another family by new files alone,
 and on copies of it that each break one rule.  No test needs a chip."""
+import hashlib
 import json
 import os
 import shutil
@@ -281,6 +282,10 @@ def test_main_refuses_a_cpu(capsys):
     assert "platform=cpu" in lines[0] and not lines[-1].startswith("{")
 
 
+def _shape(reqs):
+    return [(len(p), n) for p, n in reqs]
+
+
 def test_traffic_is_a_function_of_the_seed_alone():
     mix = Registry(ROOT).traffic("chat")
     a = traffic.arrivals(mix, 30, 2 ** 31 + 9)
@@ -298,8 +303,7 @@ def test_traffic_is_a_function_of_the_seed_alone():
     gaps = lambda due: np.sort(np.diff(np.concatenate([[0.0], due])))  # noqa: E731
     np.testing.assert_allclose(gaps(a), gaps(b), rtol=1e-9)
     r3 = traffic.requests(mix, len(a), 6, 30000)
-    shape = lambda rs: [(len(p), n) for p, n in rs]  # noqa: E731
-    assert sorted(shape(r1)) == sorted(shape(r3)) and shape(r1) != shape(r3)
+    assert sorted(_shape(r1)) == sorted(_shape(r3)) and _shape(r1) != _shape(r3)
     assert not any(np.array_equal(p, q) for (p, _), (q, _) in zip(r1, r3))
     lens = np.array([len(p) for p, _ in r1])
     outs = np.array([n for _, n in r1])
@@ -312,6 +316,91 @@ def test_traffic_is_a_function_of_the_seed_alone():
         heads.setdefault(tuple(p[:192]), []).append(1)
     shared = sorted(len(v) for v in heads.values() if len(v) > 1)
     assert len(shared) == 4 and sum(shared) == round(0.5 * len(a))
+
+
+def _digest(array, dtype):
+    return hashlib.sha256(np.asarray(array, dtype).tobytes()).hexdigest()[:16]
+
+
+def _an_order_seed_fixes_the_schedule_and_leaves_the_tokens_to_the_seed():
+    mix = dict(Registry(ROOT).traffic("chat"), order_seed=3)
+    a = traffic.arrivals(mix, 30, 5)
+    assert np.array_equal(a, traffic.arrivals(mix, 30, 2 ** 31 + 9))
+    r1 = traffic.requests(mix, len(a), 5, 30000)
+    r2 = traffic.requests(mix, len(a), 2 ** 31 + 9, 30000)
+    assert _shape(r1) == _shape(r2)
+    assert not any(np.array_equal(p, q) for (p, _), (q, _) in zip(r1, r2))
+    # another order_seed is another order of the same gaps and rows
+    other = dict(mix, order_seed=4)
+    b = traffic.arrivals(other, 30, 5)
+    assert not np.array_equal(a, b)
+    np.testing.assert_allclose(np.sort(np.diff(a, prepend=0.0)),
+                               np.sort(np.diff(b, prepend=0.0)), rtol=1e-9)
+    r3 = traffic.requests(other, len(a), 5, 30000)
+    assert sorted(_shape(r1)) == sorted(_shape(r3)) and _shape(r1) != _shape(r3)
+
+
+def _a_mix_without_the_key_is_the_parents_byte_for_byte():
+    """Digests taken on the commit before ``order_seed`` existed (PR 46)."""
+    reg = Registry(ROOT)
+    for name in ("chat", "standing_longctx", "standing_midctx",
+                 "standing_mixedctx", "standing_reasoning128"):
+        assert "order_seed" not in reg.traffic(name), name
+    mix = reg.traffic("chat")
+    a = traffic.arrivals(mix, 30, 2 ** 31 + 9)
+    r = traffic.requests(mix, len(a), 2 ** 31 + 9, 30000)
+    assert len(a) == 660
+    assert _digest(a, np.float64) == "760eaf9eda747a30"
+    assert _digest(_shape(r), np.int64) == "1ce0cf6b0a6bd011"
+    assert _digest(np.concatenate([p for p, _ in r]),
+                   np.int32) == "ec8fc8fc65d9f9f6"
+
+
+def _open_mixedlen_times_one_schedule_with_its_longest_prompt_early():
+    reg = Registry(ROOT)
+    mix = reg.traffic("open_mixedlen")
+    seconds = reg.bench["run_seconds"]
+    assert isinstance(mix["order_seed"], int) and seconds == 40
+    assert mix["rate_rps"] == 6.4 == 0.8 * mix["knee_rps"]
+    due = traffic.arrivals(mix, seconds, 11)
+    reqs = traffic.requests(mix, len(due), 11, 25024)
+    assert len(due) == 256
+    lens = np.array([len(p) for p, _ in reqs])
+    # what ``correct`` checks: the first of the longest prompts (its ring
+    # wraps), due early enough to be served inside the window
+    assert lens.max() == mix["max_prompt"] == 16384
+    assert due[int(np.argmax(lens))] <= 0.75 * seconds
+    # and the schedule is the cell's whatever the run's seed
+    assert np.array_equal(due, traffic.arrivals(mix, seconds, 2 ** 31 + 12))
+    assert _shape(reqs) == _shape(
+        traffic.requests(mix, len(due), 2 ** 31 + 12, 25024))
+
+
+@pytest.mark.parametrize("case", [
+    _an_order_seed_fixes_the_schedule_and_leaves_the_tokens_to_the_seed,
+    _a_mix_without_the_key_is_the_parents_byte_for_byte,
+    _open_mixedlen_times_one_schedule_with_its_longest_prompt_early,
+], ids=lambda f: f.__name__.strip("_"))
+def test_the_order_of_a_schedule_is_the_mixs_where_it_says_so(case):
+    case()
+
+
+def test_sweep_reads_candidate_orders_through_one_engine(toy_root):
+    from chipbench import sweep
+
+    lines = []
+    rows = sweep.sweep(Registry(toy_root), "tfbase_lm_chat", [20.0], 1.0,
+                       [7, 8], log=lines.append, order_seeds=[1, 2])
+    assert [(r["order_seed"], r["seed"]) for r in rows] == [
+        (1, 7), (1, 8), (2, 7), (2, 8)]
+    assert [json.loads(x) for x in lines] == rows
+    for r in rows:
+        assert r["attempted"] == 20 == r["completed"] and r["failed"] == 0
+        assert 0 < r["chunk_iteration_share_pct"] <= 100
+    # without the option the mix decides: chat has no key, the seed orders
+    rows = sweep.sweep(Registry(toy_root), "tfbase_lm_chat", [20.0], 0.5, [7],
+                       log=lines.append)
+    assert rows[0]["order_seed"] is None and rows[0]["attempted"] == 10
 
 
 def test_trace_reduce_on_a_recorded_chip_trace():

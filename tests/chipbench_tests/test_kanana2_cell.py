@@ -39,7 +39,7 @@ TOY_SIZES = dict(weights_dtype="float32", kv_dtype="float32", slots=4,
 NEW_METRICS = {"mla_decode_ms", "mla_decode_roofline_pct",
                "moe_expert_decode_ms", "moe_expert_roofline_pct",
                "experts_touched_pct", "expert_load_max_over_mean",
-               "decode_hbm_roofline_pct.moe"}
+               "decode_hbm_mfu_pct.kanana"}
 
 
 @pytest.fixture(scope="module")
@@ -100,7 +100,7 @@ def test_standing_moe_driver_at_toy_widths(toy_root, trace):
     assert 0 < out["metrics"]["experts_touched_pct"]["value"] <= 100
     assert out["metrics"]["expert_load_max_over_mean"]["value"] >= 1
     assert not got & {"mla_decode_ms", "moe_expert_decode_ms",
-                      "decode_hbm_roofline_pct.moe"}
+                      "decode_hbm_mfu_pct.kanana"}
 
 
 def _held(log):
@@ -265,7 +265,7 @@ def test_device_readers_on_a_hand_made_trace(toy_root):
         100 * latent / 819e9 / 0.3e-3)
     assert read("moe_expert_roofline_pct") == pytest.approx(
         100 * experts / 819e9 / 0.2e-3)
-    assert read("decode_hbm_roofline_pct.moe") == pytest.approx(
+    assert read("decode_hbm_mfu_pct.kanana") == pytest.approx(
         100 * (model.weight_bytes(cfg) + latent + experts) / 819e9 / 1e-3)
     assert read("experts_touched_pct") == pytest.approx(100 * 16 / 32)
     assert read("expert_load_max_over_mean") == pytest.approx(6 / (24 / 16))

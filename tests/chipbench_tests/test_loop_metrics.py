@@ -2,9 +2,9 @@
 (PR 38): after a toy serving run each reader gives a float, a quiet process
 reads 0.0 and never ``None`` (a ``null`` on a result line cannot be compared),
 a program WITHOUT the cell reads ``None`` (the line then leaves the metric
-out), and ``BENCHMARK.json`` lists each with its cells.  The issue's
-``stall_count``, ``stall_s`` and ``step_interval_max_ms`` are held back
-(``chipbench/loop_cells.py`` says why).  No test needs a chip."""
+out), and ``BENCHMARK.json`` lists each with at least the cells of its day.
+The issue's ``stall_count``, ``stall_s`` and ``step_interval_max_ms`` are held
+back (``chipbench/loop_cells.py`` says why).  No test needs a chip."""
 import gc
 import os
 import sys
@@ -116,15 +116,18 @@ def test_a_program_without_the_cell_reads_none(monkeypatch):
 
 
 def test_the_eight_are_in_benchmark_json_with_their_cells():
+    """Each is there with what PR 38 gave it; later PRs append metrics behind
+    them and cells to their lists, which is none of this test's business."""
     reg = Registry(ROOT)
     entries = {m["name"]: m for m in reg.bench["per_layer"]}
-    # appended, in the table's order, behind everything that was there
-    assert [m["name"] for m in reg.bench["per_layer"]][-8:] == list(EIGHT)
     for name, (unit, better, source, moves, cells) in EIGHT.items():
-        assert entries[name] == {
+        entry = dict(entries[name])
+        listed = entry.pop("workloads")
+        assert entry == {
             "name": name, "unit": unit, "better": better, "source": source,
-            "layer": "serving scheduler", "moves": moves, "workloads": cells}
-        for cell in cells:
+            "layer": "serving scheduler", "moves": moves}
+        assert set(cells) <= set(listed), name
+        for cell in listed:
             assert moves in {e["name"]
                              for e in reg.metrics("end_to_end", cell)}
     assert contract.violations(ROOT) == []

@@ -28,7 +28,7 @@ NEW_METRICS = {"full_attn_decode_ms", "window_attn_decode_ms",
                "window_read_share_pct", "moe_expert_roofline_pct.mellum",
                "experts_touched_pct.mellum",
                "expert_load_max_over_mean.mellum",
-               "decode_hbm_roofline_pct.mellum"}
+               "decode_hbm_mfu_pct.mellum"}
 # the cell and the configuration that report them, from BENCHMARK.json itself
 _BENCH = Registry(ROOT).bench
 CELL = next(m for m in _BENCH["per_layer"]
@@ -122,7 +122,7 @@ def test_standing_groups_driver_at_toy_widths(toy_root, trace, capsys):
     # contexts of 20..112 and more against a window of 24: the walk is bounded
     assert 0 < out["metrics"]["window_read_share_pct"]["value"] < 70
     assert not got & {"full_attn_decode_ms", "window_attn_decode_ms",
-                      "moe_expert_decode_ms", "decode_hbm_roofline_pct.mellum"}
+                      "moe_expert_decode_ms", "decode_hbm_mfu_pct.mellum"}
 
 
 def _held(log):
@@ -312,7 +312,7 @@ def test_device_readers_on_a_hand_made_trace(toy_root):
         100 * 270 / (3 * 400))
     assert read("moe_expert_roofline_pct.mellum") == pytest.approx(
         100 * experts / 819e9 / 0.4e-3)
-    assert read("decode_hbm_roofline_pct.mellum") == pytest.approx(
+    assert read("decode_hbm_mfu_pct.mellum") == pytest.approx(
         100 * (model.weight_bytes(cfg) + full + window + experts)
         / 819e9 / 1e-3)
     assert read("experts_touched_pct.mellum") == pytest.approx(

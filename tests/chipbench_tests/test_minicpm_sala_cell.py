@@ -43,7 +43,7 @@ TOY_SIZES = dict(
     decode_step_ops={"sparse": 2, "state": 1})
 NEW_METRICS = {"sparse_attn_decode_ms", "linear_state_decode_ms",
                "sparse_attn_roofline_pct", "linear_state_roofline_pct",
-               "decode_hbm_roofline_pct", "sparse_selected_share_pct",
+               "decode_hbm_mfu_pct.sala", "sparse_selected_share_pct",
                "history_prefill_tokens_per_s"}
 
 
@@ -106,7 +106,7 @@ def test_standing_driver_at_toy_widths(toy_root, trace):
             "sched_host_ms", "setup_warmup_s"} <= got
     assert 0 < out["metrics"]["sparse_selected_share_pct"]["value"] < 100
     assert not got & {"sparse_attn_decode_ms", "linear_state_decode_ms",
-                      "decode_hbm_roofline_pct"}
+                      "decode_hbm_mfu_pct.sala"}
 
 
 def _leaves_in_bfloat16(step, leaves):
@@ -205,7 +205,7 @@ def test_device_readers_on_a_hand_made_trace(toy_root):
         100 * sparse / 819e9 / 0.4e-3)
     assert read("linear_state_roofline_pct") == pytest.approx(
         100 * state / 819e9 / 0.2e-3)
-    assert read("decode_hbm_roofline_pct") == pytest.approx(
+    assert read("decode_hbm_mfu_pct.sala") == pytest.approx(
         100 * (model.weight_bytes(cfg) + sparse + state) / 819e9 / 1e-3)
     assert read("sparse_selected_share_pct") == pytest.approx(25.0)
 
@@ -226,7 +226,7 @@ def test_device_readers_refuse_a_program_whose_operations_moved(toy_root):
     for name, there in (("sparse_attn_decode_ms", False),
                         ("sparse_attn_roofline_pct", False),
                         ("linear_state_decode_ms", True),
-                        ("decode_hbm_roofline_pct", True)):
+                        ("decode_hbm_mfu_pct.sala", True)):
         value = reg.module("layer_metrics", name).read(obs)
         assert (value is not None) is there, name
 
