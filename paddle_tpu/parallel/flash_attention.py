@@ -93,6 +93,7 @@ __all__ = ["flash_attention", "flash_attention_rows", "mha_reference",
            "paged_decode_attention", "paged_prefill_attention",
            "paged_mla_decode_attention", "paged_mla_prefill_attention",
            "paged_gqa_decode_attention", "paged_gqa_prefill_attention",
+           "paged_eva_decode_attention", "paged_eva_prefill_attention",
            "paged_kv_finite"]
 
 # what tools and tests pass explicitly, and the scan backward's key block;
@@ -1166,7 +1167,8 @@ def _parts_dot(a_rows, n, b, dims):
 
 
 def _walk_pages(kvl, counts, *, page_size, pages, rows, width, copies,
-                tiles, scores, limit=None, first=None, pv=None):
+                tiles, scores, limit=None, first=None, pv=None, carry=None,
+                finish=True):
     """The walk of ONE slot's own pages that the paged decode kernels share:
     ``pages`` pages a turn are copied whole into tile ``slot`` of two (the
     next turn's copies in flight while this turn computes), and a turn is one
@@ -1190,6 +1192,11 @@ def _walk_pages(kvl, counts, *, page_size, pages, rows, width, copies,
         ones are.
     pv: None (``p . v`` of all rows against the whole ``v`` tile), or
         ``pv(p, v) -> [rows, width]``.
+    carry / finish: a walk over SEVERAL page lists under one softmax: with
+        ``finish=False`` the walk returns its running ``(m, l, acc)`` in
+        place of the normalised result, and the next list's walk takes it as
+        ``carry`` (lists of another page size, pool or mask; an empty list
+        passes it on unchanged).
     A masked turn replaces the scores past a row's keys whatever they are
     and zeroes the value rows past ``kvl`` (they hold what an earlier turn
     or nobody left: 0 * garbage must stay finite).  Returns the normalised
@@ -1246,9 +1253,10 @@ def _walk_pages(kvl, counts, *, page_size, pages, rows, width, copies,
     def _first():
         each_page(0, 0, lambda c: c.start())
 
-    carry = (jnp.full((rows, 128), NEG_INF, jnp.float32),
-             jnp.zeros((rows, 128), jnp.float32),
-             jnp.zeros((rows, width), jnp.float32))
+    if carry is None:
+        carry = (jnp.full((rows, 128), NEG_INF, jnp.float32),
+                 jnp.zeros((rows, 128), jnp.float32),
+                 jnp.zeros((rows, width), jnp.float32))
     if first is not None:
         n_head = jnp.minimum(first[2], n_turns)
         carry = jax.lax.fori_loop(
@@ -1259,8 +1267,11 @@ def _walk_pages(kvl, counts, *, page_size, pages, rows, width, copies,
     else:
         carry = jax.lax.fori_loop(
             0, n_whole, lambda t, c: update(t, c, False), carry)
-    _, l, acc = jax.lax.fori_loop(
+    carry = jax.lax.fori_loop(
         n_whole, n_turns, lambda t, c: update(t, c, True), carry)
+    if not finish:
+        return carry
+    _, l, acc = carry
     return acc / _lanes(jnp.maximum(l, 1e-30), width)
 
 
@@ -2459,6 +2470,233 @@ def paged_decode_attention(q, k_pool, v_pool, page_tables, kv_lens,
                                     sm_scale, layer)
     return _paged_gqa_pallas(q, k_pool, v_pool, pages, tokens, sm_scale,
                              interpret, layer)
+
+
+# ---------------------------------------------------------------------------
+# EVA (Zheng et al. 2023, arXiv:2302.04542, in EvaByte's causal, chunk-
+# summarised form): a query reads the exact K and V rows of its own ALIGNED
+# window so far and one summary row for every chunk of every earlier window,
+# under ONE softmax.  The two kinds of row live in two page groups of the
+# cache (``serving/kv_cache.py``): the window's in ``k_pool`` / ``v_pool`` by
+# ``win_tables`` (a window's ``j``-th page in column ``j``), the summaries in
+# ``ks_pool`` / ``vs_pool`` by ``sum_tables``, each with a page size of its
+# own.  Heads are folded ``[rows, H * Dh]`` as in the plain walk, and the
+# decode kernel IS the plain walk taken twice with the softmax's running state
+# carried from the first list into the second (``_walk_pages(carry=)``).
+# ---------------------------------------------------------------------------
+
+# Keys a turn of either list: 4096-lane bf16 tiles of 256 rows are 2 MB, two
+# lists x (k, v) x two tiles 16 MB of a v5e's 128 MiB (the kernel states its
+# own limit).
+_EVA_TURN_KEYS = 256
+_EVA_KERNEL_NAME = "eva_window_summary_decode"
+
+
+def _eva_lists_reference(q, pools, tables, lens, sm_scale, layer):
+    """The XLA form of one softmax over both lists: ``q [S, R, H, Dh]`` (``R``
+    query rows a slot), ``tables`` / ``lens`` the two lists' ``[S, MP]`` pages
+    and visible rows (``[S]``, or ``[S, R]`` where a slot's rows differ); a
+    row that sees nothing gives zeros."""
+    import jax.numpy as jnp
+
+    S, R, H, Dh = q.shape
+    ks, vs, oks = [], [], []
+    for (k_pool, v_pool), table, n in zip(pools, tables, lens):
+        rows = table.shape[1] * k_pool.shape[2]
+        ks.append(k_pool[layer, table].reshape(S, rows, H, Dh))
+        vs.append(v_pool[layer, table].reshape(S, rows, H, Dh))
+        n = jnp.broadcast_to(n.reshape(S, -1), (S, R))
+        oks.append(jnp.arange(rows)[None, None, :] < n[:, :, None])
+    k, v = jnp.concatenate(ks, axis=1), jnp.concatenate(vs, axis=1)
+    ok = jnp.concatenate(oks, axis=2)                       # [S, R, K]
+    # operands in their common dtype, float32 results: a bfloat16 product is
+    # exact in float32, and a chunk's 4096 keys are not copied as float32
+    dt = jnp.promote_types(q.dtype, k.dtype)
+    s = jnp.einsum("srhd,skhd->srhk", q.astype(dt), k.astype(dt),
+                   preferred_element_type=jnp.float32) * sm_scale
+    s = jnp.where(ok[:, :, None, :], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    p = jnp.where(ok.any(axis=2)[:, :, None, None], p, 0.0)
+    # float32 probabilities stay float32 (the chip's default would round them
+    # to bfloat16: 2e-3 of the result where the kernel's exact parts lose none)
+    return jnp.einsum("srhk,skhd->srhd", p, v.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+def _paged_eva_kernel(ptw_ref, pts_ref, lw_ref, ls_ref, q_ref, k_hbm, v_hbm,
+                      ks_hbm, vs_hbm, o_ref, kw_buf, vw_buf, ks_buf, vs_buf,
+                      sem, *, layer, win, summ, n_head, head_dim, sm_scale):
+    """One grid step = one SLOT: the plain walk (``_paged_decode_kernel``:
+    block-diagonal query rows, all heads a turn) over the slot's window pages,
+    masked past its ``lw`` rows, then over its summary pages up to ``ls``
+    rows, the running max, sum and accumulator carried across.  ``win`` /
+    ``summ`` = ``(page rows, pages a turn, table columns)`` of each list."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    s_idx = pl.program_id(0)
+    lanes = n_head * head_dim
+    hp = -(-n_head // 8) * 8
+    div = jax.lax.div
+    own = (div(jax.lax.broadcasted_iota(jnp.int32, (hp, lanes), 1), head_dim)
+           == jax.lax.broadcasted_iota(jnp.int32, (hp, lanes), 0))
+    q = q_ref[...].astype(jnp.float32) * sm_scale          # [1, lanes]
+    q_rows, nq = _part_rows(
+        jnp.where(own, jnp.broadcast_to(q, (hp, lanes)), 0.0))
+
+    def walk(kvl, pt_ref, geom, pools, bufs, sem_row, carry, finish):
+        ps, pages, mp = geom
+        turn = ps * pages
+
+        def copies(t, slot, i):
+            page = pt_ref[s_idx * mp + t * pages + i]
+            rows = pl.ds(pl.multiple_of(i * ps, ps), ps)
+            return tuple(pltpu.make_async_copy(
+                pool.at[layer, page], buf.at[slot, rows],
+                sem.at[sem_row + j, slot])
+                for j, (pool, buf) in enumerate(zip(pools, bufs)))
+
+        return _walk_pages(
+            kvl, (div(kvl + (ps - 1), ps), div(kvl + (turn - 1), turn),
+                  div(kvl, turn)),
+            page_size=ps, pages=pages, rows=hp, width=lanes, copies=copies,
+            tiles=lambda slot: (bufs[0][slot], bufs[1][slot]),
+            scores=lambda k: _parts_dot(q_rows, nq, k, ((1,), (1,))),
+            carry=carry, finish=finish)
+
+    carry = walk(lw_ref[s_idx], ptw_ref, win, (k_hbm, v_hbm),
+                 (kw_buf, vw_buf), 0, None, False)
+    out = walk(ls_ref[s_idx], pts_ref, summ, (ks_hbm, vs_hbm),
+               (ks_buf, vs_buf), 2, carry, True)
+    o_ref[...] = jnp.sum(jnp.where(own, out, 0.0), axis=0,
+                         keepdims=True).astype(o_ref.dtype)
+
+
+def _eva_turn_pages(ps, mp):
+    return max(1, min(mp, _EVA_TURN_KEYS // ps))
+
+
+def _paged_eva_pallas(q, pools, tables, lens, sm_scale, interpret, layer):
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from .. import observability as obs
+
+    S, H, Dh = q.shape
+    geoms, need = [], 0
+    for (k_pool, _), table in zip(pools, tables):
+        ps, mp = k_pool.shape[2], table.shape[1]
+        pages = _eva_turn_pages(ps, mp)
+        geoms.append((ps, pages, mp))
+        need += _decode_vmem_bytes(pages, ps, H * Dh, k_pool.dtype.itemsize,
+                                   H)
+    steps = obs.counter("paged.eva.grid_steps", labels={
+        "S": S, "window": "%dx%d" % geoms[0][:2],
+        "summary": "%dx%d" % geoms[1][:2]})
+    if not steps.value:
+        steps.inc(S)
+
+    kernel = functools.partial(
+        _paged_eva_kernel, layer=layer, win=geoms[0], summ=geoms[1],
+        n_head=H, head_dim=Dh, sm_scale=sm_scale)
+    row = pl.BlockSpec((None, 1, H * Dh), lambda s, *_: (s, 0, 0))
+    stack = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(S,),
+        in_specs=[row, stack, stack, stack, stack],
+        out_specs=[row],
+        scratch_shapes=[
+            pltpu.VMEM((2, ps * pages, H * Dh), pool.dtype)
+            for (ps, pages, _), pair in zip(geoms, pools) for pool in pair
+        ] + [pltpu.SemaphoreType.DMA((4, 2))],   # [list x (k | v), tile]
+    )
+    (out,) = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((S, 1, H * Dh), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=_vmem_limit(need),
+        ),
+        interpret=interpret,
+        name=_EVA_KERNEL_NAME,
+    )(tables[0].astype(jnp.int32).reshape(-1),
+      tables[1].astype(jnp.int32).reshape(-1),
+      lens[0].astype(jnp.int32), lens[1].astype(jnp.int32),
+      q.reshape(S, 1, H * Dh), *pools[0], *pools[1])
+    return out.reshape(S, H, Dh)
+
+
+def _eva_pools(q, k_pool, v_pool, ks_pool, vs_pool):
+    width = q.shape[-2] * q.shape[-1]
+    for pool in (k_pool, v_pool, ks_pool, vs_pool):
+        if pool.ndim != 4 or pool.shape[3] != width:
+            raise ValueError(
+                "EVA attention reads the stored stacks [L, pages, rows, H * "
+                "Dh] with H * Dh = %d (every head has its own K and V); got "
+                "%s" % (width, pool.shape))
+    return (k_pool, v_pool), (ks_pool, vs_pool)
+
+
+def paged_eva_decode_attention(q, k_pool, v_pool, ks_pool, vs_pool,
+                               win_tables, sum_tables, win_lens, sum_lens, *,
+                               layer, sm_scale=None, impl=None,
+                               interpret=None):
+    """EVA decode: one query token a slot against its window's exact rows and
+    the visible summaries, one softmax over both.
+
+    q: ``[S, H, Dh]``; k_pool / v_pool ``[L, Pw, psw, H * Dh]`` and ks_pool /
+        vs_pool ``[L, Ps, pss, H * Dh]``: the stored stacks of the two page
+        groups, addressed in place by ``(layer, page)``.
+    win_tables ``[S, MPw]`` / win_lens ``[S]``: the window's pages in order
+        from column 0 and its rows written so far (the query's own included);
+        sum_tables ``[S, MPs]`` / sum_lens ``[S]``: the summary pages from the
+        sequence's first on and the rows VISIBLE to the query (fewer than are
+        written).  No page or row past a length is read; a slot with both at
+        0 gives exact zeros.
+    Returns ``[S, H, Dh]`` float32.
+    """
+    if sm_scale is None:
+        sm_scale = 1.0 / float(np.sqrt(q.shape[-1]))
+    if impl in (None, "auto"):
+        impl = "reference" if cpu_backend() else "pallas"
+    if impl not in ("reference", "pallas"):
+        raise ValueError("impl must be auto|reference|pallas, got %r" % impl)
+    pools = _eva_pools(q, k_pool, v_pool, ks_pool, vs_pool)
+    tables, lens = (win_tables, sum_tables), (win_lens, sum_lens)
+    if impl == "reference":
+        return _eva_lists_reference(q[:, None], pools, tables, lens,
+                                    sm_scale, layer)[:, 0]
+    if interpret is None:
+        interpret = cpu_backend()
+    return _paged_eva_pallas(q, pools, tables, lens, sm_scale, interpret,
+                             layer)
+
+
+def paged_eva_prefill_attention(q, k_pool, v_pool, ks_pool, vs_pool,
+                                win_pages, sum_pages, start, window, chunk, *,
+                                layer, sm_scale=None):
+    """EVA over one prefill chunk: ``q [C, H, Dh]`` at positions ``start ..
+    start + C - 1``, none of which crosses a multiple of ``window`` (the
+    chunk's own K and V already scattered into the window's pages).  Row ``i``
+    reads the window's rows ``0 .. start + i - b`` with ``b = (start //
+    window) * window`` and the ``b / chunk`` summaries of the windows before.
+    The XLA form over the gathered pages (set-up's path: the decode kernel's
+    twin in Pallas is not written); the key width is the table's own, whatever
+    the chunk.  Returns ``[C, H, Dh]`` float32."""
+    import jax.numpy as jnp
+
+    if sm_scale is None:
+        sm_scale = 1.0 / float(np.sqrt(q.shape[-1]))
+    pools = _eva_pools(q, k_pool, v_pool, ks_pool, vs_pool)
+    base = (start // window) * window
+    rows = start - base + 1 + jnp.arange(q.shape[0], dtype=jnp.int32)
+    return _eva_lists_reference(
+        q[None], pools, (win_pages[None], sum_pages[None]),
+        (rows[None], jnp.reshape(base // chunk, (1, 1))), sm_scale, layer)[0]
 
 
 def paged_kv_finite(k_pool, v_pool, pages):

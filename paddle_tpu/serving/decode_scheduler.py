@@ -100,6 +100,7 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import functools
 import threading
 import time
 
@@ -259,18 +260,23 @@ class DecodeModel:
     ``paged_*_attention(..., layer=li)``; it must not slice a layer out
     (``cache["k"][li]`` is a layer-sized copy in every step on the chip).
 
-    ``page_groups``: None, or an ordered ``{group: dict(window=None | W)}``
-    for a model whose layers do not all keep the same positions
-    (``kv_cache.py``, "Page groups"); each ``page_pools`` leaf then names its
-    ``group``.  The first group keeps every position; a group with a
-    ``window`` keeps a sequence's last ``W`` (a query at position ``t`` reads
-    ``t - W + 1 .. t``) and its pages return to the allocator as they fall
-    out of it.  Such a model's step functions receive ``page_tables``,
-    ``chunk_pages`` and ``gather_pages`` as ``{group: array}``; a window
-    group's table is a RING (logical page ``p`` of a sequence in column ``p
-    % width``, released entries at scratch).  ``DecodeConfig.num_pages`` is
-    then ``{group: pages}``.  Without it a model has one group and receives
-    the arrays themselves, as every model did.
+    ``page_groups``: None, or an ordered ``{group: dict(window=None | W,
+    aligned=False, page_size=None)}`` for a model whose layers do not all keep
+    the same positions (``kv_cache.py``, "Page groups"); each ``page_pools``
+    leaf then names its ``group``.  The first group keeps every position; a
+    group with a ``window`` keeps a sequence's last ``W`` (a query at position
+    ``t`` reads ``t - W + 1 .. t``) or, ``aligned``, the positions from the
+    last multiple of ``W`` on (``(t // W) * W .. t``), and its pages return to
+    the allocator as they fall out of it: one at a time, or a whole window at
+    once.  A group may state a ``page_size`` of its own in tokens (the
+    config's otherwise): ``chunk_pages[group]`` then holds ``max(1, C //
+    page_size)`` pages from the one that holds ``start`` on.  Such a model's
+    step functions receive ``page_tables``, ``chunk_pages`` and
+    ``gather_pages`` as ``{group: array}``; a window group's table is a RING
+    (logical page ``p`` of a sequence in column ``p % width``, released
+    entries at scratch; an aligned window's ``j``-th page is column ``j``).
+    ``DecodeConfig.num_pages`` is then ``{group: pages}``.  Without it a model
+    has one group and receives the arrays themselves, as every model did.
 
     Both are jitted once a model object (``step_programs``, the cache
     donated on TPU) and every scheduler over it dispatches those callables;
@@ -279,8 +285,9 @@ class DecodeModel:
     ``models.minicpm_sala.build_decode_model``,
     ``models.deepseek_v3.build_decode_model``,
     ``models.mellum.build_decode_model``,
-    ``models.solar_open2.build_decode_model`` and
-    ``models.afmoe.build_decode_model`` are the in-repo producers.
+    ``models.solar_open2.build_decode_model``,
+    ``models.afmoe.build_decode_model`` and
+    ``models.evabyte.build_decode_model`` are the in-repo producers.
     """
 
     def __init__(self, decode_fn, prefill_chunk_fn, *, params=None,
@@ -714,7 +721,9 @@ class DecodeScheduler:
         self._pending_lock = threading.Lock()
         self._pending_release = []
         self._pending_handoffs = collections.deque()
-        worst = cfg.num_slots * -(-cfg.max_seq_len // cfg.page_size) + 1
+        def worst(page_size):
+            return cfg.num_slots * -(-cfg.max_seq_len // page_size) + 1
+
         if model.page_groups:
             sizes = cfg.num_pages if isinstance(cfg.num_pages, dict) else {}
             unknown = set(sizes) - set(model.page_groups)
@@ -723,12 +732,15 @@ class DecodeScheduler:
                     "%r keeps its pages in groups %s: num_pages is {group: "
                     "pages} over them, got %r"
                     % (model.name, list(model.page_groups), cfg.num_pages))
-            groups = {g: dict(window=spec.get("window"),
-                              num_pages=sizes.get(g, worst))
+            # a group that states no pool holds every slot's longest
+            # sequence, counted in its OWN page size
+            groups = {g: dict(spec, num_pages=sizes[g] if g in sizes
+                              else worst(spec.get("page_size")
+                                         or cfg.page_size))
                       for g, spec in model.page_groups.items()}
             num_pages = None
         else:
-            groups, num_pages = None, cfg.num_pages or worst
+            groups, num_pages = None, cfg.num_pages or worst(cfg.page_size)
         self._cache = PagedKVCache(
             model.num_layers, num_pages,
             cfg.page_size, model.num_heads, model.head_dim,
@@ -831,9 +843,10 @@ class DecodeScheduler:
         # a window a RING as wide as the most a slot holds live at once
         # (logical page p in column p % width; released entries at scratch)
         widest = max(self._chunk_widths())
+        self._check_group_geometry()
         self._more_tables = {
             g: np.zeros((cfg.num_slots,
-                         grp.slot_bound(cfg.max_seq_len, widest)), np.int32)
+                         grp.table_width(cfg.max_seq_len, widest)), np.int32)
             for g, grp in self._cache.groups.items()}
         self._widest_chunk = widest
         self._hol = None               # head-of-line request awaiting pages
@@ -970,13 +983,14 @@ class DecodeScheduler:
             np.asarray(toks)
             for w in self._chunk_widths():
                 fn = self._jit.get(("chunk", w))
-                written = np.zeros((w // cfg.page_size,), np.int32)
+                written = {g: self._chunk_page_vec(g, 0, w, lambda p: 0)
+                           for g in cache.group_names}
                 toks, cache.pools = fn(
                     params, cache.pools,
                     jnp.zeros((w,), jnp.int32), jnp.int32(0),
                     jnp.int32(1),
-                    self._by_group(written,
-                                   {g: written for g in self._more_tables}),
+                    self._by_group(written[cache.primary_group], {
+                        g: written[g] for g in self._more_tables}),
                     self._by_group(self._tables[0], {
                         g: t[0] for g, t in self._more_tables.items()}),
                     np.int32(0), jnp.uint32(0), jnp.float32(0))
@@ -986,7 +1000,7 @@ class DecodeScheduler:
                 # dispatches: the decode tail sweep ([num_slots]) and
                 # each prefill width's written-page sweep
                 for n in sorted({cfg.num_slots}
-                                | {w // cfg.page_size
+                                | {max(1, w // cache.page_size)
                                    for w in self._chunk_widths()}):
                     np.asarray(self._jit.get(("kvguard", n))(
                         cache.pools, jnp.zeros((n,), jnp.int32)))
@@ -1053,6 +1067,40 @@ class DecodeScheduler:
         out.update((g, jnp.asarray(a)) for g, a in more.items())
         return out
 
+    def _check_group_geometry(self):
+        """A chunk lies on whole pages of every group or inside one page of
+        it, and never straddles a multiple of an aligned window: chunks
+        start at multiples of the widest, so every width and every group's
+        page size divide one another, and the widest divides the window."""
+        widths = self._chunk_widths()
+        for g in self._cache.group_names:
+            ps = self._cache.group_page_size(g)
+            grp = self._cache.groups.get(g)
+            aligned = grp is not None and grp.aligned
+            bad = [w for w in widths if w % ps and ps % w]
+            if aligned and grp.window % max(widths):
+                bad.append(max(widths))
+            if bad:
+                raise ServingError(
+                    "page group %r (page_size %d%s): chunk widths %s neither "
+                    "fill whole pages nor fit inside one%s; choose "
+                    "prefill_chunk_tokens / prefill_buckets that do"
+                    % (g, ps, ", aligned window %d" % grp.window
+                       if aligned else "", sorted(set(bad)),
+                       ", or straddle a multiple of the window"
+                       if aligned else ""))
+
+    def _chunk_page_vec(self, group, start, width, column):
+        """The pages of ``group`` that a chunk ``[start, start + width)``
+        writes: ``width // page_size`` of them in order (one where the page
+        is wider than the chunk), ``column(p)`` the page that holds logical
+        page ``p`` (0: none, the rows scatter to scratch)."""
+        ps = self._cache.group_page_size(group)
+        vec = np.zeros((max(1, width // ps),), np.int32)
+        for i in range(len(vec)):
+            vec[i] = column(start // ps + i)
+        return vec
+
     def _group_needs(self, req):
         """Pages ``req`` reserves in each further group."""
         return {g: grp.slot_bound(req.prompt_len + req.max_new_tokens,
@@ -1062,9 +1110,9 @@ class DecodeScheduler:
     def _ensure_pages(self, idx, slot, end):
         """Hand the slot the further groups' pages that positions below
         ``end`` reach (under its reservation: this cannot fail)."""
-        ps = self.config.page_size
         for g, held in slot.more.items():
             table = self._more_tables[g]
+            ps = self._cache.groups[g].page_size
             for p in range(held.first + len(held.pages), -(-end // ps)):
                 page = self._cache.groups[g].alloc(1)[0]
                 held.pages.append(page)
@@ -1085,8 +1133,12 @@ class DecodeScheduler:
                 table = self._more_tables[g]
                 dead = [held.pages.popleft() for _ in range(
                     min(live - held.first, len(held.pages)))]
-                for p in range(held.first, held.first + len(dead)):
-                    table[idx, p % table.shape[1]] = 0
+                for p, page in enumerate(dead, held.first):
+                    # an aligned window's first column may already name the
+                    # next window's first page (a step in flight past the
+                    # boundary took it)
+                    if table[idx, p % table.shape[1]] == page:
+                        table[idx, p % table.shape[1]] = 0
                 held.first += len(dead)
                 grp.free(dead, released=True)
                 released += len(dead)
@@ -1613,7 +1665,7 @@ class DecodeScheduler:
             # re-register the prompt's full pages HERE: the next turn's
             # prefix probe (and its session pin) must find them in the
             # replica that will actually serve the decode
-            for pi in range(min(packet.kv_len // self.config.page_size,
+            for pi in range(min(packet.kv_len // self._cache.page_size,
                                 len(packet.hashes), len(pages))):
                 self._cache.register_prefix(packet.hashes, pi, pages[pi])
         _handoff_injected.inc()
@@ -1704,7 +1756,7 @@ class DecodeScheduler:
                 self._park_hol(req, cached_pages, hashes)
                 return
             self._place(req, cached_pages + pages,
-                        len(cached_pages) * cfg.page_size, hashes, more)
+                        len(cached_pages) * cache.page_size, hashes, more)
 
     def _place(self, req, pages, cached_tokens, hashes, more=None):
         """Seat one admitted request in a free slot in the PREFILLING
@@ -1802,28 +1854,31 @@ class DecodeScheduler:
             remaining = req.prompt_len - start
             width = self._chunk_width_for(remaining)
             valid = min(remaining, width)
-            ps = cfg.page_size
+            ps = self._cache.page_size
             tokens = np.zeros((width,), np.int32)
             tokens[:valid] = req.prompt[start:start + valid]
-            # pages this chunk writes: the prompt's pages covering
-            # [start, start + width); window tail past the prompt's pages
-            # scatters to scratch, exactly like the monolithic pad tail
-            n_prompt_pages = self._cache.pages_for(req.prompt_len)
-            p0 = start // ps
-            chunk_vec = np.zeros((width // ps,), np.int32)
-            for i in range(width // ps):
-                if p0 + i < n_prompt_pages:
-                    chunk_vec[i] = slot.pages[p0 + i]
-            # the further groups: the pages this chunk's positions reach are
-            # handed out now; what it writes, by the group's own table
+            # pages this chunk writes, a group: the prompt's pages covering
+            # [start, start + width), by the group's own table and page size
+            # (the further groups' are handed out now); the tail past the
+            # prompt's pages scatters to scratch, like the monolithic pad tail
+            cache = self._cache
             self._ensure_pages(idx, slot, start + valid)
-            more_vecs = {}
-            for g, table in self._more_tables.items():
-                vec = np.zeros((width // ps,), np.int32)
-                for i in range(min(width // ps, n_prompt_pages - p0)):
-                    vec[i] = table[idx, (p0 + i) % table.shape[1]]
-                more_vecs[g] = vec
-            written = self._by_group(chunk_vec, more_vecs)
+
+            def held(g, p):
+                """The slot's page of ``g`` that holds logical page ``p``."""
+                if p >= -(-req.prompt_len // cache.group_page_size(g)):
+                    return 0
+                if g == cache.primary_group:
+                    return slot.pages[p]
+                table = self._more_tables[g]
+                return table[idx, p % table.shape[1]]
+
+            vecs = {g: self._chunk_page_vec(g, start, width,
+                                            functools.partial(held, g))
+                    for g in cache.group_names}
+            chunk_vec = vecs[cache.primary_group]
+            written = self._by_group(
+                chunk_vec, {g: vecs[g] for g in self._more_tables})
             gathered = self._by_group(self._tables[idx], {
                 g: t[idx] for g, t in self._more_tables.items()})
             fn = self._jit.get(("chunk", width))
@@ -1917,7 +1972,7 @@ class DecodeScheduler:
                 # content is now immutable (decode appends only past the
                 # prompt), so later identical prefixes can map it
                 # read-only
-                for pi in range(p0, (start + valid) // ps):
+                for pi in range(start // ps, (start + valid) // ps):
                     if pi < len(slot.hashes):
                         self._cache.register_prefix(slot.hashes, pi,
                                                     slot.pages[pi])
@@ -2193,7 +2248,8 @@ class DecodeScheduler:
                 # the page the new token lands on, in every further group
                 self._ensure_pages(i, slot, at + 1)
                 slot.inflight += 1
-            _walked_pages.inc(int(np.sum(-(-kv_lens // cfg.page_size))))
+            _walked_pages.inc(int(np.sum(
+                -(-kv_lens // self._cache.page_size))))
             _table_pages.inc(self._tables.size)
             # COPIES: the program may run behind the host (on the CPU it
             # reads a numpy argument in place), and the tables are rewritten
@@ -2407,7 +2463,8 @@ class DecodeScheduler:
                 guard_vec = np.zeros((cfg.num_slots,), np.int32)
                 owners = list(range(cfg.num_slots))
                 for i, slot in live:
-                    guard_vec[i] = slot.pages[slot.kv_len // cfg.page_size]
+                    guard_vec[i] = slot.pages[
+                        slot.kv_len // self._cache.page_size]
                 tripped = self._guard_pages(owners, guard_vec,
                                             phase="decode")
             now = time.perf_counter()
@@ -2613,7 +2670,7 @@ class DecodeScheduler:
         sticky = origin if origin is not None else self._replica_index
         pinned = []
         if sticky == self._replica_index:
-            ps = self.config.page_size
+            ps = self._cache.page_size
             hashes = self._cache.prefix_hashes(history)
             # publish the history's full pages: prefill registered the
             # PROMPT'S full pages already (idempotent), decode appended

@@ -17,20 +17,32 @@ about the K/V leaves; every page-indexed leaf follows the same allocator.
 
 **Page groups.**  A model whose layers do not all keep the same positions
 states ``page_groups``: an ordered ``{name: dict(window=None | W,
-num_pages=N)}``, and for each ``page_pools`` leaf the ``group`` it belongs to.
-The FIRST group is the cache's own allocator (everything below; it keeps every
-position, and ``k`` / ``v`` and leaves that name no group are its).  Every
-further group (:class:`PageGroup`) has its own pool length, scratch page 0,
-free list, refcounts and RESERVATION count, and the scheduler keeps a page
-table a group.  A group with a ``window`` keeps a sequence's last ``W``
-positions: a slot reserves a bound that does not grow with the sequence (or
-its own pages, where it is shorter than the bound: ``slot_bound``), pages are
-handed out as its positions reach them and go back to the group's free list the moment every position on them is out of every
-later query's window, so its table is a RING of the bound's width (logical
-page ``p`` in column ``p % width``).  The prefix cache, sessions and handoff
-address the first group's pages only and are refused for a model with a
-further group (``decode_scheduler.py``).  One group and no window is every
-model that states nothing: the same leaves, table and programs as before.
+aligned=False, page_size=None, num_pages=N)}``, and for each ``page_pools``
+leaf the ``group`` it belongs to.  The FIRST group is the cache's own
+allocator (everything below; it keeps every position, and leaves that name no
+group are its: ``k`` / ``v`` where the model states ``num_layers``, or, for a
+model whose K and V rows live in a further group, only derived rows such as
+the summaries of EvaByte's closed windows, the K and V leaves then being
+``page_pools`` of the windowed group).  Every further group
+(:class:`PageGroup`) has its own pool length, scratch page 0, free list,
+refcounts and RESERVATION count, and the scheduler keeps a page table a group.
+A group may state a ``page_size`` of its own, in TOKENS (a leaf of one row
+for every 16 tokens fills a page of 64 rows with 1024 tokens); a group that
+states none has the cache's.  A group with a ``window`` keeps a bounded span of
+a sequence's positions, in one of two forms.  SLIDING (the default): a query
+at ``t`` reads ``t - W + 1 .. t``; pages are handed out as positions reach
+them and go back to the group's free list the moment every position on them is
+out of every later query's window, so its table is a RING of the bound's width
+(logical page ``p`` in column ``p % width``).  ALIGNED (``aligned=True``): a
+query at ``t`` reads ``(t // W) * W .. t``; the window fills to ``W /
+page_size`` pages, ALL of which go back at once when ``t`` reaches the next
+multiple of ``W``, and the table, ``W / page_size`` columns wide, is filled
+again from column 0.  Either way a slot reserves a bound that does not grow
+with the sequence (or its own pages, where it is shorter than the bound:
+``slot_bound``).  The prefix cache, sessions and handoff address the first
+group's pages only and are refused for a model with a further group
+(``decode_scheduler.py``).  One group and no window is every model that states
+nothing: the same leaves, table and programs as before.
 
 The memory half of the continuous-batching decode runtime (vLLM /
 PagedAttention, Kwon et al. SOSP'23): instead of one contiguous
@@ -156,19 +168,29 @@ class PageGroup:
     waits while ``can_reserve`` is false), takes pages one at a time as its
     positions reach them (``alloc``, which cannot fail under a reservation)
     and, in a group with a ``window``, gives back each page that fell out of
-    it (``free``); retirement frees the rest and ``unreserve``s."""
+    it (``free``); retirement frees the rest and ``unreserve``s.
+    ``page_size`` is the group's own, in tokens.  ``aligned`` is the window's
+    form: False, the last ``window`` positions (a ring that rotates); True,
+    the positions from the last multiple of ``window`` on (a table that is
+    emptied whole at each multiple and filled again from its first column)."""
 
-    def __init__(self, name, num_pages, page_size, window=None):
+    def __init__(self, name, num_pages, page_size, window=None,
+                 aligned=False):
         if num_pages < 2:
             raise ServingError(
                 "page group %r: num_pages must be >= 2 (page 0 is the "
                 "scratch page), got %d" % (name, num_pages))
         if window is not None and int(window) < 1:
             raise ServingError("page group %r: window must be >= 1" % name)
+        if aligned and (window is None or int(window) % int(page_size)):
+            raise ServingError(
+                "page group %r: an aligned window is whole pages (window %r, "
+                "page_size %d)" % (name, window, page_size))
         self.name = name
         self.num_pages = int(num_pages)
         self.page_size = int(page_size)
         self.window = None if window is None else int(window)
+        self.aligned = bool(aligned)
         self._free = collections.deque(range(1, self.num_pages))
         self._rc = [0] * self.num_pages
         self._used = 0
@@ -193,19 +215,41 @@ class PageGroup:
         """Pages a sequence of ``tokens`` positions reserves: every page of
         them, and with a window no more than a slot can hold live at once —
         the window plus a chunk in flight, unaligned — however long it is (a
-        sequence shorter than that never holds more than its own pages)."""
+        sequence shorter than that never holds more than its own pages).  An
+        ALIGNED window holds its ``window / page_size`` pages and, while the
+        decode steps that end it are still unread, the first page of the next
+        (two steps in flight at most: one page, two at a page size of 1); a
+        chunk never straddles a multiple of the window (the scheduler refuses
+        a chunk width that does not divide it)."""
         whole = -(-int(tokens) // self.page_size)
         if self.window is None:
             return whole
+        if self.aligned:
+            return min(whole, self.window // self.page_size
+                       + -(-2 // self.page_size))
         return min(whole, -(-(self.window + int(widest_chunk))
                             // self.page_size) + 1)
+
+    def table_width(self, tokens, widest_chunk):
+        """Columns of the group's page table for sequences of ``tokens``
+        positions: logical page ``p`` stands in column ``p % width``.  The
+        slot's bound, but for an aligned window: its pages alone, so that a
+        window's ``j``-th page is column ``j``."""
+        if self.aligned:
+            return min(-(-int(tokens) // self.page_size),
+                       self.window // self.page_size)
+        return self.slot_bound(tokens, widest_chunk)
 
     def first_live_page(self, next_pos):
         """The first logical page that a query at ``next_pos`` or later can
         still read: every position on the pages before it is more than
-        ``window - 1`` behind ``next_pos``."""
+        ``window - 1`` behind ``next_pos`` (sliding), or before the last
+        multiple of the window at or under ``next_pos`` (aligned)."""
         if self.window is None:
             return 0
+        if self.aligned:
+            return (int(next_pos) // self.window) * (
+                self.window // self.page_size)
         return max(0, int(next_pos) - self.window + 1) // self.page_size
 
     def can_reserve(self, n):
@@ -257,6 +301,7 @@ class PageGroup:
                   for p in range(1, self.num_pages)
                   if (self._rc[p] > 0) == (p in free)]
         return {"num_pages": self.num_pages, "window": self.window,
+                "aligned": self.aligned, "page_size": self.page_size,
                 "used_pages": self._used, "free_pages": len(self._free),
                 "reserved_pages": self.reserved,
                 "released_pages": self.released, "taken_pages": self.taken,
@@ -287,9 +332,11 @@ class PagedKVCache:
         with ``page_groups`` a leaf may name its ``group`` (whose
         ``num_pages`` its page axis then has).
     page_groups: None (one group, every position kept: ``num_pages`` is its
-        length), or an ordered ``{name: dict(window=, num_pages=)}`` — the
-        first is this cache's own allocator (``num_pages`` is then read from
-        it), each further one a :class:`PageGroup` in :attr:`groups`.
+        length), or an ordered ``{name: dict(window=, aligned=, page_size=,
+        num_pages=)}`` — the first is this cache's own allocator
+        (``num_pages`` is then read from it, and :attr:`page_size` is ITS page
+        size where it states one), each further one a :class:`PageGroup` in
+        :attr:`groups` with the page size it states, else ``page_size``.
     slot_state / num_slots: slot-indexed leaves, ``{name: dict(layers=,
         shape=, dtype=)}`` -> ``[layers, num_slots, *shape]``.
     device: commit every leaf there (None: jax's default placement).
@@ -315,6 +362,11 @@ class PagedKVCache:
                     "and keeps every position; state the window group after "
                     "it" % self.primary_group)
             num_pages = first["num_pages"]
+        # the page size of a group that states none; ``self.page_size`` is
+        # the FIRST group's own (tables, ``pages_for``, the prefix hashes)
+        shared_page_size = int(page_size)
+        if first is not None and first.get("page_size"):
+            page_size = int(first["page_size"])
         if num_pages < 2:
             raise ServingError(
                 "num_pages must be >= 2 (page 0 is the reserved scratch "
@@ -337,8 +389,9 @@ class PagedKVCache:
         # the further groups, and the leaves of each (the first group's are
         # the rest of ``_page_leaves``)
         self.groups = {
-            name: PageGroup(name, spec["num_pages"], self.page_size,
-                            spec.get("window"))
+            name: PageGroup(name, spec["num_pages"],
+                            spec.get("page_size") or shared_page_size,
+                            spec.get("window"), spec.get("aligned", False))
             for name, spec in page_groups.items()}
         # the first group's own count of pages handed out (it releases none)
         self.taken = 0
@@ -352,23 +405,24 @@ class PagedKVCache:
                 "a cache with no K/V layers (num_layers == 0) needs a "
                 "page_pools leaf: it would hold nothing a page addresses")
         for name, spec in (page_pools or {}).items():
-            if self.page_size % int(spec["tokens_per_row"]):
-                raise ServingError(
-                    "page pool %r keeps one row per %d tokens, which does "
-                    "not divide page_size %d"
-                    % (name, spec["tokens_per_row"], self.page_size))
             group = spec.get("group", self.primary_group)
             if group != self.primary_group and group not in self.groups:
                 raise ServingError(
                     "page pool %r names group %r; the cache has %s"
                     % (name, group,
                        [self.primary_group] + sorted(self.groups)))
-            self._group_of[name] = group
+            ps = self.group_page_size(group)
             pages = (self.num_pages if group == self.primary_group
                      else self.groups[group].num_pages)
+            if ps % int(spec["tokens_per_row"]):
+                raise ServingError(
+                    "page pool %r keeps one row per %d tokens, which does "
+                    "not divide page_size %d"
+                    % (name, spec["tokens_per_row"], ps))
+            self._group_of[name] = group
             self._page_leaves[name] = (
                 (int(spec["layers"]), pages,
-                 self.page_size // int(spec["tokens_per_row"]),
+                 ps // int(spec["tokens_per_row"]),
                  int(spec["width"])),
                 jnp.dtype(spec.get("dtype") or self.dtype))
         self._slot_leaves = {
@@ -450,6 +504,11 @@ class PagedKVCache:
     def group_names(self):
         """Every page group's name, the cache's own first."""
         return (self.primary_group,) + tuple(self.groups)
+
+    def group_page_size(self, group):
+        """Tokens a page of ``group`` holds."""
+        return (self.page_size if group == self.primary_group
+                else self.groups[group].page_size)
 
     @property
     def slot_leaf_names(self):
@@ -872,7 +931,8 @@ class PagedKVCache:
             own = {k: st[k] for k in ("num_pages", "used_pages", "free_pages",
                                       "rc_errors", "rc_sum_matches")}
             # the first group keeps every position: it releases nothing
-            own.update(window=None, taken_pages=self.taken, released_pages=0)
+            own.update(window=None, aligned=False, page_size=self.page_size,
+                       taken_pages=self.taken, released_pages=0)
             st["groups"] = dict({self.primary_group: own},
                                 **{n: g.stats() for n, g in
                                    self.groups.items()})

@@ -114,7 +114,7 @@ def test_one_group_and_no_window_is_the_cache_every_model_had():
 # is missing, stale, freed too early or read though it is dead moves a logit by
 # a whole number, and a NaN anywhere the walk touches shows.
 
-def _histogram(leaf, table, lens, lo):
+def _histogram(leaf, table, lens, lo, PS=PS):
     """``[S, V]``: the sum of layer 0's rows of ``leaf`` at each slot's
     positions ``lo[s] .. lens[s] - 1``, logical page ``p`` in column ``p %
     width`` of ``table [S, width]``; pages wholly outside that range are not
@@ -448,3 +448,261 @@ def test_a_model_that_states_no_groups_gets_the_arrays_themselves():
     assert seen["written"].shape == (1,) or seen["written"].shape == (2,)
     assert seen["gathered"].shape == (16,)
     assert "kv_groups" not in sched.stats()
+
+
+# -- an ALIGNED window, and a first group with a page size of its own ---------
+#
+# The same toy, with the window group aligned (a query at ``t`` reads ``(t //
+# AW) * AW .. t``: two pages that fill, go back together and fill again) and
+# the first group on pages of ``APS`` = 8 tokens where the cache's are 4.
+
+AW, APS = 8, 8
+_LEAVES = (("kf", "all", APS), ("kw", "window", PS))
+
+
+def _aligned_logits(cache, tables, lens):
+    full = _histogram(cache["kf"], tables["all"], lens, jnp.zeros_like(lens),
+                      PS=APS)
+    win = _histogram(cache["kw"], tables["window"], lens,
+                     (jnp.maximum(lens - 1, 0) // AW) * AW)
+    return full + 100.0 * win
+
+
+def _aligned_decode(params, tokens, positions, cache, tables, kv_lens):
+    cache = dict(cache)
+    S = tokens.shape[0]
+    row = jax.nn.one_hot(tokens, V, dtype=jnp.float32)
+    for leaf, g, ps in _LEAVES:
+        t = tables[g]
+        page = t[jnp.arange(S), (positions // ps) % t.shape[1]]
+        cache[leaf] = cache[leaf].at[0, page, positions % ps].set(row)
+    return _aligned_logits(cache, tables, kv_lens), cache
+
+
+def _aligned_chunk(params, tokens, start, valid, cache, chunk_pages,
+                   gather_pages, slot):
+    cache = dict(cache)
+    C = tokens.shape[0]
+    rows = jax.nn.one_hot(tokens, V, dtype=jnp.float32)
+    for leaf, g, ps in _LEAVES:
+        # whole pages, or a part of the one page that holds ``start``
+        local = start % ps + jnp.arange(C)
+        cache[leaf] = cache[leaf].at[
+            0, chunk_pages[g][local // ps], local % ps].set(rows)
+    return _aligned_logits(
+        cache, {g: t[None] for g, t in gather_pages.items()},
+        (start + valid)[None])[0], cache
+
+
+@functools.lru_cache(maxsize=None)
+def _aligned_model(window=AW):
+    leaf = dict(layers=1, tokens_per_row=1, width=V, dtype=None)
+    return serving.DecodeModel(
+        _aligned_decode, _aligned_chunk,
+        params={"unused": np.zeros((1,), np.float32)}, vocab_size=V,
+        name="toy-aligned",
+        page_groups={"all": dict(window=None, page_size=APS),
+                     "window": dict(window=window, aligned=True)},
+        page_pools={"kf": dict(leaf, group="all"),
+                    "kw": dict(leaf, group="window")})
+
+
+def _aligned_config(**over):
+    return _config(**dict(dict(num_pages={"all": 17, "window": 7}), **over))
+
+
+def _aligned_expected(prompt, n_new):
+    seq, out = list(prompt), []
+    for _ in range(n_new):
+        t = len(seq) - 1
+        full = np.bincount(seq, minlength=V)
+        win = np.bincount(seq[(t // AW) * AW:], minlength=V)
+        out.append(int(np.argmax(full + 100.0 * win)))
+        seq.append(out[-1])
+    return out
+
+
+@pytest.mark.parametrize("next_pos,first", [
+    (0, 0), (AW - 1, 0), (AW, 2), (2 * AW - 1, 2), (2 * AW, 4)])
+def test_an_aligned_window_lives_from_the_last_multiple_on(next_pos, first):
+    g = kv_cache.PageGroup("w", 9, PS, window=AW, aligned=True)
+    assert g.first_live_page(next_pos) == first
+    # its pages and the next window's first; a table of its pages alone
+    assert g.slot_bound(10 ** 6, widest_chunk=8) == AW // PS + 1 == 3
+    assert g.table_width(10 ** 6, widest_chunk=8) == AW // PS
+    assert g.slot_bound(PS + 1, 8) == 2 == g.table_width(PS + 1, 8)
+    assert g.stats()["aligned"] is True
+    with pytest.raises(ServingError, match="whole pages"):
+        kv_cache.PageGroup("w", 9, PS, window=AW + 1, aligned=True)
+
+
+def test_every_group_has_a_page_size_of_its_own():
+    c = serving.PagedKVCache(
+        0, None, PS, 0, 0, 64, num_slots=2,
+        page_pools={"s": dict(layers=1, tokens_per_row=4, width=8, dtype=None,
+                              group="all"),
+                    "k": dict(layers=2, tokens_per_row=1, width=8, dtype=None,
+                              group="window")},
+        page_groups={"all": dict(window=None, page_size=16, num_pages=5),
+                     "window": dict(window=AW, aligned=True, num_pages=7)})
+    # the first group's page is 16 tokens = 4 rows of 4; the window's the
+    # cache's own 4
+    assert c.page_size == 16 and c.max_pages_per_seq == 4
+    assert c.pages_for(17) == 2 and c.group_page_size("all") == 16
+    assert c.groups["window"].page_size == c.group_page_size("window") == PS
+    assert c.pools["s"].shape == (1, 5, 4, 8)
+    assert c.pools["k"].shape == (2, 7, PS, 8)
+    st = c.stats()["groups"]
+    assert (st["all"]["page_size"], st["window"]["page_size"]) == (16, PS)
+    assert (st["all"]["aligned"], st["window"]["aligned"]) == (False, True)
+    with pytest.raises(ServingError, match="does not divide"):
+        serving.PagedKVCache(
+            0, None, PS, 0, 0, 64,
+            page_pools={"s": dict(layers=1, tokens_per_row=3, width=8,
+                                  dtype=None, group="all")},
+            page_groups={"all": dict(window=None, page_size=16, num_pages=5)})
+
+
+def test_the_scheduler_fills_an_aligned_window_and_gives_it_back_whole():
+    """Three requests over two slots through six and more boundaries each,
+    one step in flight: every token is the one a whole-history count (pages
+    of 8) and a count from the last multiple of 8 on give; a window's two
+    pages go back in ONE call, at the step that reaches the multiple and none
+    before, poisoned as they go; a planned step's live columns name only
+    pages its slot still holds; a slot never holds more than its bound; both
+    free lists are whole at the end."""
+    sched = serving.DecodeScheduler(_aligned_model(), _aligned_config(),
+                                    autostart=False)
+    grp = sched.cache.groups["window"]
+    bound = grp.slot_bound(64, 8)
+    assert bound == 3 and sched._more_tables["window"].shape == (2, 2)
+    assert sched._tables.shape == (2, 64 // APS)
+    releases, held_most = [], [0]
+    release, plan, free = sched._release_window, sched._plan_step, grp.free
+
+    def watch_release(idx, slot):
+        before = grp.released
+        release(idx, slot)
+        if grp.released > before:
+            releases.append((slot.kv_len, grp.released - before))
+
+    def watch_plan():
+        step = plan()
+        if step is not None:
+            table, lens = np.asarray(step.args[2]["window"]), np.asarray(
+                step.args[3])
+            for i, slot in step.entries:
+                held_most[0] = max(held_most[0],
+                                   len(slot.more["window"].pages))
+                live = table[i, :((lens[i] - 1) % AW) // PS + 1]
+                assert set(live) <= set(slot.more["window"].pages), (
+                    live, slot.more["window"].pages)
+        return step
+
+    def poison(pages, released=False):
+        if released:
+            sched.cache.pools["kw"] = sched.cache.pools["kw"].at[
+                :, jnp.asarray(list(pages))].set(jnp.nan)
+        free(pages, released=released)
+
+    sched._release_window, sched._plan_step = watch_release, watch_plan
+    grp.free = poison
+    rng = np.random.RandomState(0)
+    prompts = [rng.randint(1, V, size=n).astype(np.int32)
+               for n in (29, 3, 16)]
+    sched.start()
+    outs = [sched.submit(p, max_new_tokens=30) for p in prompts]
+    for p, f in zip(prompts, outs):
+        assert list(f.result(timeout=120)) == _aligned_expected(p, 30)
+    sched.stop()
+    assert obs.counter("serving.decode.steps_overlapped").value > 0
+    # every release is a whole window, at a multiple of the window
+    assert releases and all(at % AW == 0 and n == AW // PS
+                            for at, n in releases), releases
+    assert len(releases) == sum((len(p) + 29) // AW for p in prompts)
+    assert 0 < held_most[0] <= bound
+    st = sched.cache_stats()
+    for g in ("all", "window"):
+        assert st["groups"][g]["used_pages"] == 0, st
+        assert st["groups"][g]["rc_errors"] == []
+        assert st["groups"][g]["rc_sum_matches"]
+    assert st["groups"]["window"]["reserved_pages"] == 0
+    assert not sched._more_tables["window"].any() and not sched._tables.any()
+
+
+def test_a_chunk_that_would_straddle_an_aligned_window_is_refused():
+    with pytest.raises(ServingError, match="straddle"):
+        serving.DecodeScheduler(_aligned_model(window=12), _aligned_config(),
+                                autostart=False)
+    # and a page size that a chunk width neither fills nor fits inside
+    with pytest.raises(ServingError, match="neither"):
+        serving.DecodeScheduler(
+            _aligned_model(),
+            _aligned_config(page_size=2, prefill_buckets=(6, 8, 64)),
+            autostart=False)
+
+
+# -- ONE group on pages of its own size, with everything a first group can do -
+
+def _wide_decode(params, tokens, positions, cache, tables, kv_lens):
+    t = tables["all"]
+    page = t[jnp.arange(tokens.shape[0]), positions // APS]
+    cache = dict(cache, kf=cache["kf"].at[0, page, positions % APS].set(
+        jax.nn.one_hot(tokens, V, dtype=jnp.float32)))
+    return _histogram(cache["kf"], t, kv_lens, jnp.zeros_like(kv_lens),
+                      PS=APS), cache
+
+
+def _wide_chunk(params, tokens, start, valid, cache, chunk_pages,
+                gather_pages, slot):
+    local = start % APS + jnp.arange(tokens.shape[0])
+    cache = dict(cache, kf=cache["kf"].at[
+        0, chunk_pages["all"][local // APS], local % APS].set(
+            jax.nn.one_hot(tokens, V, dtype=jnp.float32)))
+    n = (start + valid)[None]
+    return _histogram(cache["kf"], gather_pages["all"][None], n,
+                      jnp.zeros_like(n), PS=APS)[0], cache
+
+
+def test_a_first_group_on_wider_pages_keeps_the_guard_and_the_prefix_cache():
+    """One group that states ``page_size`` 8 under a ``DecodeConfig`` whose
+    own is 4: the default pool, the guard's tail page, a hit's cached tokens
+    and the pages a chunk publishes are all counted in the GROUP's pages (in
+    the config's, ``slot.pages[kv_len // 4]`` runs off the slot's list and a
+    hit of two pages would stand for 8 tokens)."""
+    leaf = dict(layers=1, tokens_per_row=1, width=V, dtype=None, group="all")
+    model = serving.DecodeModel(
+        _wide_decode, _wide_chunk,
+        params={"unused": np.zeros((1,), np.float32)}, vocab_size=V,
+        name="toy-wide", page_groups={"all": dict(window=None,
+                                                  page_size=APS)},
+        page_pools={"kf": leaf})
+    sched = serving.DecodeScheduler(
+        model, _config(num_pages=None, prefix_cache=True, kv_guard=True,
+                       max_new_tokens=24), autostart=False)
+    cache = sched.cache
+    assert cache.page_size == APS and cache.num_pages == 2 * 64 // APS + 1
+    placed, place = [], sched._place
+
+    def watch_place(req, pages, cached_tokens, *rest):
+        placed.append((len(pages), cached_tokens))
+        return place(req, pages, cached_tokens, *rest)
+
+    sched._place = watch_place
+    rng = np.random.RandomState(3)
+    shared = rng.randint(1, V, size=2 * APS + 3).astype(np.int32)
+    prompts = [np.concatenate([shared, rng.randint(1, V, size=n)]).astype(
+        np.int32) for n in (2, 9)]
+    sched.start()
+    for p in prompts:       # one after the other: the second finds the first
+        out = sched.submit(p, max_new_tokens=24).result(timeout=120)
+        seq = list(p)
+        for tok in out:
+            assert tok == int(np.argmax(np.bincount(seq, minlength=V)))
+            seq.append(tok)
+    sched.stop()
+    # the second request maps the two whole pages of the shared prefix
+    assert [c for _, c in placed] == [0, 2 * APS], placed
+    assert obs.counter("serving.decode.kv_guard_trips").value == 0
+    st = sched.cache_stats()
+    assert st["rc_errors"] == [] and st["rc_sum_matches"], st

@@ -265,6 +265,35 @@ def test_paged_decode_walk_compiles_on_the_stored_stack(
         jax.ShapeDtypeStruct((_S,), jnp.int32, sharding=one_chip)) == 1
 
 
+@pytest.mark.parametrize("qdtype", [jnp.float32, jnp.bfloat16],
+                         ids=["q-f32", "q-bf16"])
+def test_eva_decode_compiles_at_the_published_shape(one_chip,
+                                                    no_persistent_cache,
+                                                    qdtype):
+    """``evabyte_standing_decode``'s own call: 24 slots, 32 heads of 128 =
+    4096 lanes, the window group's ``[8, 793, 64, 4096]`` and the summary
+    group's ``[8, 481, 64, 4096]`` bf16 stacks left in HBM (pages of 64 rows
+    in both, 64 and 1024 tokens), tables of 32 columns each; one kernel, 256
+    rows a turn of either list."""
+    assert FA._eva_turn_pages(64, 32) * 64 == 256
+
+    def f(q, k, v, ks, vs, tw, ts, lw, ls):
+        return FA.paged_eva_decode_attention(
+            q, k, v, ks, vs, tw, ts, lw, ls, layer=5, impl="pallas",
+            interpret=False)
+
+    def stack(pages):
+        return jax.ShapeDtypeStruct((8, pages, 64, 4096), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                               sharding=one_chip)
+    assert _kernel_calls(
+        f, jax.ShapeDtypeStruct((24, 32, 128), qdtype, sharding=one_chip),
+        stack(793), stack(793), stack(481), stack(481), ints(24, 32),
+        ints(24, 32), ints(24), ints(24)) == 1
+
+
 @pytest.mark.parametrize("ps,lanes,mp,itemsize,n_head,pages", [
     (16, 512, 128, 2, 8, 32),     # the chat cell: 512 keys a turn, 4 turns
     (16, 512, 128, 4, 8, 32),     # an f32 pool of the same shape
