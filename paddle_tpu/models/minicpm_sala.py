@@ -42,7 +42,6 @@ __all__ = ["sala_params", "sala_prefill_chunk", "sala_decode_step",
            "build_decode_model", "cache_layout", "lightning_slopes",
            "select_blocks", "STEP_COUNTERS"]
 
-NEG_INF = -1e30
 LIGHTNING_BLOCK = 128       # tokens of one step of the chunk-wise scan
 STEP_COUNTERS = ("sparse.selected_tokens", "sparse.visible_tokens",
                  "sparse.dense_rows")
@@ -211,45 +210,23 @@ def select_blocks(d, q, hb, n):
     batch entry's whole page-table span in cache order, ``n [Bt, R]`` visible
     keys per row (0 = no row).  Returns ``mask [Bt, R, Hkv, NB]`` bool: the
     blocks each KV head's group reads (every visible block where
-    ``n <= dense_len``)."""
-    import jax
+    ``n <= dense_len``).  The scores (``flash_attention.block_scores``) and
+    the pick from them are two steps: a decode step takes its scores from the
+    kernel that walks the pooled keys where they lie, and picks the same."""
+    from ..parallel.flash_attention import block_scores
+
+    return _pick_blocks(d, block_scores(
+        q, hb, n, kernel_size=d["l"], stride=d["s"], block_size=d["B"]), n)
+
+
+def _pick_blocks(d, score, n):
+    """The selection from its scores: ``score [Bt, R, Hkv, NB]`` float32,
+    ``n [Bt, R]`` -> ``mask [Bt, R, Hkv, NB]`` bool (block 0, the window's
+    blocks and the ``topk`` best of the other visible ones; every visible
+    block where ``n <= dense_len``)."""
     import jax.numpy as jnp
 
-    Bt, R, Hq, Dh = q.shape
-    NHB, Hkv = hb.shape[1:3]
-    g = Hq // Hkv
-    per = d["B"] // d["s"]                 # half-kernels a block
-    NB = NHB // per
-    span = d["l"] // d["s"]                # half-kernels a pooled key averages
-    NK = NHB - span + 1
-    # one batched q . hb^T per KV head: the pooled rows stay in the order
-    # the gather left them (a k-major einsum made the compiler transpose
-    # the whole gathered span first)
-    qg = q.reshape(Bt, R, Hkv, g, Dh)
-    dots = jnp.stack([
-        jnp.einsum("brgd,bjd->brgj", qg[:, :, k], hb[:, :, k],
-                   precision=jax.lax.Precision.HIGHEST)
-        for k in range(Hkv)], axis=2)
-    # pooled key j = mean of half-kernels j .. j + span - 1 (linear in q)
-    kscore = sum(dots[..., o:o + NK] for o in range(span)) / (
-        span * math.sqrt(Dh))
-    # complete kernels inside the visible range: s j + l - 1 <= n - 1
-    nk = jnp.where(n >= d["l"], (n - d["l"]) // d["s"] + 1, 0)
-    live = jnp.arange(NK)[None, None, :] < nk[..., None]          # [Bt,R,NK]
-    live = live[:, :, None, None, :]
-    p = jax.nn.softmax(jnp.where(live, kscore, NEG_INF), axis=-1)
-    p = jnp.where(live, p, 0.0)
-    # a block's score: max of p over the kernels that overlap it, summed
-    # over the group.  kernel j overlaps block b iff
-    # per * b - (span - 1) <= j <= per * b + per - 1
-    blocks = jnp.arange(NB)
-    score = None
-    for o in range(per + span - 1):
-        j = per * blocks - (span - 1) + o
-        col = jnp.where((j >= 0) & (j < NK),
-                        jnp.take(p, jnp.clip(j, 0, NK - 1), axis=-1), 0.0)
-        score = col if score is None else jnp.maximum(score, col)
-    score = score.sum(axis=3)                                    # [Bt,R,Hkv,NB]
+    blocks = jnp.arange(score.shape[-1])
     cur = (n - 1) // d["B"]                                      # [Bt, R]
     visible = (blocks[None, None, :] <= cur[..., None]) & (n[..., None] > 0)
     forced = (blocks[None, None, :] < d["init"]) | (
@@ -467,7 +444,8 @@ def sala_decode_step(params, tokens, positions, cache, page_tables, kv_lens,
     slots, sparse layers and KV heads, and the rows that ran dense."""
     import jax.numpy as jnp
 
-    from ..parallel.flash_attention import paged_decode_attention
+    from ..parallel.flash_attention import (paged_block_scores,
+                                            paged_decode_attention)
 
     d = _dims(cfg)
     k_pool, v_pool, kbar, lin = (cache[n] for n in ("k", "v", "kbar", "lin"))
@@ -503,8 +481,10 @@ def sala_decode_step(params, tokens, positions, cache, page_tables, kv_lens,
             # a half-kernel overwrites whatever the page held before
             old = jnp.where(opens_row, 0.0, kbar[si, pages, hb_row])
             kbar = kbar.at[si, pages, hb_row].set(old + kf / d["s"])
-            hb = kbar[si, page_tables].reshape(S, -1, d["Hkv"], d["Dh"])
-            mask = select_blocks(d, q[:, None], hb, kv_lens[:, None])[:, 0]
+            score = paged_block_scores(
+                q, kbar, page_tables, kv_lens, layer=si, kernel_size=d["l"],
+                stride=d["s"], impl=attn_impl)
+            mask = _pick_blocks(d, score[:, None], kv_lens[:, None])[:, 0]
             masks.append(mask)
             sel_pages, sel_tokens = _listed(d, mask, kv_lens, page_tables)
             selected = selected + sel_tokens.sum()

@@ -94,7 +94,7 @@ __all__ = ["flash_attention", "flash_attention_rows", "mha_reference",
            "paged_mla_decode_attention", "paged_mla_prefill_attention",
            "paged_gqa_decode_attention", "paged_gqa_prefill_attention",
            "paged_eva_decode_attention", "paged_eva_prefill_attention",
-           "paged_kv_finite"]
+           "block_scores", "paged_block_scores", "paged_kv_finite"]
 
 # what tools and tests pass explicitly, and the scan backward's key block;
 # the kernels choose their own from the shape (_fwd_tiles, _bwd_tiles)
@@ -2039,6 +2039,283 @@ def _paged_gqa_pallas(q, k_pool, v_pool, pages, tokens, sm_scale, interpret,
     )(pages.reshape(S * n_kv * ns), tokens.reshape(S * n_kv), q, k_pool,
       v_pool)
     return out[:, :, :g].reshape(S, Hq, Dh)
+
+
+# ---------------------------------------------------------------------------
+# The SCORES a selection is picked from (InfLLM-V2, arXiv:2506.07900 section
+# 2.2, as MiniCPM-SALA's sparse layers use it).  Beside K and V the cache
+# keeps, a page, ``per = page_size / stride`` float32 rows: the mean of the
+# keys of each ``stride`` tokens (a "half-kernel").  Pooled key ``j`` is the
+# mean of rows ``j .. j + span - 1`` (``span = kernel_size / stride``), a
+# query row's softmax runs over the pooled keys that lie whole inside its
+# visible tokens, a block's (= page's) score is the largest probability among
+# the ``per + span - 1`` pooled keys that overlap it, and a KV head's score is
+# the sum over its group's query rows.  ``block_scores`` is that on rows
+# already gathered (a prefill chunk: many query rows, one sequence's pages);
+# in decode ``paged_block_scores`` walks each slot's page table over the
+# pooled-row pool IN PLACE: one grid step a slot, the slot's
+# ``ceil(kv_len / page_size)`` live pages copied whole (``per`` rows of all KV
+# heads' lanes: 4 KB at MiniCPM-SALA's widths), many to a turn, the next turn's
+# copies in flight (the discipline of ``_walk_pages``), and the whole row of
+# scores finished in VMEM.  What XLA made of the same step was a gather of
+# every slot's whole table span, its relayout and a contraction a KV head:
+# 159 MB written and read back a layer, 2.16 ms of an 18.2 ms decode step
+# (PERF.md section 6, PR 50).
+#
+# A page's rows land in VMEM as they lie in HBM (``[per, lanes]`` tiles of
+# their own: the stored stack's device layout, which a copy cannot change),
+# and a turn's tile is read as ``[pages * per, lanes]`` rows in cache order:
+# half-kernel ``j`` of the slot is lane ``j`` of the products.  Pooling, the
+# softmax and the overlap maximum are then elementwise over one ``[query
+# rows, half-kernels]`` array and its rolls by a few lanes, every lane holds
+# the score of a block that WOULD start there, and the caller keeps each
+# ``per``-th lane: no strided access inside the kernel.  (Reading row ``r`` of
+# every page apart, to keep ``per`` arrays a lane a page, made Mosaic load,
+# rotate and select page by page: 3000 operations a turn, 0.5 of a 1.1 ms
+# call; PERF.md section 6, PR 50.)
+# ---------------------------------------------------------------------------
+
+
+def block_scores(q, hb, n, *, kernel_size, stride, block_size):
+    """``q [Bt, R, Hq, Dh]`` float32 query rows, ``hb [Bt, NHB, Hkv, Dh]``
+    float32 half-kernel key means of each batch entry's whole page-table span
+    in cache order, ``n [Bt, R]`` visible keys per row (0 = no row).  Returns
+    ``score [Bt, R, Hkv, NB]`` float32, ``NB = NHB * stride / block_size``;
+    rows past ``n``, incomplete half-kernels and whatever else the span holds
+    count for nothing.  The contraction is float32 (highest precision)."""
+    import jax.numpy as jnp
+
+    Bt, R, Hq, Dh = q.shape
+    NHB, Hkv = hb.shape[1:3]
+    g = Hq // Hkv
+    per = block_size // stride             # half-kernels a block
+    NB = NHB // per
+    span = kernel_size // stride           # half-kernels a pooled key averages
+    NK = NHB - span + 1
+    # one batched q . hb^T per KV head: the pooled rows stay in the order
+    # the gather left them (a k-major einsum made the compiler transpose
+    # the whole gathered span first)
+    qg = q.reshape(Bt, R, Hkv, g, Dh)
+    dots = jnp.stack([
+        jnp.einsum("brgd,bjd->brgj", qg[:, :, k], hb[:, :, k],
+                   precision=jax.lax.Precision.HIGHEST)
+        for k in range(Hkv)], axis=2)
+    # pooled key j = mean of half-kernels j .. j + span - 1 (linear in q)
+    kscore = sum(dots[..., o:o + NK] for o in range(span)) / (
+        span * math.sqrt(Dh))
+    # complete kernels inside the visible range: s j + l - 1 <= n - 1
+    nk = jnp.where(n >= kernel_size, (n - kernel_size) // stride + 1, 0)
+    live = jnp.arange(NK)[None, None, :] < nk[..., None]          # [Bt,R,NK]
+    live = live[:, :, None, None, :]
+    p = jax.nn.softmax(jnp.where(live, kscore, NEG_INF), axis=-1)
+    p = jnp.where(live, p, 0.0)
+    # a block's score: max of p over the kernels that overlap it, summed
+    # over the group.  kernel j overlaps block b iff
+    # per * b - (span - 1) <= j <= per * b + per - 1
+    blocks = jnp.arange(NB)
+    score = None
+    for o in range(per + span - 1):
+        j = per * blocks - (span - 1) + o
+        col = jnp.where((j >= 0) & (j < NK),
+                        jnp.take(p, jnp.clip(j, 0, NK - 1), axis=-1), 0.0)
+        score = col if score is None else jnp.maximum(score, col)
+    return score.sum(axis=3)                                     # [Bt,R,Hkv,NB]
+
+
+# Pages a turn of the scoring walk.  A turn's products are stored at lane
+# ``turn * pages * per`` of the row, so a turn is whole 128-lane tiles (or the
+# whole table where it is shorter).  At 4 KB a page the two tiles are 1 MB.
+_SCORES_TURN_PAGES = 128
+_SCORES_KERNEL_NAME = "paged_block_scores"
+
+
+def _scores_turn_pages(mp):
+    """Pages a turn of the scoring walk, from the table's width alone."""
+    return min(mp, _SCORES_TURN_PAGES)
+
+
+def _block_scores_kernel(pt_ref, lens_ref, q_ref, hb_hbm, o_ref, buf, dots,
+                         sem, *, layer, pages, n_kv, group, per_head,
+                         head_dim, kernel_size, stride):
+    """One grid step = one slot: the walk over its live pages' pooled rows
+    (``buf [2, pages, per, lanes]``, a page an entry), each turn's ``q . row``
+    products stored in cache order in ``dots [n_kv * per_head, width]``
+    (half-kernel ``j`` at lane ``j``), then the whole row finished from
+    there: pooled keys, the softmax over the live ones, for every lane the
+    largest probability among the pooled keys that overlap a block STARTING
+    there, and the group's sum into ``o_ref [n_kv, width]`` (the caller keeps
+    the lanes where a block does start).
+
+    A turn is ``pages`` copies on one semaphore and ONE wait for the tile's
+    bytes: entry ``i`` of turn ``t`` is the slot's page ``t * pages + i``, or
+    its last live page again where the turn runs past them, so no page past
+    ``kv_len`` is read and no trip count depends on the slot.  A live pooled
+    key reads complete half-kernels of copied pages alone; the lanes past
+    them hold whatever the tiles held and are dropped by a ``where`` on the
+    live mask before any reduction."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    s_idx = pl.program_id(0)
+    per, lanes = buf.shape[2:]
+    rows, width = dots.shape
+    span = kernel_size // stride
+    ps = per * stride
+    turn = pages * per                  # half-kernels a turn
+    kvl = lens_ref[s_idx]
+    div = jax.lax.div
+    n_pages = div(kvl + (ps - 1), ps)
+    n_turns = div(n_pages + (pages - 1), pages)
+
+    def start(t, slot):
+        for i in range(pages):
+            page = pt_ref[s_idx, jnp.minimum(t * pages + i, n_pages - 1)]
+            pltpu.make_async_copy(hb_hbm.at[layer, page], buf.at[slot, i],
+                                  sem.at[slot]).start()
+
+    heads = [(slice(h * per_head, (h + 1) * per_head),
+              slice(h * head_dim, (h + 1) * head_dim)) for h in range(n_kv)]
+
+    @pl.when(n_turns > 0)
+    def _first():
+        start(0, 0)
+
+    def one_turn(t, _):
+        slot = jax.lax.rem(t, 2)
+
+        @pl.when(t + 1 < n_turns)
+        def _next():
+            start(t + 1, 1 - slot)
+
+        # the tile's bytes = the turn's copies: one wait for all of them
+        pltpu.make_async_copy(buf.at[slot], buf.at[slot], sem.at[slot]).wait()
+        x = buf[slot].reshape(turn, lanes)          # cache order, all heads
+        at = pl.ds(pl.multiple_of(t * turn, turn), turn)
+        for rs, ls in heads:
+            # float32 operands at the highest precision: Mosaic takes the
+            # product as XLA's HIGHEST takes it (the same bits on the chip)
+            dots[rs, at] = jax.lax.dot_general(
+                q_ref[rs, :], x[:, ls], (((1,), (1,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)
+        return _
+
+    jax.lax.fori_loop(0, n_turns, one_turn, None)
+
+    def ahead(x, by):
+        """``x`` read ``by`` lanes on (``out[j] = x[j + by]``); the lanes
+        that wrap are never live (see the wrapper)."""
+        shift = (-by) % width
+        return pltpu.roll(x, shift, 1) if shift else x
+
+    nk = jnp.where(kvl >= kernel_size, div(kvl - kernel_size, stride) + 1, 0)
+    live = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1) < nk
+    d = dots[...]
+    pooled = d
+    for o in range(1, span):
+        pooled = pooled + ahead(d, o)
+    scaled = jnp.where(live, pooled / (span * math.sqrt(head_dim)), NEG_INF)
+    e = jnp.exp(scaled - scaled.max(axis=1, keepdims=True))
+    p = jnp.where(live, e / e.sum(axis=1, keepdims=True), 0.0)
+    # a block that starts at half-kernel j meets pooled keys j - (span - 1)
+    # .. j + per - 1
+    best = None
+    for o in range(-(span - 1), per):
+        col = ahead(p, o)
+        best = col if best is None else jnp.maximum(best, col)
+    for h, (rs, _) in enumerate(heads):
+        o_ref[h:h + 1, :] = best[rs][:group].sum(axis=0, keepdims=True)
+
+
+def _paged_block_scores_pallas(q, hb_pool, page_tables, kv_lens, kernel_size,
+                               stride, interpret, layer):
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from .. import observability as obs
+
+    S, Hq, Dh = q.shape
+    per, lanes = hb_pool.shape[2:]
+    n_kv = lanes // Dh
+    g = Hq // n_kv
+    mp = page_tables.shape[1]
+    # a KV head's rows in whole float32 sublane tiles
+    g_pad = -(-g // 8) * 8
+    q = q.reshape(S, n_kv, g, Dh).astype(jnp.float32)
+    if g_pad != g:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, g_pad - g), (0, 0)))
+    pages = _scores_turn_pages(mp)
+    # half-kernels of the whole table, in whole turns.  A roll wraps only
+    # lanes at or past the last pooled key into a live lane's sum (they are
+    # masked first) and only into lanes past the last block's start
+    width = -(-mp // pages) * pages * per
+    steps = obs.counter("paged.select.grid_steps", labels={
+        "S": S, "heads": n_kv, "mp": mp, "ps": per * stride,
+        "turn": pages * per * stride})
+    if not steps.value:
+        steps.inc(S)
+    kernel = functools.partial(
+        _block_scores_kernel, layer=layer, pages=pages, n_kv=n_kv, group=g,
+        per_head=g_pad, head_dim=Dh, kernel_size=kernel_size, stride=stride)
+    (out,) = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(S,),
+            in_specs=[pl.BlockSpec((None, n_kv * g_pad, Dh),
+                                   lambda s, pt, kl: (s, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[pl.BlockSpec((None, n_kv, width),
+                                    lambda s, pt, kl: (s, 0, 0))],
+            scratch_shapes=[
+                pltpu.VMEM((2, pages, per, lanes), hb_pool.dtype),  # tiles
+                pltpu.VMEM((n_kv * g_pad, width), jnp.float32),     # q . rows
+                pltpu.SemaphoreType.DMA((2,)),                      # [tile]
+            ]),
+        out_shape=[jax.ShapeDtypeStruct((S, n_kv, width), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+        name=_SCORES_KERNEL_NAME,
+    )(page_tables.astype(jnp.int32), kv_lens.astype(jnp.int32),
+      q.reshape(S, n_kv * g_pad, Dh), hb_pool)
+    # a block starts every ``per`` half-kernels
+    return out[:, :, :mp * per:per]
+
+
+def paged_block_scores(q, hb_pool, page_tables, kv_lens, *, layer,
+                       kernel_size, stride, impl=None, interpret=None):
+    """Block scores of one decode token a slot against the slot's own pages.
+
+    q: ``[S, Hq, Dh]`` float32 (after QK-norm); hb_pool: the stored stack
+        ``[L, num_pages, page_size / stride, Hkv*Dh]`` float32 of half-kernel
+        key means, addressed in place by ``(layer, page)``; ``Hq = g * Hkv``.
+    page_tables ``[S, MP]`` / kv_lens ``[S]``: as
+        :func:`paged_decode_attention`.  The kernel reads no page past
+        ``kv_len`` and no row past the last complete half-kernel into a
+        score: unused table entries, unlisted pages and stale rows of a
+        reused page may hold anything.  ``kv_lens[s] == 0`` gives zeros.
+    impl: None/"auto" (pallas on TPU, reference elsewhere), "reference"
+        (gather of the table span, then :func:`block_scores`), or "pallas".
+    Returns ``[S, Hkv, MP]`` float32: :func:`block_scores` of the gathered
+    span, to float32 rounding.
+    """
+    impl, interpret = _mla_impl(impl, interpret)
+    n_kv = _gqa_heads(q, hb_pool)
+    if kernel_size % stride:
+        raise ValueError("a kernel of %d tokens is not whole strides of %d"
+                         % (kernel_size, stride))
+    if impl == "reference":
+        hb = hb_pool[int(layer), page_tables].reshape(
+            q.shape[0], -1, n_kv, q.shape[2])
+        return block_scores(
+            q[:, None], hb, kv_lens[:, None], kernel_size=kernel_size,
+            stride=stride, block_size=hb_pool.shape[2] * stride)[:, 0]
+    return _paged_block_scores_pallas(q, hb_pool, page_tables, kv_lens,
+                                      kernel_size, stride, interpret,
+                                      int(layer))
 
 
 # ---------------------------------------------------------------------------

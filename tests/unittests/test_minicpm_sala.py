@@ -79,8 +79,14 @@ def tokens():
 # the time at these sizes
 _CHUNK = jax.jit(functools.partial(M.sala_prefill_chunk, cfg=CFG,
                                    with_selection=True))
-_DECODE = jax.jit(functools.partial(M.sala_decode_step, cfg=CFG,
-                                    with_selection=True))
+# the decode step by engine: None is the backend's choice (on the CPU the XLA
+# scores over the gathered span and the reference attention), "pallas" the
+# two kernels a chip runs, interpreted
+_DECODE = {engine: jax.jit(functools.partial(
+    M.sala_decode_step, cfg=CFG, with_selection=True, attn_impl=engine))
+    for engine in (None, "pallas")}
+ENGINES = pytest.mark.parametrize("engine", [None, "pallas"],
+                                  ids=["xla", "pallas"])
 
 
 def _cache():
@@ -93,10 +99,11 @@ def _cache():
 
 
 def _through_the_cache(params, tokens, prompt_len, steps, chunk, slot=1,
-                       between=None):
+                       between=None, engine=None):
     """Prefill ``tokens[:prompt_len]`` in chunks of ``chunk`` into ``slot``,
-    then decode ``steps`` tokens (teacher forced) through the cache.  Returns
-    the logits at positions ``prompt_len - 1 ..`` and the selections there."""
+    then decode ``steps`` tokens (teacher forced) through the cache with the
+    decode step of ``engine``.  Returns the logits at positions ``prompt_len
+    - 1 ..`` and the selections there."""
     cache = _cache()
     pages = cache.alloc(cache.pages_for(prompt_len + steps))
     row = cache.table_row(pages)
@@ -120,7 +127,7 @@ def _through_the_cache(params, tokens, prompt_len, steps, chunk, slot=1,
     for t in range(prompt_len, prompt_len + steps):
         toks, pos, lens = (np.zeros(SLOTS, np.int32) for _ in range(3))
         toks[slot], pos[slot], lens[slot] = tokens[t], t, t + 1
-        lg, pools, counts, masks = _DECODE(
+        lg, pools, counts, masks = _DECODE[engine](
             params, jnp.asarray(toks), jnp.asarray(pos), pools,
             jnp.asarray(tables), jnp.asarray(lens))
         if between is not None:
@@ -175,8 +182,12 @@ def test_a_bfloat16_state_would_fail(params, tokens, truth):
 
 # 2. the selected sets --------------------------------------------------------
 
-def test_selected_sets_equal_the_reference(params, tokens, truth):
-    _, sels, _ = _through_the_cache(params, tokens, 60, 20, 16)
+@ENGINES
+def test_selected_sets_equal_the_reference(params, tokens, truth, engine):
+    """Whichever engine scores the pooled keys (the XLA contraction over the
+    gathered span, or the kernel that walks them in place), the pick is the
+    reference's: the two engines' masks are bit-equal."""
+    _, sels, _ = _through_the_cache(params, tokens, 60, 20, 16, engine=engine)
     for step, per_layer in enumerate(sels):
         for layer, mask in enumerate(per_layer):
             want = truth[1][layer][step]
@@ -224,6 +235,121 @@ def test_a_near_tie_changes_at_most_the_tied_blocks():
     nudged = selected(0.404)
     assert nudged[:, [0, 3, 5, 9, 10]].all() and nudged.sum() == 2 * 5
     assert (nudged != base)[:, [2, 5]].all() and (nudged != base).sum() == 4
+
+
+def test_the_split_left_select_blocks_as_it_was():
+    """``select_blocks`` is the scores and the pick from them since PR 50;
+    its mask on seeded inputs is the one the unsplit function gave (digest
+    taken on the parent commit)."""
+    import hashlib
+
+    rng = np.random.RandomState(7)
+    q = jnp.asarray(rng.randn(2, 3, 4, 16), jnp.float32)
+    hb = jnp.asarray(rng.randn(2, 48, 2, 16), jnp.float32)
+    n = jnp.asarray([[88, 41, 0], [96, 33, 70]], jnp.int32)
+    mask = _select(q, hb, n)
+    assert mask.shape == (2, 3, 2, 12) and mask.sum() == 54
+    assert hashlib.sha256(np.packbits(mask).tobytes()).hexdigest() == (
+        "e2aefcfbfb3dfd432b1ecfc5334b4f8d208cf6ed5e119547f224ad96e052b04f")
+    d = M._dims(CFG)
+    score = FA.block_scores(q, hb, n, kernel_size=d["l"], stride=d["s"],
+                            block_size=d["B"])
+    np.testing.assert_array_equal(np.asarray(M._pick_blocks(d, score, n)),
+                                  mask)
+
+
+# the scoring kernel's edges: a context that ends mid half-kernel (37), at a
+# page boundary (48), at the table's last token (96), an idle slot (0), one
+# shorter than a kernel (3), one kernel exactly (4), mid page (61)
+_SCORE_LENS = (37, 48, 96, 0, 3, 4, 61)
+
+
+def _score_case(seed, garbage):
+    """``(q, clean pool, dirty pool, tables, lens)``: PERMUTED page tables of
+    12 pages a slot; ``dirty`` holds ``garbage`` wherever no score may come
+    from: pages no table lists, the scratch page unused entries name, and
+    every row past a context's last complete half-kernel."""
+    rng = np.random.RandomState(seed)
+    d = M._dims(CFG)
+    S, MP, per = len(_SCORE_LENS), MAX_LEN // PAGE, PAGE // d["s"]
+    P = S * MP + 1
+    lens = np.asarray(_SCORE_LENS, np.int32)
+    clean = rng.randn(2, P, per, d["Hkv"] * d["Dh"]).astype(np.float32)
+    dirty = np.full_like(clean, garbage)
+    free = rng.permutation(P - 1) + 1
+    tables = np.zeros((S, MP), np.int32)        # 0 = the scratch page
+    for s, n in enumerate(lens):
+        pages = free[s * MP:s * MP + -(-int(n) // PAGE)]
+        tables[s, :len(pages)] = pages
+        rows = int(n) // d["s"]                 # complete half-kernels
+        for i, page in enumerate(pages):
+            keep = max(0, min(per, rows - i * per))
+            dirty[:, page, :keep] = clean[:, page, :keep]
+    q = rng.randn(S, d["Hq"], d["Dh"]).astype(np.float32)
+    return tuple(jnp.asarray(x) for x in (q, clean, dirty, tables, lens))
+
+
+@pytest.mark.parametrize("garbage", [1e30, np.nan], ids=["huge", "nan"])
+@pytest.mark.parametrize("turn_pages", [None, 4, 5],
+                         ids=["one-turn", "turns-of-4", "turns-of-5"])
+@pytest.mark.parametrize("layer", [0, 1])
+def test_the_scoring_kernel_is_the_xla_scores(monkeypatch, layer, turn_pages,
+                                              garbage):
+    """``paged_block_scores`` (interpreted) against ``block_scores`` of the
+    gathered CLEAN span: equal to float32 rounding (the products are summed in
+    another order: 1e-6 of a probability), although the kernel's pool holds
+    garbage in every row and page it must not read into a score."""
+    d = M._dims(CFG)
+    if turn_pages:
+        monkeypatch.setattr(FA, "_scores_turn_pages", lambda mp: turn_pages)
+    q, clean, dirty, tables, lens = _score_case(11 + layer, garbage)
+    hb = clean[layer, tables].reshape(len(_SCORE_LENS), -1, d["Hkv"], d["Dh"])
+    want = FA.block_scores(q[:, None], hb, lens[:, None], kernel_size=d["l"],
+                           stride=d["s"], block_size=d["B"])[:, 0]
+    got = FA.paged_block_scores(
+        q, dirty, tables, lens, layer=layer, kernel_size=d["l"],
+        stride=d["s"], impl="pallas", interpret=True)
+    assert got.shape == want.shape == (len(_SCORE_LENS), d["Hkv"],
+                                       MAX_LEN // PAGE)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5,
+                               atol=1e-6)
+    # a probability a pooled key, summed over the group's two rows
+    assert float(want.max()) > 0.1
+    got = np.asarray(got)
+    assert not got[[3, 4]].any()            # no complete kernel: no score
+    assert got[5, :, 0].tolist() == [2.0, 2.0] and not got[5, :, 1:].any()
+    # and the reference engine of the same entry point reads the same
+    ref = FA.paged_block_scores(
+        q, clean, tables, lens, layer=layer, kernel_size=d["l"],
+        stride=d["s"], impl="reference")
+    np.testing.assert_array_equal(np.asarray(ref), np.asarray(want))
+
+
+def test_a_traced_decode_step_says_it_scores_with_the_kernel(params):
+    """``paged.select.grid_steps`` is set at trace time, once a compiled
+    shape (two sparse layers trace the kernel twice): one walk a slot."""
+    from paddle_tpu import observability as obs
+
+    slots = 5                       # a shape no other test of the module has
+    d = M._dims(CFG)
+    mp = MAX_LEN // PAGE
+    cell = obs.counter("paged.select.grid_steps", labels={
+        "S": slots, "heads": d["Hkv"], "mp": mp, "ps": PAGE,
+        "turn": mp * PAGE})
+    assert cell.value == 0
+    lay = M.cache_layout(CFG)
+    pools = serving.PagedKVCache(
+        lay["num_layers"], slots * mp + 1, PAGE, lay["num_heads"],
+        lay["head_dim"], MAX_LEN, dtype="float32",
+        page_pools=lay["page_pools"], slot_state=lay["slot_state"],
+        num_slots=slots).pools
+    ints = jnp.zeros((slots,), jnp.int32)
+    step = jax.jit(functools.partial(M.sala_decode_step, cfg=CFG,
+                                     attn_impl="pallas"))
+    for _ in range(2):
+        step.lower(params, ints, ints, pools,
+                   jnp.zeros((slots, mp), jnp.int32), ints)
+        assert cell.value == slots
 
 
 # 3. the lightning scan -------------------------------------------------------
@@ -279,14 +405,17 @@ def test_a_reused_slot_and_mixed_batches_serve_what_a_fresh_engine_serves(
     batch.stop()
 
 
+@ENGINES
 def test_run_step_is_the_served_program_on_the_served_cache(
-        decode_model, tokens):
+        decode_model, params, tokens, engine):
     """What the benchmark's check reads the cache's guarantees from: a
     stopped scheduler runs its own warmed programs on its own cache, with no
-    compile, and gives the token it served."""
+    compile, and gives the token it served: on either engine (``pallas``:
+    the step built on the kernels, as a chip serves it)."""
     from paddle_tpu import executor
 
-    sched = _scheduler(decode_model)
+    sched = _scheduler(decode_model if engine is None else
+                       M.build_decode_model(params, CFG, attn_impl=engine))
     served = sched.generate(tokens[:16], max_new_tokens=2, timeout=300)
     with pytest.raises(serving.ServingError, match="owns the cache"):
         sched.run_step(("decode",))

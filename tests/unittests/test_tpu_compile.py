@@ -374,6 +374,101 @@ def test_listed_walk_compiles_for_v5e(one_chip, no_persistent_cache, qdtype):
                           .split(")")[0])) == 5
 
 
+@pytest.mark.parametrize("layer", [0, 1])
+def test_scoring_walk_compiles_for_v5e(one_chip, no_persistent_cache, layer):
+    """The selection's scores of ``sala_longctx_decode`` at the cell's own
+    shapes — 64 slots, a table of 608 pages of 64 tokens, 32 query / 2 KV
+    heads of 128 lanes, the stored float32 stack of half-kernel means ``[2,
+    24577, 4, 256]`` left in HBM, either layer of it — is ONE custom call
+    under its own name, 128 pages a turn, and nothing pool-sized beside it."""
+    S, Hq, Hkv, Dh, MP = 64, 32, 2, 128, 608
+    assert FA._scores_turn_pages(MP) == 128
+
+    def sds(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def f(q, pool, tables, lens):
+        return FA.paged_block_scores(
+            q, pool, tables, lens, layer=layer, kernel_size=32, stride=16,
+            impl="pallas", interpret=False)
+
+    compiled = jax.jit(f).lower(
+        sds((S, Hq, Dh), jnp.float32), sds((2, 24577, 4, Hkv * Dh),
+                                           jnp.float32),
+        sds((S, MP)), sds((S,))).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert FA._SCORES_KERNEL_NAME in text
+    # the pool is an operand where it lies: no temporary of any size
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
+@pytest.fixture(scope="module")
+def sala_decode_text(one_chip):
+    """The compiled decode step of MiniCPM-SALA at the sizes of
+    ``sala_longctx_decode`` (``chipbench/configs/minicpm_sala_9b.json``),
+    the cache donated as the scheduler donates it."""
+    import json
+    import os
+
+    from paddle_tpu.models import minicpm_sala as M
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "chipbench/configs/minicpm_sala_9b.json")) as f:
+        cfg = json.load(f)
+    d = M._dims(cfg)
+    S, ps, P = cfg["slots"], cfg["page"], cfg["num_pages"]
+    mp = -(-cfg["max_seq_len"] // ps)
+
+    def sds(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: M.sala_params(cfg, 0)))
+    kv = sds((d["n_sparse"], P, ps, d["Hkv"] * d["Dh"]), jnp.bfloat16)
+    cache = {"k": kv, "v": kv,
+             "kbar": sds((d["n_sparse"], P, ps // d["s"], d["Hkv"] * d["Dh"]),
+                         jnp.float32),
+             "lin": sds((d["n_lin"], S, d["Hl"], d["Dl"], d["Dl"]),
+                        jnp.float32)}
+
+    def decode(cache, params, tokens, positions, tables, lens):
+        return M.sala_decode_step(params, tokens, positions, cache, tables,
+                                  lens, cfg=cfg)
+
+    with _persistent_cache_off(), pytest.MonkeyPatch.context() as patch:
+        patch.setattr(FA, "cpu_backend", lambda: False)
+        return (cfg, jax.jit(decode, donate_argnums=(0,)).lower(
+            cache, params, sds((S,)), sds((S,)), sds((S, mp)), sds((S,))
+        ).compile().as_text())
+
+
+def test_sala_decode_step_gathers_no_table_span(sala_decode_text):
+    """The decode step scores the pooled keys where they lie: two scoring
+    walks and two selected-page walks are its custom calls, and NO
+    instruction has a ``slots * MP`` = 38912 dimension — until PR 50 the
+    gather of every slot's whole table span ``f32[38912, 4, 256]`` (159 MB a
+    layer), its relayout and the scoring fusions were 4.3 ms of an 18.2 ms
+    step.  The pools are operands in place: nothing pool-sized is copied."""
+    cfg, text = sala_decode_text
+    span = cfg["slots"] * -(-cfg["max_seq_len"] // cfg["page"])
+    assert span == 38912
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    assert text.count(FA._SCORES_KERNEL_NAME + ".") >= 2
+    shapes = re.findall(r"= \(?\w+\[([0-9,]*)\]", text)
+    assert len(shapes) > 1000
+    assert not [dims for dims in shapes
+                if str(span) in dims.split(",")]
+    pool = cfg["num_pages"] * (cfg["page"] // 16) * 256     # a layer of kbar
+    copies = [m for m in re.finditer(
+        r"%(\S+) = \w+\[([0-9,]+)\]\S* (copy|slice|dynamic-slice)\(", text)
+        if int(np.prod([int(x) for x in m.group(2).split(",")]))
+        in (pool, 2 * pool)]
+    assert copies == []
+
+
 @pytest.mark.parametrize("chunk", [16, 512], ids=["chunk16", "chunk512"])
 def test_paged_prefill_compiles_for_v5e(one_chip, no_persistent_cache, chunk):
     def f(q, k_pool, v_pool, pages, start):
