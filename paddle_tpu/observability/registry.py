@@ -310,16 +310,28 @@ class _Span:
     set to None (the extent stays in a running profiler trace under the
     name it was opened with).  ``duration`` is set on exit.  ``tags`` None
     (the collector's span alone) closes it into the thread's frame and
-    nothing else: no cell and no sink, so no lock (:class:`_GcWatch`)."""
+    nothing else: no cell and no sink, so no lock (:class:`_GcWatch`).
+    A phase that a thread pays for in two parts (:meth:`hold`) is entered
+    twice and closes once, with the sum of its extents."""
 
     __slots__ = ("name", "tags", "duration", "_telemetry", "_t0", "_ann",
-                 "_frame")
+                 "_frame", "_held", "_hold")
 
     def __init__(self, telemetry, name, tags):
         self._telemetry = telemetry
         self.name = name
         self.tags = tags
         self.duration = None
+        self._held = 0.0        # what the extents before this one took
+        self._hold = False
+
+    def hold(self):
+        """The block under way SUSPENDS the span in place of closing it: its
+        exit ends the profiler's annotation and leaves the frame's depth as
+        it found it, and no cell, frame or sink hears of it.  Entered again,
+        the span goes on, and closes with the sum of its extents (to a span
+        sink it then starts that long before its end)."""
+        self._hold = True
 
     def __enter__(self):
         # TraceMe's own inactive path, hoisted: with no profiler session
@@ -338,11 +350,14 @@ class _Span:
         return self
 
     def __exit__(self, *exc):
-        dur = self.duration = time.perf_counter() - self._t0
+        dur = self.duration = self._held + time.perf_counter() - self._t0
         if self._ann is not None:
             self._ann.__exit__(*exc)
         name = self.name
         frame = self._frame
+        if self._hold:
+            self._hold, self._held = False, dur
+            name = None
         if frame is not None:
             frame.depth -= 1
             if name is not None:

@@ -99,7 +99,6 @@ the next iteration boundary instead of decoding to max_len for nobody.
 from __future__ import annotations
 
 import collections
-import contextlib
 import functools
 import threading
 import time
@@ -134,6 +133,7 @@ _steps = _obs.counter("serving.decode.steps")
 # share of steps the one-step pipeline engaged), and tokens computed for a
 # slot that EOS, a cancel or a deadline had already ended (never served)
 _steps_overlapped = _obs.counter("serving.decode.steps_overlapped")
+_chunks_overlapped = _obs.counter("serving.decode.chunks_overlapped")
 _tokens_discarded = _obs.counter("serving.decode.tokens_discarded")
 _retired = _obs.counter("serving.decode.retired")
 _state_resets = _obs.counter("serving.cache.state_resets")
@@ -179,8 +179,8 @@ STALL_RING = 64
 STALL_CPU_EVERY_S = 0.02
 # cells that per-layer metrics read exist from the import on, so that a
 # reader tells "nothing ran" (0) from "this program has no such span"
-for _cell in ("prefill.behind", "prefill.chunk", "step.build",
-              "step.dispatch", "step.commit"):
+for _cell in ("prefill.chunk", "step.build", "step.dispatch",
+              "step.commit"):
     _obs.histogram("serving.decode." + _cell)
 _prefill_retries = _obs.counter("serving.decode.prefill_retries")
 _prefill_tokens = _obs.counter("serving.decode.prefill_tokens")
@@ -596,7 +596,8 @@ class _Step:
     rolls back to when the readback is lost, None under donation (consumed)
     and once another program has written the cache behind it."""
 
-    __slots__ = ("entries", "args", "out", "pools_before", "sampled")
+    __slots__ = ("entries", "args", "out", "pools_before", "sampled",
+                 "read_at")
 
     def __init__(self, entries, args):
         self.entries = entries
@@ -604,6 +605,29 @@ class _Step:
         self.out = None
         self.pools_before = None
         self.sampled = None            # ``out`` read back, until committed
+        self.read_at = None            # when it was: the device's next start
+
+
+class _Chunk:
+    """One prefill chunk from its dispatch to its commit, inside ONE
+    iteration: the ``_Slot`` it prefills at ``idx`` (the object: the index
+    may be vacated by a failed decode step in between), the window
+    ``[start, start + valid)`` of the prompt in a program ``width`` wide,
+    the ``pages`` it writes; ``out`` its output on the device (the sampled
+    token, then the model's chunk counters); ``pools_before`` the cache
+    pytree it took, None under donation; ``span`` the iteration's
+    ``prefill`` span, held between its two parts; ``wall`` / ``t0`` when it
+    was built (wall clock, ``perf_counter``)."""
+
+    __slots__ = ("idx", "slot", "start", "valid", "width", "pages", "out",
+                 "pools_before", "span", "wall", "t0")
+
+    def __init__(self, idx, slot, start, valid, width, pages, span):
+        self.idx, self.slot = idx, slot
+        self.start, self.valid, self.width = start, valid, width
+        self.pages, self.span = pages, span
+        self.out = self.pools_before = None
+        self.wall, self.t0 = time.time(), time.perf_counter()
 
 
 class _HeldPages:
@@ -1832,15 +1856,17 @@ class DecodeScheduler:
         remaining = slot.prompt_len - slot.prefill_pos
         return -(-remaining // self._chunk_width_for(remaining))
 
-    def _chunk_step(self):
-        """Run ONE prefill chunk, for the prefilling slot with the fewest
-        chunks left (admission order on ties): scatter the next
+    def _send_chunk(self):
+        """Dispatch ONE prefill chunk, for the prefilling slot with the
+        fewest chunks left (admission order on ties): scatter the next
         page-multiple token window's k/v, attend over everything cached so
-        far, and — on the final chunk — sample the first token (flipping
-        the slot to decoding)."""
+        far, and — on the final chunk — sample the first token.  The cache
+        is the chunk's from here (what is dispatched next runs behind its
+        writes); its token is read and the slot moved on in
+        :meth:`_read_chunk`, in the same iteration.  Returns the chunk in
+        flight, None where its dispatch failed for good."""
         import jax.numpy as jnp
 
-        cfg = self.config
         tel = self._telemetry
         self._chunk_rode = True
         with tel.span("serving.decode.chunk.build"):
@@ -1890,73 +1916,93 @@ class DecodeScheduler:
             serve_fault = _resilience._serve_fault
             if serve_fault is not None:
                 serve_fault([req])
-            # ``prefill.chunk`` is the chunk program's own time.  It goes
-            # out BEHIND a decode step still in flight and cannot start
-            # before that step ends: the rest of the step is waited out
-            # first (``prefill.behind``: no transfer, the device's order and
-            # the whole wait are what they were), and the chunk's time runs
-            # from there; with nothing in flight it runs from the dispatch
-            ahead = self._unread[-1].out if self._unread else None
-            own = (contextlib.nullcontext() if ahead is not None
-                   else tel.span("serving.decode.prefill.chunk"))
-            with own:
-                with tel.span("serving.decode.prefill.dispatch"):
-                    tok, pools = fn(
-                        self._params, self._cache.pools,
-                        jnp.asarray(tokens), jnp.int32(start),
-                        jnp.int32(valid), written, gathered, np.int32(idx),
-                        seed, temp)
-                with tel.span("serving.decode.prefill.wait"):
-                    if ahead is None:
-                        read = np.asarray(tok)
-                    else:
-                        with tel.span("serving.decode.prefill.behind"):
-                            ahead.block_until_ready()
-                        with tel.span("serving.decode.prefill.chunk"):
-                            read = np.asarray(tok)
-            return read.reshape(-1), pools
+            with tel.span("serving.decode.prefill.dispatch"):
+                return fn(
+                    self._params, self._cache.pools,
+                    jnp.asarray(tokens), jnp.int32(start),
+                    jnp.int32(valid), written, gathered, np.int32(idx),
+                    seed, temp)
 
+        # what the iteration pays for the chunk: its dispatch (retries
+        # included) here and the block on its token in ``_read_chunk``, one
+        # span in two parts
+        sent = _Chunk(idx, slot, start, valid, width, chunk_vec, tel.span(
+            "serving.decode.prefill", bucket=width, rows=valid, start=start,
+            seq=req.seq))
         try:
-            chunk_wall = time.time()
-            # the chunk program, dispatch to readback (retries included)
-            with tel.span("serving.decode.prefill", bucket=width,
-                          rows=valid, start=start, seq=req.seq) as prefill:
-                read, pools = _resilience.call_with_retry(
+            with sent.span:
+                sent.span.hold()
+                sent.out, pools = _resilience.call_with_retry(
                     attempt, policy=self._prefill_policy,
                     on_retry=self._note_prefill_retry(req))
+                # the donated pytree always belongs to the newest dispatch
+                # (in here: the unread steps let go of the cache they took)
+                sent.pools_before = (None if self._donated
+                                     else self._cache.pools)
+                self._wrote_cache(pools)
+        except Exception as exc:  # noqa: BLE001 — worker must survive
+            self._fail_chunk(sent, exc)
+            return None
+        except BaseException:
+            self._chunk_died(sent)
+            raise
+        return sent
+
+    def _read_chunk(self, sent, since=None):
+        """Read the token of the chunk in flight (ONE blocking transfer: the
+        worker parks in the device's wait) and commit it: the slot moves on
+        and, behind its final chunk, holds its first token.  ``since`` is
+        when the decode step that was ahead of the chunk was read back: where
+        the host waited for that step, the device started the chunk then."""
+        tel = self._telemetry
+        idx, slot, start, valid = sent.idx, sent.slot, sent.start, sent.valid
+        req = slot.req
+        behind = bool(self._unread)
+        try:
+            with sent.span:
+                with tel.span("serving.decode.prefill.wait"):
+                    read = np.asarray(sent.out).reshape(-1)
+                # the chunk program's time where the DEVICE sets the pace
+                # (the host had to wait for the step ahead, so the chunk
+                # started at ``since``; that step's commit lies inside: an
+                # upper bound).  Where the host reads the step ahead late the
+                # chunk is under way by then, and this is what was left of it
+                t0 = sent.t0 if since is None else since
+                tel.observe_span("serving.decode.prefill.chunk",
+                                 sent.wall + (t0 - sent.t0), t0)
                 first = int(read[0])
         except Exception as exc:  # noqa: BLE001 — worker must survive
-            self._retire(idx, error=exc)
-            self._recover_pools(exc)
-            if self._breaker is not None:
-                self._breaker.record_fatal()
+            # every decode step still unread went out behind the chunk and
+            # consumed its cache: they are forgotten (and planned again from
+            # the journals' tokens), and the loop stands on the cache as the
+            # chunk found it (gone under donation: ``_recover_pools``)
+            self._abandon(*self._unread)
+            if sent.pools_before is not None:
+                self._cache.pools = sent.pools_before
+            self._fail_chunk(sent, exc)
             return
         except BaseException:
-            # worker killed mid-chunk.  Solo mode: fail the sequence and
-            # release its reservation before the death propagates —
-            # ServingDegraded (not ServingError): the engine is sick,
-            # the request was fine, same error class as the batcher death.
-            # Pool mode (evict_on_death): leave the slot INTACT — the
-            # chunk's functional writes never landed, so the slot state
-            # is consistent, and the pool harvests it via
-            # evict_inflight and replays it on a sibling
-            if not self._evict_on_death:
-                self._retire(idx, error=ServingDegraded(
-                    "decode worker died mid-prefill; request aborted"))
+            self._chunk_died(sent)
             raise
+        if self._slots[idx] is not slot:
+            # the decode step sent behind the chunk failed and, where it had
+            # consumed the cache, took every sequence with it
+            return
         with tel.span("serving.decode.chunk.commit"):
             done = time.perf_counter()
             if tel.span_active() and req.trace is not None:
                 tel.record_span(
-                    "serving.execute", chunk_wall, prefill.duration,
+                    "serving.execute", sent.wall, sent.span.duration,
                     tags=req.trace.child().tags(
-                        phase="prefill", bucket=width, rows=valid,
+                        phase="prefill", bucket=sent.width, rows=valid,
                         start=start))
-            self._wrote_cache(pools)
+            sent.out = sent.pools_before = None
+            if behind:
+                _chunks_overlapped.inc()
             if self._breaker is not None:
                 self._breaker.record_success()
             if self.config.kv_guard and self._guard_pages(
-                    [idx] * len(chunk_vec), chunk_vec, phase="prefill"):
+                    [idx] * len(sent.pages), sent.pages, phase="prefill"):
                 return
             slot.prefill_pos = start + valid
             slot.kv_len = slot.prefill_pos
@@ -1967,7 +2013,8 @@ class DecodeScheduler:
                 c.inc(int(n))
             _prefills.inc()
             _prefill_tokens.inc(valid)
-            if cfg.prefix_cache and slot.hashes:
+            ps = self._cache.page_size
+            if self.config.prefix_cache and slot.hashes:
                 # publish every full REAL page this chunk completed: its
                 # content is now immutable (decode appends only past the
                 # prompt), so later identical prefixes can map it
@@ -1988,6 +2035,28 @@ class DecodeScheduler:
                 _tokens.inc()
                 if not self._finish_if_done(idx):
                     self._maybe_handoff(idx)
+
+    def _fail_chunk(self, sent, exc):
+        """A chunk failed for good, at its dispatch or at its readback: its
+        sequence alone is failed typed; under donation the failed dispatch
+        consumed the cache, and every sequence goes with it."""
+        if self._slots[sent.idx] is sent.slot:
+            self._retire(sent.idx, error=exc)
+        self._recover_pools(exc)
+        if self._breaker is not None:
+            self._breaker.record_fatal()
+
+    def _chunk_died(self, sent):
+        """The worker is being killed with a chunk not yet committed.  Solo
+        mode: fail the sequence and release its reservation before the death
+        propagates — ServingDegraded (not ServingError): the engine is sick,
+        the request was fine, same error class as the batcher death.  Pool
+        mode (evict_on_death): leave the slot INTACT — nothing of the chunk
+        was committed, so the slot's state is consistent, and the pool
+        harvests it via evict_inflight and replays it on a sibling."""
+        if not self._evict_on_death and self._slots[sent.idx] is sent.slot:
+            self._retire(sent.idx, error=ServingDegraded(
+                "decode worker died mid-prefill; request aborted"))
 
     def _maybe_handoff(self, idx):
         """Roles mode, prefill side: a freshly prefilled (and not yet
@@ -2200,9 +2269,29 @@ class DecodeScheduler:
         # longer one (bounded by the seat cap: each shorter request
         # holds a slot and runs exactly one winning chunk per iteration);
         # admission stays FIFO-per-priority-lane either way.
-        if any(s is not None and s.prefilling for s in self._slots):
-            self._chunk_step()
-        self._decode_step()
+        # The chunk's token is read BEHIND the decode step dispatched after
+        # it: with step n in flight the order is dispatch the chunk, plan
+        # and dispatch step n+1 behind it, read and commit step n, read and
+        # commit the chunk, so the device has step n+1 queued while the host
+        # commits.  (The slot whose final chunk this is still prefills when
+        # n+1 is planned, and joins n+2 with its first token read here.)
+        # With nothing in flight ahead (nobody decodes yet; ``kv_guard``,
+        # whose sweep must see the chunk's pages before another write
+        # lands) the chunk is read before the step is planned: the same two
+        # calls in the other order.
+        sent = (self._send_chunk() if any(
+            s is not None and s.prefilling for s in self._slots) else None)
+        if sent is not None and not self._unread:
+            self._read_chunk(sent)
+            sent = None
+        try:
+            step = self._decode_step()
+        except BaseException:
+            if sent is not None:
+                self._chunk_died(sent)
+            raise
+        if sent is not None:
+            self._read_chunk(sent, since=step and step.read_at)
 
     def _wrote_cache(self, pools):
         """Another program than a decode step (a prefill chunk, a hand-off's
@@ -2310,12 +2399,12 @@ class DecodeScheduler:
         read (the sweep must see the page before another write lands): the
         same code with nothing left in flight.  When no slot decodes next
         (or ``dispatch`` is False), what is in flight is read with nothing
-        behind it."""
+        behind it.  Returns the step it committed, if any."""
         planned = self._planned = self._plan_steps() if dispatch else []
         if not planned and not self._unread:
             self._cache.publish_gauges(
                 sum(s.kv_len for s in self._slots if s is not None))
-            return
+            return None
         try:
             # the dispatch of step n+1 to the readback of step n (retries
             # included): the per-iteration step time, and the cell
@@ -2331,7 +2420,7 @@ class DecodeScheduler:
             # decoding sequences typed, un-retried — replay can't fix a
             # deterministic fault
             self._fail_decoding(exc)
-            return
+            return None
         except BaseException:
             # the worker is being killed: what was planned and never sent
             # must not count as in flight when the loop is resumed
@@ -2340,6 +2429,7 @@ class DecodeScheduler:
             raise
         if done is not None:
             self._commit_step(done)
+        return done
 
     def _send_then_read(self):
         """One attempt of the iteration's decode phase: send what is
@@ -2404,7 +2494,9 @@ class DecodeScheduler:
         """The readback of one dispatched step: its tokens, and behind them
         the model's step counters.  Blocks until the device has run it."""
         with self._telemetry.span("serving.decode.step.wait"):
-            return np.asarray(sent.out)
+            sampled = np.asarray(sent.out)
+        sent.read_at = time.perf_counter()
+        return sampled
 
     def _abandon(self, *steps):
         """Forget steps, planned or dispatched, that will not be committed."""

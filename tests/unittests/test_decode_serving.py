@@ -507,6 +507,255 @@ class TestOneStepInFlight:
 
 # -- step programs are the model's ------------------------------------------
 
+# -- a chunk's token read behind the step dispatched after it (ISSUE 51) -------
+
+class _Lost:
+    """A chunk's output whose readback fails."""
+
+    def __array__(self, *args, **kwargs):
+        from paddle_tpu.testing import faults
+
+        raise faults.FaultInjected("injected lost chunk token")
+
+
+def _tap(sched, events):
+    """Note, in the loop's own order, each chunk sent / read (with the
+    request's ``seq``), each decode step planned (with the ``seq`` of every
+    sequence in it) and each decode step dispatched."""
+    send, read = sched._send_chunk, sched._read_chunk
+    plan, dispatch = sched._plan_step, sched._dispatch_step
+
+    def tapped_send():
+        sent = send()
+        if sent is not None:
+            events.append(("send", sent.slot.req.seq))
+        return sent
+
+    def tapped_read(sent, since=None):
+        read(sent, since)
+        events.append(("read", sent.slot.req.seq, bool(sent.slot.generated)))
+
+    def tapped_plan():
+        step = plan()
+        if step is not None:
+            events.append(("plan", {s.req.seq for _, s in step.entries}))
+        return step
+
+    def tapped_dispatch(step):
+        events.append(("step",))
+        return dispatch(step)
+
+    sched._send_chunk, sched._read_chunk = tapped_send, tapped_read
+    sched._plan_step, sched._dispatch_step = tapped_plan, tapped_dispatch
+
+
+def _beside_a_decoder(sched, prompts, first_new=40, new=4):
+    """One request decodes; the others arrive beside it."""
+    first = sched.submit(prompts[0], max_new_tokens=first_new)
+    while len(first.token_times) < 3:
+        time.sleep(0.002)
+    return [first] + [sched.submit(p, max_new_tokens=new)
+                      for p in prompts[1:]]
+
+
+class TestChunkReadBehindAStep:
+    PROMPTS = [np.arange(1, 10, dtype=np.int32),
+               np.arange(3, 43, dtype=np.int32) % 49 + 1,
+               np.arange(7, 30, dtype=np.int32) % 49 + 1]
+
+    def _cfg(self, **kw):
+        return _cfg(prefill_chunk_tokens=16, **kw)
+
+    @pytest.mark.parametrize("how", ["neighbours", "alone", "kv_guard"])
+    def test_chunks_overlapped_counts_the_chunks_that_rode_a_step(
+            self, decode_model, how):
+        events = []
+        rode0 = obs.counter("serving.decode.chunks_overlapped").value
+        chunks0 = obs.counter("serving.decode.prefills").value
+        sched = serving.DecodeScheduler(
+            decode_model, self._cfg(kv_guard=how == "kv_guard"),
+            autostart=False)
+        _tap(sched, events)
+        sched.start()
+        try:
+            if how == "alone":
+                # each is served before the next arrives: nobody decodes
+                # beside a chunk
+                for p in self.PROMPTS:
+                    sched.generate(p, max_new_tokens=4, timeout=120)
+            else:
+                for f in _beside_a_decoder(sched, self.PROMPTS):
+                    f.result(timeout=120)
+        finally:
+            sched.stop()
+        kinds = [e[0] for e in events]
+        sends = [i for i, k in enumerate(kinds) if k == "send"]
+        # a chunk rode a step when one was dispatched before its token was read
+        rode = sum(kinds[i + 1:kinds.index("read", i)].count("step") > 0
+                   for i in sends)
+        assert (obs.counter("serving.decode.prefills").value - chunks0
+                == len(sends) == 1 + 3 + 2)
+        assert (obs.counter("serving.decode.chunks_overlapped").value - rode0
+                == rode)
+        # all but the first request's own chunk, and none where nobody decodes
+        assert rode == (len(sends) - 1 if how == "neighbours" else 0)
+
+    def test_a_final_chunks_slot_misses_exactly_one_planned_step(
+            self, decode_model):
+        events = []
+        want = (_free_run(decode_model, self.PROMPTS[:1], max_new_tokens=16)
+                + _free_run(decode_model, self.PROMPTS[1:2], max_new_tokens=6))
+        sched = serving.DecodeScheduler(decode_model, self._cfg(),
+                                        autostart=False)
+        _tap(sched, events)
+        sched.start()
+        try:
+            futs = _beside_a_decoder(sched, self.PROMPTS[:2], first_new=16,
+                                     new=6)
+            got = [f.result(timeout=120) for f in futs]
+        finally:
+            sched.stop()
+        for g, w in zip(got, want):
+            assert g.tobytes() == w.tobytes()
+        late = futs[1].seq
+        final = events.index(("read", late, True))
+        sent = max(i for i, e in enumerate(events[:final])
+                   if e == ("send", late))
+        # the one step planned while the final chunk was in flight has no
+        # seat for the sequence; the next one planned has
+        behind = [e[1] for e in events[sent:final] if e[0] == "plan"]
+        after = [e[1] for e in events[final:] if e[0] == "plan"]
+        assert len(behind) == 1 and late not in behind[0]
+        assert late in after[0]
+
+    @pytest.mark.parametrize("pools", ["kept", "donated"])
+    def test_a_chunk_read_that_fails_with_a_step_behind_it(self, decode_model,
+                                                           pools):
+        from paddle_tpu.testing import faults
+
+        want = _free_run(decode_model, self.PROMPTS, max_new_tokens=12)
+        sched = serving.DecodeScheduler(decode_model, self._cfg(),
+                                        autostart=False)
+        sched._donated = pools == "donated"      # the host's side of donation
+        send, read = sched._send_chunk, sched._read_chunk
+        fired, left = [], []
+
+        def lossy_send():
+            sent = send()
+            if (sent is not None and sched._unread and not fired
+                    and sent.slot.prompt_len == len(self.PROMPTS[1])):
+                fired.append(sent.slot.req.seq)
+                sent.out = _Lost()
+            return sent
+
+        def noting_read(sent, since=None):
+            lost = isinstance(sent.out, _Lost)
+            behind = len(sched._unread)
+            read(sent, since)
+            if lost:
+                left.append((behind, len(sched._unread), [
+                    s.inflight for s in sched._slots if s is not None],
+                    bool(np.asarray(sched._cache.k_pool).any())))
+
+        sched._send_chunk, sched._read_chunk = lossy_send, noting_read
+        sched.start()
+        try:
+            futs = _beside_a_decoder(sched, self.PROMPTS, first_new=12,
+                                     new=12)
+            with pytest.raises(faults.FaultInjected, match="lost chunk"):
+                futs[1].result(timeout=120)
+            assert fired == [futs[1].seq]
+            deadline = time.time() + 30   # the future fails inside the read
+            while not left and time.time() < deadline:
+                time.sleep(0.002)
+            # a step had gone out behind the chunk: it is abandoned, and no
+            # slot counts anything in flight
+            (behind, unread, inflight, written), = left
+            assert behind == 1 and unread == 0 and not any(inflight)
+            if pools == "kept":
+                # that sequence alone: the others stand on the cache as the
+                # chunk found it and finish an undisturbed run's tokens
+                assert len(inflight) >= 1 and written
+                for f, w in zip(futs[::2], want[::2]):
+                    assert f.result(timeout=120).tobytes() == w.tobytes()
+            else:
+                # as on the chip, the chunk had consumed the pools: every
+                # seated sequence fails typed and the pools come back zeroed
+                assert inflight == [] and not written
+                with pytest.raises(faults.FaultInjected):
+                    futs[0].result(timeout=120)
+                try:        # seated by then, or served from the new pools
+                    last = futs[2].result(timeout=120)
+                except faults.FaultInjected:
+                    pass
+                else:
+                    assert last.tobytes() == want[2].tobytes()
+            got = sched.generate(self.PROMPTS[1], max_new_tokens=12,
+                                 timeout=120)
+            assert sched.stats()["kv_pages_used"] == 0
+        finally:
+            sched.stop()
+        assert got.tobytes() == want[1].tobytes()
+
+    @pytest.mark.parametrize("mode", ["solo", "pool"])
+    def test_a_worker_killed_between_the_two_dispatches(self, decode_model,
+                                                        mode):
+        from paddle_tpu.testing import faults
+
+        want = _free_run(decode_model, self.PROMPTS[:1], max_new_tokens=12)
+        sched = serving.DecodeScheduler(
+            decode_model, self._cfg(), autostart=False,
+            evict_on_death=mode == "pool")
+        send, dispatch = sched._send_chunk, sched._dispatch_step
+        flying, killed = [], []
+
+        def noting_send():
+            sent = send()
+            flying[:] = [sent] if sent is not None and sched._unread else []
+            return sent
+
+        def deadly_dispatch(step):
+            if flying and not killed:
+                killed.append((flying[0], flying[0].slot.prefill_pos))
+                raise faults.WorkerKilled("injected kill behind a chunk")
+            return dispatch(step)
+
+        sched._send_chunk, sched._dispatch_step = noting_send, deadly_dispatch
+        sched.start()
+        try:
+            futs = _beside_a_decoder(sched, self.PROMPTS[:2], first_new=12)
+            deadline = time.time() + 30
+            while sched.alive and time.time() < deadline:
+                time.sleep(0.005)
+            assert not sched.alive and len(killed) == 1
+            (sent, pos), = killed
+            # what was planned behind the chunk is forgotten: a slot counts
+            # in flight what is dispatched and unread, and nothing else
+            assert sched._planned == []
+            for slot in filter(None, sched._slots):
+                assert slot.inflight == sum(
+                    slot in [s for _, s in step.entries]
+                    for step in sched._unread)
+            if mode == "solo":
+                with pytest.raises(serving.ServingDegraded,
+                                   match="mid-prefill"):
+                    futs[1].result(timeout=120)
+                assert sched.restart()
+                assert futs[0].result(timeout=120).tobytes() \
+                    == want[0].tobytes()
+                assert sched.stats()["kv_pages_used"] == 0
+            else:
+                # the slot is left as the chunk found it, for the pool
+                assert sched._slots[sent.idx] is sent.slot
+                assert sent.slot.prefilling and sent.slot.prefill_pos == pos
+                harvested = sched.evict_inflight()
+                assert {r.seq for r in harvested} == {f.seq for f in futs}
+                assert not sched._unread and not any(sched._slots)
+                assert sched.stats()["kv_pages_used"] == 0
+        finally:
+            sched.stop()
+
+
 def _counting_model(base, calls, step_counters=()):
     """``base``'s step functions behind wrappers that count their PYTHON
     calls (one a trace) in ``calls``; with ``step_counters`` both programs

@@ -97,6 +97,43 @@ def test_a_frame_belongs_to_one_thread():
         obs.close_frame()
 
 
+def test_a_held_span_closes_once_with_the_sum_of_its_two_extents():
+    """A phase paid in two parts (the chunk's ``prefill``): the first exit
+    reaches no cell, frame or sink and leaves the depth as it found it; the
+    second closes the span at its depth with both extents, the work between
+    them apart."""
+    tel = obs.Telemetry(enabled=True)
+    ring = obs.RingBufferSink(capacity=16, record_spans=True)
+    tel.add_sink(ring)
+    frame = obs.open_frame()
+    try:
+        with tel.span("turn"):
+            part = tel.span("a", k=1)
+            with part:
+                part.hold()
+                with tel.span("a.dispatch"):
+                    time.sleep(0.002)
+            first = part.duration
+            assert frame.depth == 1 and "a" not in frame.phases
+            assert tel.histogram("a").snapshot().count == 0
+            with tel.span("b"):
+                time.sleep(0.01)             # not the held span's time
+            with part:
+                with tel.span("a.wait"):
+                    time.sleep(0.002)
+        assert {n: (e[1], e[2]) for n, e in frame.phases.items()} == {
+            "turn": (1, 0), "a.dispatch": (1, 2), "b": (1, 1),
+            "a.wait": (1, 2), "a": (1, 1)}
+        whole = frame.phases["a"][0]
+        assert whole == part.duration == tel.histogram("a").snapshot().sum
+        assert first >= 0.002 and first + 0.002 <= whole < first + 0.01
+        assert frame.children_s == whole + frame.phases["b"][0]
+        assert [s["name"] for s in ring.spans] == [
+            "a.dispatch", "b", "a.wait", "a", "turn"]
+    finally:
+        obs.close_frame()
+
+
 class _Tap:
     """Stands in for a histogram cell: keeps each observation, and passes it
     on."""
@@ -592,8 +629,12 @@ def test_a_started_scheduler_and_a_trainer_watch_the_collector(decode_model):
 # -- the chunk's own time -------------------------------------------------------------
 
 def _chunk_spans(decode_model, **cfg):
+    """A request decodes; three more arrive beside it.  The names and
+    durations of the chunk's and the step's spans as they CLOSE, the
+    requests' tokens, and how many chunks were read behind a step."""
     ring = obs.RingBufferSink(capacity=1 << 16, record_spans=True)
     obs.add_sink(ring)
+    rode0 = obs.counter(D + "chunks_overlapped").value
     try:
         sched = serving.DecodeScheduler(decode_model, _cfg(**cfg))
         first = sched.submit(_prompt(9), max_new_tokens=60)
@@ -605,41 +646,57 @@ def _chunk_spans(decode_model, **cfg):
         sched.stop()
     finally:
         obs.remove_sink(ring)
-    names = [s["name"][len(D):] for s in ring.spans
-             if s["name"].startswith(D + "prefill")]
-    durs = [s["dur"] for s in ring.spans if s["name"].startswith(D + "prefill")]
-    return names, durs, outs
+    spans = [s for s in ring.spans
+             if s["name"].startswith((D + "prefill", D + "step"))]
+    names = [s["name"][len(D):] for s in spans]
+    durs = [s["dur"] for s in spans]
+    return names, durs, outs, obs.counter(D + "chunks_overlapped").value - rode0
 
 
 def test_behind_and_chunk_split_the_wait_when_a_step_is_in_flight(
         decode_model):
-    names, durs, _ = _chunk_spans(decode_model)
-    split = [i for i, n in enumerate(names) if n == "prefill.behind"]
-    assert len(split) >= 10          # the later prompts' chunks ride a decode
-    for i in split:
-        # as they close: dispatch, behind, chunk, wait, prefill
-        assert names[i - 1:i + 4] == [
-            "prefill.dispatch", "prefill.behind", "prefill.chunk",
-            "prefill.wait", "prefill"]
-        behind, chunk, wait, whole = durs[i:i + 4]
-        assert behind + chunk <= wait <= whole
-        # nothing but two span edges lies between them
-        assert wait - (behind + chunk) < 0.02
+    """With a step in flight the chunk's token is read BEHIND the step that
+    is dispatched after it: ``prefill`` is paid in two parts, around the
+    decode step's own spans."""
+    names, durs, _, rode = _chunk_spans(decode_model)
     # the first request's own chunk found nothing in flight
     assert names[:4] == ["prefill.dispatch", "prefill.wait", "prefill.chunk",
                          "prefill"]
     assert durs[2] >= durs[0] + durs[1]
+    sent = [i for i, n in enumerate(names) if n == "prefill.dispatch"][1:]
+    assert len(sent) >= 10           # the later prompts' chunks ride a decode
+    assert rode == len(sent)
+    for i in sent:
+        # as they close: the chunk goes out, step n+1 goes out behind it,
+        # step n is read and committed, and then the chunk's token is read
+        assert names[i:i + 9] == [
+            "prefill.dispatch", "step.build", "step.dispatch", "step.wait",
+            "step", "step.commit", "prefill.wait", "prefill.chunk",
+            "prefill"]
+        dispatch, commit, wait, chunk, whole = (
+            durs[i], durs[i + 5], durs[i + 6], durs[i + 7], durs[i + 8])
+        # what the iteration pays for the chunk: nothing but span edges lies
+        # between the two parts and their sum
+        assert dispatch + wait <= whole < dispatch + wait + 0.02
+        # the chunk's own time runs from step n's readback: an upper bound
+        # with the step's commit inside it
+        assert chunk >= commit + wait
 
 
 def test_with_no_step_in_flight_behind_opens_no_span(decode_model):
-    """``kv_guard`` reads every step in the turn that sent it."""
-    behind0 = _cell(D + "prefill.behind").count
-    names, durs, outs = _chunk_spans(decode_model, kv_guard=True)
-    assert "prefill.behind" not in names
-    assert _cell(D + "prefill.behind").count == behind0
-    assert names.count("prefill.chunk") == names.count("prefill.wait") > 10
+    """``kv_guard`` reads every step in the turn that sent it: the chunk is
+    read before the step is sent, and no chunk rides a step."""
+    names, durs, outs, rode = _chunk_spans(decode_model, kv_guard=True)
+    assert rode == 0
+    sent = [i for i, n in enumerate(names) if n == "prefill.dispatch"]
+    assert len(sent) > 10
+    for i in sent:
+        assert names[i:i + 4] == ["prefill.dispatch", "prefill.wait",
+                                  "prefill.chunk", "prefill"]
+    assert names.count("prefill.chunk") == names.count("prefill.wait")
+    assert D + "prefill.behind" not in obs.get_telemetry().histograms()
     # ... and the tokens are the pipelined loop's
-    _, _, piped = _chunk_spans(decode_model)
+    _, _, piped, _ = _chunk_spans(decode_model)
     assert all(np.array_equal(a, b) for a, b in zip(outs, piped))
 
 

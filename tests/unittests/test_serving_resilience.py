@@ -895,9 +895,9 @@ class TestFaultWithAStepInFlight:
     def test_a_readback_lost_past_a_chunks_write_is_not_retried(self):
         """A prefill chunk wrote the cache behind the unread step: there is
         nothing to roll back to, so the lost step is not retried.  The
-        decoding sequences (the one whose chunk it was has its first token
-        by then) fail typed, and the next request is served a clean run's
-        tokens from the same pools."""
+        decoding sequences fail typed; the one whose chunk it was still
+        prefills (its token is read behind that step), so it and the next
+        request are served a clean run's tokens from the same pools."""
         want = self._clean()
         sched = _decode_scheduler()
         fired = [0]
@@ -917,9 +917,11 @@ class TestFaultWithAStepInFlight:
             while len(first.token_times) < 3:
                 time.sleep(0.002)
             second = sched.submit(self.PROMPT, **self.KW)
-            for f in (first, second):
-                with pytest.raises(serving.ServingDegraded, match="moved on"):
-                    f.result(timeout=120)
+            with pytest.raises(serving.ServingDegraded, match="moved on"):
+                first.result(timeout=120)
+            assert second.result(timeout=120).tobytes() == want.tobytes()
+            assert not any(s is not None and s.inflight
+                           for s in sched._slots)
             got = sched.generate(self.PROMPT, timeout=120, **self.KW)
             assert sched.stats()["kv_pages_used"] == 0
         finally:
