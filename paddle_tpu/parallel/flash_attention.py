@@ -1168,7 +1168,7 @@ def _parts_dot(a_rows, n, b, dims):
 
 def _walk_pages(kvl, counts, *, page_size, pages, rows, width, copies,
                 tiles, scores, limit=None, first=None, pv=None, carry=None,
-                finish=True):
+                finish=True, keep=None):
     """The walk of ONE slot's own pages that the paged decode kernels share:
     ``pages`` pages a turn are copied whole into tile ``slot`` of two (the
     next turn's copies in flight while this turn computes), and a turn is one
@@ -1197,6 +1197,12 @@ def _walk_pages(kvl, counts, *, page_size, pages, rows, width, copies,
         place of the normalised result, and the next list's walk takes it as
         ``carry`` (lists of another page size, pool or mask; an empty list
         passes it on unchanged).
+    keep: None, or ``keep(t) -> [rows, turn]`` bool: the keys of turn ``t``
+        each row attends to at all (a learned selection given as a mask);
+        the others' scores are replaced as those past a row's keys are.  A
+        row whose first turns keep nothing carries weights of masked keys
+        until its first kept key's turn scales them to exact zero
+        (``exp(NEG_INF - m)``): every row must keep at least one key it sees.
     A masked turn replaces the scores past a row's keys whatever they are
     and zeroes the value rows past ``kvl`` (they hold what an earlier turn
     or nobody left: 0 * garbage must stay finite).  Returns the normalised
@@ -1241,6 +1247,8 @@ def _walk_pages(kvl, counts, *, page_size, pages, rows, width, copies,
             s = jnp.where((col < seen) & (col >= first[1] - key0), s, NEG_INF)
             v = jnp.where(jax.lax.broadcasted_iota(
                 jnp.int32, (turn, 1), 0) < left, v, jnp.zeros_like(v))
+        if keep is not None:
+            s = jnp.where(keep(t), s, NEG_INF)
         m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
         p = jnp.exp(s - _lanes(m_new, turn))
         alpha = jnp.exp(m_prev - m_new)
@@ -1405,7 +1413,11 @@ def _paged_pallas(q, k_pool, v_pool, page_tables, kv_lens, sm_scale, interpret,
 # and V a lane slice of the K tile.  A grid step may carry ``q_tokens``
 # consecutive tokens of one sequence (``q_tokens * H`` rows; token ``j`` sees
 # ``kv_len - (q_tokens - 1 - j)`` keys): that is a prefill chunk, whose rows
-# are slots of one page table, ``q_tokens`` to a step.
+# are slots of one page table, ``q_tokens`` to a step.  The walk runs from
+# row 0 to ``kv_lens`` over every page between; a model that SELECTS rows a
+# query (DeepSeek sparse attention, the section after this one) gives a chunk
+# its selection as a mask over the same walk (``keep``) and a decode step a
+# gathered row list, which the same kernel reads as pages of its own.
 # ---------------------------------------------------------------------------
 
 _MLA_PREFILL_TOKENS = 8     # chunk rows a grid step of the prefill form
@@ -1421,7 +1433,7 @@ def _mla_limits(kv_lens, n_rows, n_head, q_tokens):
 
 
 def _paged_mla_reference(q, pool, page_tables, kv_lens, v_width, n_head,
-                         q_tokens, sm_scale, layer):
+                         q_tokens, sm_scale, layer, keep=None):
     import jax.numpy as jnp
 
     S, R, W = q.shape
@@ -1434,6 +1446,8 @@ def _paged_mla_reference(q, pool, page_tables, kv_lens, v_width, n_head,
     s = jnp.einsum("srw,%s->srk" % keys, q.astype(jnp.float32), lat) * sm_scale
     ok = (jnp.arange(mp * ps)[None, None, :]
           < _mla_limits(kv_lens, R, n_head, q_tokens)[:, :, None])
+    if keep is not None:                   # [S, q_tokens, keys]: a selection
+        ok = ok & jnp.repeat(keep != 0, n_head, axis=1)[:, :R]
     p = jax.nn.softmax(jnp.where(ok, s, NEG_INF), axis=-1)
     p = jnp.where(ok, p, 0.0)              # a row with no key -> zeros
     return jnp.einsum("srk,%s->srv" % keys.replace("w", "v"), p,
@@ -1453,19 +1467,26 @@ def _mla_turn_pages(ps, lanes, mp, itemsize, rows):
     return pages
 
 
-def _paged_mla_kernel(pt_ref, lens_ref, q_ref, lat_hbm, o_ref, buf, sem, *,
-                      layer, page_size, pages, num_pages_per_seq, n_head,
-                      q_tokens, v_width, sm_scale):
+def _paged_mla_kernel(pt_ref, lens_ref, q_ref, *refs, layer, page_size, pages,
+                      num_pages_per_seq, n_head, q_tokens, v_width, sm_scale,
+                      selected):
     """One grid step = one slot's ``q_tokens * H`` query rows against the
     slot's own ``ceil(kv_len / ps)`` latent pages, ``pages`` a turn
     (``_walk_pages``).  A turn: scores ``[rows, turn]``
     = the rows against the tile over all ``W`` lanes, an online-softmax
     update, ``p . tile[:, :v_width]``.  Products run on the MXU over exact
-    bf16 parts of an operand that is not bf16 (``_bf16_parts``)."""
+    bf16 parts of an operand that is not bf16 (``_bf16_parts``).  With
+    ``selected`` a further input ``keep [q_tokens, keys]`` (nonzero = the
+    token attends to the key) masks every turn: a token's row of it is
+    spread over the token's ``H`` rows by a one-hot product."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    if selected:
+        keep_ref, lat_hbm, o_ref, buf, sem = refs
+    else:
+        lat_hbm, o_ref, buf, sem = refs
     s_idx = pl.program_id(0)
     ps, turn = page_size, pages * page_size
     hp = q_ref.shape[0]
@@ -1492,15 +1513,32 @@ def _paged_mla_kernel(pt_ref, lens_ref, q_ref, lat_hbm, o_ref, buf, sem, *,
         tile = buf[slot]                                     # [turn, W]
         return tile, tile[:, :v_width]
 
+    keep = None
+    if selected:
+        # row r is token ``tok[r]``: [hp, q_tokens] one-hot, exact in bf16
+        spread = (tok == jax.lax.broadcasted_iota(
+            jnp.int32, (hp, q_tokens), 1)).astype(jnp.bfloat16)
+
+        def keep(t):
+            mine = keep_ref[:, pl.ds(pl.multiple_of(t * turn, turn), turn)]
+            return jax.lax.dot_general(
+                spread, (mine != 0).astype(jnp.bfloat16),
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32) > 0.5
+
     o_ref[...] = _walk_pages(
         kvl, (n_pages, n_turns, n_whole), page_size=ps, pages=pages, rows=hp,
-        width=v_width, copies=copies, tiles=tiles, limit=limit,
+        width=v_width, copies=copies, tiles=tiles, limit=limit, keep=keep,
         scores=lambda k: _parts_dot(q_rows, nq, k, ((1,), (1,))) * sm_scale
     ).astype(o_ref.dtype)
 
 
+_MLA_KERNEL_NAME = "paged_mla_attention"
+
+
 def _paged_mla_pallas(q, pool, page_tables, kv_lens, v_width, n_head,
-                      q_tokens, sm_scale, interpret, layer):
+                      q_tokens, sm_scale, interpret, layer, keep=None,
+                      name=_MLA_KERNEL_NAME):
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -1515,14 +1553,24 @@ def _paged_mla_pallas(q, pool, page_tables, kv_lens, v_width, n_head,
     kernel = functools.partial(
         _paged_mla_kernel, layer=layer, page_size=ps, pages=pages,
         num_pages_per_seq=mp, n_head=n_head, q_tokens=q_tokens,
-        v_width=v_width, sm_scale=sm_scale)
+        v_width=v_width, sm_scale=sm_scale, selected=keep is not None)
+    in_specs = [pl.BlockSpec((None, hp, W), lambda s, pt, kl: (s, 0, 0))]
+    operands = [q]
+    if keep is not None:
+        # [S, q_tokens, keys] float32, the keys in whole turns
+        turn = pages * ps
+        keys = -(-mp * ps // turn) * turn
+        keep = jnp.pad(keep.astype(jnp.float32),
+                       ((0, 0), (0, 0), (0, keys - keep.shape[2])))
+        in_specs.append(pl.BlockSpec((None, q_tokens, keys),
+                                     lambda s, pt, kl: (s, 0, 0)))
+        operands.append(keep)
     (out,) = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(S,),
-            in_specs=[pl.BlockSpec((None, hp, W), lambda s, pt, kl: (s, 0, 0)),
-                      pl.BlockSpec(memory_space=pl.ANY)],
+            in_specs=in_specs + [pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=[pl.BlockSpec((None, hp, v_width),
                                     lambda s, pt, kl: (s, 0, 0))],
             scratch_shapes=[
@@ -1533,9 +1581,9 @@ def _paged_mla_pallas(q, pool, page_tables, kv_lens, v_width, n_head,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
-        name="paged_mla_attention",
+        name=name,
     )(page_tables.astype(jnp.int32).reshape(S * mp),
-      kv_lens.astype(jnp.int32), q, pool)
+      kv_lens.astype(jnp.int32), *operands, pool)
     return out[:, :R]
 
 
@@ -1573,15 +1621,17 @@ def paged_mla_decode_attention(q, latent_pool, page_tables, kv_lens, *,
 
 
 def paged_mla_prefill_attention(q, latent_pool, pages, start, valid, *,
-                                v_width, sm_scale, layer, impl=None,
-                                interpret=None):
+                                v_width, sm_scale, layer, keep=None,
+                                impl=None, interpret=None):
     """Absorbed MLA attention of one prefill chunk: ``q [C, H, W]`` at
     absolute positions ``start ..`` against the sequence's ``pages [MP]``
     (the chunk's own rows already scattered in), causal by position; rows at
-    or past ``valid`` are padding (garbage out).  Returns ``[C, H, v_width]``
-    float32.  The reference reduces every row over the full page-table span
-    (chunked == one bucket, bitwise); the kernel is the decode walk with
-    ``_MLA_PREFILL_TOKENS`` rows of the chunk a grid step."""
+    or past ``valid`` are padding (garbage out).  ``keep [C, MP * ps]``
+    (nonzero = attended), where given, is a selection a query: a key is read
+    iff it is visible AND kept (:func:`dsa_keep`).  Returns ``[C, H,
+    v_width]`` float32.  The reference reduces every row over the full
+    page-table span (chunked == one bucket, bitwise); the kernel is the
+    decode walk with ``_MLA_PREFILL_TOKENS`` rows of the chunk a grid step."""
     import jax.numpy as jnp
 
     C, H, W = q.shape
@@ -1589,16 +1639,362 @@ def paged_mla_prefill_attention(q, latent_pool, pages, start, valid, *,
     if impl == "reference":
         lens = jnp.where(jnp.arange(C) < valid,
                          start + jnp.arange(C, dtype=jnp.int32) + 1, 0)
-        return _paged_mla_reference(q, latent_pool, pages, lens, v_width, H,
-                                    1, sm_scale, layer)
+        return _paged_mla_reference(
+            q, latent_pool, pages, lens, v_width, H, 1, sm_scale, layer,
+            keep=None if keep is None else keep[:, None, :])
     nt = math.gcd(C, _MLA_PREFILL_TOKENS)
     first = jnp.arange(C // nt, dtype=jnp.int32) * nt
     lens = jnp.where(first < valid, start + first + nt, 0)
     out = _paged_mla_pallas(
         q.reshape(C // nt, nt * H, W), latent_pool,
         jnp.broadcast_to(pages[None, :], (C // nt, pages.shape[0])), lens,
-        v_width, H, nt, sm_scale, interpret, layer)
+        v_width, H, nt, sm_scale, interpret, layer,
+        keep=None if keep is None else keep.reshape(C // nt, nt, -1))
     return out.reshape(C, H, v_width)
+
+
+# ---------------------------------------------------------------------------
+# DeepSeek SPARSE attention over latent rows (DeepSeek-V3.2-Exp's lightning
+# indexer, as ``glm_moe_dsa`` carries it): beside the latent row a token keeps
+# ONE indexer key ``k^I`` (``Di`` lanes, a page leaf of its own, ``[L, P, ps,
+# Di]``), a query scores every visible token ``I_s = scale * sum_j w_j *
+# relu(q^I_j . k^I_s)`` over its ``Hi`` indexer heads, the ``k`` best are its
+# SET (ties: the lower position), and latent attention reads those rows only.
+# Three stages:
+#
+# * ``paged_index_scores``: the walk of ``paged_block_scores`` (one grid step a
+#   slot, the slot's live pages copied whole, many to a turn on one semaphore
+#   and ONE wait for the tile's bytes, the next turn's copies in flight), a
+#   turn one ``[rows, Di] x [Di, turn]`` product, ReLU, the head weights and
+#   the sum over a token's heads; a grid step may carry ``q_tokens`` tokens of
+#   one sequence (a prefill chunk, as the latent kernel does).  Scores past a
+#   token's visible keys are ``NEG_INF`` whatever the rows hold.
+# * ``dsa_threshold`` / ``dsa_keep`` / ``dsa_rows``: the selection is exact and
+#   is no sort.  A float32 score's bit pattern, sign-folded, orders as the
+#   score does; the ``k``-th largest is found bit by bit from the top (32
+#   counting passes), the ties at it are filled by position with a second
+#   search over the position's bits, and the set is a MASK (a chunk: the
+#   latent walk takes it as ``keep``) or, compacted by prefix counts in two
+#   levels, a ROW LIST (a decode step).
+# * ``paged_mla_rows_attention``: the listed rows are resolved through the
+#   page table, gathered ONCE for all heads into ``[S, k, W]`` and read by the
+#   latent walk above as a slot's own ``k / ps`` pages.
+# ---------------------------------------------------------------------------
+
+_INDEX_KERNEL_NAME = "paged_index_scores"
+_ROWS_KERNEL_NAME = "paged_mla_rows_attention"
+_INDEX_TURN_KEYS = 8192      # at most; fewer where a step has many rows
+_INDEX_DOTS_BYTES = 2 * 1024 * 1024
+
+
+def _index_turn_pages(mp, ps, rows):
+    """Pages a turn of the indexer's walk, from the shapes alone: as many as
+    keep one turn's ``[rows, turn]`` float32 products inside
+    ``_INDEX_DOTS_BYTES``, at most ``_INDEX_TURN_KEYS`` keys or the table, in
+    whole 128-lane tiles of keys where a page allows it."""
+    keys = min(_INDEX_TURN_KEYS, max(128, _INDEX_DOTS_BYTES // (4 * rows)))
+    return max(1, min(mp, keys // ps))
+
+
+def _index_scores_reference(q, w, keys, limits, scale):
+    """``q [N, R, Di]``, ``w [N, R]``, ``keys [N | 1, K, Di]``, ``limits [N,
+    T]`` visible keys of each of the ``T`` tokens (``R / T`` rows a token) ->
+    ``[N, T, K]`` float32."""
+    import jax.numpy as jnp
+
+    N, R, _ = q.shape
+    T = limits.shape[1]
+    dots = jnp.einsum("nrd,nkd->nrk" if keys.shape[0] == N else "nrd,okd->nrk",
+                      q.astype(jnp.float32), keys.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)
+    s = (jax.nn.relu(dots) * w.astype(jnp.float32)[..., None]).reshape(
+        N, T, R // T, -1).sum(axis=2) * scale
+    seen = jnp.arange(s.shape[-1])[None, None, :] < limits[..., None]
+    return jnp.where(seen, s, NEG_INF)
+
+
+def _index_scores_kernel(pt_ref, lens_ref, q_ref, w_ref, k_hbm, o_ref, buf,
+                         sem, *, layer, pages, q_tokens, scale):
+    """One grid step = one slot's ``q_tokens`` tokens (``rows / q_tokens``
+    indexer heads each) against the slot's live pages of indexer keys
+    (``buf [2, pages, ps, Di]``, a page an entry; entry ``i`` of turn ``t``
+    is the slot's page ``t * pages + i``, or its last live page again where
+    the turn runs past them: no page past ``kv_len`` is read and no trip
+    count depends on the slot).  ``o_ref [q_tokens, width]``: every lane
+    past a token's visible keys is ``NEG_INF``."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    s_idx = pl.program_id(0)
+    ps, lanes = buf.shape[2:]
+    rows = q_ref.shape[0]
+    heads = rows // q_tokens
+    turn = pages * ps
+    kvl = lens_ref[s_idx]
+    div = jax.lax.div
+    n_pages = div(kvl + (ps - 1), ps)
+    n_turns = div(n_pages + (pages - 1), pages)
+    exact = (jax.lax.Precision.HIGHEST if buf.dtype == jnp.float32
+             else jax.lax.Precision.DEFAULT)
+
+    def start(t, slot):
+        for i in range(pages):
+            page = pt_ref[s_idx, jnp.minimum(t * pages + i, n_pages - 1)]
+            pltpu.make_async_copy(k_hbm.at[layer, page], buf.at[slot, i],
+                                  sem.at[slot]).start()
+
+    o_ref[...] = jnp.full(o_ref.shape, NEG_INF, jnp.float32)
+
+    @pl.when(n_turns > 0)
+    def _first():
+        start(0, 0)
+
+    q = q_ref[...].astype(buf.dtype)
+    w = w_ref[...]                                           # [rows, 1]
+    tok = jax.lax.broadcasted_iota(jnp.int32, (q_tokens, turn), 0)
+    limit = kvl - (q_tokens - 1 - tok)
+
+    def one_turn(t, _):
+        slot = jax.lax.rem(t, 2)
+
+        @pl.when(t + 1 < n_turns)
+        def _next():
+            start(t + 1, 1 - slot)
+
+        # the tile's bytes = the turn's copies: one wait for all of them
+        pltpu.make_async_copy(buf.at[slot], buf.at[slot], sem.at[slot]).wait()
+        x = buf[slot].reshape(turn, lanes)
+        dots = jax.lax.dot_general(q, x, (((1,), (1,)), ((), ())),
+                                   precision=exact,
+                                   preferred_element_type=jnp.float32)
+        s = jnp.maximum(dots, 0.0) * w                       # [rows, turn]
+        if q_tokens == 1:
+            s = s.sum(axis=0, keepdims=True)
+        else:
+            s = s.reshape(q_tokens, heads, turn).sum(axis=1)
+        pos = t * turn + jax.lax.broadcasted_iota(
+            jnp.int32, (q_tokens, turn), 1)
+        o_ref[:, pl.ds(pl.multiple_of(t * turn, turn), turn)] = jnp.where(
+            pos < limit, s * scale, NEG_INF)
+        return _
+
+    jax.lax.fori_loop(0, n_turns, one_turn, None)
+
+
+def _paged_index_scores_pallas(q, w, pool, page_tables, kv_lens, q_tokens,
+                               scale, interpret, layer):
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from .. import observability as obs
+
+    S, R, Di = q.shape
+    ps = pool.shape[2]
+    mp = page_tables.shape[1]
+    pages = _index_turn_pages(mp, ps, R)
+    width = -(-mp // pages) * pages * ps
+    steps = obs.counter("paged.index.grid_steps", labels={
+        "S": S, "rows": R, "mp": mp, "ps": ps, "turn": pages * ps})
+    if not steps.value:
+        steps.inc(S)
+    kernel = functools.partial(_index_scores_kernel, layer=layer, pages=pages,
+                               q_tokens=q_tokens, scale=scale)
+    (out,) = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(S,),
+            in_specs=[pl.BlockSpec((None, R, Di), lambda s, pt, kl: (s, 0, 0)),
+                      pl.BlockSpec((None, R, 1), lambda s, pt, kl: (s, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[pl.BlockSpec((None, q_tokens, width),
+                                    lambda s, pt, kl: (s, 0, 0))],
+            scratch_shapes=[
+                pltpu.VMEM((2, pages, ps, Di), pool.dtype),     # key tiles
+                pltpu.SemaphoreType.DMA((2,)),
+            ]),
+        out_shape=[jax.ShapeDtypeStruct((S, q_tokens, width), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+        name=_INDEX_KERNEL_NAME,
+    )(page_tables.astype(jnp.int32), kv_lens.astype(jnp.int32), q,
+      w.astype(jnp.float32)[..., None], pool)
+    return out[:, :, :mp * ps]
+
+
+def paged_index_scores(q, w, index_pool, page_tables, kv_lens, *, layer,
+                       scale, impl=None, interpret=None):
+    """The lightning indexer's scores of one decode token a slot.
+
+    q: ``[S, Hi, Di]`` the token's indexer queries (rotated); w: ``[S, Hi]``
+        its head weights; index_pool: the stored stack ``[L, num_pages,
+        page_size, Di]`` of indexer keys, addressed in place by ``(layer,
+        page)``; page_tables / kv_lens as :func:`paged_decode_attention`.
+    Returns ``[S, MP * ps]`` float32: ``scale * sum_j w_j relu(q_j . k_s)``
+    at the slot's position ``s``, ``NEG_INF`` at and past ``kv_lens``
+    whatever those rows hold (a reseated slot's last occupant, NaN)."""
+    impl, interpret = _mla_impl(impl, interpret)
+    if impl == "reference":
+        S = q.shape[0]
+        keys = index_pool[int(layer), page_tables].reshape(
+            S, -1, index_pool.shape[-1])
+        return _index_scores_reference(q, w, keys, kv_lens[:, None],
+                                       scale)[:, 0]
+    return _paged_index_scores_pallas(
+        q, w, index_pool, page_tables, kv_lens, 1, scale, interpret,
+        int(layer))[:, 0]
+
+
+def paged_index_scores_prefill(q, w, index_pool, pages, start, valid, *,
+                               layer, scale, impl=None, interpret=None):
+    """The indexer's scores of one prefill chunk: ``q [C, Hi, Di]``, ``w [C,
+    Hi]`` at absolute positions ``start ..`` against the sequence's ``pages
+    [MP]`` (the chunk's own keys already scattered in), causal by position.
+    Returns ``[C, MP * ps]`` float32, ``NEG_INF`` past a token's own position
+    (rows at or past ``valid``: all of it)."""
+    import jax.numpy as jnp
+
+    C, Hi, Di = q.shape
+    impl, interpret = _mla_impl(impl, interpret)
+    if impl == "reference":
+        lens = jnp.where(jnp.arange(C) < valid,
+                         start + jnp.arange(C, dtype=jnp.int32) + 1, 0)
+        keys = index_pool[int(layer), pages].reshape(1, -1, Di)
+        return _index_scores_reference(q, w, keys, lens[:, None], scale)[:, 0]
+    nt = math.gcd(C, _MLA_PREFILL_TOKENS)
+    first = jnp.arange(C // nt, dtype=jnp.int32) * nt
+    lens = jnp.where(first < valid, start + first + nt, 0)
+    out = _paged_index_scores_pallas(
+        q.reshape(C // nt, nt * Hi, Di), w.reshape(C // nt, nt * Hi),
+        index_pool, jnp.broadcast_to(pages[None, :],
+                                     (C // nt, pages.shape[0])),
+        lens, nt, scale, interpret, int(layer))
+    out = out.reshape(C, -1)
+    return jnp.where((jnp.arange(C) < valid)[:, None], out, NEG_INF)
+
+
+def _order_key(scores):
+    """uint32 keys that order as the float32 ``scores`` do (no score is
+    NaN)."""
+    import jax.numpy as jnp
+
+    scores = scores.astype(jnp.float32)
+    # -0.0 ties with +0.0, as a comparison of the floats has it
+    bits = jax.lax.bitcast_convert_type(
+        jnp.where(scores == 0, jnp.float32(0), scores), jnp.uint32)
+    return jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+
+
+def dsa_threshold(scores, n_visible, k):
+    """The exact ``min(n_visible, k)``-best set of each row of ``scores [N,
+    K]`` (positions at or past ``n_visible [N]`` are no candidates), as a
+    threshold: ``(key [N, K] uint32, tau [N] uint32, last_tie [N] int32)`` —
+    position ``s`` is in the set iff ``key > tau``, or ``key == tau`` and ``s
+    <= last_tie`` (ties at the threshold: the lower position wins).  ``tau``
+    is built bit by bit from the top: the largest value that at least ``k``
+    candidates reach; ``last_tie`` likewise over the position's bits."""
+    import jax.numpy as jnp
+
+    N, K = scores.shape
+    pos = jnp.arange(K, dtype=jnp.int32)[None, :]
+    # a candidate's key is at least 2 ** 23 - 1 > 0 (that of -inf)
+    key = jnp.where(pos < n_visible[:, None], _order_key(scores),
+                    jnp.uint32(0))
+    want = jnp.minimum(n_visible, k).astype(jnp.int32)
+
+    def bit(i, tau):
+        cand = tau | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        n = (key >= cand[:, None]).sum(axis=1, dtype=jnp.int32)
+        return jnp.where(n >= want, cand, tau)
+
+    tau = jax.lax.fori_loop(0, 32, bit, jnp.zeros((N,), jnp.uint32))
+    above = (key > tau[:, None]).sum(axis=1, dtype=jnp.int32)
+    tie = key == tau[:, None]
+    need = want - above                    # ties to take, lowest first
+    bits = max(1, (K - 1).bit_length())
+
+    def pbit(i, p):
+        # the largest p with fewer than ``need`` ties before it
+        cand = p | (jnp.int32(1) << (bits - 1 - i))
+        n = (tie & (pos < cand[:, None])).sum(axis=1, dtype=jnp.int32)
+        return jnp.where(n < need, cand, p)
+
+    last = jax.lax.fori_loop(0, bits, pbit, jnp.zeros((N,), jnp.int32))
+    return key, tau, jnp.where(need > 0, last, -1)
+
+
+def dsa_keep(scores, n_visible, k):
+    """The exact selection as a mask ``[N, K]`` bool (:func:`dsa_threshold`)."""
+    import jax.numpy as jnp
+
+    key, tau, last = dsa_threshold(scores, n_visible, k)
+    pos = jnp.arange(scores.shape[1], dtype=jnp.int32)[None, :]
+    return (key > tau[:, None]) | ((key == tau[:, None])
+                                   & (pos <= last[:, None]))
+
+
+def dsa_rows(keep, k):
+    """A mask ``[N, K]`` with at most ``k`` positions a row -> ``(rows [N, k]
+    int32 ascending, n [N] int32)``; entries at and past ``n`` are 0.  In two
+    levels, over blocks of 128 positions: list entry ``j`` lies in the last
+    block with at most ``j`` kept positions before it (a count over the
+    blocks' prefix sums, no search), and inside the block it is the kept
+    position of that rank (a prefix count over the block's 128 bits).  A
+    binary search a list entry over the whole row's prefix counts, the form
+    this replaced, was sixteen dependent element gathers an entry: 8.2 ms a
+    layer at ``[24, 53248] -> [24, 2048]`` against 0.92 (PERF.md section 6,
+    PR 52)."""
+    import jax.numpy as jnp
+
+    N, K = keep.shape
+    B = math.gcd(K, 128)
+    blocks = keep.reshape(N, K // B, B)
+    count = blocks.sum(axis=2, dtype=jnp.int32)
+    before = jnp.cumsum(count, axis=1) - count
+    n = count.sum(axis=1)
+    j = jnp.arange(k, dtype=jnp.int32)
+    blk = (before[:, None, :] <= j[None, :, None]).sum(
+        axis=2, dtype=jnp.int32) - 1                            # [N, k]
+    rank = j[None, :] - jnp.take_along_axis(before, blk, axis=1)
+    bits = jnp.take_along_axis(blocks, blk[:, :, None], axis=1)  # [N, k, B]
+    inside = jnp.cumsum(bits, axis=2, dtype=jnp.int32) - 1
+    lane = jnp.argmax(bits & (inside == rank[:, :, None]), axis=2)
+    rows = blk * B + lane.astype(jnp.int32)
+    return jnp.where(j[None, :] < n[:, None], rows, 0), n
+
+
+def paged_mla_rows_attention(q, latent_pool, page_tables, rows, n_rows, *,
+                             v_width, sm_scale, layer, impl=None,
+                             interpret=None):
+    """Absorbed MLA decode over a ROW LIST: slot ``s`` attends to its token
+    positions ``rows[s, :n_rows[s]]`` and to nothing else.
+
+    q, latent_pool, page_tables: as :func:`paged_mla_decode_attention`.
+    rows ``[S, NS]`` int32 token positions (resolved here through the page
+    table to ``(page, row)``), ``n_rows [S]`` how many of them count
+    (``0`` gives exact zeros).  Each listed row is gathered ONCE for all
+    heads into ``[S, NS, W]``, which the latent walk then reads as the slot's
+    own ``NS / ps`` pages.  Returns ``[S, H, v_width]`` float32."""
+    import jax.numpy as jnp
+
+    S, H, W = q.shape
+    ps = latent_pool.shape[2]
+    impl, interpret = _mla_impl(impl, interpret)
+    NS = -(-rows.shape[1] // ps) * ps
+    if NS != rows.shape[1]:
+        rows = jnp.pad(rows, ((0, 0), (0, NS - rows.shape[1])))
+    pages = jnp.take_along_axis(page_tables, rows // ps, axis=1)
+    listed = latent_pool[int(layer), pages, rows % ps]       # [S, NS, W]
+    listed = listed.reshape(1, S * (NS // ps), ps, W)
+    tables = jnp.arange(S * (NS // ps), dtype=jnp.int32).reshape(S, NS // ps)
+    if impl == "reference":
+        return _paged_mla_reference(q, listed, tables, n_rows, v_width, H, 1,
+                                    sm_scale, 0)
+    return _paged_mla_pallas(q, listed, tables, n_rows, v_width, H, 1,
+                             sm_scale, interpret, 0, name=_ROWS_KERNEL_NAME)
 
 
 # ---------------------------------------------------------------------------

@@ -980,6 +980,29 @@ class DecodeScheduler:
         return tuple(sorted({b for b in self.prefill_buckets if b < ct}
                             | {ct}))
 
+    def _idle_decode_args(self):
+        """The decode program's arguments before ``previous`` with nobody
+        seated: every slot at scratch."""
+        import jax.numpy as jnp
+
+        slots = self.config.num_slots
+        return (self._params, self._cache.pools,
+                jnp.zeros((slots,), jnp.int32), jnp.zeros((slots,), jnp.int32),
+                self._by_group(self._tables, self._more_tables),
+                jnp.zeros((slots,), jnp.int32),
+                jnp.zeros((slots,), jnp.uint32),
+                jnp.zeros((slots,), jnp.float32))
+
+    def decode_program_text(self):
+        """The compiled decode program as text: every device instruction's
+        name beside the ``op_name`` its metadata carries (the model's
+        ``jax.named_scope`` path), which is how a reader of a device trace,
+        where an instruction has its name alone, tells the stages of a step
+        apart.  Lowers and compiles the warmed program's shapes once more
+        (the persistent cache answers where there is one)."""
+        return self._jit.get(("decode",)).lower(
+            *self._idle_decode_args(), *self._no_previous).compile().as_text()
+
     def warmup(self):
         """Compile the decode step and every prefill width against the
         scratch page, so no live sequence ever pays a compile."""
@@ -996,14 +1019,7 @@ class DecodeScheduler:
             toks, nobody = self._no_previous
             for _ in range(2):
                 toks, cache.pools = step(
-                    params, cache.pools,
-                    jnp.zeros((cfg.num_slots,), jnp.int32),
-                    jnp.zeros((cfg.num_slots,), jnp.int32),
-                    self._by_group(self._tables, self._more_tables),
-                    jnp.zeros((cfg.num_slots,), jnp.int32),
-                    jnp.zeros((cfg.num_slots,), jnp.uint32),
-                    jnp.zeros((cfg.num_slots,), jnp.float32),
-                    toks, nobody)
+                    *self._idle_decode_args(), toks, nobody)
             np.asarray(toks)
             for w in self._chunk_widths():
                 fn = self._jit.get(("chunk", w))

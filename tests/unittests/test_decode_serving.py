@@ -558,6 +558,28 @@ def _beside_a_decoder(sched, prompts, first_new=40, new=4):
                       for p in prompts[1:]]
 
 
+def _arrive_behind_a_commit(sched, prompts, first_new, new):
+    """As :func:`_beside_a_decoder`, on the scheduler's own clock: the worker
+    waits behind the commit that gives the first request its third token
+    until the others are in the queue, so they arrive beside a decoder
+    however the host schedules the two threads."""
+    reached, arrived = threading.Event(), threading.Event()
+    commit, first = sched._commit_step, []
+
+    def held_commit(sent):
+        commit(sent)
+        if first and len(first[0].token_times) >= 3 and not arrived.is_set():
+            reached.set()
+            arrived.wait(120)
+
+    sched._commit_step = held_commit
+    first.append(sched.submit(prompts[0], max_new_tokens=first_new))
+    assert reached.wait(120)
+    rest = [sched.submit(p, max_new_tokens=new) for p in prompts[1:]]
+    arrived.set()
+    return first + rest
+
+
 class TestChunkReadBehindAStep:
     PROMPTS = [np.arange(1, 10, dtype=np.int32),
                np.arange(3, 43, dtype=np.int32) % 49 + 1,
@@ -638,7 +660,7 @@ class TestChunkReadBehindAStep:
                                         autostart=False)
         sched._donated = pools == "donated"      # the host's side of donation
         send, read = sched._send_chunk, sched._read_chunk
-        fired, left = [], []
+        fired, left, noted = [], [], threading.Event()
 
         def lossy_send():
             sent = send()
@@ -656,18 +678,17 @@ class TestChunkReadBehindAStep:
                 left.append((behind, len(sched._unread), [
                     s.inflight for s in sched._slots if s is not None],
                     bool(np.asarray(sched._cache.k_pool).any())))
+                noted.set()
 
         sched._send_chunk, sched._read_chunk = lossy_send, noting_read
         sched.start()
         try:
-            futs = _beside_a_decoder(sched, self.PROMPTS, first_new=12,
-                                     new=12)
+            futs = _arrive_behind_a_commit(sched, self.PROMPTS, first_new=12,
+                                           new=12)
             with pytest.raises(faults.FaultInjected, match="lost chunk"):
                 futs[1].result(timeout=120)
             assert fired == [futs[1].seq]
-            deadline = time.time() + 30   # the future fails inside the read
-            while not left and time.time() < deadline:
-                time.sleep(0.002)
+            assert noted.wait(120)        # the future fails inside the read
             # a step had gone out behind the chunk: it is abandoned, and no
             # slot counts anything in flight
             (behind, unread, inflight, written), = left

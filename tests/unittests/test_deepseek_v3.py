@@ -648,7 +648,7 @@ def test_the_grid_steps_counter_says_which_tile_a_shape_got():
 
 # 6. the family's refusals and the weights' form ------------------------------
 
-@pytest.mark.parametrize("key,value", [("q_lora_rank", 1536), ("n_group", 8),
+@pytest.mark.parametrize("key,value", [("index_topk", 2048), ("n_group", 8),
                                        ("rope_scaling", {"type": "yarn"})])
 def test_a_key_the_model_does_not_write_is_refused(key, value):
     with pytest.raises(ValueError, match=key):

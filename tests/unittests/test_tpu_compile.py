@@ -1006,3 +1006,82 @@ def test_afmoe_step_program_compiles_for_v5e(afmoe_programs, program):
     assert mem.alias_size_in_bytes == pool_bytes
     assert mem.temp_size_in_bytes < 128 * 2 ** 20
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14.5e9
+
+
+# -- DeepSeek sparse attention at glm5_standing_dsactx's shapes ---------------
+
+def _dsa_shapes():
+    """The sizes of the configuration with an indexer, from its own file."""
+    import json
+    import os
+
+    from paddle_tpu.models import deepseek_v3 as M
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "chipbench/configs/glm5_744b_a40b.json")) as f:
+        cfg = json.load(f)
+    return cfg, M._dims(cfg)
+
+
+@pytest.mark.parametrize("stage", [
+    "index_decode", "index_chunk", "select_rows", "select_mask",
+    "rows_attention", "chunk_under_a_mask"])
+def test_sparse_attention_stages_compile_for_v5e(one_chip, no_persistent_cache,
+                                                 stage):
+    """Every stage of DeepSeek sparse attention at the cell's own shapes — 24
+    slots, a table of 832 pages of 64 rows, 32 indexer heads of 128 lanes
+    (``[24, 32, 128]``), 2048 selected rows (``[24, 2048]``) of 640 lanes, a
+    chunk of 512, both stored stacks left in HBM at a layer past the first:
+    the three kernels are ONE custom call each under their own names, the
+    selection is no kernel and no sort."""
+    cfg, d = _dsa_shapes()
+    S, C, ps = cfg["slots"], cfg["chunk"], cfg["page"]
+    mp = cfg["max_seq_len"] // ps
+    Hi, Di, H, W, R, top = d["Hi"], d["Di"], d["H"], d["W"], d["R"], d["topk"]
+    assert (S, Hi, Di, top, W, mp) == (24, 32, 128, 2048, 640, 832)
+    layer = d["L"] - 1
+
+    def sds(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    ipool = sds((d["L"], cfg["num_pages"], ps, Di), jnp.bfloat16)
+    lpool = sds((d["L"], cfg["num_pages"], ps, W), jnp.bfloat16)
+    on_chip = dict(impl="pallas", interpret=False)
+    att = dict(v_width=R, sm_scale=d["sm_scale"], layer=layer, **on_chip)
+    idx = dict(layer=layer, scale=d["index_scale"], **on_chip)
+    fn, args, name = {
+        "index_decode": (
+            lambda q, w, pool, t, n: FA.paged_index_scores(
+                q, w, pool, t, n, **idx),
+            (sds((S, Hi, Di), jnp.bfloat16), sds((S, Hi), jnp.float32), ipool,
+             sds((S, mp)), sds((S,))), FA._INDEX_KERNEL_NAME),
+        "index_chunk": (
+            lambda q, w, pool, t, s, v: FA.paged_index_scores_prefill(
+                q, w, pool, t, s, v, **idx),
+            (sds((C, Hi, Di), jnp.bfloat16), sds((C, Hi), jnp.float32), ipool,
+             sds((mp,)), sds(()), sds(())), FA._INDEX_KERNEL_NAME),
+        "select_rows": (
+            lambda s, n: FA.dsa_rows(FA.dsa_keep(s, n, top), top),
+            (sds((S, mp * ps), jnp.float32), sds((S,))), None),
+        "select_mask": (
+            lambda s, n: FA.dsa_keep(s, n, top),
+            (sds((C, mp * ps), jnp.float32), sds((C,))), None),
+        "rows_attention": (
+            lambda q, pool, t, r, n: FA.paged_mla_rows_attention(
+                q, pool, t, r, n, **att),
+            (sds((S, H, W), jnp.bfloat16), lpool, sds((S, mp)),
+             sds((S, top)), sds((S,))), FA._ROWS_KERNEL_NAME),
+        "chunk_under_a_mask": (
+            lambda q, pool, t, s, v, keep: FA.paged_mla_prefill_attention(
+                q, pool, t, s, v, keep=keep, **att),
+            (sds((C, H, W), jnp.bfloat16), lpool, sds((mp,)), sds(()),
+             sds(()), sds((C, mp * ps), jnp.bool_)), FA._MLA_KERNEL_NAME),
+    }[stage]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    calls = text.count('custom_call_target="tpu_custom_call"')
+    assert calls == (0 if name is None else 1)
+    if name is None:
+        assert " sort(" not in text and "approx" not in text.lower()
+    else:
+        assert "%" + name in text
