@@ -27,11 +27,11 @@ after ``first_k_dense_replace`` dense ones, are sparse experts.
   lower position) and latent attention reads those rows only.  The cache
   holds a SECOND page-indexed leaf, ``cache["index_k"]`` (``Di`` lanes a
   token a layer), that only the indexer reads.  A decode step scores through
-  the page table, compacts the set to a row list and attends over the gathered
-  rows; a chunk attends over every visible row under the set as a mask
-  (``parallel/flash_attention.py``: ``paged_index_scores*``, ``dsa_*``,
-  ``paged_mla_rows_attention``).  Without ``index_topk`` none of this exists
-  and the step programs are dense MLA's.
+  the page table, makes the set's row list (``dsa_select``: on the chip one
+  kernel) and attends over the gathered rows; a chunk attends over every
+  visible row under the set as a mask (``parallel/flash_attention.py``:
+  ``paged_index_scores*``, ``dsa_*``, ``paged_mla_rows_attention``).  Without
+  ``index_topk`` none of this exists and the step programs are dense MLA's.
 * **Experts** (``parallel/moe.py``: ``moe_topk``): sigmoid scores over ALL
   ``router_experts`` (``n_routed_experts`` where the config has no such key),
   top-k of score + ``e_score_correction_bias`` (``n_group`` = ``topk_group``
@@ -467,8 +467,8 @@ def decode_step(p, tokens, positions, cache, page_tables, kv_lens, *, cfg,
                         index[0], index[2], cache["index_k"], page_tables,
                         kv_lens, layer=layer, scale=d["index_scale"])
                 with jax.named_scope("dsa_select"):
-                    rows, n = FA.dsa_rows(
-                        FA.dsa_keep(scores, kv_lens, d["topk"]), d["topk"])
+                    rows, n = FA.dsa_select(
+                        scores, kv_lens, d["topk"])    # scores to row list
                 with jax.named_scope("mla_rows"):
                     o = FA.paged_mla_rows_attention(
                         q, cache["latent"], page_tables, rows, n,

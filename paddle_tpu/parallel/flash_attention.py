@@ -1675,7 +1675,11 @@ def paged_mla_prefill_attention(q, latent_pool, pages, start, valid, *,
 #   counting passes), the ties at it are filled by position with a second
 #   search over the position's bits, and the set is a MASK (a chunk: the
 #   latent walk takes it as ``keep``) or, compacted by prefix counts in two
-#   levels, a ROW LIST (a decode step).
+#   levels, a ROW LIST (a decode step).  ``dsa_select`` is the decode step's
+#   entry, scores to list: the plain form is ``dsa_rows(dsa_keep())``, the
+#   kernel (``paged_dsa_select``, one grid step a slot) does the same search
+#   and the same two levels with the slot's keys resident in VMEM, the prefix
+#   counts and the block look-up as 0/1 products on the MXU.
 # * ``paged_mla_rows_attention``: the listed rows are resolved through the
 #   page table, gathered ONCE for all heads into ``[S, k, W]`` and read by the
 #   latent walk above as a slot's own ``k / ps`` pages.
@@ -1683,6 +1687,8 @@ def paged_mla_prefill_attention(q, latent_pool, pages, start, valid, *,
 
 _INDEX_KERNEL_NAME = "paged_index_scores"
 _ROWS_KERNEL_NAME = "paged_mla_rows_attention"
+_SELECT_KERNEL_NAME = "paged_dsa_select"
+_SELECT_CHUNK = 32          # rows of 128 positions a turn of a counting pass
 _INDEX_TURN_KEYS = 8192      # at most; fewer where a step has many rows
 _INDEX_DOTS_BYTES = 2 * 1024 * 1024
 
@@ -1946,7 +1952,8 @@ def dsa_rows(keep, k):
     binary search a list entry over the whole row's prefix counts, the form
     this replaced, was sixteen dependent element gathers an entry: 8.2 ms a
     layer at ``[24, 53248] -> [24, 2048]`` against 0.92 (PERF.md section 6,
-    PR 52)."""
+    PR 52).  Since PR 53 the decode step goes through :func:`dsa_select`,
+    whose plain form this stays."""
     import jax.numpy as jnp
 
     N, K = keep.shape
@@ -1964,6 +1971,213 @@ def dsa_rows(keep, k):
     lane = jnp.argmax(bits & (inside == rank[:, :, None]), axis=2)
     rows = blk * B + lane.astype(jnp.int32)
     return jnp.where(j[None, :] < n[:, None], rows, 0), n
+
+
+def _dsa_select_kernel(nv_ref, s_ref, tri_ref, o_ref, key_ref, *, k, width):
+    """One grid step = one slot: ``s_ref [B, 128]`` its scores, a block of
+    128 positions a row, -> ``o_ref [k / 128, 128]`` its row list.  The
+    sign-folded keys (``key_ref`` int32: :func:`_order_key` with the top bit
+    flipped, so that a SIGNED compare orders them; a position at or past
+    ``n_visible`` holds the least value) stay in VMEM from here to the list,
+    and every loop runs over what the slot holds: the counting passes over
+    its ``ceil(n_visible / 4096)`` turns of ``_SELECT_CHUNK`` rows, count and
+    threshold kept as broadcast vectors; the list over its ``ceil(n_visible /
+    16384)`` spans of 128 blocks, each span writing the list tiles (128
+    entries) that its kept positions fall in."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    B, Bp = s_ref.shape[0], key_ref.shape[0]
+    R, C = _SELECT_CHUNK, 128
+    low = jnp.int32(-2 ** 31)
+    nv = jnp.clip(nv_ref[pl.program_id(0)], 0, width)
+    want = jnp.minimum(nv, k)
+    div = jax.lax.div
+    n_chunks = div(nv + (R * 128 - 1), R * 128)
+    n_spans = div(nv + (C * 128 - 1), C * 128)
+    at = jax.lax.broadcasted_iota
+
+    def positions(first_row, rows):
+        return ((first_row + at(jnp.int32, (rows, 128), 0)) * 128
+                + at(jnp.int32, (rows, 128), 1))
+
+    def chunk(c):
+        return pl.ds(pl.multiple_of(c * R, R), R)
+
+    def fold(c, _):
+        x = s_ref[chunk(c), :]
+        bits = jax.lax.bitcast_convert_type(
+            jnp.where(x == 0, jnp.float32(0), x), jnp.int32)
+        key = jnp.where(bits >= 0, bits, bits ^ jnp.int32(0x7FFFFFFF))
+        key_ref[chunk(c), :] = jnp.where(positions(c * R, R) < nv, key, low)
+        return _
+
+    # every row a span below reads, and no more
+    jax.lax.fori_loop(0, jnp.minimum(B // R, n_spans * (C // R)), fold, None)
+    if Bp > B:
+        @pl.when(n_spans * C > B)
+        def _past_the_table():
+            key_ref[B:, :] = jnp.full((Bp - B, 128), low)
+
+    def count(tests, m):
+        """How many of the slot's positions pass each of the ``m`` tests of
+        ``tests(keys, first_row)``, each as a ``[1, 128]`` vector of one
+        value."""
+        def one(c, accs):
+            hits = tests(key_ref[chunk(c), :], c * R)
+            return tuple(a + jnp.where(h, 1.0, 0.0)
+                         for a, h in zip(accs, hits))
+
+        accs = jax.lax.fori_loop(
+            0, n_chunks, one,
+            tuple(jnp.zeros((R, 128), jnp.float32) for _ in range(m)))
+        return tuple(jnp.broadcast_to(jnp.sum(
+            jnp.sum(a, axis=0, keepdims=True), axis=1, keepdims=True),
+            (1, 128)).astype(jnp.int32) for a in accs)
+
+    # the threshold as :func:`dsa_threshold` builds it, TWO bits a pass (the
+    # three candidates' counts are independent reductions; a pass waits for
+    # its reduction, so half the passes is half the wait)
+    def two_bits(i, tau):
+        c1, c2, c3 = (tau ^ (jnp.int32(m) << (30 - 2 * i)) for m in (1, 2, 3))
+        n1, n2, n3 = count(
+            lambda key, _: (key >= c1, key >= c2, key >= c3), 3)
+        return jnp.where(n3 >= want, c3, jnp.where(
+            n2 >= want, c2, jnp.where(n1 >= want, c1, tau)))
+
+    tau = jax.lax.fori_loop(0, 16, two_bits, jnp.full((1, 128), low))
+    above, reach = count(lambda key, _: (key > tau, key >= tau), 2)
+    need = want - above
+    pairs = (max(1, (B * 128 - 1).bit_length()) + 1) // 2
+
+    def two_position_bits(i, p):
+        c1, c2, c3 = (p | (jnp.int32(m) << (2 * (pairs - 1 - i)))
+                      for m in (1, 2, 3))
+
+        def ties_before(key, first_row):
+            tie, pos = key == tau, positions(first_row, R)
+            return tie & (pos < c1), tie & (pos < c2), tie & (pos < c3)
+
+        t1, t2, t3 = count(ties_before, 3)
+        return jnp.where(t3 < need, c3, jnp.where(
+            t2 < need, c2, jnp.where(t1 < need, c1, p)))
+
+    # every tie at the threshold is in the set (the common case: one, the
+    # ``want``-th best itself): nothing to cut and no pass
+    cut = reach > want
+    last = jax.lax.fori_loop(
+        0, jnp.where(jnp.max(reach) > want, pairs, 0), two_position_bits,
+        jnp.zeros((1, 128), jnp.int32))
+    last = jnp.where(cut, last, B * 128)
+
+    # the list, in two levels as :func:`dsa_rows`; 0/1 and counts of at most
+    # 128 are exact in bfloat16, their sums in the float32 they add up in
+    lower = tri_ref[...]
+    ones = jnp.ones((C, 128), jnp.bfloat16)
+    o_ref[...] = jnp.zeros(o_ref.shape, jnp.int32)
+
+    def span(c, kept):
+        """Blocks ``c * 128 ..``; ``kept [1, 128]``: the set's size before."""
+        key = key_ref[pl.ds(pl.multiple_of(c * C, C), C), :]
+        pos = positions(c * C, C)
+        keep = (key > tau) | ((key == tau) & (pos <= last))
+        keep = jnp.where(keep, 1.0, 0.0).astype(jnp.bfloat16)
+        # kept positions up to each lane of each block, a block a LANE
+        inside = jax.lax.dot_general(
+            lower, keep, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32).astype(jnp.bfloat16)
+        per_block = jnp.dot(keep, ones, preferred_element_type=jnp.float32)
+        through = kept + jnp.dot(lower, per_block.astype(jnp.bfloat16),
+                                 preferred_element_type=jnp.float32)
+        before = through - per_block          # [128 blocks, 128] a block a ROW
+        first = (pos - at(jnp.int32, (C, 128), 1)).astype(jnp.float32)
+        total = through[C - 1:, :]
+
+        def tile(t, _):
+            """List entries ``t * 128 ..``, an entry a lane: its block is the
+            one with ``before <= j < through``, its lane there the count of
+            lanes that hold at most ``j - before`` kept positions."""
+            j = (t * 128 + at(jnp.int32, (1, 128), 1)).astype(jnp.float32)
+            here = (before <= j) & (through > j)
+            start = jnp.sum(jnp.where(here, first, 0.0), axis=0,
+                            keepdims=True)
+            rank = j - jnp.sum(jnp.where(here, before, 0.0), axis=0,
+                               keepdims=True)
+            counts = jnp.dot(inside, jnp.where(here, 1.0, 0.0).astype(
+                jnp.bfloat16), preferred_element_type=jnp.float32)
+            lane = jnp.sum(jnp.where(counts <= rank, 1.0, 0.0), axis=0,
+                           keepdims=True)
+            mine = (j >= kept) & (j < total)
+            o_ref[pl.ds(t, 1), :] += jnp.where(mine, start + lane,
+                                               0.0).astype(jnp.int32)
+            return _
+
+        lo, hi = (jnp.max(x).astype(jnp.int32) for x in (kept, total))
+        jax.lax.fori_loop(div(lo, 128), div(hi + 127, 128), tile, None)
+        return total
+
+    jax.lax.fori_loop(0, n_spans, span, jnp.zeros((1, 128), jnp.float32))
+
+
+def _dsa_select_pallas(scores, n_visible, k, interpret):
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from .. import observability as obs
+
+    S, K = scores.shape
+    steps = obs.counter("paged.dsa_select.grid_steps",
+                        labels={"S": S, "K": K, "k": k})
+    if not steps.value:
+        steps.inc(S)
+    turn = _SELECT_CHUNK * 128
+    Kp, kp = -(-K // turn) * turn, -(-k // 128) * 128
+    if Kp != K:         # what lies past n_visible is never read as a score
+        scores = jnp.pad(scores, ((0, 0), (0, Kp - K)))
+    B = Kp // 128
+    lower = jnp.tri(128, dtype=jnp.bfloat16)    # [r, c] = 1 where c <= r
+    (rows,) = pl.pallas_call(
+        functools.partial(_dsa_select_kernel, k=k, width=K),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(S,),
+            in_specs=[pl.BlockSpec((None, B, 128), lambda s, nv: (s, 0, 0)),
+                      pl.BlockSpec((128, 128), lambda s, nv: (0, 0))],
+            out_specs=[pl.BlockSpec((None, kp // 128, 128),
+                                    lambda s, nv: (s, 0, 0))],
+            scratch_shapes=[pltpu.VMEM((-(-B // 128) * 128, 128),
+                                       jnp.int32)]),
+        out_shape=[jax.ShapeDtypeStruct((S, kp // 128, 128), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+        name=_SELECT_KERNEL_NAME,
+    )(n_visible.astype(jnp.int32), scores.astype(jnp.float32).reshape(
+        S, B, 128), lower)
+    return rows.reshape(S, kp)[:, :k]
+
+
+def dsa_select(scores, n_visible, k, *, impl=None, interpret=None):
+    """A decode step's selection, scores to row list: ``scores [S, K]``
+    float32, ``n_visible [S]`` -> ``(rows [S, k] int32 ascending, n [S]
+    int32)``, to the bit what ``dsa_rows(dsa_keep(scores, n_visible, k), k)``
+    gives, which is what ``impl="reference"`` calls: the exact ``min(n_visible,
+    k)``-best set, ``-0.0`` tied with ``+0.0``, ties at the threshold to the
+    lower position, entries at and past ``n`` zero, whatever lies at and past
+    ``n_visible``.  ``impl="pallas"`` is one kernel that keeps a slot's scores
+    in VMEM from the order key to the finished list
+    (:func:`_dsa_select_kernel`)."""
+    import jax.numpy as jnp
+
+    impl, interpret = _mla_impl(impl, interpret)
+    if impl == "reference":
+        return dsa_rows(dsa_keep(scores, n_visible, k), k)
+    if k > scores.shape[1]:
+        raise ValueError("a list of %d rows out of %d positions"
+                         % (k, scores.shape[1]))
+    n = jnp.clip(jnp.minimum(n_visible, k), 0).astype(jnp.int32)
+    return _dsa_select_pallas(scores, n_visible, k, interpret), n
 
 
 def paged_mla_rows_attention(q, latent_pool, page_tables, rows, n_rows, *,

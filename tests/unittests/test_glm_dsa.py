@@ -346,9 +346,12 @@ def _adversarial(case, rng, N=6, K=96):
     return s, n.astype(np.int32)
 
 
-@pytest.mark.parametrize("case", [
-    "ties-at-the-threshold", "all-equal", "fewer-visible-than-k",
-    "signs-and-zeros", "minus-infinity-past-kv-lens", "garbage-past-kv-lens"])
+_CASES = ["ties-at-the-threshold", "all-equal", "fewer-visible-than-k",
+          "signs-and-zeros", "minus-infinity-past-kv-lens",
+          "garbage-past-kv-lens"]
+
+
+@pytest.mark.parametrize("case", _CASES)
 @pytest.mark.parametrize("k", [1, 16, 96])
 def test_the_selection_is_the_argsorts(case, k):
     rng = np.random.RandomState(len(case) + k)
@@ -363,6 +366,106 @@ def test_the_selection_is_the_argsorts(case, k):
         np.testing.assert_array_equal(rows[i, :m[i]], np.flatnonzero(keep[i]))
         assert not rows[i, m[i]:].any()
     assert np.isfinite(rows).all()
+
+
+def _edges(case, rng):
+    """Score sets ``(s [N, K], n_visible [N], k)`` that sit on the selection
+    kernel's own seams: a counting pass's chunk of 4096 positions, a vreg row
+    of 1024, a block of 128, a list tile of 128 entries."""
+    K = 8192
+    pos = np.arange(K)
+    if case == "how-many-are-visible":          # 0, 1, exactly k, all of it
+        n, k = np.asarray([0, 1, 160, K, 4096, 4097]), 160
+        s = rng.randn(len(n), K).astype(np.float32)
+    elif case == "more-visible-than-the-row-is-wide":
+        # 200 positions, padded to a turn's 4096 inside: the padding is no
+        # candidate whatever n_visible says
+        n, k = np.asarray([300, 200, 199, 10 ** 6]), 64
+        s = -1.0 - rng.rand(len(n), 200)
+    elif case == "ties-straddle-the-seams":
+        # ``k - need`` scores above, at the row's end; equal scores over lo ..
+        # hi, of which the ``need`` lowest positions are taken: the cut falls
+        # before, on and behind a block's, a vreg row's and a chunk's edge
+        k = 200
+        spec = [(120, 140, 5), (120, 140, 8), (120, 140, 9), (120, 140, 21),
+                (1016, 1040, 8), (1016, 1040, 9), (4090, 4100, 6),
+                (4090, 4100, 7), (0, 7000, 1), (0, 7000, 200)]
+        n = np.full(len(spec), K)
+        s = np.full((len(spec), K), -3.0, np.float32)
+        for row, (lo, hi, need) in zip(s, spec):
+            row[lo:hi + 1] = 0.25
+            row[K - (k - need):] = 2.0 + rng.rand(k - need)
+    elif case == "a-tile-over-many-blocks":
+        # one kept position every 200: a tile of 128 entries spans 200 blocks
+        n, k = np.asarray([K, K - 100, 5000]), 40
+        s = np.where(pos % 200 == 7, 5.0 + (pos % 3), rng.rand(3, K) - 2.0)
+    elif case == "a-tile-inside-one-block":
+        # the best 128 are one whole block, the next 128 another
+        n, k = np.asarray([K, 6000, 1300]), 256
+        s = rng.rand(3, K) - 2.0
+        s[:, 1152:1280] = 9.0
+        s[:, 384:512] = 8.0
+    elif case == "a-full-list-of-a-long-row":
+        K = 53248
+        n, k = np.asarray([K, 49152, 15970, 2048, 2047]), 2048
+        s = np.maximum(rng.randn(len(n), K), 0).astype(np.float32)   # ReLU
+    else:
+        raise KeyError(case)
+    s = s.astype(np.float32)
+    s[np.arange(s.shape[1])[None, :] >= n[:, None]] = [
+        1e30, -np.inf, FA.NEG_INF][rng.randint(3)]
+    return s, n, k
+
+
+@pytest.mark.parametrize("case,k", [(c, k) for c in _CASES
+                                    for k in (1, 16, 96)] + [
+    ("ties-at-the-threshold", 2048), ("signs-and-zeros", 2048),
+    ("garbage-past-kv-lens", 2048),
+    ("how-many-are-visible", None), ("ties-straddle-the-seams", None),
+    ("more-visible-than-the-row-is-wide", None),
+    ("a-tile-over-many-blocks", None),
+    ("a-tile-inside-one-block", None), ("a-full-list-of-a-long-row", None)])
+def test_the_selection_kernel_is_the_plain_form_to_the_bit(case, k):
+    """``dsa_select(impl="pallas")`` in interpret mode against
+    ``dsa_rows(dsa_keep())``: rows and n equal exactly."""
+    rng = np.random.RandomState(len(case) + (k or 0))
+    if k is None:
+        s, n, k = _edges(case, rng)
+    elif k == 2048:                         # 2048 of a long row
+        s, n = _adversarial(case, rng, N=4, K=6144)
+        n[0], n[1] = 6144, 2048
+    else:
+        s, n = _adversarial(case, rng)
+    s, n = jnp.asarray(s), jnp.asarray(n, jnp.int32)
+    want = jax.jit(lambda s, n: FA.dsa_rows(FA.dsa_keep(s, n, k), k))(s, n)
+    got = jax.jit(lambda s, n: FA.dsa_select(
+        s, n, k, impl="pallas", interpret=True))(s, n)
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+    assert got[0].dtype == want[0].dtype and got[1].dtype == want[1].dtype
+    labels = {"S": s.shape[0], "K": s.shape[1], "k": k}
+    assert obs.counter("paged.dsa_select.grid_steps",
+                       labels=labels).value == s.shape[0]
+
+
+def test_on_the_cpu_the_selection_is_the_plain_form_through_the_module(
+        monkeypatch):
+    """``impl`` left to the backend: the CPU takes ``dsa_rows(dsa_keep())``,
+    looked up in the module at the call (what a test that replaces
+    ``FA.dsa_keep`` relies on)."""
+    s = jnp.asarray(np.random.RandomState(0).randn(3, 96).astype(np.float32))
+    n = jnp.asarray([96, 10, 0], jnp.int32)
+    rows, m = FA.dsa_select(s, n, 16)
+    want = FA.dsa_rows(FA.dsa_keep(s, n, 16), 16)
+    np.testing.assert_array_equal(np.asarray(rows), np.asarray(want[0]))
+    np.testing.assert_array_equal(np.asarray(m), np.asarray(want[1]))
+    window = lambda s, n, k: (jnp.arange(96)[None, :] < n[:, None]) & (
+        jnp.arange(96)[None, :] >= n[:, None] - k)
+    monkeypatch.setattr(FA, "dsa_keep", window)
+    rows, m = FA.dsa_select(s, n, 16)
+    np.testing.assert_array_equal(np.asarray(rows[0]), np.arange(80, 96))
+    with pytest.raises(ValueError, match="impl must be"):
+        FA.dsa_select(s, n, 16, impl="sort")
 
 
 # 4. the kernels against their plain forms ------------------------------------

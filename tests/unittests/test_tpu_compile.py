@@ -1033,8 +1033,9 @@ def test_sparse_attention_stages_compile_for_v5e(one_chip, no_persistent_cache,
     slots, a table of 832 pages of 64 rows, 32 indexer heads of 128 lanes
     (``[24, 32, 128]``), 2048 selected rows (``[24, 2048]``) of 640 lanes, a
     chunk of 512, both stored stacks left in HBM at a layer past the first:
-    the three kernels are ONE custom call each under their own names, the
-    selection is no kernel and no sort."""
+    the four kernels are ONE custom call each under their own names — a decode
+    step's selection, scores to row list, among them —, the chunk's selection
+    (the mask) is no kernel, and neither form is a sort."""
     cfg, d = _dsa_shapes()
     S, C, ps = cfg["slots"], cfg["chunk"], cfg["page"]
     mp = cfg["max_seq_len"] // ps
@@ -1062,8 +1063,9 @@ def test_sparse_attention_stages_compile_for_v5e(one_chip, no_persistent_cache,
             (sds((C, Hi, Di), jnp.bfloat16), sds((C, Hi), jnp.float32), ipool,
              sds((mp,)), sds(()), sds(())), FA._INDEX_KERNEL_NAME),
         "select_rows": (
-            lambda s, n: FA.dsa_rows(FA.dsa_keep(s, n, top), top),
-            (sds((S, mp * ps), jnp.float32), sds((S,))), None),
+            lambda s, n: FA.dsa_select(s, n, top, **on_chip),
+            (sds((S, mp * ps), jnp.float32), sds((S,))),
+            FA._SELECT_KERNEL_NAME),
         "select_mask": (
             lambda s, n: FA.dsa_keep(s, n, top),
             (sds((C, mp * ps), jnp.float32), sds((C,))), None),
@@ -1081,7 +1083,7 @@ def test_sparse_attention_stages_compile_for_v5e(one_chip, no_persistent_cache,
     text = jax.jit(fn).lower(*args).compile().as_text()
     calls = text.count('custom_call_target="tpu_custom_call"')
     assert calls == (0 if name is None else 1)
-    if name is None:
-        assert " sort(" not in text and "approx" not in text.lower()
-    else:
+    if name is not None:
         assert "%" + name in text
+    if stage.startswith("select"):
+        assert " sort(" not in text and "approx" not in text.lower()
