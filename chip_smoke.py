@@ -95,20 +95,24 @@ def log(msg):
     print(msg, flush=True)
 
 
-# jax's persistent-cache events, counted from the moment main() listens: a
-# hit or a miss is one request to compile an executable
-_CACHE = {"hits": 0, "misses": 0}
+def _xla_compiles():
+    """jax's compile requests as the program counts them itself
+    (``obs.watch_compiles()``, armed by ``enable_compilation_cache()`` and
+    so by the first ``Executor()``): ``requests`` (one a backend compile,
+    whether the persistent cache answered or XLA did), ``cache_hits`` and
+    ``cache_misses``, each summed over every ``within``."""
+    from paddle_tpu import observability as obs
 
-
-def _on_jax_event(event, **_):
-    if event == "/jax/compilation_cache/cache_hits":
-        _CACHE["hits"] += 1
-    elif event == "/jax/compilation_cache/cache_misses":
-        _CACHE["misses"] += 1
+    out = {"requests": 0, "cache_hits": 0, "cache_misses": 0}
+    for key, cell in obs.get_telemetry().counters().items():
+        kind = obs.split_labels(key)[0].rpartition("xla.compile.")[2]
+        if kind in out:
+            out[kind] += cell.value
+    return out
 
 
 def _xla_compile_requests():
-    return _CACHE["hits"] + _CACHE["misses"]
+    return _xla_compiles()["requests"]
 
 
 def _finite(x):
@@ -619,7 +623,6 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     import jax
-    import jax.monitoring
 
     import paddle_tpu as fluid
 
@@ -633,8 +636,7 @@ def main(argv=None):
         log("device: need %d TPU device(s), found %s"
             % (args.chips, [str(d) for d in devices]))
         return 1
-    fluid.enable_compilation_cache()
-    jax.monitoring.register_event_listener(_on_jax_event)
+    fluid.enable_compilation_cache()   # arms obs.watch_compiles() too
     log("cache: dir=%s (JAX_COMPILATION_CACHE_DIR %s)"
         % (jax.config.jax_compilation_cache_dir,
            "set" if os.environ.get("JAX_COMPILATION_CACHE_DIR") else "unset"))
@@ -644,9 +646,10 @@ def main(argv=None):
         t0 = time.perf_counter()
         log("phase %s: start %s" % (name, SIZES.get(name, "")))
         out = fn(*a)   # an exception ends the run: traceback, exit code 1
+        n = _xla_compiles()
         log("phase %s: ok in %.1f s (persistent compile cache so far: %d hits, "
-            "%d misses)" % (name, time.perf_counter() - t0, _CACHE["hits"],
-                            _CACHE["misses"]))
+            "%d misses)" % (name, time.perf_counter() - t0, n["cache_hits"],
+                            n["cache_misses"]))
         return out
 
     if args.chips == 1:
@@ -668,8 +671,11 @@ def main(argv=None):
         assert rep["kernel_calls"] > 0, \
             "no tpu_custom_call in the compiled mesh step"
         timed("pool", phase_pool, SIZES["pool"], args.seed)
-    log("cache: persistent compile cache hits %d, misses %d"
-        % (_CACHE["hits"], _CACHE["misses"]))
+    from paddle_tpu import observability as obs
+
+    log("cache: the program's xla.compile.* counters: %s" % json.dumps(
+        {k: c.value for k, c in sorted(obs.get_telemetry().counters().items())
+         if k.startswith("xla.compile.") and c.value}))
     log("total: %.1f s" % (time.perf_counter() - t_start))
     print(json.dumps({"ok": True, "device": {
         "platform": d0.platform, "kind": d0.device_kind,

@@ -14,7 +14,11 @@ Use it like the reference::
     ...
     exe = fluid.Executor(fluid.TPUPlace())
 """
-from . import ops as _ops  # registers all op lowering rules  # noqa: F401
+import time as _time
+
+_import_t0 = _time.perf_counter()  # set-up's account: observability/startup.py
+
+from . import ops as _ops  # registers all op lowering rules  # noqa: F401,E402
 
 from . import core
 from . import unique_name
@@ -136,3 +140,7 @@ import sys as _sys
 
 fluid = _sys.modules[__name__]
 _sys.modules[__name__ + ".fluid"] = fluid
+
+from .observability import startup as _startup  # noqa: E402
+
+_startup.note_import(_import_t0)

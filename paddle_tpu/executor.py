@@ -429,11 +429,12 @@ def compile_count():
     shape escaped the warmed menu.  It is NOT a count of XLA compiles:
     jax keys executables underneath an entry (on argument committed-ness
     and sharding, say), so one entry can compile more than once and a
-    persistent-cache hit compiles nothing.  For those, listen to jax's
-    own events (``jax.monitoring.register_event_listener``:
-    ``/jax/compilation_cache/cache_hits`` and ``.../cache_misses`` are
-    one compile request each), as ``chipbench/run.py`` and
-    ``chip_smoke.py`` do."""
+    persistent-cache hit compiles nothing.  Those are the program's own
+    cells since ``obs.watch_compiles()`` (armed by
+    :func:`enable_compilation_cache`): counters
+    ``xla.compile.requests{within}``, ``.cache_hits{within}`` and
+    ``.cache_misses{within}``, spans ``xla.compile.trace`` / ``.lower`` /
+    ``.backend`` (docs/observability.md, "Set-up")."""
     return _compiles.value
 
 
@@ -496,10 +497,12 @@ def enable_compilation_cache():
     reads the variable itself and this function sets no directory at all;
     where it is unset the cache goes to ``<checkout>/.jax_cache``.  Returns
     True if a cache directory is in use.  Called by the first
-    ``Executor()``."""
+    ``Executor()``; from here on the process accounts for its compile
+    requests (``obs.watch_compiles()``)."""
     from .core import safe_import_jax
 
     jax = safe_import_jax()
+    _obs.watch_compiles()
     from_env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     cache_dir = from_env or _DEFAULT_COMPILE_CACHE_DIR
     # a corrupt/unwritable cache dir (a file squatting on the path, a dead
@@ -562,8 +565,10 @@ def _retry_fresh_entry(entry, state_in, feed_arrays, key):
 
     policy = resilience.RetryPolicy(max_retries=2, base_delay=0.2,
                                     max_delay=2.0, classify=classify)
-    return resilience.call_with_retry(entry, state_in, feed_arrays, key,
-                                      policy=policy)
+    # what this call traces, lowers and compiles is ``executor.first_run``'s
+    with _obs.compiles_within("executor.first_run"):
+        return resilience.call_with_retry(entry, state_in, feed_arrays, key,
+                                          policy=policy)
 
 _DONATION_WARNING_MSG = "Some donated buffers were not usable"
 

@@ -28,6 +28,11 @@ module-level counters:
   trace's clock.  With a span sink attached the same spans also export
   as Chrome ``trace_event`` JSON (:class:`~.sinks.ChromeTraceSink`), for
   hosts with no profiler session.  See docs/observability.md "Phases".
+- set-up (:mod:`~.startup`) — :func:`watch_compiles` turns every jax
+  compile request into spans (``xla.compile.trace`` / ``.lower`` /
+  ``.backend``) and counters (requests, persistent-cache hits and misses)
+  labelled with the set-up or loop span that caused it, so a process start
+  accounts for its own time.  See docs/observability.md "Set-up".
 - pluggable sinks (:mod:`~.sinks`) — JSONL file, in-memory ring buffer
   for tests, periodic stdout summary, Chrome-trace exporter.
 - compute introspection (:mod:`~.xla_stats`) — per-compiled-program
@@ -82,6 +87,7 @@ from .registry import (
     observe,
     observe_span,
     open_frame,
+    past_span,
     record_span,
     remove_sink,
     reset,
@@ -101,6 +107,12 @@ from .sinks import (
     print_report,
 )
 from .slo import SLOAlert, SLOMonitor, SLOTarget
+from .startup import (
+    compiles_within,
+    setup_span,
+    unwatch_compiles,
+    watch_compiles,
+)
 from .tracing import TraceContext, build_trace_tree, new_trace
 
 __all__ = [
@@ -123,6 +135,7 @@ __all__ = [
     "observe",
     "span",
     "record_span",
+    "past_span",
     "timed",
     "observe_span",
     "emit",
@@ -134,6 +147,10 @@ __all__ = [
     "close_frame",
     "watch_gc",
     "unwatch_gc",
+    "watch_compiles",
+    "unwatch_compiles",
+    "setup_span",
+    "compiles_within",
     "Sink",
     "JsonlSink",
     "RingBufferSink",

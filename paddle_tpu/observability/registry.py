@@ -55,6 +55,7 @@ __all__ = [
     "observe",
     "span",
     "record_span",
+    "past_span",
     "timed",
     "observe_span",
     "emit",
@@ -546,6 +547,27 @@ class Telemetry:
         self._emit_span(name, ts, dur,
                         thread or threading.current_thread(), tags or {})
 
+    def past_span(self, name, ts, dur, labels=None, tags=None, seconds=None,
+                  cell=None):
+        """A span of the CALLING thread that somebody else measured and
+        reports after the fact (jax's compile events: ``ts`` = wall-clock
+        start, ``dur`` seconds).  Like a span that closes here, it observes
+        into the cell ``name{labels}`` (``cell``, where the caller holds it
+        already), adds to the thread's frame (under the cell's name, a child
+        of whatever is open) and feeds the span sinks; ``seconds`` is what
+        the cell and the frame get where that is not the whole extent (a
+        span's SELF time, its children observed apart)."""
+        if cell is None:
+            cell = self.histogram(name, labels)
+        s = dur if seconds is None else seconds
+        cell.observe(s)
+        frame = _frames.frame
+        if frame is not None:
+            frame.add(cell.name, s, frame.depth)
+        if self._span_sinks:
+            self._emit_span(name, ts, dur, threading.current_thread(),
+                            tags or {})
+
     def observe_span(self, name, wall0, t0, tags=None):
         """The tail half of :meth:`span` for hand-timed sites whose
         control flow doesn't fit a with-block (multi-exit loops):
@@ -612,6 +634,10 @@ def record_span(name, ts, dur, tags=None, thread=None):
 
 
 timed = span
+
+
+def past_span(name, ts, dur, labels=None, tags=None, seconds=None, cell=None):
+    _global.past_span(name, ts, dur, labels, tags, seconds, cell)
 
 
 def observe_span(name, wall0, t0, tags=None):

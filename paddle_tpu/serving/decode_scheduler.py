@@ -161,6 +161,11 @@ _ttft_hist = _obs.histogram("serving.decode.ttft")
 # less its direct children: what lies between spans
 _iteration_host = _obs.histogram("serving.decode.iteration.host")
 _iteration_unspanned = _obs.histogram("serving.decode.iteration.unspanned")
+# a scheduler's construction less its three named parts (the cache's
+# allocation, the weights' placement, warm-up): what it does under no name.
+# Kept here because the cells' difference cannot say it: ``serving.model_load``
+# is also the name of the model store's load and of a caller's own loading
+_build_unspanned = _obs.histogram("serving.decode.build.unspanned")
 # commit to commit, by whether a prefill chunk rode the interval; and of the
 # intervals judged a stall (``DecodeScheduler._note_commit``) the excess
 # over the kind's baseline, with ``serving.decode.stall_seconds{where=...}``
@@ -701,218 +706,234 @@ class DecodeScheduler:
                  on_handoff=None, claim=None, device=None):
         self.model = model
         cfg = self.config = config or DecodeConfig()
-        if role not in ("both", "prefill", "decode"):
-            raise ServingError(
-                "role must be 'both', 'prefill', or 'decode', got %r"
-                % (role,))
-        if model.slot_state and (cfg.prefix_cache or sessions is not None
-                                 or role != "both"):
-            raise ServingError(
-                "%r keeps slot-indexed state (%s): prefix_cache, sessions "
-                "and prefill/decode roles map or move PAGES, and a page "
-                "says nothing of the state at its boundary. A state "
-                "snapshot per checkpointed boundary is missing; serve it "
-                "with prefix_cache=False, no sessions and role='both'"
-                % (model.name, ", ".join(sorted(model.slot_state))))
-        if len(model.page_groups) > 1 and (
-                cfg.prefix_cache or sessions is not None or role != "both"
-                or cfg.kv_guard):
-            raise ServingError(
-                "%r keeps its pages in groups (%s): prefix_cache, sessions, "
-                "prefill/decode roles and kv_guard map, move or sweep the "
-                "FIRST group's pages, and a window group has freed the "
-                "pages at a hit's boundary. Pinning a window's pages with a "
-                "prefix is missing; serve it with prefix_cache=False, no "
-                "sessions, role='both' and kv_guard=False"
-                % (model.name, ", ".join(model.page_groups)))
-        if sessions is not None and not cfg.prefix_cache:
-            raise ServingError(
-                "sessions require prefix_cache=True: a session pin is an "
-                "extra refcount on the prompt's prefix-index chain")
-        # conversational sessions (serving/sessions.py): the store is
-        # SHARED across a pool's replicas; each scheduler only parks
-        # into and releases pins against its OWN cache
-        self._sessions = sessions
-        self._replica_index = int(replica_index)
-        self._role = role
-        self._on_handoff = on_handoff
-        # cross-thread pin-release + handoff-injection queues: the cache
-        # allocator is worker-owned, so other threads (session TTL
-        # sweeps, a sibling's handoff dispatch) only ever ENQUEUE here;
-        # the worker drains at each loop iteration — or the enqueuer
-        # applies directly under the life lock once the worker is
-        # provably dead (stop/give-up cleanup must still land)
-        self._pending_lock = threading.Lock()
-        self._pending_release = []
-        self._pending_handoffs = collections.deque()
-        def worst(page_size):
-            return cfg.num_slots * -(-cfg.max_seq_len // page_size) + 1
-
-        if model.page_groups:
-            sizes = cfg.num_pages if isinstance(cfg.num_pages, dict) else {}
-            unknown = set(sizes) - set(model.page_groups)
-            if unknown or (cfg.num_pages and not sizes):
+        # set-up accounts for its own time: construction is one span (its
+        # self time is what it does under no name), and what compiles
+        # under it is put down to it or to the part that caused it
+        _obs.watch_compiles()
+        with _obs.setup_span("serving.decode.build",
+                             model=model.name) as build:
+            if role not in ("both", "prefill", "decode"):
                 raise ServingError(
-                    "%r keeps its pages in groups %s: num_pages is {group: "
-                    "pages} over them, got %r"
-                    % (model.name, list(model.page_groups), cfg.num_pages))
-            # a group that states no pool holds every slot's longest
-            # sequence, counted in its OWN page size
-            groups = {g: dict(spec, num_pages=sizes[g] if g in sizes
-                              else worst(spec.get("page_size")
-                                         or cfg.page_size))
-                      for g, spec in model.page_groups.items()}
-            num_pages = None
-        else:
-            groups, num_pages = None, cfg.num_pages or worst(cfg.page_size)
-        self._cache = PagedKVCache(
-            model.num_layers, num_pages,
-            cfg.page_size, model.num_heads, model.head_dim,
-            cfg.max_seq_len, dtype=cfg.kv_dtype,
-            page_pools=model.page_pools, slot_state=model.slot_state,
-            num_slots=cfg.num_slots, device=device, page_groups=groups)
-        self._admit_waits = {
-            g: _obs.counter("serving.decode.admit_waits_for_pages",
-                            {"group": g})
-            for g in self._cache.group_names}
-        # the counters' cells by program
-        self._count_cells = {}
-        # decode steps dispatched and not yet read, oldest first: one
-        # between iterations, two for a moment inside one (step n+1 goes
-        # out, then step n is read); and what the decode program takes in
-        # its ``previous`` / ``from_previous`` places when nothing is in
-        # flight: every slot feeds the host's token
-        self._unread = collections.deque()
-        self._planned = []             # planned and not yet sent (None: replan)
-        self._no_previous = (
-            np.zeros((cfg.num_slots + len(model.step_counters),), np.int32),
-            np.zeros((cfg.num_slots,), np.bool_))
-        # this scheduler's copy of the weights, on the device once: every
-        # step takes it as an argument.  ``device`` (a pool's replica)
-        # COMMITS weights and cache there, which is what keeps the worker
-        # thread's dispatches on that device
-        import jax
+                    "role must be 'both', 'prefill', or 'decode', got %r"
+                    % (role,))
+            if model.slot_state and (cfg.prefix_cache or sessions is not None
+                                     or role != "both"):
+                raise ServingError(
+                    "%r keeps slot-indexed state (%s): prefix_cache, sessions "
+                    "and prefill/decode roles map or move PAGES, and a page "
+                    "says nothing of the state at its boundary. A state "
+                    "snapshot per checkpointed boundary is missing; serve it "
+                    "with prefix_cache=False, no sessions and role='both'"
+                    % (model.name, ", ".join(sorted(model.slot_state))))
+            if len(model.page_groups) > 1 and (
+                    cfg.prefix_cache or sessions is not None or role != "both"
+                    or cfg.kv_guard):
+                raise ServingError(
+                    "%r keeps its pages in groups (%s): prefix_cache, sessions, "
+                    "prefill/decode roles and kv_guard map, move or sweep the "
+                    "FIRST group's pages, and a window group has freed the "
+                    "pages at a hit's boundary. Pinning a window's pages with a "
+                    "prefix is missing; serve it with prefix_cache=False, no "
+                    "sessions, role='both' and kv_guard=False"
+                    % (model.name, ", ".join(model.page_groups)))
+            if sessions is not None and not cfg.prefix_cache:
+                raise ServingError(
+                    "sessions require prefix_cache=True: a session pin is an "
+                    "extra refcount on the prompt's prefix-index chain")
+            # conversational sessions (serving/sessions.py): the store is
+            # SHARED across a pool's replicas; each scheduler only parks
+            # into and releases pins against its OWN cache
+            self._sessions = sessions
+            self._replica_index = int(replica_index)
+            self._role = role
+            self._on_handoff = on_handoff
+            # cross-thread pin-release + handoff-injection queues: the cache
+            # allocator is worker-owned, so other threads (session TTL
+            # sweeps, a sibling's handoff dispatch) only ever ENQUEUE here;
+            # the worker drains at each loop iteration — or the enqueuer
+            # applies directly under the life lock once the worker is
+            # provably dead (stop/give-up cleanup must still land)
+            self._pending_lock = threading.Lock()
+            self._pending_release = []
+            self._pending_handoffs = collections.deque()
+            def worst(page_size):
+                return cfg.num_slots * -(-cfg.max_seq_len // page_size) + 1
 
-        with _obs.span("serving.model_load", model=model.name):
-            if device is None:
-                self._params = jax.tree_util.tree_map(jax.numpy.asarray,
-                                                      model.params)
+            if model.page_groups:
+                sizes = cfg.num_pages if isinstance(cfg.num_pages, dict) else {}
+                unknown = set(sizes) - set(model.page_groups)
+                if unknown or (cfg.num_pages and not sizes):
+                    raise ServingError(
+                        "%r keeps its pages in groups %s: num_pages is {group: "
+                        "pages} over them, got %r"
+                        % (model.name, list(model.page_groups), cfg.num_pages))
+                # a group that states no pool holds every slot's longest
+                # sequence, counted in its OWN page size
+                groups = {g: dict(spec, num_pages=sizes[g] if g in sizes
+                                  else worst(spec.get("page_size")
+                                             or cfg.page_size))
+                          for g, spec in model.page_groups.items()}
+                num_pages = None
             else:
-                self._params = jax.device_put(model.params, device)
-            jax.block_until_ready(self._params)
-        if cfg.prefill_buckets:
-            buckets = sorted(set(int(b) for b in cfg.prefill_buckets))
-            bad = [b for b in buckets
-                   if b % cfg.page_size or b < 1 or b > cfg.max_seq_len]
-            if bad:
-                raise ServingError(
-                    "prefill_buckets must be page_size multiples within "
-                    "max_seq_len; bad: %s" % bad)
-        else:
-            buckets, b = [], cfg.page_size
-            while b < cfg.max_seq_len:
-                buckets.append(b)
-                b *= 2
-            buckets.append(-(-cfg.max_seq_len // cfg.page_size)
-                           * cfg.page_size)
-            buckets = sorted(set(buckets))
-        self.prefill_buckets = tuple(buckets)
-        self._owns_queue = queue is None
-        self._queue = queue if queue is not None else RequestQueue(
-            cfg.queue_capacity, depth_gauge=_queue_depth,
-            full_counter=_queue_full,
-            shed_counter=_obs.counter("serving.decode.shed_admission"),
-            gauge_prefix="serving.decode.queue_depth")
-        self._gate = gate
-        # claim predicate: evaluated by the shared queue UNDER ITS LOCK
-        # against the head actually popped — closes the peek-then-pop
-        # window where two replicas approve different heads and pop
-        # crosswise, stealing each other's affinity-tagged requests
-        self._claim = claim
-        self._breaker = breaker
-        self._evict_on_death = bool(evict_on_death)
-        # reset_pools safety: the cache refuses to zero pages under
-        # these sequences unless the caller says force=True
-        self._cache.live_seqs = lambda: [
-            s.req.seq for s in self._slots if s is not None]
-        self._telemetry = _obs.get_telemetry()
-        # pool donation saves an HBM copy per step on chip; CPU jax has no
-        # donation and would warn every dispatch
-        donate = not cpu_backend()
-        self._donated = donate
-        # the prefill leg is replayable (its pool inputs survive a failed
-        # attempt — KV writes are functional), so transient dispatch
-        # faults retry instead of fail-typing the request.  NOT with
-        # donation: a failed donated dispatch already consumed the pools,
-        # so there is nothing valid to replay against.
-        self._prefill_policy = _resilience.RetryPolicy(
-            max_retries=0 if self._donated else cfg.prefill_retries,
-            base_delay=0.02, max_delay=0.25,
-            classify=_resilience.is_transient_error)
-        # the decode step is replayable for the same reason (functional
-        # pool updates: a failed attempt never touched the current
-        # buffers) — and NOT replayable under donation, identically
-        self._decode_policy = _resilience.RetryPolicy(
-            max_retries=0 if self._donated else cfg.decode_retries,
-            base_delay=0.02, max_delay=0.25,
-            classify=_resilience.is_transient_error)
-        self._programs = model.step_programs(cfg.top_k, donate)
-        self._jit = JitStepCache(
-            lambda key: self._build_step(key, donate),
-            cap=2 * len(self.prefill_buckets) + 12, name="decode-steps")
-        self._slots = [None] * cfg.num_slots
-        self._tables = np.zeros(
-            (cfg.num_slots, self._cache.max_pages_per_seq), np.int32)
-        # a table a further page group: the whole sequence's pages, or with
-        # a window a RING as wide as the most a slot holds live at once
-        # (logical page p in column p % width; released entries at scratch)
-        widest = max(self._chunk_widths())
-        self._check_group_geometry()
-        self._more_tables = {
-            g: np.zeros((cfg.num_slots,
-                         grp.table_width(cfg.max_seq_len, widest)), np.int32)
-            for g, grp in self._cache.groups.items()}
-        self._widest_chunk = widest
-        self._hol = None               # head-of-line request awaiting pages
-        # the loop's own account (``_note_commit``): the worker's frame and
-        # the collector's watcher while the loop runs; where the last commit
-        # ended (None: nothing to measure an interval from); what rode the
-        # interval under way; a baseline and its samples per kind of
-        # interval (without / with a prefill chunk); the stalls
-        self._frame = None
-        self._gc = None
-        self._last_commit = None
-        self._cpu_at = (0.0, 0.0)      # newest reading of the CPU clock
-        self._committed = False
-        self._chunk_rode = False
-        self._retried = False
-        self._baseline = [0.0, 0.0]
-        self._samples = [0, 0]
-        self._stall_run = [[0, 0.0], [0, 0.0]]   # stalls in a row: n, seconds
-        self._stalls = collections.deque(maxlen=STALL_RING)
-        self._stall_count = 0
-        self._stall_seconds = 0.0
-        # serializes _hol handoff between the worker (_admit/_fail_all)
-        # and a stop() that timed out joining a wedged-but-alive worker
-        # — an unsynchronized claim could fail AND decode one request
-        self._hol_lock = threading.Lock()
-        self._drain = True
-        self._completed = 0
-        self._retired_total = 0        # SERVED slot retirements only: the
-        # service-rate EMA must not count queue-expiry sheds, mid-decode
-        # sheds, or fault mass-retires as served work, or overload and
-        # failure inflate the rate and disable shed-at-admission exactly
-        # when it matters
-        # thread lifecycle (single-use Thread re-arming, life lock
-        # against start/restart/fail_pending races, BaseException death
-        # choke) lives in the shared RestartableWorker — see worker.py
-        self._worker = RestartableWorker(
-            self._serve_loop, name or "paddle-tpu-decode-scheduler",
-            label=name or "decoder")
-        if cfg.warmup:
-            self.warmup()
-        if autostart:
-            self.start()
+                groups, num_pages = None, cfg.num_pages or worst(cfg.page_size)
+            # the page pools and state leaves, zeros on the device
+            with _obs.span("serving.cache.allocate") as part:
+                self._cache = PagedKVCache(
+                    model.num_layers, num_pages,
+                    cfg.page_size, model.num_heads, model.head_dim,
+                    cfg.max_seq_len, dtype=cfg.kv_dtype,
+                    page_pools=model.page_pools,
+                    slot_state=model.slot_state,
+                    num_slots=cfg.num_slots, device=device,
+                    page_groups=groups)
+            named_s = part.duration
+            self._admit_waits = {
+                g: _obs.counter("serving.decode.admit_waits_for_pages",
+                                {"group": g})
+                for g in self._cache.group_names}
+            # the counters' cells by program
+            self._count_cells = {}
+            # decode steps dispatched and not yet read, oldest first: one
+            # between iterations, two for a moment inside one (step n+1 goes
+            # out, then step n is read); and what the decode program takes in
+            # its ``previous`` / ``from_previous`` places when nothing is in
+            # flight: every slot feeds the host's token
+            self._unread = collections.deque()
+            self._planned = []             # planned and not yet sent (None: replan)
+            self._no_previous = (
+                np.zeros((cfg.num_slots + len(model.step_counters),), np.int32),
+                np.zeros((cfg.num_slots,), np.bool_))
+            # this scheduler's copy of the weights, on the device once: every
+            # step takes it as an argument.  ``device`` (a pool's replica)
+            # COMMITS weights and cache there, which is what keeps the worker
+            # thread's dispatches on that device
+            import jax
+
+            with _obs.setup_span("serving.model_load",
+                                 model=model.name) as part:
+                if device is None:
+                    self._params = jax.tree_util.tree_map(jax.numpy.asarray,
+                                                          model.params)
+                else:
+                    self._params = jax.device_put(model.params, device)
+                jax.block_until_ready(self._params)
+            named_s += part.duration
+            if cfg.prefill_buckets:
+                buckets = sorted(set(int(b) for b in cfg.prefill_buckets))
+                bad = [b for b in buckets
+                       if b % cfg.page_size or b < 1 or b > cfg.max_seq_len]
+                if bad:
+                    raise ServingError(
+                        "prefill_buckets must be page_size multiples within "
+                        "max_seq_len; bad: %s" % bad)
+            else:
+                buckets, b = [], cfg.page_size
+                while b < cfg.max_seq_len:
+                    buckets.append(b)
+                    b *= 2
+                buckets.append(-(-cfg.max_seq_len // cfg.page_size)
+                               * cfg.page_size)
+                buckets = sorted(set(buckets))
+            self.prefill_buckets = tuple(buckets)
+            self._owns_queue = queue is None
+            self._queue = queue if queue is not None else RequestQueue(
+                cfg.queue_capacity, depth_gauge=_queue_depth,
+                full_counter=_queue_full,
+                shed_counter=_obs.counter("serving.decode.shed_admission"),
+                gauge_prefix="serving.decode.queue_depth")
+            self._gate = gate
+            # claim predicate: evaluated by the shared queue UNDER ITS LOCK
+            # against the head actually popped — closes the peek-then-pop
+            # window where two replicas approve different heads and pop
+            # crosswise, stealing each other's affinity-tagged requests
+            self._claim = claim
+            self._breaker = breaker
+            self._evict_on_death = bool(evict_on_death)
+            # reset_pools safety: the cache refuses to zero pages under
+            # these sequences unless the caller says force=True
+            self._cache.live_seqs = lambda: [
+                s.req.seq for s in self._slots if s is not None]
+            self._telemetry = _obs.get_telemetry()
+            # pool donation saves an HBM copy per step on chip; CPU jax has no
+            # donation and would warn every dispatch
+            donate = not cpu_backend()
+            self._donated = donate
+            # the prefill leg is replayable (its pool inputs survive a failed
+            # attempt — KV writes are functional), so transient dispatch
+            # faults retry instead of fail-typing the request.  NOT with
+            # donation: a failed donated dispatch already consumed the pools,
+            # so there is nothing valid to replay against.
+            self._prefill_policy = _resilience.RetryPolicy(
+                max_retries=0 if self._donated else cfg.prefill_retries,
+                base_delay=0.02, max_delay=0.25,
+                classify=_resilience.is_transient_error)
+            # the decode step is replayable for the same reason (functional
+            # pool updates: a failed attempt never touched the current
+            # buffers) — and NOT replayable under donation, identically
+            self._decode_policy = _resilience.RetryPolicy(
+                max_retries=0 if self._donated else cfg.decode_retries,
+                base_delay=0.02, max_delay=0.25,
+                classify=_resilience.is_transient_error)
+            self._programs = model.step_programs(cfg.top_k, donate)
+            self._jit = JitStepCache(
+                lambda key: self._build_step(key, donate),
+                cap=2 * len(self.prefill_buckets) + 12, name="decode-steps")
+            self._slots = [None] * cfg.num_slots
+            self._tables = np.zeros(
+                (cfg.num_slots, self._cache.max_pages_per_seq), np.int32)
+            # a table a further page group: the whole sequence's pages, or with
+            # a window a RING as wide as the most a slot holds live at once
+            # (logical page p in column p % width; released entries at scratch)
+            widest = max(self._chunk_widths())
+            self._check_group_geometry()
+            self._more_tables = {
+                g: np.zeros((cfg.num_slots,
+                             grp.table_width(cfg.max_seq_len, widest)), np.int32)
+                for g, grp in self._cache.groups.items()}
+            self._widest_chunk = widest
+            self._hol = None               # head-of-line request awaiting pages
+            # the loop's own account (``_note_commit``): the worker's frame and
+            # the collector's watcher while the loop runs; where the last commit
+            # ended (None: nothing to measure an interval from); what rode the
+            # interval under way; a baseline and its samples per kind of
+            # interval (without / with a prefill chunk); the stalls
+            self._frame = None
+            self._gc = None
+            self._last_commit = None
+            self._cpu_at = (0.0, 0.0)      # newest reading of the CPU clock
+            self._committed = False
+            self._chunk_rode = False
+            self._retried = False
+            self._baseline = [0.0, 0.0]
+            self._samples = [0, 0]
+            self._stall_run = [[0, 0.0], [0, 0.0]]   # stalls in a row: n, seconds
+            self._stalls = collections.deque(maxlen=STALL_RING)
+            self._stall_count = 0
+            self._stall_seconds = 0.0
+            # serializes _hol handoff between the worker (_admit/_fail_all)
+            # and a stop() that timed out joining a wedged-but-alive worker
+            # — an unsynchronized claim could fail AND decode one request
+            self._hol_lock = threading.Lock()
+            self._drain = True
+            self._completed = 0
+            self._retired_total = 0        # SERVED slot retirements only: the
+            # service-rate EMA must not count queue-expiry sheds, mid-decode
+            # sheds, or fault mass-retires as served work, or overload and
+            # failure inflate the rate and disable shed-at-admission exactly
+            # when it matters
+            # thread lifecycle (single-use Thread re-arming, life lock
+            # against start/restart/fail_pending races, BaseException death
+            # choke) lives in the shared RestartableWorker — see worker.py
+            self._worker = RestartableWorker(
+                self._serve_loop, name or "paddle-tpu-decode-scheduler",
+                label=name or "decoder")
+            if cfg.warmup:
+                t0 = time.perf_counter()
+                self.warmup()
+                named_s += time.perf_counter() - t0
+            if autostart:
+                self.start()
+        _build_unspanned.observe(build.duration - named_s)
 
     # -- compiled steps ------------------------------------------------------
     def _build_step(self, key, donate):
@@ -1011,7 +1032,7 @@ class DecodeScheduler:
 
         cfg = self.config
         cache, params = self._cache, self._params
-        with _obs.span("serving.decode.warmup", slots=cfg.num_slots):
+        with _obs.setup_span("serving.decode.warmup", slots=cfg.num_slots):
             step = self._jit.get(("decode",))
             # twice: ``previous`` is a host array when nothing is in flight
             # and the step before's own output when one is, and jax keys an
@@ -1504,7 +1525,10 @@ class DecodeScheduler:
         self._frame = _obs.open_frame()
         self._last_commit = None
         try:
-            self._serve_turns(self._frame)
+            # said once, not every turn: a compile request on this thread
+            # is a shape that escaped the warmed menu
+            with _obs.compiles_within("serving.decode.iteration"):
+                self._serve_turns(self._frame)
         finally:
             self._frame = None
             _obs.close_frame()

@@ -179,7 +179,7 @@ class ModelStore:
                 "backend='program')" % dirname)
         use_aot = has_aot if backend == "auto" else (backend == "aot")
         version = self._next_version()
-        with _obs.span("serving.model_load", dirname=dirname,
+        with _obs.setup_span("serving.model_load", dirname=dirname,
                        backend="aot" if use_aot else "program"):
             model = (self._load_aot if use_aot else self._load_program)(
                 dirname, version)

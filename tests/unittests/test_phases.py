@@ -4,6 +4,7 @@ observes into the cell of its name with no sink attached, ``timed`` /
 trace on their thread's line, the scheduler's and the executor's phase means
 add up, and none of it changes an output bit.
 """
+import gc
 import glob
 import inspect
 import threading
@@ -181,7 +182,8 @@ def _train_program():
     return main, startup, loss, feed
 
 
-SCHED_CELLS = ("iteration", "iteration.host", "admit", "sweep", "chunk.build",
+SCHED_CELLS = ("iteration", "iteration.host", "iteration.unspanned",
+               "admit", "sweep", "chunk.build",
                "prefill", "prefill.dispatch", "prefill.wait", "chunk.commit",
                "step.build", "step", "step.dispatch", "step.wait",
                "step.commit", "idle")
@@ -201,11 +203,18 @@ def generate_run(decode_model):
     time.sleep(0.12)            # at least one idle wait before the burst
     c0 = {n: obs.counter("serving.decode." + n).value for n in counters}
     h0 = _sched_snap()
-    futs = [sched.submit(p, max_new_tokens=8) for p in _prompts(10)]
-    outs = [f.result(timeout=300) for f in futs]
-    # the worker closes the last turn's spans AFTER it completes the last
-    # future: join it before the cells are read
-    sched.stop()
+    # a collection between two spans of a turn is a child of the turn too
+    # (``host.gc``): none while the burst runs, so the phases named here
+    # are all of them
+    gc.disable()
+    try:
+        futs = [sched.submit(p, max_new_tokens=8) for p in _prompts(10)]
+        outs = [f.result(timeout=300) for f in futs]
+        # the worker closes the last turn's spans AFTER it completes the
+        # last future: join it before the cells are read
+        sched.stop()
+    finally:
+        gc.enable()
     snap = {c: v - h0[c] for c, v in _sched_snap().items()}
     cnt = {n: obs.counter("serving.decode." + n).value - c0[n]
            for n in counters}
@@ -235,22 +244,34 @@ def test_scheduler_phase_counts_follow_the_loop(generate_run):
 
 def test_iteration_mean_is_the_sum_of_its_phase_means(generate_run):
     """iteration = admit + sweep + share x (chunk.build + prefill +
-    chunk.commit) + step.build + step + step.commit, on sums so that
-    iterations without a chunk or a step weigh what they should."""
-    s, _, _ = generate_run
+    chunk.commit) + step.build + step + step.commit + what lies between
+    them (``iteration.unspanned``): the identity the worker's frame keeps,
+    EXACTLY, on sums and on means with every phase weighed by how often it
+    ran.  Counts and an identity of one clock's readings: no closeness of
+    two, which a loaded machine moves."""
+    s, cnt, _ = generate_run
     parts = ("admit", "sweep", "chunk.build", "prefill", "chunk.commit",
              "step.build", "step", "step.commit")
+    n = s["iteration"].count
+    # how often each phase ran: once a turn, once a chunk, once a step
+    assert s["admit"].count == s["sweep"].count == n
+    assert s["iteration.unspanned"].count == n
+    assert (s["chunk.build"].count == s["prefill"].count
+            == s["chunk.commit"].count == cnt["prefills"])
+    assert (s["step.build"].count == s["step"].count
+            == s["step.commit"].count == cnt["steps"])
     total = sum(s[p].sum for p in parts)
     assert total <= s["iteration"].sum
-    assert total == pytest.approx(s["iteration"].sum, rel=0.05)
-    n = s["iteration"].count
+    assert total + s["iteration.unspanned"].sum == pytest.approx(
+        s["iteration"].sum, rel=1e-9)
     by_means = (s["admit"].mean + s["sweep"].mean
+                + s["iteration.unspanned"].mean
                 + s["prefill"].count / n * (s["chunk.build"].mean
                                             + s["prefill"].mean
                                             + s["chunk.commit"].mean)
                 + s["step"].count / n * (s["step.build"].mean + s["step"].mean
                                          + s["step.commit"].mean))
-    assert by_means == pytest.approx(s["iteration"].mean, rel=0.05)
+    assert by_means == pytest.approx(s["iteration"].mean, rel=1e-9)
 
 
 def test_children_stay_inside_their_parents(generate_run):
