@@ -1000,8 +1000,14 @@ flash_attention_rows.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 # step program that aliases the pools input->output never re-lays them out
 # (a trailing ``[H, Dh]`` = 8 x 64 would pad in a bf16 tile, and the
 # compiler then picks a pages-minor layout the kernels cannot read).  Both
-# engines take the WHOLE stack plus a static ``layer`` and touch only the
-# pages the page table names — no ``pool[layer]`` slice ever exists:
+# engines take the WHOLE stack plus a ``layer`` and touch only the pages the
+# page table names — no ``pool[layer]`` slice ever exists.  ``layer`` is a
+# Python int where a step program unrolls its layers (closed into the kernel:
+# every family but one) or a TRACED int32 scalar where the program applies
+# its layers inside a ``lax`` loop (``models/ouro.py``: K/V layer ``u * L +
+# l`` with ``u`` the loop's counter): it then rides the scalar-prefetch path
+# as a third operand beside the page table and ``kv_lens``
+# (``_layer_prefetch``), and the static form lowers to what it always did:
 #
 # * reference (CPU / tests): gather the slot's pages out of the stack
 #   (``pool[layer, page_tables]``), unfold the heads and run the
@@ -1011,7 +1017,8 @@ flash_attention_rows.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 # * pallas (TPU): the page table and ``kv_lens`` ride the SCALAR-PREFETCH
 #   path (``PrefetchScalarGridSpec``) and the stack stays in HBM: one grid
 #   step a slot, which copies the slot's OWN ``ceil(kv_len / ps)`` pages
-#   ``(layer, page)`` by the prefetched table into a VMEM tile, many pages a
+#   ``(layer, page)`` (the layer a constant or a prefetched scalar, the page
+#   from the prefetched table) into a VMEM tile, many pages a
 #   turn (``_decode_turn_pages``: 32 of the chat cell's 16-token pages), the
 #   next turn's copies in flight — no gathered [S, max_kv, H, D]
 #   intermediate ever exists in HBM, and no page past ``kv_len`` is copied,
@@ -1026,6 +1033,37 @@ flash_attention_rows.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 # Contract (shared by both engines, tested in test_flash_decode.py):
 # ``kv_lens[s] == 0`` (inactive slot) yields EXACT ZEROS for that slot.
 # ---------------------------------------------------------------------------
+
+
+def _layer_index(layer):
+    """``layer`` as the walks take it: a Python int (closed into the kernel,
+    as every unrolled step program gives it) stays one; anything else is a
+    TRACED int32 scalar (a step program that applies its layers inside a
+    ``lax`` loop numbers the K/V layer ``u * L + l`` with ``u`` the loop's
+    counter) and rides the scalar-prefetch path beside the page table."""
+    import jax.numpy as jnp
+
+    if isinstance(layer, (int, np.integer)):
+        return int(layer)
+    return jnp.asarray(layer, jnp.int32).reshape(())
+
+
+def _layer_prefetch(kernel, layer, n, **kw):
+    """``(kernel', operands)`` of a paged kernel whose first ``n`` operands
+    are prefetched scalars and which takes ``layer=``: a static layer is
+    closed in and adds no operand (the program is the one it always was); a
+    traced one is one more prefetched scalar behind the ``n``, read in the
+    kernel where the static form has a constant.  Either way the custom call
+    keeps the kernel's name."""
+    if isinstance(layer, int):
+        return functools.partial(kernel, layer=layer, **kw), ()
+
+    def traced(*refs):
+        return kernel(*refs[:n], *refs[n + 1:], layer=refs[n][0], **kw)
+
+    traced.__name__ = kernel.__name__
+    traced.__qualname__ = kernel.__qualname__
+    return traced, (layer.reshape(1),)
 
 
 def _stacked_pools(q, k_pool, v_pool, layer):
@@ -1056,7 +1094,7 @@ def _stacked_pools(q, k_pool, v_pool, layer):
     if Hq % n_kv:
         raise ValueError("%d query heads do not group over %d KV heads"
                          % (Hq, n_kv))
-    return k_pool, v_pool, int(layer), n_kv
+    return k_pool, v_pool, _layer_index(layer), n_kv
 
 
 def _paged_reference(q, k_pool, v_pool, page_tables, kv_lens, sm_scale, layer):
@@ -1365,16 +1403,16 @@ def _paged_pallas(q, k_pool, v_pool, page_tables, kv_lens, sm_scale, interpret,
     if not steps.value:
         steps.inc(S)
 
-    kernel = functools.partial(
-        _paged_decode_kernel, layer=layer, page_size=ps, pages=pages,
+    kernel, layer_op = _layer_prefetch(
+        _paged_decode_kernel, layer, 2, page_size=ps, pages=pages,
         num_pages_per_seq=mp, n_head=H, head_dim=Dh, sm_scale=sm_scale)
     # [S, 1, H*Dh]: the block's last two dims equal the array's own (the
     # only blocking of a one-row query the TPU lowering accepts)
-    row = pl.BlockSpec((None, 1, H * Dh), lambda s, pt, kl: (s, 0, 0))
+    row = pl.BlockSpec((None, 1, H * Dh), lambda s, *_: (s, 0, 0))
     # the stacked pools stay where they are: the walk copies pages out
     stack = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=2 + len(layer_op),
         grid=(S,),
         in_specs=[row, stack, stack],
         out_specs=[row],
@@ -1392,7 +1430,7 @@ def _paged_pallas(q, k_pool, v_pool, page_tables, kv_lens, sm_scale, interpret,
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,
-    )(pt_flat, lens, q.reshape(S, 1, H * Dh), k_pool, v_pool)
+    )(pt_flat, lens, *layer_op, q.reshape(S, 1, H * Dh), k_pool, v_pool)
     return out.reshape(S, H, Dh)
 
 
@@ -2214,7 +2252,8 @@ def paged_mla_rows_attention(q, latent_pool, page_tables, rows, n_rows, *,
 # ---------------------------------------------------------------------------
 # GROUPED query heads on the walk, with an optional WINDOW.  ``Hq = g * Hkv``
 # query heads read the stored ``[L, P, ps, Hkv*Dh]`` stacks (query head ``i``
-# reads KV head ``i // g``): the same walk as the plain kernel (one grid step a
+# reads KV head ``i // g``; ``layer`` an int or a traced scalar, as in the
+# plain kernel): the same walk as the plain kernel (one grid step a
 # slot, the slot's own pages copied whole, many to a turn, the next turn's
 # copies in flight), with each KV head's ``g`` query rows scored against that
 # head's ``Dh`` lanes of the tile and ``p . v`` taken over the same lanes, so
@@ -2407,16 +2446,16 @@ def _paged_gqa_walk_pallas(q, k_pool, v_pool, page_tables, kv_lens, n_kv,
         "window": window or 0})
     if not steps.value:
         steps.inc(S)
-    kernel = functools.partial(
-        _paged_gqa_walk_kernel, layer=layer, page_size=ps, pages=pages,
+    kernel, layer_op = _layer_prefetch(
+        _paged_gqa_walk_kernel, layer, 2, page_size=ps, pages=pages,
         table_width=mp, n_kv=n_kv, per_head=per_pad, group=g,
         q_tokens=q_tokens, head_dim=Dh, window=window, sm_scale=sm_scale)
-    block = pl.BlockSpec((None, rows, Dh), lambda s, pt, kl: (s, 0, 0))
+    block = pl.BlockSpec((None, rows, Dh), lambda s, *_: (s, 0, 0))
     stack = pl.BlockSpec(memory_space=pl.ANY)
     (out,) = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=2 + len(layer_op),
             grid=(S,),
             in_specs=[block, stack, stack],
             out_specs=[block],
@@ -2432,7 +2471,7 @@ def _paged_gqa_walk_pallas(q, k_pool, v_pool, page_tables, kv_lens, n_kv,
         name=("paged_gqa_full_attention" if window is None
               else "paged_gqa_window_attention"),
     )(page_tables.astype(jnp.int32).reshape(S * mp),
-      kv_lens.astype(jnp.int32), q, k_pool, v_pool)
+      kv_lens.astype(jnp.int32), *layer_op, q, k_pool, v_pool)
     return out.reshape(S, n_kv, per_pad, Dh)[:, :, :per].reshape(S, R, Dh)
 
 
@@ -2456,14 +2495,15 @@ def _gqa_walk(q, k_pool, v_pool, page_tables, kv_lens, n_kv, q_tokens,
         raise ValueError("window must be >= 1, got %r" % (window,))
     if sm_scale is None:
         sm_scale = 1.0 / float(np.sqrt(q.shape[-1]))
+    layer = _layer_index(layer)
     if impl == "reference":
         tables = page_tables[0] if q_tokens > 1 else page_tables
         return _paged_gqa_walk_reference(
             q, k_pool, v_pool, tables, kv_lens, n_kv, q_tokens, window,
-            sm_scale, int(layer))
+            sm_scale, layer)
     return _paged_gqa_walk_pallas(
         q, k_pool, v_pool, page_tables, kv_lens, n_kv, q_tokens, window,
-        sm_scale, interpret, int(layer))
+        sm_scale, interpret, layer)
 
 
 def paged_gqa_decode_attention(q, k_pool, v_pool, page_tables, kv_lens, *,
@@ -2472,8 +2512,9 @@ def paged_gqa_decode_attention(q, k_pool, v_pool, page_tables, kv_lens, *,
     """Grouped-query decode on the walk: one query token per slot.
 
     q: ``[S, Hq, Dh]``; k_pool / v_pool: the stored stacks ``[L, num_pages,
-        page_size, Hkv*Dh]`` addressed in place by ``(layer, page)``, ``Hq =
-        g * Hkv`` (query head ``i`` reads KV head ``i // g``).
+        page_size, Hkv*Dh]`` addressed in place by ``(layer, page)``
+        (``layer`` a Python int or a traced int32 scalar), ``Hq = g * Hkv``
+        (query head ``i`` reads KV head ``i // g``).
     page_tables ``[S, MP]`` / kv_lens ``[S]``: as
         :func:`paged_decode_attention`; ``kv_lens[s] == 0`` gives exact zeros
         and reads no page.
@@ -2622,17 +2663,17 @@ def _paged_gqa_pallas(q, k_pool, v_pool, pages, tokens, sm_scale, interpret,
         "turn": turn_pages * ps})
     if not steps.value:
         steps.inc(S * n_kv)
-    kernel = functools.partial(
-        _paged_gqa_walk_kernel, layer=layer, page_size=ps, pages=turn_pages,
+    kernel, layer_op = _layer_prefetch(
+        _paged_gqa_walk_kernel, layer, 2, page_size=ps, pages=turn_pages,
         table_width=ns, n_kv=n_kv, per_head=g_pad, group=g, q_tokens=1,
         head_dim=Dh, window=None, sm_scale=sm_scale, listed=True)
     rows = pl.BlockSpec((None, None, g_pad, Dh),
-                        lambda s, h, pt, kl: (s, h, 0, 0))
+                        lambda s, h, *_: (s, h, 0, 0))
     stack = pl.BlockSpec(memory_space=pl.ANY)
     (out,) = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=2 + len(layer_op),
             grid=(S, n_kv),
             in_specs=[rows, stack, stack],
             out_specs=[rows],
@@ -2646,8 +2687,8 @@ def _paged_gqa_pallas(q, k_pool, v_pool, pages, tokens, sm_scale, interpret,
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
         name="paged_gqa_decode_attention",
-    )(pages.reshape(S * n_kv * ns), tokens.reshape(S * n_kv), q, k_pool,
-      v_pool)
+    )(pages.reshape(S * n_kv * ns), tokens.reshape(S * n_kv), *layer_op, q,
+      k_pool, v_pool)
     return out[:, :, :g].reshape(S, Hq, Dh)
 
 
@@ -2976,8 +3017,9 @@ def _paged_prefill_reference(q, k_pool, v_pool, pages, start, sm_scale,
 def _paged_prefill_kernel(pt_ref, start_ref, q_ref, k_ref, v_ref, o_ref,
                           m_scr, l_scr, acc_scr, *, page_size,
                           num_pages_per_seq, chunk, n_head, head_dim,
-                          sm_scale):
-    """One grid step = one page against the whole chunk, ALL heads.  Heads
+                          sm_scale, layer=None):
+    """One grid step = one page against the whole chunk, ALL heads (``layer``
+    is the page blocks' index maps' to read, not the kernel's).  Heads
     are folded into the lane dimension (``[C, H*Dh]`` queries, ``[ps,
     H*Dh]`` pages — the pool's stored form), which is what
     makes the blocks legal on the chip; the head loop runs inside the
@@ -3051,16 +3093,18 @@ def _paged_prefill_pallas(q, k_pool, v_pool, pages, start, sm_scale,
     pt = pages.astype(jnp.int32)
     start_arr = jnp.reshape(jnp.asarray(start, jnp.int32), (1,))
 
-    kernel = functools.partial(
-        _paged_prefill_kernel, page_size=ps, num_pages_per_seq=mp,
+    # one page of this layer out of the stacked pool, as in _paged_pallas:
+    # the kernel sees [ps, H*Dh].  A static layer is a constant of the index
+    # map; a traced one is a third prefetched scalar that only the map reads
+    kernel, layer_op = _layer_prefetch(
+        _paged_prefill_kernel, layer, 2, page_size=ps, num_pages_per_seq=mp,
         chunk=C, n_head=H, head_dim=Dh, sm_scale=sm_scale)
-    # one page of this (static) layer out of the stacked pool, as in
-    # _paged_pallas: the kernel sees [ps, H*Dh]
-    page = pl.BlockSpec((None, None, ps, H * Dh),
-                        lambda j, pt, st: (layer, pt[j], 0, 0))
-    rows = pl.BlockSpec((C, H * Dh), lambda j, pt, st: (0, 0))
+    page = pl.BlockSpec(
+        (None, None, ps, H * Dh),
+        lambda j, pt, st, *ly: (ly[0][0] if ly else layer, pt[j], 0, 0))
+    rows = pl.BlockSpec((C, H * Dh), lambda j, *_: (0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=2 + len(layer_op),
         grid=(mp,),
         in_specs=[rows, page, page],
         out_specs=[rows],
@@ -3078,7 +3122,7 @@ def _paged_prefill_pallas(q, k_pool, v_pool, pages, start, sm_scale,
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
-    )(pt, start_arr, q.reshape(C, H * Dh), k_pool, v_pool)
+    )(pt, start_arr, *layer_op, q.reshape(C, H * Dh), k_pool, v_pool)
     return out.reshape(C, H, Dh)
 
 
@@ -3267,9 +3311,11 @@ def paged_prefill_attention(q, k_pool, v_pool, pages, start, sm_scale=None,
         still applies inside a page).  Every row must have page 0
         selected.  ``g = 1`` without a mask is the kernel it always was.
     k_pool / v_pool: with ``layer=li`` (the step programs) the STORED
-        stack ``[L, num_pages, page_size, H*Dh]``, addressed in place;
-        with ``layer=None`` ONE layer's ``[num_pages, page_size, H, Dh]``
-        pool, folded into a one-layer stack for the same kernel.  The
+        stack ``[L, num_pages, page_size, H*Dh]``, addressed in place
+        (``li`` a Python int; the plain kernel also takes a traced int32
+        scalar, a third prefetched operand that its page blocks' index map
+        reads); with ``layer=None`` ONE layer's ``[num_pages, page_size, H,
+        Dh]`` pool, folded into a one-layer stack for the same kernel.  The
         chunk's OWN k/v must already be scattered in.
     pages: [max_pages] int32 — the sequence's full page-table row in
         order; unused entries must point at a valid (scratch) page.
@@ -3302,6 +3348,9 @@ def paged_prefill_attention(q, k_pool, v_pool, pages, start, sm_scale=None,
     if plain:
         return _paged_prefill_pallas(q, k_pool, v_pool, pages, start,
                                      sm_scale, interpret, layer)
+    if not isinstance(layer, int):
+        raise ValueError("the grouped / block-masked chunk kernel takes a "
+                         "static layer (its page blocks' index maps hold it)")
     return _paged_gqa_prefill_pallas(q, k_pool, v_pool, pages, start,
                                      sm_scale, interpret, layer, n_kv,
                                      block_mask)
@@ -3321,10 +3370,11 @@ def paged_decode_attention(q, k_pool, v_pool, page_tables, kv_lens,
         tokens (page by page) are valid; ``page_tables`` / ``kv_lens`` are
         then not read.  ``g = 1`` without a selection is the plain kernel:
         one grid step a slot, the slot's own pages many to a turn.
-    k_pool / v_pool: with ``layer=li`` (the step programs) the STORED
-        stack ``[L, num_pages, page_size, H*Dh]``, addressed in place;
-        with ``layer=None`` ONE layer's ``[num_pages, page_size, H, Dh]``
-        pool, folded into a one-layer stack for the same kernel.
+    k_pool / v_pool: with ``layer=li`` (the step programs; a Python int, or
+        a traced int32 scalar inside a program's loop over its layers) the
+        STORED stack ``[L, num_pages, page_size, H*Dh]``, addressed in
+        place; with ``layer=None`` ONE layer's ``[num_pages, page_size, H,
+        Dh]`` pool, folded into a one-layer stack for the same kernel.
     page_tables: [S, max_pages] int32 — slot s's kv lives in pages
         ``page_tables[s, :ceil(kv_lens[s]/page_size)]`` in order; unused
         entries must point at a valid (scratch) page id.

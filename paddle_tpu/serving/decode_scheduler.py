@@ -258,11 +258,14 @@ class DecodeModel:
     of ``slot_state`` ``{name: dict(layers=, shape=, dtype=)}`` as
     ``[layers, num_slots, *shape]``.  ``num_layers`` / ``num_heads`` /
     ``head_dim`` describe the layers that hold paged K/V (their count,
-    KV heads and head width), not the model's depth; left out (0) the
+    KV heads and head width), not the model's depth and not its layers of
+    weights (a looped model keeps one K/V layer a (loop step, layer):
+    ``models/ouro.py`` states 4 x 12 = 48 for 12); left out (0) the
     cache has NO ``"k"`` / ``"v"`` leaf and every page-indexed leaf is one
     of ``page_pools`` (an MLA model's one latent row a token).  A model scatters
     rows into a leaf and attends through
-    ``paged_*_attention(..., layer=li)``; it must not slice a layer out
+    ``paged_*_attention(..., layer=li)`` (``li`` a Python int, or a traced
+    scalar inside a program's loop); it must not slice a layer out
     (``cache["k"][li]`` is a layer-sized copy in every step on the chip).
 
     ``page_groups``: None, or an ordered ``{group: dict(window=None | W,
@@ -291,8 +294,9 @@ class DecodeModel:
     ``models.deepseek_v3.build_decode_model``,
     ``models.mellum.build_decode_model``,
     ``models.solar_open2.build_decode_model``,
-    ``models.afmoe.build_decode_model`` and
-    ``models.evabyte.build_decode_model`` are the in-repo producers.
+    ``models.afmoe.build_decode_model``,
+    ``models.evabyte.build_decode_model`` and
+    ``models.ouro.build_decode_model`` are the in-repo producers.
     """
 
     def __init__(self, decode_fn, prefill_chunk_fn, *, params=None,

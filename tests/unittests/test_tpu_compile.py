@@ -1087,3 +1087,132 @@ def test_sparse_attention_stages_compile_for_v5e(one_chip, no_persistent_cache,
         assert "%" + name in text
     if stage.startswith("select"):
         assert " sort(" not in text and "approx" not in text.lower()
+
+
+# ---------------------------------------------------------------------------
+# The LOOPED family (models/ouro.py) at the sizes of `ouro_standing_reasoning`
+# (chipbench/configs/ouro_2_6b.json): plain multi-head rows of 16 x 128 = 2048
+# lanes, 48 K/V layers from 12 layers of weights, and the K/V layer a TRACED
+# scalar (the loop over total_ut_steps is a loop in the program): the walk
+# takes it as a third prefetched scalar.  The whole step programs: 12 custom
+# calls in the loop's body, both pools (5.9 GB each) aliased and updated in
+# place across the loop's iterations - a copy of one would not fit the chip.
+# ---------------------------------------------------------------------------
+
+def _ouro_cfg():
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "chipbench/configs/ouro_2_6b.json")) as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("layer", ["traced", "static"])
+@pytest.mark.parametrize("form", ["decode", "chunk512", "chunk128"])
+def test_ouro_walks_compile_for_v5e(one_chip, no_persistent_cache, form,
+                                    layer):
+    cfg = _ouro_cfg()
+    H, Dh, ps = cfg["num_attention_heads"], cfg["head_dim"], cfg["page"]
+    S, mp = cfg["slots"], cfg["max_seq_len"] // cfg["page"]
+    layers = cfg["total_ut_steps"] * cfg["num_hidden_layers"]
+
+    def sds(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((layers, cfg["num_pages"], ps, H * Dh), jnp.bfloat16)
+    at = sds(()) if layer == "traced" else None
+
+    def pick(l):
+        return 37 if l is None else l
+
+    if form == "decode":
+        def fn(q, k, v, tables, lens, l=None):
+            return FA.paged_decode_attention(
+                q, k, v, tables, lens, layer=pick(l), impl="pallas",
+                interpret=False)
+        args = (sds((S, H, Dh), jnp.bfloat16), pool, pool, sds((S, mp)),
+                sds((S,)))
+    else:
+        C = int(form[5:])
+
+        def fn(q, k, v, pages, start, l=None):
+            return FA.paged_prefill_attention(
+                q, k, v, pages, start, layer=pick(l), impl="pallas",
+                interpret=False)
+        args = (sds((C, H, Dh), jnp.bfloat16), pool, pool, sds((mp,)),
+                sds(()))
+    args = args + ((at,) if at is not None else ())
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    # the traced layer is one more scalar operand of the call, not a slice
+    assert "dynamic-slice" not in text
+
+
+_OURO_PROGRAMS = ("decode", "chunk512")
+
+
+@pytest.fixture(scope="module")
+def ouro_programs(one_chip):
+    from paddle_tpu.models import ouro as O
+
+    cfg = _ouro_cfg()
+    S, ps = cfg["slots"], cfg["page"]
+    mp = cfg["max_seq_len"] // ps
+    layers = cfg["total_ut_steps"] * cfg["num_hidden_layers"]
+
+    def sds(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((layers, cfg["num_pages"], ps, 2048), jnp.bfloat16)
+    cache = {"k": pool, "v": pool}
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: O.params(cfg, 0)))
+
+    def decode(cache, params, tokens, positions, tables, lens):
+        return O.decode_step(params, tokens, positions, cache, tables, lens,
+                             cfg=cfg)
+
+    def chunk(cache, params, tokens, start, valid, written, row):
+        return O.prefill_chunk(params, tokens, start, valid, cache, written,
+                               row, jnp.int32(0), cfg=cfg)
+
+    args = {"decode": (decode, (sds((S,)), sds((S,)), sds((S, mp)),
+                                sds((S,)))),
+            "chunk512": (chunk, (sds((512,)), sds(()), sds(()),
+                                 sds((512 // ps,)), sds((mp,))))}
+    with _persistent_cache_off(), pytest.MonkeyPatch.context() as mp_:
+        mp_.setattr(FA, "cpu_backend", lambda: False)
+        return cfg, {name: jax.jit(fn, donate_argnums=(0,)).lower(
+            cache, params, *rest).compile() for name, (fn, rest) in
+            args.items()}
+
+
+@pytest.mark.parametrize("program", _OURO_PROGRAMS)
+def test_ouro_step_program_updates_both_pools_in_place(ouro_programs,
+                                                       program):
+    cfg, programs = ouro_programs
+    compiled = programs[program]
+    text = compiled.as_text()
+    L = cfg["num_hidden_layers"]
+    elems = (cfg["total_ut_steps"] * L * cfg["num_pages"] * cfg["page"]
+             * 2048)
+    # the loop is rolled: one walk a layer of WEIGHTS, in the loop's body
+    assert text.count('custom_call_target="tpu_custom_call"') == L
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert all("ouro.loop/while/body" in line and "ouro.attn" in line
+               for line in calls)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 2 * 2 * elems      # both bf16 pools
+    assert mem.temp_size_in_bytes < 64 * 2 ** 20
+    found = []
+    for m in re.finditer(r"%(\S+) = \w+\[([0-9,]+)\]\S* ([\w-]+)\(", text):
+        name, dims, opcode = m.groups()
+        n = int(np.prod([int(d) for d in dims.split(",")]))
+        if n in (elems, elems // (cfg["total_ut_steps"] * L)) and (
+                opcode in ("copy", "slice", "dynamic-slice")
+                or "copy" in name or "slice" in name):
+            found.append((name, opcode, dims))
+    assert found == []
