@@ -119,7 +119,14 @@ from .errors import (
 )
 from .kv_cache import PagedKVCache
 from .request_queue import Request, RequestQueue
-from .step_programs import StepPrograms
+from .step_programs import (
+    StepPrograms,
+    chunk_length,
+    int32_bits,
+    split_chunk,
+    split_step,
+    step_columns,
+)
 from .worker import RestartableWorker
 
 __all__ = ["DecodeModel", "DecodeConfig", "DecodeJournal",
@@ -135,6 +142,11 @@ _steps = _obs.counter("serving.decode.steps")
 _steps_overlapped = _obs.counter("serving.decode.steps_overlapped")
 _chunks_overlapped = _obs.counter("serving.decode.chunks_overlapped")
 _tokens_discarded = _obs.counter("serving.decode.tokens_discarded")
+# host arrays handed to the device a dispatch: ONE, the packed buffer
+# (``step_programs.py``), counted where it is made so that the next argument
+# someone adds is seen
+_host_uploads = {p: _obs.counter("serving.decode.host_uploads", {"program": p})
+                 for p in ("decode", "chunk")}
 _retired = _obs.counter("serving.decode.retired")
 _state_resets = _obs.counter("serving.cache.state_resets")
 _window_released = _obs.counter("serving.cache.window.pages_released")
@@ -599,7 +611,7 @@ class _Step:
     """One decode step from its plan to its commit.  ``entries`` are the
     ``(index, _Slot)`` pairs that decode in it — the OBJECTS, because the
     index may be reseated before the commit; ``args`` the program's
-    arguments but for ``previous`` (gone once dispatched); ``out`` the
+    packed buffer (gone once dispatched); ``out`` the
     dispatched step's output, still on the device (tokens, then the model's
     step counters); ``pools_before`` the cache pytree it took — what a retry
     rolls back to when the readback is lost, None under donation (consumed)
@@ -800,13 +812,12 @@ class DecodeScheduler:
             # decode steps dispatched and not yet read, oldest first: one
             # between iterations, two for a moment inside one (step n+1 goes
             # out, then step n is read); and what the decode program takes in
-            # its ``previous`` / ``from_previous`` places when nothing is in
-            # flight: every slot feeds the host's token
+            # its ``previous`` place when nothing is in flight (no slot's
+            # ``from_previous`` is set then: every slot feeds the host's token)
             self._unread = collections.deque()
             self._planned = []             # planned and not yet sent (None: replan)
-            self._no_previous = (
-                np.zeros((cfg.num_slots + len(model.step_counters),), np.int32),
-                np.zeros((cfg.num_slots,), np.bool_))
+            self._no_previous = np.zeros(
+                (cfg.num_slots + len(model.step_counters),), np.int32)
             # this scheduler's copy of the weights, on the device once: every
             # step takes it as an argument.  ``device`` (a pool's replica)
             # COMMITS weights and cache there, which is what keeps the worker
@@ -883,17 +894,30 @@ class DecodeScheduler:
                 lambda key: self._build_step(key, donate),
                 cap=2 * len(self.prefill_buckets) + 12, name="decode-steps")
             self._slots = [None] * cfg.num_slots
-            self._tables = np.zeros(
-                (cfg.num_slots, self._cache.max_pages_per_seq), np.int32)
-            # a table a further page group: the whole sequence's pages, or with
-            # a window a RING as wide as the most a slot holds live at once
-            # (logical page p in column p % width; released entries at scratch)
+            # a table a page group: the first's holds the whole sequence's
+            # pages, a further group's too or, with a window, a RING as wide as
+            # the most a slot holds live at once (logical page p in column
+            # p % width; released entries at scratch).  They are column VIEWS
+            # of one standing decode-step buffer (``step_programs.py``) whose
+            # other columns stay zero: a step's plan is one copy of it
             widest = max(self._chunk_widths())
             self._check_group_geometry()
-            self._more_tables = {
-                g: np.zeros((cfg.num_slots,
-                             grp.table_width(cfg.max_seq_len, widest)), np.int32)
-                for g, grp in self._cache.groups.items()}
+            widths = (self._cache.max_pages_per_seq,) + tuple(
+                grp.table_width(cfg.max_seq_len, widest)
+                for grp in self._cache.groups.values())
+            # what the programs are told of the layout (static): a table's
+            # width where there are several (one is as wide as the row
+            # leaves), and a chunk width's ``(written, gathered)`` lengths
+            self._widths = widths if len(widths) > 1 else None
+            self._chunk_sizes = {
+                w: tuple((max(1, w // self._cache.group_page_size(g)), width)
+                         for g, width in zip(self._cache.group_names, widths))
+                for w in self._chunk_widths()}
+            self._standing = np.zeros(
+                (cfg.num_slots, step_columns(widths)), np.int32)
+            self._group_tables = split_step(self._standing, self._widths)[0]
+            self._tables, *more = self._group_tables
+            self._more_tables = dict(zip(self._cache.groups, more))
             self._widest_chunk = widest
             self._hol = None               # head-of-line request awaiting pages
             # the loop's own account (``_note_commit``): the worker's frame and
@@ -1005,18 +1029,61 @@ class DecodeScheduler:
         return tuple(sorted({b for b in self.prefill_buckets if b < ct}
                             | {ct}))
 
-    def _idle_decode_args(self):
-        """The decode program's arguments before ``previous`` with nobody
-        seated: every slot at scratch."""
-        import jax.numpy as jnp
+    def _step_buffer(self, standing):
+        """The buffer of one decode dispatch, the ONE host array it hands the
+        device (it goes into the jitted call as numpy: no transfer of its
+        own): a copy of the standing one, which holds the tables as they
+        are, or zeros of its shape."""
+        _host_uploads["decode"].inc()
+        return (self._standing.copy() if standing
+                else np.zeros_like(self._standing))
 
-        slots = self.config.num_slots
-        return (self._params, self._cache.pools,
-                jnp.zeros((slots,), jnp.int32), jnp.zeros((slots,), jnp.int32),
-                self._by_group(self._tables, self._more_tables),
-                jnp.zeros((slots,), jnp.int32),
-                jnp.zeros((slots,), jnp.uint32),
-                jnp.zeros((slots,), jnp.float32))
+    def _chunk_buffer(self, width):
+        """``(buf, sizes, split_chunk(buf, sizes))``: the buffer of one
+        dispatch of the chunk program ``width`` wide (zeros), the ONE host
+        array it hands the device, its static layout and its parts."""
+        _host_uploads["chunk"].inc()
+        sizes = self._chunk_sizes[width]
+        buf = np.zeros((chunk_length(width, sizes),), np.int32)
+        return buf, sizes, split_chunk(buf, sizes)
+
+    def _pack_step(self, tokens, positions, tables, kv_lens, seeds, temps,
+                   from_previous=None):
+        """A decode step's buffer from the step's values a vector; ``tables``
+        as the model receives them.  The loop (``_plan_step``) writes the
+        same views in place."""
+        buf = self._step_buffer(standing=False)
+        views, columns = split_step(buf, self._widths)
+        for view, table in zip(views, self._group_list(tables)):
+            view[:] = np.asarray(table)
+        for column, vector, dtype in zip(
+                columns,
+                (tokens, positions, kv_lens, seeds, temps, from_previous),
+                (np.int32, np.int32, np.int32, np.uint32, np.float32,
+                 np.int32)):
+            if vector is not None:
+                column[:] = int32_bits(vector, dtype)
+        return buf
+
+    def _pack_chunk(self, width, tokens, start, valid, written, gathered,
+                    slot, seed, temp):
+        """``(buf, sizes)`` of a chunk from its values; ``written`` and
+        ``gathered`` as the model receives them."""
+        buf, sizes, (row, scalars, vecs) = self._chunk_buffer(width)
+        row[:] = np.asarray(tokens)
+        scalars[:] = (int(start), int(valid), int(slot),
+                      int32_bits(seed, np.uint32), int32_bits(temp, np.float32))
+        for (w, g), pages, table in zip(vecs, self._group_list(written),
+                                        self._group_list(gathered)):
+            w[:], g[:] = np.asarray(pages), np.asarray(table)
+        return buf, sizes
+
+    def _group_list(self, arg):
+        """A per-group argument as the model receives it (the array itself,
+        or ``{group: array}``) as a list in the cache's group order."""
+        if not self.model.page_groups:
+            return [arg]
+        return [arg[g] for g in self._cache.group_names]
 
     def decode_program_text(self):
         """The compiled decode program as text: every device instruction's
@@ -1026,7 +1093,8 @@ class DecodeScheduler:
         apart.  Lowers and compiles the warmed program's shapes once more
         (the persistent cache answers where there is one)."""
         return self._jit.get(("decode",)).lower(
-            *self._idle_decode_args(), *self._no_previous).compile().as_text()
+            self._params, self._cache.pools, np.zeros_like(self._standing),
+            self._no_previous, widths=self._widths).compile().as_text()
 
     def warmup(self):
         """Compile the decode step and every prefill width against the
@@ -1041,24 +1109,18 @@ class DecodeScheduler:
             # twice: ``previous`` is a host array when nothing is in flight
             # and the step before's own output when one is, and jax keys an
             # executable on where an argument lives as well as on its shape
-            toks, nobody = self._no_previous
+            toks = self._no_previous
             for _ in range(2):
                 toks, cache.pools = step(
-                    *self._idle_decode_args(), toks, nobody)
+                    params, cache.pools, self._step_buffer(standing=False),
+                    toks, widths=self._widths)
             np.asarray(toks)
             for w in self._chunk_widths():
-                fn = self._jit.get(("chunk", w))
-                written = {g: self._chunk_page_vec(g, 0, w, lambda p: 0)
-                           for g in cache.group_names}
-                toks, cache.pools = fn(
-                    params, cache.pools,
-                    jnp.zeros((w,), jnp.int32), jnp.int32(0),
-                    jnp.int32(1),
-                    self._by_group(written[cache.primary_group], {
-                        g: written[g] for g in self._more_tables}),
-                    self._by_group(self._tables[0], {
-                        g: t[0] for g, t in self._more_tables.items()}),
-                    np.int32(0), jnp.uint32(0), jnp.float32(0))
+                # every page at scratch, one valid token
+                buf, sizes, (_, scalars, _) = self._chunk_buffer(w)
+                scalars[1] = 1
+                toks, cache.pools = self._jit.get(("chunk", w))(
+                    params, cache.pools, buf, sizes=sizes)
                 np.asarray(toks)
             if cfg.kv_guard:
                 # one guard program per page-vector length the runtime
@@ -1119,19 +1181,6 @@ class DecodeScheduler:
         return self._cache
 
     # -- page groups ---------------------------------------------------------
-    def _by_group(self, first, more):
-        """What a step program receives for a per-group argument: ``first``
-        itself for a model that states no groups (the programs every model
-        had), else ``{group: array}`` with ``first`` under the first
-        group's name."""
-        import jax.numpy as jnp
-
-        if not self.model.page_groups:
-            return jnp.asarray(first)
-        out = {self._cache.primary_group: jnp.asarray(first)}
-        out.update((g, jnp.asarray(a)) for g, a in more.items())
-        return out
-
     def _check_group_geometry(self):
         """A chunk lies on whole pages of every group or inside one page of
         it, and never straddles a multiple of an aligned window: chunks
@@ -1155,16 +1204,14 @@ class DecodeScheduler:
                        ", or straddle a multiple of the window"
                        if aligned else ""))
 
-    def _chunk_page_vec(self, group, start, width, column):
-        """The pages of ``group`` that a chunk ``[start, start + width)``
-        writes: ``width // page_size`` of them in order (one where the page
-        is wider than the chunk), ``column(p)`` the page that holds logical
-        page ``p`` (0: none, the rows scatter to scratch)."""
+    def _chunk_page_vec(self, vec, group, start, column):
+        """Fill ``vec`` with the pages of ``group`` that a chunk from
+        ``start`` on writes: ``width // page_size`` of them in order (one
+        where the page is wider than the chunk), ``column(p)`` the page that
+        holds logical page ``p`` (0: none, the rows scatter to scratch)."""
         ps = self._cache.group_page_size(group)
-        vec = np.zeros((max(1, width // ps),), np.int32)
         for i in range(len(vec)):
             vec[i] = column(start // ps + i)
-        return vec
 
     def _group_needs(self, req):
         """Pages ``req`` reserves in each further group."""
@@ -1224,21 +1271,33 @@ class DecodeScheduler:
     def run_step(self, key, *args):
         """One dispatch of this scheduler's OWN compiled step program
         ``key`` (``("decode",)`` or ``("chunk", width)``, as warmed up and
-        served) on its own weights and cache: ``program(params,
-        cache.pools, *args)`` with ``args`` as :meth:`warmup` gives them,
-        the cache updated in place as the loop does; a decode step given
-        without its last two arguments feeds every slot ``tokens`` (no step
-        in flight before it).  Returns the program's first output (the
-        tokens).  For a check or a tool that must read what the SERVED
-        executables leave in the SERVED cache; refused while the worker,
-        which owns the cache, is alive."""
+        served) on its own weights and cache, the cache updated in place as
+        the loop does.  ``args`` are the step's values one by one, host or
+        device arrays, as the model's own function names them: a decode
+        step's ``(tokens, positions, page_tables, kv_lens, seeds, temps)``,
+        then ``previous, from_previous`` or neither (every slot feeds
+        ``tokens``: no step in flight before it); a chunk's ``(tokens, start,
+        valid, chunk_pages, gather_pages, slot, seed, temp)``.  They are
+        packed into the one buffer the program takes, as the loop packs its
+        own.  Returns the program's first output (the tokens).  For a check
+        or a tool that must read what the SERVED executables leave in the
+        SERVED cache; refused while the worker, which owns the cache, is
+        alive."""
         if self.alive:
             raise ServingError(
                 "run_step: the worker thread owns the cache; stop() first")
-        if tuple(key) == ("decode",) and len(args) == 6:
-            args += self._no_previous
-        out, self._cache.pools = self._jit.get(tuple(key))(
-            self._params, self._cache.pools, *args)
+        key = tuple(key)
+        if key == ("decode",):
+            *values, previous, from_previous = (
+                args if len(args) == 8 else args + (self._no_previous, None))
+            out, self._cache.pools = self._jit.get(key)(
+                self._params, self._cache.pools,
+                self._pack_step(*values, from_previous), previous,
+                widths=self._widths)
+        else:
+            packed, sizes = self._pack_chunk(key[1], *args)
+            out, self._cache.pools = self._jit.get(key)(
+                self._params, self._cache.pools, packed, sizes=sizes)
         return out
 
     def fail_pending(self, exc):
@@ -1909,8 +1968,6 @@ class DecodeScheduler:
         writes); its token is read and the slot moved on in
         :meth:`_read_chunk`, in the same iteration.  Returns the chunk in
         flight, None where its dispatch failed for good."""
-        import jax.numpy as jnp
-
         tel = self._telemetry
         self._chunk_rode = True
         with tel.span("serving.decode.chunk.build"):
@@ -1924,8 +1981,8 @@ class DecodeScheduler:
             remaining = req.prompt_len - start
             width = self._chunk_width_for(remaining)
             valid = min(remaining, width)
-            ps = self._cache.page_size
-            tokens = np.zeros((width,), np.int32)
+            packed, sizes, (tokens, scalars, vecs) = self._chunk_buffer(
+                width)
             tokens[:valid] = req.prompt[start:start + valid]
             # pages this chunk writes, a group: the prompt's pages covering
             # [start, start + width), by the group's own table and page size
@@ -1943,16 +2000,15 @@ class DecodeScheduler:
                 table = self._more_tables[g]
                 return table[idx, p % table.shape[1]]
 
-            vecs = {g: self._chunk_page_vec(g, start, width,
-                                            functools.partial(held, g))
-                    for g in cache.group_names}
-            chunk_vec = vecs[cache.primary_group]
-            written = self._by_group(
-                chunk_vec, {g: vecs[g] for g in self._more_tables})
-            gathered = self._by_group(self._tables[idx], {
-                g: t[idx] for g, t in self._more_tables.items()})
-            fn = self._jit.get(("chunk", width))
+            for g, table, (written, gathered) in zip(
+                    cache.group_names, self._group_tables, vecs):
+                self._chunk_page_vec(written, g, start,
+                                     functools.partial(held, g))
+                gathered[:] = table[idx]
             temp, seed = self._sampling_params(req)
+            scalars[:] = (start, valid, idx, int32_bits(seed, np.uint32),
+                          int32_bits(temp, np.float32))
+            fn = self._jit.get(("chunk", width))
 
         def attempt():
             # the chaos choke point is consulted per ATTEMPT (a retry is
@@ -1961,16 +2017,13 @@ class DecodeScheduler:
             if serve_fault is not None:
                 serve_fault([req])
             with tel.span("serving.decode.prefill.dispatch"):
-                return fn(
-                    self._params, self._cache.pools,
-                    jnp.asarray(tokens), jnp.int32(start),
-                    jnp.int32(valid), written, gathered, np.int32(idx),
-                    seed, temp)
+                return fn(self._params, self._cache.pools, packed,
+                          sizes=sizes)
 
         # what the iteration pays for the chunk: its dispatch (retries
         # included) here and the block on its token in ``_read_chunk``, one
         # span in two parts
-        sent = _Chunk(idx, slot, start, valid, width, chunk_vec, tel.span(
+        sent = _Chunk(idx, slot, start, valid, width, vecs[0][0], tel.span(
             "serving.decode.prefill", bucket=width, rows=valid, start=start,
             seq=req.seq))
         try:
@@ -2351,10 +2404,13 @@ class DecodeScheduler:
         its dispatched length ``kv_len + inflight`` and takes its token from
         that step's output on the device; a slot whose committed and
         in-flight tokens reach ``max_new_tokens`` is not in the step.  The
-        planned step counts as in flight for its slots from here on."""
-        import jax.numpy as jnp
+        planned step counts as in flight for its slots from here on.
 
-        cfg = self.config
+        The plan is ONE buffer (``step_programs.py``): a copy of the
+        standing one, which holds every group's table (the copy the step
+        needs anyway: the program may run behind the host, which rewrites
+        the tables while it is in flight), with the slots' values written
+        into its other columns."""
         with self._telemetry.span("serving.decode.step.build") as build:
             entries = [(i, s) for i, s in enumerate(self._slots)
                        if s is not None and not s.prefilling
@@ -2363,47 +2419,38 @@ class DecodeScheduler:
             if not entries:
                 build.name = None      # no step: the span closes into no cell
                 return None
-            tokens = np.zeros((cfg.num_slots,), np.int32)
-            positions = np.zeros((cfg.num_slots,), np.int32)
-            kv_lens = np.zeros((cfg.num_slots,), np.int32)
-            seeds = np.zeros((cfg.num_slots,), np.uint32)
-            temps = np.zeros((cfg.num_slots,), np.float32)
-            from_previous = np.zeros((cfg.num_slots,), np.bool_)
+            if self._more_tables:
+                # the page the new token lands on, in every further group
+                for i, slot in entries:
+                    self._ensure_pages(i, slot, slot.kv_len + slot.inflight + 1)
+            buf = self._step_buffer(standing=True)
+            tokens, positions, kv_lens, seeds, temps, from_previous = (
+                split_step(buf, self._widths)[1])
+            seeds, temps = seeds.view(np.uint32), temps.view(np.float32)
             for i, slot in entries:
                 at = slot.kv_len + slot.inflight
                 if slot.inflight:
-                    from_previous[i] = True      # its token is on the device
+                    from_previous[i] = 1         # its token is on the device
                 else:
                     tokens[i] = slot.generated[-1]   # the last sampled token
                 positions[i] = at                # ... at the next cache index
                 kv_lens[i] = at + 1              # visible kv incl. this token
                 temps[i], seeds[i] = self._sampling_params(slot.req)
-                # the page the new token lands on, in every further group
-                self._ensure_pages(i, slot, at + 1)
                 slot.inflight += 1
             _walked_pages.inc(int(np.sum(
                 -(-kv_lens // self._cache.page_size))))
             _table_pages.inc(self._tables.size)
-            # COPIES: the program may run behind the host (on the CPU it
-            # reads a numpy argument in place), and the tables are rewritten
-            # while it is in flight.  The decode step scatters EVERY slot's
-            # token k/v at page_tables[s, 0] offset 0 when positions[s] == 0
-            # — a seated slot that does not decode in this step (prefilling,
-            # or at its length with its last token in flight) points at real
-            # (possibly SHARED prefix) pages, so its dispatch row must aim
-            # at scratch like an empty slot's or the write corrupts position
-            # 0 of its (or a prefix neighbor's) cache
+            # The decode step scatters EVERY slot's token k/v at
+            # page_tables[s, 0] offset 0 when positions[s] == 0 — a seated
+            # slot that does not decode in this step (prefilling, or at its
+            # length with its last token in flight) points at real (possibly
+            # SHARED prefix) pages, so its dispatch row must aim at scratch
+            # like an empty slot's or the write corrupts position 0 of its
+            # (or a prefix neighbor's) cache.  (Its other columns are zero.)
             idle = [i for i, s in enumerate(self._slots)
                     if s is not None and not kv_lens[i]]
-            tables = self._tables.copy()
-            tables[idle] = 0
-            more_tables = {g: t.copy() for g, t in self._more_tables.items()}
-            for t in more_tables.values():
-                t[idle] = 0
-            return _Step(entries, (
-                jnp.asarray(tokens), jnp.asarray(positions),
-                self._by_group(tables, more_tables), jnp.asarray(kv_lens),
-                jnp.asarray(seeds), jnp.asarray(temps), from_previous))
+            buf[idle] = 0
+            return _Step(entries, buf)
 
     def _plan_steps(self):
         """What this iteration dispatches: the next step, and with nothing
@@ -2422,12 +2469,12 @@ class DecodeScheduler:
     def _dispatch_step(self, plan):
         """Send one planned step behind whatever is in flight."""
         with self._telemetry.span("serving.decode.step.dispatch"):
-            *args, from_previous = plan.args
             previous = (self._unread[-1].out if self._unread
-                        else self._no_previous[0])
+                        else self._no_previous)
             before = self._cache.pools
             plan.out, pools = self._jit.get(("decode",))(
-                self._params, before, *args, previous, from_previous)
+                self._params, before, plan.args, previous,
+                widths=self._widths)
             # the donated pytree always belongs to the newest dispatch
             self._cache.pools = pools
             plan.args = None

@@ -549,20 +549,12 @@ def _tap(sched, events):
     sched._plan_step, sched._dispatch_step = tapped_plan, tapped_dispatch
 
 
-def _beside_a_decoder(sched, prompts, first_new=40, new=4):
-    """One request decodes; the others arrive beside it."""
-    first = sched.submit(prompts[0], max_new_tokens=first_new)
-    while len(first.token_times) < 3:
-        time.sleep(0.002)
-    return [first] + [sched.submit(p, max_new_tokens=new)
-                      for p in prompts[1:]]
-
-
-def _arrive_behind_a_commit(sched, prompts, first_new, new):
-    """As :func:`_beside_a_decoder`, on the scheduler's own clock: the worker
-    waits behind the commit that gives the first request its third token
-    until the others are in the queue, so they arrive beside a decoder
-    however the host schedules the two threads."""
+def _arrive_behind_a_commit(sched, prompts, first_new=40, new=4):
+    """One request decodes; the others arrive beside it, on the scheduler's
+    own clock: the worker waits behind the commit that gives the first
+    request its third token until the others are in the queue, so they arrive
+    beside a decoder however fast the loop is and however the host schedules
+    the two threads."""
     reached, arrived = threading.Event(), threading.Event()
     commit, first = sched._commit_step, []
 
@@ -606,7 +598,7 @@ class TestChunkReadBehindAStep:
                 for p in self.PROMPTS:
                     sched.generate(p, max_new_tokens=4, timeout=120)
             else:
-                for f in _beside_a_decoder(sched, self.PROMPTS):
+                for f in _arrive_behind_a_commit(sched, self.PROMPTS):
                     f.result(timeout=120)
         finally:
             sched.stop()
@@ -632,8 +624,8 @@ class TestChunkReadBehindAStep:
         _tap(sched, events)
         sched.start()
         try:
-            futs = _beside_a_decoder(sched, self.PROMPTS[:2], first_new=16,
-                                     new=6)
+            futs = _arrive_behind_a_commit(sched, self.PROMPTS[:2],
+                                           first_new=16, new=6)
             got = [f.result(timeout=120) for f in futs]
         finally:
             sched.stop()
@@ -744,7 +736,8 @@ class TestChunkReadBehindAStep:
         sched._send_chunk, sched._dispatch_step = noting_send, deadly_dispatch
         sched.start()
         try:
-            futs = _beside_a_decoder(sched, self.PROMPTS[:2], first_new=12)
+            futs = _arrive_behind_a_commit(sched, self.PROMPTS[:2],
+                                           first_new=12)
             deadline = time.time() + 30
             while sched.alive and time.time() < deadline:
                 time.sleep(0.005)
@@ -858,6 +851,262 @@ class TestStepProgramsAreTheModels:
         assert cells[1].value - before[1] == 1
         assert cells[0].value - before[0] >= 3
         assert obs.counter("serving.decode.toy.rides").value == plain
+
+
+# -- one host upload a dispatch (ISSUE 59) -----------------------------------
+
+class _ByArgument:
+    """The step programs as they were before ISSUE 59, a value an argument:
+    the reference the packed ones (``step_programs.py``) are held to."""
+
+    def __init__(self, model, top_k):
+        import jax.numpy as jnp
+
+        from paddle_tpu.serving.step_programs import sample_token
+
+        def decode(params, pools, tokens, positions, tables, kv_lens, seeds,
+                   temps, previous, from_previous):
+            tokens = jnp.where(from_previous, previous[:tokens.shape[0]],
+                               tokens)
+            logits, pools, *_ = model.decode_fn(
+                params, tokens, positions, pools, tables, kv_lens)
+
+            def samp(logit, seed, pos, temp):
+                k = jax.random.fold_in(jax.random.PRNGKey(seed), pos)
+                return sample_token(logit, k, temp, top_k)
+
+            return jax.vmap(samp)(logits, seeds, kv_lens, temps), pools
+
+        def chunk(params, pools, tokens, start, valid, chunk_pages,
+                  gather_pages, slot, seed, temp):
+            logits, pools, *_ = model.prefill_chunk_fn(
+                params, tokens, start, valid, pools, chunk_pages,
+                gather_pages, slot)
+            kk = jax.random.fold_in(jax.random.PRNGKey(seed), start + valid)
+            return sample_token(logits, kk, temp, top_k), pools
+
+        self.decode, self.chunk = jax.jit(decode), jax.jit(chunk)
+
+
+def _serve_by_argument(sched, monkeypatch, mixed):
+    """Make ``sched``'s loop dispatch ``_ByArgument``'s programs: its packed
+    buffers are taken apart on the HOST and handed over a value an argument.
+    ``mixed`` gathers the decode steps in which one live slot took its token
+    from the step before and another from the host."""
+    from paddle_tpu.serving.step_programs import split_chunk, split_step
+
+    ref = _ByArgument(sched.model, sched.config.top_k)
+    groups = tuple(sched.model.page_groups)
+
+    def by_group(arrays):
+        arrays = [np.ascontiguousarray(a) for a in arrays]
+        return dict(zip(groups, arrays)) if groups else arrays[0]
+
+    def decode(params, pools, packed, previous, widths=None):
+        tables, columns = split_step(np.asarray(packed), widths)
+        tokens, positions, kv_lens, seeds, temps, from_previous = (
+            np.ascontiguousarray(c) for c in columns)
+        live = from_previous[kv_lens > 0]
+        if live.any() and not live.all():
+            mixed.append(live)
+        return ref.decode(params, pools, tokens, positions, by_group(tables),
+                          kv_lens, seeds.view(np.uint32),
+                          temps.view(np.float32), previous,
+                          from_previous != 0)
+
+    def chunk(params, pools, packed, sizes):
+        tokens, scalars, vecs = split_chunk(np.asarray(packed), sizes)
+        start, valid, slot = (np.int32(x) for x in scalars[:3])
+        seed, temp = np.ascontiguousarray(scalars[3:])[:, None]
+        return ref.chunk(params, pools, np.ascontiguousarray(tokens), start,
+                         valid, by_group([w for w, _ in vecs]),
+                         by_group([g for _, g in vecs]), slot,
+                         seed.view(np.uint32)[0], temp.view(np.float32)[0])
+
+    monkeypatch.setattr(
+        sched._jit, "get",
+        lambda key: decode if tuple(key) == ("decode",) else chunk)
+
+
+def _toy_groups():
+    import test_kv_cache_groups as G
+
+    return G._model(), G._config(num_slots=3, max_new_tokens=12), G.V
+
+
+def _upload_scenario(name, decode_model):
+    """``(model, config, waves)``: ``waves`` are lists of ``(prompt,
+    submit kwargs)`` sent together, each wave behind the one before."""
+    rng = np.random.RandomState(59)
+    model, cfg, vocab = decode_model, _cfg(prefill_chunk_tokens=16), 50
+    if name == "two_groups":
+        model, cfg, vocab = _toy_groups()
+
+    def some(n, **kw):
+        return [(p, dict(kw)) for p in _prompts(n, rng, vocab=vocab)]
+
+    if name == "greedy" or name == "two_groups":
+        return model, cfg, [some(6)]
+    if name == "sampled":
+        return model, cfg, [[(p, dict(temperature=0.6 + 0.2 * i,
+                                      seed=(7919 * i + 2 ** 31) % 2 ** 32))
+                             for i, (p, _) in enumerate(some(6))]]
+    if name == "prefix_hit":
+        head = rng.randint(1, 50, size=24).astype(np.int32)
+        tails = _prompts(3, rng, lo=2, hi=9)
+        return model, _cfg(prefill_chunk_tokens=16, prefix_cache=True), [
+            [(np.concatenate([head, t]), {})] for t in tails]
+    assert name == "sits_out"
+    # answers of different lengths over prompts of one to three chunks: a
+    # slot joins, and another reaches its length, while the others decode
+    return model, cfg, [[
+        (p, dict(max_new_tokens=n, temperature=t, seed=11 + n))
+        for (p, _), n, t in zip(some(6), (3, 12, 5, 9, 2, 7),
+                                (0.0, 0.8, 0.0, 1.1, 0.7, 0.0))]]
+
+
+class TestOneUploadADispatch:
+    @pytest.mark.parametrize("scenario", [
+        "greedy", "sampled", "prefix_hit", "sits_out", "two_groups"])
+    def test_packed_programs_serve_the_tokens_of_a_value_an_argument(
+            self, decode_model, monkeypatch, scenario):
+        """The same requests through the packed programs and through the
+        programs that took every value as an argument of its own: bitwise
+        the same tokens, for greedy and sampled requests, behind a
+        prefix-cache hit, with slots joining and leaving between steps, and
+        for a model in two page groups."""
+        model, cfg, waves = _upload_scenario(scenario, decode_model)
+        hits = obs.counter("serving.decode.kv_hit_pages")
+        served, mixed = {}, []
+        for form in ("packed", "by_argument"):
+            hit0 = hits.value
+            sched = serving.DecodeScheduler(model, cfg, autostart=False)
+            if form == "by_argument":
+                _serve_by_argument(sched, monkeypatch, mixed)
+            sched.start()
+            try:
+                served[form] = [
+                    [f.result(timeout=120).tobytes() for f in
+                     [sched.submit(p, **kw) for p, kw in wave]]
+                    for wave in waves]
+            finally:
+                sched.stop()
+            if scenario == "prefix_hit":
+                assert hits.value - hit0 >= 2 * (24 // cfg.page_size)
+        assert served["packed"] == served["by_argument"]
+        if scenario != "prefix_hit":
+            assert mixed, "no step mixed host tokens with the device's"
+
+    def test_every_dispatch_uploads_one_host_array(self, decode_model):
+        """``serving.decode.host_uploads`` moves by one a decode dispatch and
+        by one a chunk dispatch, warm-up's included: the buffer is the only
+        host array a step program is handed."""
+        cells = {p: obs.counter("serving.decode.host_uploads", {"program": p})
+                 for p in ("decode", "chunk")}
+        spans = {"decode": obs.histogram("serving.decode.step.dispatch"),
+                 "chunk": obs.histogram("serving.decode.prefill.dispatch")}
+        before = {p: c.value for p, c in cells.items()}
+        sched = serving.DecodeScheduler(
+            decode_model, _cfg(prefill_chunk_tokens=16), autostart=False)
+        warmed = {"decode": 2, "chunk": len(sched._chunk_widths())}
+        assert {p: c.value - before[p] for p, c in cells.items()} == warmed
+        sent = {p: h.snapshot().count for p, h in spans.items()}
+        sched.start()
+        rng = np.random.RandomState(2)
+        for f in [sched.submit(p) for p in _prompts(5, rng, hi=40)]:
+            f.result(timeout=120)
+        sched.stop()
+        for p, cell in cells.items():
+            dispatched = spans[p].snapshot().count - sent[p]
+            assert dispatched > 0
+            assert cell.value - before[p] - warmed[p] == dispatched, p
+
+    @pytest.mark.parametrize("arrays", ["host", "device"])
+    def test_run_step_by_argument_leaves_the_loops_cache_rows(
+            self, decode_model, arrays):
+        """``run_step`` keeps its argument lists (a check hands it the values
+        one by one, as host or device arrays) and packs them as the loop
+        does: a prompt prefilled and decoded through it leaves, bit for bit,
+        the rows and the tokens that the loop leaves."""
+        import jax.numpy as jnp
+
+        put = np.asarray if arrays == "host" else jnp.asarray
+        cfg = _cfg(prefill_chunk_tokens=16)
+        prompt = np.arange(3, 3 + 21, dtype=np.int32) % 50
+        new, temp, seed = 6, 0.9, 2 ** 32 - 5
+
+        loop = serving.DecodeScheduler(decode_model, cfg, autostart=False)
+        took, alloc = [], loop.cache.alloc
+        loop.cache.alloc = lambda n: took.append(alloc(n)) or took[-1]
+        loop.start()
+        want = loop.generate(prompt, max_new_tokens=new, temperature=temp,
+                             seed=seed, timeout=120)
+        loop.stop()
+        (pages,) = took
+
+        sched = serving.DecodeScheduler(decode_model, cfg, autostart=False)
+        cache, S = sched.cache, cfg.num_slots
+        assert cache.alloc(len(pages)) == pages
+        tables = np.zeros((S, cache.max_pages_per_seq), np.int32)
+        tables[1] = cache.table_row(pages)          # seated in slot 1
+        ps, got = cfg.page_size, []
+        for start, width in ((0, 16), (16, 8)):
+            valid = min(width, len(prompt) - start)
+            tokens = np.zeros((width,), np.int32)
+            tokens[:valid] = prompt[start:start + valid]
+            tok = sched.run_step(
+                ("chunk", width), put(tokens), put(np.int32(start)),
+                put(np.int32(valid)),
+                put(np.asarray(pages[start // ps:(start + width) // ps],
+                               np.int32)),
+                put(tables[1]), np.int32(1), put(np.uint32(seed)),
+                put(np.float32(temp)))
+        got.append(int(tok))
+        zeros = np.zeros((S,), np.int32)
+        seeds = np.full((S,), seed, np.uint32)
+        temps = np.full((S,), temp, np.float32)
+        for at in range(len(prompt), len(prompt) + new - 1):
+            tokens, positions, kv_lens = (zeros.copy() for _ in range(3))
+            tokens[1], positions[1], kv_lens[1] = got[-1], at, at + 1
+            step_tables = np.zeros_like(tables)
+            step_tables[1] = tables[1]
+            out = sched.run_step(
+                ("decode",), put(tokens), put(positions), put(step_tables),
+                put(kv_lens), put(seeds), put(temps))
+            got.append(int(np.asarray(out)[1]))
+        assert got == list(want)
+        rows = len(prompt) + new - 1
+        for leaf in ("k", "v"):
+            a, b = (np.asarray(c.pools[leaf])[:, pages].reshape(
+                decode_model.num_layers, -1, c.pools[leaf].shape[-1])[:, :rows]
+                for c in (loop.cache, cache))
+            np.testing.assert_array_equal(a, b)
+
+    def test_no_compile_in_the_loop_for_any_chunk_width(self, decode_model):
+        """After ``warmup()`` the loop asks jax for no compile, whatever
+        chunk width a prompt's remainder takes: warm-up's buffers live where
+        the loop's do."""
+        obs.watch_compiles()
+        asked = obs.counter("xla.compile.requests",
+                            {"within": "serving.decode.iteration"})
+        sched = serving.DecodeScheduler(
+            decode_model, _cfg(prefill_chunk_tokens=32, max_seq_len=128),
+            autostart=False)
+        widths = sched._chunk_widths()
+        assert widths == (8, 16, 32)
+        before, keys, get = asked.value, set(), sched._jit.get
+        sched._jit.get = lambda key: keys.add(tuple(key)) or get(key)
+        sched.start()
+        rng = np.random.RandomState(8)
+        futs = [sched.submit(rng.randint(1, 50, size=n).astype(np.int32),
+                             temperature=t, seed=n)
+                for n, t in ((5, 0.0), (12, 0.7), (30, 0.0), (32 + 11, 1.0),
+                             (64 + 3, 0.0))]
+        for f in futs:
+            assert len(f.result(timeout=120)) == 8
+        sched.stop()
+        assert keys == {("decode",)} | {("chunk", w) for w in widths}
+        assert asked.value == before
 
 
 # -- engine integration ------------------------------------------------------

@@ -20,6 +20,7 @@ from paddle_tpu import observability as obs
 from paddle_tpu import serving
 from paddle_tpu.serving import kv_cache
 from paddle_tpu.serving.errors import ServingError
+from paddle_tpu.serving.step_programs import STEP_COLUMNS, split_step
 
 PS, W, V = 4, 6, 32     # page, window, vocabulary
 
@@ -319,12 +320,20 @@ def test_a_released_page_poisoned_in_anothers_hands_changes_nothing():
     assert grp.stats()["rc_errors"] == []
 
 
+def _slowed(sched, nap=0.005):
+    """``sched`` with a nap at every step's readback: a request stays seated
+    long enough for another thread to see it there."""
+    read = sched._read_step
+    sched._read_step = lambda sent: (time.sleep(nap), read(sent))[1]
+    return sched
+
+
 def test_admission_waits_while_either_group_is_short():
     """The window group holds ONE slot's bound: the second request parks at
     the head of the line though the full group has room, and is admitted when
     the first retires; then the other way round."""
-    sched = serving.DecodeScheduler(
-        _model(), _config(num_pages={"full": 33, "window": 6}))
+    sched = _slowed(serving.DecodeScheduler(
+        _model(), _config(num_pages={"full": 33, "window": 6})))
     a = sched.submit(np.arange(1, 10, dtype=np.int32), max_new_tokens=30)
     b = sched.submit(np.arange(1, 6, dtype=np.int32), max_new_tokens=2)
     deadline = time.time() + 60
@@ -336,8 +345,8 @@ def test_admission_waits_while_either_group_is_short():
     assert len(b.result(timeout=120)) == 2
     sched.stop()
     # the full group short: 40 + 4 positions need 11 pages of its 12 usable
-    sched = serving.DecodeScheduler(
-        _model(), _config(num_pages={"full": 13, "window": 11}))
+    sched = _slowed(serving.DecodeScheduler(
+        _model(), _config(num_pages={"full": 13, "window": 11})))
     a = sched.submit(np.arange(1, 31, dtype=np.int32), max_new_tokens=14)
     b = sched.submit(np.arange(1, 6, dtype=np.int32), max_new_tokens=2)
     while not a.token_times and time.time() < deadline:
@@ -362,7 +371,7 @@ def test_a_request_no_group_can_ever_hold_fails_at_once():
 
 @pytest.mark.parametrize("how", ["cancel", "recover_pools", "evict"])
 def test_every_way_out_leaves_both_free_lists_whole(how):
-    sched = serving.DecodeScheduler(_model(), _config())
+    sched = _slowed(serving.DecodeScheduler(_model(), _config()))
     futs = [sched.submit(np.arange(1, 20, dtype=np.int32) % V,
                          max_new_tokens=40) for _ in range(2)]
     deadline = time.time() + 60
@@ -589,8 +598,8 @@ def test_the_scheduler_fills_an_aligned_window_and_gives_it_back_whole():
     def watch_plan():
         step = plan()
         if step is not None:
-            table, lens = np.asarray(step.args[2]["window"]), np.asarray(
-                step.args[3])
+            (_, table), columns = split_step(step.args, sched._widths)
+            lens = columns[STEP_COLUMNS.index("kv_lens")]
             for i, slot in step.entries:
                 held_most[0] = max(held_most[0],
                                    len(slot.more["window"].pages))

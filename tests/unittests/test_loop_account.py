@@ -24,6 +24,8 @@ from paddle_tpu.models import transformer as T  # noqa: E402
 from paddle_tpu.serving import decode_scheduler as ds  # noqa: E402
 from paddle_tpu.testing import faults  # noqa: E402
 
+from test_decode_serving import _arrive_behind_a_commit  # noqa: E402
+
 D = "serving.decode."
 
 
@@ -451,7 +453,7 @@ def test_one_provoked_stall_is_one_entry_with_its_phase(
                 fn = get(key)
                 if key != ("decode",):
                     return fn
-                return lambda *a: (maybe(), fn(*a))[1]
+                return lambda *a, **k: (maybe(), fn(*a, **k))[1]
             monkeypatch.setattr(sched._jit, "get", slow_get)
         elif site == "commit":
             publish = sched._cache.publish_gauges
@@ -637,12 +639,9 @@ def _chunk_spans(decode_model, **cfg):
     rode0 = obs.counter(D + "chunks_overlapped").value
     try:
         sched = serving.DecodeScheduler(decode_model, _cfg(**cfg))
-        first = sched.submit(_prompt(9), max_new_tokens=60)
-        while len(first.token_times) < 3:
-            time.sleep(0.002)
-        futs = [sched.submit(_prompt(n, n), max_new_tokens=4)
-                for n in (70, 37, 90)]
-        outs = [f.result(timeout=300) for f in [first] + futs]
+        outs = [f.result(timeout=300) for f in _arrive_behind_a_commit(
+            sched, [_prompt(9)] + [_prompt(n, n) for n in (70, 37, 90)],
+            first_new=60, new=4)]
         sched.stop()
     finally:
         obs.remove_sink(ring)

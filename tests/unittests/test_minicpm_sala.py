@@ -30,6 +30,7 @@ from paddle_tpu import serving
 from paddle_tpu.models import minicpm_sala as M
 from paddle_tpu.models import transformer as T
 from paddle_tpu.parallel import flash_attention as FA
+from paddle_tpu.serving import step_programs
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 CONFIG = os.path.join(ROOT, "chipbench/configs/minicpm_sala_9b.json")
@@ -605,17 +606,13 @@ def _largest_constant_bytes(lowered_text):
 def _lowered_steps(sched, chunk):
     c, p, cfg = sched._cache, sched._params, sched.config
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
-    S, mp = cfg.num_slots, c.max_pages_per_seq
+    S, sizes = cfg.num_slots, sched._chunk_sizes[chunk]
     yield sched._jit.get(("decode",)).lower(
-        p, c.pools, i32(S), i32(S), i32(S, mp), i32(S),
-        jax.ShapeDtypeStruct((S,), jnp.uint32),
-        jax.ShapeDtypeStruct((S,), jnp.float32),
-        i32(S + len(sched.model.step_counters)),
-        jax.ShapeDtypeStruct((S,), jnp.bool_)).as_text()
+        p, c.pools, i32(*sched._standing.shape),
+        i32(S + len(sched.model.step_counters))).as_text()
     yield sched._jit.get(("chunk", chunk)).lower(
-        p, c.pools, i32(chunk), i32(), i32(), i32(chunk // cfg.page_size),
-        i32(mp), i32(), jax.ShapeDtypeStruct((), jnp.uint32),
-        jax.ShapeDtypeStruct((), jnp.float32)).as_text()
+        p, c.pools, i32(step_programs.chunk_length(chunk, sizes)),
+        sizes=sizes).as_text()
 
 
 def test_step_programs_hold_no_weights():
