@@ -2259,7 +2259,14 @@ def paged_mla_rows_attention(q, latent_pool, page_tables, rows, n_rows, *,
 # head's ``Dh`` lanes of the tile and ``p . v`` taken over the same lanes, so
 # no product is wasted on another head's lanes.  A grid step may carry
 # ``q_tokens`` consecutive tokens of one sequence (a prefill chunk's rows, as
-# the latent kernel does).  With ``window = W`` a query at position ``t`` sees
+# the latent kernel does), staggered by ``block``: token ``t`` of the step sees
+# the keys up to the end of its own BLOCK of ``block`` tokens (``limit = kv_len
+# - (q_tokens - (t // block + 1) * block)``; the step's first token stands at
+# a multiple of ``block``).  ``block = 1`` is the causal rule, one key more a
+# token; ``block = q_tokens`` is no stagger at all, every row of the step sees
+# all ``kv_len`` keys: a decode step that carries a slot's whole block under
+# attention that is bidirectional inside it (``models/sdar.py``).  With
+# ``window = W`` a query at position ``t`` sees
 # keys ``t - W + 1 .. t``: the walk starts at the page that holds the oldest
 # visible key and masks that page's rows before it, and the table is read as
 # a RING (logical page ``p`` in column ``p % width``; a table as wide as the
@@ -2282,7 +2289,7 @@ def _gqa_row_tokens(n_rows, per_head, group, q_tokens):
 
 
 def _paged_gqa_walk_reference(q, k_pool, v_pool, page_tables, kv_lens, n_kv,
-                              q_tokens, window, sm_scale, layer):
+                              q_tokens, window, sm_scale, layer, block=1):
     """``q [S, Hkv * q_tokens * g, Dh]`` (KV-head-major, then token, then
     group member) against each slot's pages; ``page_tables [S, MP]`` or one
     row ``[MP]`` for every slot.  Gathers only the pages a window can reach."""
@@ -2294,14 +2301,16 @@ def _paged_gqa_walk_reference(q, k_pool, v_pool, page_tables, kv_lens, n_kv,
     per = R // n_kv
     g = per // q_tokens
     tok = _gqa_row_tokens(R, per, g, q_tokens)
-    limit = kv_lens[:, None] - (q_tokens - 1 - tok)[None, :]        # [S, R]
+    # a row sees the keys up to the end of its token's block
+    limit = kv_lens[:, None] - (
+        q_tokens - (tok // block + 1) * block)[None, :]             # [S, R]
     if window is None:
         first = jnp.zeros((S,), jnp.int32)
         n_walk = mp
         lo = jnp.zeros_like(limit)
     else:
         lo = jnp.maximum(limit - window, 0)
-        first = jnp.maximum(kv_lens - (q_tokens - 1) - window, 0) // ps
+        first = jnp.maximum(kv_lens - (q_tokens - block) - window, 0) // ps
         n_walk = min(mp, (window + q_tokens + ps - 2) // ps + 1)
     cols = (first[:, None] + jnp.arange(n_walk)[None, :]) % mp      # [S, NW]
     pages = (page_tables[cols] if page_tables.ndim == 1
@@ -2331,7 +2340,7 @@ def _paged_gqa_walk_reference(q, k_pool, v_pool, page_tables, kv_lens, n_kv,
 def _paged_gqa_walk_kernel(pt_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
                            k_buf, v_buf, sem, *, layer, page_size, pages,
                            table_width, n_kv, per_head, group, q_tokens,
-                           head_dim, window, sm_scale, listed=False):
+                           head_dim, window, sm_scale, listed=False, block=1):
     """One grid step = one slot's ``n_kv * per_head`` query rows against the
     slot's live pages, ``pages`` a turn (``_walk_pages``): the pages up to
     ``kv_len``, from the sequence's first or, with a ``window``, from the one
@@ -2357,8 +2366,12 @@ def _paged_gqa_walk_kernel(pt_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
     div = jax.lax.div
     r = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
     tok = jnp.minimum(div(jax.lax.rem(r, per_head), group), q_tokens - 1)
-    limit = kvl - (q_tokens - 1 - tok)                       # [rows, 1]
-    least = jnp.maximum(kvl - (q_tokens - 1), 0)             # the first token's
+    if block == 1:
+        limit = kvl - (q_tokens - 1 - tok)                   # [rows, 1]
+    else:
+        # to the end of the token's block: ``block == q_tokens`` is ``kvl``
+        limit = kvl - (q_tokens - (div(tok, block) + 1) * block)
+    least = jnp.maximum(kvl - (q_tokens - block), 0)         # the first token's
     if window is None:
         first_page, first = 0, None
     else:
@@ -2420,7 +2433,8 @@ def _gqa_turn_pages(ps, lanes, mp, itemsize, rows, keys=None):
 
 
 def _paged_gqa_walk_pallas(q, k_pool, v_pool, page_tables, kv_lens, n_kv,
-                           q_tokens, window, sm_scale, interpret, layer):
+                           q_tokens, window, sm_scale, interpret, layer,
+                           block=1):
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -2449,16 +2463,17 @@ def _paged_gqa_walk_pallas(q, k_pool, v_pool, page_tables, kv_lens, n_kv,
     kernel, layer_op = _layer_prefetch(
         _paged_gqa_walk_kernel, layer, 2, page_size=ps, pages=pages,
         table_width=mp, n_kv=n_kv, per_head=per_pad, group=g,
-        q_tokens=q_tokens, head_dim=Dh, window=window, sm_scale=sm_scale)
-    block = pl.BlockSpec((None, rows, Dh), lambda s, *_: (s, 0, 0))
+        q_tokens=q_tokens, head_dim=Dh, window=window, sm_scale=sm_scale,
+        **({} if block == 1 else {"block": block}))
+    slot_rows = pl.BlockSpec((None, rows, Dh), lambda s, *_: (s, 0, 0))
     stack = pl.BlockSpec(memory_space=pl.ANY)
     (out,) = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2 + len(layer_op),
             grid=(S,),
-            in_specs=[block, stack, stack],
-            out_specs=[block],
+            in_specs=[slot_rows, stack, stack],
+            out_specs=[slot_rows],
             scratch_shapes=[
                 pltpu.VMEM((2, pages * ps, lanes), k_pool.dtype),   # k tiles
                 pltpu.VMEM((2, pages * ps, lanes), v_pool.dtype),   # v tiles
@@ -2489,29 +2504,44 @@ def _gqa_heads(q, k_pool):
 
 
 def _gqa_walk(q, k_pool, v_pool, page_tables, kv_lens, n_kv, q_tokens,
-              window, sm_scale, impl, interpret, layer):
+              window, sm_scale, impl, interpret, layer, block=1,
+              one_table=False):
+    """``one_table``: every row of ``page_tables`` is the same sequence's (a
+    chunk's grid steps)."""
     impl, interpret = _mla_impl(impl, interpret)
     if window is not None and int(window) < 1:
         raise ValueError("window must be >= 1, got %r" % (window,))
+    block = int(block)
+    if block < 1 or q_tokens % block:
+        raise ValueError(
+            "a grid step's %d tokens are whole blocks of `block` tokens; got "
+            "block = %r" % (q_tokens, block))
+    if block > 1 and window is not None:
+        raise ValueError("a window under blocks of %d tokens is not written "
+                         "here" % block)
     if sm_scale is None:
         sm_scale = 1.0 / float(np.sqrt(q.shape[-1]))
     layer = _layer_index(layer)
     if impl == "reference":
-        tables = page_tables[0] if q_tokens > 1 else page_tables
+        tables = (page_tables[0] if one_table and q_tokens > 1
+                  else page_tables)
         return _paged_gqa_walk_reference(
             q, k_pool, v_pool, tables, kv_lens, n_kv, q_tokens, window,
-            sm_scale, layer)
+            sm_scale, layer, block)
     return _paged_gqa_walk_pallas(
         q, k_pool, v_pool, page_tables, kv_lens, n_kv, q_tokens, window,
-        sm_scale, interpret, layer)
+        sm_scale, interpret, layer, block)
 
 
 def paged_gqa_decode_attention(q, k_pool, v_pool, page_tables, kv_lens, *,
                                layer, window=None, sm_scale=None, impl=None,
-                               interpret=None):
-    """Grouped-query decode on the walk: one query token per slot.
+                               interpret=None, block=1):
+    """Grouped-query decode on the walk: one query token per slot, or a
+    slot's ``T`` newest tokens.
 
-    q: ``[S, Hq, Dh]``; k_pool / v_pool: the stored stacks ``[L, num_pages,
+    q: ``[S, Hq, Dh]``, or ``[S, T, Hq, Dh]`` for the ``T`` tokens at
+        positions ``kv_len - T .. kv_len - 1`` (their rows already in the
+        pool); k_pool / v_pool: the stored stacks ``[L, num_pages,
         page_size, Hkv*Dh]`` addressed in place by ``(layer, page)``
         (``layer`` a Python int or a traced int32 scalar), ``Hq = g * Hkv``
         (query head ``i`` reads KV head ``i // g``).
@@ -2523,29 +2553,45 @@ def paged_gqa_decode_attention(q, k_pool, v_pool, page_tables, kv_lens, *,
         ``p`` in column ``p % MP`` — of which only the columns of the pages
         that hold those keys are read: a column of an older page may name
         any page, or scratch.
-    Returns ``[S, Hq, Dh]`` float32.
+    block: how the ``T`` tokens are staggered: 1 causally, ``T`` not at all
+        (every token sees all ``kv_len`` keys: one block under attention that
+        is bidirectional inside it); ``kv_len - T`` is a multiple of it.
+    Returns ``q``'s shape, float32.
     """
-    return _gqa_walk(q, k_pool, v_pool, page_tables, kv_lens,
-                     _gqa_heads(q, k_pool), 1, window, sm_scale, impl,
-                     interpret, layer)
+    n_kv = _gqa_heads(q, k_pool)
+    if q.ndim == 3:
+        return _gqa_walk(q, k_pool, v_pool, page_tables, kv_lens, n_kv, 1,
+                         window, sm_scale, impl, interpret, layer, block)
+    S, T, Hq, Dh = q.shape
+    g = Hq // n_kv
+    # rows of a slot KV-head-major: [S, T, Hkv, g, Dh] -> [S, Hkv, T, g, Dh]
+    rows = q.reshape(S, T, n_kv, g, Dh).transpose(0, 2, 1, 3, 4)
+    out = _gqa_walk(rows.reshape(S, n_kv * T * g, Dh), k_pool, v_pool,
+                    page_tables, kv_lens, n_kv, T, window, sm_scale, impl,
+                    interpret, layer, block)
+    return out.reshape(S, n_kv, T, g, Dh).transpose(0, 2, 1, 3, 4).reshape(
+        S, T, Hq, Dh)
 
 
 def paged_gqa_prefill_attention(q, k_pool, v_pool, pages, start, valid, *,
                                 layer, window=None, sm_scale=None, impl=None,
-                                interpret=None):
+                                interpret=None, block=1):
     """Grouped-query attention of one prefill chunk on the walk: ``q [C, Hq,
     Dh]`` at absolute positions ``start ..`` against the sequence's ``pages
     [MP]`` (the chunk's own rows already scattered in), causal by position
     and, with ``window = W``, no further back than ``W - 1`` (``pages`` then
     a ring, as in :func:`paged_gqa_decode_attention`); rows at or past
     ``valid`` are padding (garbage out).  ``_GQA_PREFILL_TOKENS`` rows of the
-    chunk a grid step.  Returns ``[C, Hq, Dh]`` float32."""
+    chunk a grid step.  With ``block = B`` a row sees the keys up to the end
+    of its own block of ``B`` positions (causal between blocks, bidirectional
+    inside one; ``start`` a multiple of ``B``, and a grid step carries whole
+    blocks).  Returns ``[C, Hq, Dh]`` float32."""
     import jax.numpy as jnp
 
     C, Hq, Dh = q.shape
     n_kv = _gqa_heads(q, k_pool)
     g = Hq // n_kv
-    nt = math.gcd(C, _GQA_PREFILL_TOKENS)
+    nt = math.gcd(C, math.lcm(_GQA_PREFILL_TOKENS, int(block)))
     first = jnp.arange(C // nt, dtype=jnp.int32) * nt
     lens = jnp.where(first < valid, start + first + nt, 0)
     # rows of a step KV-head-major: [C/nt, nt, Hkv, g, Dh] -> [.., Hkv, nt, g]
@@ -2553,7 +2599,8 @@ def paged_gqa_prefill_attention(q, k_pool, v_pool, pages, start, valid, *,
     out = _gqa_walk(
         rows.reshape(C // nt, n_kv * nt * g, Dh), k_pool, v_pool,
         jnp.broadcast_to(pages[None, :], (C // nt, pages.shape[0])), lens,
-        n_kv, nt, window, sm_scale, impl, interpret, layer)
+        n_kv, nt, window, sm_scale, impl, interpret, layer, block,
+        one_table=True)
     return out.reshape(C // nt, n_kv, nt, g, Dh).transpose(
         0, 2, 1, 3, 4).reshape(C, Hq, Dh)
 

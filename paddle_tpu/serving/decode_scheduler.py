@@ -120,7 +120,10 @@ from .errors import (
 from .kv_cache import PagedKVCache
 from .request_queue import Request, RequestQueue
 from .step_programs import (
+    BLOCK_COUNTERS,
     StepPrograms,
+    block_state,
+    block_state_length,
     chunk_length,
     int32_bits,
     split_chunk,
@@ -142,6 +145,10 @@ _steps = _obs.counter("serving.decode.steps")
 _steps_overlapped = _obs.counter("serving.decode.steps_overlapped")
 _chunks_overlapped = _obs.counter("serving.decode.chunks_overlapped")
 _tokens_discarded = _obs.counter("serving.decode.tokens_discarded")
+# a block model (``DecodeModel.block``): tokens a commit delivered to a slot
+# (0 .. B: what a forward unmasked IN ORDER behind what the request had; a
+# forward that wrote K/V delivers none), a cell a model's block length
+_tokens_delivered = _obs.histogram("serving.decode.tokens_delivered")
 # host arrays handed to the device a dispatch: ONE, the packed buffer
 # (``step_programs.py``), counted where it is made so that the next argument
 # someone adds is seen
@@ -260,6 +267,29 @@ class DecodeModel:
     have: ``serving.decode.<name>{chunk="0"}`` the decode steps',
     ``{chunk="1"}`` the chunk programs'.
 
+    ``block``: None, or ``dict(length=B, mask_id=, steps=, threshold=)`` for a
+    model that generates by DIFFUSION OVER BLOCKS: a step carries each slot's
+    current block of ``B`` positions, some of them the mask id.  ``decode_fn``
+    then receives ``tokens [S, B]``, ``positions [S]`` the blocks' STARTS
+    (multiples of ``B``) and ``kv_lens = start + B`` (0: the slot does not
+    decode): it writes the block's ``B`` rows at ``start ..`` and attends over
+    the slot's first ``kv_lens`` rows with NO stagger inside the block
+    (``paged_gqa_decode_attention(..., block=B)``), and returns ``logits [S,
+    B, V]``, row ``i`` predicting the id AT position ``start + i``
+    (unshifted).  The step program (``step_programs.py``) draws a candidate
+    and its confidence a masked position and unmasks by the rule the model
+    states (``steps`` denoising forwards a block at most, every candidate
+    above ``threshold`` at once, every id a candidate); a forward that finds
+    its block whole, or out of its ``steps`` denoising forwards, is the one
+    whose K/V rows stay: the slot's ``kv_len`` moves by ``B`` there and
+    nowhere else.  A step delivers 0 to ``B`` tokens a slot.
+    ``prefill_chunk_fn`` runs under the same mask (position ``i`` sees ``j``
+    iff ``j // B <= i // B``, so a prompt's K/V is not causal): only the
+    prompt's whole blocks are prefilled, its last ``len % B`` ids are seated
+    in the first decoded block as known positions, and nobody reads the
+    chunk's logits.  ``page_size`` is a multiple of ``B``, so chunks and
+    pages hold whole blocks and a prefix hit stays exact.
+
     ``cache`` is the cache's pytree, a dict of arrays
     (``kv_cache.PagedKVCache.pools``): ``"k"`` and ``"v"`` in the stored
     shape ``[num_layers, num_pages, page_size, num_heads * head_dim]``
@@ -307,14 +337,16 @@ class DecodeModel:
     ``models.mellum.build_decode_model``,
     ``models.solar_open2.build_decode_model``,
     ``models.afmoe.build_decode_model``,
-    ``models.evabyte.build_decode_model`` and
-    ``models.ouro.build_decode_model`` are the in-repo producers.
+    ``models.evabyte.build_decode_model``,
+    ``models.ouro.build_decode_model`` and
+    ``models.sdar.build_decode_model`` are the in-repo producers.
     """
 
     def __init__(self, decode_fn, prefill_chunk_fn, *, params=None,
                  num_layers=0, num_heads=0, head_dim=0, vocab_size,
                  eos_id=None, name="decode-model", page_pools=None,
-                 slot_state=None, step_counters=(), page_groups=None):
+                 slot_state=None, step_counters=(), page_groups=None,
+                 block=None):
         self.decode_fn = decode_fn
         self.prefill_chunk_fn = prefill_chunk_fn
         self.params = params
@@ -326,8 +358,21 @@ class DecodeModel:
         self.name = name
         self.page_pools = dict(page_pools or {})
         self.slot_state = dict(slot_state or {})
-        self.step_counters = tuple(step_counters)
         self.page_groups = dict(page_groups or {})
+        if block is not None:
+            block = dict(length=int(block["length"]),
+                         mask_id=int(block["mask_id"]),
+                         steps=int(block["steps"]),
+                         threshold=float(block["threshold"]))
+            if (block["length"] < 1 or block["steps"] < 1
+                    or not 0 <= block["mask_id"] < self.vocab_size):
+                raise ValueError(
+                    "block: length and steps are >= 1 and mask_id a row of "
+                    "the vocabulary; got %r" % (block,))
+        self.block = block
+        # a block step counts its own three behind the model's
+        self.step_counters = tuple(step_counters) + (
+            BLOCK_COUNTERS if block else ())
         self._programs = {}
         self._programs_lock = threading.Lock()
 
@@ -580,15 +625,33 @@ class _Slot:
     iteration; the first sampled token (produced by the final chunk)
     flips it to decoding.  A slot made without ``prefill_pos`` (a
     handed-off sequence) is already past prefill.
+
+    Under a block model (``block``: ``DecodeModel.block``) the slot prefills
+    the prompt's whole blocks (``prefill_end``) and then holds its CURRENT
+    block as the last committed forward left it: ``block`` (``B`` ids, the
+    mask id where a position is still masked; the first one opens with the
+    prompt's leftover ids), ``forwards`` (denoising forwards it has had),
+    ``kv_len`` its start (the rows of the blocks before it are in the cache)
+    and ``end`` the sequence's last block's end.
     """
 
     __slots__ = ("req", "pages", "prompt_len", "kv_len", "generated",
-                 "prefill_pos", "hashes", "more", "inflight")
+                 "prefill_pos", "hashes", "more", "inflight", "prefill_end",
+                 "block", "forwards", "end", "mask_id", "block_since")
 
-    def __init__(self, req, pages, prefill_pos=None, hashes=None):
+    def __init__(self, req, pages, prefill_pos=None, hashes=None, block=None):
         self.req = req
         self.pages = pages
-        self.prompt_len = req.prompt_len
+        self.prompt_len = self.prefill_end = req.prompt_len
+        self.block = self.block_since = None
+        if block is not None:
+            B = block["length"]
+            self.prefill_end = req.prompt_len // B * B
+            self.end = -(-(req.prompt_len + req.max_new_tokens) // B) * B
+            self.mask_id, self.forwards = block["mask_id"], 0
+            self.block = np.full((B,), self.mask_id, np.int32)
+            left = req.prompt_len - self.prefill_end
+            self.block[:left] = req.prompt[self.prefill_end:]
         # tokens written to the paged cache so far
         self.kv_len = (req.prompt_len if prefill_pos is None
                        else int(prefill_pos))
@@ -603,8 +666,17 @@ class _Slot:
 
     @property
     def prefilling(self):
-        """True until the final chunk has produced the first token."""
-        return self.prefill_pos < self.prompt_len or not self.generated
+        """True until the final chunk has produced the first token (under a
+        block model: until the prompt's whole blocks are in the cache)."""
+        return self.prefill_pos < self.prefill_end or (
+            self.block is None and not self.generated)
+
+    def next_block(self):
+        """The block's rows are in the cache: the next one opens, all
+        masked."""
+        self.kv_len += len(self.block)
+        self.block = np.full_like(self.block, self.mask_id)
+        self.forwards = 0
 
 
 class _Step:
@@ -618,12 +690,12 @@ class _Step:
     and once another program has written the cache behind it."""
 
     __slots__ = ("entries", "args", "out", "pools_before", "sampled",
-                 "read_at")
+                 "read_at", "sent_at")
 
     def __init__(self, entries, args):
         self.entries = entries
         self.args = args
-        self.out = None
+        self.out = self.sent_at = None
         self.pools_before = None
         self.sampled = None            # ``out`` read back, until committed
         self.read_at = None            # when it was: the device's next start
@@ -756,6 +828,17 @@ class DecodeScheduler:
                 raise ServingError(
                     "sessions require prefix_cache=True: a session pin is an "
                     "extra refcount on the prompt's prefix-index chain")
+            blk = self._block = model.block
+            if blk and (model.slot_state or len(model.page_groups) > 1
+                        or role != "both" or cfg.page_size % blk["length"]):
+                raise ServingError(
+                    "%r decodes by blocks of %d positions: pages (and so "
+                    "chunks and prefix hits) hold whole blocks, so page_size "
+                    "(%d) is a multiple of it; a block's state is not part of "
+                    "a hand-off between roles and has no rule for slot state "
+                    "or a windowed page group: serve it with role='both'"
+                    % (model.name, blk["length"], cfg.page_size))
+            B = blk["length"] if blk else 0
             # conversational sessions (serving/sessions.py): the store is
             # SHARED across a pool's replicas; each scheduler only parks
             # into and releases pins against its OWN cache
@@ -817,7 +900,8 @@ class DecodeScheduler:
             self._unread = collections.deque()
             self._planned = []             # planned and not yet sent (None: replan)
             self._no_previous = np.zeros(
-                (cfg.num_slots + len(model.step_counters),), np.int32)
+                ((block_state_length(cfg.num_slots, B) if B
+                  else cfg.num_slots) + len(model.step_counters),), np.int32)
             # this scheduler's copy of the weights, on the device once: every
             # step takes it as an argument.  ``device`` (a pool's replica)
             # COMMITS weights and cache there, which is what keeps the worker
@@ -914,8 +998,8 @@ class DecodeScheduler:
                          for g, width in zip(self._cache.group_names, widths))
                 for w in self._chunk_widths()}
             self._standing = np.zeros(
-                (cfg.num_slots, step_columns(widths)), np.int32)
-            self._group_tables = split_step(self._standing, self._widths)[0]
+                (cfg.num_slots, step_columns(widths, B)), np.int32)
+            self._group_tables = self._split_step(self._standing)[0]
             self._tables, *more = self._group_tables
             self._more_tables = dict(zip(self._cache.groups, more))
             self._widest_chunk = widest
@@ -1029,6 +1113,11 @@ class DecodeScheduler:
         return tuple(sorted({b for b in self.prefill_buckets if b < ct}
                             | {ct}))
 
+    def _split_step(self, buf):
+        """``split_step`` of a buffer of this scheduler's decode step."""
+        return split_step(buf, self._widths,
+                          self._block["length"] if self._block else 0)
+
     def _step_buffer(self, standing):
         """The buffer of one decode dispatch, the ONE host array it hands the
         device (it goes into the jitted call as numpy: no transfer of its
@@ -1048,19 +1137,20 @@ class DecodeScheduler:
         return buf, sizes, split_chunk(buf, sizes)
 
     def _pack_step(self, tokens, positions, tables, kv_lens, seeds, temps,
-                   from_previous=None):
+                   from_previous=None, forwards=None):
         """A decode step's buffer from the step's values a vector; ``tables``
         as the model receives them.  The loop (``_plan_step``) writes the
         same views in place."""
         buf = self._step_buffer(standing=False)
-        views, columns = split_step(buf, self._widths)
+        views, columns = self._split_step(buf)
         for view, table in zip(views, self._group_list(tables)):
             view[:] = np.asarray(table)
         for column, vector, dtype in zip(
                 columns,
-                (tokens, positions, kv_lens, seeds, temps, from_previous),
+                (tokens, positions, kv_lens, seeds, temps, from_previous,
+                 forwards),
                 (np.int32, np.int32, np.int32, np.uint32, np.float32,
-                 np.int32)):
+                 np.int32, np.int32)):
             if vector is not None:
                 column[:] = int32_bits(vector, dtype)
         return buf
@@ -1274,7 +1364,9 @@ class DecodeScheduler:
         served) on its own weights and cache, the cache updated in place as
         the loop does.  ``args`` are the step's values one by one, host or
         device arrays, as the model's own function names them: a decode
-        step's ``(tokens, positions, page_tables, kv_lens, seeds, temps)``,
+        step's ``(tokens, positions, page_tables, kv_lens, seeds, temps)``
+        (a block model's: ``(ids [S, B], starts, page_tables, ends, seeds,
+        temps, forwards)``, ``step_programs.py``),
         then ``previous, from_previous`` or neither (every slot feeds
         ``tokens``: no step in flight before it); a chunk's ``(tokens, start,
         valid, chunk_pages, gather_pages, slot, seed, temp)``.  They are
@@ -1288,12 +1380,12 @@ class DecodeScheduler:
                 "run_step: the worker thread owns the cache; stop() first")
         key = tuple(key)
         if key == ("decode",):
-            *values, previous, from_previous = (
-                args if len(args) == 8 else args + (self._no_previous, None))
+            n = 7 if self._block else 6
+            previous, from_previous = args[n:] or (self._no_previous, None)
             out, self._cache.pools = self._jit.get(key)(
                 self._params, self._cache.pools,
-                self._pack_step(*values, from_previous), previous,
-                widths=self._widths)
+                self._pack_step(*args[:6], from_previous, *args[6:n]),
+                previous, widths=self._widths)
         else:
             packed, sizes = self._pack_chunk(key[1], *args)
             out, self._cache.pools = self._jit.get(key)(
@@ -1842,6 +1934,8 @@ class DecodeScheduler:
                 self._completed += 1
                 continue
             need = cache.pages_for(req.prompt_len + req.max_new_tokens)
+            # (a block model's last block may end past that: inside the same
+            # page, which holds whole blocks)
             if cfg.prefix_cache and hashes is None:
                 # probe ONCE, before the fresh alloc: hits shrink the
                 # fresh reservation and stay rc-pinned (a re-parked
@@ -1903,7 +1997,8 @@ class DecodeScheduler:
                 "serving.queue_wait", req.enqueue_wall, wait,
                 tags=req.trace.child().tags(priority=req.priority,
                                             seq=req.seq))
-        slot = _Slot(req, pages, prefill_pos=cached_tokens, hashes=hashes)
+        slot = _Slot(req, pages, prefill_pos=cached_tokens, hashes=hashes,
+                     block=self._block)
         for g, n in (more or {}).items():
             self._cache.groups[g].reserve(n)
             slot.more[g] = _HeldPages(n)
@@ -1956,7 +2051,7 @@ class DecodeScheduler:
         return min(ct, b)
 
     def _chunks_left(self, slot):
-        remaining = slot.prompt_len - slot.prefill_pos
+        remaining = slot.prefill_end - slot.prefill_pos
         return -(-remaining // self._chunk_width_for(remaining))
 
     def _send_chunk(self):
@@ -1978,7 +2073,7 @@ class DecodeScheduler:
             slot = self._slots[idx]
             req = slot.req
             start = slot.prefill_pos
-            remaining = req.prompt_len - start
+            remaining = slot.prefill_end - start
             width = self._chunk_width_for(remaining)
             valid = min(remaining, width)
             packed, sizes, (tokens, scalars, vecs) = self._chunk_buffer(
@@ -2120,9 +2215,11 @@ class DecodeScheduler:
                     if pi < len(slot.hashes):
                         self._cache.register_prefix(slot.hashes, pi,
                                                     slot.pages[pi])
-            if slot.prefill_pos >= req.prompt_len:
+            if slot.block is None and slot.prefill_pos >= req.prompt_len:
                 # final chunk: the sampled token at position
                 # prompt_len - 1 is the sequence's first generated token
+                # (a block model's chunk samples nothing anyone reads: its
+                # first token comes out of the first block's forwards)
                 slot.generated.append(first)
                 req.journal.accepted.append(first)
                 req.token_times.append(time.perf_counter())
@@ -2310,7 +2407,8 @@ class DecodeScheduler:
         slot = self._slots[idx]
         eos = self.model.eos_id
         if (len(slot.generated) >= slot.req.max_new_tokens
-                or (eos is not None and slot.generated[-1] == eos)):
+                or (eos is not None and slot.generated
+                    and slot.generated[-1] == eos)):
             self._retire(idx)
             return True
         return False
@@ -2410,11 +2508,23 @@ class DecodeScheduler:
         standing one, which holds every group's table (the copy the step
         needs anyway: the program may run behind the host, which rewrites
         the tables while it is in flight), with the slots' values written
-        into its other columns."""
+        into its other columns.
+
+        Under a block model a step carries a slot's current BLOCK (``B`` ids
+        from ``kv_len`` on) and may deliver 0 to ``B`` tokens, which only its
+        logits decide: a slot is in the step until what it has DELIVERED
+        reaches ``max_new_tokens`` (so one step more than it needed may go
+        out: ``tokens_discarded``), a slot with a step in flight takes its
+        block, its start and its forwards from that step's output on the
+        device, and the host's columns hold the block as the last COMMITTED
+        forward left it (what a replan after a lost readback starts from).
+        ``kv_lens`` carries the sequence's end: past it the program takes the
+        slot out of the step itself."""
+        blk = self._block
         with self._telemetry.span("serving.decode.step.build") as build:
             entries = [(i, s) for i, s in enumerate(self._slots)
                        if s is not None and not s.prefilling
-                       and (len(s.generated) + s.inflight
+                       and (len(s.generated) + (0 if blk else s.inflight)
                             < s.req.max_new_tokens)]
             if not entries:
                 build.name = None      # no step: the span closes into no cell
@@ -2424,21 +2534,31 @@ class DecodeScheduler:
                 for i, slot in entries:
                     self._ensure_pages(i, slot, slot.kv_len + slot.inflight + 1)
             buf = self._step_buffer(standing=True)
-            tokens, positions, kv_lens, seeds, temps, from_previous = (
-                split_step(buf, self._widths)[1])
+            (tokens, positions, kv_lens, seeds, temps, from_previous,
+             *forwards) = self._split_step(buf)[1]
             seeds, temps = seeds.view(np.uint32), temps.view(np.float32)
             for i, slot in entries:
-                at = slot.kv_len + slot.inflight
                 if slot.inflight:
                     from_previous[i] = 1         # its token is on the device
+                if blk:
+                    kv_lens[i] = slot.end        # the sequence's last row
+                    if not slot.inflight:
+                        tokens[i] = slot.block       # the block as committed
+                        positions[i] = slot.kv_len   # ... its start
+                        forwards[0][i] = slot.forwards
                 else:
-                    tokens[i] = slot.generated[-1]   # the last sampled token
-                positions[i] = at                # ... at the next cache index
-                kv_lens[i] = at + 1              # visible kv incl. this token
+                    at = slot.kv_len + slot.inflight
+                    positions[i] = at            # ... at the next cache index
+                    kv_lens[i] = at + 1          # visible kv incl. this token
+                    if not slot.inflight:
+                        tokens[i] = slot.generated[-1]   # the last sampled
                 temps[i], seeds[i] = self._sampling_params(slot.req)
                 slot.inflight += 1
-            _walked_pages.inc(int(np.sum(
-                -(-kv_lens // self._cache.page_size))))
+            # rows a slot's walk reads, in pages (a block model's ``kv_lens``
+            # holds the sequence's end: its rows are its block's end)
+            rows = (np.asarray([s.kv_len + blk["length"] for _, s in entries])
+                    if blk else kv_lens)
+            _walked_pages.inc(int(np.sum(-(-rows // self._cache.page_size))))
             _table_pages.inc(self._tables.size)
             # The decode step scatters EVERY slot's token k/v at
             # page_tables[s, 0] offset 0 when positions[s] == 0 — a seated
@@ -2478,6 +2598,12 @@ class DecodeScheduler:
             # the donated pytree always belongs to the newest dispatch
             self._cache.pools = pools
             plan.args = None
+            if self._block:
+                # a block's span opens with its first forward's dispatch
+                plan.sent_at = (time.time(), time.perf_counter())
+                for _, slot in plan.entries:
+                    if slot.block_since is None:
+                        slot.block_since = plan.sent_at
             plan.pools_before = None if self._donated else before
             if self._unread:
                 _steps_overlapped.inc()
@@ -2616,12 +2742,72 @@ class DecodeScheduler:
         if self._breaker is not None:
             self._breaker.record_fatal()
 
+    def _commit_blocks(self, sent, out, live, tripped, now):
+        """A block model's part of :meth:`_commit_step`: ``out`` is the
+        step's ``block_state``.  A slot whose forward closed its block (found
+        it whole, or out of denoising forwards) moves ``kv_len`` by ``B``
+        (nowhere else does it move) and opens the next block; any other takes
+        the block as the forward left it.  Either delivers the block's ids IN
+        ORDER behind what the request has (0 to ``B`` of them, one stamp each,
+        all of this commit's instant; none past ``max_new_tokens`` or behind
+        an EOS): a denoising forward as far as they are unmasked, a closing
+        one what is left, as it is (nothing, unless a position's candidate
+        was the mask id).  What the forward of a slot that has left unmasked
+        is discarded."""
+        B = self._block["length"]
+        ids, _, _, flags, _ = block_state(out, self.config.num_slots, B)
+        eos = self.model.eos_id
+        # the step still in flight (a slot is in it where ``inflight`` is
+        # left over once this step's count is taken off)
+        ahead = self._unread[0] if self._unread else None
+        delivered = 0
+        for i, slot in live:
+            if i in tripped:
+                continue               # retired typed by the guard
+            req, n = slot.req, len(slot.generated)
+            closed = flags[i] >> B
+            if not closed:
+                slot.block = ids[i].copy()
+                slot.forwards += 1
+            at = slot.prompt_len + n - slot.kv_len
+            while (0 <= at < B and len(slot.generated) < req.max_new_tokens
+                   and (closed or slot.block[at] != slot.mask_id)):
+                tok = int(slot.block[at])
+                slot.generated.append(tok)
+                req.journal.accepted.append(tok)
+                req.token_times.append(now)
+                at += 1
+                if tok == eos:
+                    break
+            if not n and slot.generated:
+                # admission -> first delivered token
+                _ttft_hist.observe(now - req.enqueue_ts)
+            if closed:
+                # the forward that wrote the block's K/V: the block's span
+                # runs from its first forward's dispatch to here, and the
+                # next block's first forward is the step in flight
+                if slot.block_since is not None:
+                    self._telemetry.observe_span(
+                        "serving.decode.block", *slot.block_since)
+                slot.block_since = ahead.sent_at if slot.inflight else None
+                slot.next_block()
+            _tokens_delivered.observe(len(slot.generated) - n)
+            delivered += len(slot.generated) - n
+        _tokens.inc(delivered)
+        gone = [i for i, slot in sent.entries if self._slots[i] is not slot]
+        if gone:
+            _tokens_discarded.inc(int(sum(
+                bin(int(flags[i]) & ((1 << B) - 1)).count("1")
+                for i in gone)))
+
     def _commit_step(self, sent):
         """Take one read step's tokens into the slots that decoded in it —
         the ``_Slot`` objects captured at its plan: a slot that left in
         between (EOS seen a step late, a cancel, a deadline) drops its
         token, whoever sits at its index now — then run the decisions that
-        need token values."""
+        need token values.  (Under a block model a slot takes 0 to ``B``
+        tokens and its ``kv_len`` moves by ``B`` or not at all:
+        :meth:`_commit_blocks`.)"""
         cfg = self.config
         with self._telemetry.span("serving.decode.step.commit"):
             # the cache as this step found it and the step's output are
@@ -2631,7 +2817,9 @@ class DecodeScheduler:
             sampled = np.array(sent.sampled)
             sent.pools_before = sent.out = sent.sampled = None
             # the model's step counters came back behind the tokens
-            for c, n in zip(self._step_counters(0), sampled[cfg.num_slots:]):
+            for c, n in zip(self._step_counters(0),
+                            sampled[len(sampled) - len(
+                                self.model.step_counters):]):
                 c.inc(int(n))
             if self._breaker is not None:
                 self._breaker.record_success()
@@ -2651,20 +2839,23 @@ class DecodeScheduler:
                 tripped = self._guard_pages(owners, guard_vec,
                                             phase="decode")
             now = time.perf_counter()
-            for i, slot in live:
-                if i in tripped:
-                    continue           # retired typed by the guard
-                slot.kv_len += 1
-                if slot.more:
-                    self._release_window(i, slot)
-                tok = int(sampled[i])
-                slot.generated.append(tok)
-                slot.req.journal.accepted.append(tok)
-                slot.req.token_times.append(now)
+            if self._block:
+                self._commit_blocks(sent, sampled, live, tripped, now)
+            else:
+                for i, slot in live:
+                    if i in tripped:
+                        continue           # retired typed by the guard
+                    slot.kv_len += 1
+                    if slot.more:
+                        self._release_window(i, slot)
+                    tok = int(sampled[i])
+                    slot.generated.append(tok)
+                    slot.req.journal.accepted.append(tok)
+                    slot.req.token_times.append(now)
+                _tokens.inc(len(live) - len(tripped))
+                if len(live) < len(sent.entries):
+                    _tokens_discarded.inc(len(sent.entries) - len(live))
             _steps.inc()
-            _tokens.inc(len(live) - len(tripped))
-            if len(live) < len(sent.entries):
-                _tokens_discarded.inc(len(sent.entries) - len(live))
             for i, slot in live:
                 if self._slots[i] is slot:
                     self._finish_if_done(i)

@@ -1216,3 +1216,153 @@ def test_ouro_step_program_updates_both_pools_in_place(ouro_programs,
                 or "copy" in name or "slice" in name):
             found.append((name, opcode, dims))
     assert found == []
+
+
+# ---------------------------------------------------------------------------
+# SDAR (block diffusion): the grouped walk with ``block`` = 4 in both forms at
+# the published shapes, the grouped matrix product at a block step's 2048
+# pairs over 128 experts of 768, and the two step programs as the scheduler
+# dispatches them (the block form of the decode program, unmasking included)
+# ---------------------------------------------------------------------------
+
+def _sdar_cfg():
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "chipbench/configs/sdar_30b_a3b.json")) as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("form", ["decode", "chunk512", "chunk128"])
+def test_sdar_walks_compile_for_v5e(one_chip, no_persistent_cache, form):
+    """``[64, 4 x 32, 128]`` query rows a step (a slot's whole block: 32 rows
+    a K/V head, no stagger) and a chunk's two blocks a grid step, against a
+    ``[6, P, 64, 512]`` pool."""
+    cfg = _sdar_cfg()
+    H, Hkv, Dh = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
+                  cfg["head_dim"])
+    S, ps, B = cfg["slots"], cfg["page"], cfg["block_length"]
+    mp = cfg["max_seq_len"] // ps
+
+    def sds(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((cfg["num_hidden_layers"], cfg["num_pages"], ps, Hkv * Dh),
+               jnp.bfloat16)
+    kw = dict(layer=5, block=B, impl="pallas", interpret=False)
+    if form == "decode":
+        fn, args = (
+            lambda q, k, v, t, n: FA.paged_gqa_decode_attention(
+                q, k, v, t, n, **kw),
+            (sds((S, B, H, Dh), jnp.bfloat16), pool, pool, sds((S, mp)),
+             sds((S,))))
+    else:
+        fn, args = (
+            lambda q, k, v, pages, start, valid:
+            FA.paged_gqa_prefill_attention(q, k, v, pages, start, valid, **kw),
+            (sds((int(form[5:]), H, Dh), jnp.bfloat16), pool, pool,
+             sds((mp,)), sds(()), sds(())))
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "paged_gqa_full_attention" in text
+
+
+def test_sdar_grouped_matmul_compiles_for_v5e(one_chip, no_persistent_cache):
+    """``[2048, 2048] x [128, 2048, 1536]`` and ``[2048, 768] x [128, 768,
+    2048]``: a block step's 256 rows x 8 experts, 16 rows an expert."""
+    from paddle_tpu.parallel import moe
+
+    cfg = _sdar_cfg()
+    D, Fm, E, L = (cfg["hidden_size"], cfg["moe_intermediate_size"],
+                   cfg["num_experts"], cfg["num_hidden_layers"])
+    pairs = (cfg["slots"] * cfg["block_length"]
+             * cfg["num_experts_per_tok"])
+    assert (pairs, D, E, 2 * Fm) == (2048, 2048, 128, 1536)
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def both(x, w_gu, w_down, sizes):
+        gu = moe.grouped_matmul(x, w_gu, sizes, layer=L - 1, impl="pallas",
+                                interpret=False)
+        act = (jax.nn.silu(gu[:, :Fm]) * gu[:, Fm:]).astype(x.dtype)
+        return moe.grouped_matmul(act, w_down, sizes, layer=L - 1,
+                                  impl="pallas", interpret=False)
+
+    assert _kernel_calls(
+        both, sds((pairs, D)), sds((L, E, D, 2 * Fm)), sds((L, E, Fm, D)),
+        sds((E,), jnp.int32)) == 2
+
+
+_SDAR_PROGRAMS = ("decode", "chunk512")
+
+
+@pytest.fixture(scope="module")
+def sdar_programs(one_chip):
+    """The scheduler's own step programs (``StepPrograms``: the packed buffer
+    in, the block form's state out) over the published shapes."""
+    from paddle_tpu import core
+    from paddle_tpu.models import sdar as M
+    from paddle_tpu.serving import step_programs as SP
+
+    cfg = _sdar_cfg()
+    S, ps, B = cfg["slots"], cfg["page"], cfg["block_length"]
+    mp = cfg["max_seq_len"] // ps
+
+    def sds(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((cfg["num_hidden_layers"], cfg["num_pages"], ps, 512),
+               jnp.bfloat16)
+    cache = {"k": pool, "v": pool}
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: M.params(cfg, 0)))
+    model = M.build_decode_model(None, cfg)
+    programs = SP.StepPrograms(model, None, True)
+    sizes = ((512 // ps, mp),)
+    args = {
+        "decode": (programs.decode, (
+            sds((S, SP.step_columns((mp,), B))),
+            sds((SP.block_state_length(S, B) + len(model.step_counters),))),
+            {}),
+        "chunk512": (programs.chunk, (
+            sds((SP.chunk_length(512, sizes),)),), {"sizes": sizes})}
+    with _persistent_cache_off(), pytest.MonkeyPatch.context() as mp_:
+        mp_.setattr(FA, "cpu_backend", lambda: False)
+        mp_.setattr(core, "cpu_backend", lambda: False)
+        return cfg, {name: fn.lower(params, cache, *rest, **kw).compile()
+                     for name, (fn, rest, kw) in args.items()}
+
+
+@pytest.mark.parametrize("program", _SDAR_PROGRAMS)
+def test_sdar_step_program_compiles_for_v5e(sdar_programs, program):
+    """One walk and two grouped products a layer, both pools updated in
+    place, no pool-sized copy, and the program's scopes in the text."""
+    cfg, programs = sdar_programs
+    compiled = programs[program]
+    text = compiled.as_text()
+    L = cfg["num_hidden_layers"]
+    assert text.count('custom_call_target="tpu_custom_call"') == 3 * L
+    for scope in ("sdar.attn", "sdar.experts", "sdar.head") + (
+            ("sdar.unmask",) if program == "decode" else ()):
+        assert scope in text, scope
+    elems = L * cfg["num_pages"] * cfg["page"] * 512
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 2 * 2 * elems      # both bf16 pools
+    # a decode step's logits [256, 151936] float32 and their softmax, never
+    # as [64, 4, 151936] (four rows under a tile's eight: a padded copy)
+    assert mem.temp_size_in_bytes < 1.5 * 2 ** 30
+    assert "f32[%d,%d,%d]" % (cfg["slots"], cfg["block_length"],
+                              cfg["vocab_size"]) not in text
+    found = []
+    for m in re.finditer(r"%(\S+) = \w+\[([0-9,]+)\]\S* ([\w-]+)\(", text):
+        name, dims, opcode = m.groups()
+        n = int(np.prod([int(d) for d in dims.split(",")]))
+        if n in (elems, elems // L) and (
+                opcode in ("copy", "slice", "dynamic-slice")
+                or "copy" in name or "slice" in name):
+            found.append((name, opcode, dims))
+    assert found == []
